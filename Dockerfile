@@ -28,8 +28,8 @@ COPY tools ./tools
 # (the bridge stages through DLPack — no CUDA anywhere, unlike the
 # reference's NGC base).
 RUN pip install --no-cache-dir \
-        "jax[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html \
-        flax optax orbax-checkpoint chex einops ml_dtypes numpy \
+        "jax[tpu]==0.9.0" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html \
+        flax==0.12.3 optax orbax-checkpoint chex einops ml_dtypes numpy \
     && pip install --no-cache-dir torch --index-url https://download.pytorch.org/whl/cpu \
     && pip install --no-cache-dir -e .
 

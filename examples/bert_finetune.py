@@ -61,6 +61,11 @@ def main():
 
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+    from torch_cgx_tpu.utils import entry
+
+    entry.setup_compile_cache()
+    print("device:", entry.require_accelerator(cpu_requested=args.cpu),
+          file=sys.stderr)
     import jax.numpy as jnp
     import numpy as np
     import optax

@@ -144,6 +144,12 @@ def main():
 
     if args.simulate_devices:
         jax.config.update("jax_platforms", "cpu")
+    from torch_cgx_tpu.utils import entry
+
+    entry.setup_compile_cache()
+    print("device:", entry.require_accelerator(
+        cpu_requested=bool(args.simulate_devices)
+    ), file=sys.stderr)
 
     import jax.numpy as jnp
     import numpy as np

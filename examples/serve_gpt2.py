@@ -122,8 +122,12 @@ def main():
         ServeConfig, ServeSloController,
     )
     from torch_cgx_tpu.serving.prefill import PrefillWorker
+    from torch_cgx_tpu.utils import entry
     from torch_cgx_tpu.utils.logging import metrics
 
+    entry.setup_compile_cache()
+    device = entry.require_accelerator(cpu_requested=args.cpu)
+    print(f"device: {device}", file=sys.stderr)
     cfg = (
         GPT2Config.tiny() if args.model == "tiny" else GPT2Config.small()
     )
@@ -213,6 +217,7 @@ def main():
     summary = {
         "requests": len(requests),
         "tokens": tokens,
+        "device": device,
         "tokens_per_s": round(tokens / wall, 3),
         "ttft_p50_ms": round(ttft.get("p50", 0.0), 3),
         "ttft_p90_ms": round(ttft.get("p90", 0.0), 3),

@@ -41,7 +41,10 @@ def parse_args():
 
 
 def train(rank: int, ws: int, init_method: str, args) -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # codec runs on host
+    # The codec runs on the host. Set, not default: an inherited
+    # JAX_PLATFORMS=tpu (the Dockerfile sets it) would have every
+    # spawned rank claim the one chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     if args.simulate_hosts > 1:
         if "RANK" in os.environ and "WORLD_SIZE" in os.environ:
             # External (torchrun) launch may span REAL machines: a shared

@@ -52,7 +52,10 @@ def parse_args():
 
 
 def train(rank: int, ws: int, init_method: str, args) -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # codec runs on host
+    # The codec runs on the host. Set, not default: an inherited
+    # JAX_PLATFORMS=tpu (the Dockerfile sets it) would have every
+    # spawned rank claim the one chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["CGX_COMPRESSION_QUANTIZATION_BITS"] = str(args.bits)
     if args.allgather_bits:
         os.environ["CGX_FSDP_ALLGATHER_BITS"] = str(args.allgather_bits)

@@ -1,7 +1,7 @@
-"""Bench regression gate tests (ISSUE 3): the committed trajectory must
-pass ``--smoke`` (this IS the tier-1 self-check the issue asks for), a
-synthetic 2x regression must fail with the offending metric named, and
-the record normalization must skip failure/unresolved rows.
+"""Bench regression gate tests (ISSUE 3): a steady trajectory must pass
+``--smoke``, a synthetic 2x regression must fail with the offending
+metric named, and the record normalization must skip failure/unresolved
+rows.
 """
 
 from __future__ import annotations
@@ -25,10 +25,24 @@ def _load_gate():
     return mod
 
 
-def test_smoke_passes_on_committed_trajectory():
-    # Acceptance: bench_gate exits zero on the committed BENCH_LOG.
+_METRIC = "bridge_put_take_16MB"
+
+
+def _trajectory(tmp_path, values=(0.50, 0.52, 0.48, 0.51)) -> str:
+    """A small host-metric trajectory (the repo commits no log: the
+    benchmark that will own one is ROADMAP A1)."""
+    log = tmp_path / "trajectory.jsonl"
+    log.write_text("".join(
+        json.dumps({"tool": "shm_bench", "metric": _METRIC, "value": v,
+                    "unit": "GB/s", "backend": "host"}) + "\n"
+        for v in values
+    ))
+    return str(log)
+
+
+def test_smoke_passes_on_a_steady_trajectory(tmp_path):
     proc = subprocess.run(
-        [sys.executable, _GATE, "--smoke"],
+        [sys.executable, _GATE, "--smoke", "--log", _trajectory(tmp_path)],
         capture_output=True, text=True, cwd=_REPO,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
@@ -39,35 +53,34 @@ def test_synthetic_2x_regression_fails_named(tmp_path):
     # Acceptance: a fresh run at half the historical throughput exits
     # nonzero and names the offending metric.
     gate = _load_gate()
-    history = gate._read_jsonl(os.path.join(_REPO, "BENCH_LOG.jsonl"))
-    baselines = gate.build_baselines(history)
-    metric, base = next(iter(sorted(baselines.items())))
+    log = _trajectory(tmp_path)
+    base = gate.build_baselines(gate._read_jsonl(log))[_METRIC]
     cand = tmp_path / "cand.jsonl"
     cand.write_text(json.dumps({
-        "tool": "shm_bench" if "bridge" in metric else "bench",
-        "metric": metric, "value": base / 2, "unit": "GB/s",
+        "tool": "shm_bench", "metric": _METRIC, "value": base / 2,
+        "unit": "GB/s",
     }) + "\n")
     proc = subprocess.run(
-        [sys.executable, _GATE, "--candidate", str(cand)],
+        [sys.executable, _GATE, "--log", log, "--candidate", str(cand)],
         capture_output=True, text=True, cwd=_REPO,
     )
     assert proc.returncode == 1
-    assert metric in proc.stderr  # the offending metric is named
+    assert _METRIC in proc.stderr  # the offending metric is named
     assert "REGRESSION" in proc.stdout
 
 
 def test_candidate_within_threshold_passes(tmp_path):
     gate = _load_gate()
-    history = gate._read_jsonl(os.path.join(_REPO, "BENCH_LOG.jsonl"))
-    baselines = gate.build_baselines(history)
-    metric, base = next(iter(sorted(baselines.items())))
+    log = _trajectory(tmp_path)
+    base = gate.build_baselines(gate._read_jsonl(log))[_METRIC]
     cand = tmp_path / "cand.jsonl"
     cand.write_text(json.dumps({
-        "tool": "shm_bench", "metric": metric, "value": base * 0.9,
+        "tool": "shm_bench", "metric": _METRIC, "value": base * 0.9,
         "unit": "GB/s",
     }) + "\n")
     proc = subprocess.run(
-        [sys.executable, _GATE, "--candidate", str(cand), "--json"],
+        [sys.executable, _GATE, "--log", log, "--candidate", str(cand),
+         "--json"],
         capture_output=True, text=True, cwd=_REPO,
     )
     assert proc.returncode == 0, proc.stdout
@@ -124,8 +137,7 @@ def test_published_floor_wins_over_history():
 
 # ---------------------------------------------------------------------------
 # CPU-placeholder separation (ISSUE 6 satellite): rows that ran on the CPU
-# stand-in during the flaky-transport rounds (BENCH_r05's
-# device_init_failure incident) must form their own trajectory and never
+# stand-in while no chip answered must form their own trajectory and never
 # dilute — or be judged against — chip truth.
 # ---------------------------------------------------------------------------
 

@@ -247,3 +247,20 @@ def test_fuzz_three_way_byte_identity():
         if native.available():
             d_nat = native.dequantize_f32(p_nat, m_nat, bits, bucket, n)
             np.testing.assert_array_equal(d_np, d_nat, err_msg=str(ctx))
+
+
+def test_native_build_is_keyed_on_source_and_flags(monkeypatch):
+    """The .so lives in the checkout's cache dir under a name derived from
+    the source bytes and the flags — never beside the source, where a
+    library built on another machine would ride along in a copied tree."""
+    from torch_cgx_tpu.runtime import native
+    from torch_cgx_tpu.utils import entry
+
+    path = native._lib_path()
+    assert path.parent == entry.cache_root() / "native"
+    assert path.parent != native._SRC.parent.parent
+    assert "-march=native" not in native._FLAGS
+    monkeypatch.setattr(native, "_FLAGS", native._FLAGS + ("-DX=1",))
+    assert native._lib_path() != path
+    st = native.status()
+    assert st["host_codec"] in ("native", "numpy") and len(st) == 2

@@ -845,3 +845,74 @@ def test_int8_accum_constant_buckets_exact(monkeypatch):
     monkeypatch.setenv("CGX_SRA_ACCUM", "int8")
     red = codec_pallas.reduce_rows_batch(q, interpret=True)
     np.testing.assert_allclose(np.asarray(red), ws * 1.5, rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Tile alignment Mosaic enforces (found compiling for a v5e, PR 21).
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "bits,bucket,n_chunks",
+    [(3, 4096, 40), (4, 16384, 6), (5, 2048, 40), (4, 512, 2355), (1, 8192, 9)],
+)
+def test_chunk_tile_is_sublane_aligned(bits, bucket, n_chunks, monkeypatch):
+    """A multi-block chunk-kernel tile makes the (tc*bits, bucket) word
+    block a whole number of 8-sublane tiles — for the heuristic and for a
+    forced tile alike (libtpu 0.0.34 refuses anything else)."""
+    for forced in (None, "3"):
+        if forced:
+            monkeypatch.setenv("CGX_PALLAS_TILE_CHUNKS", forced)
+        tc = codec_pallas._chunks_tc(n_chunks, bucket, bits)
+        assert 1 <= tc <= n_chunks
+        assert tc == n_chunks or (tc * bits) % 8 == 0, (tc, bits)
+
+
+def test_supports_refuses_geometry_without_a_legal_tile():
+    # 3 bits at bucket 16384: the aligned quantum is 8 chunks = 16 MB of
+    # f32 per block — no kernel path for rows that need the chunk kernels.
+    n = (6 * 32 + 5) * 16384
+    assert not codec_pallas.supports(n, 3, 16384, False)
+    assert codec_pallas.supports(n, 4, 16384, False)  # quantum 2 fits
+    # whole-chunk rows of 128-lane buckets take the flat kernels: fine
+    assert codec_pallas.supports(6 * 32 * 16384, 3, 16384, False)
+    # rows below one chunk never reach a chunk kernel
+    assert codec_pallas.supports(5 * 16384, 3, 16384, False)
+
+
+def test_on_tpu_does_not_swallow_a_dead_backend(monkeypatch):
+    """A backend that fails to come up must fail the caller, not answer
+    "not a TPU" (which selects the interpret kernels and the XLA codec)."""
+
+    def dead():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", dead)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        dispatch._on_tpu()
+
+
+def test_lowering_ledger_names_what_ran():
+    """Trace-time ledger (nothing is compiled or run here)."""
+    from torch_cgx_tpu.utils.logging import metrics
+
+    metrics.reset()
+    cc = CompressionConfig(bits=4, bucket_size=512)
+
+    def row(buckets):
+        return jax.ShapeDtypeStruct((1, buckets * 512), jnp.float32)
+
+    jax.eval_shape(
+        lambda x: dispatch.dequantize_batch(dispatch.quantize_batch(x, cc)),
+        row(40),
+    )
+    assert metrics.get("cgx.codec.lowering.quantize.xla") == 1  # cpu: auto
+    assert metrics.get("cgx.codec.lowering.dequantize.xla") == 1
+    pallas = lambda x: codec_pallas.quantize_batch(x, 4, 512, interpret=True)
+    jax.eval_shape(pallas, row(40))
+    assert metrics.get("cgx.codec.lowering.quantize.pallas_chunks") == 1
+    # the 16-token GPT-2 KV page: no whole chunk, the XLA tail does it all
+    jax.eval_shape(pallas, row(24))
+    assert metrics.get("cgx.codec.lowering.quantize.xla_tail") == 1
+    jax.eval_shape(pallas, row(64))
+    assert metrics.get("cgx.codec.lowering.quantize.pallas_flat") == 1

@@ -290,10 +290,10 @@ def test_stash_epoch_and_claim():
 
 @pytest.mark.tpu  # compiled Mosaic lowering of the producer kernel
 def test_kernel_bytes_match_compose_tpu():
-    """Hardware validation of `_matmul_quantize_impl` (the hw_session runs
-    `pytest -m tpu`): compiled-kernel wire bytes vs the compose reference
-    on the real chip — envelope on decode (matmul association may differ
-    between the MXU grid and XLA's lowering), bit-equal when it doesn't."""
+    """Hardware validation of `_matmul_quantize_impl` (`CGX_TEST_TPU=1
+    pytest -m tpu` on the chip): compiled-kernel wire bytes vs the compose
+    reference — envelope on decode (matmul association may differ between
+    the MXU grid and XLA's lowering), bit-equal when it doesn't."""
     cc = CompressionConfig(bits=4, bucket_size=512)
     K, din, o, ws = 256, 1024, 1024, 4
     rng = np.random.default_rng(5)
@@ -313,3 +313,16 @@ def test_kernel_bytes_match_compose_tpu():
     d_r = np.asarray(dispatch.dequantize_batch(q_ref))
     unit = np.abs(np.asarray(dw)).max() / ((1 << cc.bits) - 1)
     assert np.max(np.abs(d_k - d_r)) <= 2 * unit + 1e-6
+
+
+def test_kernel_geometry_refuses_blocks_mosaic_refuses():
+    """The (tk, tm) block of x needs tm % 128 == 0 (or all of din): GPT-2's
+    qkv / mlp_in over 4 ranks give tm=64 and must take the compose path
+    (libtpu 0.0.34 refuses the block); mlp_out aligns."""
+    cc = CompressionConfig(bits=4, bucket_size=512)
+    assert fp._kernel_geometry(4096, 768, 2304, 4, 768 * 2304 // 4, cc) is None
+    assert fp._kernel_geometry(4096, 768, 3072, 4, 768 * 3072 // 4, cc) is None
+    tm, tk = fp._kernel_geometry(4096, 3072, 768, 4, 3072 * 768 // 4, cc)
+    assert tm % 128 == 0 and tk % 16 == 0
+    # an odd contraction length is one whole block, never a 1-row block
+    assert fp._kernel_geometry(37, 1024, 1024, 4, 1024 * 1024 // 4, cc)[1] == 37

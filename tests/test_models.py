@@ -233,24 +233,6 @@ def test_vit_hierarchical_compressed_training(monkeypatch):
         np.testing.assert_array_equal(s, shards[0])
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason=(
-        "container limitation (jax pinned at 0.4.x): partial-auto "
-        "shard_map — manual over dp, GSPMD over tp — cannot run the "
-        "quantized reducers on this runtime. Root cause, reproduced "
-        "minimally: (a) lax.axis_index of a manual axis lowers to a bare "
-        "PartitionId instruction, which the SPMD partitioner rejects "
-        "('PartitionId instruction is not supported for SPMD "
-        "partitioning'); (b) even with axis_index routed around, the "
-        "SRA/Ring collectives (all_to_all, ppermute) inside the "
-        "partial-auto region hit a FATAL XLA check "
-        "(hlo_sharding_util.cc IsManualSubgroup) and abort the process. "
-        "Both are fixed in the modern jax.shard_map lowering this "
-        "codebase targets (utils/compat.py); the test runs wherever "
-        "jax.shard_map exists."
-    ),
-)
 def test_tp_sharding_survives_train_step(monkeypatch):
     """make_train_step leaves non-sync mesh axes to GSPMD: tensor-parallel
     parameter shardings must SURVIVE the step (review r3: in_specs=P() on a

@@ -267,6 +267,31 @@ def test_decide_slice_respects_engagement(monkeypatch):
     assert dec is not None and dec.chunks >= 2
 
 
+def test_auto_on_tpu_needs_a_calibrated_model(monkeypatch):
+    """auto never plans from the built-in host-bridge constants: on a TPU
+    with the default model the static schedule runs and is counted."""
+    from torch_cgx_tpu.utils.logging import metrics
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    metrics.reset()
+    default = planner.CostModel.default()
+    assert not planner.cost_model().calibrated
+    assert not planner.engaged()
+    assert metrics.get("cgx.plan.uncalibrated_static") == 1
+    try:
+        # provenance alone, or the step clock alone, calibrates nothing
+        planner.set_cost_model(dataclasses.replace(
+            default, source="default+step_p50", compute_s=0.05
+        ))
+        assert not planner.engaged()
+        planner.set_cost_model(dataclasses.replace(
+            default, quantize_gbps=250.0, wire_gbps=90.0, source="file"
+        ))
+        assert planner.engaged()
+    finally:
+        planner.set_cost_model(None)
+
+
 def test_backend_bridge_mirror_matches_planner(monkeypatch):
     """The bridge keeps a dependency-light duplicate of the DEFAULT-model
     depth argmin (``backend._plan_bridge_chunks`` — a pure-bridge rank

@@ -246,10 +246,10 @@ def test_ring_scan_program_size_constant_in_ws():
 
 
 def _pallas_kernel_counts(jaxpr):
-    """kernel-function-name -> pallas_call count, walking nested jaxprs.
-    Kernel closures in codec_pallas.py carry distinctive names
-    (_quantize_flat_kernel, _sra_epilogue_kernel, ...) precisely so this
-    guard can count codec invocations by identity."""
+    """kernel name -> pallas_call count, walking nested jaxprs. Every
+    pallas_call in ops/ passes ``name=`` (cgx_quantize_flat,
+    cgx_sra_epilogue, ...) precisely so this guard can count codec
+    invocations by identity."""
     from collections import Counter
 
     counts = Counter()
@@ -257,8 +257,7 @@ def _pallas_kernel_counts(jaxpr):
     def walk(jx):
         for eqn in jx.eqns:
             if eqn.primitive.name == "pallas_call":
-                info = str(eqn.params.get("name_and_src_info", ""))
-                counts[info.split(" ")[0]] += 1
+                counts[eqn.params["name"]] += 1
             for v in eqn.params.values():
                 for item in v if isinstance(v, (list, tuple)) else [v]:
                     if isinstance(item, jax.extend.core.ClosedJaxpr):
@@ -297,11 +296,11 @@ def test_sra_codec_invocation_guard(monkeypatch):
     counts = _pallas_kernel_counts(
         jax.make_jaxpr(body)(jnp.zeros((ws, n), jnp.float32)).jaxpr
     )
-    assert counts.get("_quantize_flat_kernel", 0) == 1, counts
-    assert counts.get("_sra_epilogue_kernel", 0) == 1, counts
+    assert counts.get("cgx_quantize_flat", 0) == 1, counts
+    assert counts.get("cgx_sra_epilogue", 0) == 1, counts
     # allgather decode only; the peer-row decode lives inside the epilogue
-    assert counts.get("_dequantize_flat_kernel", 0) == 1, counts
-    assert counts.get("_reduce_rows_kernel", 0) == 0, counts
+    assert counts.get("cgx_dequantize_flat", 0) == 1, counts
+    assert counts.get("cgx_reduce_rows", 0) == 0, counts
     # nothing else codec-shaped hides elsewhere in the program
     assert sum(counts.values()) == 3, counts
 

@@ -352,13 +352,12 @@ def test_staged_program_one_kernel_pair_per_shard(monkeypatch):
 
     def visit(eqn):
         if eqn.primitive.name == "pallas_call":
-            info = str(eqn.params.get("name_and_src_info", ""))
-            counts[info.split(" ")[0]] += 1
+            counts[eqn.params["name"]] += 1
 
     _walk_jaxpr(_staged_jaxpr(ws, n, cc), visit)
-    assert counts.get("_quantize_flat_kernel", 0) == 1, counts
-    assert counts.get("_sra_epilogue_kernel", 0) == 1, counts
-    assert counts.get("_dequantize_flat_kernel", 0) == 1, counts
+    assert counts.get("cgx_quantize_flat", 0) == 1, counts
+    assert counts.get("cgx_sra_epilogue", 0) == 1, counts
+    assert counts.get("cgx_dequantize_flat", 0) == 1, counts
     assert sum(counts.values()) == 3, counts
 
 

@@ -16,8 +16,8 @@ gate makes it mechanical:
   Rows carry ``backend``/``chip`` tags (bench.py's ``log_jsonl`` fills
   them from the live backend; host-side tools tag ``backend: "host"``).
   A device bench that ran on the **CPU stand-in** (``backend``/``chip``
-  == ``"cpu"`` — the flaky-transport rounds, BENCH_r05's
-  ``device_init_failure`` incident) is keyed into its own ``<metric>@cpu``
+  == ``"cpu"`` — a round in which no chip answered) is keyed into its own
+  ``<metric>@cpu``
   trajectory: placeholder rows never mix into the chip-truth median,
   never meet a published floor, and ``--smoke`` skips their
   placeholder-only trajectories entirely.
@@ -86,8 +86,8 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _EXCLUDED_METRICS = {"device_init_failure", "lint_failure"}
 
 # CPU-placeholder suffix: device benches that ran on the CPU fallback
-# (the flaky-transport rounds — BENCH_r05's device_init_failure
-# escalation) form their OWN trajectory under this suffix, so a
+# (rounds in which no chip answered) form their OWN trajectory under
+# this suffix, so a
 # placeholder row can never dilute the chip-truth baseline (or be
 # compared against a published floor measured on silicon).
 _PLACEHOLDER_SUFFIX = "@cpu"

@@ -11,9 +11,8 @@ output incremental):
     python tools/qbench.py read           # HBM read floor (max-reduce only)
     python tools/qbench.py dequant        # public dequantize_batch
 
-All operands are generated on-device (host->device transfer of benchmark
-payloads has wedged the device transport under load before) and sized to
-128 MB by default. Timing is the same scan-slope method as bench.py.
+All operands are generated on-device (no host->device copy of benchmark
+payloads) and sized to 128 MB by default. Timing is the same scan-slope method as bench.py.
 Experimental kernels are byte-checked against the XLA codec oracle on a
 small slice before timing — a variant that changes the wire is reported,
 not silently timed.
@@ -34,9 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from bench import scan_time  # noqa: E402 — single source of timing truth
-
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_bench_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
 
 CB = 32  # chunk buckets (codec.CHUNK_BUCKETS)
 
@@ -167,6 +163,9 @@ def run_variant_kernel(name, xs, bits, b, tc, interpret: bool = False):
 
 
 def main():
+    from torch_cgx_tpu.utils import entry
+
+    entry.setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("variant", choices=[
         "current", "butterfly", "mul", "nometa", "metalane", "read", "dequant",

@@ -451,7 +451,11 @@ def planner_mode() -> str:
     (pipeline depth, bit-width, emission order) against a trace-
     calibrated cost model:
 
-    * "auto" (default) — plan only on a real TPU backend; on every
+    * "auto" (default) — plan only on a real TPU backend and only from a
+      calibrated cost model (``CGX_PLANNER_MODEL`` or an in-process
+      adoption; the built-in default rates were never measured on a TPU,
+      so without one the static ``CGX_SCHED_CHUNKS`` schedule runs and
+      ``cgx.plan.uncalibrated_static`` counts it); on every
       CPU/CI path no plan is derived and the staged programs, store keys
       and wire bytes are bit-identical to the pre-planner code
       (jaxpr-pinned in tests/test_planner.py).

@@ -138,7 +138,7 @@ def sp_lm_loss(logits, tokens, axis_name: str):
     single-column ``ppermute`` — and only the global final position has no
     target. Returns the global mean (identical to :func:`lm_loss` on the
     unsharded sequence), replicated across the axis."""
-    ws = jax.lax.psum(1, axis_name)
+    ws = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     # dst i receives from src i+1: the right neighbor's first column.
     perm = [((i + 1) % ws, i) for i in range(ws)]

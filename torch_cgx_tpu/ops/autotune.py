@@ -86,13 +86,9 @@ def _chip_slug() -> str:
     """Filesystem-safe chip identity: ``<backend>-<device_kind>``. A plan
     measured on one chip generation must never serve another (the
     schedule-LRU ``_chip_fingerprint`` contract)."""
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        raw = f"{jax.default_backend()}-{getattr(dev, 'device_kind', 'unknown')}"
-    except Exception:
-        raw = "none"
+    raw = f"{jax.default_backend()}-{jax.devices()[0].device_kind}"
     return re.sub(r"[^A-Za-z0-9_.-]+", "_", raw)
 
 

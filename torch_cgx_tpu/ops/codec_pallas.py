@@ -66,6 +66,7 @@ final-bucket tail is sliced off outside the kernel (compressor.cc:315-339).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -77,6 +78,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import autotune, codec
 from .. import config as cfg_mod
 from ..utils import env as _env
+from ..utils.logging import metrics
 
 LANE_GROUP = codec.LANE_GROUP  # 32
 CHUNK_BUCKETS = codec.CHUNK_BUCKETS  # 32 buckets per sublane-packed chunk
@@ -97,18 +99,33 @@ def _use_db(tuned: "autotune.TunedConfig | None") -> bool:
     return bool(tuned is not None and tuned.db)
 
 
+def note_lowering(site: str, lowering: str) -> None:
+    """Trace-time record of which lowering one codec call site resolved
+    to: ``cgx.codec.lowering.<site>.<lowering>`` counts call sites per
+    traced program (never executions), so an entry script can print what
+    actually engaged instead of what the mode knobs promise."""
+    metrics.add(f"cgx.codec.lowering.{site}.{lowering}")
+
+
 def supports(n: int, bits: int, bucket_size: int, skip_incomplete: bool) -> bool:
     # skip_incomplete_buckets (the reference's residual mode,
     # compressor.cc:315-339) keeps the fast path: the incomplete final
     # bucket is sliced off before the kernels and carried raw (see
     # quantize_batch), so only the whole-bucket prefix length matters.
     main_n = n - (n % bucket_size) if skip_incomplete else n
-    return (
+    if not (
         1 <= bits <= 8
         and bucket_size % LANE_GROUP == 0
         and bucket_size <= MAX_BUCKET_ELEMS
         and main_n >= bucket_size  # tiny tensors: XLA path beats a grid
-    )
+    ):
+        return False
+    # Rows that are whole chunks of 128-lane buckets take the flat kernels;
+    # every other row with a full chunk needs a chunk-block tile Mosaic
+    # accepts (see _chunks_tc) — no tile, no kernel path.
+    nb = codec.num_buckets(main_n, bucket_size)
+    flat = nb % CHUNK_BUCKETS == 0 and bucket_size % 128 == 0
+    return flat or nb < CHUNK_BUCKETS or _chunk_tile_fits(bits, bucket_size)
 
 
 def _forced_tile_chunks() -> Optional[int]:
@@ -150,6 +167,41 @@ def _tile_chunks(
     if tuned is not None:
         return int(max(1, min(tuned.tc, cap, max(1, n_chunks))))
     return int(min(16, cap, max(1, n_chunks)))
+
+
+def _chunk_quantum(bits: int) -> int:
+    """Chunks per block that make the chunk-block kernels' 2-D
+    ``(tc*bits, bucket)`` word block a whole number of 8-sublane tiles."""
+    return 8 // math.gcd(bits, 8)
+
+
+def _chunk_tile_fits(bits: int, bucket_size: int) -> bool:
+    """Whether the smallest aligned chunk-block tile stays within twice
+    the VMEM block budget ``_tile_chunks`` sizes against (``supports``
+    refuses the kernel path otherwise — e.g. 3 bits at bucket 8192)."""
+    return _chunk_quantum(bits) * CHUNK_BUCKETS * bucket_size <= (1 << 20)
+
+
+def _chunks_tc(
+    n_chunks: int,
+    bucket_size: int,
+    bits: int,
+    tuned: "autotune.TunedConfig | None" = None,
+) -> int:
+    """Tile of the chunk-block kernels. Mosaic refuses a word block whose
+    sublane extent is neither a multiple of 8 nor the whole array
+    (libtpu 0.0.34: bits=3/bucket=4096, bits=4/bucket=16384 and a forced
+    ``tc=3`` all fail to lower). ``_tile_chunks`` knows nothing of that —
+    at bucket 512 it holds only because its cap is 16 — so every tier's
+    answer (heuristic, autotuned, forced) is rounded down here to the
+    :func:`_chunk_quantum` unless one block spans the array. Callers
+    gate on :func:`supports`, which refuses geometries whose quantum
+    does not fit VMEM."""
+    tc = _tile_chunks(n_chunks, bucket_size, bits, tuned)
+    if tc >= n_chunks:
+        return n_chunks
+    quantum = _chunk_quantum(bits)
+    return min(max(quantum, tc // quantum * quantum), n_chunks)
 
 
 def _encode_strategy() -> str:
@@ -344,8 +396,9 @@ def _quantize_flat_impl(
     rb = b // 128
     n_chunks = rows * m_pad // (CHUNK_BUCKETS * b)
 
-    # Named (not a generic `kernel`) so jaxpr-level guards can count codec
-    # invocations by kernel identity (test_reducers codec-invocation guard).
+    # Every pallas_call in ops/ passes ``name=``: jaxpr-level guards count
+    # codec invocations by it (test_reducers codec-invocation guard) and a
+    # device trace shows the kernel under it.
     # The block math lives in _requantize_block — shared with the fused
     # SRA epilogue's requantize and the DB lowering, so the wire contract
     # cannot drift between them. (The rb sublane-group axis reduces FIRST
@@ -361,6 +414,7 @@ def _quantize_flat_impl(
     xv = xs.reshape(rows * m_pad // 128, 128)
     words, meta = pl.pallas_call(
         _quantize_flat_kernel,
+        name="cgx_quantize_flat",
         grid=(n_chunks // tc,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -454,6 +508,7 @@ def _dequantize_flat_impl(
         )
     out = pl.pallas_call(
         _dequantize_flat_kernel,
+        name="cgx_dequantize_flat",
         grid=(n_chunks // tc,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((s_rows, 128), lambda i: (i, 0),
@@ -595,13 +650,14 @@ def _quantize_flat_db_impl(
     xv = xs.reshape(rows * m_pad // 128, 128)
     return pl.pallas_call(
         _quantize_flat_db_kernel,
+        name="cgx_quantize_flat_db",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_chunks * bits * rb, 128), jnp.int32),
@@ -732,19 +788,20 @@ def _dequantize_flat_db_impl(
     wv = words.reshape(rows * w_row // 128, 128)
     mv = meta.reshape(rows * nb_r, 2)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [wv, mv]
     if with_add:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         operands.append(
             add_to.astype(jnp.float32).reshape(rows * nb_r * b // 128, 128)
         )
     out = pl.pallas_call(
         _dequantize_flat_db_kernel,
+        name="cgx_dequantize_flat_db",
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(
             (n_chunks * CHUNK_BUCKETS * rb, 128), jnp.float32
         ),
@@ -785,6 +842,7 @@ def _quantize_chunks_impl(
             _quantize_kernel, bits=bits, tc=tc, stochastic=stochastic,
             pack=pack, encode=encode,
         ),
+        name="cgx_quantize_chunks",
         grid=(cp // tc,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -832,6 +890,7 @@ def _dequantize_chunks_impl(
 
     out = pl.pallas_call(
         functools.partial(_dequantize_kernel, bits=bits, tc=tc),
+        name="cgx_dequantize_chunks",
         grid=(cp // tc,),
         in_specs=[
             pl.BlockSpec((tc * bits, b), lambda i: (i, 0),
@@ -904,9 +963,9 @@ def quantize_batch(
         tuned = autotune.lookup(
             autotune.KIND_FLAT, n_chunks=rows * c_r, bucket_size=b, bits=bits
         )
-        impl = (
-            _quantize_flat_db_impl if _use_db(tuned) else _quantize_flat_impl
-        )
+        db = _use_db(tuned)
+        note_lowering("quantize", "pallas_flat_db" if db else "pallas_flat")
+        impl = _quantize_flat_db_impl if db else _quantize_flat_impl
         words, meta = impl(
             xs,
             seed_from_key(key),
@@ -932,6 +991,7 @@ def quantize_batch(
     xb = xs.reshape(rows, nb_r, b).astype(jnp.float32)
 
     word_parts, meta_parts = [], []
+    note_lowering("quantize", "pallas_chunks" if c_r else "xla_tail")
     if c_r:
         head = xb[:, : c_r * CHUNK_BUCKETS].reshape(-1, b)
         tuned = autotune.lookup(
@@ -945,7 +1005,7 @@ def quantize_batch(
             bucket_size=b,
             stochastic=stochastic,
             interpret=interpret,
-            tc=_tile_chunks(rows * c_r, b, bits, tuned),
+            tc=_chunks_tc(rows * c_r, b, bits, tuned),
             pack=_pack_strategy(tuned),
             encode=_encode_strategy(),
         )
@@ -1024,11 +1084,11 @@ def dequantize_batch(
             autotune.KIND_FLAT, n_chunks=rows * c_r, bucket_size=b,
             bits=q.bits,
         )
-        impl = (
-            _dequantize_flat_db_impl
-            if _use_db(tuned)
-            else _dequantize_flat_impl
+        db = _use_db(tuned)
+        note_lowering(
+            "dequantize", "pallas_flat_db" if db else "pallas_flat"
         )
+        impl = _dequantize_flat_db_impl if db else _dequantize_flat_impl
         vals = impl(
             jax.lax.bitcast_convert_type(q.packed, jnp.int32),
             meta,
@@ -1043,6 +1103,7 @@ def dequantize_batch(
             return vals.astype(out_dtype)
     else:
         parts = []
+        note_lowering("dequantize", "pallas_chunks" if c_r else "xla_tail")
         head_words = c_r * q.bits * b
         if c_r:
             w3 = q.packed[:, :head_words].reshape(rows * c_r * q.bits, b)
@@ -1053,7 +1114,7 @@ def dequantize_batch(
                 bits=q.bits,
                 bucket_size=b,
                 interpret=interpret,
-                tc=_tile_chunks(
+                tc=_chunks_tc(
                     rows * c_r, b, q.bits,
                     autotune.lookup(
                         autotune.KIND_CHUNKS, n_chunks=rows * c_r,
@@ -1354,6 +1415,7 @@ def _reduce_rows_impl(
         operands.append(raw.reshape(nb_r * b // 128, 128))
     out = pl.pallas_call(
         _reduce_rows_kernel,
+        name="cgx_reduce_rows",
         grid=(c_r // tc,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tc * CHUNK_BUCKETS * rb, 128),
@@ -1446,6 +1508,7 @@ def _sra_epilogue_impl(
         operands.append(raw.reshape(nb_r * b // 128, 128))
     words_out, meta_out = pl.pallas_call(
         _sra_epilogue_kernel,
+        name="cgx_sra_epilogue",
         grid=(c_r // tc,),
         in_specs=in_specs,
         out_specs=[
@@ -1611,8 +1674,8 @@ def _sra_epilogue_db_impl(
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [
         seed.reshape(1, 1).astype(jnp.int32),
@@ -1621,14 +1684,15 @@ def _sra_epilogue_db_impl(
         meta.reshape(ws, nb_r, 2),
     ]
     if with_raw:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         operands.append(raw.reshape(nb_r * b // 128, 128))
     return pl.pallas_call(
         _sra_epilogue_db_kernel,
+        name="cgx_sra_epilogue_db",
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((c_r * bits * rb, 128), jnp.int32),
@@ -1658,6 +1722,7 @@ def reduce_rows_batch(
         autotune.KIND_EPILOGUE, n_chunks=nb_r // CHUNK_BUCKETS,
         bucket_size=q.bucket_size, bits=q.bits, ws=ws,
     )
+    note_lowering("reduce_rows", "pallas_fused")
     return _reduce_rows_impl(
         words,
         meta,
@@ -1704,7 +1769,16 @@ def sra_epilogue_batch(
             bucket_size=q.bucket_size, bits=q.bits, ws=ws,
         )
     )
-    impl = _sra_epilogue_db_impl if _use_db(tuned) else _sra_epilogue_impl
+    # The double-buffered epilogue is not selectable from an autotune
+    # entry: Mosaic (libtpu 0.0.34) refuses its 2-lane meta DMA slice
+    # ("Slice shape along dimension 2 must be aligned to tiling (128)"),
+    # so only an explicit CGX_PALLAS_DB=on reaches it — and fails loudly
+    # on a TPU until ROADMAP C1 decides its fate.
+    db = cfg_mod.pallas_db() == "on"
+    note_lowering(
+        "sra_epilogue", "pallas_fused_db" if db else "pallas_fused"
+    )
+    impl = _sra_epilogue_db_impl if db else _sra_epilogue_impl
     words_out, meta_out = impl(
         words,
         meta,

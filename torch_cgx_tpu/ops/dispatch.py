@@ -20,10 +20,10 @@ from . import codec, codec_pallas
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    """Whether the default backend is a TPU. A backend that fails to come
+    up raises here: answering False would silently select the interpret
+    kernels and the XLA codec — the CPU program — on a TPU host."""
+    return jax.default_backend() == "tpu"
 
 
 def _pick(n: int, cc: CompressionConfig) -> str:
@@ -54,6 +54,7 @@ def quantize_batch(
             interpret=not _on_tpu(),
             skip_incomplete_buckets=cc.skip_incomplete_buckets,
         )
+    codec_pallas.note_lowering("quantize", "xla")
     if stochastic:
         keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
             jnp.arange(xs.shape[0])
@@ -91,6 +92,7 @@ def dequantize_batch(
         return codec_pallas.dequantize_batch(
             q, add_to=add_to, out_dtype=out_dtype, interpret=not _on_tpu()
         )
+    codec_pallas.note_lowering("dequantize", "xla")
     if add_to is not None:
         return jax.vmap(
             lambda qq, acc: codec.dequantize(qq, add_to=acc, out_dtype=out_dtype)
@@ -229,6 +231,7 @@ def reduce_rows(
             q, raw_row=rr, own_idx=own_idx, interpret=not _on_tpu()
         ).astype(out_dtype)
     # Staged reference path (also the fused kernels' byte oracle).
+    codec_pallas.note_lowering("reduce_rows", "staged")
     if rows == 1 and not have_raw:
         return dequantize_batch(
             q,
@@ -281,6 +284,7 @@ def reduce_rows_requantize(
             out_dtype=out_dtype,
             interpret=not _on_tpu(),
         )
+    codec_pallas.note_lowering("sra_epilogue", "staged")
     reduced = reduce_rows(
         q, raw_rows=raw_rows, raw_row=raw_row, own_idx=own_idx,
         out_dtype=jnp.float32,

@@ -469,7 +469,12 @@ def _kernel_geometry(
     """(tm, tk) grid tiling for the fused kernel, or None when the shapes
     don't align: output row-blocks must cover whole 32-bucket chunks of
     the flat layout, nest inside the (ws, chunk) wire rows, and leave a
-    VMEM-sized accumulator; the contraction dim splits evenly."""
+    VMEM-sized accumulator; the contraction dim splits evenly; and the
+    ``(tk, tm)`` block of ``x`` must be one Mosaic accepts — ``tm`` a
+    multiple of 128 lanes (or all of ``din``), ``tk`` a multiple of 16
+    sublanes (or all of ``k_total``). GPT-2's qkv / mlp_in kernels
+    (din=768 over 4 ranks -> tm=64) are refused by that rule and take
+    the compose path."""
     import math
 
     b = cc.bucket_size
@@ -489,13 +494,12 @@ def _kernel_geometry(
         tm *= 2
     if tm * o > _KERNEL_MAX_ACC_ELEMS:
         return None
-    tk = None
-    for cand in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if k_total % cand == 0:
-            tk = cand
-            break
-    if tk is None:
+    if tm % 128 and tm != din:
         return None
+    tk = next(
+        (c for c in (512, 256, 128, 64, 32, 16) if k_total % c == 0),
+        k_total,
+    )
     return tm, tk
 
 
@@ -590,6 +594,7 @@ def _matmul_quantize_impl(
 
     words, meta = pl.pallas_call(
         _matmul_quantize_kernel,
+        name="cgx_matmul_quantize",
         grid=(nm, nk),
         in_specs=[
             pl.BlockSpec((tk, tm), lambda m, k: (k, m),

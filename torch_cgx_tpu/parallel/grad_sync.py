@@ -951,8 +951,16 @@ def make_train_step(
         metrics.add("cgx.recovery.rollbacks")
         return snap.step, ckpt.restore_in_memory(snap)
 
+    def lower(*args):
+        """``jax.stages.Lowered`` of the jitted step for ``step``'s own
+        arguments: what an entry script reads to show what was staged
+        (Mosaic custom calls, collectives) — lowering only, nothing is
+        compiled, run or donated."""
+        return _build(args[-2]).lower(*args)
+
     step.last_snapshot = last_snapshot
     step.rollback = rollback
+    step.lower = lower
     return step
 
 

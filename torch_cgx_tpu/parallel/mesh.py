@@ -45,7 +45,7 @@ def init_distributed(
 
     # NOT jax.process_count(): that initializes the XLA backend, after which
     # jax.distributed.initialize() unconditionally raises.
-    from ..utils.compat import distributed_is_initialized, ensure_cpu_collectives
+    from ..utils.compat import distributed_is_initialized
 
     if distributed_is_initialized():
         return True
@@ -63,13 +63,6 @@ def init_distributed(
     )
     if coordinator_address is None and not on_pod:
         return False  # single host — nothing to bootstrap
-    # CPU-pinned multi-process runs (the CI harness, dev boxes) need the
-    # Gloo CPU collectives armed BEFORE the backend comes up — jax 0.4.x
-    # defaults them off and every cross-process collective then fails.
-    # Only HERE, behind the coordinator check: a gloo CPU client without a
-    # distributed runtime fails backend init outright, so a single-host
-    # process must never arm it.
-    ensure_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

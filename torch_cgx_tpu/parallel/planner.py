@@ -36,8 +36,11 @@ future perf lever becomes a cost-model change instead of a new
 subsystem.
 
 **Inertness contract** (the ``CGX_SCHEDULE``/``CGX_WIRE`` discipline):
-``CGX_PLANNER`` unset ("auto") engages only on a real TPU backend; on
-every CPU/CI path :func:`engaged` is False, no plan is derived, and
+``CGX_PLANNER`` unset ("auto") engages only on a real TPU backend with a
+calibrated cost model (the built-in defaults are host-bridge rates — an
+uncalibrated TPU run keeps the static schedule and bumps
+``cgx.plan.uncalibrated_static``); on every CPU/CI path
+:func:`engaged` is False, no plan is derived, and
 staged programs, store keys and wire bytes are bit-identical to the
 pre-planner code (jaxpr-pinned in tests/test_planner.py). ``on`` engages
 anywhere (the CPU test/bench configuration — and the only mode the
@@ -135,6 +138,18 @@ class CostModel:
     @classmethod
     def default(cls) -> "CostModel":
         return cls()
+
+    @property
+    def calibrated(self) -> bool:
+        """Whether the two rates a depth decision weighs against each
+        other — codec and wire — both come from measurement rather than
+        from the built-in constants (host-bridge rates, never measured on
+        a TPU). ``source`` is provenance only: a model that learned just
+        its ``compute_s`` from the step clock is still uncalibrated."""
+        return (
+            self.quantize_gbps != CostModel.quantize_gbps
+            and self.wire_gbps != CostModel.wire_gbps
+        )
 
     @classmethod
     def from_spans(cls, directory: str) -> "CostModel":
@@ -650,17 +665,27 @@ def engaged(route_staged: bool = True) -> bool:
     """Whether the planner may decide for JAX-plane slices under the
     current mode/backend: "on" anywhere, "auto" only on a real TPU
     backend (inert on every CPU/CI path — the ``CGX_SCHEDULE`` gate
-    discipline), "off" never."""
+    discipline) AND only from a calibrated model, "off" never.
+
+    The built-in :class:`CostModel` defaults are host-bridge rates
+    (8/16/1 GB/s); they were never measured on any TPU, so under "auto"
+    they are no basis for a decision: an uncalibrated TPU run keeps the
+    static schedule (``CGX_SCHED_CHUNKS``) and counts that it did
+    (``cgx.plan.uncalibrated_static``). A :attr:`CostModel.calibrated`
+    model — installed through ``CGX_PLANNER_MODEL``, ``set_cost_model``
+    or :class:`StepPlanner` adoption — engages it."""
     del route_staged  # the topology router already picked the plane
     mode = cfg_mod.planner_mode()
     if mode == "off":
         return False
     if mode == "on":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
+    if jax.default_backend() != "tpu":
         return False
+    if not cost_model().calibrated:
+        metrics.add("cgx.plan.uncalibrated_static")
+        return False
+    return True
 
 
 def engaged_bridge() -> bool:
@@ -930,14 +955,6 @@ def note_membership(generation: int, world_size: int) -> None:
     metrics.add("cgx.plan.membership_replans")
 
 
-def _chip_fingerprint() -> str:
-    try:
-        dev = jax.devices()[0]
-        return f"{jax.default_backend()}/{getattr(dev, 'device_kind', '?')}"
-    except RuntimeError:
-        return "none"
-
-
 def _model_fingerprint(model: CostModel) -> Tuple:
     return dataclasses.astuple(model)
 
@@ -950,7 +967,7 @@ def _plan_key(group_sig, ws, route, reduction) -> Tuple:
         reduction,
         cfg_mod.planner_mode(),
         cfg_mod.planner_avg_bits(),
-        _chip_fingerprint(),
+        sched_mod._chip_fingerprint(),
         cfg_mod.registry_version(),
         _model_fingerprint(cost_model()),
         # The memory-envelope staging budget (ISSUE 18): active only

@@ -185,11 +185,7 @@ def invalidate_schedule_cache(reason: str = "reconfigure") -> None:
 def _chip_fingerprint() -> str:
     """The (backend, chip) component of the schedule key: a plan derived
     for one chip's crossover must not serve another's."""
-    try:
-        dev = jax.devices()[0]
-        return f"{jax.default_backend()}/{getattr(dev, 'device_kind', '?')}"
-    except RuntimeError:
-        return "none"
+    return f"{jax.default_backend()}/{jax.devices()[0].device_kind}"
 
 
 def cache_key_component() -> Tuple:
@@ -226,10 +222,7 @@ def _engaged(route_staged: bool) -> bool:
         return False
     if mode == "on":
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def engaged() -> bool:

@@ -4,55 +4,82 @@ The shared library is built on demand with ``g++`` (this image has no
 pybind11; the C ABI + ctypes replaces the reference's pybind11 module,
 /root/reference/setup.py). If no compiler is available the callers
 (:mod:`..ops.codec_host`, :mod:`.executor`) fall back to numpy/Python — the
-framework stays fully functional, just slower on the host staging path.
+framework stays fully functional, just slower on the host staging path;
+:func:`status` says which of the two is live.
+
+The build is keyed on what it is built FROM — the source bytes and the
+compiler flags — and lands in the checkout's git-ignored cache directory
+(``utils.entry.native_build_dir``), never beside the source: a library
+built on another machine cannot ride along in a copied tree and be
+reused for being newer than the source. The flags name no host CPU
+(no ``-march=native``), so every machine builds the same program.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sysconfig
 import threading
 from pathlib import Path
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..utils.entry import native_build_dir
+
 _SRC = Path(__file__).parent / "csrc" / "cgx_core.cpp"
+_FLAGS = (
+    "-O3", "-ffp-contract=off", "-shared", "-fPIC", "-std=c++17", "-pthread",
+)
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
+_WHY = "not tried yet"  # why numpy serves, once _load has run
 
 
-def _lib_path() -> Path:
+def _lib_path() -> Optional[Path]:
+    out_dir = native_build_dir()
+    if out_dir is None:
+        return None
+    key = hashlib.sha256(
+        _SRC.read_bytes() + "\0".join(_FLAGS).encode()
+    ).hexdigest()[:16]
     tag = sysconfig.get_config_var("SOABI") or "generic"
-    return Path(__file__).parent / f"_cgx_core.{tag}.so"
+    return out_dir / f"_cgx_core.{key}.{tag}.so"
 
 
 def build(force: bool = False) -> Optional[Path]:
-    """Compile the core with g++ -O3; returns the .so path or None."""
+    """Compile the core with g++; returns the .so path or None."""
+    global _WHY
     out = _lib_path()
-    if out.exists() and not force and out.stat().st_mtime >= _SRC.stat().st_mtime:
+    if out is None:
+        _WHY = "build directory not writable"
+        return None
+    if out.exists() and not force:
         return out
-    cmd = [
-        "g++", "-O3", "-march=native", "-ffp-contract=off", "-shared", "-fPIC", "-std=c++17",
-        "-pthread", str(_SRC), "-o", str(out),
-    ]
+    # Unique temporary + rename: concurrent ranks may build at once.
+    tmp = out.with_suffix(f".tmp{os.getpid()}")
+    cmd = ["g++", *_FLAGS, str(_SRC), "-o", str(tmp)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-    except (OSError, subprocess.SubprocessError):
+        os.replace(tmp, out)
+    except (OSError, subprocess.SubprocessError) as e:
+        _WHY = f"g++ build failed: {type(e).__name__}"
         return None
     return out
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _WHY
     with _LOCK:
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
         if os.environ.get("CGX_DISABLE_NATIVE", "0") == "1":
+            _WHY = "CGX_DISABLE_NATIVE=1"
             return None
         path = build()
         if path is None:
@@ -99,6 +126,15 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> Dict[str, str]:
+    """Which host codec is live: ``{"host_codec": "native", "lib": path}``
+    or ``{"host_codec": "numpy", "why": reason}`` (loads on first call)."""
+    lib = _load()
+    if lib is not None:
+        return {"host_codec": "native", "lib": lib._name}
+    return {"host_codec": "numpy", "why": _WHY}
 
 
 def _f32p(a: np.ndarray):

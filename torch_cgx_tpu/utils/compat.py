@@ -1,21 +1,20 @@
-"""JAX version compatibility shims.
+"""The JAX surface this codebase is written against, in one place.
 
-The codebase targets the modern surface (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.set_mesh``,
-``jax.distributed.is_initialized``); older runtimes (<= 0.4.x) expose the
-same machinery under ``jax.experimental.shard_map`` /
-``jax.sharding``-era names with different keyword spellings. Routing every
-call site through this module keeps the robustness/chaos suite runnable on
-both — a wedged-container debug session should not also be a jax-upgrade
-session.
+One installation is supported — jax 0.9.x as pinned in ``pyproject.toml``
+— so these are the public ``jax`` entry points themselves (``jax.shard_map``
+with ``axis_names``/``check_vma``, ``jax.set_mesh``,
+``jax.distributed.is_initialized``, ``jax.lax.axis_size``). Call sites keep
+importing from here so that the next JAX rename is a one-file change.
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Optional
 
 import jax
+
+set_mesh = jax.set_mesh
+axis_size = jax.lax.axis_size
 
 
 def shard_map(
@@ -26,101 +25,18 @@ def shard_map(
     out_specs,
     axis_names=None,
     check_vma: Optional[bool] = None,
-    check_rep: Optional[bool] = None,
 ):
-    """``jax.shard_map`` when available, else the
-    ``jax.experimental.shard_map`` spelling with keywords translated:
-    ``check_vma`` -> ``check_rep`` and ``axis_names`` -> the complementary
-    ``auto`` set (old shard_map names the *non*-manual axes)."""
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None or check_rep is not None:
-            kw["check_vma"] = check_vma if check_vma is not None else check_rep
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+    """``jax.shard_map`` with ``axis_names``/``check_vma`` passed only when
+    the caller set them (so JAX's own defaults stay JAX's)."""
     kw = {}
-    if check_vma is not None or check_rep is not None:
-        kw["check_rep"] = check_vma if check_vma is not None else check_rep
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(
+        kw["axis_names"] = axis_names
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
     )
 
 
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.set_mesh`` / ``jax.sharding.use_mesh`` when present, else the
-    legacy ``with mesh:`` context (old global-mesh semantics)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return contextlib.nullcontext(mesh) if mesh is None else mesh
-
-
-def ensure_cpu_collectives() -> None:
-    """Arm cross-process collectives for CPU-backend multi-process runs.
-
-    jaxlib ships a Gloo CPU-collectives implementation, but jax 0.4.x
-    defaults the ``jax_cpu_collectives_implementation`` flag to none — a
-    multi-process CPU program then fails every collective with
-    "Multiprocess computations aren't implemented on the CPU backend"
-    (newer jax defaults to gloo). Called only when a distributed runtime
-    is about to initialize (``mesh.init_distributed`` behind its
-    coordinator check — gloo needs the distributed client; arming it on a
-    single-host process fails CPU backend init outright). A no-op when
-    the platform is explicitly pinned away from CPU, when the flag is
-    already set (an explicit mpi/gloo choice is respected), or on
-    runtimes without the flag (initialize() surfaces the gap there).
-    An UNSET platform still arms it: jax may auto-select the CPU backend
-    (CPU-only hosts), and on accelerator pods the secondary CPU client
-    takes gloo harmlessly once the distributed client exists."""
-    import os
-
-    plats = str(
-        getattr(jax.config, "jax_platforms", None)
-        or os.environ.get("JAX_PLATFORMS")
-        or ""
-    ).lower()
-    if plats and "cpu" not in plats:
-        return
-    try:
-        from jax._src import xla_bridge as _xb
-
-        flag = getattr(_xb, "CPU_COLLECTIVES_IMPLEMENTATION", None)
-        if flag is not None and flag.value in (None, "none"):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
-
-
 def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized`` with a state-probe fallback for
-    runtimes that predate the accessor."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:
-        return False
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (static mapped-axis extent inside shard_map)
-    with the classic ``psum(1, axis)`` constant-fold fallback for runtimes
-    that predate the accessor."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return bool(jax.distributed.is_initialized())
