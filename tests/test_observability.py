@@ -118,15 +118,17 @@ def test_trace_span_records_duration_on_raise():
         with trace_span("failing_op"):
             time.sleep(0.01)
             raise RuntimeError("boom")
-    assert metrics.get("span.failing_op.count") == 1.0
-    assert metrics.get("span.failing_op.seconds") >= 0.01
+    # ONE histogram holds count and seconds; the errors counter is kept.
+    stats = metrics.histogram_stats("cgx.failing_op_s")
+    assert stats["count"] == 1 and stats["sum"] >= 0.01
     assert metrics.get("span.failing_op.errors") == 1.0
-    assert metrics.histogram_stats("span.failing_op.duration_s")["count"] == 1
     # clean spans don't count errors
     with trace_span("clean_op"):
         pass
     assert metrics.get("span.clean_op.errors") == 0.0
-    assert metrics.get("span.clean_op.count") == 1.0
+    assert metrics.histogram_stats("cgx.clean_op_s")["count"] == 1
+    # ...and nothing repeats the histogram's .sum/.count as counters.
+    assert not [k for k in metrics.snapshot("span.") if "errors" not in k]
 
 
 # ---------------------------------------------------------------------------

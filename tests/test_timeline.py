@@ -122,13 +122,14 @@ def test_trace_span_emits_timeline(tmp_path, monkeypatch):
 
     monkeypatch.setenv("CGX_METRICS_DIR", str(tmp_path))
     timeline.set_rank(0)
-    with trace_span("grad_sync"):
+    with trace_span("grad_sync", req="r1", step=3):
         pass
     timeline.flush()
     lines = [json.loads(l) for l in open(tmp_path / "spans-rank0.jsonl")]
     spans = [e for e in lines if e.get("kind") == "span"]
     assert any(
         s["name"] == "grad_sync" and s["cat"] == "span" and s["ok"]
+        and s["req"] == "r1" and s["step"] == 3
         for s in spans
     )
 

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..ops import codec_host
 from ..utils.logging import get_logger, metrics
+from ..utils.tracing import trace_span
 from . import transport as tp
 from .scheduler import (
     GPT2Server,
@@ -75,69 +76,64 @@ class PrefillWorker:
         included). The sender thread keeps draining after this returns —
         call :meth:`stop` to join them all (bounded)."""
         self._reap_drained()
-        t0 = time.perf_counter()
         cfg = self.server.cfg
         sv = self.server.serve
-        specs = _resolved_specs(self.server)
         prompt = np.asarray(tokens, np.int32)
         s = prompt.shape[0]
         pt = sv.page_tokens
         n_full = s // pt
         tail_len = s - n_full * pt
-        first, ks, vs = _prefill_forward(self.server, prompt)
-        sender = tp.KvPageSender(
-            self._store, str(request_id), shm=self._shm,
-            depth=sv.ship_depth, throttle=self._throttle,
-        )
-        self._senders.append(sender)
         frames = 1 + 2 * cfg.n_layer * n_full + 2 * cfg.n_layer
-        sender.post_meta({
-            "frames": frames,
-            "prompt_tokens": int(s),
-            "page_tokens": int(pt),
-            "pages": int(n_full),
-            "tail_tokens": int(tail_len),
-            "first_token": int(first),
-        })
-        for page in range(n_full):
-            lo, hi = page * pt, (page + 1) * pt
-            for layer in range(cfg.n_layer):
-                spec = specs[layer]
-                for kind, cache in ((tp.K_PAGE, ks), (tp.V_PAGE, vs)):
-                    row = cache[layer][lo:hi].reshape(-1)
-                    sender.post_page(
-                        layer, kind, page, spec.bits,
-                        spec.bucket_size if spec.quantized else 0,
-                        spec.flat, _encode_page(row, spec),
-                    )
-                if spec.quantized:
-                    _observe_page_qerr(
-                        self.server.layer_name(layer), spec,
-                        ks[layer][lo:hi].reshape(1, -1),
-                        already_host=True,
-                    )
-                _account_pages(self.server.layer_name(layer), spec, 2)
-        # The not-yet-full last page ships raw f16 (it is re-quantized
-        # by the decode side only when it fills and commits).
-        for layer in range(cfg.n_layer):
-            for kind, cache in ((tp.K_TAIL, ks), (tp.V_TAIL, vs)):
-                vals = cache[layer][n_full * pt:].astype(np.float16)
-                sender.post_page(
-                    layer, kind, 0, 0, 0, int(vals.size),
-                    vals.tobytes(),
-                )
-        metrics.add("cgx.serve.prefills_shipped")
-        t1 = time.perf_counter()
-        metrics.observe("cgx.serve.prefill_s", t1 - t0)
         # Request-tagged prefill span (ISSUE 17): the critical-path
         # engine's TTFT decomposition joins it to the kv.ship stream
         # and the scheduler's submit/admit instants by ``req``.
-        from ..observability import timeline
-
-        timeline.record(
-            "serve.prefill", timeline.CAT_SPAN, t0, t1 - t0,
+        with trace_span(
+            "serve.prefill", hist="cgx.serve.prefill_s",
             req=str(request_id), frames=frames, prompt_tokens=int(s),
-        )
+        ):
+            specs = _resolved_specs(self.server)
+            first, ks, vs = _prefill_forward(self.server, prompt)
+            sender = tp.KvPageSender(
+                self._store, str(request_id), shm=self._shm,
+                depth=sv.ship_depth, throttle=self._throttle,
+            )
+            self._senders.append(sender)
+            sender.post_meta({
+                "frames": frames,
+                "prompt_tokens": int(s),
+                "page_tokens": int(pt),
+                "pages": int(n_full),
+                "tail_tokens": int(tail_len),
+                "first_token": int(first),
+            })
+            for page in range(n_full):
+                lo, hi = page * pt, (page + 1) * pt
+                for layer in range(cfg.n_layer):
+                    spec = specs[layer]
+                    for kind, cache in ((tp.K_PAGE, ks), (tp.V_PAGE, vs)):
+                        row = cache[layer][lo:hi].reshape(-1)
+                        sender.post_page(
+                            layer, kind, page, spec.bits,
+                            spec.bucket_size if spec.quantized else 0,
+                            spec.flat, _encode_page(row, spec),
+                        )
+                    if spec.quantized:
+                        _observe_page_qerr(
+                            self.server.layer_name(layer), spec,
+                            ks[layer][lo:hi].reshape(1, -1),
+                            already_host=True,
+                        )
+                    _account_pages(self.server.layer_name(layer), spec, 2)
+            # The not-yet-full last page ships raw f16 (it is re-quantized
+            # by the decode side only when it fills and commits).
+            for layer in range(cfg.n_layer):
+                for kind, cache in ((tp.K_TAIL, ks), (tp.V_TAIL, vs)):
+                    vals = cache[layer][n_full * pt:].astype(np.float16)
+                    sender.post_page(
+                        layer, kind, 0, 0, 0, int(vals.size),
+                        vals.tobytes(),
+                    )
+            metrics.add("cgx.serve.prefills_shipped")
         return frames
 
     def _reap_drained(self) -> None:

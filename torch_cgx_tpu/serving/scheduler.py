@@ -51,6 +51,7 @@ from ..ops import codec_host
 from ..ops import paged_kv
 from ..observability import memledger, timeline
 from ..utils.logging import get_logger, metrics
+from ..utils.tracing import install_gc_hook, trace_span
 from ..wire import dispatch as wire_dispatch
 from . import kv_cache as kv_mod
 from . import transport as tp
@@ -489,6 +490,9 @@ class _Ready:
     tail_len: int
     first_token: int
     pos: int
+    # End of the prefill (or ingest) that built it, on ``submitted_at``'s
+    # clock: ``cgx.serve.ready_wait_s`` counts from here to the lane write.
+    ready_at: float = dataclasses.field(default_factory=time.monotonic)
 
 
 class ContinuousBatchScheduler:
@@ -516,6 +520,7 @@ class ContinuousBatchScheduler:
         # start the memory ledger, yet its KV pool is a primary ledger
         # owner — arm it here too (no-op when CGX_MEMLEDGER is unset).
         memledger.maybe_start()
+        self._gc_pauses = install_gc_hook()
         self.cache = kv_mod.PagedKvCache(sv.max_pages, sv.page_tokens)
         self._cache_gen = self.cache.generation
         self._prog = _decode_program(server)
@@ -687,11 +692,13 @@ class ContinuousBatchScheduler:
         streams, admit, commit full tails, decode one token for every
         active lane, evict completed lanes. Returns whether anything
         progressed (the run loop's idle-sleep signal). NEVER blocks."""
-        self._maybe_rebuild()
-        progressed = self._drain_transport()
-        progressed |= self._failover_stalled()
-        progressed |= self._admit()
-        progressed |= self._decode()
+        with trace_span("serve.step"):
+            self._gc_pauses.publish()
+            self._maybe_rebuild()
+            progressed = self._drain_transport()
+            progressed |= self._failover_stalled()
+            progressed |= self._admit()
+            progressed |= self._decode()
         return progressed
 
     def run(self, *, deadline_s: float = 120.0,
@@ -721,9 +728,8 @@ class ContinuousBatchScheduler:
             meta = self._receiver.meta(stream) or {}
             self._receiver.drop_stream(stream)
             try:
-                with timeline.span(
-                    "serve.ingest", cat=timeline.CAT_SPAN, req=req.id,
-                    frames=len(frames),
+                with trace_span(
+                    "serve.ingest", req=req.id, frames=len(frames)
                 ):
                     self._ingest_stream(req, meta, frames)
             except Exception as e:
@@ -853,8 +859,6 @@ class ContinuousBatchScheduler:
 
     def _local_prefill(self, req: Request) -> Optional[_Ready]:
         sv = self.server.serve
-        cfg = self.server.cfg
-        pt = sv.page_tokens
         prompt = np.asarray(req.tokens, np.int32)
         s = prompt.shape[0]
         if s < 1 or s + req.max_new_tokens > sv.max_seq:
@@ -863,16 +867,15 @@ class ContinuousBatchScheduler:
                 f"{req.max_new_tokens} exceeds CGX_SERVE_MAX_SEQ "
                 f"{sv.max_seq}"
             )
-        n_full = s // pt
         pids: List[int] = []
-        for _ in range(n_full):
+        for _ in range(s // sv.page_tokens):
             pid = self.cache.alloc(req.id)
             if pid is None:
                 self.cache.free_seq(req.id)
                 return None  # pool pressure: stay queued
             pids.append(pid)
         try:
-            return self._local_prefill_compute(req, n_full, pids, s)
+            return self._local_prefill_compute(req, prompt, pids)
         except BaseException:
             # A prefill failure (jit error, bad prompt) must release the
             # pages it reserved — the request re-enters the queue or
@@ -881,68 +884,91 @@ class ContinuousBatchScheduler:
             raise
 
     def _local_prefill_compute(
-        self, req: Request, n_full: int, pids: List[int], s: int
+        self, req: Request, prompt: np.ndarray, pids: List[int]
     ) -> _Ready:
-        sv = self.server.serve
+        """The prefill of one request whose full pages ``pids`` are
+        reserved, in four phases, each under its own span. Only the tail
+        copies wait for the device, so their span holds the device time of
+        the three before them."""
         cfg = self.server.cfg
-        pt = sv.page_tokens
-        prompt = np.asarray(req.tokens, np.int32)
-        t0 = time.perf_counter()
-        padded = _pad_prompt(prompt, pt)
-        first, ks, vs = self._prog.prefill(
-            self.server.p, padded[None],
-            np.arange(padded.shape[0], dtype=np.int32)[None],
-            np.int32(s - 1),
-        )
+        pt = self.server.serve.page_tokens
         h, d = self.server.n_head, self.server.d_head
+        s, n_full = prompt.shape[0], len(pids)
         tail_len = s - n_full * pt
-        tail_k = np.zeros((cfg.n_layer, pt, h, d), np.float32)
-        tail_v = np.zeros((cfg.n_layer, pt, h, d), np.float32)
-        if n_full:
-            ids = jnp.asarray(pids, jnp.int32)
-            layer_rows_k = []
-            layer_rows_v = []
-            for layer in range(cfg.n_layer):
-                spec = self._prog.specs[layer]
-                k_full = ks[layer][0, : n_full * pt].reshape(n_full, -1)
-                v_full = vs[layer][0, : n_full * pt].reshape(n_full, -1)
-                if spec.quantized:
-                    layer_rows_k.append(
-                        paged_kv.quantize_page_rows(k_full, spec)
-                    )
-                    layer_rows_v.append(
-                        paged_kv.quantize_page_rows(v_full, spec)
-                    )
-                    _observe_page_qerr(
-                        self.server.layer_name(layer), spec, k_full
-                    )
-                else:
-                    layer_rows_k.append(k_full)
-                    layer_rows_v.append(v_full)
-                _account_pages(
-                    self.server.layer_name(layer), spec, 2 * n_full
+        queue_wait = time.monotonic() - req.submitted_at
+        metrics.observe("cgx.serve.queue_wait_s", queue_wait)
+        with trace_span(
+            "serve.prefill.local", hist="cgx.serve.prefill_s", req=req.id,
+            prompt_tokens=int(s), queue_wait_ms=round(queue_wait * 1e3, 3),
+        ):
+            with trace_span(
+                "serve.prefill.forward",
+                hist="cgx.serve.prefill_forward_s", req=req.id,
+            ):
+                padded = _pad_prompt(prompt, pt)
+                first, ks, vs = self._prog.prefill(
+                    self.server.p, padded[None],
+                    np.arange(padded.shape[0], dtype=np.int32)[None],
+                    np.int32(s - 1),
                 )
-            self._state = dict(
-                self._state,
-                pools=self._prog.ingest(
-                    self._state["pools"], layer_rows_k, layer_rows_v, ids
-                ),
-            )
-        for layer in range(cfg.n_layer):
+            tail_k = np.zeros((cfg.n_layer, pt, h, d), np.float32)
+            tail_v = np.zeros((cfg.n_layer, pt, h, d), np.float32)
+            if n_full:
+                with trace_span(
+                    "serve.prefill.quantize",
+                    hist="cgx.serve.prefill_quantize_s", req=req.id,
+                ):
+                    ids = jnp.asarray(pids, jnp.int32)
+                    layer_rows_k = []
+                    layer_rows_v = []
+                    for layer in range(cfg.n_layer):
+                        spec = self._prog.specs[layer]
+                        k_full = ks[layer][0, : n_full * pt].reshape(
+                            n_full, -1
+                        )
+                        v_full = vs[layer][0, : n_full * pt].reshape(
+                            n_full, -1
+                        )
+                        if spec.quantized:
+                            layer_rows_k.append(
+                                paged_kv.quantize_page_rows(k_full, spec)
+                            )
+                            layer_rows_v.append(
+                                paged_kv.quantize_page_rows(v_full, spec)
+                            )
+                            _observe_page_qerr(
+                                self.server.layer_name(layer), spec, k_full
+                            )
+                        else:
+                            layer_rows_k.append(k_full)
+                            layer_rows_v.append(v_full)
+                        _account_pages(
+                            self.server.layer_name(layer), spec, 2 * n_full
+                        )
+                with trace_span(
+                    "serve.prefill.ingest",
+                    hist="cgx.serve.prefill_ingest_s", req=req.id,
+                ):
+                    self._state = dict(
+                        self._state,
+                        pools=self._prog.ingest(
+                            self._state["pools"], layer_rows_k,
+                            layer_rows_v, ids,
+                        ),
+                    )
             if tail_len:
-                tail_k[layer, :tail_len] = np.asarray(
-                    ks[layer][0, n_full * pt: s]
-                )
-                tail_v[layer, :tail_len] = np.asarray(
-                    vs[layer][0, n_full * pt: s]
-                )
-        t1 = time.perf_counter()
-        metrics.observe("cgx.serve.prefill_s", t1 - t0)
+                with trace_span(
+                    "serve.prefill.tail_copy",
+                    hist="cgx.serve.prefill_tail_copy_s", req=req.id,
+                ):
+                    for layer in range(cfg.n_layer):
+                        tail_k[layer, :tail_len] = np.asarray(
+                            ks[layer][0, n_full * pt: s]
+                        )
+                        tail_v[layer, :tail_len] = np.asarray(
+                            vs[layer][0, n_full * pt: s]
+                        )
         metrics.add("cgx.serve.local_prefills")
-        timeline.record(
-            "serve.prefill.local", timeline.CAT_SPAN, t0, t1 - t0,
-            req=req.id, prompt_tokens=int(s),
-        )
         return _Ready(
             req=req, page_ids=pids, tail_k=tail_k, tail_v=tail_v,
             tail_len=tail_len, first_token=int(first[0]), pos=s,
@@ -990,41 +1016,49 @@ class ContinuousBatchScheduler:
     def _admit_lane(self, lane: int, ready: _Ready) -> None:
         sv = self.server.serve
         req = ready.req
-        st = self._state
-        padded = np.full((sv.pages_per_seq,), -1, np.int32)
-        padded[: len(ready.page_ids)] = ready.page_ids
-        st["page_table"] = st["page_table"].at[lane].set(padded)
-        st["n_pages"] = st["n_pages"].at[lane].set(len(ready.page_ids))
-        st["tail_len"] = st["tail_len"].at[lane].set(ready.tail_len)
-        st["tokens"] = st["tokens"].at[lane].set(ready.first_token)
-        st["pos"] = st["pos"].at[lane].set(ready.pos)
-        st["active"] = st["active"].at[lane].set(True)
-        st["tail_k"] = tuple(
-            st["tail_k"][i].at[lane].set(ready.tail_k[i])
-            for i in range(self.server.cfg.n_layer)
-        )
-        st["tail_v"] = tuple(
-            st["tail_v"][i].at[lane].set(ready.tail_v[i])
-            for i in range(self.server.cfg.n_layer)
-        )
-        self._lanes[lane] = req
-        # The prefill's own argmax IS the first generated token — the
-        # disaggregated convention: TTFT is admission, not first decode.
-        now = time.monotonic()
-        req.output.append(ready.first_token)
-        req.first_token_at = now
-        ttft_ms = (now - req.submitted_at) * 1e3
-        metrics.observe("cgx.serve.ttft_ms", ttft_ms)
-        metrics.add("cgx.serve.requests_admitted")
-        timeline.instant(
-            "serve.admit", cat=timeline.CAT_TRACE, req=req.id,
-            lane=int(lane), ttft_ms=round(ttft_ms, 3),
-        )
-        self._note_tokens(1)
-        if len(req.output) >= req.max_new_tokens or (
-            sv.eos_token is not None and ready.first_token == sv.eos_token
+        ready_wait = time.monotonic() - ready.ready_at
+        metrics.observe("cgx.serve.ready_wait_s", ready_wait)
+        with trace_span(
+            "serve.admit_lane", req=req.id, lane=int(lane),
+            ready_wait_ms=round(ready_wait * 1e3, 3),
         ):
-            self._finish_lane(lane)
+            st = self._state
+            padded = np.full((sv.pages_per_seq,), -1, np.int32)
+            padded[: len(ready.page_ids)] = ready.page_ids
+            st["page_table"] = st["page_table"].at[lane].set(padded)
+            st["n_pages"] = st["n_pages"].at[lane].set(len(ready.page_ids))
+            st["tail_len"] = st["tail_len"].at[lane].set(ready.tail_len)
+            st["tokens"] = st["tokens"].at[lane].set(ready.first_token)
+            st["pos"] = st["pos"].at[lane].set(ready.pos)
+            st["active"] = st["active"].at[lane].set(True)
+            st["tail_k"] = tuple(
+                st["tail_k"][i].at[lane].set(ready.tail_k[i])
+                for i in range(self.server.cfg.n_layer)
+            )
+            st["tail_v"] = tuple(
+                st["tail_v"][i].at[lane].set(ready.tail_v[i])
+                for i in range(self.server.cfg.n_layer)
+            )
+            self._lanes[lane] = req
+            # The prefill's own argmax IS the first generated token — the
+            # disaggregated convention: TTFT is admission, not first
+            # decode.
+            now = time.monotonic()
+            req.output.append(ready.first_token)
+            req.first_token_at = now
+            ttft_ms = (now - req.submitted_at) * 1e3
+            metrics.observe("cgx.serve.ttft_ms", ttft_ms)
+            metrics.add("cgx.serve.requests_admitted")
+            timeline.instant(
+                "serve.admit", cat=timeline.CAT_TRACE, req=req.id,
+                lane=int(lane), ttft_ms=round(ttft_ms, 3),
+            )
+            self._note_tokens(1)
+            if len(req.output) >= req.max_new_tokens or (
+                sv.eos_token is not None
+                and ready.first_token == sv.eos_token
+            ):
+                self._finish_lane(lane)
 
     def _finish_lane(self, lane: int) -> None:
         req = self._lanes[lane]
@@ -1049,87 +1083,83 @@ class ContinuousBatchScheduler:
         if not active:
             return False
         sv = self.server.serve
-        st = self._state
-        # Promote full tails first so every lane has tail room.
-        tail_len = np.asarray(st["tail_len"])
-        full = [
-            i for i in active
-            if tail_len[i] >= sv.page_tokens
-        ]
-        if full:
-            mask = np.zeros((sv.max_batch,), bool)
-            pids = np.zeros((sv.max_batch,), np.int32)
-            committed = []
-            for lane in full:
-                req = self._lanes[lane]
-                pid = self.cache.alloc(req.id)
-                if pid is None:
-                    # Pool pressure mid-decode: evict this lane back to
-                    # the queue (it re-prefills when pages free up)
-                    # rather than stalling every other lane.
-                    metrics.add("cgx.serve.decode_evictions")
-                    self.cache.free_seq(req.id)
-                    req.output.clear()
-                    req.first_token_at = None
-                    self._waiting.append(req)
-                    self._lanes[lane] = None
-                    st["active"] = st["active"].at[lane].set(False)
-                    continue
-                mask[lane] = True
-                pids[lane] = pid
-                committed.append(lane)
-            if committed:
-                if cfg_mod.qerr_stats():
-                    for layer in range(self.server.cfg.n_layer):
-                        spec = self._prog.specs[layer]
-                        if spec.quantized:
-                            rows = np.asarray(
-                                st["tail_k"][layer]
-                            )[committed].reshape(len(committed), -1)
-                            _observe_page_qerr(
-                                self.server.layer_name(layer), spec,
-                                rows, already_host=True,
-                            )
-                self._state = self._prog.commit(
-                    self._state, jnp.asarray(mask), jnp.asarray(pids)
-                )
-                for layer in range(self.server.cfg.n_layer):
-                    _account_pages(
-                        self.server.layer_name(layer),
-                        self._prog.specs[layer], 2 * len(committed),
+        n_layer = self.server.cfg.n_layer
+        with trace_span(
+            "serve.decode.prepare", hist="cgx.serve.decode_prepare_s"
+        ):
+            st = self._state
+            # Promote full tails first so every lane has tail room.
+            tail_len = np.asarray(st["tail_len"])
+            full = [i for i in active if tail_len[i] >= sv.page_tokens]
+            if full:
+                mask = np.zeros((sv.max_batch,), bool)
+                pids = np.zeros((sv.max_batch,), np.int32)
+                committed = []
+                for lane in full:
+                    req = self._lanes[lane]
+                    pid = self.cache.alloc(req.id)
+                    if pid is None:
+                        # Pool pressure mid-decode: evict this lane back
+                        # to the queue (it re-prefills when pages free
+                        # up) rather than stalling every other lane.
+                        metrics.add("cgx.serve.decode_evictions")
+                        self.cache.free_seq(req.id)
+                        req.output.clear()
+                        req.first_token_at = None
+                        self._waiting.append(req)
+                        self._lanes[lane] = None
+                        st["active"] = st["active"].at[lane].set(False)
+                        continue
+                    mask[lane] = True
+                    pids[lane] = pid
+                    committed.append(lane)
+                if committed:
+                    if cfg_mod.qerr_stats():
+                        for layer in range(n_layer):
+                            spec = self._prog.specs[layer]
+                            if spec.quantized:
+                                rows = np.asarray(
+                                    st["tail_k"][layer]
+                                )[committed].reshape(len(committed), -1)
+                                _observe_page_qerr(
+                                    self.server.layer_name(layer), spec,
+                                    rows, already_host=True,
+                                )
+                    self._state = self._prog.commit(
+                        self._state, jnp.asarray(mask), jnp.asarray(pids)
                     )
-                metrics.add(
-                    "cgx.serve.pages_committed",
-                    float(2 * len(committed) * self.server.cfg.n_layer),
-                )
-            active = [i for i, r in enumerate(self._lanes)
-                      if r is not None]
-            if not active:
-                return True
-        t0 = time.perf_counter()
-        self._state, nxt = self._prog.decode_step(
-            self.server.p, self._state
-        )
-        nxt = np.asarray(nxt)
-        dt = time.perf_counter() - t0
-        metrics.observe("cgx.serve.decode_step_s", dt)
-        metrics.add("cgx.serve.decode_steps")
-        metrics.set(
-            "cgx.serve.batch_occupancy",
-            len(active) / self.server.serve.max_batch,
-        )
-        n_new = 0
-        for lane in active:
-            req = self._lanes[lane]
-            token = int(nxt[lane])
-            req.output.append(token)
-            n_new += 1
-            if len(req.output) >= req.max_new_tokens or (
-                self.server.serve.eos_token is not None
-                and token == self.server.serve.eos_token
-            ):
-                self._finish_lane(lane)
-        self._note_tokens(n_new)
+                    for layer in range(n_layer):
+                        _account_pages(
+                            self.server.layer_name(layer),
+                            self._prog.specs[layer], 2 * len(committed),
+                        )
+                    metrics.add(
+                        "cgx.serve.pages_committed",
+                        float(2 * len(committed) * n_layer),
+                    )
+                active = [i for i, r in enumerate(self._lanes)
+                          if r is not None]
+                if not active:
+                    return True
+        with trace_span("serve.decode_step"):
+            self._state, nxt = self._prog.decode_step(
+                self.server.p, self._state
+            )
+            nxt = np.asarray(nxt)
+        with trace_span("serve.decode.emit", hist="cgx.serve.decode_emit_s"):
+            metrics.add("cgx.serve.decode_steps")
+            metrics.set(
+                "cgx.serve.batch_occupancy", len(active) / sv.max_batch
+            )
+            for lane in active:
+                req = self._lanes[lane]
+                token = int(nxt[lane])
+                req.output.append(token)
+                if len(req.output) >= req.max_new_tokens or (
+                    sv.eos_token is not None and token == sv.eos_token
+                ):
+                    self._finish_lane(lane)
+            self._note_tokens(len(active))
         return True
 
     def _note_tokens(self, n: int) -> None:

@@ -1,46 +1,51 @@
 """Tracing / profiling spans.
 
 The reference's only tracing is c10d ``profilingTitle`` strings surfaced to
-torch.profiler (SURVEY.md §5.1). The TPU-native equivalent: every collective
-wraps itself in a ``jax.profiler.TraceAnnotation`` (visible in XLA/Perfetto
-traces) and records wall-clock spans into the metrics registry for host-side
-inspection.
+torch.profiler (SURVEY.md §5.1). The TPU-native equivalent is
+:func:`trace_span`, the package's one way to time host-side work: a
+``jax.profiler.TraceAnnotation`` on the profiler's host plane (the same
+clock as the device's op line), one histogram in the metrics registry, and
+a record in the cross-rank timeline. :func:`named_scope` names regions of
+traced (jitted) code; :func:`profile_capture` writes a device profile.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import time
 
 import jax
 
+from ..observability import timeline
 from .logging import get_logger, metrics
 
 log = get_logger()
 
 
 @contextlib.contextmanager
-def trace_span(name: str):
-    """Annotate a host-side span: XLA trace annotation + duration counter
-    (``span.{name}.seconds`` / ``span.{name}.count`` in ``metrics``) and
-    a duration histogram (``span.{name}.duration_s`` — distinct name so
-    its flattened ``.count``/``.sum`` stats never collide with the legacy
-    counter keys in ``snapshot()``).
+def trace_span(name: str, *, hist: str | None = None, **fields):
+    """The package's one host-side span. Three sinks, one pair of clock
+    reads:
 
-    With ``CGX_METRICS_DIR`` set the span also lands in the cross-rank
-    timeline (``observability.timeline``) so it shows up as a slice in
-    the merged ``trace.json``.
+    * ``jax.profiler.TraceAnnotation("cgx." + name, **fields)`` — an event
+      on the host plane of the profiler's trace, on the same clock as the
+      device's op line (free while no profiler session runs);
+    * ONE histogram of the duration in seconds: ``hist``, or
+      ``cgx.<name>_s``;
+    * with ``CGX_METRICS_DIR`` set, a record under ``name`` with
+      ``fields`` in the cross-rank timeline (``observability.timeline``).
 
-    The duration sample is recorded in a ``finally`` so a span whose body
-    raises still lands in the registry — failed collectives are the
-    interesting ones; ``span.{name}.errors`` counts them.
+    The sample is recorded in a ``finally`` so a span whose body raises
+    still lands in the registry — failed operations are the interesting
+    ones; ``span.<name>.errors`` counts them. The span never waits for
+    the device and copies nothing from it: it times what the body itself
+    blocks on.
     """
-    from ..observability import timeline
-
     start = time.perf_counter()
     ok = True
     try:
-        with jax.profiler.TraceAnnotation(name):
+        with jax.profiler.TraceAnnotation("cgx." + name, **fields):
             yield
     except BaseException:
         ok = False
@@ -48,10 +53,54 @@ def trace_span(name: str):
         raise
     finally:
         dur = time.perf_counter() - start
-        metrics.add(f"span.{name}.seconds", dur)
-        metrics.add(f"span.{name}.count", 1.0)
-        metrics.observe(f"span.{name}.duration_s", dur)
-        timeline.record(name, timeline.CAT_SPAN, start, dur, ok=ok)
+        metrics.observe(hist or f"cgx.{name}_s", dur)
+        if timeline.enabled():
+            timeline.record(
+                name, timeline.CAT_SPAN, start, dur, ok=ok, **fields
+            )
+
+
+class GcPauses:
+    """``gc.callbacks`` hook: the pauses of full (generation 2)
+    collections, each under a ``cgx.host.gc`` annotation so that it shows
+    in a profiler trace beside the device's idle gap it causes.
+
+    A collection starts at whatever allocation tripped it, possibly one
+    made under the metric registry's own (non-reentrant) lock, so the
+    callback only stamps; :meth:`publish`, called from a safe point (the
+    scheduler's tick), moves the pauses into ``cgx.serve.host_gc_s``."""
+
+    def __init__(self):
+        self._span = None
+        self._start = 0.0
+        self._pending: list = []
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if info["generation"] < 2:
+            return
+        if phase == "start":
+            self._span = jax.profiler.TraceAnnotation("cgx.host.gc")
+            self._span.__enter__()
+            self._start = time.perf_counter()
+        elif self._span is not None:
+            self._pending.append(time.perf_counter() - self._start)
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def publish(self) -> None:
+        while self._pending:
+            metrics.observe("cgx.serve.host_gc_s", self._pending.pop())
+
+
+def install_gc_hook() -> GcPauses:
+    """The process's :class:`GcPauses`, installed on first call; later
+    calls return the one already in ``gc.callbacks``."""
+    for cb in gc.callbacks:
+        if isinstance(cb, GcPauses):
+            return cb
+    hook = GcPauses()
+    gc.callbacks.append(hook)
+    return hook
 
 
 def named_scope(name: str):
