@@ -465,19 +465,24 @@ def phase_serve(rehearse: bool) -> int:
     sched = ContinuousBatchScheduler(server, receiver=KvPageReceiver(store))
     checks = Checks()
 
-    # A server compiles before it takes traffic: one LOCAL request through
-    # the scheduler builds the prefill / ingest / decode programs the
-    # disaggregated requests below share (the failover clock of a remote
-    # stream starts at submit and is shorter than a cold compile).
+    # A server compiles before it takes traffic (the failover clock of a
+    # remote stream starts at submit and is shorter than a cold compile):
+    # one LOCAL request through the scheduler builds the local prefill, lane
+    # write and decode programs, and one stream shipped BEFORE its submit
+    # builds the two only the disaggregated path runs, the worker's forward
+    # and the scheduler's ingest.
+    worker = PrefillWorker(server, store)
     t0 = time.perf_counter()
     sched.submit(request("warmup", 2))
+    shipped = request("warmup-shipped", 2)
+    worker.serve(shipped.id, shipped.tokens)
+    sched.submit(shipped, remote=True)
     warm_ok = sched.run(deadline_s=300.0)
     warmup_s = time.perf_counter() - t0
-    checks.add("warm-up request (local prefill, compiles the programs)",
-               warm_ok, f"{warmup_s:.1f} s")
+    checks.add("warm-up requests (one local, one shipped: compile the "
+               "programs)", warm_ok, f"{warmup_s:.1f} s")
 
     requests = [request(f"req{i}", gen) for i in range(n_req)]
-    worker = PrefillWorker(server, store)
     t0 = time.perf_counter()
     for r in requests:
         sched.submit(r, remote=True)
@@ -521,9 +526,9 @@ def phase_serve(rehearse: bool) -> int:
                counters["prefill_failovers"])
     checks.add("cgx.serve.ingest_errors == 0",
                counters["ingest_errors"] == 0, counters["ingest_errors"])
-    checks.add("only the warm-up prefilled locally",
+    checks.add("only the local warm-up prefilled locally",
                counters["local_prefills"] == 1
-               and counters["prefills_shipped"] == n_req,
+               and counters["prefills_shipped"] == n_req + 1,
                {k: counters[k] for k in
                 ("local_prefills", "prefills_shipped")})
 

@@ -1,7 +1,10 @@
-"""The serving scheduler's spans (ISSUE 25): every phase of admission and of
-a decode tick goes through ``utils.tracing.trace_span`` — one annotation on
-the profiler's host plane, one histogram under ``cgx.serve.``, one timeline
-record — plus the two waits of a request and the full-collection pauses.
+"""The serving scheduler's spans (ISSUE 25, re-cut by ISSUE 26): every phase
+of admission and of a decode tick goes through ``utils.tracing.trace_span`` —
+one annotation on the profiler's host plane, one histogram under
+``cgx.serve.``, one timeline record — plus the two waits of a request and the
+full-collection pauses. An admission is two compiled programs (ISSUE 26), so
+a prefill has two phases: the program's call, and the read of the first token
+that waits for it.
 
 CPU, the tiny model: counts, containment and nesting are what a CPU run can
 say; the times themselves are read on the chip (PERF.md).
@@ -30,16 +33,13 @@ from torch_cgx_tpu.utils.logging import metrics
 from torch_cgx_tpu.utils.tracing import GcPauses, install_gc_hook, trace_span
 
 PAGE = 8
-# Each at least one full page and none a whole number of pages, so every
-# admission runs all four prefill phases.
-PROMPT_LENS = (13, 19, 22)
+# A tail alone, pages with a tail, whole pages: every admission runs both
+# prefill phases whatever its shape.
+PROMPT_LENS = (5, 19, 24)
 GEN = 5
 
-# Histogram of each span of the table (ISSUE 25), under ``cgx.serve.``.
-PREFILL_PHASES = (
-    "prefill_forward_s", "prefill_quantize_s", "prefill_ingest_s",
-    "prefill_tail_copy_s",
-)
+# Histogram of each span of the table (ISSUE 26), under ``cgx.serve.``.
+PREFILL_PHASES = ("prefill_forward_s", "prefill_first_token_s")
 PER_ADMISSION = (
     "prefill_s", *PREFILL_PHASES, "admit_lane_s", "queue_wait_s",
     "ready_wait_s",
@@ -49,9 +49,7 @@ PER_DECODE_STEP = ("decode_prepare_s", "decode_step_s", "decode_emit_s")
 # profiler's trace has them under ``cgx.``).
 PARENT = {
     "serve.prefill.forward": "serve.prefill.local",
-    "serve.prefill.quantize": "serve.prefill.local",
-    "serve.prefill.ingest": "serve.prefill.local",
-    "serve.prefill.tail_copy": "serve.prefill.local",
+    "serve.prefill.first_token": "serve.prefill.local",
     "serve.prefill.local": "serve.step",
     "serve.admit_lane": "serve.step",
     "serve.decode.prepare": "serve.step",
@@ -126,7 +124,7 @@ def test_prefill_phases_sum_within_the_prefill_span(server):
 def test_waits_and_spans_decompose_ttft(server):
     """One request: submit -> first token is queue wait, prefill, ready
     wait, then the lane write up to the first-token stamp."""
-    (req,), _, start, end = _serve(server, lens=(13,))
+    (req,), _, start, end = _serve(server, lens=(19,))
     ttft = req.first_token_at - req.submitted_at
     before_lane = sum(
         _delta(start, end, f"{n}.sum")
@@ -185,7 +183,7 @@ def _host_events(trace_dir, prefix):
 
 def test_profiler_trace_holds_the_spans_nested_on_one_clock(server,
                                                             tmp_path):
-    _serve(server, lens=(13,))  # compile outside the traced stretch
+    _serve(server)  # compile outside the traced stretch
     with jax.profiler.trace(str(tmp_path)):
         with jax.profiler.TraceAnnotation("cgx.test.window"):
             reqs, ticks, _, _ = _serve(server)

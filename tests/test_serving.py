@@ -753,6 +753,241 @@ def test_prefill_ahead_bounded_by_free_lanes(model_setup):
     assert sched.run(deadline_s=DEADLINE_S)
 
 
+# ---------------------------------------------------------------------------
+# Admission as compiled programs (ISSUE 26): the prefill writes its own
+# pages, the tails stay on the device, one call writes a lane.
+# ---------------------------------------------------------------------------
+
+
+def _admit_only(sched, req, *, remote=False):
+    """Admit ``req`` without decoding: transport drain + ``_admit`` until
+    the request holds a lane. Returns the lane."""
+    sched.submit(req, remote=remote)
+    deadline = time.monotonic() + DEADLINE_S
+    while req not in sched._lanes:
+        assert time.monotonic() < deadline, "admission wedged"
+        sched._drain_transport()
+        sched._admit()
+    return sched._lanes.index(req)
+
+
+def _lane_snapshot(sched, lane):
+    """Everything an admission wrote, as host arrays: the lane's
+    bookkeeping, its tails, and the pool rows at its page ids."""
+    st = sched._state
+    n_pages = int(st["n_pages"][lane])
+    ids = np.asarray(st["page_table"])[lane, :n_pages]
+    return {
+        "lane": {
+            k: np.asarray(st[k])[lane]
+            for k in ("page_table", "n_pages", "tail_len", "tokens", "pos",
+                      "active")
+        },
+        "tail_k": [np.asarray(t)[lane] for t in st["tail_k"]],
+        "tail_v": [np.asarray(t)[lane] for t in st["tail_v"]],
+        "rows": [
+            [np.asarray(a)[ids] for a in jax.tree.leaves(pool)]
+            for pool in st["pools"]
+        ],
+    }
+
+
+def _assert_same(got, want):
+    flat_got, tree_got = jax.tree.flatten(got)
+    flat_want, tree_want = jax.tree.flatten(want)
+    assert tree_got == tree_want
+    for a, b in zip(flat_got, flat_want):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("tail", [0, 1, PAGE - 1])
+@pytest.mark.parametrize("bits", ["8", "0"])
+def test_admission_matches_hand_composition(model_setup, monkeypatch, bits,
+                                            tail):
+    """After one admission the pool rows at the request's page ids and
+    the lane's tails are BYTE-identical to the pieces composed by hand:
+    ``prefill_forward`` -> ``quantize_page_rows`` -> ``_ingest_pool`` for
+    the full pages, the remaining rows zero-padded for the tails."""
+    from torch_cgx_tpu.ops import paged_kv
+
+    cfg, _model, params = model_setup
+    monkeypatch.setenv("CGX_KV_BITS", bits)
+    server = GPT2Server(cfg, params, _serve_cfg())
+    sched = ContinuousBatchScheduler(server)
+    n_full = 2
+    s = n_full * PAGE + tail
+    (prompt,) = _prompts(cfg, 1, lens=[s], seed=7 + tail)
+    req = Request(id="a", tokens=prompt, max_new_tokens=4)
+    lane = _admit_only(sched, req)
+    got = _lane_snapshot(sched, lane)
+
+    specs = sched._prog.specs
+    assert {sp.bits for sp in specs} == {int(bits)}
+    padded = sched_mod._pad_prompt(np.asarray(prompt, np.int32), PAGE)
+    logits, ks, vs = jax.jit(server.prefill_forward)(
+        padded[None], np.arange(padded.shape[0], dtype=np.int32)[None],
+        np.int32(s - 1),
+    )
+    ids = jnp.asarray(got["lane"]["page_table"][:n_full])
+    assert sorted(np.asarray(ids)) == sorted(sched.cache.pages_of("a"))
+    want_rows, want_tails = [], {"k": [], "v": []}
+    for layer, spec in enumerate(specs):
+        pools = {}
+        for kind, cache in (("k", ks), ("v", vs)):
+            rows = cache[layer][0, : n_full * PAGE].reshape(n_full, -1)
+            if spec.quantized:
+                rows = paged_kv.quantize_page_rows(rows, spec)
+            pools[kind] = sched_mod._ingest_pool(
+                paged_kv.empty_pool(server.serve.max_pages + 1, spec), ids,
+                rows, spec,
+            )
+            t = np.zeros((PAGE, server.n_head, server.d_head), np.float32)
+            t[:tail] = np.asarray(cache[layer][0, n_full * PAGE: s])
+            want_tails[kind].append(t)
+        want_rows.append([
+            np.asarray(a)[np.asarray(ids)] for a in jax.tree.leaves(pools)
+        ])
+    table_row = np.full((server.serve.pages_per_seq,), -1, np.int32)
+    table_row[:n_full] = np.asarray(ids)
+    _assert_same(got, {
+        "lane": {
+            "page_table": table_row, "n_pages": np.int32(n_full),
+            "tail_len": np.int32(tail),
+            "tokens": np.asarray(jnp.argmax(logits[0]), np.int32),
+            "pos": np.int32(s), "active": np.bool_(True),
+        },
+        "tail_k": want_tails["k"], "tail_v": want_tails["v"],
+        "rows": want_rows,
+    })
+    assert req.output == [int(jnp.argmax(logits[0]))]
+
+
+def test_prompt_lengths_under_one_padded_length_compile_nothing(
+    model_setup
+):
+    """One compiled prefill per PADDED length and none per prompt length
+    (a compile listener, not a timing): a tail, another tail and a whole
+    number of pages under one padded length share the first's programs."""
+    from jax import monitoring
+
+    cfg, _model, params = model_setup
+    server = GPT2Server(cfg, params, _serve_cfg())
+    sched = ContinuousBatchScheduler(server)
+    lens = [2 * PAGE + 1, 2 * PAGE + 3, 3 * PAGE - 1, 3 * PAGE, 3 * PAGE + 1]
+    reqs = [
+        Request(id=f"r{i}", tokens=p, max_new_tokens=4)
+        for i, p in enumerate(_prompts(cfg, len(lens), lens=lens))
+    ]
+    compiles = []
+
+    def on_duration(event, duration, **_):
+        if event.endswith("backend_compile_duration"):
+            compiles.append(event)
+
+    _admit_only(sched, reqs[0])  # compiles the padded length's programs
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        for req in reqs[1:4]:
+            _admit_only(sched, req)
+        assert compiles == []
+        sched_mod.invalidate_decode_cache("test: a padded length not seen")
+        server2 = GPT2Server(cfg, params, _serve_cfg())
+        _admit_only(ContinuousBatchScheduler(server2), reqs[4])
+        assert compiles, "the listener hears no compile at all"
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert [len(r.output) for r in reqs] == [1] * 5
+
+
+def test_stream_admission_leaves_the_local_lane_state(model_setup,
+                                                      monkeypatch):
+    """A stream admitted through ``_ingest_stream`` goes through the same
+    lane write as a local prefill and leaves the same lane: bookkeeping
+    and pool rows byte-identical, tails identical up to the f16 the wire
+    carries them in."""
+    cfg, _model, params = model_setup
+    monkeypatch.setenv("CGX_SERVE_PREFILL_TIMEOUT_MS", "60000")
+    (prompt,) = _prompts(cfg, 1, lens=[2 * PAGE + 3])
+    server = GPT2Server(cfg, params, _serve_cfg())
+
+    local = ContinuousBatchScheduler(server)
+    want = _lane_snapshot(local, _admit_only(
+        local, Request(id="a", tokens=prompt, max_new_tokens=4)))
+
+    store = FakeStore()
+    remote = ContinuousBatchScheduler(server, receiver=KvPageReceiver(store))
+    worker = PrefillWorker(server, store)
+    before = metrics.get("cgx.serve.local_prefills")
+    req = Request(id="a", tokens=prompt, max_new_tokens=4)
+    worker.serve(req.id, req.tokens)
+    got = _lane_snapshot(remote, _admit_only(remote, req, remote=True))
+    worker.stop()
+    assert metrics.get("cgx.serve.local_prefills") == before  # no failover
+
+    for kind in ("tail_k", "tail_v"):
+        assert any(t.any() for t in want[kind])
+        want[kind] = [
+            t.astype(np.float16).astype(np.float32) for t in want[kind]
+        ]
+    _assert_same(got, want)
+
+
+def test_failed_prefill_frees_pages_and_keeps_pools_usable(model_setup,
+                                                           monkeypatch):
+    """A prefill program that raises marks its request errored, frees the
+    pages it reserved, and leaves the donated pools usable: the lane
+    admitted before it and the request after it both finish with the
+    tokens an undisturbed run gives."""
+    cfg, _model, params = model_setup
+    prompts = _prompts(cfg, 3, lens=[2 * PAGE + 1, 3 * PAGE + 2,
+                                     3 * PAGE + 5])
+    want = _run_local(cfg, params, prompts, gen=6)
+    sched_mod.invalidate_decode_cache("test: trace the programs afresh")
+    server = GPT2Server(cfg, params, _serve_cfg())
+    sched = ContinuousBatchScheduler(server)
+    reqs = [Request(id=f"r{i}", tokens=p, max_new_tokens=6)
+            for i, p in enumerate(prompts)]
+    _admit_only(sched, reqs[0])
+    held = sched.cache.free_pages
+    errors = metrics.get("cgx.serve.request_errors")
+
+    def refuse(*_a, **_k):
+        raise RuntimeError("refused while tracing")
+
+    with monkeypatch.context() as m:
+        m.setattr(sched_mod.paged_kv, "commit_page_rows", refuse)
+        sched.submit(reqs[1])
+        sched._admit()  # a padded length not traced yet: the trace raises
+    assert reqs[1].done and reqs[1].output == []
+    assert metrics.get("cgx.serve.request_errors") == errors + 1
+    assert sched.cache.free_pages == held
+    sched.submit(reqs[2])
+    assert sched.run(deadline_s=DEADLINE_S)
+    assert [reqs[0].output, reqs[2].output] == [want[0], want[2]]
+    assert sched.cache.free_pages == sched.cache.max_pages
+
+
+def test_prefill_observes_page_qerr_only_when_asked(model_setup,
+                                                     monkeypatch):
+    """``CGX_QERR_STATS`` keeps its view of a local prefill: one
+    ``kv_page`` observation per layer per FULL page (the scratch-row page
+    of a tail is not a page), and none, nor any row fetched, when off."""
+    cfg, _model, params = model_setup
+    (prompt,) = _prompts(cfg, 1, lens=[2 * PAGE + 3])
+
+    def observed(knob):
+        monkeypatch.setenv("CGX_QERR_STATS", knob)
+        sched = ContinuousBatchScheduler(
+            GPT2Server(cfg, params, _serve_cfg()))
+        key = "cgx.qerr.wire:kv_page:layer_1.count"
+        before = metrics.snapshot("cgx.qerr.").get(key, 0.0)
+        _admit_only(sched, Request(id="q", tokens=prompt, max_new_tokens=2))
+        return metrics.snapshot("cgx.qerr.").get(key, 0.0) - before
+
+    assert observed("1") == 2
+    assert observed("0") == 0
+
+
 def test_sender_retry_keeps_seq_dense():
     """A transient store failure mid-ship must not burn a sequence
     number: the retried frame publishes under the SAME seq, so the

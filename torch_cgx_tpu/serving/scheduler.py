@@ -8,7 +8,11 @@ the lane's current token against pages + the raw f32 tail block, and
 emit the greedy next token. Admission and eviction happen per step
 around that program (continuous batching): completed lanes free their
 pages back to the refcounted pool and a waiting request takes the lane
-on the next step — the batch never drains to refill.
+on the next step — the batch never drains to refill. An admission is two
+more compiled programs over the same donated state: ``prefill_pages``
+(forward, the prompt's pages into the pools, its tails left on the
+device; the one wait is the read of the first token) and ``admit_lane``
+(one lane written in place).
 
 Requests arrive with their KV either computed here (local prefill — the
 colocated mode, also the FAILOVER path) or shipped by a disaggregated
@@ -38,7 +42,7 @@ import dataclasses
 import time
 from collections import OrderedDict
 from types import SimpleNamespace
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +63,9 @@ from . import transport as tp
 log = get_logger()
 
 _TPS_EWMA = 0.2  # tokens/s gauge smoothing
+# The per-lane bookkeeping of the decode state, and what ``release_lanes``
+# resets each entry of a finished or evicted lane to.
+_LANE_RESET = {"active": False, "n_pages": 0, "tail_len": 0, "page_table": -1}
 
 
 # ---------------------------------------------------------------------------
@@ -444,9 +451,82 @@ def _build_programs(server: GPT2Server) -> SimpleNamespace:
         return tuple(out)
 
     def prefill(params, tokens, positions, last_idx):
+        """Forward alone, every layer's K/V out: the prefill worker's
+        program (``serving/prefill.py`` ships the pages itself)."""
         srv = GPT2Server(server.cfg, params, sv)
         logits, ks, vs = srv.prefill_forward(tokens, positions, last_idx)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), ks, vs
+
+    observe_qerr = cfg_mod.qerr_stats()  # in the program key's fingerprint
+
+    def prefill_pages(params, pools, tokens, positions, last_idx, ids,
+                      tail_len):
+        """The local prefill of one padded prompt, whole: forward, then
+        every page of every layer's K/V through ``commit_page_rows`` into
+        the donated pools at ``ids (padded pages,)``, and the last page's
+        first ``tail_len`` rows as the lane's tails ``(L, page_tokens, H,
+        Dh) f32``, zero from ``tail_len`` on. A last page that is a tail
+        has the scratch row for its id, so one program serves every prompt
+        length under a padded length, whole pages or not. Also ``{layer:
+        its K rows as quantized}`` of the quantized layers, empty unless
+        ``CGX_QERR_STATS`` was on when the programs were built."""
+        first, ks, vs = prefill(params, tokens, positions, last_idx)
+        n_pages = ids.shape[0]
+        live = jax.lax.broadcasted_iota(
+            jnp.int32, (sv.page_tokens, 1, 1), 0
+        ) < tail_len
+        out, tail_k, tail_v, qerr_rows = [], [], [], {}
+        for layer in range(server.cfg.n_layer):
+            spec, pool = specs[layer], pools[layer]
+            k, v = ks[layer][0], vs[layer][0]  # (padded tokens, H, Dh)
+            rows_k = k.reshape(n_pages, -1)
+            out.append({
+                "k": paged_kv.commit_page_rows(
+                    pool["k"], ids, rows_k, spec
+                ),
+                "v": paged_kv.commit_page_rows(
+                    pool["v"], ids, v.reshape(n_pages, -1), spec
+                ),
+            })
+            tail_k.append(jnp.where(live, k[-sv.page_tokens:], 0.0))
+            tail_v.append(jnp.where(live, v[-sv.page_tokens:], 0.0))
+            if observe_qerr and spec.quantized:
+                qerr_rows[layer] = rows_k
+        return (
+            first, tuple(out), jnp.stack(tail_k), jnp.stack(tail_v),
+            qerr_rows,
+        )
+
+    def admit_lane(state, lane, table_row, n_pages, tail_len, token, pos,
+                   tail_k, tail_v):
+        """Write one ready request into lane ``lane`` of the donated
+        state: its page-table row, counts, first token and position, and
+        its stacked tails ``(L, page_tokens, H, Dh)``, device or host
+        arrays alike."""
+        out = dict(state)
+        for name, value in (
+            ("page_table", table_row), ("n_pages", n_pages),
+            ("tail_len", tail_len), ("tokens", token), ("pos", pos),
+            ("active", True),
+        ):
+            out[name] = state[name].at[lane].set(value)
+        for name, tails in (("tail_k", tail_k), ("tail_v", tail_v)):
+            out[name] = tuple(
+                t.at[lane].set(tails[layer])
+                for layer, t in enumerate(state[name])
+            )
+        return out
+
+    def release_lanes(lanes, mask):
+        """Reset the lane bookkeeping (``_LANE_RESET``) of the lanes in
+        ``mask (B,) bool``: finished or evicted, once a tick."""
+        return {
+            name: jnp.where(
+                mask.reshape((-1,) + (1,) * (value.ndim - 1)),
+                _LANE_RESET[name], value,
+            )
+            for name, value in lanes.items()
+        }
 
     return SimpleNamespace(
         specs=specs,
@@ -454,6 +534,9 @@ def _build_programs(server: GPT2Server) -> SimpleNamespace:
         commit=jax.jit(commit, donate_argnums=(0,)),
         ingest=jax.jit(ingest, donate_argnums=(0,)),
         prefill=jax.jit(prefill),
+        prefill_pages=jax.jit(prefill_pages, donate_argnums=(1,)),
+        admit_lane=jax.jit(admit_lane, donate_argnums=(0,)),
+        release_lanes=jax.jit(release_lanes, donate_argnums=(0,)),
     )
 
 
@@ -485,8 +568,11 @@ class _Ready:
 
     req: Request
     page_ids: List[int]
-    tail_k: np.ndarray  # (L, page_tokens, H, Dh) f32
-    tail_v: np.ndarray
+    # (L, page_tokens, H, Dh) f32, rows from ``tail_len`` on zero: device
+    # arrays from the local prefill, host arrays from a page stream — the
+    # ``admit_lane`` program takes either.
+    tail_k: Union[jax.Array, np.ndarray]
+    tail_v: Union[jax.Array, np.ndarray]
     tail_len: int
     first_token: int
     pos: int
@@ -532,6 +618,7 @@ class ContinuousBatchScheduler:
         self._ready: List[_Ready] = []
         self._frames: Dict[str, List[tp.PageFrame]] = {}
         self._done: List[Request] = []
+        self._released: List[int] = []  # lanes awaiting _release_lanes
         self._rekey_pending = False
         self._tokens_total = 0
         self._last_step_t: Optional[float] = None
@@ -642,6 +729,7 @@ class ContinuousBatchScheduler:
             self._remote.pop(stream)
             requeued += 1
         self._state = self._fresh_state()
+        self._released.clear()
         if requeued:
             log.info(
                 "serving scheduler reset (%s): %d request(s) requeued "
@@ -887,12 +975,13 @@ class ContinuousBatchScheduler:
         self, req: Request, prompt: np.ndarray, pids: List[int]
     ) -> _Ready:
         """The prefill of one request whose full pages ``pids`` are
-        reserved, in four phases, each under its own span. Only the tail
-        copies wait for the device, so their span holds the device time of
-        the three before them."""
-        cfg = self.server.cfg
-        pt = self.server.serve.page_tokens
-        h, d = self.server.n_head, self.server.d_head
+        reserved: one call of the ``prefill_pages`` program, which leaves
+        the pages in the pools and the tails on the device, then the one
+        wait of an admission, the read of the first token, whose span
+        holds the device time of the whole prefill."""
+        sv = self.server.serve
+        pt = sv.page_tokens
+        specs = self._prog.specs
         s, n_full = prompt.shape[0], len(pids)
         tail_len = s - n_full * pt
         queue_wait = time.monotonic() - req.submitted_at
@@ -906,72 +995,38 @@ class ContinuousBatchScheduler:
                 hist="cgx.serve.prefill_forward_s", req=req.id,
             ):
                 padded = _pad_prompt(prompt, pt)
-                first, ks, vs = self._prog.prefill(
-                    self.server.p, padded[None],
-                    np.arange(padded.shape[0], dtype=np.int32)[None],
-                    np.int32(s - 1),
-                )
-            tail_k = np.zeros((cfg.n_layer, pt, h, d), np.float32)
-            tail_v = np.zeros((cfg.n_layer, pt, h, d), np.float32)
-            if n_full:
-                with trace_span(
-                    "serve.prefill.quantize",
-                    hist="cgx.serve.prefill_quantize_s", req=req.id,
-                ):
-                    ids = jnp.asarray(pids, jnp.int32)
-                    layer_rows_k = []
-                    layer_rows_v = []
-                    for layer in range(cfg.n_layer):
-                        spec = self._prog.specs[layer]
-                        k_full = ks[layer][0, : n_full * pt].reshape(
-                            n_full, -1
-                        )
-                        v_full = vs[layer][0, : n_full * pt].reshape(
-                            n_full, -1
-                        )
-                        if spec.quantized:
-                            layer_rows_k.append(
-                                paged_kv.quantize_page_rows(k_full, spec)
-                            )
-                            layer_rows_v.append(
-                                paged_kv.quantize_page_rows(v_full, spec)
-                            )
-                            _observe_page_qerr(
-                                self.server.layer_name(layer), spec, k_full
-                            )
-                        else:
-                            layer_rows_k.append(k_full)
-                            layer_rows_v.append(v_full)
-                        _account_pages(
-                            self.server.layer_name(layer), spec, 2 * n_full
-                        )
-                with trace_span(
-                    "serve.prefill.ingest",
-                    hist="cgx.serve.prefill_ingest_s", req=req.id,
-                ):
-                    self._state = dict(
-                        self._state,
-                        pools=self._prog.ingest(
-                            self._state["pools"], layer_rows_k,
-                            layer_rows_v, ids,
-                        ),
+                # A last page that is a tail goes to the scratch row.
+                ids = np.full((padded.shape[0] // pt,), sv.max_pages,
+                              np.int32)
+                ids[:n_full] = pids
+                first, pools, tail_k, tail_v, qerr_rows = (
+                    self._prog.prefill_pages(
+                        self.server.p, self._state["pools"], padded[None],
+                        np.arange(padded.shape[0], dtype=np.int32)[None],
+                        np.int32(s - 1), ids, np.int32(tail_len),
                     )
-            if tail_len:
-                with trace_span(
-                    "serve.prefill.tail_copy",
-                    hist="cgx.serve.prefill_tail_copy_s", req=req.id,
-                ):
-                    for layer in range(cfg.n_layer):
-                        tail_k[layer, :tail_len] = np.asarray(
-                            ks[layer][0, n_full * pt: s]
-                        )
-                        tail_v[layer, :tail_len] = np.asarray(
-                            vs[layer][0, n_full * pt: s]
-                        )
+                )
+                self._state["pools"] = pools
+            # Host work the device's prefill hides.
+            if n_full:
+                for layer, spec in enumerate(specs):
+                    _account_pages(
+                        self.server.layer_name(layer), spec, 2 * n_full
+                    )
+            with trace_span(
+                "serve.prefill.first_token",
+                hist="cgx.serve.prefill_first_token_s", req=req.id,
+            ):
+                first_token = int(first[0])
+            for layer, rows in qerr_rows.items():
+                _observe_page_qerr(
+                    self.server.layer_name(layer), specs[layer],
+                    np.asarray(rows)[:n_full], already_host=True,
+                )
         metrics.add("cgx.serve.local_prefills")
         return _Ready(
             req=req, page_ids=pids, tail_k=tail_k, tail_v=tail_v,
-            tail_len=tail_len, first_token=int(first[0]), pos=s,
+            tail_len=tail_len, first_token=first_token, pos=s,
         )
 
     # -- admission / eviction ---------------------------------------------
@@ -1011,6 +1066,7 @@ class ContinuousBatchScheduler:
             ready = self._ready.pop(0)
             self._admit_lane(lane, ready)
             progressed = True
+        self._release_lanes()  # a first token can finish its request
         return progressed
 
     def _admit_lane(self, lane: int, ready: _Ready) -> None:
@@ -1022,22 +1078,13 @@ class ContinuousBatchScheduler:
             "serve.admit_lane", req=req.id, lane=int(lane),
             ready_wait_ms=round(ready_wait * 1e3, 3),
         ):
-            st = self._state
-            padded = np.full((sv.pages_per_seq,), -1, np.int32)
-            padded[: len(ready.page_ids)] = ready.page_ids
-            st["page_table"] = st["page_table"].at[lane].set(padded)
-            st["n_pages"] = st["n_pages"].at[lane].set(len(ready.page_ids))
-            st["tail_len"] = st["tail_len"].at[lane].set(ready.tail_len)
-            st["tokens"] = st["tokens"].at[lane].set(ready.first_token)
-            st["pos"] = st["pos"].at[lane].set(ready.pos)
-            st["active"] = st["active"].at[lane].set(True)
-            st["tail_k"] = tuple(
-                st["tail_k"][i].at[lane].set(ready.tail_k[i])
-                for i in range(self.server.cfg.n_layer)
-            )
-            st["tail_v"] = tuple(
-                st["tail_v"][i].at[lane].set(ready.tail_v[i])
-                for i in range(self.server.cfg.n_layer)
+            table_row = np.full((sv.pages_per_seq,), -1, np.int32)
+            table_row[: len(ready.page_ids)] = ready.page_ids
+            self._state = self._prog.admit_lane(
+                self._state, np.int32(lane), table_row,
+                np.int32(len(ready.page_ids)), np.int32(ready.tail_len),
+                np.int32(ready.first_token), np.int32(ready.pos),
+                ready.tail_k, ready.tail_v,
             )
             self._lanes[lane] = req
             # The prefill's own argmax IS the first generated token — the
@@ -1061,20 +1108,28 @@ class ContinuousBatchScheduler:
                 self._finish_lane(lane)
 
     def _finish_lane(self, lane: int) -> None:
+        """The host's half of a finished request; its lane's state is
+        reset by the tick's one :meth:`_release_lanes`."""
         req = self._lanes[lane]
         assert req is not None
         self.cache.free_seq(req.id)
         req.done = True
         self._done.append(req)
         self._lanes[lane] = None
-        st = self._state
-        st["active"] = st["active"].at[lane].set(False)
-        st["n_pages"] = st["n_pages"].at[lane].set(0)
-        st["tail_len"] = st["tail_len"].at[lane].set(0)
-        st["page_table"] = st["page_table"].at[lane].set(
-            np.full((self.server.serve.pages_per_seq,), -1, np.int32)
-        )
+        self._released.append(lane)
         metrics.add("cgx.serve.requests_completed")
+
+    def _release_lanes(self) -> None:
+        """One ``release_lanes`` call for the lanes that finished or were
+        evicted since the last one."""
+        if not self._released:
+            return
+        mask = np.zeros((self.server.serve.max_batch,), bool)
+        mask[self._released] = True
+        self._released.clear()
+        self._state.update(self._prog.release_lanes(
+            {name: self._state[name] for name in _LANE_RESET}, mask
+        ))
 
     # -- decode ------------------------------------------------------------
 
@@ -1108,11 +1163,12 @@ class ContinuousBatchScheduler:
                         req.first_token_at = None
                         self._waiting.append(req)
                         self._lanes[lane] = None
-                        st["active"] = st["active"].at[lane].set(False)
+                        self._released.append(lane)
                         continue
                     mask[lane] = True
                     pids[lane] = pid
                     committed.append(lane)
+                self._release_lanes()
                 if committed:
                     if cfg_mod.qerr_stats():
                         for layer in range(n_layer):
@@ -1159,6 +1215,7 @@ class ContinuousBatchScheduler:
                     sv.eos_token is not None and token == sv.eos_token
                 ):
                     self._finish_lane(lane)
+            self._release_lanes()
             self._note_tokens(len(active))
         return True
 
