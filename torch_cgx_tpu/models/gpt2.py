@@ -40,6 +40,10 @@ class GPT2Config:
     moe_capacity_factor: float = 1.25
     ep_axis: Optional[str] = None
 
+    def kv_bytes_per_token(self) -> int:
+        """float32 bytes of one token's K and V over all layers."""
+        return 2 * self.n_layer * self.d_model * 4
+
     @staticmethod
     def small(**kw):
         return GPT2Config(**kw)

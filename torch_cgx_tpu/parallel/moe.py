@@ -17,6 +17,12 @@ dispatch, shaped for the MXU and for GSPMD expert parallelism:
   says should collapse into the compiler.
 * **Load-balance auxiliary loss** (Switch-Transformer form) is sown under
   ``intermediates/moe_aux_loss``; collect with :func:`aux_loss`.
+* :func:`dropless_moe` — the serving plane's expert layer (DeepSeek-V3
+  form: sigmoid scores, a selection bias, normalised top-k weights times a
+  scaling factor): the token-expert assignments sorted by expert and every
+  expert's SwiGLU run over its own contiguous rows through
+  ``jax.lax.ragged_dot``. No capacity, so no token is ever dropped, at any
+  skew. Holds every expert on one device (no EP exchange yet).
 
 Composes with the quantized gradient allreduce: expert weights are regular
 pytree leaves, so per-layer compression configs apply (pattern
@@ -214,6 +220,65 @@ class MoEMlp(nn.Module):
             "tec,ecd->td", combine.astype(self.dtype), exp_out
         )
         return y.reshape(b, s, d)
+
+
+# What :func:`dropless_moe` counts, in the order of its ``stats`` vector.
+STATS = ("assignments", "experts_touched", "load_max", "dropped")
+
+
+def sigmoid_topk_route(y, router, bias, *, top_k: int, scale: float):
+    """``y (T, D)`` -> the chosen experts ``(T, top_k)`` int32 and their
+    combine weights ``(T, top_k)`` float32: scores ``sigmoid(y W_g)`` in
+    float32 at full matmul precision (a near-tie between the last expert
+    chosen and the first left out must not turn on the MXU's bf16 passes),
+    selection by ``score + bias``, weights the chosen scores normalised to
+    sum to 1, times ``scale``."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        y.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
+    return idx.astype(jnp.int32), weights
+
+
+def dropless_moe(y, router, bias, gate, up, down, *, top_k: int,
+                 scale: float, dtype=jnp.bfloat16, count_mask=None):
+    """``sum_i w_i E_i(y)`` over the ``top_k`` routed experts of every row
+    of ``y (T, D)``; experts ``gate, up (E, D, F)``, ``down (E, F, D)``,
+    each ``down(silu(gate y) * up y)``. Returns ``(out (T, D) dtype, stats
+    (4,) int32)``, ``stats`` as :data:`STATS` names them: assignments made,
+    experts that got at least one, the largest expert's load, and tokens
+    dropped (assignments asked for less assignments computed; 0 by
+    construction, counted all the same). ``count_mask (T,)`` bool leaves
+    rows (idle decode lanes) out of the counts; they are computed anyway."""
+    t, _ = y.shape
+    e = gate.shape[0]
+    idx, weights = sigmoid_topk_route(
+        y, router, bias, top_k=top_k, scale=scale
+    )
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)  # assignments, by expert
+    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    xs = y.astype(dtype)[order // top_k]  # (T * top_k, D)
+    h = jax.nn.silu(
+        jax.lax.ragged_dot(xs, gate.astype(dtype), sizes)
+    ) * jax.lax.ragged_dot(xs, up.astype(dtype), sizes)
+    rows = jax.lax.ragged_dot(h, down.astype(dtype), sizes)
+    rows = rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
+    out = jnp.zeros((t * top_k, y.shape[1]), jnp.float32).at[order].set(rows)
+    out = out.reshape(t, top_k, -1).sum(axis=1).astype(dtype)
+
+    counted = jnp.ones((t,), bool) if count_mask is None else count_mask
+    per_row = jnp.repeat(counted.astype(jnp.int32), top_k)
+    load = jnp.zeros((e,), jnp.int32).at[flat].add(per_row)
+    asked = jnp.sum(per_row)
+    stats = jnp.stack([
+        jnp.sum(load), jnp.sum(load > 0).astype(jnp.int32), jnp.max(load),
+        asked - jnp.sum(load),
+    ]).astype(jnp.int32)
+    return out, stats
 
 
 def moe_param_spec(path: str, leaf, axis: str = "ep") -> Optional[P]:

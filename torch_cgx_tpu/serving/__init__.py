@@ -12,9 +12,12 @@ inference:
 * :mod:`.transport` — disaggregated prefill→decode shipping of
   quantized pages over the shm/store bridge with publish-after-write
   counter streams (decode never blocks on prefill).
-* :mod:`.scheduler` — continuous-batching decode: admit/evict per step,
-  paged gather with the dequantize fused into the KV read, bounded
-  prefill-failover instead of wedging.
+* :mod:`.scheduler` — continuous-batching decode over a model adapter's
+  cache streams: admit/evict per step, paged gather with the dequantize
+  fused into the KV read, bounded prefill-failover instead of wedging;
+  the GPT-2 adapter (streams ``k``, ``v``).
+* :mod:`.latent` — the latent-attention (MLA) adapter with dropless
+  experts (streams ``c``, ``kr``).
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -28,5 +31,6 @@ from .scheduler import (  # noqa: F401
     ServeConfig,
     invalidate_decode_cache,
 )
+from .latent import LatentMoEServer  # noqa: F401
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401

@@ -1,0 +1,168 @@
+"""The latent-attention adapter: an MLA decoder with dropless experts
+(``models/mla_moe.py``) behind the one scheduler.
+
+A token leaves two cache streams a layer, shared by all heads and neither
+of them a key or a value: ``c``, the normalised latent (``kv_lora_rank``
+values), and ``kr``, the rotated position key (``d_rope`` values). They
+differ in width and in scale, so each is a stream of its own with its own
+pool, pages and buckets. Prefill takes the expanded attention (keys and
+values of the whole prompt rebuilt from ``c``, in query blocks); decode
+takes the absorbed one against the gathered latents, so no per-head key or
+value of a cached token is ever rebuilt.
+
+Page geometry is the streams' arithmetic (``ops/codec_pallas``: the flat
+Mosaic kernels want whole 32-bucket chunks a page): at 256 tokens a page
+and bucket 512, a ``c`` page of a 512-wide latent is 256 buckets (eight
+chunks, one bucket a token) and a ``kr`` page of a 64-wide key is 32
+buckets (one chunk, eight tokens a bucket).
+
+The disaggregated path ships K and V frames and refuses this adapter
+(``transport.require_kv_streams``); it is served with local prefill.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..models import mla_moe
+from ..models.mla_moe import MlaMoeConfig
+from ..ops import paged_kv
+from ..parallel import moe
+from .scheduler import ServeConfig, page_specs
+
+
+class LatentMoEServer:
+    """Model adapter (the protocol is in ``scheduler.py``) for one
+    ``(MlaMoeConfig, params)`` pair; cache streams ``c`` and ``kr``."""
+
+    kind = "mla_moe"
+    # What a decode step counts over its expert layers, as
+    # ``cgx.serve.<name>``: ``moe.STATS`` in order.
+    step_counters = tuple(f"moe.{name}" for name in moe.STATS)
+
+    def __init__(self, model_cfg: MlaMoeConfig, params,
+                 serve: Optional[ServeConfig] = None):
+        self.cfg = model_cfg
+        self.p = params
+        self.serve = serve or ServeConfig.from_env(model_cfg)
+        self.n_layer = model_cfg.n_layer
+        self.geometry = tuple(
+            (f.name, str(getattr(model_cfg, f.name)))
+            for f in dataclasses.fields(model_cfg)
+        )
+
+    def layer_name(self, layer: int) -> str:
+        return f"layer_{layer}"
+
+    def cache_streams(self, layer: int):
+        c, kr = page_specs(
+            self.layer_name(layer), self.serve.page_tokens,
+            [(1, self.cfg.kv_lora_rank), (1, self.cfg.d_rope)],
+        )
+        return (("c", c), ("kr", kr))
+
+    def with_params(self, params) -> "LatentMoEServer":
+        return LatentMoEServer(self.cfg, params, self.serve)
+
+    def kv_bytes_per_token(self) -> int:
+        return self.cfg.kv_bytes_per_token()
+
+    # -- forwards ----------------------------------------------------------
+
+    def _block_tail(self, x, pl, attn_out, count_mask=None):
+        """Output projection, residual, then the layer's feed-forward."""
+        cfg = self.cfg
+        x = x + mla_moe._mm(attn_out, pl["attn"]["o"], cfg.dtype)
+        out, stats = mla_moe.ffn(
+            cfg, pl, mla_moe.rms_norm(x, pl["ffn_norm"], cfg.eps),
+            count_mask=count_mask,
+        )
+        return x + out, stats
+
+    def prefill_forward(self, tokens, positions, last_idx):
+        """Full causal forward over a (right-padded) prompt: the logits at
+        ``last_idx``, then every layer's ``c (B, S, 1, Rkv)`` and ``kr (B,
+        S, 1, dr)`` f32. Right-padding is inert for every real position
+        under the causal mask; a padded token does go through the experts
+        (dropless: it takes no real token's place)."""
+        cfg = self.cfg
+        x = mla_moe.embed(cfg, self.p, tokens)
+        cs, krs = [], []
+        for layer in range(cfg.n_layer):
+            pl = self.p[f"layer_{layer}"]
+            y = mla_moe.rms_norm(x, pl["attn_norm"], cfg.eps)
+            q_nope, q_rope, c, k_r = mla_moe.mla_project(
+                cfg, y, pl["attn"], positions
+            )
+            cs.append(c[:, :, None])
+            krs.append(k_r[:, :, None])
+            o = mla_moe.attend_expanded(
+                cfg, pl["attn"], q_nope, q_rope, c, k_r
+            )
+            x, _ = self._block_tail(x, pl, o)
+        x_last = jax.lax.dynamic_index_in_dim(x, last_idx, 1)
+        return mla_moe.logits(cfg, self.p, x_last)[:, -1], cs, krs
+
+    def decode_forward(self, state, streams):
+        """One decode position against the paged latent cache: ``(logits
+        (B, V), the new tails by stream, moe.STATS summed over the expert
+        layers (``load_max`` their largest) counted over the active
+        lanes)``."""
+        cfg, dt = self.cfg, self.cfg.dtype
+        pt = self.serve.page_tokens
+        p_dim = self.serve.pages_per_seq
+        x = mla_moe.embed(cfg, self.p, state["tokens"][:, None])
+        positions = state["pos"][:, None]
+        b = x.shape[0]
+        tail_idx = jnp.minimum(state["tail_len"], pt - 1)
+        onehot = (
+            jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
+            == tail_idx[:, None]
+        )[:, :, None, None]
+        committed = state["n_pages"] * pt
+        pos_c = jax.lax.broadcasted_iota(jnp.int32, (b, p_dim * pt), 1)
+        pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
+        kv_mask = jnp.concatenate(
+            [pos_c < committed[:, None], pos_t <= tail_idx[:, None]], axis=1
+        )
+        new_tails = {"c": [], "kr": []}
+        counts = []
+        for layer in range(cfg.n_layer):
+            pl = self.p[f"layer_{layer}"]
+            y = mla_moe.rms_norm(x, pl["attn_norm"], cfg.eps)
+            q_nope, q_rope, c, k_r = mla_moe.mla_project(
+                cfg, y, pl["attn"], positions
+            )
+            gathered = {}
+            for (name, spec), new in zip(streams[layer], (c, k_r)):
+                tail = jnp.where(
+                    onehot, new[:, :, None].astype(jnp.float32),
+                    state[f"tail_{name}"][layer],
+                )
+                new_tails[name].append(tail)
+                pages = paged_kv.gather_dequant_pages(
+                    state["pools"][layer][name], state["page_table"], spec
+                )
+                gathered[name] = jnp.concatenate(
+                    [pages, tail], axis=1
+                )[:, :, 0].astype(dt)
+            o = mla_moe.attend_absorbed(
+                cfg, pl["attn"], q_nope[:, 0], q_rope[:, 0], gathered["c"],
+                gathered["kr"], kv_mask,
+            )
+            x, stats = self._block_tail(
+                x, pl, o[:, None], count_mask=state["active"]
+            )
+            if stats is not None:
+                counts.append(stats)
+        logits = mla_moe.logits(cfg, self.p, x)[:, -1]
+        counts = jnp.stack(counts)  # (expert layers, len(moe.STATS))
+        peak = moe.STATS.index("load_max")
+        summed = jnp.sum(counts, axis=0).at[peak].set(
+            jnp.max(counts[:, peak])
+        )
+        return logits, new_tails, summed

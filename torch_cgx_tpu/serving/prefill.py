@@ -29,7 +29,6 @@ from ..utils.logging import get_logger, metrics
 from ..utils.tracing import trace_span
 from . import transport as tp
 from .scheduler import (
-    GPT2Server,
     _account_pages,
     _observe_page_qerr,
     _resolved_specs,
@@ -46,7 +45,7 @@ class PrefillWorker:
 
     def __init__(
         self,
-        server: GPT2Server,
+        server,
         store,
         *,
         shm=None,
@@ -54,6 +53,7 @@ class PrefillWorker:
         transport_endpoint: str = "kvtx",
         transport_peers: Sequence[str] = ("kvrx",),
     ):
+        tp.require_kv_streams(server)  # the frames are K and V pages
         self.server = server
         # PR 20: with CGX_TRANSPORT=socket every KvPageSender this worker
         # creates ships its frames over the socket plane toward
@@ -76,14 +76,14 @@ class PrefillWorker:
         included). The sender thread keeps draining after this returns —
         call :meth:`stop` to join them all (bounded)."""
         self._reap_drained()
-        cfg = self.server.cfg
+        n_layer = self.server.n_layer
         sv = self.server.serve
         prompt = np.asarray(tokens, np.int32)
         s = prompt.shape[0]
         pt = sv.page_tokens
         n_full = s // pt
         tail_len = s - n_full * pt
-        frames = 1 + 2 * cfg.n_layer * n_full + 2 * cfg.n_layer
+        frames = 1 + 2 * n_layer * n_full + 2 * n_layer
         # Request-tagged prefill span (ISSUE 17): the critical-path
         # engine's TTFT decomposition joins it to the kv.ship stream
         # and the scheduler's submit/admit instants by ``req``.
@@ -108,7 +108,7 @@ class PrefillWorker:
             })
             for page in range(n_full):
                 lo, hi = page * pt, (page + 1) * pt
-                for layer in range(cfg.n_layer):
+                for layer in range(n_layer):
                     spec = specs[layer]
                     for kind, cache in ((tp.K_PAGE, ks), (tp.V_PAGE, vs)):
                         row = cache[layer][lo:hi].reshape(-1)
@@ -126,7 +126,7 @@ class PrefillWorker:
                     _account_pages(self.server.layer_name(layer), spec, 2)
             # The not-yet-full last page ships raw f16 (it is re-quantized
             # by the decode side only when it fills and commits).
-            for layer in range(cfg.n_layer):
+            for layer in range(n_layer):
                 for kind, cache in ((tp.K_TAIL, ks), (tp.V_TAIL, vs)):
                     vals = cache[layer][n_full * pt:].astype(np.float16)
                     sender.post_page(
@@ -159,7 +159,7 @@ class PrefillWorker:
         self._senders.clear()
 
 
-def _prefill_forward(server: GPT2Server, prompt: np.ndarray):
+def _prefill_forward(server, prompt: np.ndarray):
     """(first_token, ks, vs): the full forward's greedy argmax and the
     per-layer K/V as host arrays ``(S, H, Dh) f32`` — jitted through the
     server's own program (prompts pad to a page multiple, so prefill and
@@ -169,15 +169,15 @@ def _prefill_forward(server: GPT2Server, prompt: np.ndarray):
     prog = sched_mod._decode_program(server)
     s = prompt.shape[0]
     padded = sched_mod._pad_prompt(prompt, server.serve.page_tokens)
-    first, ks, vs = prog.prefill(
+    first, payloads = prog.prefill(
         server.p, padded[None],
         np.arange(padded.shape[0], dtype=np.int32)[None],
         np.int32(s - 1),
     )
     return (
         int(np.asarray(first)[0]),
-        [np.asarray(k[0, :s], np.float32) for k in ks],
-        [np.asarray(v[0, :s], np.float32) for v in vs],
+        [np.asarray(k[0, :s], np.float32) for k in payloads["k"]],
+        [np.asarray(v[0, :s], np.float32) for v in payloads["v"]],
     )
 
 

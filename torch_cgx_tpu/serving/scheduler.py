@@ -1,5 +1,13 @@
 """Continuous-batching decode scheduler over the paged quantized KV pool.
 
+The scheduler serves a MODEL ADAPTER (the protocol is written out above
+:class:`GPT2Server`): the adapter states, per layer, the cache streams a
+token leaves behind as ``(name, PageSpec)`` — GPT-2's ``k`` and ``v``, a
+latent-attention model's latent and rotated key (``serving/latent.py``) —
+and gives a prefill and a decode forward over them. Pools, tails and every
+compiled program below go over the streams the adapter names; nothing here
+knows what a stream means.
+
 The decode worker runs ONE compiled step program: for every lane of a
 fixed ``CGX_SERVE_MAX_BATCH``-wide batch, gather the lane's committed KV
 pages (``ops/paged_kv.gather_dequant_pages`` — the dequantize staged
@@ -23,8 +31,8 @@ wedging admission (``cgx.serve.prefill_failovers`` — the serving plane's
 recovery-ladder rung; docs/SERVING.md).
 
 The compiled decode/commit/prefill programs live in a module-level LRU
-(``_PROGRAM_CACHE``) keyed by :func:`_program_key` — model geometry,
-serve geometry, the per-layer resolved ``kv_page`` wire configs
+(``_PROGRAM_CACHE``) keyed by :func:`_program_key` — the adapter's kind
+and model geometry, serve geometry, the per-layer resolved ``kv_page`` wire configs
 (registry-versioned) and ``config.trace_knob_fingerprint()``, so a knob
 flip or an SLO-controller re-solve can never hit a stale staged decode
 step (the ISSUE 14/15 knob→cache-key completeness contract; the cache is
@@ -95,23 +103,24 @@ class ServeConfig:
         return -(-self.max_seq // self.page_tokens)
 
     @classmethod
-    def from_env(cls, model_cfg: Optional[GPT2Config] = None,
+    def from_env(cls, model=None,
                  eos_token: Optional[int] = None) -> "ServeConfig":
         """Knobs with the planner filling the zeros: ``CGX_KV_PAGE_TOKENS``
         / ``CGX_KV_SHIP_DEPTH`` unset lets ``planner.solve_serve_plan``
-        pick page size and shipping depth from the serve cost curves
-        (model geometry needed for the per-token KV bytes; without a
-        model config the static defaults apply)."""
+        pick page size and shipping depth from the serve cost curves.
+        ``model`` (an adapter or its model config: anything with
+        ``n_layer`` and ``kv_bytes_per_token()``) says what a token's
+        cache weighs, which differs sevenfold between a K/V cache and a
+        latent one; without it the static defaults apply."""
         pt = cfg_mod.kv_page_tokens()
         depth = cfg_mod.kv_ship_depth()
-        if (not pt or not depth) and model_cfg is not None:
+        if (not pt or not depth) and model is not None:
             from ..parallel import planner
 
-            kv_per_token = 2 * model_cfg.n_layer * model_cfg.d_model * 4
             plan = planner.solve_serve_plan(
                 prompt_tokens=min(cfg_mod.serve_max_seq(), 128),
-                kv_token_bytes=kv_per_token,
-                n_layers=model_cfg.n_layer,
+                kv_token_bytes=model.kv_bytes_per_token(),
+                n_layers=model.n_layer,
                 bits=cfg_mod.kv_bits(),
                 bucket=cfg_mod.default_compression_config().bucket_size,
             )
@@ -142,10 +151,54 @@ class Request:
 
 
 # ---------------------------------------------------------------------------
+# Model adapters. What the scheduler and its programs ask of one:
+#
+#   kind            a name for the program key ("gpt2", "mla_moe")
+#   geometry        hashable model geometry, for the program key
+#   n_layer, serve, p (the parameter tree, an argument of every program)
+#   step_counters   names under ``cgx.serve.`` of what ``decode_forward``
+#                   counts each step (empty for a model that counts nothing)
+#   layer_name(l)   the layer's ``kv_page`` edge name
+#   cache_streams(l)  the layer's cache streams, ``((name, PageSpec), ...)``,
+#                   built with :func:`page_specs`; every layer names the same
+#                   streams in the same order
+#   with_params(p)  the adapter over another (traced) parameter tree
+#   kv_bytes_per_token()  float32 bytes a token's cache weighs, all layers
+#   prefill_forward(tokens, positions, last_idx) -> (logits (B, V), then one
+#                   list per stream, in ``cache_streams`` order, of each
+#                   layer's (B, S, n_head, d_head) f32 cache payload)
+#   decode_forward(state, streams) -> (logits (B, V), {stream: [each
+#                   layer's new tail (B, page_tokens, n_head, d_head)]},
+#                   int32 vector of ``step_counters`` or None); ``state``
+#                   holds ``pools[l][stream]`` and ``tail_<stream>[l]``
+#
 # The GPT-2 adapter: explicit-parameter forward passes over the module's
 # own parameter tree (models/gpt2.py) — decode against the paged cache
 # needs per-layer K/V in and out, which the flax module doesn't expose.
 # ---------------------------------------------------------------------------
+
+
+def page_specs(layer_name: str, page_tokens: int,
+               shapes: Sequence[Tuple[int, int]]) -> List[paged_kv.PageSpec]:
+    """The page geometry of a layer's cache streams, one per ``(n_head,
+    d_head)`` of ``shapes``, under the CURRENT ``kv_page`` resolution of the
+    layer (resolved once: this runs every tick, for the program key): the
+    registered edge configs (the SLO controller's writes) or the
+    ``CGX_KV_BITS`` env default decide bits; the bucket is the resolved
+    config's (env-back-filled) bucket clipped to each stream's page
+    payload."""
+    cc = kv_mod.resolve_kv_config(layer_name)
+    if cc is None or not cc.enabled:
+        return [paged_kv.PageSpec(page_tokens, h, d, bits=0, bucket_size=1)
+                for h, d in shapes]
+    return [
+        paged_kv.PageSpec(
+            page_tokens, h, d, bits=cc.bits,
+            bucket_size=paged_kv.default_bucket(page_tokens * h * d,
+                                                cc.bucket_size),
+        )
+        for h, d in shapes
+    ]
 
 
 def _ln(x, scale, bias, eps=1e-6):
@@ -164,22 +217,47 @@ def _dense(x, w, b, dtype):
 
 
 class GPT2Server:
-    """Model adapter: prefill/decode forwards + serving geometry for one
-    (GPT2Config, params) pair. Dense-MLP decoder models only (the
-    serving plane's flagship path; MoE decode needs its own dispatch)."""
+    """The GPT-2 adapter: prefill/decode forwards + serving geometry for
+    one (GPT2Config, params) pair, cache streams ``k`` and ``v``. It is
+    the dense-MLP GPT-2 block and nothing else; a model with experts is
+    served by an adapter that has them (``serving/latent.py``)."""
+
+    kind = "gpt2"
+    step_counters = ()
 
     def __init__(self, model_cfg: GPT2Config, params,
                  serve: Optional[ServeConfig] = None):
         if model_cfg.n_experts:
-            raise ValueError("GPT2Server serves dense-MLP configs only")
+            raise ValueError(
+                "GPT2Server is the dense-MLP GPT-2 adapter: it has no "
+                f"expert layer for n_experts={model_cfg.n_experts} (the "
+                "serving plane serves experts through "
+                "serving.latent.LatentMoEServer)"
+            )
         self.cfg = model_cfg
         self.p = params.get("params", params)
         self.serve = serve or ServeConfig.from_env(model_cfg)
+        self.n_layer = model_cfg.n_layer
         self.n_head = model_cfg.n_head
         self.d_head = model_cfg.d_model // model_cfg.n_head
+        self.geometry = (
+            model_cfg.n_layer, model_cfg.n_head, model_cfg.d_model,
+            model_cfg.vocab_size, model_cfg.max_seq, str(model_cfg.dtype),
+        )
 
     def layer_name(self, layer: int) -> str:
         return f"layer_{layer}"
+
+    def cache_streams(self, layer: int):
+        (spec,) = page_specs(self.layer_name(layer), self.serve.page_tokens,
+                             [(self.n_head, self.d_head)])
+        return (("k", spec), ("v", spec))
+
+    def with_params(self, params) -> "GPT2Server":
+        return GPT2Server(self.cfg, params, self.serve)
+
+    def kv_bytes_per_token(self) -> int:
+        return self.cfg.kv_bytes_per_token()
 
     # -- forwards ----------------------------------------------------------
 
@@ -245,11 +323,11 @@ class GPT2Server:
         x_last = jax.lax.dynamic_index_in_dim(x, last_idx, 1)
         return self._logits(x_last)[:, -1], ks, vs
 
-    def decode_forward(self, state, specs: Tuple[paged_kv.PageSpec, ...]):
+    def decode_forward(self, state, streams):
         """One decode position against the paged cache: current tokens at
         their positions, KV read = gathered committed pages (dequantized
         at the consumer) + the raw tail with this token's K/V appended.
-        Returns (logits (B, vocab), new tail_k/tail_v lists)."""
+        Returns (logits (B, vocab), the new tails by stream, None)."""
         cfg = self.cfg
         pt = self.serve.page_tokens
         p_dim = self.serve.pages_per_seq
@@ -280,12 +358,12 @@ class GPT2Server:
                            state["tail_v"][layer])
             new_tk.append(tk)
             new_tv.append(tv)
-            pool = state["pools"][layer]
+            pool, spec = state["pools"][layer], streams[layer][0][1]
             kc = paged_kv.gather_dequant_pages(
-                pool["k"], state["page_table"], specs[layer]
+                pool["k"], state["page_table"], spec
             )
             vc = paged_kv.gather_dequant_pages(
-                pool["v"], state["page_table"], specs[layer]
+                pool["v"], state["page_table"], spec
             )
             k_all = jnp.concatenate([kc, tk], axis=1).transpose(
                 0, 2, 1, 3
@@ -296,7 +374,7 @@ class GPT2Server:
             o = decode_attention(q, k_all, v_all, kv_mask=kv_mask)
             o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.d_model)
             x = self._block_tail(x, pl, o)
-        return self._logits(x)[:, -1], new_tk, new_tv
+        return self._logits(x)[:, -1], {"k": new_tk, "v": new_tv}, None
 
 
 # ---------------------------------------------------------------------------
@@ -304,46 +382,52 @@ class GPT2Server:
 # ---------------------------------------------------------------------------
 
 
-def _resolved_specs(server: GPT2Server) -> Tuple[paged_kv.PageSpec, ...]:
-    """Per-layer page specs under the CURRENT kv_page resolution: the
-    registered edge configs (the SLO controller's writes) or the
-    ``CGX_KV_BITS`` env default decide bits; the bucket is the resolved
-    config's (env-back-filled) bucket clipped to the page payload."""
-    flat = server.serve.page_tokens * server.cfg.d_model
-    specs = []
-    for layer in range(server.cfg.n_layer):
-        cc = kv_mod.resolve_kv_config(server.layer_name(layer))
-        if cc is None:
-            specs.append(paged_kv.PageSpec(
-                page_tokens=server.serve.page_tokens,
-                n_head=server.n_head, d_head=server.d_head,
-                bits=0, bucket_size=1,
-            ))
-        else:
-            specs.append(paged_kv.PageSpec(
-                page_tokens=server.serve.page_tokens,
-                n_head=server.n_head, d_head=server.d_head,
-                bits=cc.bits if cc.enabled else 0,
-                bucket_size=paged_kv.default_bucket(flat, cc.bucket_size),
-            ))
-    return tuple(specs)
+def _resolved_streams(server) -> Tuple:
+    """Every layer's cache streams ``((name, PageSpec), ...)`` under the
+    CURRENT kv_page resolution (:func:`page_specs`), as the adapter states
+    them; all layers have to name the same streams."""
+    streams = tuple(
+        tuple(server.cache_streams(layer)) for layer in range(server.n_layer)
+    )
+    names = _stream_names(streams)
+    for layer, layer_streams in enumerate(streams):
+        if tuple(n for n, _ in layer_streams) != names:
+            raise ValueError(
+                f"adapter {server.kind!r}: layer {layer} names the cache "
+                f"streams {[n for n, _ in layer_streams]}, layer 0 {names}"
+            )
+    return streams
 
 
-def _program_key(server: GPT2Server) -> Tuple:
-    """Everything the compiled serving programs bake in: model + serve
-    geometry, the per-layer resolved wire specs (covering the edge
-    registry through both the resolved values AND the registry version —
-    a re-registration that resolves identically keeps the key), and the
-    trace-affecting env knobs (``trace_knob_fingerprint`` carries the
-    CGX_KV_*/CGX_SERVE_* serving subset plus the codec-lowering knobs
-    the staged dequantize consumes)."""
-    cfg = server.cfg
+def _stream_names(streams) -> Tuple[str, ...]:
+    return tuple(name for name, _ in streams[0])
+
+
+def _leading_specs(streams) -> Tuple[paged_kv.PageSpec, ...]:
+    """Each layer's leading stream's spec: the layer's wire resolution
+    (bits are per layer; GPT-2's ``k`` and ``v`` share the whole spec)."""
+    return tuple(layer[0][1] for layer in streams)
+
+
+def _resolved_specs(server) -> Tuple[paged_kv.PageSpec, ...]:
+    return _leading_specs(_resolved_streams(server))
+
+
+def _program_key(server) -> Tuple:
+    """Everything the compiled serving programs bake in: the adapter's
+    kind and model geometry, serve geometry, the per-layer resolved cache
+    streams (covering the edge registry through both the resolved values
+    AND the registry version — a re-registration that resolves identically
+    keeps the key), and the trace-affecting env knobs
+    (``trace_knob_fingerprint`` carries the CGX_KV_*/CGX_SERVE_* serving
+    subset plus the codec-lowering knobs the staged dequantize
+    consumes)."""
     return (
-        (cfg.n_layer, cfg.n_head, cfg.d_model, cfg.vocab_size,
-         cfg.max_seq, str(cfg.dtype)),
+        server.kind,
+        server.geometry,
         (server.serve.page_tokens, server.serve.max_batch,
          server.serve.max_pages, server.serve.max_seq),
-        _resolved_specs(server),
+        _resolved_streams(server),
         cfg_mod.registry_version(),
         cfg_mod.trace_knob_fingerprint(),
     )
@@ -363,7 +447,7 @@ def invalidate_decode_cache(reason: str = "reconfigure") -> None:
     log.info("serving decode-program cache invalidated (%s)", reason)
 
 
-def _decode_program(server: GPT2Server) -> SimpleNamespace:
+def _decode_program(server) -> SimpleNamespace:
     """The compiled serving programs for this server's current key —
     from the LRU, building on miss."""
     key = _program_key(server)
@@ -380,23 +464,30 @@ def _decode_program(server: GPT2Server) -> SimpleNamespace:
     return prog
 
 
-def _build_programs(server: GPT2Server) -> SimpleNamespace:
-    specs = _resolved_specs(server)
+def _build_programs(server) -> SimpleNamespace:
+    streams = _resolved_streams(server)
+    names = _stream_names(streams)
+    n_layer = server.n_layer
     sv = server.serve
 
     def decode_step(params, state):
-        srv = GPT2Server(server.cfg, params, sv)
-        logits, new_tk, new_tv = srv.decode_forward(state, specs)
+        """One token for every lane. Returns the new state and what the
+        host reads each tick, in one array: the lanes' next tokens, then
+        the adapter's ``step_counters`` (none for GPT-2)."""
+        srv = server.with_params(params)
+        logits, new_tails, counts = srv.decode_forward(state, streams)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         out = dict(state)
-        out["tail_k"] = tuple(new_tk)
-        out["tail_v"] = tuple(new_tv)
+        for name in names:
+            out[f"tail_{name}"] = tuple(new_tails[name])
         out["tail_len"] = jnp.where(
             state["active"], state["tail_len"] + 1, state["tail_len"]
         )
         out["pos"] = jnp.where(state["active"], state["pos"] + 1,
                                state["pos"])
         out["tokens"] = jnp.where(state["active"], nxt, state["tokens"])
+        if counts is not None:
+            nxt = jnp.concatenate([nxt, counts.astype(jnp.int32)])
         return out, nxt
 
     def commit(state, commit_mask, page_ids):
@@ -407,20 +498,16 @@ def _build_programs(server: GPT2Server) -> SimpleNamespace:
         b = commit_mask.shape[0]
         ids = jnp.where(commit_mask, page_ids, sv.max_pages)
         out = dict(state)
-        pools = []
-        for layer in range(server.cfg.n_layer):
-            pool = state["pools"][layer]
-            rows_k = state["tail_k"][layer].reshape(b, -1)
-            rows_v = state["tail_v"][layer].reshape(b, -1)
-            pools.append({
-                "k": paged_kv.commit_page_rows(
-                    pool["k"], ids, rows_k, specs[layer]
-                ),
-                "v": paged_kv.commit_page_rows(
-                    pool["v"], ids, rows_v, specs[layer]
-                ),
-            })
-        out["pools"] = tuple(pools)
+        out["pools"] = tuple(
+            {
+                name: paged_kv.commit_page_rows(
+                    state["pools"][layer][name], ids,
+                    state[f"tail_{name}"][layer].reshape(b, -1), spec,
+                )
+                for name, spec in streams[layer]
+            }
+            for layer in range(n_layer)
+        )
         p_iota = jax.lax.broadcasted_iota(
             jnp.int32, state["page_table"].shape, 1
         )
@@ -432,77 +519,77 @@ def _build_programs(server: GPT2Server) -> SimpleNamespace:
         out["tail_len"] = jnp.where(commit_mask, 0, state["tail_len"])
         return out
 
-    def ingest(pools, layer_rows_k, layer_rows_v, ids):
+    def ingest(pools, layer_rows, ids):
         """Batch-write received/locally-prefetched page payload rows
-        (n, flat) into pool rows ``ids (n,)`` for every layer — the
-        stream-completion path (payloads already in pool layout when
-        quantized)."""
-        out = []
-        for layer in range(server.cfg.n_layer):
-            pool = pools[layer]
-            out.append({
-                "k": _ingest_pool(
-                    pool["k"], ids, layer_rows_k[layer], specs[layer]
-                ),
-                "v": _ingest_pool(
-                    pool["v"], ids, layer_rows_v[layer], specs[layer]
-                ),
-            })
-        return tuple(out)
+        (n, flat) into pool rows ``ids (n,)`` for every layer and stream
+        (``layer_rows[layer][stream]``) — the stream-completion path
+        (payloads already in pool layout when quantized)."""
+        return tuple(
+            {
+                name: _ingest_pool(
+                    pools[layer][name], ids, layer_rows[layer][name], spec
+                )
+                for name, spec in streams[layer]
+            }
+            for layer in range(n_layer)
+        )
 
     def prefill(params, tokens, positions, last_idx):
-        """Forward alone, every layer's K/V out: the prefill worker's
-        program (``serving/prefill.py`` ships the pages itself)."""
-        srv = GPT2Server(server.cfg, params, sv)
-        logits, ks, vs = srv.prefill_forward(tokens, positions, last_idx)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), ks, vs
+        """Forward alone, every layer's cache payload out by stream: the
+        prefill worker's program (``serving/prefill.py`` ships the pages
+        itself)."""
+        srv = server.with_params(params)
+        logits, *payloads = srv.prefill_forward(tokens, positions, last_idx)
+        return (
+            jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            dict(zip(names, payloads)),
+        )
 
     observe_qerr = cfg_mod.qerr_stats()  # in the program key's fingerprint
 
     def prefill_pages(params, pools, tokens, positions, last_idx, ids,
                       tail_len):
         """The local prefill of one padded prompt, whole: forward, then
-        every page of every layer's K/V through ``commit_page_rows`` into
-        the donated pools at ``ids (padded pages,)``, and the last page's
-        first ``tail_len`` rows as the lane's tails ``(L, page_tokens, H,
-        Dh) f32``, zero from ``tail_len`` on. A last page that is a tail
-        has the scratch row for its id, so one program serves every prompt
-        length under a padded length, whole pages or not. Also ``{layer:
-        its K rows as quantized}`` of the quantized layers, empty unless
-        ``CGX_QERR_STATS`` was on when the programs were built."""
-        first, ks, vs = prefill(params, tokens, positions, last_idx)
+        every page of every layer's streams through ``commit_page_rows``
+        into the donated pools at ``ids (padded pages,)``, and the last
+        page's first ``tail_len`` rows as the lane's tails ``{stream: (L,
+        page_tokens, H, Dh) f32}``, zero from ``tail_len`` on. A last page
+        that is a tail has the scratch row for its id, so one program
+        serves every prompt length under a padded length, whole pages or
+        not. Also ``{layer: its leading stream's rows as quantized}`` of the
+        quantized layers, empty unless ``CGX_QERR_STATS`` was on when the
+        programs were built."""
+        first, payloads = prefill(params, tokens, positions, last_idx)
         n_pages = ids.shape[0]
         live = jax.lax.broadcasted_iota(
             jnp.int32, (sv.page_tokens, 1, 1), 0
         ) < tail_len
-        out, tail_k, tail_v, qerr_rows = [], [], [], {}
-        for layer in range(server.cfg.n_layer):
-            spec, pool = specs[layer], pools[layer]
-            k, v = ks[layer][0], vs[layer][0]  # (padded tokens, H, Dh)
-            rows_k = k.reshape(n_pages, -1)
-            out.append({
-                "k": paged_kv.commit_page_rows(
-                    pool["k"], ids, rows_k, spec
-                ),
-                "v": paged_kv.commit_page_rows(
-                    pool["v"], ids, v.reshape(n_pages, -1), spec
-                ),
-            })
-            tail_k.append(jnp.where(live, k[-sv.page_tokens:], 0.0))
-            tail_v.append(jnp.where(live, v[-sv.page_tokens:], 0.0))
-            if observe_qerr and spec.quantized:
-                qerr_rows[layer] = rows_k
+        out, tails, qerr_rows = [], {name: [] for name in names}, {}
+        for layer in range(n_layer):
+            pool, written = pools[layer], {}
+            for name, spec in streams[layer]:
+                x = payloads[name][layer][0]  # (padded tokens, H, Dh)
+                rows = x.reshape(n_pages, -1)
+                written[name] = paged_kv.commit_page_rows(
+                    pool[name], ids, rows, spec
+                )
+                tails[name].append(
+                    jnp.where(live, x[-sv.page_tokens:], 0.0)
+                )
+                if observe_qerr and spec.quantized and name == names[0]:
+                    qerr_rows[layer] = rows
+            out.append(written)
         return (
-            first, tuple(out), jnp.stack(tail_k), jnp.stack(tail_v),
-            qerr_rows,
+            first, tuple(out),
+            {name: jnp.stack(t) for name, t in tails.items()}, qerr_rows,
         )
 
     def admit_lane(state, lane, table_row, n_pages, tail_len, token, pos,
-                   tail_k, tail_v):
+                   tails):
         """Write one ready request into lane ``lane`` of the donated
         state: its page-table row, counts, first token and position, and
-        its stacked tails ``(L, page_tokens, H, Dh)``, device or host
-        arrays alike."""
+        its stacked tails ``{stream: (L, page_tokens, H, Dh)}``, device or
+        host arrays alike."""
         out = dict(state)
         for name, value in (
             ("page_table", table_row), ("n_pages", n_pages),
@@ -510,10 +597,10 @@ def _build_programs(server: GPT2Server) -> SimpleNamespace:
             ("active", True),
         ):
             out[name] = state[name].at[lane].set(value)
-        for name, tails in (("tail_k", tail_k), ("tail_v", tail_v)):
-            out[name] = tuple(
-                t.at[lane].set(tails[layer])
-                for layer, t in enumerate(state[name])
+        for name in names:
+            out[f"tail_{name}"] = tuple(
+                t.at[lane].set(tails[name][layer])
+                for layer, t in enumerate(state[f"tail_{name}"])
             )
         return out
 
@@ -529,7 +616,9 @@ def _build_programs(server: GPT2Server) -> SimpleNamespace:
         }
 
     return SimpleNamespace(
-        specs=specs,
+        streams=streams,
+        names=names,
+        specs=_leading_specs(streams),
         decode_step=jax.jit(decode_step, donate_argnums=(1,)),
         commit=jax.jit(commit, donate_argnums=(0,)),
         ingest=jax.jit(ingest, donate_argnums=(0,)),
@@ -568,11 +657,10 @@ class _Ready:
 
     req: Request
     page_ids: List[int]
-    # (L, page_tokens, H, Dh) f32, rows from ``tail_len`` on zero: device
-    # arrays from the local prefill, host arrays from a page stream — the
-    # ``admit_lane`` program takes either.
-    tail_k: Union[jax.Array, np.ndarray]
-    tail_v: Union[jax.Array, np.ndarray]
+    # {stream: (L, page_tokens, H, Dh) f32}, rows from ``tail_len`` on
+    # zero: device arrays from the local prefill, host arrays from a page
+    # stream — the ``admit_lane`` program takes either.
+    tails: Dict[str, Union[jax.Array, np.ndarray]]
     tail_len: int
     first_token: int
     pos: int
@@ -582,7 +670,8 @@ class _Ready:
 
 
 class ContinuousBatchScheduler:
-    """Admit/evict-per-step decode over one :class:`GPT2Server`.
+    """Admit/evict-per-step decode over one model adapter
+    (:class:`GPT2Server`, ``latent.LatentMoEServer``).
 
     ``receiver`` (optional :class:`~.transport.KvPageReceiver`) is the
     disaggregated mode: ``submit(req, remote=True)`` registers the
@@ -595,12 +684,14 @@ class ContinuousBatchScheduler:
 
     def __init__(
         self,
-        server: GPT2Server,
+        server,
         *,
         receiver: Optional[tp.KvPageReceiver] = None,
     ):
         self.server = server
         sv = server.serve
+        if receiver is not None:
+            tp.require_kv_streams(server)
         self._receiver = receiver
         # A pure-serving process never touches the train paths that
         # start the memory ledger, yet its KV pool is a primary ledger
@@ -628,28 +719,29 @@ class ContinuousBatchScheduler:
 
     def _fresh_state(self) -> Dict:
         sv = self.server.serve
-        specs = self._prog.specs
-        b, pt = sv.max_batch, sv.page_tokens
-        h, d = self.server.n_head, self.server.d_head
+        streams = self._prog.streams
+        b = sv.max_batch
         pools = tuple(
             {
                 # +1 row: the masked-commit scratch row (see commit()).
-                "k": paged_kv.empty_pool(sv.max_pages + 1, specs[i]),
-                "v": paged_kv.empty_pool(sv.max_pages + 1, specs[i]),
+                name: paged_kv.empty_pool(sv.max_pages + 1, spec)
+                for name, spec in layer
             }
-            for i in range(self.server.cfg.n_layer)
+            for layer in streams
         )
-        zeros_tail = tuple(
-            jnp.zeros((b, pt, h, d), jnp.float32)
-            for _ in range(self.server.cfg.n_layer)
-        )
+        tails = {
+            f"tail_{name}": tuple(
+                jnp.zeros(
+                    (b, spec.page_tokens, spec.n_head, spec.d_head),
+                    jnp.float32,
+                )
+                for spec in (dict(layer)[name] for layer in streams)
+            )
+            for name in self._prog.names
+        }
         return {
             "pools": pools,
-            "tail_k": zeros_tail,
-            "tail_v": tuple(
-                jnp.zeros((b, pt, h, d), jnp.float32)
-                for _ in range(self.server.cfg.n_layer)
-            ),
+            **tails,
             "page_table": jnp.full(
                 (b, sv.pages_per_seq), -1, jnp.int32
             ),
@@ -867,12 +959,12 @@ class ContinuousBatchScheduler:
                        frames: Sequence[tp.PageFrame]) -> None:
         """Turn a completed page stream into a ready lane payload: pool
         rows written in one batched scatter per layer, tail + first
-        token from the META frame."""
-        specs = self._prog.specs
-        cfg = self.server.cfg
-        sv = self.server.serve
-        pt = sv.page_tokens
-        h, d = self.server.n_head, self.server.d_head
+        token from the META frame. The transport's frames are K and V
+        pages and tails (``tp.STREAM_OF_KIND``); an adapter with other
+        streams never gets a receiver (``tp.require_kv_streams``)."""
+        streams = self._prog.streams
+        n_layer = self.server.n_layer
+        pt = self.server.serve.page_tokens
         n_pages = int(meta["pages"])
         if int(meta.get("page_tokens", pt)) != pt:
             raise ValueError(
@@ -886,16 +978,24 @@ class ContinuousBatchScheduler:
                 self.cache.free_seq(req.id)
                 raise RuntimeError("KV pool exhausted during ingest")
             page_ids.append(pid)
-        rows_k: List[List] = [[None] * n_pages for _ in range(cfg.n_layer)]
-        rows_v: List[List] = [[None] * n_pages for _ in range(cfg.n_layer)]
-        tail_k = np.zeros((cfg.n_layer, pt, h, d), np.float32)
-        tail_v = np.zeros((cfg.n_layer, pt, h, d), np.float32)
+        rows = [
+            {name: [None] * n_pages for name, _ in streams[layer]}
+            for layer in range(n_layer)
+        ]
+        tails = {
+            name: np.zeros(
+                (n_layer, pt, spec.n_head, spec.d_head), np.float32
+            )
+            for name, spec in streams[0]
+        }
         tail_len = int(meta.get("tail_tokens", 0))
+        spec_of = [dict(layer) for layer in streams]
         for f in frames:
             if f.is_meta:
                 continue
-            spec = specs[f.layer]
-            if f.kind in (tp.K_PAGE, tp.V_PAGE):
+            name, is_page = tp.STREAM_OF_KIND[f.kind]
+            spec = spec_of[f.layer][name]
+            if is_page:
                 if f.bits != spec.bits or (
                     spec.quantized and f.bucket != spec.bucket_size
                 ):
@@ -906,42 +1006,46 @@ class ContinuousBatchScheduler:
                         f"bucket={spec.bucket_size}) — prefill and "
                         "decode must resolve the same kv_page configs"
                     )
-                row = _decode_page_payload(f, spec)
-                (rows_k if f.kind == tp.K_PAGE else rows_v)[
-                    f.layer][f.page_idx] = row
+                rows[f.layer][name][f.page_idx] = _decode_page_payload(
+                    f, spec
+                )
             else:  # tail
                 vals = np.frombuffer(f.payload, np.float16).astype(
                     np.float32
-                ).reshape(-1, h, d)
-                t = (tail_k if f.kind == tp.K_TAIL else tail_v)
-                t[f.layer, : vals.shape[0]] = vals
+                ).reshape(-1, spec.n_head, spec.d_head)
+                tails[name][f.layer, : vals.shape[0]] = vals
         if n_pages:
-            layer_rows_k = [_stack_rows(rows_k[i], specs[i])
-                            for i in range(cfg.n_layer)]
-            layer_rows_v = [_stack_rows(rows_v[i], specs[i])
-                            for i in range(cfg.n_layer)]
+            layer_rows = [
+                {name: _stack_rows(rows[layer][name], spec)
+                 for name, spec in streams[layer]}
+                for layer in range(n_layer)
+            ]
             ids = jnp.asarray(page_ids, jnp.int32)
             self._state = dict(
                 self._state,
                 pools=self._prog.ingest(
-                    self._state["pools"], layer_rows_k, layer_rows_v, ids
+                    self._state["pools"], layer_rows, ids
                 ),
             )
-        for layer in range(cfg.n_layer):
-            spec = specs[layer]
-            _account_pages(
-                self.server.layer_name(layer), spec, 2 * n_pages
-            )
+        self._note_pages(n_pages)
         metrics.add("cgx.serve.pages_ingested", float(n_pages))
         self._ready.append(_Ready(
             req=req,
             page_ids=page_ids,
-            tail_k=tail_k,
-            tail_v=tail_v,
+            tails=tails,
             tail_len=tail_len,
             first_token=int(meta["first_token"]),
             pos=int(meta["prompt_tokens"]),
         ))
+
+    def _note_pages(self, n_pages: int) -> None:
+        """``n_pages`` pages of every stream of every layer went into the
+        pools: the wire plane's ``kv_page`` accounting."""
+        if not n_pages:
+            return
+        for layer, layer_streams in enumerate(self._prog.streams):
+            for _, spec in layer_streams:
+                _account_pages(self.server.layer_name(layer), spec, n_pages)
 
     # -- local prefill (colocated mode + the failover rung) ---------------
 
@@ -999,7 +1103,7 @@ class ContinuousBatchScheduler:
                 ids = np.full((padded.shape[0] // pt,), sv.max_pages,
                               np.int32)
                 ids[:n_full] = pids
-                first, pools, tail_k, tail_v, qerr_rows = (
+                first, pools, tails, qerr_rows = (
                     self._prog.prefill_pages(
                         self.server.p, self._state["pools"], padded[None],
                         np.arange(padded.shape[0], dtype=np.int32)[None],
@@ -1008,11 +1112,7 @@ class ContinuousBatchScheduler:
                 )
                 self._state["pools"] = pools
             # Host work the device's prefill hides.
-            if n_full:
-                for layer, spec in enumerate(specs):
-                    _account_pages(
-                        self.server.layer_name(layer), spec, 2 * n_full
-                    )
+            self._note_pages(n_full)
             with trace_span(
                 "serve.prefill.first_token",
                 hist="cgx.serve.prefill_first_token_s", req=req.id,
@@ -1025,7 +1125,7 @@ class ContinuousBatchScheduler:
                 )
         metrics.add("cgx.serve.local_prefills")
         return _Ready(
-            req=req, page_ids=pids, tail_k=tail_k, tail_v=tail_v,
+            req=req, page_ids=pids, tails=tails,
             tail_len=tail_len, first_token=first_token, pos=s,
         )
 
@@ -1084,7 +1184,7 @@ class ContinuousBatchScheduler:
                 self._state, np.int32(lane), table_row,
                 np.int32(len(ready.page_ids)), np.int32(ready.tail_len),
                 np.int32(ready.first_token), np.int32(ready.pos),
-                ready.tail_k, ready.tail_v,
+                ready.tails,
             )
             self._lanes[lane] = req
             # The prefill's own argmax IS the first generated token — the
@@ -1138,7 +1238,8 @@ class ContinuousBatchScheduler:
         if not active:
             return False
         sv = self.server.serve
-        n_layer = self.server.cfg.n_layer
+        n_layer = self.server.n_layer
+        lead = self._prog.names[0]  # the stream the qerr telemetry watches
         with trace_span(
             "serve.decode.prepare", hist="cgx.serve.decode_prepare_s"
         ):
@@ -1175,7 +1276,7 @@ class ContinuousBatchScheduler:
                             spec = self._prog.specs[layer]
                             if spec.quantized:
                                 rows = np.asarray(
-                                    st["tail_k"][layer]
+                                    st[f"tail_{lead}"][layer]
                                 )[committed].reshape(len(committed), -1)
                                 _observe_page_qerr(
                                     self.server.layer_name(layer), spec,
@@ -1184,14 +1285,11 @@ class ContinuousBatchScheduler:
                     self._state = self._prog.commit(
                         self._state, jnp.asarray(mask), jnp.asarray(pids)
                     )
-                    for layer in range(n_layer):
-                        _account_pages(
-                            self.server.layer_name(layer),
-                            self._prog.specs[layer], 2 * len(committed),
-                        )
+                    self._note_pages(len(committed))
                     metrics.add(
                         "cgx.serve.pages_committed",
-                        float(2 * len(committed) * n_layer),
+                        float(len(self._prog.names) * len(committed)
+                              * n_layer),
                     )
                 active = [i for i, r in enumerate(self._lanes)
                           if r is not None]
@@ -1204,6 +1302,10 @@ class ContinuousBatchScheduler:
             nxt = np.asarray(nxt)
         with trace_span("serve.decode.emit", hist="cgx.serve.decode_emit_s"):
             metrics.add("cgx.serve.decode_steps")
+            # What the adapter counted this step, read with the tokens.
+            for name, count in zip(self.server.step_counters,
+                                   nxt[sv.max_batch:]):
+                metrics.add(f"cgx.serve.{name}", float(count))
             metrics.set(
                 "cgx.serve.batch_occupancy", len(active) / sv.max_batch
             )

@@ -54,6 +54,26 @@ V_PAGE = 1
 K_TAIL = 2  # raw f16 tail block (the not-yet-full last page)
 V_TAIL = 3
 META = 4
+# The frame kinds carry a K/V cache and nothing else: kind -> (the cache
+# stream it belongs to, whether it is a whole page).
+STREAM_OF_KIND = {
+    K_PAGE: ("k", True), V_PAGE: ("v", True),
+    K_TAIL: ("k", False), V_TAIL: ("v", False),
+}
+
+
+def require_kv_streams(server) -> None:
+    """The disaggregated path ships K and V frames: refuse, up front and in
+    plain words, an adapter whose cache streams are others (a latent
+    cache), instead of half-working."""
+    names = tuple(name for name, _ in server.cache_streams(0))
+    if names != ("k", "v"):
+        raise ValueError(
+            f"the disaggregated prefill path ships K and V page frames "
+            f"(serving/transport.py kinds); adapter {server.kind!r} caches "
+            f"the streams {list(names)} and is served with local prefill "
+            "only"
+        )
 # Elastic-join snapshot pages (robustness/elastic.py — the param_page
 # wire edge): the `layer` field carries the flat LEAF index of the
 # training-state tree, `page_idx` the page within that leaf.
