@@ -916,3 +916,80 @@ def test_lowering_ledger_names_what_ran():
     assert metrics.get("cgx.codec.lowering.quantize.xla_tail") == 1
     jax.eval_shape(pallas, row(64))
     assert metrics.get("cgx.codec.lowering.quantize.pallas_flat") == 1
+
+
+@pytest.mark.parametrize("bits", [4, 8])
+@pytest.mark.parametrize("bucket", [128, 512])
+def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket,
+                                                        monkeypatch):
+    """The flat decode kernel's store (ISSUE 28): a ``bfloat16`` store is
+    the float32 decode cast to ``bfloat16`` bit for bit, flat or as rows
+    of the consumer's width; the default call stays float32, byte for byte
+    what the double-buffered twin (which this change left alone) decodes;
+    an ``add_to`` keeps the float32 store (fused add, one cast after it),
+    and the ledger says which store ran."""
+    from torch_cgx_tpu.utils.logging import metrics
+
+    rng = np.random.default_rng(bits * 1000 + bucket)
+    rows, width = 3, 2 * bucket
+    numel = 64 * bucket  # two whole chunks a row; 32 rows of ``width``
+    xs = jnp.asarray(rng.standard_normal((rows, numel)) * 3.0, jnp.float32)
+    q = codec_pallas.quantize_batch(xs, bits, bucket, interpret=True)
+
+    metrics.reset()
+    f32 = codec_pallas.dequantize_batch(q, interpret=True)
+    assert f32.dtype == jnp.float32 and f32.shape == (rows, numel)
+    with monkeypatch.context() as m:
+        m.setenv("CGX_PALLAS_DB", "on")
+        twin = codec_pallas.dequantize_batch(q, interpret=True)
+        # ... which stores float32 only: another type keeps to the grid.
+        codec_pallas.dequantize_batch(
+            q, interpret=True, out_dtype=jnp.bfloat16
+        )
+    np.testing.assert_array_equal(np.asarray(f32), np.asarray(twin))
+    assert metrics.get("cgx.codec.lowering.dequantize.pallas_flat") == 1
+    assert metrics.get("cgx.codec.lowering.dequantize.pallas_flat_db") == 1
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 1
+
+    def bits_of(a):
+        return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))
+
+    cast = f32.astype(jnp.bfloat16)
+    bf = codec_pallas.dequantize_batch(
+        q, interpret=True, out_dtype=jnp.bfloat16
+    )
+    assert bf.dtype == jnp.bfloat16 and bf.shape == (rows, numel)
+    np.testing.assert_array_equal(bits_of(bf), bits_of(cast))
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 2
+
+    as_rows = codec_pallas.dequantize_batch(
+        q, interpret=True, out_dtype=jnp.bfloat16, row_width=width
+    )
+    assert as_rows.shape == (rows, numel // width, width)
+    np.testing.assert_array_equal(
+        bits_of(as_rows), bits_of(cast).reshape(as_rows.shape)
+    )
+    assert metrics.get("cgx.codec.lowering.dequantize_rows.pallas_flat") == 1
+    # Rows that are not whole 128-lane columns: the same values, reshaped
+    # after the kernel.
+    narrow = codec_pallas.dequantize_batch(
+        q, interpret=True, out_dtype=jnp.bfloat16, row_width=64
+    )
+    np.testing.assert_array_equal(
+        bits_of(narrow), bits_of(cast).reshape(rows, -1, 64)
+    )
+    assert metrics.get("cgx.codec.lowering.dequantize_rows.xla_reshape") == 1
+
+    acc = jnp.asarray(rng.standard_normal(xs.shape), jnp.float32)
+    added = codec_pallas.dequantize_batch(
+        q, add_to=acc, out_dtype=jnp.bfloat16, interpret=True
+    )
+    np.testing.assert_array_equal(
+        bits_of(added), bits_of((acc + f32).astype(jnp.bfloat16))
+    )
+    # ... through the float32 store: no further bfloat16 one was counted.
+    assert metrics.get("cgx.codec.lowering.dequantize.pallas_flat") == 2
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 4

@@ -458,10 +458,13 @@ def test_full_join_round_is_bit_identical_and_reaps(monkeypatch):
         except Exception:  # pragma: no cover
             errs[rank] = traceback.format_exc()
 
+    # Announced before the survivors start (as in the abort test above):
+    # from its own thread, on a loaded machine, the announcement could come
+    # after the survivors' six boundaries, and nobody was left to admit it.
+    k = elastic.announce_join(store, global_rank=2, host="joinerhost|99")
+
     def joiner():
         try:
-            k = elastic.announce_join(store, global_rank=2,
-                                      host="joinerhost|99")
             akey = elastic._admit_key(k)
             deadline = time.monotonic() + 20
             while not rdz._flag_set(store, akey):

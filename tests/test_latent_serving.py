@@ -196,6 +196,118 @@ def test_absorbed_attention_equals_expanded(params):
         np.abs(want))
 
 
+# What a lane holds, as (committed pages, tail_len) of the two lanes.
+_LANE_KINDS = {
+    "no_page": ((0, 0), (0, 3)),
+    "some_pages": ((2, 5), (3, 1)),
+    "full_tail": ((1, PAGE - 1), (0, PAGE - 1)),
+}
+
+
+@pytest.mark.parametrize("lanes", sorted(_LANE_KINDS))
+@pytest.mark.parametrize("bits", [8, 4, 0])
+def test_latent_read_and_attend_matches_the_old_composition(params, bits,
+                                                            lanes):
+    """The latent read and absorbed attention as ISSUE 28 left them
+    (``cfg.dtype`` rows from ``gather_dequant_pages``, the tail attended
+    apart under one softmax) against the composition they replaced (each
+    stream decoded to float32, the tail concatenated, cast, one attention
+    over the joined table), to ``bfloat16`` rounding of the output."""
+    from torch_cgx_tpu.ops import paged_kv
+
+    cfg, pa = _cfg(dtype=jnp.bfloat16), params["layer_1"]["attn"]
+    b, p, max_pages = 2, 3, 8
+    widths = {"c": cfg.kv_lora_rank, "kr": cfg.d_rope}
+    rng = np.random.default_rng(bits * 10 + len(lanes))
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    specs = {
+        name: paged_kv.PageSpec(
+            PAGE, 1, w, bits, paged_kv.default_bucket(PAGE * w) if bits else 1)
+        for name, w in widths.items()
+    }
+    pools = {
+        name: paged_kv.commit_page_rows(
+            paged_kv.empty_pool(max_pages, spec), jnp.arange(max_pages),
+            normal(max_pages, spec.flat), spec)
+        for name, spec in specs.items()
+    }
+    (n0, t0), (n1, t1) = _LANE_KINDS[lanes]
+    table = jnp.asarray(rng.permutation(max_pages)[: b * p].reshape(b, p),
+                        jnp.int32)
+    table = jnp.where(
+        jnp.arange(p)[None, :] < jnp.asarray([[n0], [n1]]), table, -1)
+    mask_c = jnp.arange(p * PAGE)[None, :] < jnp.asarray(
+        [[n0 * PAGE], [n1 * PAGE]])
+    mask_t = jnp.arange(PAGE)[None, :] <= jnp.asarray([[t0], [t1]])
+    q_nope = normal(b, cfg.n_head, cfg.d_nope).astype(cfg.dtype)
+    q_rope = normal(b, cfg.n_head, cfg.d_rope).astype(cfg.dtype)
+    tails = {name: normal(b, PAGE, 1, w) for name, w in widths.items()}
+
+    pages = {
+        name: paged_kv.gather_dequant_pages(
+            pools[name], table, specs[name], cfg.dtype)
+        for name in widths
+    }
+    assert pages["c"].shape == (b, p * PAGE, cfg.kv_lora_rank)
+    assert pages["c"].dtype == cfg.dtype
+    got = mla_moe.attend_absorbed(
+        cfg, pa, q_nope, q_rope, pages["c"], pages["kr"], mask_c,
+        tail=tuple(tails[n][:, :, 0].astype(cfg.dtype) for n in widths)
+        + (mask_t,),
+    )
+    joined = {
+        name: jnp.concatenate(
+            [paged_kv.gather_dequant_pages(
+                pools[name], table, specs[name], jnp.float32
+            )[:, :, None], tails[name]], axis=1,
+        )[:, :, 0].astype(cfg.dtype)
+        for name in widths
+    }
+    want = mla_moe.attend_absorbed(
+        cfg, pa, q_nope, q_rope, joined["c"], joined["kr"],
+        jnp.concatenate([mask_c, mask_t], axis=1),
+    )
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    # One bfloat16 step of the latents' weighted sum, which the output
+    # projection carries into every value it mixes.
+    assert np.max(np.abs(got - want)) <= 2.0 ** -7 * np.abs(want).max()
+
+
+def test_latent_decode_step_holds_no_table_sized_glue(params, monkeypatch):
+    """Structure of the traced latent ``decode_step``: no ``concatenate``
+    and no ``convert_element_type`` over either stream's table (the
+    walker is tests/test_serving.py's). Pages of 512 tokens make both
+    streams' pages whole chunks of 128-wide buckets, so both reads are the
+    flat kernel's own ``bfloat16`` store, as on the chip; at these toy
+    widths (32 and 8) a row is not whole lanes, so the rows keep the
+    reshape after the kernel (tests/test_serving.py holds the kernel's
+    row tiling)."""
+    from test_serving import table_sized_glue
+
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    monkeypatch.setenv("CGX_COMPRESSION_BUCKET_SIZE", "128")
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    cfg = _cfg(dtype=jnp.bfloat16)
+    sv = _serve(page_tokens=512, max_batch=3, max_pages=6, max_seq=1024)
+    server = LatentMoEServer(cfg, params, sv)
+    sched = ContinuousBatchScheduler(server)
+    rows = (sv.max_batch * sv.pages_per_seq * sv.page_tokens,
+            sv.max_batch * (sv.pages_per_seq + 1) * sv.page_tokens)
+    metrics.reset()
+    jaxpr = jax.make_jaxpr(sched._prog.decode_step)(server.p, sched._state)
+    assert table_sized_glue(jaxpr, rows, cfg.d_rope) == []
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16"
+    ) == 2 * cfg.n_layer
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize_rows.pallas_flat") == 0
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize_rows.xla_reshape") == 2 * cfg.n_layer
+
+
 def _loop_moe(y, pm, top_k, scale):
     """The expert layer one token and one expert at a time, in numpy."""
     y = np.asarray(y, np.float64)

@@ -1054,3 +1054,198 @@ def test_training_controller_excludes_kv_page_labels(monkeypatch):
     alloc = ctl.update()
     assert not any(k.startswith("wire:kv_page:") for k in alloc)
     assert kv_mod.resolve_kv_config("layer_0").bits == 8
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 28: the cache read hands attention what it consumes.
+# ---------------------------------------------------------------------------
+
+from torch_cgx_tpu.models.attention import decode_attention  # noqa: E402
+from torch_cgx_tpu.ops import paged_kv  # noqa: E402
+
+# What a lane holds, as (committed pages, tail_len) of the two lanes.
+_LANE_KINDS = {
+    "no_page": ((0, 0), (0, 3)),
+    "some_pages": ((2, 5), (3, 1)),
+    "full_tail": ((1, 15), (0, 15)),
+}
+
+
+def table_sized_glue(jaxpr, rows, width):
+    """Every ``concatenate`` / ``convert_element_type`` equation of a
+    (closed) jaxpr, nested programs included, with an operand that holds a
+    cache table: leading dimensions that multiply to one of ``rows`` (a
+    row a cached position: the static page table's ``B * P * page_tokens``,
+    or that with the tails joined) and at least ``width`` values a row. A
+    Pallas kernel's body is left out: what it converts is a block in
+    VMEM, never a table in HBM."""
+    found = []
+
+    def is_table(shape):
+        lead = np.cumprod(shape).tolist()
+        return any(r in lead and lead[-1] >= r * width for r in rows)
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                continue
+            if eqn.primitive.name in ("concatenate", "convert_element_type"):
+                shapes = [tuple(v.aval.shape) for v in eqn.invars]
+                if any(is_table(shape) for shape in shapes):
+                    found.append((eqn.primitive.name, shapes))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+def _old_decode_attention(q, k, v, *, kv_mask):
+    """``models.attention.decode_attention`` as it stood before ISSUE 28:
+    ``q (B, H, 1, D)`` against ``k``/``v (B, H, T, D)``."""
+    d = q.shape[-1]
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / np.float32(np.sqrt(d))
+    scores = jnp.where(kv_mask[:, None, None, :], scores, np.float32(-1e30))
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+@pytest.mark.parametrize("lanes", sorted(_LANE_KINDS))
+@pytest.mark.parametrize("bits", [8, 4, 0])
+def test_read_and_attend_matches_the_old_composition(bits, lanes,
+                                                     monkeypatch):
+    """The new cache read and attention (``cfg.dtype`` rows from the
+    kernel, contracted where they lie, the tail attended apart) against
+    the composition it replaced (decode to float32, concatenate the tail,
+    transpose heads-major, cast, one attention), to ``bfloat16`` rounding
+    of the output. Pages are one whole chunk of 128-wide buckets and the
+    Pallas codec is forced, so the flat kernel's own store and row tiling
+    are what is read (interpret mode)."""
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    b, p, pt, h, d, max_pages = 2, 3, 16, 4, 64, 8
+    dt = jnp.bfloat16
+    spec = paged_kv.PageSpec(pt, h, d, bits, 128 if bits else 1)
+    rng = np.random.default_rng(bits * 10 + len(lanes))
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    pools = [
+        paged_kv.commit_page_rows(
+            paged_kv.empty_pool(max_pages, spec), jnp.arange(max_pages),
+            normal(max_pages, spec.flat), spec)
+        for _ in range(2)
+    ]
+    table = jnp.asarray(rng.permutation(max_pages)[: b * p].reshape(b, p),
+                        jnp.int32)
+    (n0, t0), (n1, t1) = _LANE_KINDS[lanes]
+    table = jnp.where(
+        jnp.arange(p)[None, :] < jnp.asarray([[n0], [n1]]), table, -1)
+    mask_c = jnp.arange(p * pt)[None, :] < jnp.asarray([[n0 * pt], [n1 * pt]])
+    mask_t = jnp.arange(pt)[None, :] <= jnp.asarray([[t0], [t1]])
+    q = normal(b, h, d).astype(dt)
+    tk, tv = normal(b, pt, h, d), normal(b, pt, h, d)
+
+    metrics.reset()
+    kc, vc = (paged_kv.gather_dequant_pages(pool, table, spec, dt)
+              for pool in pools)
+    assert kc.shape == (b, p * pt, h * d) and kc.dtype == dt
+    if bits:
+        for name in ("dequantize.pallas_flat.bfloat16",
+                     "dequantize_rows.pallas_flat"):
+            assert metrics.get(f"cgx.codec.lowering.{name}") == 2, name
+    got = decode_attention(
+        q, kc, vc, tk.reshape(b, pt, h * d).astype(dt),
+        tv.reshape(b, pt, h * d).astype(dt), mask=mask_c, tail_mask=mask_t,
+    )
+
+    def old_read(pool, tail):
+        pages = paged_kv.gather_dequant_pages(
+            pool, table, spec, jnp.float32).reshape(b, p * pt, h, d)
+        return jnp.concatenate([pages, tail], axis=1).transpose(
+            0, 2, 1, 3).astype(dt)
+
+    want = _old_decode_attention(
+        q[:, :, None], old_read(pools[0], tk), old_read(pools[1], tv),
+        kv_mask=jnp.concatenate([mask_c, mask_t], axis=1),
+    )[:, :, 0].reshape(b, h * d)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    # One bfloat16 step of the value (2**-7 of it), and a floor for sums
+    # that cancel.
+    assert np.all(np.abs(got - want)
+                  <= 2.0 ** -7 * np.abs(want) + 1e-3 * np.abs(want).max())
+
+
+def test_decode_step_holds_no_table_sized_glue(model_setup, monkeypatch):
+    """Structure of the traced ``decode_step``: between the cache read and
+    the attention nothing of the table's size is concatenated or cast, so
+    the glue PR 27's trace showed (104.8 ms of a 169.6 ms step) cannot
+    come back unseen. Traced with the Pallas codec on whole-chunk pages,
+    the program the chip runs; the old composition, traced the same way,
+    is caught."""
+    cfg, _, params = model_setup
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    monkeypatch.setenv("CGX_COMPRESSION_BUCKET_SIZE", "128")
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    sv = _serve_cfg(page_tokens=32, max_batch=3, max_pages=12, max_seq=128)
+    server = GPT2Server(cfg, params, sv)
+    sched = ContinuousBatchScheduler(server)
+    streams = sched._prog.streams
+    spec = streams[0][0][1]
+    assert spec.num_buckets == 32 and cfg.dtype == jnp.bfloat16
+    rows = (sv.max_batch * sv.pages_per_seq * sv.page_tokens,
+            sv.max_batch * (sv.pages_per_seq + 1) * sv.page_tokens)
+
+    metrics.reset()
+    jaxpr = jax.make_jaxpr(sched._prog.decode_step)(server.p, sched._state)
+    assert table_sized_glue(jaxpr, rows, cfg.d_model) == []
+    reads = 2 * cfg.n_layer
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == reads
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize_rows.pallas_flat") == reads
+
+    def old_read(state):
+        pages = paged_kv.gather_dequant_pages(
+            state["pools"][0]["k"], state["page_table"], spec, jnp.float32)
+        return jnp.concatenate(
+            [pages, state["tail_k"][0].reshape(sv.max_batch, -1,
+                                               cfg.d_model)], axis=1
+        ).astype(cfg.dtype)
+
+    old = table_sized_glue(
+        jax.make_jaxpr(old_read)(sched._state), rows, cfg.d_model)
+    assert sorted(name for name, _ in old) == [
+        "concatenate", "convert_element_type"]
+
+
+def test_a_prefilled_request_takes_its_lane_before_the_next_prefill(
+        model_setup):
+    """Order of one tick's admissions: prefill, lane write, prefill, lane
+    write. With every prefill before the first lane write, a request's
+    first token waited for the prefills of the requests behind it; in a
+    closed loop whose step got shorter, the 51 s window then reached ticks
+    of more admissions and the prefill cell's TTFT p90 read 13 % worse
+    with no admission any slower (PERF.md section 6, PR 28)."""
+    cfg, _model, params = model_setup
+    sched = ContinuousBatchScheduler(GPT2Server(cfg, params, _serve_cfg()))
+    order = []
+    for name in ("_local_prefill", "_admit_lane"):
+        inner = getattr(sched, name)
+
+        def noted(*args, _inner=inner, _name=name):
+            order.append(_name)
+            return _inner(*args)
+
+        setattr(sched, name, noted)
+    reqs = [Request(id=f"r{i}", tokens=list(p), max_new_tokens=3)
+            for i, p in enumerate(_prompts(cfg, 3))]
+    for r in reqs:
+        sched.submit(r)
+    sched._admit()
+    assert order == ["_local_prefill", "_admit_lane"] * 3
+    stamps = [r.first_token_at for r in reqs]
+    assert stamps == sorted(stamps) and all(r in sched._lanes for r in reqs)
+    assert sched.run(deadline_s=DEADLINE_S)

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..parallel import moe
+from .attention import joined_softmax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -199,25 +200,41 @@ def attend_expanded(cfg: MlaMoeConfig, pa, q_nope, q_rope, c, k_r):
 
 
 def attend_absorbed(cfg: MlaMoeConfig, pa, q_nope, q_rope, c_all, kr_all,
-                    kv_mask):
+                    kv_mask, tail=None):
     """One query a lane against cached latents: ``q_nope (B, H, dn)``,
     ``q_rope (B, H, dr)``, ``c_all (B, T, Rkv)``, ``kr_all (B, T, dr)``,
     ``kv_mask (B, T)`` (True = a live position) -> ``(B, H*dv)``. ``W_kvb``'s
     key half is folded into the query and its value half applied to the
-    weighted sum of latents."""
+    weighted sum of latents. ``tail``: the lane's tail rows ``(c (B, Tt,
+    Rkv), kr (B, Tt, dr), mask (B, Tt))``, attended apart from the pages
+    under one softmax (``attention.joined_softmax``), the two weighted
+    sums of latents added in float32."""
     dt = cfg.dtype
     w_k, w_v = _kv_b_heads(cfg, pa)
     q_abs = jnp.einsum("bhn,lhn->bhl", q_nope, w_k.astype(dt))
-    scores = (
-        jnp.einsum("bhl,btl->bht", q_abs, c_all,
-                   preferred_element_type=jnp.float32)
-        + jnp.einsum("bhr,btr->bht", q_rope, kr_all,
-                     preferred_element_type=jnp.float32)
-    ) * _softmax_scale(cfg)
-    scores = jnp.where(kv_mask[:, None, :], scores, np.float32(-1e30))
-    probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-    o_lat = jnp.einsum("bht,btl->bhl", probs, c_all)
-    o = jnp.einsum("bhl,lhv->bhv", o_lat, w_v.astype(dt))
+
+    def scores(c, kr):
+        return (
+            jnp.einsum("bhl,btl->bht", q_abs, c,
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("bhr,btr->bht", q_rope, kr,
+                         preferred_element_type=jnp.float32)
+        ) * _softmax_scale(cfg)
+
+    def weighted(probs, c):
+        return jnp.einsum("bht,btl->bhl", probs, c,
+                          preferred_element_type=jnp.float32)
+
+    c_tail, kr_tail, tail_mask = tail or (None, None, None)
+    probs, tail_probs = joined_softmax(
+        scores(c_all, kr_all), kv_mask,
+        None if tail is None else scores(c_tail, kr_tail), tail_mask,
+        dtype=dt,
+    )
+    o_lat = weighted(probs, c_all)
+    if tail is not None:
+        o_lat = o_lat + weighted(tail_probs, c_tail)
+    o = jnp.einsum("bhl,lhv->bhv", o_lat.astype(dt), w_v.astype(dt))
     return o.reshape(o.shape[0], -1)
 
 

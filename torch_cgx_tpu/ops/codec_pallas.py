@@ -83,6 +83,9 @@ from ..utils.logging import metrics
 LANE_GROUP = codec.LANE_GROUP  # 32
 CHUNK_BUCKETS = codec.CHUNK_BUCKETS  # 32 buckets per sublane-packed chunk
 MAX_BUCKET_ELEMS = 16384  # VMEM guard for the (32, bucket) chunk tile
+# Types the flat decode kernel stores itself; any other is a cast after it
+# (Mosaic, libtpu 0.0.34, refuses a float16 store on v5e: pack_subelements).
+_FLAT_STORE_DTYPES = (np.dtype(np.float32), np.dtype(jnp.bfloat16))
 
 
 def _use_db(tuned: "autotune.TunedConfig | None") -> bool:
@@ -438,7 +441,10 @@ def _quantize_flat_impl(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bits", "bucket_size", "interpret", "tc", "with_add"),
+    static_argnames=(
+        "bits", "bucket_size", "interpret", "tc", "with_add", "out_dtype",
+        "row_width",
+    ),
 )
 def _dequantize_flat_impl(
     words: jax.Array,
@@ -450,12 +456,31 @@ def _dequantize_flat_impl(
     interpret: bool = False,
     tc: int = 8,
     with_add: bool = False,
+    out_dtype=np.dtype(np.float32),
+    row_width: Optional[int] = None,
 ):
     """Zero-relayout dequantize: words (rows, W) int32 + meta (rows, nb_r, 2)
-    -> (rows, nb_r*B) f32. Word blocks are natural (., 128) flat rows like
-    :func:`_quantize_flat_impl`'s output; the decoded values are computed on
-    a full-vreg 2-D ``(tc*32*rb, 128)`` shape (measured ~1.4 ms for 512 MB
-    at 4-bit on v5e — near the HBM write floor).
+    -> (rows, nb_r*B) ``out_dtype``. Word blocks are natural (., 128) flat
+    rows like :func:`_quantize_flat_impl`'s output; the decoded values are
+    computed on a full-vreg 2-D ``(tc*32*rb, 128)`` shape (measured ~1.4 ms
+    for 512 MB at 4-bit on v5e — near the HBM write floor).
+
+    ``out_dtype``: the type the kernel STORES. The arithmetic stays float32
+    (``bmin + unit * lvl``) and the store casts, so a value is bit for bit
+    ``float32 -> astype(out_dtype)`` of the default call's, at half the
+    write (and no table-sized ``convert`` after the kernel) for a 16-bit
+    consumer.
+
+    ``row_width`` (a multiple of 128 that tiles a block: see
+    :func:`_rows_tc`): the consumer reads the decoded values as rows of
+    that many (a token's heads side by side, a latent). The values are the
+    same and in the same order, but on the chip a ``(n, 128)`` array and a
+    ``(n*128/row_width, row_width)`` array tile differently, so XLA answers
+    the reshape between them with a copy of the whole table (the
+    ``reshape`` and ``copy`` ops of the PR 27 traces). With ``row_width``
+    the kernel stores that tiling itself — each 128-lane column of the
+    output block is a sublane-strided read of the decoded block — and
+    returns ``(rows, nb_r*B / row_width, row_width)``.
 
     ``with_add``: fuse the decompress-accumulate (the reference's
     ``UnpackArray<ADD>`` kernel mode, cuda_compression_operations.cu:
@@ -471,9 +496,14 @@ def _dequantize_flat_impl(
     n_chunks = rows * nb_r // CHUNK_BUCKETS
     s_rows = tc * CHUNK_BUCKETS * rb
 
+    k = 1 if row_width is None else row_width // 128
+    t_rows = s_rows // k  # output rows a block
+
     def _dequantize_flat_kernel(w_ref, m_ref, *rest):
         if with_add:
             acc_ref, out_ref = rest
+        elif k > 1:
+            out_ref, flat_ref = rest
         else:
             (out_ref,) = rest
         w4 = w_ref[:].reshape(tc, bits, rb, 128)
@@ -487,7 +517,18 @@ def _dequantize_flat_impl(
         unit = m2[:, 0:1].reshape(tc, CHUNK_BUCKETS, 1, 1)
         bmin = m2[:, 1:2].reshape(tc, CHUNK_BUCKETS, 1, 1)
         vals = (bmin + unit * lvl.astype(jnp.float32)).reshape(s_rows, 128)
-        out_ref[:] = acc_ref[:] + vals if with_add else vals
+        if with_add:
+            out_ref[:] = acc_ref[:] + vals
+        elif k > 1:
+            # Row t of the output block is the k flat rows t*k .. t*k+k-1
+            # side by side: column j is every k-th flat row from j.
+            flat_ref[:] = vals
+            for j in range(k):
+                out_ref[:, j * 128 : (j + 1) * 128] = flat_ref[
+                    pl.ds(j, t_rows, stride=k), :
+                ].astype(out_dtype)
+        else:
+            out_ref[:] = vals.astype(out_dtype)
 
     wv = words.reshape(rows * w_row // 128, 128)
     mv = meta.reshape(rows * nb_r, 2)
@@ -511,13 +552,18 @@ def _dequantize_flat_impl(
         name="cgx_dequantize_flat",
         grid=(n_chunks // tc,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((s_rows, 128), lambda i: (i, 0),
+        out_specs=pl.BlockSpec((t_rows, k * 128), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(
-            (n_chunks * CHUNK_BUCKETS * rb, 128), jnp.float32
+            (n_chunks * CHUNK_BUCKETS * rb // k, k * 128), out_dtype
+        ),
+        scratch_shapes=(
+            [pltpu.VMEM((s_rows, 128), jnp.float32)] if k > 1 else []
         ),
         interpret=interpret,
     )(*operands)
+    if row_width is not None:
+        return out.reshape(rows, -1, row_width)
     return out.reshape(rows, nb_r * b)
 
 
@@ -1051,16 +1097,62 @@ def quantize_batch(
     )
 
 
+def _rows_tc(
+    n_chunks: int,
+    bucket_size: int,
+    row_width: int,
+    store: np.dtype,
+    tuned: "autotune.TunedConfig | None" = None,
+) -> Optional[int]:
+    """Chunks per block for a flat decode that stores rows of
+    ``row_width`` (see :func:`_dequantize_flat_impl`): the largest tile
+    within :func:`_tile_chunks`'s cap that divides the chunk count, holds
+    whole rows, and makes the output block whole sublane tiles of the
+    stored type (8 rows of a 32-bit type, 16 of a 16-bit one). None when
+    the rows are not whole 128-lane columns or no such tile exists; the
+    caller then lets XLA reshape the kernel's flat output."""
+    if row_width % 128:
+        return None
+    block_rows = row_width * (32 // store.itemsize)
+    chunk = CHUNK_BUCKETS * bucket_size
+    cap = _tile_chunks(n_chunks, bucket_size, 8, tuned)
+    for tc in range(min(cap, n_chunks), 0, -1):
+        if n_chunks % tc == 0 and (tc * chunk) % block_rows == 0:
+            return tc
+    return None
+
+
+def as_rows(vals: jax.Array, row_width: Optional[int]) -> jax.Array:
+    """``(rows, numel)`` values as ``(rows, numel // row_width,
+    row_width)`` when the caller asked for rows."""
+    if row_width is None:
+        return vals
+    return vals.reshape(vals.shape[0], -1, row_width)
+
+
 def dequantize_batch(
     q: codec.QTensor,
     *,
     add_to: Optional[jax.Array] = None,
     out_dtype=None,
     interpret: bool = False,
+    row_width: Optional[int] = None,
 ) -> jax.Array:
     """Decode a batched QTensor -> (rows, numel). A raw residual tail
     (skip_incomplete_buckets mode) is re-appended after the kernel decode,
-    mirroring ``codec.dequantize``."""
+    mirroring ``codec.dequantize``.
+
+    The flat kernel stores ``out_dtype`` itself (float32 arithmetic, one
+    cast at the store) when nothing has to be added to its output after
+    it: no ``add_to`` (the fused add keeps float32, and an unfused one
+    adds in float32) and no residual. Every other path decodes to float32
+    and casts after, as before; the values are the same bit for bit.
+
+    ``row_width`` (must divide ``numel``): return the values as rows of
+    that many, ``(rows, numel // row_width, row_width)``. The flat kernel
+    stores that tiling when it stores the type and a block holds whole
+    rows (:func:`_rows_tc`); otherwise the result is reshaped, which on
+    the chip is a copy."""
     if out_dtype is None:
         out_dtype = add_to.dtype if add_to is not None else q.dtype
     rows = q.packed.shape[0]
@@ -1084,11 +1176,29 @@ def dequantize_batch(
             autotune.KIND_FLAT, n_chunks=rows * c_r, bucket_size=b,
             bits=q.bits,
         )
-        db = _use_db(tuned)
+        # The kernel stores the caller's type and rows itself when nothing
+        # is added to its output after it.
+        plain = add_to is None and not q.residual.shape[-1]
+        store = np.dtype(out_dtype)
+        if not plain or store not in _FLAT_STORE_DTYPES:
+            store = np.dtype(np.float32)
+        tc_rows = None
+        if row_width is not None:
+            if plain and q.numel_main == nb_r * b:
+                tc_rows = _rows_tc(rows * c_r, b, row_width, store, tuned)
+            note_lowering(
+                "dequantize_rows", "pallas_flat" if tc_rows else "xla_reshape"
+            )
+        # The double-buffered twin stores flat float32 only.
+        db = _use_db(tuned) and store == np.float32 and not tc_rows
+        name = "pallas_flat_db" if db else "pallas_flat"
         note_lowering(
-            "dequantize", "pallas_flat_db" if db else "pallas_flat"
+            "dequantize", name if store == np.float32 else f"{name}.{store.name}"
         )
-        impl = _dequantize_flat_db_impl if db else _dequantize_flat_impl
+        impl = _dequantize_flat_db_impl if db else functools.partial(
+            _dequantize_flat_impl, out_dtype=store,
+            row_width=row_width if tc_rows else None,
+        )
         vals = impl(
             jax.lax.bitcast_convert_type(q.packed, jnp.int32),
             meta,
@@ -1096,11 +1206,14 @@ def dequantize_batch(
             bits=q.bits,
             bucket_size=b,
             interpret=interpret,
-            tc=_pipe_tc(rows * c_r, b, tuned),
+            tc=tc_rows or _pipe_tc(rows * c_r, b, tuned),
             with_add=fuse_add,
-        )[:, : q.numel_main]
-        if fuse_add:
+        )
+        if tc_rows:
             return vals.astype(out_dtype)
+        vals = vals[:, : q.numel_main]
+        if fuse_add:
+            return as_rows(vals.astype(out_dtype), row_width)
     else:
         parts = []
         note_lowering("dequantize", "pallas_chunks" if c_r else "xla_tail")
@@ -1139,8 +1252,8 @@ def dequantize_batch(
             [vals, q.residual.astype(jnp.float32)], axis=1
         )
     if add_to is not None:
-        return (add_to.astype(jnp.float32) + vals).astype(out_dtype)
-    return vals.astype(out_dtype)
+        vals = add_to.astype(jnp.float32) + vals
+    return as_rows(vals.astype(out_dtype), row_width)
 
 
 # ---------------------------------------------------------------------------

@@ -80,9 +80,16 @@ def quantize_batch(
 
 
 def dequantize_batch(
-    q: codec.QTensor, *, add_to: Optional[jax.Array] = None, out_dtype=None
+    q: codec.QTensor,
+    *,
+    add_to: Optional[jax.Array] = None,
+    out_dtype=None,
+    row_width: Optional[int] = None,
 ) -> jax.Array:
-    """Decode a batched QTensor (leading rows dim) -> (rows, numel)."""
+    """Decode a batched QTensor (leading rows dim) -> (rows, numel), or
+    ``(rows, numel // row_width, row_width)`` when the consumer reads rows
+    of ``row_width`` values (``codec_pallas.dequantize_batch`` says what
+    that saves on the chip)."""
     cc = CompressionConfig(
         bits=q.bits or 32,
         bucket_size=q.bucket_size or 512,
@@ -90,14 +97,19 @@ def dequantize_batch(
     )
     if q.bits and _pick(q.numel, cc) == "pallas":
         return codec_pallas.dequantize_batch(
-            q, add_to=add_to, out_dtype=out_dtype, interpret=not _on_tpu()
+            q, add_to=add_to, out_dtype=out_dtype, interpret=not _on_tpu(),
+            row_width=row_width,
         )
     codec_pallas.note_lowering("dequantize", "xla")
     if add_to is not None:
-        return jax.vmap(
+        vals = jax.vmap(
             lambda qq, acc: codec.dequantize(qq, add_to=acc, out_dtype=out_dtype)
         )(q, add_to)
-    return jax.vmap(lambda qq: codec.dequantize(qq, out_dtype=out_dtype))(q)
+    else:
+        vals = jax.vmap(
+            lambda qq: codec.dequantize(qq, out_dtype=out_dtype)
+        )(q)
+    return codec_pallas.as_rows(vals, row_width)
 
 
 # ---------------------------------------------------------------------------

@@ -6,12 +6,12 @@ through the same max-min codec every other wire in the system uses
 (``ops.dispatch`` — Pallas kernels on TPU, XLA elsewhere), so a page has
 one wire representation everywhere it travels: the prefill→decode
 transport ships exactly the bytes the pool stores, and the decode
-program's KV read dequantizes them *inside* the consumer — the gathered
-page rows feed ``dequantize_batch`` immediately before the attention
-einsum in one staged program, the fused computation-collective shape
-(arxiv 2305.06942) applied to the KV hop. On TPU dispatch the decode
-rides the flat Pallas dequantize kernel; there is no intermediate f32
-pool materialization at any size.
+program's KV read dequantizes them for its consumer — the gathered page
+rows feed ``dequantize_batch`` immediately before the attention in one
+staged program. On TPU dispatch the decode rides the flat Pallas
+dequantize kernel, which writes the gathered table once, in the type and
+the row order the attention reads (:func:`gather_dequant_pages`); the
+pool itself is never decoded.
 
 Layouts (all static per compiled decode program):
 
@@ -157,31 +157,46 @@ def pool_qtensor(
 
 
 def gather_dequant_pages(
-    pool, page_table: jax.Array, spec: PageSpec
+    pool, page_table: jax.Array, spec: PageSpec, dtype=jnp.float32
 ) -> jax.Array:
     """The decode program's paged KV read: gather ``page_table (B, P)``
-    rows from the pool and decode them AT the consumer -> ``(B,
-    P * page_tokens, n_head, d_head) f32``.
+    rows from the pool and decode them for the consumer -> ``(B,
+    P * page_tokens, n_head * d_head)`` in ``dtype``: one row a cached
+    position, the heads side by side as a page holds them, in the type
+    the attention contracts in (the adapter's ``cfg.dtype``). A value is
+    ``float32 decode -> astype(dtype)``, whichever lowering writes it.
 
     Sentinel entries (< 0) are clipped to row 0 before the gather (XLA
     gathers must stay in bounds) and their decoded tokens are garbage by
     construction — callers mask attention scores by the lane's committed
     token count, never by inspecting decoded values. The dequantize is
     ``ops.dispatch.dequantize_batch``: the Pallas flat decode kernel on
-    TPU dispatch, staged XLA elsewhere, fused by XLA into the attention
-    read that consumes it (this function is only ever called inside the
-    jitted decode step)."""
+    TPU dispatch (whole-chunk pages), staged XLA elsewhere.
+
+    XLA does NOT fuse the decode into the attention that reads it: the
+    kernel's output is a table in HBM, and whatever lies between it and
+    the contraction is paid over the whole table. When this returned
+    ``(B, T, n_head, d_head)`` float32, a GPT-2 large step spent 104.8 ms
+    of its 169.6 in ``copy`` + ``reshape`` + ``convert`` (joining the
+    tail, transposing to heads-major, casting; ledger, PR 27) against
+    40.5 ms in the kernel. So the kernel is asked for the consumer's type
+    and row width (``row_width``: it stores the rows' tiling itself, see
+    ``codec_pallas._dequantize_flat_impl``), the attention contracts the
+    rows where they lie (``models.attention.decode_attention``,
+    ``models.mla_moe.attend_absorbed``) and the tail is attended apart
+    (``models.attention.joined_softmax``)."""
     b, p = page_table.shape
     ids = jnp.maximum(page_table.reshape(-1), 0)
+    width = spec.n_head * spec.d_head
     if not spec.quantized:
-        pages = pool[ids].astype(jnp.float32)
-        return pages.reshape(
-            b, p * spec.page_tokens, spec.n_head, spec.d_head
+        rows = pool[ids].astype(dtype)
+    else:
+        packed, meta = pool
+        q = pool_qtensor(packed, meta, ids, spec)
+        rows = ops_dispatch.dequantize_batch(
+            q, out_dtype=dtype, row_width=width
         )
-    packed, meta = pool
-    q = pool_qtensor(packed, meta, ids, spec)
-    vals = ops_dispatch.dequantize_batch(q, out_dtype=jnp.float32)
-    return vals.reshape(b, p * spec.page_tokens, spec.n_head, spec.d_head)
+    return rows.reshape(b, p * spec.page_tokens, width)
 
 
 def commit_page_rows(pool, page_ids: jax.Array, rows: jax.Array, spec: PageSpec):

@@ -126,9 +126,8 @@ class LatentMoEServer:
         committed = state["n_pages"] * pt
         pos_c = jax.lax.broadcasted_iota(jnp.int32, (b, p_dim * pt), 1)
         pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-        kv_mask = jnp.concatenate(
-            [pos_c < committed[:, None], pos_t <= tail_idx[:, None]], axis=1
-        )
+        mask_c = pos_c < committed[:, None]
+        mask_t = pos_t <= tail_idx[:, None]
         new_tails = {"c": [], "kr": []}
         counts = []
         for layer in range(cfg.n_layer):
@@ -137,22 +136,21 @@ class LatentMoEServer:
             q_nope, q_rope, c, k_r = mla_moe.mla_project(
                 cfg, y, pl["attn"], positions
             )
-            gathered = {}
+            pages, tails = {}, {}
             for (name, spec), new in zip(streams[layer], (c, k_r)):
                 tail = jnp.where(
                     onehot, new[:, :, None].astype(jnp.float32),
                     state[f"tail_{name}"][layer],
                 )
                 new_tails[name].append(tail)
-                pages = paged_kv.gather_dequant_pages(
-                    state["pools"][layer][name], state["page_table"], spec
+                tails[name] = tail[:, :, 0].astype(dt)  # cast alone
+                pages[name] = paged_kv.gather_dequant_pages(
+                    state["pools"][layer][name], state["page_table"], spec,
+                    dt,
                 )
-                gathered[name] = jnp.concatenate(
-                    [pages, tail], axis=1
-                )[:, :, 0].astype(dt)
             o = mla_moe.attend_absorbed(
-                cfg, pl["attn"], q_nope[:, 0], q_rope[:, 0], gathered["c"],
-                gathered["kr"], kv_mask,
+                cfg, pl["attn"], q_nope[:, 0], q_rope[:, 0], pages["c"],
+                pages["kr"], mask_c, tail=(tails["c"], tails["kr"], mask_t),
             )
             x, stats = self._block_tail(
                 x, pl, o[:, None], count_mask=state["active"]

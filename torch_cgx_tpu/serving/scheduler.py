@@ -10,9 +10,9 @@ knows what a stream means.
 
 The decode worker runs ONE compiled step program: for every lane of a
 fixed ``CGX_SERVE_MAX_BATCH``-wide batch, gather the lane's committed KV
-pages (``ops/paged_kv.gather_dequant_pages`` — the dequantize staged
-immediately at the attention read, Pallas codec on TPU dispatch), attend
-the lane's current token against pages + the raw f32 tail block, and
+pages (``ops/paged_kv.gather_dequant_pages`` — ``cfg.dtype`` rows as the
+attention reads them, Pallas codec on TPU dispatch), attend the lane's
+current token against the pages and, apart, the raw f32 tail block, and
 emit the greedy next token. Admission and eviction happen per step
 around that program (continuous batching): completed lanes free their
 pages back to the refcounted pool and a waiting request takes the lane
@@ -325,8 +325,9 @@ class GPT2Server:
 
     def decode_forward(self, state, streams):
         """One decode position against the paged cache: current tokens at
-        their positions, KV read = gathered committed pages (dequantized
-        at the consumer) + the raw tail with this token's K/V appended.
+        their positions, KV read = gathered committed pages (decoded to
+        ``cfg.dtype`` rows, contracted where they lie) and, apart, the raw
+        tail with this token's K/V appended; one softmax over both.
         Returns (logits (B, vocab), the new tails by stream, None)."""
         cfg = self.cfg
         pt = self.serve.page_tokens
@@ -343,9 +344,12 @@ class GPT2Server:
         mask_c = pos_c < committed[:, None]
         pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
         mask_t = pos_t <= tail_idx[:, None]
-        kv_mask = jnp.concatenate([mask_c, mask_t], axis=1)
         new_tk: List[jax.Array] = []
         new_tv: List[jax.Array] = []
+
+        def tail_rows(t):  # (B, pt, H, Dh) f32 -> (B, pt, Dm), cast alone
+            return t.reshape(b, pt, cfg.d_model).astype(cfg.dtype)
+
         for layer in range(cfg.n_layer):
             pl = self.p[f"h_{layer}"]
             q, k, v = self._qkv(x, pl)  # (B, H, 1, Dh)
@@ -360,20 +364,16 @@ class GPT2Server:
             new_tv.append(tv)
             pool, spec = state["pools"][layer], streams[layer][0][1]
             kc = paged_kv.gather_dequant_pages(
-                pool["k"], state["page_table"], spec
+                pool["k"], state["page_table"], spec, cfg.dtype
             )
             vc = paged_kv.gather_dequant_pages(
-                pool["v"], state["page_table"], spec
+                pool["v"], state["page_table"], spec, cfg.dtype
             )
-            k_all = jnp.concatenate([kc, tk], axis=1).transpose(
-                0, 2, 1, 3
-            ).astype(cfg.dtype)
-            v_all = jnp.concatenate([vc, tv], axis=1).transpose(
-                0, 2, 1, 3
-            ).astype(cfg.dtype)
-            o = decode_attention(q, k_all, v_all, kv_mask=kv_mask)
-            o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.d_model)
-            x = self._block_tail(x, pl, o)
+            o = decode_attention(
+                q[:, :, 0], kc, vc, tail_rows(tk), tail_rows(tv),
+                mask=mask_c, tail_mask=mask_t,
+            )
+            x = self._block_tail(x, pl, o[:, None])
         return self._logits(x)[:, -1], {"k": new_tk, "v": new_tv}, None
 
 
@@ -1139,12 +1139,21 @@ class ContinuousBatchScheduler:
             return False  # draining toward a program re-key: no admits
         progressed = False
         free = self._free_lanes()
-        # Prefill-ahead is bounded by the lanes that could actually take
-        # the result this step: one free lane must not trigger a
-        # whole-queue prefill burst (which would hold pool pages for
-        # requests that cannot run yet and inflate every TTFT behind
-        # the synchronous forwards).
-        while self._waiting and len(self._ready) < len(free):
+        while True:
+            # A ready request takes a free lane at once: its first token
+            # does not wait for the prefills of the requests behind it
+            # (prefill all, then write all lanes, made every admission of
+            # a tick as late as the last one).
+            while free and self._ready:
+                self._admit_lane(free.pop(0), self._ready.pop(0))
+                progressed = True
+            # Prefill-ahead is bounded by the lanes that could actually
+            # take the result this step: one free lane must not trigger a
+            # whole-queue prefill burst (which would hold pool pages for
+            # requests that cannot run yet and inflate every TTFT behind
+            # the synchronous forwards).
+            if not (self._waiting and free):
+                break
             req = self._waiting.pop(0)
             try:
                 ready = self._local_prefill(req)
@@ -1160,12 +1169,6 @@ class ContinuousBatchScheduler:
                 self._waiting.insert(0, req)  # pool pressure
                 break
             self._ready.append(ready)
-            progressed = True
-        while free and self._ready:
-            lane = free.pop(0)
-            ready = self._ready.pop(0)
-            self._admit_lane(lane, ready)
-            progressed = True
         self._release_lanes()  # a first token can finish its request
         return progressed
 
