@@ -174,14 +174,6 @@ def _counters(*prefixes: str) -> dict:
     }
 
 
-def _autotune_report() -> dict:
-    from torch_cgx_tpu.ops import autotune
-
-    path = autotune.cache_path()
-    return {"path": str(path), "exists": path.exists(),
-            "stats": autotune.stats()}
-
-
 def _finish(result: dict, checks: Checks) -> int:
     result["checks"] = checks.rows
     result["ok"] = checks.ok
@@ -397,7 +389,6 @@ def phase_train(rehearse: bool) -> int:
         "planner_model": planner.cost_model().source,
         "staged": staged,
         "engaged": engaged,
-        "autotune": _autotune_report(),
         "memory_stats": mem,
         "smoke_timing_not_benchmark": {
             "lower_s": round(lower_s, 2),
@@ -578,7 +569,6 @@ def phase_serve(rehearse: bool) -> int:
         "host_codec": host_codec,
         "counters": counters,
         "codec_lowering": lowering,
-        "autotune": _autotune_report(),
         "smoke_timing_not_benchmark": {
             "warmup_s": round(warmup_s, 2),
             "serve_s": round(serve_s, 2),

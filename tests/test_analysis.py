@@ -843,17 +843,14 @@ def test_trace_knob_fingerprint_moves_with_env(monkeypatch):
 
 
 def test_xla_trace_fingerprint_covers_pr11_kernel_knobs(monkeypatch):
-    # knob-key regression: CGX_SRA_ACCUM / CGX_PALLAS_DB lowered into the
-    # staged program body without re-keying the program LRU.
+    # knob-key regression: CGX_SRA_ACCUM lowered into the staged program
+    # body without re-keying the program LRU.
     from torch_cgx_tpu.parallel import xla_allreduce as xr
 
     base = xr._trace_env_fingerprint()
     monkeypatch.setenv("CGX_SRA_ACCUM", "int8")
     assert xr._trace_env_fingerprint() != base
     monkeypatch.delenv("CGX_SRA_ACCUM")
-    monkeypatch.setenv("CGX_PALLAS_DB", "on")
-    assert xr._trace_env_fingerprint() != base
-    monkeypatch.delenv("CGX_PALLAS_DB")
     monkeypatch.setenv("CGX_PALLAS_TILE_CHUNKS", "2")
     assert xr._trace_env_fingerprint() != base
 

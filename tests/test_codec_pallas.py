@@ -730,75 +730,8 @@ def test_fused_epilogue_tpu():
 
 
 # ---------------------------------------------------------------------------
-# Double-buffered manual-DMA lowerings (CGX_PALLAS_DB) + int8 epilogue
-# accumulation (CGX_SRA_ACCUM) — codec roofline round 2.
+# int8 epilogue accumulation (CGX_SRA_ACCUM) — codec roofline round 2.
 # ---------------------------------------------------------------------------
-
-
-def _db_case(rng, rows=2, chunks=4, bucket=512):
-    return jnp.asarray(
-        rng.standard_normal((rows, chunks * 32 * bucket)), jnp.float32
-    )
-
-
-@pytest.mark.parametrize("bits", [1, 4, 8])
-def test_db_quantize_bytes_match_grid(bits, monkeypatch):
-    """CGX_PALLAS_DB=on: the manual-DMA quantize emits byte-identical
-    words/meta to the grid kernel (per-block math is shared)."""
-    xs = _db_case(np.random.default_rng(21))
-    q_grid = codec_pallas.quantize_batch(xs, bits, 512, interpret=True)
-    monkeypatch.setenv("CGX_PALLAS_DB", "on")
-    q_db = codec_pallas.quantize_batch(xs, bits, 512, interpret=True)
-    np.testing.assert_array_equal(
-        np.asarray(q_grid.packed), np.asarray(q_db.packed)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(q_grid.meta), np.asarray(q_db.meta)
-    )
-
-
-def test_db_dequantize_and_fused_add_match_grid(monkeypatch):
-    rng = np.random.default_rng(22)
-    xs = _db_case(rng)
-    q = codec_pallas.quantize_batch(xs, 4, 512, interpret=True)
-    acc = jnp.asarray(rng.standard_normal(xs.shape), jnp.float32)
-    d_grid = codec_pallas.dequantize_batch(q, interpret=True)
-    a_grid = codec_pallas.dequantize_batch(q, add_to=acc, interpret=True)
-    monkeypatch.setenv("CGX_PALLAS_DB", "on")
-    d_db = codec_pallas.dequantize_batch(q, interpret=True)
-    a_db = codec_pallas.dequantize_batch(q, add_to=acc, interpret=True)
-    np.testing.assert_array_equal(np.asarray(d_grid), np.asarray(d_db))
-    np.testing.assert_array_equal(np.asarray(a_grid), np.asarray(a_db))
-
-
-def test_db_epilogue_bytes_match_grid(monkeypatch):
-    ws, bits, bucket = 4, 4, 512
-    rng = np.random.default_rng(23)
-    xs = jnp.asarray(
-        rng.standard_normal((ws, 2 * 32 * bucket)), jnp.float32
-    )
-    q = codec_pallas.quantize_batch(xs, bits, bucket, interpret=True)
-    own = jnp.int32(1)
-    e_grid = codec_pallas.sra_epilogue_batch(
-        q, raw_row=xs[1], own_idx=own, interpret=True
-    )
-    monkeypatch.setenv("CGX_PALLAS_DB", "on")
-    e_db = codec_pallas.sra_epilogue_batch(
-        q, raw_row=xs[1], own_idx=own, interpret=True
-    )
-    np.testing.assert_array_equal(
-        np.asarray(e_grid.packed), np.asarray(e_db.packed)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(e_grid.meta, np.float32),
-        np.asarray(e_db.meta, np.float32),
-    )
-
-
-def test_db_auto_is_inert_without_tuned_entry():
-    """auto (default) never engages the DB lowering unless a persisted
-    autotune entry measured it faster on this chip."""
-    assert not codec_pallas._use_db(None)
 
 
 def test_int8_accum_envelope(monkeypatch):
@@ -920,14 +853,12 @@ def test_lowering_ledger_names_what_ran():
 
 @pytest.mark.parametrize("bits", [4, 8])
 @pytest.mark.parametrize("bucket", [128, 512])
-def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket,
-                                                        monkeypatch):
+def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket):
     """The flat decode kernel's store (ISSUE 28): a ``bfloat16`` store is
     the float32 decode cast to ``bfloat16`` bit for bit, flat or as rows
-    of the consumer's width; the default call stays float32, byte for byte
-    what the double-buffered twin (which this change left alone) decodes;
-    an ``add_to`` keeps the float32 store (fused add, one cast after it),
-    and the ledger says which store ran."""
+    of the consumer's width; the default call stays float32; an ``add_to``
+    keeps the float32 store (fused add, one cast after it), and the ledger
+    says which store ran."""
     from torch_cgx_tpu.utils.logging import metrics
 
     rng = np.random.default_rng(bits * 1000 + bucket)
@@ -939,18 +870,7 @@ def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket,
     metrics.reset()
     f32 = codec_pallas.dequantize_batch(q, interpret=True)
     assert f32.dtype == jnp.float32 and f32.shape == (rows, numel)
-    with monkeypatch.context() as m:
-        m.setenv("CGX_PALLAS_DB", "on")
-        twin = codec_pallas.dequantize_batch(q, interpret=True)
-        # ... which stores float32 only: another type keeps to the grid.
-        codec_pallas.dequantize_batch(
-            q, interpret=True, out_dtype=jnp.bfloat16
-        )
-    np.testing.assert_array_equal(np.asarray(f32), np.asarray(twin))
     assert metrics.get("cgx.codec.lowering.dequantize.pallas_flat") == 1
-    assert metrics.get("cgx.codec.lowering.dequantize.pallas_flat_db") == 1
-    assert metrics.get(
-        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 1
 
     def bits_of(a):
         return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint16))
@@ -962,7 +882,7 @@ def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket,
     assert bf.dtype == jnp.bfloat16 and bf.shape == (rows, numel)
     np.testing.assert_array_equal(bits_of(bf), bits_of(cast))
     assert metrics.get(
-        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 2
+        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 1
 
     as_rows = codec_pallas.dequantize_batch(
         q, interpret=True, out_dtype=jnp.bfloat16, row_width=width
@@ -992,4 +912,194 @@ def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket,
     # ... through the float32 store: no further bfloat16 one was counted.
     assert metrics.get("cgx.codec.lowering.dequantize.pallas_flat") == 2
     assert metrics.get(
-        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 4
+        "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 3
+
+
+# ---------------------------------------------------------------------------
+# What the benchmark's cells run (ISSUE 29). The static tile functions are
+# the one owner of "which lowering, which tile": each case is one kernel call
+# site of a cell (PERF.md section 4, benchmark/configs/*.json), with the
+# lowering and the tile the program had at PR 28 with every CGX_* unset. A
+# change to a case is a change to a cell's program: say so in PERF.md.
+# ---------------------------------------------------------------------------
+
+_GPT2L_PAGE = 64 * 1280  # one layer's K or V page: 64 tokens x 20 heads x 64
+_JOYAI_C, _JOYAI_KR = 256 * 512, 256 * 64  # latent / rotated-key pages
+_TRAIN_FLAT = (1048576, 491520, 147456)  # GPT-2 124M fusion slices / ws 4
+_TRAIN_CHUNKS = (110592, 314880, 316096)  # ... whose rows end in a chunk tail
+
+
+def _cell_cases():
+    flat, chunks = "pallas_flat", "pallas_chunks"
+    read = dict(bits=8, rows=512, out_dtype=jnp.bfloat16)  # 32 lanes x 16 pages
+    yield "gpt2l-decode-read", "dequantize", dict(
+        read, numel=_GPT2L_PAGE, row_width=1280,
+    ), {"dequantize": "pallas_flat.bfloat16", "dequantize_rows": flat}, {
+        "_rows_tc": 10}
+    # A float16 payload: Mosaic refuses a float16 store, so float32 + a cast.
+    yield "gpt2l-decode-read-f16", "dequantize", dict(
+        read, numel=_GPT2L_PAGE, row_width=1280, out_dtype=jnp.float16,
+    ), {"dequantize": flat, "dequantize_rows": flat}, {"_rows_tc": 10}
+    yield "joyai-decode-read-c", "dequantize", dict(
+        read, numel=_JOYAI_C, row_width=512,
+    ), {"dequantize": "pallas_flat.bfloat16", "dequantize_rows": flat}, {
+        "_rows_tc": 16}
+    # 64 is not whole lanes: the kernel stores flat rows, XLA reshapes.
+    yield "joyai-decode-read-kr", "dequantize", dict(
+        read, numel=_JOYAI_KR, row_width=64,
+    ), {"dequantize": "pallas_flat.bfloat16",
+        "dequantize_rows": "xla_reshape"}, {"_rows_tc": None, "_pipe_tc": 16}
+    # Page commits: every lane's tail in the decode loop (32 rows), a padded
+    # prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and 3,072).
+    for name, numel, commits in (
+        ("gpt2l", _GPT2L_PAGE, {32: 16, 11: 11, 14: 14}),
+        ("joyai-c", _JOYAI_C, {32: 16, 8: 16, 12: 16}),
+        ("joyai-kr", _JOYAI_KR, {32: 16, 8: 8, 12: 12}),
+    ):
+        for rows, tc in commits.items():
+            yield f"{name}-commit-{rows}", "quantize", dict(
+                bits=8, rows=rows, numel=numel,
+            ), {"quantize": flat}, {"_pipe_tc": tc}
+    # gpt2s-dp4-q4: stage-1 quantize of the (ws, slice / ws) rows, the fused
+    # epilogue of the two slices over CGX_SRA_EPILOGUE_MIN_ELEMS (the staged
+    # one's stage-2 quantize of one row for the others), the all-gather
+    # leg's decode of (ws, chunk).
+    for numel, tc, tc_reduce in zip(_TRAIN_FLAT, (16, 15, 12), (8, 6, None)):
+        geo = dict(bits=4, rows=4, numel=numel)
+        yield f"train-quantize-{numel}", "quantize", geo, {
+            "quantize": flat}, {"_pipe_tc": tc}
+        yield f"train-allgather-decode-{numel}", "dequantize", dict(
+            geo, out_dtype=jnp.float32), {"dequantize": flat}, {"_pipe_tc": tc}
+        if tc_reduce:
+            for kind in ("sra_epilogue", "sra_epilogue_key"):
+                yield f"train-{kind}-{numel}", kind, geo, {
+                    "sra_epilogue": "pallas_fused"}, {"_reduce_tc": tc_reduce}
+    yield "train-stage2-quantize-147456", "quantize", dict(
+        bits=4, rows=1, numel=147456), {"quantize": flat}, {"_pipe_tc": 9}
+    for numel, tc1 in zip(_TRAIN_CHUNKS, (6, 16, 16)):
+        geo = dict(bits=4, rows=4, numel=numel)
+        yield f"train-quantize-{numel}", "quantize", geo, {
+            "quantize": chunks}, {"_chunks_tc": 16}
+        yield f"train-stage2-quantize-{numel}", "quantize", dict(
+            geo, rows=1), {"quantize": chunks}, {"_chunks_tc": tc1}
+        yield f"train-allgather-decode-{numel}", "dequantize", dict(
+            geo, out_dtype=jnp.float32), {"dequantize": chunks}, {
+            "_chunks_tc": 16}
+
+
+def _trace_cell_call(kind, *, bits, rows, numel, bucket=512, **kw):
+    """``jax.eval_shape`` of one wrapper call: nothing compiled or run."""
+    if kind == "quantize":
+        return jax.eval_shape(
+            lambda x: codec_pallas.quantize_batch(
+                x, bits, bucket, interpret=True),
+            jax.ShapeDtypeStruct((rows, numel), jnp.float32),
+        )
+    nb = codec.num_buckets(numel, bucket)
+
+    def q_of(packed, meta):
+        return codec.QTensor(
+            packed=packed, meta=meta,
+            residual=jnp.zeros((rows, 0), jnp.float32), numel=numel,
+            bits=bits, bucket_size=bucket, dtype=np.dtype(np.float32),
+        )
+
+    shapes = [
+        jax.ShapeDtypeStruct((rows, nb * bucket * bits // 32), jnp.uint32),
+        jax.ShapeDtypeStruct((rows, nb, 2), jnp.float32),
+    ]
+    if kind == "dequantize":
+        return jax.eval_shape(
+            lambda p, m: codec_pallas.dequantize_batch(
+                q_of(p, m), interpret=True, **kw),
+            *shapes,
+        )
+    assert codec_pallas.supports_reduce(q_of(*shapes), rows)
+    key = jax.random.PRNGKey(0) if kind == "sra_epilogue_key" else None
+    return jax.eval_shape(
+        lambda p, m, raw, own: codec_pallas.sra_epilogue_batch(
+            q_of(p, m), raw_row=raw, own_idx=own, key=key, interpret=True),
+        *shapes, jax.ShapeDtypeStruct((numel,), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "kind,geo,lowering,tiles",
+    [pytest.param(*case[1:], id=case[0]) for case in _cell_cases()],
+)
+def test_cells_lowering_and_tile(kind, geo, lowering, tiles, monkeypatch):
+    from torch_cgx_tpu.utils.logging import metrics
+
+    seen = {}  # conftest clears every CGX_*
+    for name in ("_pipe_tc", "_rows_tc", "_reduce_tc", "_chunks_tc"):
+        def spy(*a, _fn=getattr(codec_pallas, name), _name=name):
+            seen.setdefault(_name, []).append(_fn(*a))
+            return seen[_name][-1]
+
+        monkeypatch.setattr(codec_pallas, name, spy)
+    assert codec_pallas.supports(geo["numel"], geo["bits"], 512, False)
+    metrics.reset()
+    _trace_cell_call(kind, **geo)
+    ledger = {
+        k[len("cgx.codec.lowering."):]: v
+        for k, v in metrics.snapshot("cgx.codec.lowering.").items()
+    }
+    assert ledger == {f"{site}.{low}": 1 for site, low in lowering.items()}
+    assert {k: seen[k] for k in tiles} == {k: [v] for k, v in tiles.items()}
+
+
+def _cell_geometries():
+    """(rows, numel, bits) of every cell case above, once each."""
+    return sorted({
+        (c[2]["rows"], c[2]["numel"], c[2]["bits"]) for c in _cell_cases()
+    })
+
+
+def test_rows_tc_refuses_what_no_block_can_hold():
+    """``_rows_tc`` answers None (the caller lets XLA reshape) for a row
+    that is not whole 128-lane columns and for a chunk count none of whose
+    divisors makes a block whole rows; any tile it does give divides the
+    chunks, holds whole rows, and fills whole sublane tiles of the store."""
+    f32, bf16 = np.dtype(np.float32), np.dtype(jnp.bfloat16)
+    for width in (64, 192, 1280 + 64):
+        assert codec_pallas._rows_tc(2560, 512, width, bf16) is None
+    # 1,280-wide bfloat16 rows need blocks of 5 chunks: 7 and 16 chunks
+    # have no such divisor under the cap, 10 has.
+    assert codec_pallas._rows_tc(7, 512, 1280, bf16) is None
+    assert codec_pallas._rows_tc(16, 512, 1280, bf16) is None
+    assert codec_pallas._rows_tc(10, 512, 1280, bf16) == 10
+    for rows, numel, _ in _cell_geometries():
+        n_chunks, tail = divmod(rows * numel, 32 * 512)
+        for width in (128, 512, 1280):
+            for store in (f32, bf16):
+                tc = codec_pallas._rows_tc(n_chunks, 512, width, store)
+                if tail or tc is None:
+                    continue
+                assert n_chunks % tc == 0 and tc <= 16
+                assert tc * 32 * 512 % (width * 32 // store.itemsize) == 0
+
+
+@pytest.mark.parametrize("forced", [None, "4"])
+def test_reduce_tc_is_pipe_tc_where_vmem_allows(forced, monkeypatch):
+    """The fused epilogue's requantize draws its stochastic rounding per
+    grid step, so its grid must be the staged stage-2 quantize's
+    (``_pipe_tc`` over the reduced row) wherever the ws-way block fits the
+    VMEM budget, and a divisor of the row's chunks within it elsewhere —
+    with ``CGX_PALLAS_TILE_CHUNKS`` forced as without."""
+    if forced:
+        monkeypatch.setenv("CGX_PALLAS_TILE_CHUNKS", forced)
+    chunk_rows = {
+        numel // (32 * 512) for _, numel, _ in _cell_geometries()
+        if numel % (32 * 512) == 0
+    }
+    assert {64, 30, 9} <= chunk_rows  # the training cell's flat slices
+    for c_r in sorted(chunk_rows):
+        for ws in (2, 4, 8, 16):
+            budget = codec_pallas.MAX_REDUCE_BLOCK_ELEMS // (2 * ws * 32 * 512)
+            staged = codec_pallas._pipe_tc(c_r, 512)
+            tc = codec_pallas._reduce_tc(c_r, 512, ws)
+            if staged <= budget:
+                assert tc == staged, (c_r, ws)
+            else:
+                assert c_r % tc == 0 and 1 <= tc <= max(1, budget), (c_r, ws)

@@ -127,6 +127,31 @@ def test_cost_model_empty_dir_keeps_defaults(tmp_path):
     )
 
 
+def test_from_telemetry_with_nothing_to_read_is_the_default(monkeypatch):
+    """No span directory, no step histogram, no async gauge."""
+    from torch_cgx_tpu.utils.logging import metrics
+
+    monkeypatch.delenv("CGX_METRICS_DIR", raising=False)
+    metrics.reset()
+    m = planner.CostModel.from_telemetry()
+    assert m == planner.CostModel.default() and m.source == "default"
+
+
+def test_from_telemetry_names_the_step_histogram_alone(monkeypatch):
+    """A step histogram alone moves ``compute_s`` and nothing else."""
+    from torch_cgx_tpu.utils.logging import metrics
+
+    monkeypatch.delenv("CGX_METRICS_DIR", raising=False)
+    metrics.reset()
+    metrics.observe("cgx.step.time_s", 0.25)
+    m = planner.CostModel.from_telemetry()
+    assert m.source == "default+step_p50"
+    assert m == dataclasses.replace(
+        planner.CostModel.default(), compute_s=0.25, source=m.source
+    )
+    assert not m.calibrated
+
+
 def test_predict_slice_shape():
     m = planner.CostModel.default()
     n = 1 << 22

@@ -293,6 +293,19 @@ def test_invalidate_trace_caches_bumps_registry_version():
     assert cfg.registry_version() == v0 + 1
 
 
+def test_invalidate_trace_caches_empties_serving_programs():
+    """The cascade reaches the serving plane's compiled-program LRU and
+    says so in ``cgx.serve.program_invalidations``."""
+    from torch_cgx_tpu.serving import scheduler as sched_mod
+    from torch_cgx_tpu.utils.logging import metrics
+
+    sched_mod._PROGRAM_CACHE[("sentinel",)] = object()
+    before = metrics.get("cgx.serve.program_invalidations")
+    invalidate_trace_caches()
+    assert len(sched_mod._PROGRAM_CACHE) == 0
+    assert metrics.get("cgx.serve.program_invalidations") == before + 1
+
+
 def test_invalidate_trace_caches_resets_qerr_sampling():
     # ISSUE 6 satellite: the flightrec qerr subsample cadence
     # (allreduce._QERR_SEEN) must restart with the registry-version bump —

@@ -62,10 +62,6 @@ INERT_KNOBS: Dict[str, str] = {
     # keyed by its own env spec at injector-construction time — a seed
     # flip re-seeds injection, never what a cached program computes.
     "CGX_FAULTS_SEED": "host-side fault injection seed; wraps, never lowers",
-    # Autotune DIRECTORY only moves the on-disk cache location the tuner
-    # loads from; the decisions lowering consumes (CGX_AUTOTUNE mode +
-    # the loaded per-chip entries) ARE keyed (_trace_env_fingerprint).
-    "CGX_AUTOTUNE_DIR": "on-disk cache location; tuner decisions are keyed",
 }
 
 

@@ -420,9 +420,9 @@ _METRIC_NAMESPACES = ("cgx.", "span.")
 # (`cgx.arena_pressure_waits`) and dynamic prefixes that stop at `cgx.`
 # stay uncheckable and pass.
 _METRIC_CGX_SUBNAMESPACES = frozenset({
-    # "codec" joined with the roofline round-2 work (PR 11): the kernel
-    # autotuner (cgx.codec.autotune_*) and the producer-fused gradient
-    # quantizer (cgx.codec.producer_*) — docs/OBSERVABILITY.md.
+    # "codec" joined with the roofline round-2 work (PR 11): the
+    # producer-fused gradient quantizer (cgx.codec.producer_*) and the
+    # lowering ledger (cgx.codec.lowering.*) — docs/OBSERVABILITY.md.
     # "plan" is the whole-step planner family (PR 12): plan-LRU
     # hits/misses/invalidations, per-slice chunk/bit gauges, the
     # predicted-step gauge and the bridge depth hints —
@@ -586,7 +586,7 @@ def check_reducer_reduce_routing(path: Path, tree: ast.Module) -> List[str]:
 # under ops/) may never materialize a full-width f32 intermediate from
 # decoded peer rows: the audited f32 fold lives in ONE place —
 # ``codec_pallas._decode_accumulate`` (with ``_requant_cast``/
-# ``_raw4_cast`` for the small requantize-cast and raw-chunk reads) —
+# ``_read_raw4`` for the small requantize-cast and raw-chunk reads) —
 # and the int8 fixed-point accumulation mode exists precisely so new
 # kernel code folds rows in the integer level domain. ``_reference``/
 # ``_staged``-suffixed functions are the suite's escape hatch, as in the

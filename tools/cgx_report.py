@@ -411,29 +411,13 @@ def summarize(data: dict) -> dict:
             "slices": {k: dict(v) for k, v in sorted(plan_slices.items())},
             "counters": plan_counters,
         }
-    # Codec plane: autotune cache efficiency + producer-fuse consumption
-    # (counters summed across ranks) and the measured roofline fraction
-    # (a gauge — max across ranks, like the controller bit levels; a
-    # hardware session watches this converge toward 1.0).
+    # Codec plane: lowering ledger + producer-fuse consumption (counters
+    # summed across ranks).
     codec_counters = {
-        k: v for k, v in totals.items()
-        if k.startswith("cgx.codec.") and k != "cgx.codec.roofline_frac"
+        k: v for k, v in totals.items() if k.startswith("cgx.codec.")
     }
-    roofline = 0.0
-    for per_rank in rank_counters.values():
-        roofline = max(
-            roofline, per_rank.get("cgx.codec.roofline_frac", 0.0)
-        )
-    if codec_counters or roofline:
-        hits = codec_counters.get("cgx.codec.autotune_hits", 0.0)
-        misses = codec_counters.get("cgx.codec.autotune_misses", 0.0)
-        summary["codec"] = {
-            "autotune_hit_rate": (
-                round(hits / (hits + misses), 3) if hits + misses else None
-            ),
-            "roofline_frac": round(roofline, 4) if roofline else None,
-            "counters": codec_counters,
-        }
+    if codec_counters:
+        summary["codec"] = {"counters": codec_counters}
     # Asynchronous cross-slice plane (PR 13): outer-round progress,
     # on-time rate, worst lag and the sender's measured DCN rate.
     # Counters sum across ranks; gauges are levels (max-folded above).
@@ -799,16 +783,7 @@ def render(summary: dict) -> str:
             parts.append(f"  {k}: {v:g}")
     if summary.get("codec"):
         c = summary["codec"]
-        parts.append("\n== codec (kernel autotune + producer fuse) ==")
-        if c.get("autotune_hit_rate") is not None:
-            parts.append(
-                f"  autotune cache hit rate: {c['autotune_hit_rate']:.1%}"
-            )
-        if c.get("roofline_frac"):
-            parts.append(
-                "  measured quantize roofline fraction: "
-                f"{c['roofline_frac']:.1%}"
-            )
+        parts.append("\n== codec (kernel lowerings + producer fuse) ==")
         for k, v in sorted(c.get("counters", {}).items()):
             parts.append(f"  {k}: {v:g}")
     # cgx.recovery.* counters are NOT repeated here — the recovery

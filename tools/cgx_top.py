@@ -8,7 +8,7 @@ atomically-replaced status snapshots (``health-status-rank<N>.json``),
 health event streams (``health-rank<N>.jsonl``) and flight-recorder
 dumps — and renders one row per rank:
 
-    rank  steps/s  allreduce p50/p99 (ms)  wire ratio  edges  overlap  sched$  plan$  pred  atune$  roofl  lag  async$  link  straggler  gen  ws  last fault
+    rank  steps/s  allreduce p50/p99 (ms)  wire ratio  edges  overlap  sched$  plan$  pred  lag  async$  link  straggler  gen  ws  last fault
 
 * **steps/s** — delta of the ``cgx.step.count`` counter between two
   refreshes (the first frame shows ``-``); bridge-only ranks (no JAX
@@ -31,13 +31,6 @@ dumps — and renders one row per rank:
   or predicted-step gauge / step-time p50 live): < 1 means the
   planner's cost model underpredicts reality — drift toward the
   ``bench_gate`` prediction floor.
-* **atune$** — codec-autotune cache hit rate from the
-  ``cgx.codec.autotune_*`` counters (``-`` until the tuner is
-  consulted; climbs as the persisted per-chip cache warms).
-* **roofl** — measured quantize roofline fraction (the
-  ``cgx.codec.roofline_frac`` gauge ``bench.py --codec-roofline``
-  publishes): how close the codec kernels sit to the chip's HBM
-  roofline, live, so a hardware session can watch tuning converge.
 * **lag** — the async cross-slice plane's worst peer staleness in outer
   rounds (the ``cgx.async.lag_rounds`` gauge; ``-`` until an outer
   round has run). Climbing toward ``CGX_ASYNC_MAX_LAG`` means a slice's
@@ -314,26 +307,6 @@ def _crit(directory: str, state: dict) -> str:
         return "-"
 
 
-def _autotune_cache(m: Dict[str, float]) -> str:
-    """Codec autotune cache hit rate (``cgx.codec.autotune_*``) — a
-    hardware session watches this climb as the persisted per-chip cache
-    warms; ``-`` while the tuner is off / unconsulted."""
-    hits = m.get("cgx.codec.autotune_hits", 0.0)
-    misses = m.get("cgx.codec.autotune_misses", 0.0)
-    total = hits + misses
-    if not total:
-        return "-"
-    return f"{hits / total * 100:.0f}%"
-
-
-def _roofline(m: Dict[str, float]) -> str:
-    """Measured quantize roofline fraction (the ``cgx.codec.
-    roofline_frac`` gauge ``bench.py --codec-roofline`` publishes) —
-    the convergence number of the kernel-tuning story."""
-    v = m.get("cgx.codec.roofline_frac", 0.0)
-    return f"{v:.2f}" if v else "-"
-
-
 def _async_lag(m: Dict[str, float]) -> str:
     """Worst peer-slice staleness in outer rounds (``cgx.async.
     lag_rounds``) — ``-`` until the async plane has run a round."""
@@ -439,7 +412,7 @@ def render(directory: str, state: dict) -> str:
     ]
     headers = ("rank", "steps/s", "ar_p50ms", "ar_p99ms", "wire",
                "edges", "overlap", "sched$", "plan$", "pred", "crit",
-               "atune$", "roofl", "lag", "async$", "link", "tok/s", "ttft",
+               "lag", "async$", "link", "tok/s", "ttft",
                "mem", "frag", "straggler", "gen", "ws", "last_fault")
     rows: List[Tuple[str, ...]] = []
     events: List[str] = []
@@ -460,8 +433,6 @@ def render(directory: str, state: dict) -> str:
             _plan_cache(m),
             _pred(m),
             crit,
-            _autotune_cache(m),
-            _roofline(m),
             _async_lag(m),
             _async_rate(m),
             _link(m),

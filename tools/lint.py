@@ -64,7 +64,7 @@ def check_file(path: Path) -> list:
     return perfile.check_file(Path(path))
 
 
-DEFAULT_PATHS = ["torch_cgx_tpu", "examples", "tests", "tools", "bench.py",
+DEFAULT_PATHS = ["torch_cgx_tpu", "examples", "tests", "tools",
                  "__graft_entry__.py"]
 
 

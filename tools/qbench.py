@@ -12,7 +12,7 @@ output incremental):
     python tools/qbench.py dequant        # public dequantize_batch
 
 All operands are generated on-device (no host->device copy of benchmark
-payloads) and sized to 128 MB by default. Timing is the same scan-slope method as bench.py.
+payloads) and sized to 128 MB by default. Timing is a scan slope (scan_time).
 Experimental kernels are byte-checked against the XLA codec oracle on a
 small slice before timing — a variant that changes the wire is reported,
 not silently timed.
@@ -21,7 +21,10 @@ not silently timed.
 from __future__ import annotations
 
 import argparse
+import json
+import os
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -32,9 +35,36 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-from bench import scan_time  # noqa: E402 — single source of timing truth
 
 CB = 32  # chunk buckets (codec.CHUNK_BUCKETS)
+
+
+def scan_time(fn, stack, iters: int = 6) -> float:
+    """Marginal per-execution seconds: slope between a K-length and a
+    1-length scan over stacked operand sets (dispatch overhead cancels)."""
+
+    def runner(s):
+        def body(c, x):
+            out = fn(x)
+            leaf = jax.tree.leaves(out)[0]
+            return c + leaf.ravel()[0].astype(jnp.float32), 0
+
+        return lax.scan(body, jnp.float32(0), s)[0]
+
+    jr = jax.jit(runner)
+
+    def timed(s):
+        np.asarray(jr(s))  # warm + sync
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            o = jr(s)
+        np.asarray(o)
+        return (time.perf_counter() - t0) / iters
+
+    k = jax.tree.leaves(stack)[0].shape[0]
+    t_k = timed(stack)
+    t_1 = timed(jax.tree.map(lambda a: a[:1], stack))
+    return max((t_k - t_1) / (k - 1), 1e-9)
 
 
 def make_variant_kernel(name: str, bits: int, b: int, tc: int):
@@ -187,8 +217,6 @@ def main():
     if args.k < 2:
         ap.error("--k must be >= 2 (slope timing needs two scan lengths)")
 
-    import os
-
     if args.tc:
         os.environ["CGX_PALLAS_TILE_CHUNKS"] = str(args.tc)
 
@@ -313,8 +341,6 @@ def main():
         f = run_variant_kernel(args.variant, stack[0], bits, b, tc)
         t = scan_time(f, stack)
 
-    from bench import log_jsonl
-
     # scan_time clamps a non-positive slope to 1e-9 s; at any real payload
     # that means dispatch noise swamped the k-spread (seen 2026-07-31 on a
     # noisy transport day) — record the measurement as unresolved (null
@@ -345,8 +371,8 @@ def main():
         rec["t_ms"] = round(t * 1e3, 3)
         rec["gbps_in"] = round(gb / t, 1)
         line = f"{prefix} t={t * 1e3:.3f} ms  {gb / t:.1f} GB/s(in)"
-    log_jsonl(rec)
     print(line)
+    print(json.dumps(rec))
 
 
 if __name__ == "__main__":

@@ -95,14 +95,11 @@ WIRE_BITS = "CGX_WIRE_BITS"  # env-default bits for unregistered edges
 PLANNER = "CGX_PLANNER"  # auto | on | off — step-level plan compiler
 PLANNER_AVG_BITS = "CGX_PLANNER_AVG_BITS"  # joint-solve bit budget (0 = off)
 PLANNER_MODEL = "CGX_PLANNER_MODEL"  # calibrated CostModel json (group-wide)
-# Codec roofline round 2 (ops/codec_pallas.py + ops/autotune.py +
-# ops/fused_producer.py — PR 11):
-PALLAS_DB = "CGX_PALLAS_DB"  # auto | on | off — double-buffered DMA kernels
+# Codec roofline round 2 (ops/codec_pallas.py + ops/fused_producer.py —
+# PR 11):
 PALLAS_PACK = "CGX_PALLAS_PACK"  # sum | butterfly — bit-plane pack lowering
 PALLAS_TILE_CHUNKS = "CGX_PALLAS_TILE_CHUNKS"  # explicit tile override
 SRA_ACCUM = "CGX_SRA_ACCUM"  # exact | int8 — epilogue accumulation domain
-AUTOTUNE = "CGX_AUTOTUNE"  # auto | on | off — per-chip tile autotuner
-AUTOTUNE_DIR = "CGX_AUTOTUNE_DIR"  # on-disk autotune cache location
 PRODUCER_FUSE = "CGX_PRODUCER_FUSE"  # auto | on | off — fused grad quantize
 # Asynchronous cross-slice plane (parallel/async_plane.py +
 # torch_backend/async_bridge.py — PR 13): decoupled DCN exchange with
@@ -539,31 +536,6 @@ def sra_epilogue_min_elems() -> int:
     return max(v, 0)
 
 
-def pallas_db() -> str:
-    """CGX_PALLAS_DB: double-buffered manual-DMA lowering of the flat
-    Pallas codec kernels (quantize / dequantize / fused SRA epilogue):
-
-    * "auto" (default) — double-buffer only where a persisted autotune
-      entry for this chip says the DB lowering measured faster
-      (``ops/autotune.py``); with no tuned entry the grid kernels run
-      unchanged on every backend (tier-1 inertness, and no untested
-      Mosaic lowering ever engages on hardware by default — the
-      BENCH_r05 wedge lesson).
-    * "on" — force the DB kernels anywhere they geometrically apply
-      (interpret mode included — the byte-parity test knob).
-    * "off" — never; the grid kernels run unchanged.
-
-    Deterministic wire bytes are identical between the two lowerings (the
-    per-block math is op-for-op the grid kernel; asserted in
-    tests/test_codec_pallas.py); stochastic draws reseed per block with
-    the block index exactly like the grid's ``program_id`` seeding, so
-    stochastic bytes match too."""
-    mode = _env.get_str_env_or_default(PALLAS_DB, "auto").lower()
-    if mode not in ("auto", "on", "off"):
-        raise ValueError(f"{PALLAS_DB} must be auto|on|off, got {mode!r}")
-    return mode
-
-
 def sra_accum() -> str:
     """CGX_SRA_ACCUM: accumulation domain of the fused SRA epilogue's
     peer-row fold (``codec_pallas._sra_epilogue_kernel``):
@@ -585,31 +557,6 @@ def sra_accum() -> str:
     if mode not in ("exact", "int8"):
         raise ValueError(f"{SRA_ACCUM} must be exact|int8, got {mode!r}")
     return mode
-
-
-def autotune_mode() -> str:
-    """CGX_AUTOTUNE: the per-chip codec tile autotuner (``ops/autotune.py``):
-
-    * "auto" (default) — consult the persisted on-disk cache when an
-      entry exists for this (kernel, shape, bits, bucket, chip); fall
-      back to the static heuristics otherwise. Never measures. With no
-      cache file present this is fully inert (the heuristics run
-      unchanged — the tier-1 inertness contract).
-    * "on" — additionally measure-and-persist a missing entry the first
-      time a kernel shape is dispatched on a real device (a short timed
-      sweep per shape; intended for hardware sessions, not CI).
-    * "off" — never consult or measure; static heuristics only."""
-    mode = _env.get_str_env_or_default(AUTOTUNE, "auto").lower()
-    if mode not in ("auto", "on", "off"):
-        raise ValueError(f"{AUTOTUNE} must be auto|on|off, got {mode!r}")
-    return mode
-
-
-def autotune_dir() -> Optional[str]:
-    """CGX_AUTOTUNE_DIR: directory of the persisted autotune cache
-    (``autotune-<chip-slug>.json``). Unset = ``~/.cache/torch_cgx_tpu``."""
-    v = _env.get_str_env_or_default(AUTOTUNE_DIR, "")
-    return v or None
 
 
 def producer_fuse() -> str:
@@ -1252,8 +1199,6 @@ def trace_knob_fingerprint() -> Tuple:
         sra_epilogue(),
         sra_epilogue_min_elems(),
         sra_accum(),
-        pallas_db(),
-        autotune_mode(),
         dummy_compression(),
         force_codec(),
         fake_ratio(),

@@ -75,7 +75,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import autotune, codec
+from . import codec
 from .. import config as cfg_mod
 from ..utils import env as _env
 from ..utils.logging import metrics
@@ -86,20 +86,6 @@ MAX_BUCKET_ELEMS = 16384  # VMEM guard for the (32, bucket) chunk tile
 # Types the flat decode kernel stores itself; any other is a cast after it
 # (Mosaic, libtpu 0.0.34, refuses a float16 store on v5e: pack_subelements).
 _FLAT_STORE_DTYPES = (np.dtype(np.float32), np.dtype(jnp.bfloat16))
-
-
-def _use_db(tuned: "autotune.TunedConfig | None") -> bool:
-    """Whether the double-buffered manual-DMA lowering runs for a flat
-    kernel: ``CGX_PALLAS_DB=on`` forces it; "auto" engages only when a
-    persisted autotune entry for this chip measured the DB lowering
-    faster (never an untested Mosaic lowering by default — the BENCH_r05
-    wedge lesson); "off" never."""
-    mode = cfg_mod.pallas_db()
-    if mode == "off":
-        return False
-    if mode == "on":
-        return True
-    return bool(tuned is not None and tuned.db)
 
 
 def note_lowering(site: str, lowering: str) -> None:
@@ -132,9 +118,8 @@ def supports(n: int, bits: int, bucket_size: int, skip_incomplete: bool) -> bool
 
 
 def _forced_tile_chunks() -> Optional[int]:
-    """The explicit CGX_PALLAS_TILE_CHUNKS override — strongest tier,
-    beating both the heuristic and any autotuned entry (the hardware
-    sweep's per-run knob must always win)."""
+    """The explicit CGX_PALLAS_TILE_CHUNKS override: it beats the static
+    heuristic of every tile function below."""
     forced = _env.get_optional_str_env("CGX_PALLAS_TILE_CHUNKS")
     if not forced:
         return None
@@ -149,26 +134,17 @@ def _forced_tile_chunks() -> Optional[int]:
     return tc
 
 
-def _tile_chunks(
-    n_chunks: int,
-    bucket_size: int,
-    bits: int,
-    tuned: "autotune.TunedConfig | None" = None,
-) -> int:
+def _tile_chunks(n_chunks: int, bucket_size: int, bits: int) -> int:
     """Chunks per grid step. Bounded so a block (x + levels + words + out)
     stays well inside VMEM; large tiles amortize per-step grid overhead.
-    Resolution order: the CGX_PALLAS_TILE_CHUNKS override, then a
-    measured per-chip autotune entry (``tuned``, still VMEM-capped so a
-    stale cache can never stage an over-budget block), then the static
-    heuristic. Read from the UNJITTED public wrappers so the env override
-    is honored (and validated) on every call, then passed as a static
-    argument."""
+    Resolution order: the CGX_PALLAS_TILE_CHUNKS override, then the
+    static heuristic. Read from the UNJITTED public wrappers so the env
+    override is honored (and validated) on every call, then passed as a
+    static argument."""
     forced = _forced_tile_chunks()
     if forced is not None:
         return forced
     cap = max(1, (1 << 19) // (CHUNK_BUCKETS * bucket_size))
-    if tuned is not None:
-        return int(max(1, min(tuned.tc, cap, max(1, n_chunks))))
     return int(min(16, cap, max(1, n_chunks)))
 
 
@@ -185,22 +161,17 @@ def _chunk_tile_fits(bits: int, bucket_size: int) -> bool:
     return _chunk_quantum(bits) * CHUNK_BUCKETS * bucket_size <= (1 << 20)
 
 
-def _chunks_tc(
-    n_chunks: int,
-    bucket_size: int,
-    bits: int,
-    tuned: "autotune.TunedConfig | None" = None,
-) -> int:
+def _chunks_tc(n_chunks: int, bucket_size: int, bits: int) -> int:
     """Tile of the chunk-block kernels. Mosaic refuses a word block whose
     sublane extent is neither a multiple of 8 nor the whole array
     (libtpu 0.0.34: bits=3/bucket=4096, bits=4/bucket=16384 and a forced
     ``tc=3`` all fail to lower). ``_tile_chunks`` knows nothing of that —
-    at bucket 512 it holds only because its cap is 16 — so every tier's
-    answer (heuristic, autotuned, forced) is rounded down here to the
+    at bucket 512 it holds only because its cap is 16 — so either tier's
+    answer (heuristic, forced) is rounded down here to the
     :func:`_chunk_quantum` unless one block spans the array. Callers
     gate on :func:`supports`, which refuses geometries whose quantum
     does not fit VMEM."""
-    tc = _tile_chunks(n_chunks, bucket_size, bits, tuned)
+    tc = _tile_chunks(n_chunks, bucket_size, bits)
     if tc >= n_chunks:
         return n_chunks
     quantum = _chunk_quantum(bits)
@@ -239,23 +210,18 @@ def _encode_lvl(x, bmin, safe, r, maxlvl, encode: str):
     ).astype(jnp.int32)
 
 
-def _pack_strategy(tuned: "autotune.TunedConfig | None" = None) -> str:
+def _pack_strategy() -> str:
     """Bit-plane pack lowering: ``sum`` (cross-sublane reduction of shifted
     bits — the default) or ``butterfly`` (log2(32) pairwise shift-OR folds).
     Both emit identical bytes (CPU-asserted in the suite); the knob exists
     so the faster lowering can be picked empirically per chip generation
-    without a code change. An explicit CGX_PALLAS_PACK wins; with the env
-    unset, a measured per-chip autotune entry (``tuned.pack``) is used."""
-    raw = (_env.get_optional_str_env("CGX_PALLAS_PACK") or "").lower()
-    if raw and raw not in ("sum", "butterfly"):
+    without a code change."""
+    raw = (_env.get_optional_str_env("CGX_PALLAS_PACK") or "sum").lower()
+    if raw not in ("sum", "butterfly"):
         raise ValueError(
             f"CGX_PALLAS_PACK={raw!r}: expected 'sum' or 'butterfly'"
         )
-    if raw:
-        return raw
-    if tuned is not None and tuned.pack in ("sum", "butterfly"):
-        return tuned.pack
-    return "sum"
+    return raw
 
 
 def _pack_planes(lvl, bits: int, sub_axis: int, strategy: str):
@@ -282,14 +248,11 @@ def _pack_planes(lvl, bits: int, sub_axis: int, strategy: str):
     return planes
 
 
-def _stochastic_r(seed_ref, shape, block_idx=None):
+def _stochastic_r(seed_ref, shape):
     """In-kernel U[0,1) rounding offsets from the hardware PRNG. Routed
     through int32 because Mosaic lacks uint32->f32 (values stay < 2^24).
-    ``block_idx`` defaults to the grid step; the double-buffered kernels
-    pass their loop index instead — same per-block seed, same draw shape,
-    therefore bit-identical stochastic bytes across the two lowerings."""
-    if block_idx is None:
-        block_idx = pl.program_id(0)
+    Reseeded per grid step."""
+    block_idx = pl.program_id(0)
     pltpu.prng_seed(seed_ref[0, 0] + block_idx)
     rbits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
     return (rbits >> np.uint32(8)).astype(jnp.int32).astype(
@@ -340,17 +303,11 @@ def _dequantize_kernel(words_ref, meta_ref, out_ref, *, bits, tc):
     )
 
 
-def _pipe_tc(
-    n_chunks: int,
-    bucket_size: int,
-    tuned: "autotune.TunedConfig | None" = None,
-) -> int:
+def _pipe_tc(n_chunks: int, bucket_size: int) -> int:
     """Chunks per block for the flat fast path: the largest candidate within
     the VMEM cap that divides the total chunk count (the flat grid tiles all
-    rows' chunks as one contiguous sequence). A measured autotune entry
-    (``tuned.tc``) replaces the heuristic candidate, snapped to the same
-    divisibility/VMEM constraints."""
-    cap = _tile_chunks(n_chunks, bucket_size, 8, tuned)
+    rows' chunks as one contiguous sequence)."""
+    cap = _tile_chunks(n_chunks, bucket_size, 8)
     for tc in range(min(cap, n_chunks), 0, -1):
         if n_chunks % tc == 0:
             return tc
@@ -403,8 +360,8 @@ def _quantize_flat_impl(
     # codec invocations by it (test_reducers codec-invocation guard) and a
     # device trace shows the kernel under it.
     # The block math lives in _requantize_block — shared with the fused
-    # SRA epilogue's requantize and the DB lowering, so the wire contract
-    # cannot drift between them. (The rb sublane-group axis reduces FIRST
+    # SRA epilogue's requantize, so the wire contract cannot drift between
+    # them. (The rb sublane-group axis reduces FIRST
     # in there — full-width elementwise folds before the cross-lane
     # reduction; max/min are order-independent: bytes unchanged.)
     def _quantize_flat_kernel(seed_ref, x_ref, words_ref, meta_ref):
@@ -567,295 +524,6 @@ def _dequantize_flat_impl(
     return out.reshape(rows, nb_r * b)
 
 
-# ---------------------------------------------------------------------------
-# Double-buffered manual-DMA lowerings (CGX_PALLAS_DB). The grid kernels
-# above lean on Mosaic's automatic block pipeline; these variants own the
-# whole HBM stream instead: ONE kernel invocation walks the blocks with
-# 2-slot VMEM scratch per stream, starting block k+1's input copy while
-# block k computes and letting block k's OUTPUT copy drain under block
-# k+1's compute — input and output DMA both overlap compute, which the
-# automatic pipeline cannot guarantee for multi-output kernels. The
-# per-block math is the SAME ``_requantize_block``/``_decode_accumulate``
-# helpers as the grid kernels (stochastic draws reseed per block index
-# exactly like the grid's ``program_id`` seeding), so wire bytes are
-# bit-identical between the two lowerings — asserted in interpret mode by
-# tests/test_codec_pallas.py.
-# ---------------------------------------------------------------------------
-
-
-def _slot_store(ref, slot, val):
-    """Predicated store into a 2-slot scratch (dynamic-index VMEM stores
-    are not guaranteed by Mosaic; two predicated static-slot stores are)."""
-
-    @pl.when(slot == 0)
-    def _():
-        ref[0] = val
-
-    @pl.when(slot != 0)
-    def _():
-        ref[1] = val
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "bits", "bucket_size", "stochastic", "interpret", "tc", "pack",
-        "encode",
-    ),
-)
-def _quantize_flat_db_impl(
-    xs: jax.Array,
-    seed: jax.Array,
-    *,
-    bits: int,
-    bucket_size: int,
-    stochastic: bool,
-    interpret: bool = False,
-    tc: int = 8,
-    pack: str = "sum",
-    encode: str = "div",
-):
-    """Double-buffered sibling of :func:`_quantize_flat_impl` — same
-    contract, same wire bytes, manual in/out DMA pipeline."""
-    rows, m_pad = xs.shape
-    b = bucket_size
-    rb = b // 128
-    n_chunks = rows * m_pad // (CHUNK_BUCKETS * b)
-    nblk = n_chunks // tc
-    in_rows = tc * CHUNK_BUCKETS * rb
-    w_rows = tc * bits * rb
-    m_rows = tc * CHUNK_BUCKETS
-
-    def _quantize_flat_db_kernel(seed_ref, x_hbm, words_hbm, meta_hbm):
-        def body(xb, wb, mb, in_sem, w_sem, m_sem):
-            def in_dma(slot, i):
-                return pltpu.make_async_copy(
-                    x_hbm.at[pl.ds(i * in_rows, in_rows)], xb.at[slot],
-                    in_sem.at[slot],
-                )
-
-            def w_dma(slot, i):
-                return pltpu.make_async_copy(
-                    wb.at[slot], words_hbm.at[pl.ds(i * w_rows, w_rows)],
-                    w_sem.at[slot],
-                )
-
-            def m_dma(slot, i):
-                return pltpu.make_async_copy(
-                    mb.at[slot], meta_hbm.at[pl.ds(i * m_rows, m_rows)],
-                    m_sem.at[slot],
-                )
-
-            in_dma(0, 0).start()
-
-            def step(i, carry):
-                cur = i % 2
-
-                @pl.when(i + 1 < nblk)
-                def _():
-                    in_dma((i + 1) % 2, i + 1).start()
-
-                in_dma(cur, i).wait()
-
-                # This slot's block-(i-2) output copies must land before
-                # the scratch is overwritten.
-                @pl.when(i >= 2)
-                def _():
-                    w_dma(cur, i - 2).wait()
-                    m_dma(cur, i - 2).wait()
-
-                x4 = xb[cur].astype(jnp.float32).reshape(
-                    tc, CHUNK_BUCKETS, rb, 128
-                )
-                words, meta = _requantize_block(
-                    x4, seed_ref, bits=bits, tc=tc, rb=rb,
-                    stochastic=stochastic, pack=pack, encode=encode,
-                    block_idx=i,
-                )
-                _slot_store(wb, cur, words)
-                _slot_store(mb, cur, meta)
-                w_dma(cur, i).start()
-                m_dma(cur, i).start()
-                return carry
-
-            jax.lax.fori_loop(0, nblk, step, 0)
-            for j in range(max(0, nblk - 2), nblk):  # static drain
-                w_dma(j % 2, j).wait()
-                m_dma(j % 2, j).wait()
-
-        pl.run_scoped(
-            body,
-            xb=pltpu.VMEM((2, in_rows, 128), xs.dtype),
-            wb=pltpu.VMEM((2, w_rows, 128), jnp.int32),
-            mb=pltpu.VMEM((2, m_rows, 2), jnp.float32),
-            in_sem=pltpu.SemaphoreType.DMA((2,)),
-            w_sem=pltpu.SemaphoreType.DMA((2,)),
-            m_sem=pltpu.SemaphoreType.DMA((2,)),
-        )
-
-    xv = xs.reshape(rows * m_pad // 128, 128)
-    return pl.pallas_call(
-        _quantize_flat_db_kernel,
-        name="cgx_quantize_flat_db",
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_chunks * bits * rb, 128), jnp.int32),
-            jax.ShapeDtypeStruct((n_chunks * CHUNK_BUCKETS, 2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(seed.reshape(1, 1).astype(jnp.int32), xv)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("bits", "bucket_size", "interpret", "tc", "with_add"),
-)
-def _dequantize_flat_db_impl(
-    words: jax.Array,
-    meta: jax.Array,
-    add_to: Optional[jax.Array] = None,
-    *,
-    bits: int,
-    bucket_size: int,
-    interpret: bool = False,
-    tc: int = 8,
-    with_add: bool = False,
-):
-    """Double-buffered sibling of :func:`_dequantize_flat_impl` — same
-    contract (``with_add`` included), same values, manual DMA pipeline."""
-    rows, w_row = words.shape
-    b = bucket_size
-    rb = b // 128
-    nb_r = w_row * LANE_GROUP // (b * bits)
-    n_chunks = rows * nb_r // CHUNK_BUCKETS
-    nblk = n_chunks // tc
-    s_rows = tc * CHUNK_BUCKETS * rb
-    w_rows = tc * bits * rb
-    m_rows = tc * CHUNK_BUCKETS
-
-    def _dequantize_flat_db_kernel(w_hbm, m_hbm, *rest):
-        if with_add:
-            a_hbm, out_hbm = rest
-        else:
-            a_hbm, (out_hbm,) = None, rest
-
-        def body(wbuf, mbuf, abuf, obuf, w_sem, m_sem, a_sem, o_sem):
-            def w_dma(slot, i):
-                return pltpu.make_async_copy(
-                    w_hbm.at[pl.ds(i * w_rows, w_rows)], wbuf.at[slot],
-                    w_sem.at[slot],
-                )
-
-            def m_dma(slot, i):
-                return pltpu.make_async_copy(
-                    m_hbm.at[pl.ds(i * m_rows, m_rows)], mbuf.at[slot],
-                    m_sem.at[slot],
-                )
-
-            def a_dma(slot, i):
-                return pltpu.make_async_copy(
-                    a_hbm.at[pl.ds(i * s_rows, s_rows)], abuf.at[slot],
-                    a_sem.at[slot],
-                )
-
-            def o_dma(slot, i):
-                return pltpu.make_async_copy(
-                    obuf.at[slot], out_hbm.at[pl.ds(i * s_rows, s_rows)],
-                    o_sem.at[slot],
-                )
-
-            def start_in(slot, i):
-                w_dma(slot, i).start()
-                m_dma(slot, i).start()
-                if with_add:
-                    a_dma(slot, i).start()
-
-            start_in(0, 0)
-
-            def step(i, carry):
-                cur = i % 2
-
-                @pl.when(i + 1 < nblk)
-                def _():
-                    start_in((i + 1) % 2, i + 1)
-
-                w_dma(cur, i).wait()
-                m_dma(cur, i).wait()
-                if with_add:
-                    a_dma(cur, i).wait()
-
-                @pl.when(i >= 2)
-                def _():
-                    o_dma(cur, i - 2).wait()
-
-                sub = jax.lax.broadcasted_iota(
-                    jnp.int32, (tc, CHUNK_BUCKETS, rb, 128), 1
-                )
-                lvl = _decode_lvl(wbuf[cur], sub, bits=bits, tc=tc, rb=rb)
-                m2 = mbuf[cur]
-                unit = m2[:, 0:1].reshape(tc, CHUNK_BUCKETS, 1, 1)
-                bmin = m2[:, 1:2].reshape(tc, CHUNK_BUCKETS, 1, 1)
-                vals = (bmin + unit * lvl.astype(jnp.float32)).reshape(
-                    s_rows, 128
-                )
-                if with_add:
-                    vals = abuf[cur] + vals  # acc + decoded — the fused order
-                _slot_store(obuf, cur, vals)
-                o_dma(cur, i).start()
-                return carry
-
-            jax.lax.fori_loop(0, nblk, step, 0)
-            for j in range(max(0, nblk - 2), nblk):
-                o_dma(j % 2, j).wait()
-
-        scratch = dict(
-            wbuf=pltpu.VMEM((2, w_rows, 128), jnp.int32),
-            mbuf=pltpu.VMEM((2, m_rows, 2), jnp.float32),
-            # abuf unused without the fused add — keep it token-sized so
-            # the 2-slot output buffer gets the VMEM instead.
-            abuf=pltpu.VMEM(
-                (2, s_rows, 128) if with_add else (2, 8, 128), jnp.float32
-            ),
-            obuf=pltpu.VMEM((2, s_rows, 128), jnp.float32),
-            w_sem=pltpu.SemaphoreType.DMA((2,)),
-            m_sem=pltpu.SemaphoreType.DMA((2,)),
-            a_sem=pltpu.SemaphoreType.DMA((2,)),
-            o_sem=pltpu.SemaphoreType.DMA((2,)),
-        )
-        pl.run_scoped(body, **scratch)
-
-    wv = words.reshape(rows * w_row // 128, 128)
-    mv = meta.reshape(rows * nb_r, 2)
-    in_specs = [
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    operands = [wv, mv]
-    if with_add:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        operands.append(
-            add_to.astype(jnp.float32).reshape(rows * nb_r * b // 128, 128)
-        )
-    out = pl.pallas_call(
-        _dequantize_flat_db_kernel,
-        name="cgx_dequantize_flat_db",
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(
-            (n_chunks * CHUNK_BUCKETS * rb, 128), jnp.float32
-        ),
-        interpret=interpret,
-    )(*operands)
-    return out.reshape(rows, nb_r * b)
-
-
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -1002,25 +670,17 @@ def quantize_batch(
         # 128-lane rows — the flat kernel reads the natural flat layout
         # straight from HBM, zero XLA relayout on either side. A plain
         # pallas_call, so it runs under CPU interpret mode too and the
-        # normal suite asserts its bytes against the XLA oracle. The tile
-        # and pack lowering consult the per-chip autotune cache
-        # (ops/autotune.py); CGX_PALLAS_DB routes to the double-buffered
-        # manual-DMA sibling (same bytes).
-        tuned = autotune.lookup(
-            autotune.KIND_FLAT, n_chunks=rows * c_r, bucket_size=b, bits=bits
-        )
-        db = _use_db(tuned)
-        note_lowering("quantize", "pallas_flat_db" if db else "pallas_flat")
-        impl = _quantize_flat_db_impl if db else _quantize_flat_impl
-        words, meta = impl(
+        # normal suite asserts its bytes against the XLA oracle.
+        note_lowering("quantize", "pallas_flat")
+        words, meta = _quantize_flat_impl(
             xs,
             seed_from_key(key),
             bits=bits,
             bucket_size=b,
             stochastic=stochastic,
             interpret=interpret,
-            tc=_pipe_tc(rows * c_r, b, tuned),
-            pack=_pack_strategy(tuned),
+            tc=_pipe_tc(rows * c_r, b),
+            pack=_pack_strategy(),
             encode=_encode_strategy(),
         )
         return codec.QTensor(
@@ -1040,10 +700,6 @@ def quantize_batch(
     note_lowering("quantize", "pallas_chunks" if c_r else "xla_tail")
     if c_r:
         head = xb[:, : c_r * CHUNK_BUCKETS].reshape(-1, b)
-        tuned = autotune.lookup(
-            autotune.KIND_CHUNKS, n_chunks=rows * c_r, bucket_size=b,
-            bits=bits,
-        )
         words, meta = _quantize_chunks_impl(
             head,
             seed_from_key(key),
@@ -1051,8 +707,8 @@ def quantize_batch(
             bucket_size=b,
             stochastic=stochastic,
             interpret=interpret,
-            tc=_chunks_tc(rows * c_r, b, bits, tuned),
-            pack=_pack_strategy(tuned),
+            tc=_chunks_tc(rows * c_r, b, bits),
+            pack=_pack_strategy(),
             encode=_encode_strategy(),
         )
         word_parts.append(words.reshape(rows, c_r * bits * b))
@@ -1098,11 +754,7 @@ def quantize_batch(
 
 
 def _rows_tc(
-    n_chunks: int,
-    bucket_size: int,
-    row_width: int,
-    store: np.dtype,
-    tuned: "autotune.TunedConfig | None" = None,
+    n_chunks: int, bucket_size: int, row_width: int, store: np.dtype
 ) -> Optional[int]:
     """Chunks per block for a flat decode that stores rows of
     ``row_width`` (see :func:`_dequantize_flat_impl`): the largest tile
@@ -1115,7 +767,7 @@ def _rows_tc(
         return None
     block_rows = row_width * (32 // store.itemsize)
     chunk = CHUNK_BUCKETS * bucket_size
-    cap = _tile_chunks(n_chunks, bucket_size, 8, tuned)
+    cap = _tile_chunks(n_chunks, bucket_size, 8)
     for tc in range(min(cap, n_chunks), 0, -1):
         if n_chunks % tc == 0 and (tc * chunk) % block_rows == 0:
             return tc
@@ -1172,10 +824,6 @@ def dequantize_batch(
             and q.numel_main == nb_r * b
             and tuple(add_to.shape) == (rows, q.numel_main)
         )
-        tuned = autotune.lookup(
-            autotune.KIND_FLAT, n_chunks=rows * c_r, bucket_size=b,
-            bits=q.bits,
-        )
         # The kernel stores the caller's type and rows itself when nothing
         # is added to its output after it.
         plain = add_to is None and not q.residual.shape[-1]
@@ -1185,29 +833,26 @@ def dequantize_batch(
         tc_rows = None
         if row_width is not None:
             if plain and q.numel_main == nb_r * b:
-                tc_rows = _rows_tc(rows * c_r, b, row_width, store, tuned)
+                tc_rows = _rows_tc(rows * c_r, b, row_width, store)
             note_lowering(
                 "dequantize_rows", "pallas_flat" if tc_rows else "xla_reshape"
             )
-        # The double-buffered twin stores flat float32 only.
-        db = _use_db(tuned) and store == np.float32 and not tc_rows
-        name = "pallas_flat_db" if db else "pallas_flat"
         note_lowering(
-            "dequantize", name if store == np.float32 else f"{name}.{store.name}"
+            "dequantize",
+            "pallas_flat" if store == np.float32
+            else f"pallas_flat.{store.name}",
         )
-        impl = _dequantize_flat_db_impl if db else functools.partial(
-            _dequantize_flat_impl, out_dtype=store,
-            row_width=row_width if tc_rows else None,
-        )
-        vals = impl(
+        vals = _dequantize_flat_impl(
             jax.lax.bitcast_convert_type(q.packed, jnp.int32),
             meta,
             add_to if fuse_add else None,
             bits=q.bits,
             bucket_size=b,
             interpret=interpret,
-            tc=tc_rows or _pipe_tc(rows * c_r, b, tuned),
+            tc=tc_rows or _pipe_tc(rows * c_r, b),
             with_add=fuse_add,
+            out_dtype=store,
+            row_width=row_width if tc_rows else None,
         )
         if tc_rows:
             return vals.astype(out_dtype)
@@ -1227,13 +872,7 @@ def dequantize_batch(
                 bits=q.bits,
                 bucket_size=b,
                 interpret=interpret,
-                tc=_chunks_tc(
-                    rows * c_r, b, q.bits,
-                    autotune.lookup(
-                        autotune.KIND_CHUNKS, n_chunks=rows * c_r,
-                        bucket_size=b, bits=q.bits,
-                    ),
-                ),
+                tc=_chunks_tc(rows * c_r, b, q.bits),
             )
             parts.append(vals.reshape(rows, c_r * CHUNK_BUCKETS * b))
         if t_r:
@@ -1297,27 +936,14 @@ def supports_reduce(q: codec.QTensor, ws: Optional[int] = None) -> bool:
     return ws * CHUNK_BUCKETS * b <= MAX_REDUCE_BLOCK_ELEMS
 
 
-def _reduce_tc(
-    c_r: int,
-    bucket_size: int,
-    ws: int,
-    tuned: "autotune.TunedConfig | None" = None,
-) -> int:
+def _reduce_tc(c_r: int, bucket_size: int, ws: int) -> int:
     """Chunks per grid step for the fused reduce: largest divisor of the
     per-row chunk count whose ws-way decoded block stays inside the VMEM
     budget. Matches ``_pipe_tc`` whenever the budget allows, so the
     requantize's grid (and its stochastic draw) lines up with the staged
-    stage-2 quantize. A measured autotune entry (kind "epilogue")
-    replaces the heuristic candidate within the same budget — but the
-    CGX_PALLAS_TILE_CHUNKS override still wins (it routes through
-    ``_pipe_tc``, the strongest tier), and stochastic callers pass
-    ``tuned=None`` so the requantize draw geometry stays pinned to the
-    staged quantize's grid."""
+    stage-2 quantize."""
     cap = max(1, MAX_REDUCE_BLOCK_ELEMS // (2 * ws * CHUNK_BUCKETS * bucket_size))
-    if tuned is not None and _forced_tile_chunks() is None:
-        cap = min(cap, max(1, tuned.tc))
-    else:
-        cap = min(cap, _pipe_tc(c_r, bucket_size))
+    cap = min(cap, _pipe_tc(c_r, bucket_size))
     for tc in range(min(cap, c_r), 0, -1):
         if c_r % tc == 0:
             return tc
@@ -1351,8 +977,7 @@ def _decode_accumulate(
     scatter_reduce_allgather.cc:116-155).
 
     ``words``: (ws, tc*bits*rb, 128) int32 VALUES (the caller reads its
-    refs/scratch slots — grid and DB lowerings share this body);
-    ``meta``: (ws, tc*CHUNK_BUCKETS, 2) f32; ``raw``: the own chunk as
+    refs); ``meta``: (ws, tc*CHUNK_BUCKETS, 2) f32; ``raw``: the own chunk as
     (tc, CHUNK_BUCKETS, rb, 128) f32 or None; ``own``: traced row index
     scalar (-1 = no raw substitution).
 
@@ -1414,18 +1039,13 @@ def _decode_accumulate(
     return acc
 
 
-def _raw4_cast(raw, *, tc, rb):
-    """Upcast + reshape the raw own chunk VALUE of one block (the SRA
-    exactness rule streams it at 1/ws of the decoded size — a small,
-    audited conversion, not a decoded-peer-row materialization)."""
-    return raw.astype(jnp.float32).reshape(tc, CHUNK_BUCKETS, rb, 128)
-
-
 def _read_raw4(raw_ref, *, tc, rb):
-    """Ref-reading sibling of :func:`_raw4_cast` for the grid kernels."""
+    """Upcast + reshape the raw own chunk of one block, None without one
+    (the SRA exactness rule streams it at 1/ws of the decoded size — a
+    small, audited conversion, not a decoded-peer-row materialization)."""
     if raw_ref is None:
         return None
-    return _raw4_cast(raw_ref[:], tc=tc, rb=rb)
+    return raw_ref[:].astype(jnp.float32).reshape(tc, CHUNK_BUCKETS, rb, 128)
 
 
 def _requant_cast(acc, cast_dtype):
@@ -1437,13 +1057,13 @@ def _requant_cast(acc, cast_dtype):
 
 
 def _requantize_block(
-    x4, seed_ref, *, bits, tc, rb, stochastic, pack, encode, block_idx=None
+    x4, seed_ref, *, bits, tc, rb, stochastic, pack, encode
 ):
     """Quantize one (tc, CHUNK_BUCKETS, rb, 128) f32 block — op-for-op the
     ``_quantize_flat_kernel`` body (same meta math, encode lowering, pack
-    and stochastic draw geometry), shared by the flat quantize kernels,
-    the fused SRA epilogue's requantize and the DB lowerings so the wire
-    contract cannot drift between them. Returns
+    and stochastic draw geometry), shared by the flat quantize kernel and
+    the fused SRA epilogue's requantize so the wire contract cannot drift
+    between them. Returns
     ``(words (tc*bits*rb, 128) int32, meta (tc*CHUNK_BUCKETS, 2) f32)``."""
     maxlvl = np.float32((1 << bits) - 1)
     bmax = jnp.max(jnp.max(x4, axis=2, keepdims=True), axis=3, keepdims=True)
@@ -1451,11 +1071,7 @@ def _requantize_block(
     # Reciprocal-multiply like codec.compute_meta (byte-identity).
     unit = (bmax - bmin) * np.float32(1.0 / ((1 << bits) - 1))
     safe = jnp.where(unit > 0, unit, np.float32(1.0))
-    r = (
-        _stochastic_r(seed_ref, x4.shape, block_idx)
-        if stochastic
-        else np.float32(0.5)
-    )
+    r = _stochastic_r(seed_ref, x4.shape) if stochastic else np.float32(0.5)
     lvl = _encode_lvl(x4, bmin, safe, r, maxlvl, encode)
     planes = _pack_planes(lvl, bits, 1, pack)
     # disjoint bits -> int32 wrap on the s=31 term is exact
@@ -1639,182 +1255,6 @@ def _sra_epilogue_impl(
     return words_out, meta_out
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "bits", "bucket_size", "ws", "with_raw", "stochastic", "interpret",
-        "tc", "pack", "encode", "cast_dtype", "accum",
-    ),
-)
-def _sra_epilogue_db_impl(
-    words: jax.Array,
-    meta: jax.Array,
-    raw: Optional[jax.Array],
-    own: jax.Array,
-    seed: jax.Array,
-    *,
-    bits: int,
-    bucket_size: int,
-    ws: int,
-    with_raw: bool,
-    stochastic: bool,
-    interpret: bool = False,
-    tc: int = 8,
-    pack: str = "sum",
-    encode: str = "div",
-    cast_dtype=None,
-    accum: str = "exact",
-):
-    """Double-buffered sibling of :func:`_sra_epilogue_impl` — same
-    contract and (under ``accum="exact"``) the same wire bytes; the ws
-    peer-row streams, the raw own chunk and both outputs ride the manual
-    2-slot DMA pipeline (per-peer-row copies, one semaphore per (slot,
-    row))."""
-    b = bucket_size
-    rb = b // 128
-    nb_r = meta.shape[1]
-    c_r = nb_r // CHUNK_BUCKETS
-    nblk = c_r // tc
-    w_rows = tc * bits * rb
-    m_rows = tc * CHUNK_BUCKETS
-    s_rows = tc * CHUNK_BUCKETS * rb
-
-    def _sra_epilogue_db_kernel(seed_ref, own_ref, w_hbm, m_hbm, *rest):
-        if with_raw:
-            r_hbm, wo_hbm, mo_hbm = rest
-        else:
-            r_hbm, (wo_hbm, mo_hbm) = None, rest
-
-        def body(wbuf, mbuf, rbuf, wob, mob, in_sem, r_sem, wo_sem, mo_sem):
-            def w_dma(slot, r, i):
-                return pltpu.make_async_copy(
-                    w_hbm.at[r, pl.ds(i * w_rows, w_rows)],
-                    wbuf.at[slot, r], in_sem.at[slot, r, 0],
-                )
-
-            def m_dma(slot, r, i):
-                return pltpu.make_async_copy(
-                    m_hbm.at[r, pl.ds(i * m_rows, m_rows)],
-                    mbuf.at[slot, r], in_sem.at[slot, r, 1],
-                )
-
-            def r_dma(slot, i):
-                return pltpu.make_async_copy(
-                    r_hbm.at[pl.ds(i * s_rows, s_rows)], rbuf.at[slot],
-                    r_sem.at[slot],
-                )
-
-            def wo_dma(slot, i):
-                return pltpu.make_async_copy(
-                    wob.at[slot], wo_hbm.at[pl.ds(i * w_rows, w_rows)],
-                    wo_sem.at[slot],
-                )
-
-            def mo_dma(slot, i):
-                return pltpu.make_async_copy(
-                    mob.at[slot], mo_hbm.at[pl.ds(i * m_rows, m_rows)],
-                    mo_sem.at[slot],
-                )
-
-            def start_in(slot, i):
-                for r in range(ws):
-                    w_dma(slot, r, i).start()
-                    m_dma(slot, r, i).start()
-                if with_raw:
-                    r_dma(slot, i).start()
-
-            def wait_in(slot, i):
-                for r in range(ws):
-                    w_dma(slot, r, i).wait()
-                    m_dma(slot, r, i).wait()
-                if with_raw:
-                    r_dma(slot, i).wait()
-
-            start_in(0, 0)
-
-            def step(i, carry):
-                cur = i % 2
-
-                @pl.when(i + 1 < nblk)
-                def _():
-                    start_in((i + 1) % 2, i + 1)
-
-                wait_in(cur, i)
-
-                @pl.when(i >= 2)
-                def _():
-                    wo_dma(cur, i - 2).wait()
-                    mo_dma(cur, i - 2).wait()
-
-                raw4 = (
-                    _raw4_cast(rbuf[cur], tc=tc, rb=rb) if with_raw else None
-                )
-                acc = _decode_accumulate(
-                    wbuf[cur], mbuf[cur], raw4, own_ref[0, 0],
-                    bits=bits, tc=tc, ws=ws, rb=rb, accum=accum,
-                )
-                w_out, m_out = _requantize_block(
-                    _requant_cast(acc, cast_dtype), seed_ref,
-                    bits=bits, tc=tc, rb=rb, stochastic=stochastic,
-                    pack=pack, encode=encode, block_idx=i,
-                )
-                _slot_store(wob, cur, w_out)
-                _slot_store(mob, cur, m_out)
-                wo_dma(cur, i).start()
-                mo_dma(cur, i).start()
-                return carry
-
-            jax.lax.fori_loop(0, nblk, step, 0)
-            for j in range(max(0, nblk - 2), nblk):
-                wo_dma(j % 2, j).wait()
-                mo_dma(j % 2, j).wait()
-
-        pl.run_scoped(
-            body,
-            wbuf=pltpu.VMEM((2, ws, w_rows, 128), jnp.int32),
-            mbuf=pltpu.VMEM((2, ws, m_rows, 2), jnp.float32),
-            rbuf=pltpu.VMEM(
-                (2, s_rows, 128) if with_raw else (2, 8, 128), jnp.float32
-            ),
-            wob=pltpu.VMEM((2, w_rows, 128), jnp.int32),
-            mob=pltpu.VMEM((2, m_rows, 2), jnp.float32),
-            in_sem=pltpu.SemaphoreType.DMA((2, ws, 2)),
-            r_sem=pltpu.SemaphoreType.DMA((2,)),
-            wo_sem=pltpu.SemaphoreType.DMA((2,)),
-            mo_sem=pltpu.SemaphoreType.DMA((2,)),
-        )
-
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    operands = [
-        seed.reshape(1, 1).astype(jnp.int32),
-        own.reshape(1, 1).astype(jnp.int32),
-        words.reshape(ws, c_r * bits * rb, 128),
-        meta.reshape(ws, nb_r, 2),
-    ]
-    if with_raw:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        operands.append(raw.reshape(nb_r * b // 128, 128))
-    return pl.pallas_call(
-        _sra_epilogue_db_kernel,
-        name="cgx_sra_epilogue_db",
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((c_r * bits * rb, 128), jnp.int32),
-            jax.ShapeDtypeStruct((c_r * CHUNK_BUCKETS, 2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*operands)
-
-
 def reduce_rows_batch(
     q: codec.QTensor,
     *,
@@ -1831,10 +1271,6 @@ def reduce_rows_batch(
     with_raw = raw_row is not None
     own = own_idx if own_idx is not None else jnp.int32(-1)
     nb_r = codec.num_buckets(q.numel_main, q.bucket_size)
-    tuned = autotune.lookup(
-        autotune.KIND_EPILOGUE, n_chunks=nb_r // CHUNK_BUCKETS,
-        bucket_size=q.bucket_size, bits=q.bits, ws=ws,
-    )
     note_lowering("reduce_rows", "pallas_fused")
     return _reduce_rows_impl(
         words,
@@ -1846,7 +1282,7 @@ def reduce_rows_batch(
         ws=ws,
         with_raw=with_raw,
         interpret=interpret,
-        tc=_reduce_tc(nb_r // CHUNK_BUCKETS, q.bucket_size, ws, tuned),
+        tc=_reduce_tc(nb_r // CHUNK_BUCKETS, q.bucket_size, ws),
         accum=cfg_mod.sra_accum(),
     )[: q.numel]
 
@@ -1871,28 +1307,8 @@ def sra_epilogue_batch(
     with_raw = raw_row is not None
     own = own_idx if own_idx is not None else jnp.int32(-1)
     nb_r = codec.num_buckets(q.numel_main, q.bucket_size)
-    # Stochastic requantize: keep the heuristic tile — a tuned epilogue tc
-    # differing from the flat quantize tc would change the per-block
-    # _stochastic_r draw geometry vs the staged stage-2 quantize.
-    tuned = (
-        None
-        if key is not None
-        else autotune.lookup(
-            autotune.KIND_EPILOGUE, n_chunks=nb_r // CHUNK_BUCKETS,
-            bucket_size=q.bucket_size, bits=q.bits, ws=ws,
-        )
-    )
-    # The double-buffered epilogue is not selectable from an autotune
-    # entry: Mosaic (libtpu 0.0.34) refuses its 2-lane meta DMA slice
-    # ("Slice shape along dimension 2 must be aligned to tiling (128)"),
-    # so only an explicit CGX_PALLAS_DB=on reaches it — and fails loudly
-    # on a TPU until ROADMAP C1 decides its fate.
-    db = cfg_mod.pallas_db() == "on"
-    note_lowering(
-        "sra_epilogue", "pallas_fused_db" if db else "pallas_fused"
-    )
-    impl = _sra_epilogue_db_impl if db else _sra_epilogue_impl
-    words_out, meta_out = impl(
+    note_lowering("sra_epilogue", "pallas_fused")
+    words_out, meta_out = _sra_epilogue_impl(
         words,
         meta,
         raw_row if with_raw else None,
@@ -1904,8 +1320,8 @@ def sra_epilogue_batch(
         with_raw=with_raw,
         stochastic=key is not None,
         interpret=interpret,
-        tc=_reduce_tc(nb_r // CHUNK_BUCKETS, q.bucket_size, ws, tuned),
-        pack=_pack_strategy(tuned),
+        tc=_reduce_tc(nb_r // CHUNK_BUCKETS, q.bucket_size, ws),
+        pack=_pack_strategy(),
         encode=_encode_strategy(),
         cast_dtype=np.dtype(out_dtype),
         accum=cfg_mod.sra_accum(),

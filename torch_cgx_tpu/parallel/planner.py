@@ -13,8 +13,8 @@ is that compiler for the step tier:
 
 * a :class:`CostModel` calibrated ONLINE from live telemetry — the
   ``cgx_trace`` span files (per-phase byte rates + the ``overlap_frac``
-  attribution), the WireController's trace-time (numel, bits) side
-  tables, and the PR 11 per-chip autotune entries (measured codec GB/s);
+  attribution) and the WireController's trace-time (numel, bits) side
+  tables;
 * a **joint solve** over ALL fusion slices of a train step at once:
   (pipeline depth per slice, bit-width per slice, emission order)
   against the model — per-slice costs are additive, so the exact argmin
@@ -191,7 +191,7 @@ class CostModel:
                 t0 = float(ev.get("t_mono", 0.0))
                 cat = ev.get("cat")
                 if cat == "quantize":
-                    # Rates are per f32 byte (the qbench/autotune unit
+                    # Rates are per f32 byte (the qbench unit
                     # predict_slice divides by), so calibrate from the
                     # span's `elems` f32 count — its `bytes` field is
                     # WIRE bytes (~bits/32 of the input). Split by span
@@ -256,10 +256,8 @@ class CostModel:
     def from_telemetry(cls, spans_dir: Optional[str] = None) -> "CostModel":
         """The live-calibration entry point :meth:`StepPlanner.update`
         drives: span files when a metrics dir is available (argument or
-        ``CGX_METRICS_DIR``), the per-chip autotune cache's best measured
-        codec throughput (PR 11 entries carry the GB/s their tile
-        decision was based on), and the ``cgx.step.time_s`` histogram's
-        p50 as the compute baseline."""
+        ``CGX_METRICS_DIR``) and the ``cgx.step.time_s`` histogram's p50
+        as the compute baseline."""
         base = (
             cls.from_spans(spans_dir or cfg_mod.metrics_dir() or "")
             if (spans_dir or cfg_mod.metrics_dir())
@@ -267,11 +265,6 @@ class CostModel:
         )
         kw: Dict[str, float] = {}
         fields = [base.source]
-        tuned = _best_autotune_gbps()
-        if tuned and base.quantize_gbps == cls.quantize_gbps:
-            kw["quantize_gbps"] = tuned
-            kw["dequantize_gbps"] = 2.0 * tuned
-            fields.append("autotune")
         try:
             hist = metrics.snapshot_typed()["histograms"].get("cgx.step.time_s")
         except Exception:
@@ -584,22 +577,6 @@ def _overlap_len(
         else:
             j += 1
     return total
-
-
-def _best_autotune_gbps() -> float:
-    """Best measured codec throughput among the chip's persisted autotune
-    entries (PR 11), 0.0 when none are loaded — consulting the in-memory
-    memo only (never touches disk; the tuner loads it on first codec
-    dispatch)."""
-    try:
-        from ..ops import autotune as at_mod
-
-        with at_mod._LOCK:
-            return max(
-                (t.gbps for t in at_mod._MEMO.values() if t.gbps), default=0.0
-            )
-    except Exception:
-        return 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -232,11 +232,10 @@ def _mesh_fingerprint(mesh) -> tuple:
 def _trace_env_fingerprint() -> tuple:
     """Every env knob the staged body bakes in at TRACE time (codec
     lowering, encode strategy, epilogue selection, accumulation domain,
-    kernel tiling/packing, autotune engagement, debug modes): a flip of
-    any of these between eager calls must compile a fresh program, never
-    serve a stale one — the same discipline as allreduce's layout LRU.
-    The PR 11 kernel knobs (``CGX_PALLAS_DB``/``CGX_SRA_ACCUM``/
-    ``CGX_AUTOTUNE``/``CGX_PALLAS_PACK``/``CGX_PALLAS_TILE_CHUNKS``)
+    kernel tiling/packing, debug modes): a flip of any of these between
+    eager calls must compile a fresh program, never serve a stale one —
+    the same discipline as allreduce's layout LRU. The PR 11 kernel knobs
+    (``CGX_SRA_ACCUM``/``CGX_PALLAS_PACK``/``CGX_PALLAS_TILE_CHUNKS``)
     joined with ISSUE 14's knob→cache-key pass, which caught them
     lowering into the program body without re-keying it."""
     from ..ops import codec_pallas
@@ -251,8 +250,6 @@ def _trace_env_fingerprint() -> tuple:
         cfg_mod.force_codec(),
         cfg_mod.minimal_size(),
         cfg_mod.sra_accum(),
-        cfg_mod.pallas_db(),
-        cfg_mod.autotune_mode(),
         _env.get_optional_str_env(cfg_mod.PALLAS_PACK),
         _env.get_optional_str_env(cfg_mod.PALLAS_TILE_CHUNKS),
     )

@@ -130,15 +130,6 @@ def invalidate_trace_caches() -> None:
         planner = sys.modules.get("torch_cgx_tpu.parallel.planner")
         if planner is not None:
             planner.invalidate_plan_cache("recovery reconfigure")
-    # Codec autotune memo: entries themselves are chip-keyed (world-size
-    # independent), but the memo is a trace-time cache like the layout
-    # and schedule LRUs — drop it with them so post-recovery traces
-    # re-read the persisted state instead of serving the dead
-    # generation's in-memory image (cgx.codec.autotune_invalidations).
-    if "torch_cgx_tpu.ops.autotune" in sys.modules:
-        sys.modules["torch_cgx_tpu.ops.autotune"].invalidate(
-            "recovery reconfigure"
-        )
     # Producer-fuse context: the configured mesh/axis name the dead
     # generation and stashed pre-quantized payloads hold retired traces'
     # tracers — deactivate and re-epoch so the first post-recovery build
