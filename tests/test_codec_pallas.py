@@ -7,6 +7,8 @@ the XLA codec (same divide, same floor/clip), so deterministic payloads are
 asserted byte-equal, not merely close.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -915,6 +917,107 @@ def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket):
         "cgx.codec.lowering.dequantize.pallas_flat.bfloat16") == 3
 
 
+def _paged_pool(rng, n_pool, numel, bits, bucket, interpret=True):
+    """A quantized page pool in the flat kernel's operand layout
+    (``ops/paged_kv.py``) and the QTensor it was made from."""
+    xs = jnp.asarray(rng.standard_normal((n_pool, numel)) * 3.0, jnp.float32)
+    q = codec_pallas.quantize_batch(xs, bits, bucket, interpret=interpret)
+    words = jax.lax.bitcast_convert_type(q.packed, jnp.int32).reshape(
+        n_pool, -1, 128)
+    return q, words, q.meta
+
+
+def _gathered(q, ids):
+    return dataclasses.replace(
+        q, packed=q.packed[ids], meta=q.meta[ids], residual=q.residual[ids])
+
+
+def _bits_of(a):
+    kind = {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]
+    return np.asarray(jax.lax.bitcast_convert_type(a, kind))
+
+
+@pytest.mark.parametrize("geo", [
+    # (page tokens, row width, bits, bucket, store, pages a table)
+    pytest.param((64, 1280, 8, 512, jnp.bfloat16, 8), id="gpt2l-kv"),
+    pytest.param((64, 1280, 8, 512, jnp.float32, 6), id="gpt2l-kv-f32"),
+    pytest.param((256, 512, 8, 512, jnp.bfloat16, 4), id="joyai-c"),
+    pytest.param((32, 256, 4, 128, jnp.bfloat16, 12), id="one-chunk-4bit"),
+])
+def test_paged_decode_is_the_gathered_decode_bit_for_bit(geo):
+    """ISSUE 30: the flat decode kernel fed a page POOL and page ids (the
+    ids a scalar-prefetch operand, each grid step's word and meta blocks
+    picked by id) writes what ``dequantize_batch`` writes over the gathered
+    rows, bit for bit, for a permuted table that repeats a row (the clipped
+    sentinels of a real table), at the serving cells' page geometries, in
+    ``_pages_tc``'s tile (several page operands a step) and one page a
+    step."""
+    pt, width, bits, bucket, store, n = geo
+    rng = np.random.default_rng(pt + width + n)
+    n_pool = n + 1
+    q, words, meta = _paged_pool(rng, n_pool, pt * width, bits, bucket)
+    ids = rng.permutation(n_pool)[:n].astype(np.int32)
+    ids[[1, n - 1]] = 0  # sentinel entries, clipped to row 0 by the caller
+    ids = jnp.asarray(ids)
+    want = codec_pallas.dequantize_batch(
+        _gathered(q, ids), interpret=True, out_dtype=store, row_width=width)
+    page_chunks = pt * width // (32 * bucket)
+    tile = codec_pallas._pages_tc(
+        n, page_chunks, bucket, width, np.dtype(store))
+    assert tile and tile % page_chunks == 0 and tile > page_chunks
+    for tc in (tile, page_chunks):
+        got = codec_pallas._dequantize_flat_impl(
+            words, meta, None, ids, bits=bits, bucket_size=bucket,
+            interpret=True, tc=tc, out_dtype=np.dtype(store),
+            row_width=width)
+        assert got.shape == want.shape == (n, pt, width)
+        assert got.dtype == want.dtype == store
+        np.testing.assert_array_equal(_bits_of(got), _bits_of(want))
+    # A consumer of float16 rows: float32 store, cast after the kernel.
+    f16 = codec_pallas.dequantize_pages(
+        words, meta, ids, bits=bits, bucket_size=bucket, tc=tile,
+        out_dtype=jnp.float16, row_width=width, interpret=True)
+    np.testing.assert_array_equal(
+        _bits_of(f16), _bits_of(codec_pallas.dequantize_batch(
+            _gathered(q, ids), interpret=True, out_dtype=jnp.float16,
+            row_width=width)))
+
+
+def test_pages_tc_is_the_gathered_reads_tile_in_whole_pages():
+    """``_pages_tc`` keeps the tile the gathered read of the same table
+    takes (``_rows_tc``) where that is whole pages, and refuses (the read
+    gathers) rows the kernel cannot store, a page the tile does not hold
+    and a forced tile under a page."""
+    bf16, f32 = np.dtype(jnp.bfloat16), np.dtype(np.float32)
+    assert codec_pallas._pages_tc(512, 5, 512, 1280, bf16) == 10
+    assert codec_pallas._pages_tc(512, 5, 512, 1280, f32) == 10
+    assert codec_pallas._pages_tc(512, 8, 512, 512, bf16) == 16
+    assert codec_pallas._pages_tc(512, 1, 512, 64, bf16) is None
+    # 7 pages of five chunks: 35 chunks, whose only tiles of whole
+    # 1,280-wide bfloat16 rows are 5 (one page a step) ... and of three
+    # chunks a page with 128-wide rows, 15 chunks: tile 15, five pages.
+    assert codec_pallas._pages_tc(7, 5, 512, 1280, bf16) == 5
+    assert codec_pallas._pages_tc(5, 3, 512, 128, bf16) == 15
+    # A page over the VMEM cap (16 chunks): the tile is part of a page.
+    assert codec_pallas._rows_tc(2 * 32, 512, 512, bf16) == 16
+    assert codec_pallas._pages_tc(2, 32, 512, 512, bf16) is None
+
+
+@pytest.mark.tpu  # compiled Mosaic lowering of the scalar-prefetch grid
+def test_paged_decode_tpu():
+    rng = np.random.default_rng(30)
+    pt, width, bits, bucket, n = 64, 1280, 8, 512, 16
+    q, words, meta = _paged_pool(rng, n + 1, pt * width, bits, bucket,
+                                 interpret=False)
+    ids = jnp.asarray(rng.permutation(n + 1)[:n], jnp.int32)
+    want = codec_pallas.dequantize_batch(
+        _gathered(q, ids), out_dtype=jnp.bfloat16, row_width=width)
+    got = codec_pallas.dequantize_pages(
+        words, meta, ids, bits=bits, bucket_size=bucket, tc=10,
+        out_dtype=jnp.bfloat16, row_width=width)
+    np.testing.assert_array_equal(_bits_of(got), _bits_of(want))
+
+
 # ---------------------------------------------------------------------------
 # What the benchmark's cells run (ISSUE 29). The static tile functions are
 # the one owner of "which lowering, which tile": each case is one kernel call
@@ -949,6 +1052,25 @@ def _cell_cases():
         read, numel=_JOYAI_KR, row_width=64,
     ), {"dequantize": "pallas_flat.bfloat16",
         "dequantize_rows": "xla_reshape"}, {"_rows_tc": None, "_pipe_tc": 16}
+    # ISSUE 30: the same reads as the serving programs make them, through
+    # ``paged_kv.gather_dequant_pages`` over a pool of 513 rows and a (32,
+    # 16) page table. Where the kernel stores the rows itself it walks the
+    # page table in the gathered read's tile (two pages a grid step); the
+    # 64-wide ``kr`` keeps the gather and XLA's reshape.
+    paged = {"dequantize_pages": "pallas_paged", "dequantize_rows": flat}
+    yield "gpt2l-decode-pages", "dequantize_pages", dict(
+        read, page=(64, 20, 64),
+    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 10}
+    yield "gpt2l-decode-pages-f16", "dequantize_pages", dict(
+        read, page=(64, 20, 64), out_dtype=jnp.float16,
+    ), dict(paged, dequantize=flat), {"_pages_tc": 10}
+    yield "joyai-decode-pages-c", "dequantize_pages", dict(
+        read, page=(256, 1, 512),
+    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    yield "joyai-decode-pages-kr", "dequantize_pages", dict(
+        read, page=(256, 1, 64),
+    ), {"dequantize_pages": "xla_gather", "dequantize": "pallas_flat.bfloat16",
+        "dequantize_rows": "xla_reshape"}, {"_pages_tc": None, "_pipe_tc": 16}
     # Page commits: every lane's tail in the decode loop (32 rows), a padded
     # prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and 3,072).
     for name, numel, commits in (
@@ -987,8 +1109,18 @@ def _cell_cases():
             "_chunks_tc": 16}
 
 
-def _trace_cell_call(kind, *, bits, rows, numel, bucket=512, **kw):
+def _trace_cell_call(kind, *, bits, rows, numel=None, bucket=512, **kw):
     """``jax.eval_shape`` of one wrapper call: nothing compiled or run."""
+    if kind == "dequantize_pages":
+        from torch_cgx_tpu.ops import paged_kv
+
+        spec = paged_kv.PageSpec(*kw["page"], bits, bucket)
+        pool = jax.eval_shape(lambda: paged_kv.empty_pool(513, spec))
+        return jax.eval_shape(
+            lambda pool, table: paged_kv.gather_dequant_pages(
+                pool, table, spec, kw["out_dtype"]),
+            pool, jax.ShapeDtypeStruct((32, rows // 32), jnp.int32),
+        )
     if kind == "quantize":
         return jax.eval_shape(
             lambda x: codec_pallas.quantize_batch(
@@ -1032,7 +1164,11 @@ def test_cells_lowering_and_tile(kind, geo, lowering, tiles, monkeypatch):
     from torch_cgx_tpu.utils.logging import metrics
 
     seen = {}  # conftest clears every CGX_*
-    for name in ("_pipe_tc", "_rows_tc", "_reduce_tc", "_chunks_tc"):
+    if kind == "dequantize_pages":  # the serving read asks the backend
+        monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+        geo = dict(geo, numel=int(np.prod(geo["page"])))
+    for name in ("_pipe_tc", "_rows_tc", "_reduce_tc", "_chunks_tc",
+                 "_pages_tc"):
         def spy(*a, _fn=getattr(codec_pallas, name), _name=name):
             seen.setdefault(_name, []).append(_fn(*a))
             return seen[_name][-1]
@@ -1040,7 +1176,11 @@ def test_cells_lowering_and_tile(kind, geo, lowering, tiles, monkeypatch):
         monkeypatch.setattr(codec_pallas, name, spy)
     assert codec_pallas.supports(geo["numel"], geo["bits"], 512, False)
     metrics.reset()
-    _trace_cell_call(kind, **geo)
+    out = _trace_cell_call(kind, **geo)
+    if kind == "dequantize_pages":
+        pt, h, d = geo["page"]
+        assert out.shape == (32, geo["rows"] // 32 * pt, h * d)
+        assert out.dtype == geo["out_dtype"]
     ledger = {
         k[len("cgx.codec.lowering."):]: v
         for k, v in metrics.snapshot("cgx.codec.lowering.").items()
@@ -1053,6 +1193,7 @@ def _cell_geometries():
     """(rows, numel, bits) of every cell case above, once each."""
     return sorted({
         (c[2]["rows"], c[2]["numel"], c[2]["bits"]) for c in _cell_cases()
+        if "numel" in c[2]
     })
 
 

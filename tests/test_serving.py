@@ -670,30 +670,218 @@ def test_slo_scoped_controller_leaves_training_edges_alone(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+def _pool_row_wire_bytes(pool, i):
+    """Pool row ``i`` of a quantized pool as its frame payload: the
+    host codec's ``meta | words`` (``HostQTensor.to_bytes``)."""
+    words, meta = (np.asarray(a) for a in pool)
+    return np.concatenate([
+        meta[i].reshape(-1).view(np.uint8), words[i].reshape(-1).view(np.uint8)
+    ])
+
+
 def test_host_wire_bytes_drop_into_pool_rows():
     """The transport's host-codec page bytes and the decode pool's own
     jit commit produce IDENTICAL pool rows — the zero-re-encoding
-    contract the receiver relies on."""
+    contract the receiver relies on. A pool row holds the wire words as
+    rows of 128 int32 (``PageSpec.word_shape``, the flat kernels' operand
+    layout): the frame's words by a reshape, byte for byte."""
     from torch_cgx_tpu.ops import codec_host, paged_kv
 
     spec = paged_kv.PageSpec(
         page_tokens=PAGE, n_head=4, d_head=32, bits=8, bucket_size=512
     )
+    assert spec.word_shape == (2, 128) and spec.packed_words == 256
     rng = np.random.default_rng(3)
     row = rng.standard_normal(spec.flat).astype(np.float32)
-    packed_j, meta_j = paged_kv.quantize_page_rows(row[None], spec)
+    words_j, meta_j = paged_kv.quantize_page_rows(row[None], spec)
+    assert words_j.shape == (1, 2, 128) and words_j.dtype == jnp.int32
     q_host = codec_host.quantize(row, spec.bits, spec.bucket_size)
     buf = np.asarray(q_host.to_bytes())
     rehydrated = codec_host.from_bytes(
         buf, spec.flat, spec.bits, spec.bucket_size, np.float32
     )
     np.testing.assert_array_equal(
-        np.asarray(packed_j[0]), rehydrated.packed
+        np.asarray(words_j[0]),
+        paged_kv.pool_words(rehydrated.packed[None], spec)[0],
+    )
+    np.testing.assert_array_equal(
+        np.asarray(paged_kv.wire_words(words_j, spec)[0]), rehydrated.packed
     )
     np.testing.assert_array_equal(
         np.asarray(meta_j[0]), rehydrated.meta
     )
     assert buf.nbytes == spec.wire_bytes()
+    np.testing.assert_array_equal(
+        _pool_row_wire_bytes((words_j, meta_j), 0), buf
+    )
+
+
+@pytest.mark.parametrize("writer", ["commit_page_rows", "ingest_pool",
+                                    "prefill_pages"])
+@pytest.mark.parametrize("geo", ["xla-tail", "flat-kernel"])
+def test_a_written_page_has_the_frames_wire_bytes(model_setup, monkeypatch,
+                                                  writer, geo):
+    """ISSUE 30 changed the pool's stored shape, not a byte of a page:
+    whichever program writes a page — the commit of a full tail, the
+    ingest of a received frame, the local prefill — pool row ``id`` is the
+    frame the prefill worker would ship for that payload
+    (``prefill._encode_page``: the host codec's ``meta | words``), in
+    ``(max_pages + 1, *spec.word_shape) int32`` rows; with pages of a
+    chunk tail through the XLA codec and with whole-chunk pages through
+    the flat Pallas kernel (interpret mode)."""
+    from types import SimpleNamespace
+
+    from torch_cgx_tpu.ops import paged_kv
+    from torch_cgx_tpu.serving import prefill as prefill_mod
+
+    cfg, _model, params = model_setup
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    pt = PAGE
+    if geo == "flat-kernel":
+        monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+        monkeypatch.setenv("CGX_COMPRESSION_BUCKET_SIZE", "128")
+        pt = 32
+    sv = _serve_cfg(page_tokens=pt, max_batch=2, max_pages=12, max_seq=128)
+    server = GPT2Server(cfg, params, sv)
+    sched = ContinuousBatchScheduler(server)
+    spec = sched._prog.streams[0][0][1]
+    assert (spec.num_buckets % 32 == 0) == (geo == "flat-kernel")
+    n_full = 2
+    s = n_full * pt + 3
+    (prompt,) = _prompts(cfg, 1, lens=[s], seed=11)
+    padded = sched_mod._pad_prompt(np.asarray(prompt, np.int32), pt)
+    _, ks, _ = jax.jit(server.prefill_forward)(
+        padded[None], np.arange(padded.shape[0], dtype=np.int32)[None],
+        np.int32(s - 1),
+    )
+    rows = np.asarray(ks[0][0, : n_full * pt]).reshape(n_full, -1)
+    frames = [prefill_mod._encode_page(r, spec) for r in rows]
+    assert {len(f) for f in frames} == {spec.wire_bytes()}
+
+    metrics.reset()
+    if writer == "prefill_pages":
+        lane = _admit_only(sched, Request(id="a", tokens=prompt,
+                                          max_new_tokens=2))
+        ids = np.asarray(sched._state["page_table"])[lane, :n_full]
+        pool = sched._state["pools"][0]["k"]
+    else:
+        ids = np.asarray([5, 2])
+        empty = paged_kv.empty_pool(sv.max_pages + 1, spec)
+        if writer == "commit_page_rows":
+            pool = paged_kv.commit_page_rows(
+                empty, jnp.asarray(ids), jnp.asarray(rows), spec)
+        else:
+            stacked = sched_mod._stack_rows([
+                sched_mod._decode_page_payload(
+                    SimpleNamespace(payload=f), spec)
+                for f in frames
+            ], spec)
+            pool = sched_mod._ingest_pool(
+                empty, jnp.asarray(ids), stacked, spec)
+    words, meta = pool
+    assert words.shape == (sv.max_pages + 1,) + spec.word_shape
+    assert words.dtype == jnp.int32 and spec.word_shape[1] == 128
+    assert meta.shape == (sv.max_pages + 1, spec.num_buckets, 2)
+    for i, frame in zip(ids, frames):
+        np.testing.assert_array_equal(
+            _pool_row_wire_bytes(pool, int(i)),
+            np.frombuffer(frame, np.uint8),
+        )
+    if writer != "ingest_pool":  # the device quantized: by which codec
+        assert metrics.get("cgx.codec.lowering.quantize.pallas_flat") == (
+            (2 * cfg.n_layer if writer == "prefill_pages" else 1)
+            if geo == "flat-kernel" else 0
+        )
+
+
+_READ_GEOS = {
+    # name: (page tokens, heads, head size, rows' type, paged tile or None)
+    "gpt2l-kv": (64, 20, 64, jnp.bfloat16, 10),
+    "gpt2l-kv-f32": (64, 20, 64, jnp.float32, 10),
+    "joyai-c": (256, 1, 512, jnp.bfloat16, 16),
+    "joyai-kr": (256, 1, 64, jnp.bfloat16, None),
+}
+
+
+@pytest.mark.parametrize("geo", sorted(_READ_GEOS))
+def test_paged_read_is_the_gathered_read_bit_for_bit(geo, monkeypatch):
+    """ISSUE 30: ``gather_dequant_pages`` on Pallas dispatch (interpret
+    mode here) walks the page table inside the decode kernel where the
+    geometry allows (``pallas_paged``: the GPT-2 K/V pages, the latent
+    ``c``), and its rows are those of the composition it replaced — gather
+    the table's pool rows, ``dequantize_batch`` over them — bit for bit,
+    for a permuted table with sentinels, in ``bfloat16`` and ``float32``
+    rows. The 64-wide ``kr`` keeps that composition (``xla_gather``)."""
+    from torch_cgx_tpu.ops import dispatch as ops_dispatch
+    from torch_cgx_tpu.ops import paged_kv
+
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    pt, h, d, dt, tile = _READ_GEOS[geo]
+    spec = paged_kv.PageSpec(pt, h, d, 8, 512)
+    b, p, max_pages = 2, 4, 8
+    assert spec.paged_read_tile(b * p, dt) == tile
+    rng = np.random.default_rng(len(geo))
+    pool = paged_kv.commit_page_rows(
+        paged_kv.empty_pool(max_pages + 1, spec), jnp.arange(max_pages),
+        jnp.asarray(rng.standard_normal((max_pages, spec.flat)),
+                    jnp.float32), spec)
+    table = rng.permutation(max_pages).reshape(b, p).astype(np.int32)
+    table[0, 2:] = -1
+    table[1, 3:] = -1
+    table = jnp.asarray(table)
+
+    metrics.reset()
+    got = paged_kv.gather_dequant_pages(pool, table, spec, dt)
+    assert got.shape == (b, p * pt, h * d) and got.dtype == dt
+    lowering = "pallas_paged" if tile else "xla_gather"
+    assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
+        f"cgx.codec.lowering.dequantize_pages.{lowering}": 1}
+    assert metrics.get("cgx.codec.lowering.dequantize_rows."
+                       + ("pallas_flat" if tile else "xla_reshape")) == 1
+
+    ids = jnp.maximum(table.reshape(-1), 0)
+    want = ops_dispatch.dequantize_batch(
+        paged_kv.pool_qtensor(*pool, ids, spec), out_dtype=dt,
+        row_width=h * d,
+    ).reshape(got.shape)
+    kind = {2: np.uint16, 4: np.uint32}[np.dtype(dt).itemsize]
+    np.testing.assert_array_equal(
+        np.asarray(got).view(kind), np.asarray(want).view(kind))
+
+
+@pytest.mark.parametrize("page,bits,bucket,why", [
+    ((64, 20, 64), 0, 1, "a raw pool: nothing to decode"),
+    ((16, 20, 64), 8, 512, "40 buckets: a chunk tail, the XLA codec's"),
+    ((256, 1, 64), 8, 512, "64-wide rows are not whole lanes"),
+    ((64, 20, 64), 8, 64, "a bucket under 128 lanes: the chunk kernels'"),
+    ((64, 20, 24), 8, 512, "480-wide rows are not whole lanes"),
+])
+def test_paged_read_rule_refuses_what_the_kernel_cannot_store(page, bits,
+                                                              bucket, why):
+    """``PageSpec.paged_read_tile`` is a static function of the geometry:
+    None (the read gathers) for raw pools, pages that are not whole
+    chunks of 128-lane buckets and rows the kernel cannot store itself; a
+    tile of whole pages for the serving cells' K/V and latent pages."""
+    from torch_cgx_tpu.ops import paged_kv
+
+    spec = paged_kv.PageSpec(*page, bits, bucket)
+    for dt in (jnp.bfloat16, jnp.float32, jnp.float16):
+        assert spec.paged_read_tile(512, dt) is None, why
+    assert paged_kv.PageSpec(64, 20, 64, 8, 512).paged_read_tile(
+        512, jnp.bfloat16) == 10
+    assert paged_kv.PageSpec(256, 1, 512, 8, 512).paged_read_tile(
+        512, jnp.bfloat16) == 16
+    # Off Pallas dispatch (this suite's default) every read gathers.
+    metrics.reset()
+    spec = paged_kv.PageSpec(64, 20, 64, 8, 512)
+    jax.eval_shape(
+        lambda pool, table: paged_kv.gather_dequant_pages(
+            pool, table, spec, jnp.bfloat16),
+        jax.eval_shape(lambda: paged_kv.empty_pool(9, spec)),
+        jax.ShapeDtypeStruct((2, 4), jnp.int32),
+    )
+    assert metrics.get(
+        "cgx.codec.lowering.dequantize_pages.xla_gather") == 1
 
 
 def test_rekey_drains_active_lanes_without_token_loss(
@@ -1219,6 +1407,66 @@ def test_decode_step_holds_no_table_sized_glue(model_setup, monkeypatch):
         jax.make_jaxpr(old_read)(sched._state), rows, cfg.d_model)
     assert sorted(name for name, _ in old) == [
         "concatenate", "convert_element_type"]
+
+
+def _eqns_touching(jaxpr, shape):
+    """Names of the equations of a (closed) jaxpr, nested programs
+    included, with an operand of ``shape``."""
+    names = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if any(tuple(getattr(v.aval, "shape", ())) == shape
+                   for v in eqn.invars):
+                names.append(eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return names
+
+
+def test_decode_step_hands_the_pool_to_the_kernel_alone(model_setup,
+                                                        monkeypatch):
+    """Structure of the traced ``decode_step`` since ISSUE 30: a quantized
+    pool's words and meta go into the decode kernel as they are stored and
+    nowhere else — no gather, reshape or bitcast of the pool in front of
+    it (16-19 ms of the 76.7 ms GPT-2 large step, PERF.md section 6, PR
+    30). The gathered read, traced the same way, is caught."""
+    from torch_cgx_tpu.ops import dispatch as ops_dispatch
+    from torch_cgx_tpu.ops import paged_kv
+
+    cfg, _, params = model_setup
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    monkeypatch.setenv("CGX_COMPRESSION_BUCKET_SIZE", "128")
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    sv = _serve_cfg(page_tokens=32, max_batch=3, max_pages=12, max_seq=128)
+    server = GPT2Server(cfg, params, sv)
+    sched = ContinuousBatchScheduler(server)
+    spec = sched._prog.streams[0][0][1]
+    words, meta = sched._state["pools"][0]["k"]
+    assert words.shape == (sv.max_pages + 1,) + spec.word_shape
+
+    metrics.reset()
+    jaxpr = jax.make_jaxpr(sched._prog.decode_step)(server.p, sched._state)
+    reads = 2 * cfg.n_layer
+    assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
+        "cgx.codec.lowering.dequantize_pages.pallas_paged": reads}
+    # (The jitted impl around the kernel is the one other equation.)
+    for shape in (words.shape, meta.shape):
+        assert sorted(set(_eqns_touching(jaxpr, shape))) == [
+            "jit", "pallas_call"], shape
+        assert _eqns_touching(jaxpr, shape).count("pallas_call") == reads
+
+    def gathered_read(state):
+        ids = jnp.maximum(state["page_table"].reshape(-1), 0)
+        return ops_dispatch.dequantize_batch(
+            paged_kv.pool_qtensor(*state["pools"][0]["k"], ids, spec),
+            out_dtype=cfg.dtype, row_width=cfg.d_model)
+
+    old = jax.make_jaxpr(gathered_read)(sched._state)
+    assert "gather" in _eqns_touching(old, words.shape)
+    assert "gather" in _eqns_touching(old, meta.shape)
 
 
 def test_a_prefilled_request_takes_its_lane_before_the_next_prefill(

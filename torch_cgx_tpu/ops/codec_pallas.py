@@ -407,6 +407,7 @@ def _dequantize_flat_impl(
     words: jax.Array,
     meta: jax.Array,
     add_to: Optional[jax.Array] = None,
+    page_ids: Optional[jax.Array] = None,
     *,
     bits: int,
     bucket_size: int,
@@ -445,48 +446,109 @@ def _dequantize_flat_impl(
     kernel and the output is ``add_to + decoded``, skipping one HBM
     round trip of the decoded floats that a separate XLA add would pay.
     Values are bit-identical to the unfused add (same op order:
-    ``acc + (bmin + unit*lvl)``)."""
-    rows, w_row = words.shape
+    ``acc + (bmin + unit*lvl)``).
+
+    ``page_ids (n,) int32``: the paged read. ``words (pool rows, W/128,
+    128)`` and ``meta (pool rows, nb_r, 2)`` are then a page POOL in the
+    kernel's own operand layout (``ops/paged_kv.py``), and output row ``i``
+    is the decode of pool row ``page_ids[i]``: the ids are a
+    scalar-prefetch operand and the word and meta ``index_map``s pick the
+    block, so nothing gathers or reshapes the pool in front of the kernel.
+    ``tc`` is the chunks a grid step decodes, a whole number of pages
+    (:func:`_pages_tc`): one page operand pair a page, the body above once
+    a page. The ids must be valid rows (the caller clips its sentinels)."""
     b = bucket_size
     rb = b // 128
-    nb_r = w_row * LANE_GROUP // (b * bits)
+    nb_r = meta.shape[1]
+    rows = words.shape[0] if page_ids is None else page_ids.shape[0]
     n_chunks = rows * nb_r // CHUNK_BUCKETS
-    s_rows = tc * CHUNK_BUCKETS * rb
+    # Chunks one pass of the body decodes, and passes a grid step.
+    tc_body = tc if page_ids is None else nb_r // CHUNK_BUCKETS
+    ppb = tc // tc_body
+    s_rows = tc_body * CHUNK_BUCKETS * rb
 
     k = 1 if row_width is None else row_width // 128
-    t_rows = s_rows // k  # output rows a block
+    t_rows = s_rows // k  # output rows a pass
 
-    def _dequantize_flat_kernel(w_ref, m_ref, *rest):
-        if with_add:
-            acc_ref, out_ref = rest
-        elif k > 1:
-            out_ref, flat_ref = rest
-        else:
-            (out_ref,) = rest
-        w4 = w_ref[:].reshape(tc, bits, rb, 128)
+    def _decode(w_ref, m_ref):
+        w4 = w_ref[:].reshape(tc_body, bits, rb, 128)
         sub = jax.lax.broadcasted_iota(
-            jnp.int32, (tc, CHUNK_BUCKETS, rb, 128), 1
+            jnp.int32, (tc_body, CHUNK_BUCKETS, rb, 128), 1
         )
-        lvl = jnp.zeros((tc, CHUNK_BUCKETS, rb, 128), jnp.int32)
+        lvl = jnp.zeros((tc_body, CHUNK_BUCKETS, rb, 128), jnp.int32)
         for w in range(bits):
             lvl = lvl | (((w4[:, w : w + 1, :, :] >> sub) & 1) << w)
         m2 = m_ref[:]
-        unit = m2[:, 0:1].reshape(tc, CHUNK_BUCKETS, 1, 1)
-        bmin = m2[:, 1:2].reshape(tc, CHUNK_BUCKETS, 1, 1)
-        vals = (bmin + unit * lvl.astype(jnp.float32)).reshape(s_rows, 128)
-        if with_add:
-            out_ref[:] = acc_ref[:] + vals
-        elif k > 1:
+        unit = m2[:, 0:1].reshape(tc_body, CHUNK_BUCKETS, 1, 1)
+        bmin = m2[:, 1:2].reshape(tc_body, CHUNK_BUCKETS, 1, 1)
+        return (bmin + unit * lvl.astype(jnp.float32)).reshape(s_rows, 128)
+
+    def _store(out_ref, flat_ref, vals, at=slice(None)):
+        if k > 1:
             # Row t of the output block is the k flat rows t*k .. t*k+k-1
             # side by side: column j is every k-th flat row from j.
             flat_ref[:] = vals
             for j in range(k):
-                out_ref[:, j * 128 : (j + 1) * 128] = flat_ref[
+                out_ref[at, j * 128 : (j + 1) * 128] = flat_ref[
                     pl.ds(j, t_rows, stride=k), :
                 ].astype(out_dtype)
         else:
-            out_ref[:] = vals.astype(out_dtype)
+            out_ref[at, :] = vals.astype(out_dtype)
 
+    def _dequantize_flat_kernel(w_ref, m_ref, *rest):
+        vals = _decode(w_ref, m_ref)
+        if with_add:
+            acc_ref, out_ref = rest
+            out_ref[:] = acc_ref[:] + vals
+        else:
+            _store(rest[0], rest[1] if k > 1 else None, vals)
+
+    def _dequantize_pages_kernel(ids_ref, *refs):
+        del ids_ref  # read by the index maps alone
+        out_ref = refs[2 * ppb]
+        flat_ref = refs[2 * ppb + 1] if k > 1 else None
+        for p in range(ppb):
+            _store(
+                out_ref, flat_ref, _decode(refs[p], refs[ppb + p]),
+                slice(p * t_rows, (p + 1) * t_rows),
+            )
+
+    out_shape = jax.ShapeDtypeStruct(
+        (n_chunks * CHUNK_BUCKETS * rb // k, k * 128), out_dtype
+    )
+    scratch_shapes = [pltpu.VMEM((s_rows, 128), jnp.float32)] if k > 1 else []
+    if page_ids is not None:
+
+        def page(p):  # the p-th page of grid step i, by its id
+            return lambda i, ids: (ids[i * ppb + p], 0, 0)
+
+        out = pl.pallas_call(
+            _dequantize_pages_kernel,
+            name="cgx_dequantize_flat",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(rows // ppb,),
+                in_specs=[
+                    pl.BlockSpec((None,) + words.shape[1:], page(p),
+                                 memory_space=pltpu.VMEM)
+                    for p in range(ppb)
+                ] + [
+                    pl.BlockSpec((None, nb_r, 2), page(p),
+                                 memory_space=pltpu.VMEM)
+                    for p in range(ppb)
+                ],
+                out_specs=pl.BlockSpec(
+                    (ppb * t_rows, k * 128), lambda i, ids: (i, 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                scratch_shapes=scratch_shapes,
+            ),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(page_ids, *([words] * ppb), *([meta] * ppb))
+        return out.reshape(rows, -1, k * 128)
+
+    w_row = words.shape[1]
     wv = words.reshape(rows * w_row // 128, 128)
     mv = meta.reshape(rows * nb_r, 2)
     in_specs = [
@@ -511,12 +573,8 @@ def _dequantize_flat_impl(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((t_rows, k * 128), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(
-            (n_chunks * CHUNK_BUCKETS * rb // k, k * 128), out_dtype
-        ),
-        scratch_shapes=(
-            [pltpu.VMEM((s_rows, 128), jnp.float32)] if k > 1 else []
-        ),
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
         interpret=interpret,
     )(*operands)
     if row_width is not None:
@@ -753,6 +811,21 @@ def quantize_batch(
     )
 
 
+def _flat_store(out_dtype) -> np.dtype:
+    """The type the flat decode kernel stores for a consumer of
+    ``out_dtype``: its own where Mosaic takes the store
+    (``_FLAT_STORE_DTYPES``), else float32 and a cast after the kernel."""
+    store = np.dtype(out_dtype)
+    return store if store in _FLAT_STORE_DTYPES else np.dtype(np.float32)
+
+
+def _flat_lowering(store: np.dtype) -> str:
+    """The ``dequantize`` lowering name of a flat decode storing ``store``."""
+    return (
+        "pallas_flat" if store == np.float32 else f"pallas_flat.{store.name}"
+    )
+
+
 def _rows_tc(
     n_chunks: int, bucket_size: int, row_width: int, store: np.dtype
 ) -> Optional[int]:
@@ -772,6 +845,42 @@ def _rows_tc(
         if n_chunks % tc == 0 and (tc * chunk) % block_rows == 0:
             return tc
     return None
+
+
+def _pages_tc(
+    n_pages: int, page_chunks: int, bucket_size: int, row_width: int,
+    store: np.dtype,
+) -> Optional[int]:
+    """Chunks per grid step of the paged flat decode (``page_ids`` of
+    :func:`_dequantize_flat_impl`) over a table of ``n_pages`` pages of
+    ``page_chunks``: :func:`_rows_tc`'s tile over the table's chunks — the
+    tile the gathered read of the same table takes — where that is a whole
+    number of pages (two GPT-2 pages of five chunks, two latent ones of
+    eight; on the chip two pages a step read 1.271 ms a GPT-2 layer against
+    1.320 for one, PERF.md section 6, PR 30). None where the kernel cannot
+    store the rows itself or a page does not divide the tile: the read then
+    gathers (``ops/paged_kv.gather_dequant_pages``)."""
+    tc = _rows_tc(n_pages * page_chunks, bucket_size, row_width, store)
+    if tc is None or tc % page_chunks:
+        return None
+    return tc
+
+
+def pages_tile(
+    n_pages: int, numel: int, bucket_size: int, row_width: int, out_dtype
+) -> Optional[int]:
+    """:func:`_pages_tc`'s tile for a table of ``n_pages`` quantized pages
+    of ``numel`` values read as rows of ``row_width`` in ``out_dtype``, None
+    where a page is not the flat kernels' geometry (whole 32-bucket chunks
+    of 128-lane buckets, as :func:`dequantize_batch` asks of a row) or the
+    kernel cannot store those rows in whole pages."""
+    nb_r = codec.num_buckets(numel, bucket_size)
+    c_r, t_r = _row_split(nb_r)
+    if t_r or bucket_size % 128 or numel != nb_r * bucket_size:
+        return None
+    return _pages_tc(
+        n_pages, c_r, bucket_size, row_width, _flat_store(out_dtype)
+    )
 
 
 def as_rows(vals: jax.Array, row_width: Optional[int]) -> jax.Array:
@@ -827,9 +936,7 @@ def dequantize_batch(
         # The kernel stores the caller's type and rows itself when nothing
         # is added to its output after it.
         plain = add_to is None and not q.residual.shape[-1]
-        store = np.dtype(out_dtype)
-        if not plain or store not in _FLAT_STORE_DTYPES:
-            store = np.dtype(np.float32)
+        store = _flat_store(out_dtype) if plain else np.dtype(np.float32)
         tc_rows = None
         if row_width is not None:
             if plain and q.numel_main == nb_r * b:
@@ -837,11 +944,7 @@ def dequantize_batch(
             note_lowering(
                 "dequantize_rows", "pallas_flat" if tc_rows else "xla_reshape"
             )
-        note_lowering(
-            "dequantize",
-            "pallas_flat" if store == np.float32
-            else f"pallas_flat.{store.name}",
-        )
+        note_lowering("dequantize", _flat_lowering(store))
         vals = _dequantize_flat_impl(
             jax.lax.bitcast_convert_type(q.packed, jnp.int32),
             meta,
@@ -893,6 +996,34 @@ def dequantize_batch(
     if add_to is not None:
         vals = add_to.astype(jnp.float32) + vals
     return as_rows(vals.astype(out_dtype), row_width)
+
+
+def dequantize_pages(
+    words: jax.Array,
+    meta: jax.Array,
+    page_ids: jax.Array,
+    *,
+    bits: int,
+    bucket_size: int,
+    tc: int,
+    out_dtype,
+    row_width: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """The paged read: decode pool rows ``page_ids (n,)`` of a page pool
+    kept in the flat kernel's operand layout (``words (pool rows, W/128,
+    128) int32``, ``meta (pool rows, nb, 2) f32``) -> ``(n, numel /
+    row_width, row_width)`` of ``out_dtype``, the values of
+    :func:`dequantize_batch` over the gathered rows bit for bit. ``tc``:
+    :func:`_pages_tc`'s tile, which the caller has checked is not None."""
+    store = _flat_store(out_dtype)
+    note_lowering("dequantize_rows", "pallas_flat")
+    note_lowering("dequantize", _flat_lowering(store))
+    return _dequantize_flat_impl(
+        words, meta, None, page_ids,
+        bits=bits, bucket_size=bucket_size, interpret=interpret, tc=tc,
+        out_dtype=store, row_width=row_width,
+    ).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -112,6 +112,34 @@ def dequantize_batch(
     return codec_pallas.as_rows(vals, row_width)
 
 
+def takes_pallas(n: int, cc: CompressionConfig) -> bool:
+    """Whether a codec call over rows of ``n`` values at ``cc`` takes the
+    Pallas kernels on this backend (what :func:`quantize_batch` and
+    :func:`dequantize_batch` decide for themselves)."""
+    return _pick(n, cc) == "pallas"
+
+
+def dequantize_pages(
+    words: jax.Array,
+    meta: jax.Array,
+    page_ids: jax.Array,
+    cc: CompressionConfig,
+    *,
+    tile: int,
+    out_dtype,
+    row_width: int,
+) -> jax.Array:
+    """The paged cache read on Pallas dispatch
+    (``codec_pallas.dequantize_pages``); ``ops/paged_kv.py`` decides
+    whether a pool takes it (:func:`takes_pallas` and
+    ``PageSpec.paged_read_tile``)."""
+    return codec_pallas.dequantize_pages(
+        words, meta, page_ids, bits=cc.bits, bucket_size=cc.bucket_size,
+        tc=tile, out_dtype=out_dtype, row_width=row_width,
+        interpret=not _on_tpu(),
+    )
+
+
 # ---------------------------------------------------------------------------
 # Fused SRA epilogue dispatch (CGX_SRA_EPILOGUE = auto|fused|staged).
 #

@@ -1,4 +1,4 @@
-"""Paged KV-cache pool ops: quantized page commit + gather-dequantize read.
+"""Paged KV-cache pool ops: quantized page commit + paged dequantize read.
 
 The serving plane (``torch_cgx_tpu/serving/``) stores each sequence's KV
 cache as fixed-size pages in a pre-allocated pool. Pages are QUANTIZED
@@ -6,22 +6,35 @@ through the same max-min codec every other wire in the system uses
 (``ops.dispatch`` — Pallas kernels on TPU, XLA elsewhere), so a page has
 one wire representation everywhere it travels: the prefill→decode
 transport ships exactly the bytes the pool stores, and the decode
-program's KV read dequantizes them for its consumer — the gathered page
-rows feed ``dequantize_batch`` immediately before the attention in one
-staged program. On TPU dispatch the decode rides the flat Pallas
-dequantize kernel, which writes the gathered table once, in the type and
-the row order the attention reads (:func:`gather_dequant_pages`); the
-pool itself is never decoded.
+program's KV read dequantizes them for its consumer in one staged
+program. On TPU dispatch the read is the flat Pallas dequantize kernel
+walking the page table: the table's page ids are its scalar-prefetch
+operand and each grid step fetches its pages' words and meta from the
+pool by id, then writes the table once, in the type and the row order the
+attention reads (:func:`gather_dequant_pages`). Nothing gathers or
+reshapes the pool in front of the kernel, and the pool itself is never
+decoded.
 
 Layouts (all static per compiled decode program):
 
 * a page's flat payload is ``page_tokens * n_head * d_head`` values
   (one payload per (layer, K|V) pair);
-* quantized pool: ``packed (max_pages, words) uint32`` +
+* quantized pool: ``words (max_pages, *PageSpec.word_shape) int32`` +
   ``meta (max_pages, num_buckets, 2) f32`` per (layer, kind) — row ``p``
-  is page ``p``'s rows=1 QTensor, byte-compatible with the host codec's
-  wire format (``ops/codec_host.py``), so transport bytes drop straight
-  into pool rows;
+  is page ``p``'s rows=1 QTensor. The words of a page are the host
+  codec's wire words (``ops/codec_host.py``) in their wire order, as rows
+  of 128: the flat kernels' own operand layout and type
+  (``_quantize_flat_impl`` emits ``(chunks*bits*rb, 128) int32``,
+  ``_dequantize_flat_impl`` reads it), so the kernel's blocks ARE the
+  pool's rows — a GPT-2 page of 64 tokens x 1,280 is 160 rows (5 chunks x
+  8 bits x 4), fetched as one ``(160, 128)`` block at ``page_ids[i]``.
+  Stored as ``(max_pages, words)`` the pool had to be gathered and then
+  reshaped to ``(n * words / 128, 128)`` in front of every read, and on
+  the chip a ``(n, W)`` and a ``(n * W / 128, 128)`` array tile
+  differently, so that reshape was a copy of the whole table (PERF.md
+  section 6, PR 30). The bytes and their order are the wire's: a frame's
+  payload drops into a pool row by a host-side reshape
+  (:func:`pool_words`), and ``PageSpec.wire_bytes`` counts both;
 * raw pool (``bits == 0``, the f16 shipping baseline):
   ``(max_pages, page_tokens, n_head, d_head) f16``.
 """
@@ -29,7 +42,7 @@ Layouts (all static per compiled decode program):
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +50,7 @@ import numpy as np
 
 from ..config import MAX_BITS, CompressionConfig
 from . import codec
+from . import codec_pallas
 from . import dispatch as ops_dispatch
 
 
@@ -95,6 +109,31 @@ class PageSpec:
             bits=self.bits, bucket_size=self.bucket_size, stochastic=False
         )
 
+    @property
+    def word_shape(self) -> Tuple[int, int]:
+        """A page's packed words as a pool row holds them: rows of 128,
+        the flat kernels' operand layout, in wire order (a page whose
+        words are not whole rows of 128 — a bucket under 128 values, a
+        chunk tail — is one row of them all; its read gathers)."""
+        lanes = 128 if self.packed_words % 128 == 0 else self.packed_words
+        return (self.packed_words // lanes, lanes)
+
+    def paged_read_tile(self, n_pages: int, dtype) -> Optional[int]:
+        """Chunks a grid step of the paged read of an ``n_pages`` table
+        into rows of ``dtype`` (:func:`gather_dequant_pages`), None where
+        the read gathers instead. A static function of the geometry
+        (``codec_pallas.pages_tile``): the pool is quantized, a page is
+        whole 32-bucket chunks of 128-lane buckets (``page_tokens=16``
+        pages of GPT-2 are a chunk tail) and the kernel stores the
+        consumer's rows itself in a tile of whole pages (a 64-wide
+        rotated-key row is not whole lanes)."""
+        if not self.quantized:
+            return None
+        return codec_pallas.pages_tile(
+            n_pages, self.flat, self.bucket_size, self.n_head * self.d_head,
+            dtype,
+        )
+
     def wire_bytes(self) -> int:
         """Transport bytes of one page payload at this spec (meta f32 +
         bucket-padded packed words — the exact frame payload the
@@ -116,7 +155,7 @@ def default_bucket(flat: int, base: int = 512) -> int:
 
 
 def empty_pool(max_pages: int, spec: PageSpec):
-    """(packed, meta) zero pool for a quantized spec, or the raw f16
+    """(words, meta) zero pool for a quantized spec, or the raw f16
     pool array for ``bits == 0``."""
     if not spec.quantized:
         return jnp.zeros(
@@ -124,29 +163,41 @@ def empty_pool(max_pages: int, spec: PageSpec):
             jnp.float16,
         )
     return (
-        jnp.zeros((max_pages, spec.packed_words), jnp.uint32),
+        jnp.zeros((max_pages,) + spec.word_shape, jnp.int32),
         jnp.zeros((max_pages, spec.num_buckets, 2), jnp.float32),
     )
 
 
+def pool_words(packed, spec: PageSpec):
+    """Wire words ``(n, packed_words) uint32`` (a QTensor's ``packed``, a
+    frame's payload; device or host array) as pool rows ``(n,
+    *spec.word_shape) int32``: the same bytes in the same order."""
+    return packed.view(jnp.int32).reshape((-1,) + spec.word_shape)
+
+
+def wire_words(words, spec: PageSpec):
+    """Pool rows back as wire words: :func:`pool_words` undone."""
+    return words.reshape(-1, spec.packed_words).view(jnp.uint32)
+
+
 def quantize_page_rows(rows: jax.Array, spec: PageSpec) -> Tuple[jax.Array, jax.Array]:
-    """Quantize ``rows (n, flat) f32`` page payloads -> (packed, meta)
+    """Quantize ``rows (n, flat) f32`` page payloads -> (words, meta)
     pool rows. Deterministic (see :meth:`PageSpec.cc`) so the commit
     path, the host-codec transport path and any replay produce identical
     wire bytes."""
     q = ops_dispatch.quantize_batch(rows.astype(jnp.float32), spec.cc)
-    return q.packed, q.meta.astype(jnp.float32)
+    return pool_words(q.packed, spec), q.meta.astype(jnp.float32)
 
 
 def pool_qtensor(
-    packed: jax.Array, meta: jax.Array, page_ids: jax.Array, spec: PageSpec
+    words: jax.Array, meta: jax.Array, page_ids: jax.Array, spec: PageSpec
 ) -> codec.QTensor:
     """The batched QTensor view of gathered pool rows: ``page_ids (n,)``
     int32 (callers clip sentinel ids to a valid row and mask downstream —
     gathers stay in-bounds, masking stays explicit)."""
     n = page_ids.shape[0]
     return codec.QTensor(
-        packed=packed[page_ids],
+        packed=wire_words(words[page_ids], spec),
         meta=meta[page_ids],
         residual=jnp.zeros((n, 0), jnp.float32),
         numel=spec.flat,
@@ -159,19 +210,28 @@ def pool_qtensor(
 def gather_dequant_pages(
     pool, page_table: jax.Array, spec: PageSpec, dtype=jnp.float32
 ) -> jax.Array:
-    """The decode program's paged KV read: gather ``page_table (B, P)``
-    rows from the pool and decode them for the consumer -> ``(B,
-    P * page_tokens, n_head * d_head)`` in ``dtype``: one row a cached
-    position, the heads side by side as a page holds them, in the type
-    the attention contracts in (the adapter's ``cfg.dtype``). A value is
-    ``float32 decode -> astype(dtype)``, whichever lowering writes it.
+    """The decode program's paged KV read: decode the pool rows
+    ``page_table (B, P)`` names for the consumer -> ``(B, P * page_tokens,
+    n_head * d_head)`` in ``dtype``: one row a cached position, the heads
+    side by side as a page holds them, in the type the attention
+    contracts in (the adapter's ``cfg.dtype``). A value is ``float32
+    decode -> astype(dtype)``, whichever lowering writes it.
 
-    Sentinel entries (< 0) are clipped to row 0 before the gather (XLA
-    gathers must stay in bounds) and their decoded tokens are garbage by
-    construction — callers mask attention scores by the lane's committed
-    token count, never by inspecting decoded values. The dequantize is
-    ``ops.dispatch.dequantize_batch``: the Pallas flat decode kernel on
-    TPU dispatch (whole-chunk pages), staged XLA elsewhere.
+    Sentinel entries (< 0) are clipped to row 0 (every fetch stays in
+    bounds) and their decoded tokens are garbage by construction —
+    callers mask attention scores by the lane's committed token count,
+    never by inspecting decoded values. Every table entry is decoded.
+
+    Two lowerings, counted per call site as
+    ``cgx.codec.lowering.dequantize_pages.*``: ``pallas_paged`` on Pallas
+    dispatch where :meth:`PageSpec.paged_read_tile` gives a tile — the
+    flat decode kernel fetches each page from the pool by its id
+    (``codec_pallas.dequantize_pages``; nothing in front of it but the
+    clip) — and ``xla_gather`` everywhere else: an XLA gather of the
+    table's rows, then ``ops.dispatch.dequantize_batch`` over them (the
+    64-wide rotated key of a latent cache, whose rows XLA reshapes; pages
+    that are not whole chunks; the XLA codec off the TPU; raw pools, a
+    gather and a cast).
 
     XLA does NOT fuse the decode into the attention that reads it: the
     kernel's output is a table in HBM, and whatever lies between it and
@@ -190,11 +250,23 @@ def gather_dequant_pages(
     width = spec.n_head * spec.d_head
     if not spec.quantized:
         rows = pool[ids].astype(dtype)
+        return rows.reshape(b, p * spec.page_tokens, width)
+    words, meta = pool
+    tile = None
+    if ops_dispatch.takes_pallas(spec.flat, spec.cc):
+        tile = spec.paged_read_tile(b * p, dtype)
+    codec_pallas.note_lowering(
+        "dequantize_pages", "pallas_paged" if tile else "xla_gather"
+    )
+    if tile:
+        rows = ops_dispatch.dequantize_pages(
+            words, meta, ids, spec.cc, tile=tile, out_dtype=dtype,
+            row_width=width,
+        )
     else:
-        packed, meta = pool
-        q = pool_qtensor(packed, meta, ids, spec)
         rows = ops_dispatch.dequantize_batch(
-            q, out_dtype=dtype, row_width=width
+            pool_qtensor(words, meta, ids, spec), out_dtype=dtype,
+            row_width=width,
         )
     return rows.reshape(b, p * spec.page_tokens, width)
 
@@ -209,6 +281,6 @@ def commit_page_rows(pool, page_ids: jax.Array, rows: jax.Array, spec: PageSpec)
             -1, spec.page_tokens, spec.n_head, spec.d_head
         ).astype(jnp.float16)
         return pool.at[page_ids].set(pages)
-    packed, meta = pool
-    p_rows, m_rows = quantize_page_rows(rows, spec)
-    return packed.at[page_ids].set(p_rows), meta.at[page_ids].set(m_rows)
+    words, meta = pool
+    w_rows, m_rows = quantize_page_rows(rows, spec)
+    return words.at[page_ids].set(w_rows), meta.at[page_ids].set(m_rows)

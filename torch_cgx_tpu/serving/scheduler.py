@@ -172,6 +172,15 @@ class Request:
 #                   int32 vector of ``step_counters`` or None); ``state``
 #                   holds ``pools[l][stream]`` and ``tail_<stream>[l]``
 #
+# ``pools[l][stream]`` is ``paged_kv.empty_pool(max_pages + 1, spec)``: for
+# a quantized stream ``(words (max_pages + 1, *spec.word_shape) int32, meta
+# (max_pages + 1, num_buckets, 2) f32)``, a page's wire words as rows of
+# 128 — the flat decode kernel's own blocks, so the read fetches a page from
+# the pool by its id and nothing gathers or reshapes the pool first (on the
+# chip a ``(n, W)`` and a ``(n * W / 128, 128)`` array tile differently:
+# ``ops/paged_kv.py``, "Layouts"); ``(max_pages + 1, page_tokens, n_head,
+# d_head) f16`` for a raw one. The last row is the masked commit's scratch.
+#
 # The GPT-2 adapter: explicit-parameter forward passes over the module's
 # own parameter tree (models/gpt2.py) — decode against the paged cache
 # needs per-layer K/V in and out, which the flax module doesn't expose.
@@ -630,18 +639,18 @@ def _build_programs(server) -> SimpleNamespace:
 
 
 def _ingest_pool(pool, ids, rows, spec: paged_kv.PageSpec):
-    """Scatter pre-encoded pool rows: quantized rows arrive as (packed,
-    meta) pairs (the transport's wire layout IS the pool layout), raw
-    rows as f32 payloads."""
+    """Scatter pre-encoded pool rows: quantized rows arrive as (words,
+    meta) pairs in pool-row form (the transport's wire bytes ARE the
+    pool's, ``paged_kv.pool_words``), raw rows as f32 payloads."""
     if not spec.quantized:
         pages = rows.reshape(
             -1, spec.page_tokens, spec.n_head, spec.d_head
         ).astype(jnp.float16)
         return pool.at[ids].set(pages)
-    packed, meta = pool
-    rows_packed, rows_meta = rows
+    words, meta = pool
+    rows_words, rows_meta = rows
     return (
-        packed.at[ids].set(rows_packed),
+        words.at[ids].set(rows_words),
         meta.at[ids].set(rows_meta),
     )
 
@@ -1357,9 +1366,9 @@ def _pad_prompt(prompt: np.ndarray, page_tokens: int) -> np.ndarray:
 
 
 def _decode_page_payload(frame: tp.PageFrame, spec: paged_kv.PageSpec):
-    """A page frame's payload in pool-row form: (packed, meta) numpy
-    pair for quantized specs (the host-codec wire layout — zero
-    re-encoding), or the raw f32 payload row."""
+    """A page frame's payload in pool-row form: (words, meta) numpy
+    pair for quantized specs (the host-codec wire words, reshaped to the
+    pool's rows of 128 — zero re-encoding), or the raw f32 payload row."""
     if not spec.quantized:
         return np.frombuffer(frame.payload, np.float16).astype(
             np.float32
@@ -1368,7 +1377,10 @@ def _decode_page_payload(frame: tp.PageFrame, spec: paged_kv.PageSpec):
         np.frombuffer(frame.payload, np.uint8),
         spec.flat, spec.bits, spec.bucket_size, np.float32,
     )
-    return np.asarray(q.packed), np.asarray(q.meta, np.float32)
+    return (
+        paged_kv.pool_words(np.asarray(q.packed), spec)[0],
+        np.asarray(q.meta, np.float32),
+    )
 
 
 def _stack_rows(rows: List, spec: paged_kv.PageSpec):
