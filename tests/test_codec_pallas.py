@@ -1071,6 +1071,21 @@ def _cell_cases():
         read, page=(256, 1, 64),
     ), {"dequantize_pages": "xla_gather", "dequantize": "pallas_flat.bfloat16",
         "dequantize_rows": "xla_reshape"}, {"_pages_tc": None, "_pipe_tc": 16}
+    # ISSUE 31: granite-serve-chat64. ``k`` and ``v`` of the four attention
+    # layers: a page of 256 tokens x 8 K/V heads x 64 is the latent ``c``'s
+    # geometry (256 buckets of 512 in rows of 512), read through a (64, 6)
+    # page table over a pool of 385 rows: paged, two pages a grid step.
+    for name in ("k", "v"):
+        yield f"granite-decode-pages-{name}", "dequantize_pages", dict(
+            bits=8, rows=384, out_dtype=jnp.bfloat16, page=(256, 8, 64),
+            lanes=64, pool=385,
+        ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    # Its commits: 64 lanes' tails in the decode loop, a padded prompt's 2
+    # or 4 pages in prefill_pages (512 and 1,024 tokens).
+    for rows in (64, 2, 4):
+        yield f"granite-kv-commit-{rows}", "quantize", dict(
+            bits=8, rows=rows, numel=_JOYAI_C,
+        ), {"quantize": flat}, {"_pipe_tc": 16}
     # Page commits: every lane's tail in the decode loop (32 rows), a padded
     # prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and 3,072).
     for name, numel, commits in (
@@ -1115,11 +1130,13 @@ def _trace_cell_call(kind, *, bits, rows, numel=None, bucket=512, **kw):
         from torch_cgx_tpu.ops import paged_kv
 
         spec = paged_kv.PageSpec(*kw["page"], bits, bucket)
-        pool = jax.eval_shape(lambda: paged_kv.empty_pool(513, spec))
+        lanes = kw.get("lanes", 32)
+        pool = jax.eval_shape(
+            lambda: paged_kv.empty_pool(kw.get("pool", 513), spec))
         return jax.eval_shape(
             lambda pool, table: paged_kv.gather_dequant_pages(
                 pool, table, spec, kw["out_dtype"]),
-            pool, jax.ShapeDtypeStruct((32, rows // 32), jnp.int32),
+            pool, jax.ShapeDtypeStruct((lanes, rows // lanes), jnp.int32),
         )
     if kind == "quantize":
         return jax.eval_shape(
@@ -1179,7 +1196,8 @@ def test_cells_lowering_and_tile(kind, geo, lowering, tiles, monkeypatch):
     out = _trace_cell_call(kind, **geo)
     if kind == "dequantize_pages":
         pt, h, d = geo["page"]
-        assert out.shape == (32, geo["rows"] // 32 * pt, h * d)
+        lanes = geo.get("lanes", 32)
+        assert out.shape == (lanes, geo["rows"] // lanes * pt, h * d)
         assert out.dtype == geo["out_dtype"]
     ledger = {
         k[len("cgx.codec.lowering."):]: v

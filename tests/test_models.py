@@ -286,3 +286,80 @@ def test_tp_sharding_survives_train_step(monkeypatch):
             assert "tp" in str(got), (path_str(path), got)
             checked += 1
     assert checked >= 4, f"only {checked} tp-sharded leaves found"
+
+
+# -- the hybrid state-space / attention decoder (ISSUE 31) -------------------
+
+
+def _published_granite():
+    import json
+    from pathlib import Path
+
+    path = (Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+            / "granite-4.0-h-micro-serve-kv8.json")
+    return json.loads(path.read_text())
+
+
+def test_hybrid_config_counts_what_the_published_widths_weigh():
+    """``HybridConfig.from_hf`` over granite-4.0-h-micro's published keys:
+    36 Mamba-2 and 4 attention layers, and the two weights the serve plan
+    is told apart: a token's K and V over the attention layers alone, a
+    lane's recurrent state over the Mamba layers whatever its length (77.4
+    MB: 4.95 GB at 64 lanes)."""
+    from torch_cgx_tpu.models.granite_hybrid import HybridConfig
+
+    cfg = HybridConfig.from_hf(_published_granite())
+    assert cfg.n_layer == 40 and cfg.attention_layers == (5, 15, 25, 35)
+    assert (cfg.n_head, cfg.n_kv_head, cfg.d_head) == (32, 8, 64)
+    assert (cfg.d_inner, cfg.d_xbc, cfg.d_state, cfg.chunk) == (
+        4096, 4352, 128, 256)
+    assert cfg.kv_bytes_per_token() == 4 * 2 * 512 * 4
+    assert cfg.state_bytes_per_lane() == 36 * (3 * 4352 + 128 * 4096) * 4
+    assert round(cfg.state_bytes_per_lane() * 64 / 1e9, 2) == 4.95
+
+
+@pytest.mark.parametrize("key,value", [
+    ("mamba_n_groups", 8), ("attention_bias", True),
+    ("num_local_experts", 4), ("position_embedding_type", "rope"),
+])
+def test_hybrid_config_refuses_what_it_has_no_equations_for(key, value):
+    from torch_cgx_tpu.models.granite_hybrid import HybridConfig
+
+    with pytest.raises(ValueError, match=key):
+        HybridConfig.from_hf(dict(_published_granite(), **{key: value}))
+
+
+@pytest.mark.parametrize("h,hk", [(8, 8), (8, 2), (4, 1)])
+def test_decode_attention_with_grouped_queries(h, hk):
+    """``decode_attention`` over rows of ``hk`` K/V heads for ``h`` query
+    heads, scores divided by a stated divisor, against the attention
+    written out a head at a time (query head ``i`` reads K/V head ``i //
+    (h / hk)``); float32, limit 1e-5 of the output's largest value."""
+    from torch_cgx_tpu.models.attention import decode_attention
+
+    rng = np.random.default_rng(h * 10 + hk)
+    b, t, tt, d = 2, 12, 4, 8
+    q = rng.standard_normal((b, h, d)).astype(np.float32)
+    k, v = (rng.standard_normal((b, t, hk * d)).astype(np.float32)
+            for _ in range(2))
+    kt, vt = (rng.standard_normal((b, tt, hk * d)).astype(np.float32)
+              for _ in range(2))
+    mask = np.arange(t)[None, :] < np.asarray([[9], [12]])
+    tail_mask = np.arange(tt)[None, :] <= np.asarray([[1], [3]])
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(decode_attention(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), jnp.asarray(kt),
+            jnp.asarray(vt), mask=jnp.asarray(mask),
+            tail_mask=jnp.asarray(tail_mask), score_divisor=5.0,
+        )).reshape(b, h, d)
+    keys = np.concatenate([k, kt], axis=1).reshape(b, t + tt, hk, d)
+    vals = np.concatenate([v, vt], axis=1).reshape(b, t + tt, hk, d)
+    live = np.concatenate([mask, tail_mask], axis=1)
+    for i in range(h):
+        g = i // (h // hk)
+        s = np.einsum("bd,btd->bt", q[:, i], keys[:, :, g]) / 5.0
+        s = np.where(live, s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        want = np.einsum("bt,btd->bd", p, vals[:, :, g])
+        assert np.max(np.abs(got[:, i] - want)) < 1e-5 * np.max(np.abs(want))

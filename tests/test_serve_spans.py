@@ -263,3 +263,121 @@ def test_gc_pause_is_published_by_the_next_tick(server):
     assert metrics.get("cgx.serve.host_gc_s") == before  # stamped only
     sched.step()
     assert metrics.get("cgx.serve.host_gc_s") == before + 1
+
+
+# -- the recurrent state beside the pages (ISSUE 31) -------------------------
+
+HYBRID = dict(
+    vocab_size=512, hidden_size=64, num_attention_heads=8,
+    num_key_value_heads=2, shared_intermediate_size=128,
+    layer_types=["mamba", "attention", "mamba"], mamba_n_heads=8,
+    mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4, mamba_chunk_size=PAGE,
+    mamba_n_groups=1, embedding_multiplier=12, residual_multiplier=0.22,
+    attention_multiplier=0.125, logits_scaling=8, rms_norm_eps=1e-5,
+    precision={"params": "float32"},
+)
+
+
+@pytest.fixture
+def hybrid_server(monkeypatch):
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    from benchmark import weights_granite_hybrid
+    from torch_cgx_tpu.models.granite_hybrid import HybridConfig
+    from torch_cgx_tpu.serving.hybrid import HybridSSMServer
+
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    cfg = HybridConfig.from_hf(HYBRID, dtype=jnp.float32)
+    serve = ServeConfig(page_tokens=PAGE, max_batch=2, max_pages=16,
+                        max_seq=64, ship_depth=4)
+    return HybridSSMServer(
+        cfg, weights_granite_hybrid.make_params(HYBRID, 1), serve)
+
+
+def _run_hybrid(server, n_requests=3):
+    sched = ContinuousBatchScheduler(server)
+    reqs = [Request(id=f"h{i}", tokens=list(range(3, 3 + n)),
+                    max_new_tokens=GEN)
+            for i, n in enumerate(PROMPT_LENS[:n_requests])]
+    for r in reqs:
+        sched.submit(r)
+    assert sched.run(deadline_s=300.0)
+    return sched
+
+
+def test_state_bytes_gauge_is_what_the_lanes_hold(hybrid_server):
+    """``cgx.serve.state.bytes``: the recurrent state the scheduler holds,
+    every lane of every Mamba layer, from the adapter's own count; 0 for a
+    model without state streams (GPT-2)."""
+    metrics.reset()
+    sched = ContinuousBatchScheduler(hybrid_server)
+    held = sum(t.nbytes for name in ("state_conv", "state_ssm")
+               for t in sched._state[name] if t is not None)
+    assert held == 2 * hybrid_server.state_bytes_per_lane() > 0
+    assert metrics.get("cgx.serve.state.bytes") == float(held)
+
+
+def test_state_bytes_gauge_reads_zero_without_state(server):
+    metrics.reset()
+    ContinuousBatchScheduler(server)
+    assert metrics.get("cgx.serve.state.bytes") == 0.0
+
+
+def test_state_is_a_memledger_owner(hybrid_server, monkeypatch):
+    """The state is registered with the memory ledger under
+    ``serve.state``, lanes and bytes; a rebuild releases what it drops."""
+    from torch_cgx_tpu.observability import memledger
+
+    noted = []
+    monkeypatch.setattr(
+        memledger, "note_alloc",
+        lambda owner, n=1, nbytes=0: noted.append(("alloc", owner, n, nbytes)))
+    monkeypatch.setattr(
+        memledger, "note_release",
+        lambda owner, n=1, nbytes=0: noted.append(
+            ("release", owner, n, nbytes)))
+    sched = ContinuousBatchScheduler(hybrid_server)
+    held = int(metrics.get("cgx.serve.state.bytes"))
+    assert ("alloc", "serve.state", 2, held) in noted
+    sched._state = sched._fresh_state()
+    ours = [e for e in noted if e[1] == "serve.state"]
+    assert ours == [("alloc", "serve.state", 2, held),
+                    ("release", "serve.state", 2, held),
+                    ("alloc", "serve.state", 2, held)]
+
+
+def test_lane_writes_count_admissions_that_wrote_a_state(hybrid_server,
+                                                         server):
+    """``cgx.serve.state.lane_writes``: one an admission of a model with
+    state streams, none for GPT-2's."""
+    metrics.reset()
+    _run_hybrid(hybrid_server)
+    assert metrics.get("cgx.serve.state.lane_writes") == 3.0
+    assert metrics.get("cgx.serve.requests_admitted") == 3.0
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    sched.submit(Request(id="g", tokens=list(range(3, 9)),
+                         max_new_tokens=GEN))
+    assert sched.run(deadline_s=300.0)
+    assert metrics.get("cgx.serve.requests_admitted") == 1.0
+    assert metrics.get("cgx.serve.state.lane_writes") == 0.0
+
+
+@pytest.mark.parametrize("impl,lowering", [("xla", "xla"),
+                                           ("pallas", "pallas")])
+def test_ssm_update_call_sites_are_counted_by_lowering(
+        hybrid_server, monkeypatch, impl, lowering):
+    """``cgx.codec.lowering.ssm_update.<lowering>`` counts the decode
+    program's call sites, one a Mamba layer, as the codec counts its own."""
+    from torch_cgx_tpu.serving import scheduler as sched_mod
+
+    monkeypatch.setenv("CGX_CODEC_IMPL", impl)
+    sched_mod.invalidate_decode_cache("test")
+    metrics.reset()
+    sched = ContinuousBatchScheduler(hybrid_server)
+    jax.make_jaxpr(sched._prog.decode_step)(hybrid_server.p, sched._state)
+    counted = {k: v for k, v in metrics.snapshot(
+        "cgx.codec.lowering.ssm_update.").items()}
+    assert counted == {f"cgx.codec.lowering.ssm_update.{lowering}": 2.0}

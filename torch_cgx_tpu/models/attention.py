@@ -61,17 +61,22 @@ def joined_softmax(scores, mask, tail_scores=None, tail_mask=None, *, dtype):
     return probs[..., :t], probs[..., t:]
 
 
-def decode_attention(q, k, v, k_tail, v_tail, *, mask, tail_mask):
+def decode_attention(q, k, v, k_tail, v_tail, *, mask, tail_mask,
+                     score_divisor=None):
     """Single-position decode attention over a lane's cache as it lies.
 
-    ``q``: (B, H, D) — the lane's current token. ``k``/``v``: (B, T, H*D)
+    ``q``: (B, H, D) — the lane's current token. ``k``/``v``: (B, T, Hk*D)
     — the page table's positions as ``ops.paged_kv.gather_dequant_pages``
-    returns them: one row a position, the heads side by side, garbage
+    returns them: one row a position, the K/V heads side by side, garbage
     beyond each lane's committed length. ``k_tail``/``v_tail``: (B, Tt,
-    H*D), the tail rows the same way. ``mask`` (B, T) / ``tail_mask`` (B,
+    Hk*D), the tail rows the same way. ``mask`` (B, T) / ``tail_mask`` (B,
     Tt): True = a live position. Returns (B, H*D) in ``q.dtype``.
     Causality is implied: every live cached position precedes (or is) the
-    query token, so the masks ARE the causal mask.
+    query token, so the masks ARE the causal mask. ``Hk`` is the rows'
+    width over ``D``: with fewer K/V heads than query heads (grouped
+    queries) K/V head ``g`` is read by the ``H / Hk`` query heads ``g * H /
+    Hk`` onward. The scores are divided by ``score_divisor`` (``sqrt(D)``
+    unless given).
 
     The rows are contracted where they lie. Each head's query is laid
     into its own D columns of an H*D row (zeros elsewhere), so the scores
@@ -84,13 +89,18 @@ def decode_attention(q, k, v, k_tail, v_tail, *, mask, tail_mask):
     softmax, ``q.dtype`` probabilities, the pages' and the tail's weighted
     sums accumulated and added in float32 and cast once."""
     b, h, d = q.shape
-    own = jnp.eye(h, dtype=q.dtype)
-    q_rows = (q[:, :, None, :] * own[:, :, None]).reshape(b, h, h * d)
+    hk = k.shape[-1] // d
+    own = jnp.eye(hk, dtype=q.dtype)
+    if h != hk:  # (H, Hk): which K/V head a query head reads
+        own = jnp.repeat(own, h // hk, axis=0)
+    q_rows = (q[:, :, None, :] * own[:, :, None]).reshape(b, h, hk * d)
+    if score_divisor is None:
+        score_divisor = np.sqrt(d)
 
     def scores(rows):
         return jnp.einsum("bhw,btw->bht", q_rows, rows,
                           preferred_element_type=jnp.float32
-                          ) / np.float32(np.sqrt(d))
+                          ) / np.float32(score_divisor)
 
     def weighted(probs, rows):
         return jnp.einsum("bht,btw->bhw", probs, rows,
@@ -99,9 +109,13 @@ def decode_attention(q, k, v, k_tail, v_tail, *, mask, tail_mask):
     probs, tail_probs = joined_softmax(
         scores(k), mask, scores(k_tail), tail_mask, dtype=q.dtype
     )
-    o = weighted(probs, v) + weighted(tail_probs, v_tail)  # (B, H, H*D) f32
+    o = weighted(probs, v) + weighted(tail_probs, v_tail)  # (B, H, Hk*D) f32
+    # A query head keeps its own K/V head's D columns (one product of each
+    # sum is not an exact zero). With as many K/V heads as query heads
+    # ``own`` is symmetric and the sum over either axis is the same array.
     o = jnp.sum(
-        o.reshape(b, h, h, d) * own.astype(jnp.float32)[:, :, None], axis=1
+        o.reshape(b, h, hk, d) * own.astype(jnp.float32)[:, :, None],
+        axis=1 if h == hk else 2,
     )
     return o.reshape(b, h * d).astype(q.dtype)
 

@@ -44,6 +44,10 @@ class GPT2Config:
         """float32 bytes of one token's K and V over all layers."""
         return 2 * self.n_layer * self.d_model * 4
 
+    def state_bytes_per_lane(self) -> int:
+        """No recurrent state beside the pages."""
+        return 0
+
     @staticmethod
     def small(**kw):
         return GPT2Config(**kw)

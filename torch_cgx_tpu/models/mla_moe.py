@@ -95,6 +95,10 @@ class MlaMoeConfig:
         and the rotated key."""
         return self.n_layer * (self.kv_lora_rank + self.d_rope) * 4
 
+    def state_bytes_per_lane(self) -> int:
+        """No recurrent state beside the pages."""
+        return 0
+
 
 def rms_norm(x, w, eps):
     xf = x.astype(jnp.float32)

@@ -16,7 +16,7 @@ from jax import lax
 
 from .. import config as cfg_mod
 from ..config import CompressionConfig
-from . import codec, codec_pallas
+from . import codec, codec_pallas, ssm
 
 
 def _on_tpu() -> bool:
@@ -332,3 +332,19 @@ def reduce_rows_requantize(
     return quantize_batch(
         reduced.astype(out_dtype)[None], cc, key if stochastic else None
     )
+
+
+def ssm_update(state, decay, dtx, bm, cm):
+    """One token's update of a state-space layer's recurrent state, all
+    lanes (``ops/ssm.py``): the ``cgx_ssm_update`` kernel on the TPU (and,
+    interpreted, wherever ``CGX_CODEC_IMPL=pallas`` asks for the kernels),
+    its ``jax.numpy`` form elsewhere; counted per call site as
+    ``cgx.codec.lowering.ssm_update.pallas`` / ``.xla``."""
+    impl = cfg_mod.codec_impl()
+    if impl == "pallas" or (impl == "auto" and _on_tpu()):
+        codec_pallas.note_lowering("ssm_update", "pallas")
+        return ssm.ssm_update_pallas(
+            state, decay, dtx, bm, cm, interpret=not _on_tpu()
+        )
+    codec_pallas.note_lowering("ssm_update", "xla")
+    return ssm.ssm_update_xla(state, decay, dtx, bm, cm)

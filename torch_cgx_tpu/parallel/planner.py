@@ -511,6 +511,7 @@ class CostModel:
         bucket: int,
         page_tokens: int,
         depth: int,
+        state_lane_bytes: int = 0,
     ) -> Tuple[float, float]:
         """(predicted TTFT seconds, predicted per-page wire seconds) of
         the disaggregated prefill→decode hop (PR 15):
@@ -525,7 +526,11 @@ class CostModel:
           ``chunk_overhead_s``;
         * TTFT is the full prompt's page stream through that pipe —
           admission waits for the LAST page, so the stream is the
-          latency term the SLO controller's bit budget moves.
+          latency term the SLO controller's bit budget moves;
+        * ``state_lane_bytes`` of recurrent state (a state-space model's
+          lanes hold a fixed-size state beside their pages) would cross
+          once, raw, whatever the page size: a term that moves no choice
+          and keeps the predicted TTFT honest.
         """
         page_tokens = max(1, int(page_tokens))
         depth = max(1, int(depth))
@@ -547,6 +552,7 @@ class CostModel:
         # from always picking the largest page.
         waste = 0.5 * page_tokens / max(1, prompt_tokens)
         ttft = frames * per_frame * (1.0 + waste)
+        ttft += state_lane_bytes / (self.wire_gbps * 1e9)
         return ttft, per_frame
 
 
@@ -1232,6 +1238,7 @@ def solve_serve_plan(
     bits: int,
     bucket: int,
     *,
+    state_lane_bytes: int = 0,
     model: Optional[CostModel] = None,
 ) -> ServePlan:
     """argmin of :meth:`CostModel.predict_serve` over the candidate
@@ -1249,7 +1256,7 @@ def solve_serve_plan(
         for depth in SERVE_DEPTH_CANDIDATES:
             ttft, per_frame = model.predict_serve(
                 prompt_tokens, kv_token_bytes, n_layers, bits, bucket,
-                pt, depth,
+                pt, depth, state_lane_bytes,
             )
             if best is None or ttft < best[0] - 1e-15:
                 best = (ttft, pt, depth, per_frame)
@@ -1267,6 +1274,7 @@ def solve_serve_plan(
         predicted_ttft_ms=round(ttft * 1e3, 3),
         bits=int(bits),
         prompt_tokens=int(prompt_tokens),
+        state_lane_bytes=int(state_lane_bytes),
         model=model.source,
     )
     return ServePlan(

@@ -18,6 +18,9 @@ inference:
   the GPT-2 adapter (streams ``k``, ``v``).
 * :mod:`.latent` — the latent-attention (MLA) adapter with dropless
   experts (streams ``c``, ``kr``).
+* :mod:`.hybrid` — the hybrid state-space adapter: ``k``, ``v`` pages on
+  its few attention layers, a per-lane recurrent state (``conv``, ``ssm``)
+  on its Mamba-2 layers.
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -32,5 +35,6 @@ from .scheduler import (  # noqa: F401
     invalidate_decode_cache,
 )
 from .latent import LatentMoEServer  # noqa: F401
+from .hybrid import HybridSSMServer  # noqa: F401
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401

@@ -65,11 +65,17 @@ class LatentMoEServer:
         )
         return (("c", c), ("kr", kr))
 
+    def state_streams(self, layer: int):
+        return ()
+
     def with_params(self, params) -> "LatentMoEServer":
         return LatentMoEServer(self.cfg, params, self.serve)
 
     def kv_bytes_per_token(self) -> int:
         return self.cfg.kv_bytes_per_token()
+
+    def state_bytes_per_lane(self) -> int:
+        return 0
 
     # -- forwards ----------------------------------------------------------
 

@@ -6,7 +6,14 @@ token leaves behind as ``(name, PageSpec)`` — GPT-2's ``k`` and ``v``, a
 latent-attention model's latent and rotated key (``serving/latent.py``) —
 and gives a prefill and a decode forward over them. Pools, tails and every
 compiled program below go over the streams the adapter names; nothing here
-knows what a stream means.
+knows what a stream means. Layers may name different streams (a hybrid
+model's few attention layers among its state-space ones,
+``serving/hybrid.py``): a layer without a stream has no pool, tail or
+payload for it. Beside the pages an adapter may state, per layer, STATE
+streams ``(name, shape, dtype)``: a fixed-size recurrent state a lane,
+rewritten whole by every decode step, written into a lane at admission from
+what the prefill left on the device, and never committed, paged, forked or
+evicted by page.
 
 The decode worker runs ONE compiled step program: for every lane of a
 fixed ``CGX_SERVE_MAX_BATCH``-wide batch, gather the lane's committed KV
@@ -109,9 +116,11 @@ class ServeConfig:
         / ``CGX_KV_SHIP_DEPTH`` unset lets ``planner.solve_serve_plan``
         pick page size and shipping depth from the serve cost curves.
         ``model`` (an adapter or its model config: anything with
-        ``n_layer`` and ``kv_bytes_per_token()``) says what a token's
-        cache weighs, which differs sevenfold between a K/V cache and a
-        latent one; without it the static defaults apply."""
+        ``n_layer``, ``kv_bytes_per_token()`` and
+        ``state_bytes_per_lane()``) says what a token's cache weighs, which
+        differs sevenfold between a K/V cache and a latent one, and what a
+        lane's recurrent state weighs whatever its length; without it the
+        static defaults apply."""
         pt = cfg_mod.kv_page_tokens()
         depth = cfg_mod.kv_ship_depth()
         if (not pt or not depth) and model is not None:
@@ -120,9 +129,11 @@ class ServeConfig:
             plan = planner.solve_serve_plan(
                 prompt_tokens=min(cfg_mod.serve_max_seq(), 128),
                 kv_token_bytes=model.kv_bytes_per_token(),
-                n_layers=model.n_layer,
+                # a hybrid model's pages are its attention layers' alone
+                n_layers=getattr(model, "n_cache_layers", model.n_layer),
                 bits=cfg_mod.kv_bits(),
                 bucket=cfg_mod.default_compression_config().bucket_size,
+                state_lane_bytes=model.state_bytes_per_lane(),
             )
             pt = pt or plan.page_tokens
             depth = depth or plan.ship_depth
@@ -160,17 +171,27 @@ class Request:
 #                   counts each step (empty for a model that counts nothing)
 #   layer_name(l)   the layer's ``kv_page`` edge name
 #   cache_streams(l)  the layer's cache streams, ``((name, PageSpec), ...)``,
-#                   built with :func:`page_specs`; every layer names the same
-#                   streams in the same order
+#                   built with :func:`page_specs`; ``()`` for a layer that
+#                   leaves no pages. The programs' stream names are the
+#                   layers' union, in order of first appearance
+#   state_streams(l)  the layer's recurrent state a lane, ``((name, shape,
+#                   dtype), ...)``; ``()`` for a layer (or a model) with none
 #   with_params(p)  the adapter over another (traced) parameter tree
 #   kv_bytes_per_token()  float32 bytes a token's cache weighs, all layers
+#   state_bytes_per_lane()  float32 bytes of a lane's state streams, all layers
 #   prefill_forward(tokens, positions, last_idx) -> (logits (B, V), then one
-#                   list per stream, in ``cache_streams`` order, of each
-#                   layer's (B, S, n_head, d_head) f32 cache payload)
+#                   list per cache stream name, of each layer's (B, S,
+#                   n_head, d_head) f32 cache payload, then one list per
+#                   state stream name, of each layer's (B, *shape) state after
+#                   position ``last_idx``; None in a list for a layer
+#                   without that stream)
 #   decode_forward(state, streams) -> (logits (B, V), {stream: [each
-#                   layer's new tail (B, page_tokens, n_head, d_head)]},
+#                   layer's new tail (B, page_tokens, n_head, d_head)]} and,
+#                   in the same dictionary, {state stream: [each layer's new
+#                   state (B, *shape)]}, None for a layer without it,
 #                   int32 vector of ``step_counters`` or None); ``state``
-#                   holds ``pools[l][stream]`` and ``tail_<stream>[l]``
+#                   holds ``pools[l][stream]``, ``tail_<stream>[l]`` and
+#                   ``state_<state stream>[l]``
 #
 # ``pools[l][stream]`` is ``paged_kv.empty_pool(max_pages + 1, spec)``: for
 # a quantized stream ``(words (max_pages + 1, *spec.word_shape) int32, meta
@@ -262,11 +283,17 @@ class GPT2Server:
                              [(self.n_head, self.d_head)])
         return (("k", spec), ("v", spec))
 
+    def state_streams(self, layer: int):
+        return ()
+
     def with_params(self, params) -> "GPT2Server":
         return GPT2Server(self.cfg, params, self.serve)
 
     def kv_bytes_per_token(self) -> int:
         return self.cfg.kv_bytes_per_token()
+
+    def state_bytes_per_lane(self) -> int:
+        return 0
 
     # -- forwards ----------------------------------------------------------
 
@@ -394,28 +421,46 @@ class GPT2Server:
 def _resolved_streams(server) -> Tuple:
     """Every layer's cache streams ``((name, PageSpec), ...)`` under the
     CURRENT kv_page resolution (:func:`page_specs`), as the adapter states
-    them; all layers have to name the same streams."""
-    streams = tuple(
+    them; ``()`` for a layer that leaves no pages."""
+    return tuple(
         tuple(server.cache_streams(layer)) for layer in range(server.n_layer)
     )
-    names = _stream_names(streams)
-    for layer, layer_streams in enumerate(streams):
-        if tuple(n for n, _ in layer_streams) != names:
-            raise ValueError(
-                f"adapter {server.kind!r}: layer {layer} names the cache "
-                f"streams {[n for n, _ in layer_streams]}, layer 0 {names}"
-            )
-    return streams
+
+
+def _resolved_state_streams(server) -> Tuple:
+    """Every layer's state streams as ``((name, (shape, dtype name)),
+    ...)``: the cache streams' form, a name and what one lane's row is."""
+    return tuple(
+        tuple((name, (tuple(shape), jnp.dtype(dtype).name))
+              for name, shape, dtype in server.state_streams(layer))
+        for layer in range(server.n_layer)
+    )
 
 
 def _stream_names(streams) -> Tuple[str, ...]:
-    return tuple(name for name, _ in streams[0])
+    """The names the layers' streams (cache or state) go by: their union,
+    in order of first appearance."""
+    return tuple(dict.fromkeys(
+        name for layer in streams for name, _ in layer
+    ))
 
 
-def _leading_specs(streams) -> Tuple[paged_kv.PageSpec, ...]:
+def _holders(streams) -> Dict[str, Dict[int, int]]:
+    """``{stream: {layer: its rank among the layers that have the
+    stream}}``: where a layer's entry lies in an array stacked over those
+    layers (a prefill's tails and states)."""
+    out: Dict[str, Dict[int, int]] = {n: {} for n in _stream_names(streams)}
+    for layer, layer_streams in enumerate(streams):
+        for name, _ in layer_streams:
+            out[name][layer] = len(out[name])
+    return out
+
+
+def _leading_specs(streams) -> Tuple[Optional[paged_kv.PageSpec], ...]:
     """Each layer's leading stream's spec: the layer's wire resolution
-    (bits are per layer; GPT-2's ``k`` and ``v`` share the whole spec)."""
-    return tuple(layer[0][1] for layer in streams)
+    (bits are per layer; GPT-2's ``k`` and ``v`` share the whole spec);
+    None for a layer without pages."""
+    return tuple(layer[0][1] if layer else None for layer in streams)
 
 
 def _resolved_specs(server) -> Tuple[paged_kv.PageSpec, ...]:
@@ -427,7 +472,8 @@ def _program_key(server) -> Tuple:
     kind and model geometry, serve geometry, the per-layer resolved cache
     streams (covering the edge registry through both the resolved values
     AND the registry version — a re-registration that resolves identically
-    keeps the key), and the trace-affecting env knobs
+    keeps the key), the per-layer state streams, and the trace-affecting
+    env knobs
     (``trace_knob_fingerprint`` carries the CGX_KV_*/CGX_SERVE_* serving
     subset plus the codec-lowering knobs the staged dequantize
     consumes)."""
@@ -437,6 +483,7 @@ def _program_key(server) -> Tuple:
         (server.serve.page_tokens, server.serve.max_batch,
          server.serve.max_pages, server.serve.max_seq),
         _resolved_streams(server),
+        _resolved_state_streams(server),
         cfg_mod.registry_version(),
         cfg_mod.trace_knob_fingerprint(),
     )
@@ -476,6 +523,15 @@ def _decode_program(server) -> SimpleNamespace:
 def _build_programs(server) -> SimpleNamespace:
     streams = _resolved_streams(server)
     names = _stream_names(streams)
+    state_streams = _resolved_state_streams(server)
+    state_names = _stream_names(state_streams)
+    both = sorted(set(names) & set(state_names))
+    if both:
+        raise ValueError(
+            f"adapter {server.kind!r} names {both} both a cache stream and "
+            "a state stream"
+        )
+    holders = {**_holders(streams), **_holders(state_streams)}
     n_layer = server.n_layer
     sv = server.serve
 
@@ -489,6 +545,8 @@ def _build_programs(server) -> SimpleNamespace:
         out = dict(state)
         for name in names:
             out[f"tail_{name}"] = tuple(new_tails[name])
+        for name in state_names:  # rewritten whole, every lane, every step
+            out[f"state_{name}"] = tuple(new_tails[name])
         out["tail_len"] = jnp.where(
             state["active"], state["tail_len"] + 1, state["tail_len"]
         )
@@ -544,14 +602,14 @@ def _build_programs(server) -> SimpleNamespace:
         )
 
     def prefill(params, tokens, positions, last_idx):
-        """Forward alone, every layer's cache payload out by stream: the
-        prefill worker's program (``serving/prefill.py`` ships the pages
-        itself)."""
+        """Forward alone, every layer's cache payload (and state) out by
+        stream: the prefill worker's program (``serving/prefill.py`` ships
+        the pages itself)."""
         srv = server.with_params(params)
         logits, *payloads = srv.prefill_forward(tokens, positions, last_idx)
         return (
             jnp.argmax(logits, axis=-1).astype(jnp.int32),
-            dict(zip(names, payloads)),
+            dict(zip(names + state_names, payloads)),
         )
 
     observe_qerr = cfg_mod.qerr_stats()  # in the program key's fingerprint
@@ -567,7 +625,10 @@ def _build_programs(server) -> SimpleNamespace:
         serves every prompt length under a padded length, whole pages or
         not. Also ``{layer: its leading stream's rows as quantized}`` of the
         quantized layers, empty unless ``CGX_QERR_STATS`` was on when the
-        programs were built."""
+        programs were built. Last, the lane's recurrent state after
+        ``last_idx`` as the adapter's prefill left it, ``{state stream: (its
+        layers, *shape)}``, empty for a model without state streams. Tails
+        and states are stacked over the layers that have the stream."""
         first, payloads = prefill(params, tokens, positions, last_idx)
         n_pages = ids.shape[0]
         live = jax.lax.broadcasted_iota(
@@ -585,20 +646,29 @@ def _build_programs(server) -> SimpleNamespace:
                 tails[name].append(
                     jnp.where(live, x[-sv.page_tokens:], 0.0)
                 )
-                if observe_qerr and spec.quantized and name == names[0]:
+                if (observe_qerr and spec.quantized
+                        and name == streams[layer][0][0]):
                     qerr_rows[layer] = rows
             out.append(written)
+        states = {
+            name: jnp.stack([payloads[name][layer][0]
+                             for layer in holders[name]])
+            for name in state_names
+        }
         return (
             first, tuple(out),
             {name: jnp.stack(t) for name, t in tails.items()}, qerr_rows,
+            states,
         )
 
     def admit_lane(state, lane, table_row, n_pages, tail_len, token, pos,
-                   tails):
+                   tails, states):
         """Write one ready request into lane ``lane`` of the donated
-        state: its page-table row, counts, first token and position, and
-        its stacked tails ``{stream: (L, page_tokens, H, Dh)}``, device or
-        host arrays alike."""
+        state: its page-table row, counts, first token and position, its
+        stacked tails ``{stream: (L, page_tokens, H, Dh)}``, device or
+        host arrays alike, and its recurrent state ``{state stream: (L,
+        *shape)}`` (whatever the lane's last request left there is
+        overwritten whole)."""
         out = dict(state)
         for name, value in (
             ("page_table", table_row), ("n_pages", n_pages),
@@ -606,11 +676,14 @@ def _build_programs(server) -> SimpleNamespace:
             ("active", True),
         ):
             out[name] = state[name].at[lane].set(value)
-        for name in names:
-            out[f"tail_{name}"] = tuple(
-                t.at[lane].set(tails[name][layer])
-                for layer, t in enumerate(state[f"tail_{name}"])
-            )
+        for prefix, which, written in (("tail", names, tails),
+                                       ("state", state_names, states)):
+            for name in which:
+                out[f"{prefix}_{name}"] = tuple(
+                    None if t is None
+                    else t.at[lane].set(written[name][holders[name][layer]])
+                    for layer, t in enumerate(state[f"{prefix}_{name}"])
+                )
         return out
 
     def release_lanes(lanes, mask):
@@ -627,6 +700,8 @@ def _build_programs(server) -> SimpleNamespace:
     return SimpleNamespace(
         streams=streams,
         names=names,
+        state_streams=state_streams,
+        state_names=state_names,
         specs=_leading_specs(streams),
         decode_step=jax.jit(decode_step, donate_argnums=(1,)),
         commit=jax.jit(commit, donate_argnums=(0,)),
@@ -673,6 +748,10 @@ class _Ready:
     tail_len: int
     first_token: int
     pos: int
+    # {state stream: (its layers, *shape)}: the lane's recurrent state after
+    # the prompt's last token, left on the device by the local prefill;
+    # empty for a model without state streams.
+    states: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
     # End of the prefill (or ingest) that built it, on ``submitted_at``'s
     # clock: ``cgx.serve.ready_wait_s`` counts from here to the lane write.
     ready_at: float = dataclasses.field(default_factory=time.monotonic)
@@ -711,6 +790,7 @@ class ContinuousBatchScheduler:
         self._cache_gen = self.cache.generation
         self._prog = _decode_program(server)
         self._prog_key = _program_key(server)
+        self._state_bytes = 0  # the recurrent state held (memledger owner)
         self._state = self._fresh_state()
         self._lanes: List[Optional[Request]] = [None] * sv.max_batch
         self._waiting: List[Request] = []  # local-prefill queue
@@ -738,19 +818,45 @@ class ContinuousBatchScheduler:
             }
             for layer in streams
         )
+        # A layer without the stream holds None in the stream's tuple, so
+        # that every per-layer entry is found at its layer's index.
         tails = {
             f"tail_{name}": tuple(
-                jnp.zeros(
+                None if spec is None else jnp.zeros(
                     (b, spec.page_tokens, spec.n_head, spec.d_head),
                     jnp.float32,
                 )
-                for spec in (dict(layer)[name] for layer in streams)
+                for spec in (dict(layer).get(name) for layer in streams)
             )
             for name in self._prog.names
         }
+        # The recurrent state, one row a lane: zeros until an admission
+        # writes the lane (a free lane's rows go through every decode step
+        # like any other's and reach no other lane).
+        states = {
+            f"state_{name}": tuple(
+                None if row is None else jnp.zeros((b,) + row[0], row[1])
+                for row in (dict(layer).get(name)
+                            for layer in self._prog.state_streams)
+            )
+            for name in self._prog.state_names
+        }
+        state_bytes = sum(
+            t.nbytes for per_layer in states.values() for t in per_layer
+            if t is not None
+        )
+        metrics.set("cgx.serve.state.bytes", float(state_bytes))
+        if state_bytes:
+            if self._state_bytes:  # a rebuild drops the old state's arrays
+                memledger.note_release(
+                    "serve.state", n=b, nbytes=self._state_bytes
+                )
+            memledger.note_alloc("serve.state", n=b, nbytes=state_bytes)
+        self._state_bytes = state_bytes
         return {
             "pools": pools,
             **tails,
+            **states,
             "page_table": jnp.full(
                 (b, sv.pages_per_seq), -1, jnp.int32
             ),
@@ -1112,7 +1218,7 @@ class ContinuousBatchScheduler:
                 ids = np.full((padded.shape[0] // pt,), sv.max_pages,
                               np.int32)
                 ids[:n_full] = pids
-                first, pools, tails, qerr_rows = (
+                first, pools, tails, qerr_rows, states = (
                     self._prog.prefill_pages(
                         self.server.p, self._state["pools"], padded[None],
                         np.arange(padded.shape[0], dtype=np.int32)[None],
@@ -1136,6 +1242,7 @@ class ContinuousBatchScheduler:
         return _Ready(
             req=req, page_ids=pids, tails=tails,
             tail_len=tail_len, first_token=first_token, pos=s,
+            states=states,
         )
 
     # -- admission / eviction ---------------------------------------------
@@ -1196,8 +1303,10 @@ class ContinuousBatchScheduler:
                 self._state, np.int32(lane), table_row,
                 np.int32(len(ready.page_ids)), np.int32(ready.tail_len),
                 np.int32(ready.first_token), np.int32(ready.pos),
-                ready.tails,
+                ready.tails, ready.states,
             )
+            if ready.states:
+                metrics.add("cgx.serve.state.lane_writes")
             self._lanes[lane] = req
             # The prefill's own argmax IS the first generated token — the
             # disaggregated convention: TTFT is admission, not first
@@ -1251,7 +1360,6 @@ class ContinuousBatchScheduler:
             return False
         sv = self.server.serve
         n_layer = self.server.n_layer
-        lead = self._prog.names[0]  # the stream the qerr telemetry watches
         with trace_span(
             "serve.decode.prepare", hist="cgx.serve.decode_prepare_s"
         ):
@@ -1286,7 +1394,10 @@ class ContinuousBatchScheduler:
                     if cfg_mod.qerr_stats():
                         for layer in range(n_layer):
                             spec = self._prog.specs[layer]
-                            if spec.quantized:
+                            if spec is not None and spec.quantized:
+                                # the layer's leading stream is the one
+                                # the qerr telemetry watches
+                                lead = self._prog.streams[layer][0][0]
                                 rows = np.asarray(
                                     st[f"tail_{lead}"][layer]
                                 )[committed].reshape(len(committed), -1)
@@ -1300,8 +1411,8 @@ class ContinuousBatchScheduler:
                     self._note_pages(len(committed))
                     metrics.add(
                         "cgx.serve.pages_committed",
-                        float(len(self._prog.names) * len(committed)
-                              * n_layer),
+                        float(sum(len(layer) for layer in self._prog.streams)
+                              * len(committed)),
                     )
                 active = [i for i, r in enumerate(self._lanes)
                           if r is not None]
@@ -1356,8 +1467,11 @@ class ContinuousBatchScheduler:
 
 def _pad_prompt(prompt: np.ndarray, page_tokens: int) -> np.ndarray:
     """Right-pad a prompt to the next page multiple so distinct lengths
-    share one compiled prefill program (causal attention makes the pad
-    inert for every real position — see ``prefill_forward``)."""
+    share one compiled prefill program. Causal attention makes the pad
+    inert for every real position (see ``prefill_forward``); a recurrent
+    layer's state would swallow it, so an adapter with state streams
+    returns the state at ``last_idx``, not at the padded end
+    (``models/granite_hybrid.mamba_prefill``)."""
     s = prompt.shape[0]
     padded_len = -(-s // page_tokens) * page_tokens
     if padded_len == s:

@@ -63,17 +63,29 @@ STREAM_OF_KIND = {
 
 
 def require_kv_streams(server) -> None:
-    """The disaggregated path ships K and V frames: refuse, up front and in
-    plain words, an adapter whose cache streams are others (a latent
-    cache), instead of half-working."""
-    names = tuple(name for name, _ in server.cache_streams(0))
-    if names != ("k", "v"):
-        raise ValueError(
-            f"the disaggregated prefill path ships K and V page frames "
-            f"(serving/transport.py kinds); adapter {server.kind!r} caches "
-            f"the streams {list(names)} and is served with local prefill "
-            "only"
-        )
+    """The disaggregated path ships K and V frames of every layer: refuse,
+    up front and in plain words, an adapter whose cache streams are others
+    (a latent cache), whose layers do not all leave K and V pages, or that
+    keeps a recurrent state a lane (no frame kind ships one), instead of
+    half-working."""
+    refusal = (
+        "the disaggregated prefill path ships K and V page frames "
+        f"(serving/transport.py kinds); adapter {server.kind!r} "
+    )
+    for layer in range(server.n_layer):
+        names = [name for name, _ in server.cache_streams(layer)]
+        state = [name for name, _, _ in server.state_streams(layer)]
+        if state:
+            raise ValueError(
+                f"{refusal}keeps the recurrent state {state} a lane at "
+                f"layer {layer}, which no frame kind ships, and is served "
+                "with local prefill only"
+            )
+        if names != ["k", "v"]:
+            raise ValueError(
+                f"{refusal}caches the streams {names} at layer {layer} and "
+                "is served with local prefill only"
+            )
 # Elastic-join snapshot pages (robustness/elastic.py — the param_page
 # wire edge): the `layer` field carries the flat LEAF index of the
 # training-state tree, `page_idx` the page within that leaf.
