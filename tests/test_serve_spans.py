@@ -4,7 +4,11 @@ one annotation on the profiler's host plane, one histogram under
 ``cgx.serve.``, one timeline record — plus the two waits of a request and the
 full-collection pauses. An admission is two compiled programs (ISSUE 26), so
 a prefill has two phases: the program's call, and the read of the first token
-that waits for it.
+that waits for it. Since ISSUE 32 the two lie in different phases of the tick
+(the dispatch in ``_admit``, the read after the decode step is queued), so
+``serve.prefill.local``, which spans both, is a histogram and a timeline
+record closed at the read, with no annotation of its own; and the counters of
+what the tick read, queued ahead and dropped are checked here too.
 
 CPU, the tiny model: counts, containment and nesting are what a CPU run can
 say; the times themselves are read on the chip (PERF.md).
@@ -48,15 +52,17 @@ PER_DECODE_STEP = ("decode_prepare_s", "decode_step_s", "decode_emit_s")
 # Span -> the span that holds it (names as the timeline has them; the
 # profiler's trace has them under ``cgx.``).
 PARENT = {
-    "serve.prefill.forward": "serve.prefill.local",
-    "serve.prefill.first_token": "serve.prefill.local",
-    "serve.prefill.local": "serve.step",
+    "serve.prefill.forward": "serve.step",
+    # in serve.decode_step, but for a burst's: read before a third is queued
+    "serve.prefill.first_token": "serve.step",
     "serve.admit_lane": "serve.step",
     "serve.decode.prepare": "serve.step",
     "serve.decode_step": "serve.step",
     "serve.decode.emit": "serve.step",
 }
-REQUEST_SPANS = [n for n in PARENT if "prefill" in n or "admit" in n]
+ANNOTATED_REQUEST_SPANS = [n for n in PARENT
+                           if "prefill" in n or "admit" in n]
+REQUEST_SPANS = ["serve.prefill.local", *ANNOTATED_REQUEST_SPANS]
 
 
 @pytest.fixture(scope="module")
@@ -122,16 +128,18 @@ def test_prefill_phases_sum_within_the_prefill_span(server):
 
 
 def test_waits_and_spans_decompose_ttft(server):
-    """One request: submit -> first token is queue wait, prefill, ready
-    wait, then the lane write up to the first-token stamp."""
+    """One request: submit -> first token is the queue wait, then the
+    prefill span, which runs from the prefill's dispatch to the first token
+    on the host and so holds the ready wait, the lane write and both of its
+    own phases."""
     (req,), _, start, end = _serve(server, lens=(19,))
     ttft = req.first_token_at - req.submitted_at
-    before_lane = sum(
-        _delta(start, end, f"{n}.sum")
-        for n in ("queue_wait_s", "prefill_s", "ready_wait_s")
-    )
-    lane = _delta(start, end, "admit_lane_s.sum")
-    assert before_lane <= ttft <= before_lane + lane + 1e-3
+    total = lambda *names: sum(
+        _delta(start, end, f"{n}.sum") for n in names)
+    whole = total("queue_wait_s", "prefill_s")
+    assert whole <= ttft <= whole + 1e-3
+    assert total("prefill_forward_s", "ready_wait_s", "admit_lane_s",
+                 "prefill_first_token_s") <= total("prefill_s")
 
 
 def test_request_spans_carry_req_in_the_timeline(server, tmp_path,
@@ -205,8 +213,231 @@ def test_profiler_trace_holds_the_spans_nested_on_one_clock(server,
                 for _, pa, pb, _, _ in by_name["cgx." + parent]
             ), f"{child} lies outside every {parent}"
     ids = {r.id for r in reqs}
-    for name in REQUEST_SPANS:
+    for name in ANNOTATED_REQUEST_SPANS:
         assert {e[3]["req"] for e in by_name["cgx." + name]} == ids, name
+    assert "cgx.serve.prefill.local" not in by_name  # it would not nest
+
+
+# ---------------------------------------------------------------------------
+# What a tick reads, queues ahead and drops (ISSUE 32).
+# ---------------------------------------------------------------------------
+
+
+def _requests(server, sizes, tag="c", seed=7):
+    """One request a ``(prompt length, tokens asked)`` of ``sizes``."""
+    rng = np.random.default_rng(seed)
+    return [
+        Request(id=f"{tag}{i}", max_new_tokens=gen,
+                tokens=[int(t) for t in
+                        rng.integers(0, server.cfg.vocab_size, n)])
+        for i, (n, gen) in enumerate(sizes)
+    ]
+
+
+def _tick_until_done(sched, reqs, each_tick=lambda: None):
+    for r in reqs:
+        sched.submit(r)
+    ticks = 0
+    while sched.outstanding():
+        sched.step()
+        each_tick()
+        ticks += 1
+        assert ticks < 1000, "serving run wedged"
+    return ticks
+
+
+def test_a_tick_reads_once_for_its_step_and_once_an_admission(
+        server, monkeypatch):
+    """``cgx.serve.host_reads``: every tick that reads a step makes one
+    read for it and one for each first token, and nothing else on the
+    device is read: not in ``serve.decode.prepare``, whose commit goes by
+    the host's own count of the tails, nor anywhere outside the two reads
+    (counted here: every ``int()`` of a device array, and every
+    ``np.asarray`` of one that the scheduler's module makes)."""
+    from jax._src import array as jax_array
+    from torch_cgx_tpu.serving import scheduler as sched_mod
+
+    value = jax_array.ArrayImpl._value
+    copies = []
+
+    def counted_value(self):
+        if self._npy_value is None:
+            copies.append(1)
+        return value.fget(self)
+
+    class CountingNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(x, *args, **kwargs):
+            if isinstance(x, jax.Array):
+                copies.append(1)
+            return np.asarray(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value",
+                        property(counted_value))
+    monkeypatch.setattr(sched_mod, "np", CountingNumpy())
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    in_prepare = []
+    commit = sched._commit_full_tails
+
+    def prepare():
+        before = len(copies)
+        commit()
+        in_prepare.append(len(copies) - before)
+
+    sched._commit_full_tails = prepare
+    seen = {"reads": 0.0, "steps": 0.0, "admitted": 0.0}
+
+    def each_tick():
+        now = {"reads": metrics.get("cgx.serve.host_reads"),
+               "steps": metrics.get("cgx.serve.decode_steps"),
+               "admitted": metrics.get("cgx.serve.requests_admitted")}
+        grew = {k: now[k] - seen[k] for k in now}
+        assert grew["steps"] in (0.0, 1.0)
+        assert grew["reads"] == grew["steps"] + grew["admitted"]
+        seen.update(now)
+
+    # six requests on four lanes, answers long enough that tails fill
+    reqs = _requests(server, [(5, 12), (19, 20), (24, 9), (7, 14), (11, 3),
+                              (13, 10)])
+    _tick_until_done(sched, reqs, each_tick)
+    assert metrics.get("cgx.serve.pages_committed") > 0
+    assert in_prepare and not any(in_prepare)
+    assert len(copies) == seen["reads"] == seen["steps"] + len(reqs)
+
+
+def test_no_step_is_queued_ahead_while_a_lane_finishes_every_tick(server):
+    """Every answer is two tokens: the first at admission, the second at
+    the one step its lane decodes. Each step is some lane's last, so each
+    is read before anything else is queued and the next request's prefill
+    goes in front of the next step."""
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    reqs = _requests(server, [(5 + i, 2) for i in range(9)])
+    _tick_until_done(sched, reqs)
+    assert all(len(r.output) == 2 for r in reqs)
+    assert metrics.get("cgx.serve.decode_steps") > 0
+    assert metrics.get("cgx.serve.decode.ahead") == 0.0
+
+
+def test_most_steps_are_queued_ahead_while_every_lane_is_busy(server):
+    """Four long answers on four lanes: until the first of them is one
+    step from its last token, nothing could be admitted whatever arrived,
+    and every step but the first is dispatched before the one in front of
+    it is read."""
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    reqs = _requests(server, [(5, 40), (9, 44), (12, 48), (7, 50)])
+    _tick_until_done(sched, reqs)
+    steps = metrics.get("cgx.serve.decode_steps")
+    ahead = metrics.get("cgx.serve.decode.ahead")
+    assert ahead == 38.0  # steps 2..39: the 39th is the first answer's last
+    assert steps == 49.0 and ahead > steps / 2
+    assert metrics.get("cgx.serve.decode.discarded_tokens") == 0.0
+
+
+def test_discarded_tokens_are_the_eos_finishes_under_a_queued_step(server):
+    """Two long answers on two lanes, and an end-of-sequence token that
+    the first of them alone produces, in mid-answer: it is found at the
+    read of a step behind which the next was already queued, so that step
+    decodes one token for the lane, which is dropped and counted; from
+    then on a lane is free and nothing runs ahead. Without ``eos_token``
+    nothing is ever dropped."""
+    import dataclasses
+
+    two = dataclasses.replace(server.serve, max_batch=2)
+    sizes = [(5, 30), (9, 34)]
+
+    def served(eos):
+        metrics.reset()
+        adapter = GPT2Server(server.cfg, {"params": server.p},
+                             dataclasses.replace(two, eos_token=eos))
+        reqs = _requests(server, sizes, seed=9)
+        _tick_until_done(ContinuousBatchScheduler(adapter), reqs)
+        return ([r.output for r in reqs],
+                metrics.get("cgx.serve.decode.discarded_tokens"),
+                metrics.get("cgx.serve.tokens_generated"))
+
+    (first, second), dropped, _ = served(None)
+    assert dropped == 0.0
+    at, eos = next((i, t) for i, t in enumerate(first)
+                   if 2 <= i < len(first) - 2 and t not in second
+                   and t not in first[:i])
+    outputs, dropped, generated = served(eos)
+    assert outputs == [first[: at + 1], second]
+    assert dropped == 1.0
+    assert generated == at + 1 + len(second)  # the dropped one is no token
+
+
+def test_first_token_and_its_stamp_are_written_at_the_read(server):
+    """``req.output[0]`` and ``first_token_at`` appear together, at the
+    read of the first token and not at the dispatch of the lane write; a
+    first token is stamped when the host holds it. ``cgx.serve.prefill_s``
+    runs from the prefill's dispatch to that read, whatever lies between."""
+    import time
+
+    _serve(server)  # compile outside the timed stretch
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    reqs = _requests(server, [(5, GEN), (19, GEN)])
+    for r in reqs:
+        sched.submit(r)
+    sched._admit()
+    assert all(r in sched._lanes for r in reqs)
+    assert all(r.output == [] and r.first_token_at is None for r in reqs)
+    found = metrics.snapshot("cgx.serve.")
+    assert "cgx.serve.ttft_ms.count" not in found
+    assert "cgx.serve.prefill_s.count" not in found
+    time.sleep(0.05)
+    before = time.monotonic()
+    sched._read_first_tokens()
+    assert all(len(r.output) == 1 and r.first_token_at >= before
+               for r in reqs)
+    found = metrics.snapshot("cgx.serve.")
+    assert found["cgx.serve.ttft_ms.count"] == 2.0
+    assert found["cgx.serve.prefill_s.count"] == 2.0
+    assert found["cgx.serve.prefill_s.sum"] >= 2 * 0.05
+    dispatch_and_read = (found["cgx.serve.prefill_forward_s.sum"]
+                         + found["cgx.serve.prefill_first_token_s.sum"])
+    assert dispatch_and_read < 0.05
+    while sched.outstanding():
+        sched.step()
+        assert all((r.first_token_at is None) == (r.output == [])
+                   for r in reqs)
+
+
+def test_a_first_token_whose_read_raises_fails_its_request_alone(server):
+    """The failed-prefill contract at the later read: the request errors
+    alone, its pages are freed and its lane released, and the lane beside
+    it, decoding under the same steps, gets the tokens it would have."""
+    (want,) = [r.output for r in _serve(server, lens=(19,), gen=8)[0]]
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+
+    class Unreadable:
+        def __int__(self):
+            raise RuntimeError("the device lost it")
+
+    rng = np.random.default_rng(1)  # _serve's first prompt again
+    good = Request(id="good", max_new_tokens=8, tokens=[
+        int(t) for t in rng.integers(0, server.cfg.vocab_size, 19)])
+    (bad,) = _requests(server, [(11, 6)], tag="bad")
+    sched.submit(good)
+    sched.submit(bad)
+    sched._admit()
+    _, ready = sched._unread[1]
+    assert ready.req is bad
+    ready.first_token = Unreadable()  # the lane write has its operand
+    assert sched.run(deadline_s=300.0)
+    assert bad.done and bad.output == [] and bad.first_token_at is None
+    assert good.done and good.output == want
+    assert metrics.get("cgx.serve.request_errors") == 1.0
+    assert metrics.get("span.serve.prefill.local.errors") == 1.0
+    assert sched.cache.free_pages == server.serve.max_pages
+    assert sched._lanes == [None] * server.serve.max_batch
 
 
 # ---------------------------------------------------------------------------

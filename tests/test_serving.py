@@ -948,15 +948,19 @@ def test_prefill_ahead_bounded_by_free_lanes(model_setup):
 
 
 def _admit_only(sched, req, *, remote=False):
-    """Admit ``req`` without decoding: transport drain + ``_admit`` until
-    the request holds a lane. Returns the lane."""
+    """Admit ``req`` without decoding: transport drain + ``_admit`` (the
+    dispatch) until the request holds a lane, then the read of its first
+    token. Returns the lane."""
     sched.submit(req, remote=remote)
     deadline = time.monotonic() + DEADLINE_S
     while req not in sched._lanes:
         assert time.monotonic() < deadline, "admission wedged"
         sched._drain_transport()
         sched._admit()
-    return sched._lanes.index(req)
+    lane = sched._lanes.index(req)
+    assert req.output == [] and req.first_token_at is None  # dispatched only
+    sched._read_first_tokens()
+    return lane
 
 
 def _lane_snapshot(sched, lane):
@@ -1494,6 +1498,183 @@ def test_a_prefilled_request_takes_its_lane_before_the_next_prefill(
         sched.submit(r)
     sched._admit()
     assert order == ["_local_prefill", "_admit_lane"] * 3
+    assert all(r in sched._lanes for r in reqs)
+    # Dispatched back to back (ISSUE 32), two in flight at most: the
+    # newest two have no token and no stamp yet, the first was read when
+    # the third was about to be queued; the read goes in the same order.
+    assert [len(r.output) for r in reqs] == [1, 0, 0]
+    assert [r.first_token_at is None for r in reqs] == [False, True, True]
+    sched._read_first_tokens()
     stamps = [r.first_token_at for r in reqs]
-    assert stamps == sorted(stamps) and all(r in sched._lanes for r in reqs)
+    assert stamps == sorted(stamps) and all(len(r.output) == 1 for r in reqs)
     assert sched.run(deadline_s=DEADLINE_S)
+
+
+def test_a_burst_of_admissions_holds_two_prefills_outputs_at_most(
+        model_setup):
+    """With every lane free at once a tick admits a request a lane. Each
+    prefill queued holds its outputs on the device from its dispatch (a
+    lane's tails and recurrent state: 77 MB for granite-4.0-h-micro, and
+    64 of them overran the chip; PERF.md section 6, PR 32), so the burst
+    reads the oldest first token before it queues a third."""
+    cfg, _model, params = model_setup
+    sched = ContinuousBatchScheduler(
+        GPT2Server(cfg, params, _serve_cfg(max_batch=6)))
+    inner, queued = sched._local_prefill, []
+
+    def noted(req):
+        queued.append(len(sched._unread))
+        return inner(req)
+
+    sched._local_prefill = noted
+    reqs = [Request(id=f"r{i}", tokens=list(p), max_new_tokens=3)
+            for i, p in enumerate(_prompts(cfg, 6, lens=[9 + i for i in
+                                                           range(6)]))]
+    for r in reqs:
+        sched.submit(r)
+    sched._admit()
+    assert queued == [0, 1, 1, 1, 1, 1]  # the one before it still runs
+    assert [len(r.output) for r in reqs] == [1, 1, 1, 1, 0, 0]
+    assert sched.run(deadline_s=DEADLINE_S)
+    assert all(len(r.output) == 3 for r in reqs)
+
+
+# ---------------------------------------------------------------------------
+# A tick queues everything before it reads anything (ISSUE 32): whatever
+# the tick dispatched ahead of its reads, every request gets the tokens of a
+# plain decode.
+# ---------------------------------------------------------------------------
+
+# (prompt length, tokens asked), each in pages and odd tokens: two pairs
+# that are admitted together and ask for the same count, so they finish in
+# the same tick; long answers that fill tail after tail until the pool runs
+# dry; one that asks for its first token alone.
+_MIX = [((2, 1), (3, 6)), ((1, 3), (3, 6)), ((2, 1), (3, 6)),
+        ((1, 5), (1, 1)), ((1, 5), (1, 1)), ((0, 5), (0, 1)),
+        ((1, 2), (1, 4))]
+_MIX_LANES = 3
+_MIX_PAGES = 11  # three lanes of the long answers would hold fifteen
+
+
+def _mix_adapter(kind, model_setup):
+    """(build(eos) -> adapter at its small CPU configuration, its page
+    size, its vocabulary)."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+    def serve(page, eos):
+        return ServeConfig(page_tokens=page, max_batch=_MIX_LANES,
+                           max_pages=_MIX_PAGES, max_seq=8 * page,
+                           ship_depth=2, eos_token=eos)
+
+    if kind == "gpt2":
+        cfg, _model, params = model_setup
+        return (lambda eos: GPT2Server(cfg, params, serve(PAGE, eos)),
+                PAGE, cfg.vocab_size)
+    if kind == "latent":
+        from benchmark import weights_mla_moe
+        from test_latent_serving import HF
+        from torch_cgx_tpu.models.mla_moe import MlaMoeConfig
+        from torch_cgx_tpu.serving.latent import LatentMoEServer
+
+        cfg = MlaMoeConfig.from_hf(HF, dtype=jnp.float32, q_block=8)
+        params = weights_mla_moe.make_params(HF, 3)
+        return (lambda eos: LatentMoEServer(cfg, params, serve(PAGE, eos)),
+                PAGE, HF["vocab_size"])
+    from benchmark import weights_granite_hybrid
+    from test_hybrid_serving import HF, PAGE as chunk
+    from torch_cgx_tpu.models.granite_hybrid import HybridConfig
+    from torch_cgx_tpu.serving.hybrid import HybridSSMServer
+
+    cfg = HybridConfig.from_hf(HF, dtype=jnp.float32)
+    params = weights_granite_hybrid.make_params(HF, 3)
+    return (lambda eos: HybridSSMServer(cfg, params, serve(chunk, eos)),
+            chunk, HF["vocab_size"])
+
+
+def _mix_requests(page, vocab, tag):
+    rng = np.random.default_rng(32)
+    size = lambda pages, odd: pages * page + odd
+    return [
+        Request(id=f"{tag}{i}", max_new_tokens=size(*gen),
+                tokens=[int(t) for t in rng.integers(0, vocab, size(*prompt))])
+        for i, (prompt, gen) in enumerate(_MIX)
+    ]
+
+
+@pytest.mark.parametrize("with_eos", [False, True], ids=["no-eos", "eos"])
+@pytest.mark.parametrize("kind", ["gpt2", "latent", "hybrid"])
+def test_mixed_ticks_serve_the_tokens_of_a_plain_decode(
+        model_setup, monkeypatch, kind, with_eos):
+    """A seeded mix in which lanes finish in the same tick, tails fill,
+    the pool runs dry mid-decode (eviction and re-prefill) and one prefill
+    raises, with and without an end-of-sequence token: every request's
+    tokens are those of a greedy decode of that request alone (a lane free
+    beside it, so no step of it is ever queued ahead), the failed request
+    errors alone with its pages freed, and ``run()`` leaves no step in
+    flight."""
+    from types import SimpleNamespace
+
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    build, page, vocab = _mix_adapter(kind, model_setup)
+
+    plain = []
+    for req in _mix_requests(page, vocab, "p"):
+        alone = ContinuousBatchScheduler(build(None))
+        alone.submit(req)
+        assert alone.run(deadline_s=DEADLINE_S)
+        plain.append(req.output)
+    asked = [r.max_new_tokens for r in _mix_requests(page, vocab, "p")]
+    assert [len(o) for o in plain] == asked
+    eos = None
+    if with_eos:
+        # a token that ends some answers early and leaves others whole
+        inner = [t for o in plain for t in o[1:-1]]
+        eos = max(sorted(set(inner)), key=inner.count)
+        plain = [o[: o.index(eos) + 1] if eos in o else o for o in plain]
+        assert any(len(o) < n for o, n in zip(plain, asked))
+
+    metrics.reset()
+    sched = ContinuousBatchScheduler(build(eos))
+    reqs = _mix_requests(page, vocab, "m")
+    bad = Request(id="bad", tokens=reqs[3].tokens[: page + 1],
+                  max_new_tokens=4)
+    prog = sched._prog
+
+    def prefill_pages(params, pools, tokens, positions, last_idx, *rest):
+        if int(last_idx) == len(bad.tokens) - 1:  # no other prompt's length
+            raise RuntimeError("refused before anything was donated")
+        return prog.prefill_pages(params, pools, tokens, positions,
+                                  last_idx, *rest)
+
+    sched._prog = SimpleNamespace(
+        **{**vars(prog), "prefill_pages": prefill_pages})
+    for r in reqs[:4] + [bad] + reqs[4:]:
+        sched.submit(r)
+    together, done, ticks = 0, set(), 0
+    while sched.outstanding():
+        sched.step()
+        ticks += 1
+        assert ticks < 2000, "serving run wedged"
+        now = {r.id for r in reqs if r.done}
+        together = max(together, len(now - done))
+        done = now
+    assert not sched._steps and not sched._unread
+    assert [r.output for r in reqs] == plain
+    assert bad.done and bad.output == [] and bad.first_token_at is None
+    assert metrics.get("cgx.serve.request_errors") == 1.0
+    assert metrics.get("cgx.serve.requests_completed") == len(reqs)
+    assert sched.cache.free_pages == _MIX_PAGES
+    assert together >= 2  # lanes finished in the same tick
+    assert metrics.get("cgx.serve.pages_committed") > 0  # tails filled
+    assert metrics.get("cgx.serve.decode.ahead") > 0  # steps queued ahead
+    assert (metrics.get("cgx.serve.host_reads")
+            == metrics.get("cgx.serve.decode_steps")
+            + metrics.get("cgx.serve.requests_admitted"))
+    if eos is None:
+        assert metrics.get("cgx.serve.decode_evictions") > 0  # the pool ran dry
+        assert metrics.get("cgx.serve.decode.discarded_tokens") == 0.0
+    # run() on an empty scheduler has nothing to drain
+    assert sched.run(deadline_s=1.0) and not sched._steps

@@ -25,9 +25,26 @@ around that program (continuous batching): completed lanes free their
 pages back to the refcounted pool and a waiting request takes the lane
 on the next step — the batch never drains to refill. An admission is two
 more compiled programs over the same donated state: ``prefill_pages``
-(forward, the prompt's pages into the pools, its tails left on the
-device; the one wait is the read of the first token) and ``admit_lane``
-(one lane written in place).
+(forward, the prompt's pages into the pools, its tails and its first token
+left on the device) and ``admit_lane`` (one lane written in place, the
+first token an operand it takes from the device).
+
+A tick queues everything before it reads anything. The programs are
+ordered on the device by the state they donate to one another, so the
+host dispatches prefill, lane write, the next prefill, its lane write,
+the commit of full tails (decided from tail lengths the host counts
+itself: set at the lane write, plus one a step, zero at a commit) and the
+decode step, and only then reads: the first tokens in admission order,
+then the step's tokens. A first token is stamped, and can finish its
+request, when the host holds it. When no lane is free and none produces
+its last token at the step just dispatched, nothing could be admitted
+before the next step whatever arrives, so that step is dispatched too,
+before the read: emit, the caller's work between ticks and the next
+dispatch then run under a step, not between two
+(:meth:`ContinuousBatchScheduler._runs_ahead`; never more than one step
+beyond the one being read). With ``eos_token`` set a lane can finish
+unannounced under a step so queued: the token that step decodes for it is
+dropped (``cgx.serve.decode.discarded_tokens``).
 
 Requests arrive with their KV either computed here (local prefill — the
 colocated mode, also the FAILOVER path) or shipped by a disaggregated
@@ -55,7 +72,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from types import SimpleNamespace
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -70,7 +87,7 @@ from ..ops import codec_host
 from ..ops import paged_kv
 from ..observability import memledger, timeline
 from ..utils.logging import get_logger, metrics
-from ..utils.tracing import install_gc_hook, trace_span
+from ..utils.tracing import install_gc_hook, observe_span, trace_span
 from ..wire import dispatch as wire_dispatch
 from . import kv_cache as kv_mod
 from . import transport as tp
@@ -78,6 +95,11 @@ from . import transport as tp
 log = get_logger()
 
 _TPS_EWMA = 0.2  # tokens/s gauge smoothing
+# Admissions dispatched and not read that one more may be queued behind:
+# one on the device and one waiting for it keep the device busy through a
+# burst, and each one queued holds its prefill's outputs (a lane's tails and
+# recurrent state) on the device from its dispatch, whenever it runs.
+_ADMISSIONS_IN_FLIGHT = 2
 # The per-lane bookkeeping of the decode state, and what ``release_lanes``
 # resets each entry of a finished or evicted lane to.
 _LANE_RESET = {"active": False, "n_pages": 0, "tail_len": 0, "page_table": -1}
@@ -623,7 +645,8 @@ def _build_programs(server) -> SimpleNamespace:
         page_tokens, H, Dh) f32}``, zero from ``tail_len`` on. A last page
         that is a tail has the scratch row for its id, so one program
         serves every prompt length under a padded length, whole pages or
-        not. Also ``{layer: its leading stream's rows as quantized}`` of the
+        not. The first token is a scalar, ``admit_lane``'s operand as it
+        is. Also ``{layer: its leading stream's rows as quantized}`` of the
         quantized layers, empty unless ``CGX_QERR_STATS`` was on when the
         programs were built. Last, the lane's recurrent state after
         ``last_idx`` as the adapter's prefill left it, ``{state stream: (its
@@ -656,7 +679,7 @@ def _build_programs(server) -> SimpleNamespace:
             for name in state_names
         }
         return (
-            first, tuple(out),
+            first[0], tuple(out),
             {name: jnp.stack(t) for name, t in tails.items()}, qerr_rows,
             states,
         )
@@ -664,9 +687,10 @@ def _build_programs(server) -> SimpleNamespace:
     def admit_lane(state, lane, table_row, n_pages, tail_len, token, pos,
                    tails, states):
         """Write one ready request into lane ``lane`` of the donated
-        state: its page-table row, counts, first token and position, its
-        stacked tails ``{stream: (L, page_tokens, H, Dh)}``, device or
-        host arrays alike, and its recurrent state ``{state stream: (L,
+        state: its page-table row, counts, first token (a scalar still on
+        the device from the local prefill, or a host one from a page
+        stream) and position, its stacked tails ``{stream: (L, page_tokens,
+        H, Dh)}``, device or host arrays alike, and its recurrent state ``{state stream: (L,
         *shape)}`` (whatever the lane's last request left there is
         overwritten whole)."""
         out = dict(state)
@@ -746,7 +770,9 @@ class _Ready:
     # stream — the ``admit_lane`` program takes either.
     tails: Dict[str, Union[jax.Array, np.ndarray]]
     tail_len: int
-    first_token: int
+    # A scalar still on the device from the local prefill (nothing waits
+    # for it until the tick's read phase), an int from a page stream.
+    first_token: Union[int, jax.Array]
     pos: int
     # {state stream: (its layers, *shape)}: the lane's recurrent state after
     # the prompt's last token, left on the device by the local prefill;
@@ -755,6 +781,23 @@ class _Ready:
     # End of the prefill (or ingest) that built it, on ``submitted_at``'s
     # clock: ``cgx.serve.ready_wait_s`` counts from here to the lane write.
     ready_at: float = dataclasses.field(default_factory=time.monotonic)
+    # A local prefill's open ``serve.prefill.local`` span, ``(start, its
+    # fields)``: closed at the read of the first token.
+    span: Optional[Tuple[float, Dict]] = None
+    # ``CGX_QERR_STATS``: the rows ``prefill_pages`` quantized, by layer,
+    # on the device until that read.
+    qerr_rows: Dict[int, jax.Array] = dataclasses.field(default_factory=dict)
+
+
+@dataclasses.dataclass
+class _Step:
+    """A decode step dispatched and not read yet: its tokens (then the
+    adapter's counters) on the device, and the request each lane's token
+    belongs to. A lane is struck out when its request leaves it under the
+    step."""
+
+    tokens: jax.Array
+    lanes: Dict[int, Request]
 
 
 class ContinuousBatchScheduler:
@@ -799,6 +842,18 @@ class ContinuousBatchScheduler:
         self._frames: Dict[str, List[tp.PageFrame]] = {}
         self._done: List[Request] = []
         self._released: List[int] = []  # lanes awaiting _release_lanes
+        # What the host counts for itself, a lane each: the state's
+        # ``tail_len`` (written with the lane, plus one a dispatched step
+        # while the lane is held, zero at a commit and at a release), and
+        # the decode steps the lane's request may still be dispatched (0
+        # for a free lane).
+        self._tail_len = np.zeros((sv.max_batch,), np.int64)
+        self._left = np.zeros((sv.max_batch,), np.int64)
+        # Dispatched and unread, in device order: lane writes whose first
+        # token nobody has read, and decode steps (two at most, the second
+        # only by :meth:`_runs_ahead`).
+        self._unread: List[Tuple[int, _Ready]] = []
+        self._steps: "deque[_Step]" = deque()
         self._rekey_pending = False
         self._tokens_total = 0
         self._last_step_t: Optional[float] = None
@@ -937,6 +992,11 @@ class ContinuousBatchScheduler:
             requeued += 1
         self._state = self._fresh_state()
         self._released.clear()
+        # What was in flight computed over the state just dropped.
+        self._unread.clear()
+        self._steps.clear()
+        self._tail_len[:] = 0
+        self._left[:] = 0
         if requeued:
             log.info(
                 "serving scheduler reset (%s): %d request(s) requeued "
@@ -974,6 +1034,7 @@ class ContinuousBatchScheduler:
             + len(self._remote)
             + len(self._ready)
             + sum(1 for r in self._lanes if r is not None)
+            + len(self._steps)  # a step in flight is read before the end
         )
 
     @property
@@ -984,9 +1045,11 @@ class ContinuousBatchScheduler:
 
     def step(self) -> bool:
         """One scheduler tick: drain transport, fail over stalled
-        streams, admit, commit full tails, decode one token for every
-        active lane, evict completed lanes. Returns whether anything
-        progressed (the run loop's idle-sleep signal). NEVER blocks."""
+        streams, dispatch the admissions, the commit of full tails and a
+        decode step for every active lane, then read what they produced
+        (first tokens, the step's tokens) and evict completed lanes.
+        Returns whether anything progressed (the run loop's idle-sleep
+        signal). Waits for nothing but the device's own programs."""
         with trace_span("serve.step"):
             self._gc_pauses.publish()
             self._maybe_rebuild()
@@ -1193,22 +1256,21 @@ class ContinuousBatchScheduler:
     def _local_prefill_compute(
         self, req: Request, prompt: np.ndarray, pids: List[int]
     ) -> _Ready:
-        """The prefill of one request whose full pages ``pids`` are
+        """The dispatch of one request's prefill, its full pages ``pids``
         reserved: one call of the ``prefill_pages`` program, which leaves
-        the pages in the pools and the tails on the device, then the one
-        wait of an admission, the read of the first token, whose span
-        holds the device time of the whole prefill."""
+        the pages in the pools and the tails and the first token on the
+        device. Nothing is read here: the ``serve.prefill.local`` span
+        opened now is closed by :meth:`_read_first_tokens`."""
         sv = self.server.serve
         pt = sv.page_tokens
-        specs = self._prog.specs
         s, n_full = prompt.shape[0], len(pids)
         tail_len = s - n_full * pt
+        start = time.perf_counter()
         queue_wait = time.monotonic() - req.submitted_at
         metrics.observe("cgx.serve.queue_wait_s", queue_wait)
-        with trace_span(
-            "serve.prefill.local", hist="cgx.serve.prefill_s", req=req.id,
-            prompt_tokens=int(s), queue_wait_ms=round(queue_wait * 1e3, 3),
-        ):
+        fields = dict(req=req.id, prompt_tokens=int(s),
+                      queue_wait_ms=round(queue_wait * 1e3, 3))
+        try:
             with trace_span(
                 "serve.prefill.forward",
                 hist="cgx.serve.prefill_forward_s", req=req.id,
@@ -1226,24 +1288,24 @@ class ContinuousBatchScheduler:
                     )
                 )
                 self._state["pools"] = pools
-            # Host work the device's prefill hides.
-            self._note_pages(n_full)
-            with trace_span(
-                "serve.prefill.first_token",
-                hist="cgx.serve.prefill_first_token_s", req=req.id,
-            ):
-                first_token = int(first[0])
-            for layer, rows in qerr_rows.items():
-                _observe_page_qerr(
-                    self.server.layer_name(layer), specs[layer],
-                    np.asarray(rows)[:n_full], already_host=True,
-                )
+        except BaseException:
+            self._close_prefill_span((start, fields), ok=False)
+            raise
+        self._note_pages(n_full)
         metrics.add("cgx.serve.local_prefills")
         return _Ready(
             req=req, page_ids=pids, tails=tails,
-            tail_len=tail_len, first_token=first_token, pos=s,
-            states=states,
+            tail_len=tail_len, first_token=first, pos=s,
+            states=states, span=(start, fields), qerr_rows=qerr_rows,
         )
+
+    @staticmethod
+    def _close_prefill_span(span: Optional[Tuple[float, Dict]],
+                            ok: bool) -> None:
+        if span is not None:  # a page stream's request has none
+            start, fields = span
+            observe_span("serve.prefill.local", start,
+                         hist="cgx.serve.prefill_s", ok=ok, **fields)
 
     # -- admission / eviction ---------------------------------------------
 
@@ -1251,6 +1313,9 @@ class ContinuousBatchScheduler:
         return [i for i, r in enumerate(self._lanes) if r is None]
 
     def _admit(self) -> bool:
+        """The dispatch phase of the tick's admissions; their first
+        tokens are read after the decode step is queued
+        (:meth:`_read_first_tokens`)."""
         if self._rekey_pending:
             return False  # draining toward a program re-key: no admits
         progressed = False
@@ -1267,28 +1332,37 @@ class ContinuousBatchScheduler:
             # take the result this step: one free lane must not trigger a
             # whole-queue prefill burst (which would hold pool pages for
             # requests that cannot run yet and inflate every TTFT behind
-            # the synchronous forwards).
+            # the forwards queued in front of theirs).
             if not (self._waiting and free):
                 break
+            # A burst reads as it goes: with every lane free at once, the
+            # prefills queued would else hold a lane's state each.
+            self._read_first_tokens(keep=_ADMISSIONS_IN_FLIGHT - 1)
             req = self._waiting.pop(0)
             try:
                 ready = self._local_prefill(req)
             except Exception as e:
-                metrics.add("cgx.serve.request_errors")
-                log.warning("serving: request %s failed prefill: %s",
-                            req.id, e)
-                req.done = True
-                self._done.append(req)
+                self._fail(req, e)
                 progressed = True
                 continue
             if ready is None:
                 self._waiting.insert(0, req)  # pool pressure
                 break
             self._ready.append(ready)
-        self._release_lanes()  # a first token can finish its request
         return progressed
 
+    def _fail(self, req: Request, error: Exception) -> None:
+        """A request whose prefill raised, at its dispatch or at the read
+        of its first token, errors alone; the caller has freed its pages."""
+        metrics.add("cgx.serve.request_errors")
+        log.warning("serving: request %s failed prefill: %s", req.id, error)
+        req.done = True
+        self._done.append(req)
+
     def _admit_lane(self, lane: int, ready: _Ready) -> None:
+        """Dispatch the write of one ready request into a free lane. The
+        first token goes in as it is, from the device or from the host;
+        the request has it, and its TTFT, at :meth:`_read_first_tokens`."""
         sv = self.server.serve
         req = ready.req
         ready_wait = time.monotonic() - ready.ready_at
@@ -1299,46 +1373,104 @@ class ContinuousBatchScheduler:
         ):
             table_row = np.full((sv.pages_per_seq,), -1, np.int32)
             table_row[: len(ready.page_ids)] = ready.page_ids
+            first = ready.first_token
             self._state = self._prog.admit_lane(
                 self._state, np.int32(lane), table_row,
                 np.int32(len(ready.page_ids)), np.int32(ready.tail_len),
-                np.int32(ready.first_token), np.int32(ready.pos),
-                ready.tails, ready.states,
+                np.int32(first) if isinstance(first, int) else first,
+                np.int32(ready.pos), ready.tails, ready.states,
             )
             if ready.states:
                 metrics.add("cgx.serve.state.lane_writes")
             self._lanes[lane] = req
-            # The prefill's own argmax IS the first generated token — the
-            # disaggregated convention: TTFT is admission, not first
-            # decode.
+            self._tail_len[lane] = ready.tail_len
+            self._left[lane] = max(req.max_new_tokens - 1, 0)
+            self._unread.append((lane, ready))
+            metrics.add("cgx.serve.requests_admitted")
+
+    def _read_first_tokens(self, keep: int = 0) -> bool:
+        """The read phase of the admissions dispatched and not read (all
+        but the newest ``keep``), in their order: each read waits for its
+        own prefill and nothing behind it. The prefill's own argmax IS the
+        first generated token — the disaggregated convention: TTFT is
+        admission, not first decode — and the request has it, its stamp
+        and its TTFT here, when the host does. A first token that is all
+        the request asked for (or the end of sequence) finishes it here."""
+        n = max(len(self._unread) - keep, 0)
+        unread, self._unread = self._unread[:n], self._unread[n:]
+        for lane, ready in unread:
+            req = ready.req
+            first = ready.first_token
+            try:
+                if not isinstance(first, int):  # still on the device
+                    with trace_span(
+                        "serve.prefill.first_token",
+                        hist="cgx.serve.prefill_first_token_s", req=req.id,
+                    ):
+                        first = int(first)
+                    metrics.add("cgx.serve.host_reads")
+                for layer, rows in ready.qerr_rows.items():
+                    _observe_page_qerr(
+                        self.server.layer_name(layer),
+                        self._prog.specs[layer],
+                        np.asarray(rows)[: len(ready.page_ids)],
+                        already_host=True,
+                    )
+            except Exception as e:
+                # The failed-prefill contract at the later read: the
+                # request errors alone, its pages freed, its lane released.
+                self._close_prefill_span(ready.span, ok=False)
+                self.cache.free_seq(req.id)
+                self._vacate(lane)
+                self._fail(req, e)
+                continue
+            self._close_prefill_span(ready.span, ok=True)
             now = time.monotonic()
-            req.output.append(ready.first_token)
             req.first_token_at = now
             ttft_ms = (now - req.submitted_at) * 1e3
             metrics.observe("cgx.serve.ttft_ms", ttft_ms)
-            metrics.add("cgx.serve.requests_admitted")
             timeline.instant(
                 "serve.admit", cat=timeline.CAT_TRACE, req=req.id,
                 lane=int(lane), ttft_ms=round(ttft_ms, 3),
             )
+            self._emit(lane, req, first)
             self._note_tokens(1)
-            if len(req.output) >= req.max_new_tokens or (
-                sv.eos_token is not None
-                and ready.first_token == sv.eos_token
-            ):
-                self._finish_lane(lane)
+        self._release_lanes()  # a first token can finish its request
+        return bool(unread)
+
+    def _emit(self, lane: int, req: Request, token: int) -> None:
+        """Hand a request the token the host has just read for it."""
+        req.output.append(token)
+        if len(req.output) >= req.max_new_tokens or (
+            token == self.server.serve.eos_token
+        ):
+            self._finish_lane(lane)
 
     def _finish_lane(self, lane: int) -> None:
         """The host's half of a finished request; its lane's state is
-        reset by the tick's one :meth:`_release_lanes`."""
+        reset by the tick's one :meth:`_release_lanes`. No step in flight
+        holds a token for a lane that finishes by count (``_left``), so
+        one that does was finished by ``eos_token``, unannounced."""
         req = self._lanes[lane]
         assert req is not None
         self.cache.free_seq(req.id)
         req.done = True
         self._done.append(req)
-        self._lanes[lane] = None
-        self._released.append(lane)
+        late = self._vacate(lane)
+        if late:
+            metrics.add("cgx.serve.decode.discarded_tokens", float(late))
         metrics.add("cgx.serve.requests_completed")
+
+    def _vacate(self, lane: int) -> int:
+        """Take the lane from its request (finished, evicted or failed).
+        Returns the tokens that steps in flight were decoding for it:
+        struck out, nobody reads them."""
+        self._lanes[lane] = None
+        self._left[lane] = 0
+        self._released.append(lane)
+        return sum(
+            step.lanes.pop(lane, None) is not None for step in self._steps
+        )
 
     def _release_lanes(self) -> None:
         """One ``release_lanes`` call for the lanes that finished or were
@@ -1348,6 +1480,7 @@ class ContinuousBatchScheduler:
         mask = np.zeros((self.server.serve.max_batch,), bool)
         mask[self._released] = True
         self._released.clear()
+        self._tail_len[mask] = _LANE_RESET["tail_len"]
         self._state.update(self._prog.release_lanes(
             {name: self._state[name] for name in _LANE_RESET}, mask
         ))
@@ -1355,74 +1488,38 @@ class ContinuousBatchScheduler:
     # -- decode ------------------------------------------------------------
 
     def _decode(self) -> bool:
-        active = [i for i, r in enumerate(self._lanes) if r is not None]
-        if not active:
-            return False
+        """The decode half of the tick: dispatch (unless the last tick
+        queued this one's step ahead) the commit of full tails and the
+        step, dispatch the next step too where :meth:`_runs_ahead`
+        allows, and only then read: the first tokens of the tick's
+        admissions, then the tokens of the oldest step in flight."""
         sv = self.server.serve
-        n_layer = self.server.n_layer
+        fresh = not self._steps
+        if fresh and not self._left.any():
+            # No lane has a token to come from a step: a first token can
+            # be all a request asked for.
+            return self._read_first_tokens()
         with trace_span(
             "serve.decode.prepare", hist="cgx.serve.decode_prepare_s"
         ):
-            st = self._state
-            # Promote full tails first so every lane has tail room.
-            tail_len = np.asarray(st["tail_len"])
-            full = [i for i in active if tail_len[i] >= sv.page_tokens]
-            if full:
-                mask = np.zeros((sv.max_batch,), bool)
-                pids = np.zeros((sv.max_batch,), np.int32)
-                committed = []
-                for lane in full:
-                    req = self._lanes[lane]
-                    pid = self.cache.alloc(req.id)
-                    if pid is None:
-                        # Pool pressure mid-decode: evict this lane back
-                        # to the queue (it re-prefills when pages free
-                        # up) rather than stalling every other lane.
-                        metrics.add("cgx.serve.decode_evictions")
-                        self.cache.free_seq(req.id)
-                        req.output.clear()
-                        req.first_token_at = None
-                        self._waiting.append(req)
-                        self._lanes[lane] = None
-                        self._released.append(lane)
-                        continue
-                    mask[lane] = True
-                    pids[lane] = pid
-                    committed.append(lane)
-                self._release_lanes()
-                if committed:
-                    if cfg_mod.qerr_stats():
-                        for layer in range(n_layer):
-                            spec = self._prog.specs[layer]
-                            if spec is not None and spec.quantized:
-                                # the layer's leading stream is the one
-                                # the qerr telemetry watches
-                                lead = self._prog.streams[layer][0][0]
-                                rows = np.asarray(
-                                    st[f"tail_{lead}"][layer]
-                                )[committed].reshape(len(committed), -1)
-                                _observe_page_qerr(
-                                    self.server.layer_name(layer), spec,
-                                    rows, already_host=True,
-                                )
-                    self._state = self._prog.commit(
-                        self._state, jnp.asarray(mask), jnp.asarray(pids)
-                    )
-                    self._note_pages(len(committed))
-                    metrics.add(
-                        "cgx.serve.pages_committed",
-                        float(sum(len(layer) for layer in self._prog.streams)
-                              * len(committed)),
-                    )
-                active = [i for i, r in enumerate(self._lanes)
-                          if r is not None]
-                if not active:
-                    return True
+            if fresh:
+                self._commit_full_tails()
+        if fresh and not self._left.any():  # the pool evicted them all
+            self._read_first_tokens()
+            return True
         with trace_span("serve.decode_step"):
-            self._state, nxt = self._prog.decode_step(
-                self.server.p, self._state
-            )
-            nxt = np.asarray(nxt)
+            if fresh:
+                self._dispatch_step()
+            if len(self._steps) == 1 and self._runs_ahead():
+                # Under the step just queued, not between two: the next
+                # step's commit, from the host's counts, and its dispatch.
+                self._commit_full_tails()
+                self._dispatch_step()
+                metrics.add("cgx.serve.decode.ahead")
+            self._read_first_tokens()
+            step = self._steps.popleft()
+            nxt = np.asarray(step.tokens)
+            metrics.add("cgx.serve.host_reads")
         with trace_span("serve.decode.emit", hist="cgx.serve.decode_emit_s"):
             metrics.add("cgx.serve.decode_steps")
             # What the adapter counted this step, read with the tokens.
@@ -1430,19 +1527,101 @@ class ContinuousBatchScheduler:
                                    nxt[sv.max_batch:]):
                 metrics.add(f"cgx.serve.{name}", float(count))
             metrics.set(
-                "cgx.serve.batch_occupancy", len(active) / sv.max_batch
+                "cgx.serve.batch_occupancy", len(step.lanes) / sv.max_batch
             )
-            for lane in active:
-                req = self._lanes[lane]
-                token = int(nxt[lane])
-                req.output.append(token)
-                if len(req.output) >= req.max_new_tokens or (
-                    sv.eos_token is not None and token == sv.eos_token
-                ):
-                    self._finish_lane(lane)
+            for lane, req in step.lanes.items():
+                self._emit(lane, req, int(nxt[lane]))
             self._release_lanes()
-            self._note_tokens(len(active))
+            self._note_tokens(len(step.lanes))
         return True
+
+    def _runs_ahead(self) -> bool:
+        """Whether the next step may be dispatched before the last one is
+        read. It may when no lane is free and no lane's request has its
+        last token in the steps dispatched: whatever arrives meanwhile
+        could not be admitted before the next step, so queueing it
+        lengthens no TTFT. (A free lane has no steps left either.) The
+        commit in front of it must find its pages: a lane evicted for want
+        of one is a free lane."""
+        if self._left.min() <= 0:
+            return False
+        full = int((self._tail_len >= self.server.serve.page_tokens).sum())
+        return full <= self.cache.free_pages
+
+    def _dispatch_step(self) -> None:
+        """Queue one decode step over the state as the programs dispatched
+        so far leave it. The step runs every lane the device holds active,
+        which are the lanes held here (whatever vacates a lane releases it
+        before the next dispatch); a token is read for the lanes whose
+        request still has one to come."""
+        self._state, tokens = self._prog.decode_step(
+            self.server.p, self._state
+        )
+        held = [i for i, r in enumerate(self._lanes) if r is not None]
+        self._tail_len[held] += 1
+        lanes = {i: self._lanes[i] for i in held if self._left[i] > 0}
+        self._left[list(lanes)] -= 1
+        self._steps.append(_Step(tokens=tokens, lanes=lanes))
+
+    def _commit_full_tails(self) -> None:
+        """Promote full tails into pool pages, so that every lane has
+        tail room for the step dispatched next: which tails are full is
+        the host's own count, nothing is read from the device. A lane the
+        pool has no page for is evicted back to the queue."""
+        sv = self.server.serve
+        full = [i for i, r in enumerate(self._lanes)
+                if r is not None and self._tail_len[i] >= sv.page_tokens]
+        if not full:
+            return
+        mask = np.zeros((sv.max_batch,), bool)
+        pids = np.zeros((sv.max_batch,), np.int32)
+        committed = []
+        for lane in full:
+            req = self._lanes[lane]
+            pid = self.cache.alloc(req.id)
+            if pid is None:
+                # Pool pressure mid-decode: evict this lane back to the
+                # queue (it re-prefills when pages free up) rather than
+                # stalling every other lane.
+                metrics.add("cgx.serve.decode_evictions")
+                self.cache.free_seq(req.id)
+                req.output.clear()
+                req.first_token_at = None
+                self._waiting.append(req)
+                self._vacate(lane)
+                continue
+            mask[lane] = True
+            pids[lane] = pid
+            committed.append(lane)
+        self._release_lanes()
+        if not committed:
+            return
+        if cfg_mod.qerr_stats():
+            st = self._state
+            for layer in range(self.server.n_layer):
+                spec = self._prog.specs[layer]
+                if spec is not None and spec.quantized:
+                    # the layer's leading stream is the one the qerr
+                    # telemetry watches
+                    lead = self._prog.streams[layer][0][0]
+                    rows = np.asarray(
+                        st[f"tail_{lead}"][layer]
+                    )[committed].reshape(len(committed), -1)
+                    metrics.add("cgx.serve.host_reads")
+                    _observe_page_qerr(
+                        self.server.layer_name(layer), spec,
+                        rows, already_host=True,
+                    )
+        self._state = self._prog.commit(
+            self._state, jnp.asarray(mask), jnp.asarray(pids)
+        )
+        self._tail_len[committed] = 0
+        self._note_pages(len(committed))
+        metrics.add(
+            "cgx.serve.pages_committed",
+            float(sum(len(layer) for layer in self._prog.streams)
+                  * len(committed)),
+        )
 
     def _note_tokens(self, n: int) -> None:
         self._tokens_total += n

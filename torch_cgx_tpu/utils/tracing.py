@@ -49,15 +49,26 @@ def trace_span(name: str, *, hist: str | None = None, **fields):
             yield
     except BaseException:
         ok = False
-        metrics.add(f"span.{name}.errors", 1.0)
         raise
     finally:
-        dur = time.perf_counter() - start
-        metrics.observe(hist or f"cgx.{name}_s", dur)
-        if timeline.enabled():
-            timeline.record(
-                name, timeline.CAT_SPAN, start, dur, ok=ok, **fields
-            )
+        observe_span(name, start, hist=hist, ok=ok, **fields)
+
+
+def observe_span(name: str, start: float, *, hist: str | None = None,
+                 ok: bool = True, **fields) -> None:
+    """Close a span that began at ``start`` (a ``time.perf_counter()``
+    reading): :func:`trace_span`'s histogram and timeline record, for a
+    span whose two ends lie in different calls and so in no one ``with``
+    block (``serve.prefill.local``: the dispatch in one phase of a tick,
+    the read of its first token in another). It has no annotation in the
+    profiler's trace: a thread's annotations nest, and such a span
+    overlaps its neighbours."""
+    dur = time.perf_counter() - start
+    if not ok:
+        metrics.add(f"span.{name}.errors", 1.0)
+    metrics.observe(hist or f"cgx.{name}_s", dur)
+    if timeline.enabled():
+        timeline.record(name, timeline.CAT_SPAN, start, dur, ok=ok, **fields)
 
 
 class GcPauses:
