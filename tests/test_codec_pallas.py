@@ -1028,6 +1028,7 @@ def test_paged_decode_tpu():
 
 _GPT2L_PAGE = 64 * 1280  # one layer's K or V page: 64 tokens x 20 heads x 64
 _JOYAI_C, _JOYAI_KR = 256 * 512, 256 * 64  # latent / rotated-key pages
+_OLMOH_PAGE = 64 * 3840  # a K or V page: 64 tokens x 30 heads x 128
 _TRAIN_FLAT = (1048576, 491520, 147456)  # GPT-2 124M fusion slices / ws 4
 _TRAIN_CHUNKS = (110592, 314880, 316096)  # ... whose rows end in a chunk tail
 
@@ -1086,6 +1087,21 @@ def _cell_cases():
         yield f"granite-kv-commit-{rows}", "quantize", dict(
             bits=8, rows=rows, numel=_JOYAI_C,
         ), {"quantize": flat}, {"_pipe_tc": 16}
+    # ISSUE 33: olmoh-serve-chat96. ``k`` and ``v`` of the four full-attention
+    # layers: a page of 64 tokens x 30 heads x 128 is 480 buckets of 512,
+    # fifteen whole 32-bucket chunks, rows of 3,840, read through a (96, 5)
+    # page table over a pool of 481 rows: paged, a page a grid step.
+    for name in ("k", "v"):
+        yield f"olmoh-decode-pages-{name}", "dequantize_pages", dict(
+            bits=8, rows=480, out_dtype=jnp.bfloat16, page=(64, 30, 128),
+            lanes=96, pool=481,
+        ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 15}
+    # Its commits: 96 lanes' tails in the decode loop, a padded prompt's 2
+    # pages in prefill_pages (128 tokens).
+    for rows, tc in ((96, 16), (2, 15)):
+        yield f"olmoh-kv-commit-{rows}", "quantize", dict(
+            bits=8, rows=rows, numel=_OLMOH_PAGE,
+        ), {"quantize": flat}, {"_pipe_tc": tc}
     # Page commits: every lane's tail in the decode loop (32 rows), a padded
     # prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and 3,072).
     for name, numel, commits in (

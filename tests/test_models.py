@@ -329,6 +329,77 @@ def test_hybrid_config_refuses_what_it_has_no_equations_for(key, value):
         HybridConfig.from_hf(dict(_published_granite(), **{key: value}))
 
 
+# -- the hybrid gated delta-rule / attention decoder (ISSUE 33) --------------
+
+
+def _published_olmo_hybrid():
+    import json
+    from pathlib import Path
+
+    path = (Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+            / "olmo-hybrid-7b-serve-kv8.json")
+    return json.loads(path.read_text())
+
+
+def test_olmo_hybrid_config_counts_what_the_published_widths_weigh():
+    """``OlmoHybridConfig.from_hf`` over Olmo-Hybrid-7B's published keys
+    (its first 16 layers): 12 gated delta-rule and 4 full-attention layers,
+    and the two weights the serve plan is told apart: a token's K and V
+    over the attention layers alone (30,720 values), a lane's recurrent
+    state over the delta-rule layers whatever its length (28.2 MB: 2.71 GB
+    at 96 lanes)."""
+    from torch_cgx_tpu.models.olmo_hybrid import OlmoHybridConfig
+
+    cfg = OlmoHybridConfig.from_hf(_published_olmo_hybrid())
+    assert cfg.n_layer == 16 and cfg.attention_layers == (3, 7, 11, 15)
+    assert (cfg.n_head, cfg.n_kv_head, cfg.d_head) == (30, 30, 128)
+    assert (cfg.g_heads, cfg.d_k, cfg.d_v, cfg.d_conv) == (30, 96, 192, 4)
+    assert (cfg.d_qkv, cfg.d_value, cfg.chunk) == (11520, 5760, 64)
+    assert cfg.allow_neg_eigval and cfg.eps == 1e-6
+    assert cfg.kv_bytes_per_token() == 30720 * 4
+    assert cfg.state_bytes_per_lane() == 12 * (3 * 11520 + 96 * 5760) * 4
+    assert round(cfg.state_bytes_per_lane() * 96 / 1e9, 2) == 2.71
+
+
+@pytest.mark.parametrize("key,value", [
+    ("rope_theta", 500000.0), ("rope_parameters", {"rope_theta": 10000.0}),
+    ("attention_bias", True), ("tie_word_embeddings", True),
+    ("hidden_act", "gelu"), ("linear_num_key_heads", 15),
+])
+def test_olmo_hybrid_config_refuses_what_it_has_no_equations_for(key, value):
+    """A stated rotary base (either spelling) is refused rather than
+    guessed, as are biases, a tied head, another activation and fewer key
+    heads than value heads."""
+    from torch_cgx_tpu.models.olmo_hybrid import OlmoHybridConfig
+
+    with pytest.raises(ValueError, match=key.replace("rope_parameters",
+                                                     "rope_theta")):
+        OlmoHybridConfig.from_hf(dict(_published_olmo_hybrid(),
+                                      **{key: value}))
+
+
+def test_granite_and_olmo_share_one_convolution():
+    """The depthwise causal convolution both recurrent mixers use
+    (``granite_hybrid.conv_prefill`` / ``conv_state_at`` / ``conv_step``),
+    with and without a bias: a prompt's convolution equals its positions
+    taken a step at a time from a zero state, and the state cut at
+    ``last_idx`` is the state those steps leave."""
+    from torch_cgx_tpu.models import granite_hybrid as gh
+
+    rng = np.random.default_rng(4)
+    w = jnp.asarray(rng.standard_normal((4, 6)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((2, 9, 6)), jnp.float32)
+    for b in (None, jnp.asarray(rng.standard_normal((6,)), jnp.float32)):
+        conv, padded = gh.conv_prefill(w, b, x)
+        state = jnp.zeros((2, 3, 6), jnp.float32)
+        for t in range(9):
+            step, window = gh.conv_step(w, b, state, x[:, t])
+            state = window[:, 1:]
+            np.testing.assert_allclose(step, conv[:, t], atol=1e-5)
+            np.testing.assert_allclose(
+                state, gh.conv_state_at(padded, t, 4), atol=0)
+
+
 @pytest.mark.parametrize("h,hk", [(8, 8), (8, 2), (4, 1)])
 def test_decode_attention_with_grouped_queries(h, hk):
     """``decode_attention`` over rows of ``hk`` K/V heads for ``h`` query

@@ -217,6 +217,34 @@ def _gated_out(cfg: HybridConfig, pm, y, z):
     return _mm(rms_norm(g, pm["norm"], cfg.eps), pm["out_proj"], cfg.dtype)
 
 
+def conv_prefill(w, b, x):
+    """The depthwise causal convolution over a whole prompt, before its
+    activation: ``w (d_conv, C)``, ``b (C,)`` or None, ``x (B, S, C)``
+    float32 -> ``(conv (B, S, C), x with d_conv - 1 zero positions in
+    front)``; :func:`conv_state_at` cuts a lane's state from the latter."""
+    k, s = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    conv = sum(w[j] * padded[:, j: j + s] for j in range(k))
+    return (conv if b is None else conv + b), padded
+
+
+def conv_state_at(padded, last_idx, k: int):
+    """The convolution's state after position ``last_idx``: its ``k - 1``
+    inputs ending there (zeros before the prompt's start)."""
+    return jax.lax.dynamic_slice_in_dim(padded, last_idx + 1, k - 1, 1)
+
+
+def conv_step(w, b, conv_state, x):
+    """One token of the same convolution: ``conv_state (B, d_conv - 1,
+    C)``, ``x (B, C)`` float32 -> ``(conv (B, C)``, the window ``(B, d_conv,
+    C))``, whose last ``d_conv - 1`` rows are the next state."""
+    window = jnp.concatenate(
+        [conv_state.astype(jnp.float32), x[:, None]], axis=1
+    )
+    conv = jnp.sum(w * window, axis=1)
+    return (conv if b is None else conv + b), window
+
+
 def ssd_scan(x, dt, a, bm, cm, chunk: int, state0=None):
     """The recurrence ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (outer) B_t``,
     ``y_t = h_t C_t`` over ``S`` positions in chunks (the SSD form).
@@ -284,16 +312,13 @@ def mamba_prefill(cfg: HybridConfig, pm, y, last_idx):
     b, s, _ = y.shape
     k = cfg.d_conv
     z, xbc_in, dt_raw = mamba_project(cfg, y, pm)
-    padded = jnp.pad(xbc_in, ((0, 0), (k - 1, 0), (0, 0)))
-    conv = sum(
-        pm["conv_w"][j] * padded[:, j: j + s] for j in range(k)
-    ) + pm["conv_b"]
+    conv, padded = conv_prefill(pm["conv_w"], pm["conv_b"], xbc_in)
     live = (jnp.arange(s) <= last_idx)[None, :, None]
     x, dt, bm, cm = _step_operands(cfg, pm, jax.nn.silu(conv), dt_raw, live)
     xh = x.reshape(b, s, cfg.m_heads, cfg.m_head)
     yh, state = ssd_scan(xh, dt, -jnp.exp(pm["A_log"]), bm, cm, cfg.chunk)
     yh = yh + pm["D"][:, None] * xh
-    conv_state = jax.lax.dynamic_slice_in_dim(padded, last_idx + 1, k - 1, 1)
+    conv_state = conv_state_at(padded, last_idx, k)
     # (B, H, P, N) -> the lanes' layout (B, N, H*P).
     ssm_state = state.transpose(0, 3, 1, 2).reshape(b, cfg.d_state,
                                                     cfg.d_inner)
@@ -307,10 +332,7 @@ def mamba_step(cfg: HybridConfig, pm, y, conv_state, ssm_state):
     new conv state, the new ssm state)``. The state update is
     ``ops.dispatch.ssm_update`` (one kernel over all lanes on the chip)."""
     z, xbc_in, dt_raw = mamba_project(cfg, y, pm)
-    window = jnp.concatenate(
-        [conv_state.astype(jnp.float32), xbc_in[:, None]], axis=1
-    )
-    conv = jnp.sum(pm["conv_w"] * window, axis=1) + pm["conv_b"]
+    conv, window = conv_step(pm["conv_w"], pm["conv_b"], conv_state, xbc_in)
     x, dt, bm, cm = _step_operands(cfg, pm, jax.nn.silu(conv), dt_raw)
     decay = _head_rows(cfg, jnp.exp(dt * -jnp.exp(pm["A_log"])))
     new_ssm, yv = ops_dispatch.ssm_update(

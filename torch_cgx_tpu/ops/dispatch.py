@@ -16,7 +16,7 @@ from jax import lax
 
 from .. import config as cfg_mod
 from ..config import CompressionConfig
-from . import codec, codec_pallas, ssm
+from . import codec, codec_pallas, gdn, ssm
 
 
 def _on_tpu() -> bool:
@@ -348,3 +348,19 @@ def ssm_update(state, decay, dtx, bm, cm):
         )
     codec_pallas.note_lowering("ssm_update", "xla")
     return ssm.ssm_update_xla(state, decay, dtx, bm, cm)
+
+
+def gdn_update(state, q, k, v, alpha, beta):
+    """One token's update of a gated delta-rule layer's recurrent state,
+    all lanes (``ops/gdn.py``): the ``cgx_gdn_update`` kernel on the TPU
+    (and, interpreted, wherever ``CGX_CODEC_IMPL=pallas`` asks for the
+    kernels), its ``jax.numpy`` form elsewhere; counted per call site as
+    ``cgx.codec.lowering.gdn_update.pallas`` / ``.xla``."""
+    impl = cfg_mod.codec_impl()
+    if impl == "pallas" or (impl == "auto" and _on_tpu()):
+        codec_pallas.note_lowering("gdn_update", "pallas")
+        return gdn.gdn_update_pallas(
+            state, q, k, v, alpha, beta, interpret=not _on_tpu()
+        )
+    codec_pallas.note_lowering("gdn_update", "xla")
+    return gdn.gdn_update_xla(state, q, k, v, alpha, beta)

@@ -18,9 +18,10 @@ inference:
   the GPT-2 adapter (streams ``k``, ``v``).
 * :mod:`.latent` — the latent-attention (MLA) adapter with dropless
   experts (streams ``c``, ``kr``).
-* :mod:`.hybrid` — the hybrid state-space adapter: ``k``, ``v`` pages on
-  its few attention layers, a per-lane recurrent state (``conv``, ``ssm``)
-  on its Mamba-2 layers.
+* :mod:`.hybrid` — the two hybrid adapters: ``k``, ``v`` pages on their
+  few attention layers, a per-lane recurrent state on the others (``conv``,
+  ``ssm`` on Mamba-2 layers; ``conv``, ``gdn``, a matrix a head, on gated
+  delta-rule layers).
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -35,6 +36,6 @@ from .scheduler import (  # noqa: F401
     invalidate_decode_cache,
 )
 from .latent import LatentMoEServer  # noqa: F401
-from .hybrid import HybridSSMServer  # noqa: F401
+from .hybrid import HybridGDNServer, HybridSSMServer  # noqa: F401
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401

@@ -1,26 +1,39 @@
-"""The hybrid state-space adapter: a decoder whose layers are mostly
-Mamba-2 with a few grouped-query attention layers among them
-(``models/granite_hybrid.py``) behind the one scheduler.
+"""The hybrid adapters: decoders whose layers are mostly recurrent with a
+few attention layers among them, behind the one scheduler.
+:class:`HybridSSMServer` serves ``models/granite_hybrid.py`` (Mamba-2 layers
+and grouped-query attention), :class:`HybridGDNServer`
+``models/olmo_hybrid.py`` (gated delta-rule layers and multi-head attention
+with normed queries and keys).
 
-Its layers name different streams. An attention layer leaves pages: ``k``
+Their layers name different streams. An attention layer leaves pages: ``k``
 and ``v``, rows of ``n_kv_head * d_head`` (no positional embedding, so
-what is cached is the projection itself). A Mamba layer leaves none: what
-a lane keeps of it is a fixed-size recurrent state, ``conv (d_conv - 1,
-d_inner + 2 d_state)`` (the convolution's last inputs) and ``ssm (d_state,
-d_inner)``, float32, which every decode step rewrites whole
-(``ops.dispatch.ssm_update``: one kernel a layer over all lanes, in place)
-and an admission overwrites with what the prefill left at the prompt's last
-token. ``state_streams`` states them; the scheduler carries them in the
-donated state beside pools and tails.
+what is cached is the projection itself, normed where the model norms it). A
+recurrent layer leaves none: what a lane keeps of it is a fixed-size state,
+float32, which every decode step rewrites whole (one kernel a layer over
+all lanes, in place) and an admission overwrites with what the prefill left
+at the prompt's last token:
+
+* Mamba-2: ``conv (d_conv - 1, d_inner + 2 d_state)`` (the convolution's
+  last inputs) and ``ssm (d_state, d_inner)``; ``ops.dispatch.ssm_update``.
+* gated delta rule: ``conv (d_conv - 1, heads x (2 d_k + d_v))`` and ``gdn
+  (d_k, heads x d_v)``, a matrix a head; ``ops.dispatch.gdn_update``.
+
+``state_streams`` states them; the scheduler carries them in the donated
+state beside pools and tails. What an attention layer's decode position does
+is the same in both (:func:`lane_masks`, :func:`attend_paged`): the token's
+``k`` and ``v`` into the raw tail, the committed pages read where they lie,
+one ``decode_attention`` over both.
 
 Page geometry is the streams' arithmetic (``serving/latent.py`` says the
-same of its own): at 256 tokens a page and bucket 512, a ``k`` or ``v``
-page of eight heads of 64 is 256 buckets, eight whole 32-bucket chunks,
-one bucket a token, rows of 512: the flat Mosaic kernels at commit and the
-paged read at decode.
+same of its own). granite-4.0-h-micro at 256 tokens a page and bucket 512:
+a ``k`` or ``v`` page of eight heads of 64 is 256 buckets, eight whole
+32-bucket chunks, one bucket a token, rows of 512. Olmo-Hybrid-7B at 64
+tokens a page: thirty heads of 128 are 480 buckets, fifteen whole chunks,
+rows of 3,840. Both take the flat Mosaic kernels at commit and the paged
+read at decode.
 
 The disaggregated path ships K and V frames of every layer and no state;
-it refuses this adapter (``transport.require_kv_streams``), which is
+it refuses these adapters (``transport.require_kv_streams``), which are
 served with local prefill.
 """
 
@@ -31,25 +44,77 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models import granite_hybrid as gh
+from ..models import olmo_hybrid as oh
 from ..models.attention import decode_attention
-from ..models.granite_hybrid import HybridConfig
 from ..models.mla_moe import _mm, rms_norm
 from ..ops import paged_kv
 from .scheduler import ServeConfig, page_specs
 
 
-class HybridSSMServer:
-    """Model adapter (the protocol is in ``scheduler.py``) for one
-    ``(HybridConfig, params)`` pair: cache streams ``k`` and ``v`` on the
-    attention layers, state streams ``conv`` and ``ssm`` (``state_dtype``,
-    float32 unless a control asks for less) on the Mamba layers."""
+def lane_masks(serve: ServeConfig, state):
+    """What every attention layer of a decode step shares: ``(onehot (B,
+    page_tokens, 1, 1)``, where this token's K and V go in the tail; ``mask_c
+    (B, pages x page_tokens)``, the committed positions; ``mask_t (B,
+    page_tokens))``, the tail's live positions, this token's among them."""
+    pt = serve.page_tokens
+    b = state["tokens"].shape[0]
+    tail_idx = jnp.minimum(state["tail_len"], pt - 1)
+    onehot = (
+        jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
+        == tail_idx[:, None]
+    )[:, :, None, None]
+    committed = state["n_pages"] * pt
+    pos_c = jax.lax.broadcasted_iota(
+        jnp.int32, (b, serve.pages_per_seq * pt), 1)
+    pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
+    mask_c = pos_c < committed[:, None]
+    mask_t = pos_t <= tail_idx[:, None]
+    return onehot, mask_c, mask_t
 
-    kind = "hybrid_ssm"
+
+def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
+                 score_divisor):
+    """One decode position of an attention layer over a lane's cache: this
+    token's ``k`` and ``v (B, 1, Hk, dh)`` written into the raw float32
+    tails, the committed pages read as ``dt`` rows where they lie
+    (``paged_kv.gather_dequant_pages``), one ``decode_attention`` of ``q (B,
+    1, H, dh)`` over pages and tail. Returns ``(o (B, H * dh), {stream: its
+    new tail})``."""
+    onehot, mask_c, mask_t = masks
+    b, pt = onehot.shape[:2]
+    width = k.shape[-2] * k.shape[-1]
+    pages, tails, new = {}, {}, {}
+    for (name, spec), fresh in zip(layer_streams, (k, v)):
+        tail = jnp.where(
+            onehot, fresh.astype(jnp.float32),
+            state[f"tail_{name}"][layer],
+        )
+        new[name] = tail
+        tails[name] = tail.reshape(b, pt, width).astype(dt)
+        pages[name] = paged_kv.gather_dequant_pages(
+            state["pools"][layer][name], state["page_table"],
+            spec, dt,
+        )
+    o = decode_attention(
+        q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],
+        mask=mask_c, tail_mask=mask_t, score_divisor=score_divisor,
+    )
+    return o, new
+
+
+class _HybridAdapter:
+    """What the two adapters share of the protocol in ``scheduler.py``: one
+    ``(model config, params)`` pair, ``k`` and ``v`` pages on the layers the
+    config lists as ``attention_layers``, and on the others the state
+    streams a subclass names, kept in ``state_dtype`` (float32 unless a
+    control asks for less)."""
+
     step_counters = ()
 
-    def __init__(self, model_cfg: HybridConfig, params,
+    def __init__(self, model_cfg, params,
                  serve: Optional[ServeConfig] = None,
                  state_dtype: Any = jnp.float32):
         self.cfg = model_cfg
@@ -67,11 +132,28 @@ class HybridSSMServer:
 
     def cache_streams(self, layer: int):
         cfg = self.cfg
-        if cfg.layer_types[layer] != "attention":
+        if layer not in cfg.attention_layers:
             return ()
         (spec,) = page_specs(self.layer_name(layer), self.serve.page_tokens,
                              [(cfg.n_kv_head, cfg.d_head)])
         return (("k", spec), ("v", spec))
+
+    def with_params(self, params):
+        return type(self)(self.cfg, params, self.serve, self.state_dtype)
+
+    def kv_bytes_per_token(self) -> int:
+        return self.cfg.kv_bytes_per_token()
+
+    def state_bytes_per_lane(self) -> int:
+        return (self.cfg.state_bytes_per_lane() // 4
+                * self.state_dtype.itemsize)
+
+
+class HybridSSMServer(_HybridAdapter):
+    """Model adapter for one ``(HybridConfig, params)`` pair: state streams
+    ``conv`` and ``ssm`` on the Mamba layers."""
+
+    kind = "hybrid_ssm"
 
     def state_streams(self, layer: int):
         cfg = self.cfg
@@ -81,17 +163,6 @@ class HybridSSMServer:
             ("conv", (cfg.d_conv - 1, cfg.d_xbc), self.state_dtype),
             ("ssm", (cfg.d_state, cfg.d_inner), self.state_dtype),
         )
-
-    def with_params(self, params) -> "HybridSSMServer":
-        return HybridSSMServer(self.cfg, params, self.serve,
-                               self.state_dtype)
-
-    def kv_bytes_per_token(self) -> int:
-        return self.cfg.kv_bytes_per_token()
-
-    def state_bytes_per_lane(self) -> int:
-        return (self.cfg.state_bytes_per_lane() // 4
-                * self.state_dtype.itemsize)
 
     # -- forwards ----------------------------------------------------------
 
@@ -135,21 +206,8 @@ class HybridSSMServer:
         rewritten. Returns (logits (B, V), the new tails and states by
         stream, None)."""
         cfg, dt = self.cfg, self.cfg.dtype
-        pt = self.serve.page_tokens
-        p_dim = self.serve.pages_per_seq
         x = gh.embed(cfg, self.p, state["tokens"][:, None])[:, 0]  # (B, D)
-        b = x.shape[0]
-        tail_idx = jnp.minimum(state["tail_len"], pt - 1)
-        onehot = (
-            jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-            == tail_idx[:, None]
-        )[:, :, None, None]
-        committed = state["n_pages"] * pt
-        pos_c = jax.lax.broadcasted_iota(jnp.int32, (b, p_dim * pt), 1)
-        pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-        mask_c = pos_c < committed[:, None]
-        mask_t = pos_t <= tail_idx[:, None]
-        width = cfg.n_kv_head * cfg.d_head
+        masks = lane_masks(self.serve, state)
         new = {name: [None] * cfg.n_layer for name in ("k", "v", "conv",
                                                        "ssm")}
         for layer, kind in enumerate(cfg.layer_types):
@@ -162,23 +220,92 @@ class HybridSSMServer:
                 )
             else:
                 q, k, v = gh.attn_project(cfg, y[:, None], pl["attn"])
-                pages, tails = {}, {}
-                for (name, spec), fresh in zip(streams[layer], (k, v)):
-                    tail = jnp.where(
-                        onehot, fresh.astype(jnp.float32),
-                        state[f"tail_{name}"][layer],
-                    )
-                    new[name][layer] = tail
-                    tails[name] = tail.reshape(b, pt, width).astype(dt)
-                    pages[name] = paged_kv.gather_dequant_pages(
-                        state["pools"][layer][name], state["page_table"],
-                        spec, dt,
-                    )
-                o = decode_attention(
-                    q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],
-                    mask=mask_c, tail_mask=mask_t,
-                    score_divisor=1.0 / cfg.attention_multiplier,
+                o, tails = attend_paged(
+                    state, layer, streams[layer], masks, q, k, v, dt,
+                    1.0 / cfg.attention_multiplier,
                 )
+                for name, tail in tails.items():
+                    new[name][layer] = tail
                 mixed = _mm(o, pl["attn"]["o"], dt)
             x = gh.mlp_half(cfg, pl, gh.residual(cfg, x, mixed))
         return gh.logits(cfg, self.p, x), new, None
+
+
+class HybridGDNServer(_HybridAdapter):
+    """Model adapter for one ``(OlmoHybridConfig, params)`` pair: state
+    streams ``conv`` and ``gdn`` on the gated delta-rule layers."""
+
+    kind = "hybrid_gdn"
+
+    def state_streams(self, layer: int):
+        cfg = self.cfg
+        if cfg.layer_types[layer] != "linear_attention":
+            return ()
+        return (
+            ("conv", (cfg.d_conv - 1, cfg.d_qkv), self.state_dtype),
+            ("gdn", (cfg.d_k, cfg.d_value), self.state_dtype),
+        )
+
+    # -- forwards ----------------------------------------------------------
+
+    def prefill_forward(self, tokens, positions, last_idx):
+        """Full causal forward over a (right-padded) prompt: the logits at
+        ``last_idx``; each full-attention layer's normed ``k`` and its ``v``
+        ``(B, S, Hk, dh)`` f32, as the projections left them (right-padding
+        is inert under the causal mask); each delta-rule layer's ``conv``
+        and ``gdn`` state after
+        position ``last_idx``, which the pad does not reach
+        (``olmo_hybrid.gdn_prefill``). One list a stream, None for a layer
+        without it. ``positions`` is not used: nothing is rotated."""
+        cfg = self.cfg
+        x = oh.embed(cfg, self.p, tokens)
+        out = {name: [None] * cfg.n_layer for name in ("k", "v", "conv",
+                                                       "gdn")}
+        for layer, kind in enumerate(cfg.layer_types):
+            pl = self.p[f"layer_{layer}"]
+            if kind == "linear_attention":
+                mixed, conv, state = oh.gdn_prefill(
+                    cfg, pl["gdn"], x, last_idx
+                )
+                out["conv"][layer] = conv.astype(self.state_dtype)
+                out["gdn"][layer] = state.astype(self.state_dtype)
+            else:
+                q, k, v = oh.attn_project(cfg, x, pl["attn"])
+                out["k"][layer], out["v"][layer] = k, v
+                mixed = oh.attend(cfg, q, k, v, pl["attn"])
+            x = oh.mlp_half(cfg, pl, oh.post_norm_residual(
+                cfg, x, mixed, pl["mixer_norm"]))
+        x_last = jax.lax.dynamic_index_in_dim(x, last_idx, 1)
+        return (oh.logits(cfg, self.p, x_last)[:, -1], out["k"], out["v"],
+                out["conv"], out["gdn"])
+
+    def decode_forward(self, state, streams):
+        """One decode position: a full-attention layer reads its committed
+        pages and, apart, its raw tail with this token's K and V appended
+        (:func:`attend_paged`); a delta-rule layer takes one step of its
+        recurrence and hands back its state, rewritten. Returns (logits (B,
+        V), the new tails and states by stream, None)."""
+        cfg, dt = self.cfg, self.cfg.dtype
+        x = oh.embed(cfg, self.p, state["tokens"])  # (B, D)
+        masks = lane_masks(self.serve, state)
+        new = {name: [None] * cfg.n_layer for name in ("k", "v", "conv",
+                                                       "gdn")}
+        for layer, kind in enumerate(cfg.layer_types):
+            pl = self.p[f"layer_{layer}"]
+            if kind == "linear_attention":
+                mixed, new["conv"][layer], new["gdn"][layer] = oh.gdn_step(
+                    cfg, pl["gdn"], x, state["state_conv"][layer],
+                    state["state_gdn"][layer],
+                )
+            else:
+                q, k, v = oh.attn_project(cfg, x[:, None], pl["attn"])
+                o, tails = attend_paged(
+                    state, layer, streams[layer], masks, q, k, v, dt,
+                    np.sqrt(cfg.d_head),
+                )
+                for name, tail in tails.items():
+                    new[name][layer] = tail
+                mixed = oh.attn_out(cfg, o, pl["attn"])
+            x = oh.mlp_half(cfg, pl, oh.post_norm_residual(
+                cfg, x, mixed, pl["mixer_norm"]))
+        return oh.logits(cfg, self.p, x), new, None
