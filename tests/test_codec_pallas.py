@@ -1033,6 +1033,16 @@ _TRAIN_FLAT = (1048576, 491520, 147456)  # GPT-2 124M fusion slices / ws 4
 _TRAIN_CHUNKS = (110592, 314880, 316096)  # ... whose rows end in a chunk tail
 
 
+def _commit_lanes(max_batch, page_tokens):
+    """Rows of a cell's one ``commit`` program: ``ServeConfig.commit_lanes``
+    at the cell's lanes and page size (4, and 8 for the 96-lane cell)."""
+    from torch_cgx_tpu.serving.scheduler import ServeConfig
+
+    return ServeConfig(page_tokens=page_tokens, max_batch=max_batch,
+                       max_pages=8, max_seq=page_tokens,
+                       ship_depth=1).commit_lanes
+
+
 def _cell_cases():
     flat, chunks = "pallas_flat", "pallas_chunks"
     read = dict(bits=8, rows=512, out_dtype=jnp.bfloat16)  # 32 lanes x 16 pages
@@ -1081,10 +1091,12 @@ def _cell_cases():
             bits=8, rows=384, out_dtype=jnp.bfloat16, page=(256, 8, 64),
             lanes=64, pool=385,
         ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
-    # Its commits: 64 lanes' tails in the decode loop, a padded prompt's 2
-    # or 4 pages in prefill_pages (512 and 1,024 tokens).
-    for rows in (64, 2, 4):
-        yield f"granite-kv-commit-{rows}", "quantize", dict(
+    # Its commits: the tails that filled in the decode loop (ISSUE 34: 4 of
+    # the 64 lanes a call), a padded prompt's 2 or 4 pages in prefill_pages
+    # (512 and 1,024 tokens).
+    tails = _commit_lanes(64, 256)
+    for tag, rows in ((f"tails-{tails}", tails), ("2", 2), ("4", 4)):
+        yield f"granite-kv-commit-{tag}", "quantize", dict(
             bits=8, rows=rows, numel=_JOYAI_C,
         ), {"quantize": flat}, {"_pipe_tc": 16}
     # ISSUE 33: olmoh-serve-chat96. ``k`` and ``v`` of the four full-attention
@@ -1096,18 +1108,22 @@ def _cell_cases():
             bits=8, rows=480, out_dtype=jnp.bfloat16, page=(64, 30, 128),
             lanes=96, pool=481,
         ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 15}
-    # Its commits: 96 lanes' tails in the decode loop, a padded prompt's 2
-    # pages in prefill_pages (128 tokens).
-    for rows, tc in ((96, 16), (2, 15)):
+    # Its commits: the tails that filled in the decode loop (ISSUE 34: 8 of
+    # the 96 lanes a call), a padded prompt's 2 pages in prefill_pages (128
+    # tokens).
+    for rows, tc in ((_commit_lanes(96, 64), 15), (2, 15)):
         yield f"olmoh-kv-commit-{rows}", "quantize", dict(
             bits=8, rows=rows, numel=_OLMOH_PAGE,
         ), {"quantize": flat}, {"_pipe_tc": tc}
-    # Page commits: every lane's tail in the decode loop (32 rows), a padded
-    # prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and 3,072).
+    # Page commits: the tails that filled in the decode loop (ISSUE 34: 4 of
+    # the 32 lanes a call, where every lane's 32 rows were quantized), a
+    # padded prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and
+    # 3,072).
     for name, numel, commits in (
-        ("gpt2l", _GPT2L_PAGE, {32: 16, 11: 11, 14: 14}),
-        ("joyai-c", _JOYAI_C, {32: 16, 8: 16, 12: 16}),
-        ("joyai-kr", _JOYAI_KR, {32: 16, 8: 8, 12: 12}),
+        ("gpt2l", _GPT2L_PAGE,
+         {_commit_lanes(32, 64): 10, 11: 11, 14: 14}),
+        ("joyai-c", _JOYAI_C, {_commit_lanes(32, 256): 16, 8: 16, 12: 16}),
+        ("joyai-kr", _JOYAI_KR, {_commit_lanes(32, 256): 4, 8: 8, 12: 12}),
     ):
         for rows, tc in commits.items():
             yield f"{name}-commit-{rows}", "quantize", dict(

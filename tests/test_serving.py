@@ -1556,7 +1556,7 @@ _MIX_LANES = 3
 _MIX_PAGES = 11  # three lanes of the long answers would hold fifteen
 
 
-def _mix_adapter(kind, model_setup):
+def _mix_adapter(kind, model_setup, lanes=_MIX_LANES, pages=_MIX_PAGES):
     """(build(eos) -> adapter at its small CPU configuration, its page
     size, its vocabulary)."""
     import sys
@@ -1565,8 +1565,8 @@ def _mix_adapter(kind, model_setup):
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
     def serve(page, eos):
-        return ServeConfig(page_tokens=page, max_batch=_MIX_LANES,
-                           max_pages=_MIX_PAGES, max_seq=8 * page,
+        return ServeConfig(page_tokens=page, max_batch=lanes,
+                           max_pages=pages, max_seq=8 * page,
                            ship_depth=2, eos_token=eos)
 
     if kind == "gpt2":
@@ -1678,3 +1678,250 @@ def test_mixed_ticks_serve_the_tokens_of_a_plain_decode(
         assert metrics.get("cgx.serve.decode.discarded_tokens") == 0.0
     # run() on an empty scheduler has nothing to drain
     assert sched.run(deadline_s=1.0) and not sched._steps
+
+
+# ---------------------------------------------------------------------------
+# The commit quantizes the lanes that filled a page (ISSUE 34): the program
+# takes K lane indices, and the all-lanes masked commit it replaced is the
+# oracle for every byte it writes.
+# ---------------------------------------------------------------------------
+
+_COMMIT_LANES = 6  # K = 4 at these page sizes: six full tails take two calls
+
+
+@pytest.mark.parametrize("lanes,page,k", [
+    (32, 64, 4), (32, 256, 4), (64, 256, 4), (96, 64, 8),  # the five cells
+    (_COMMIT_LANES, PAGE, 4), (3, PAGE, 3), (256, 16, 64),
+])
+def test_commit_lanes_follow_the_serve_geometry(lanes, page, k):
+    """``K`` is four times the tails that fill a step with every lane
+    decoding, as a power of two, at least 4 and at most the lanes."""
+    sv = ServeConfig(page_tokens=page, max_batch=lanes, max_pages=8,
+                     max_seq=page, ship_depth=1)
+    assert sv.commit_lanes == k
+
+
+def _masked_commit(sched, state, mask, page_ids):
+    """The commit as it was until PR 34: every lane's tail of every layer
+    and stream quantized, the lanes outside ``mask`` sent to the scratch
+    row; ``page_table``, ``n_pages`` and ``tail_len`` by ``where``."""
+    from torch_cgx_tpu.ops import paged_kv
+
+    sv, prog = sched.server.serve, sched._prog
+    b = mask.shape[0]
+    ids = jnp.where(mask, page_ids, sv.max_pages)
+    out = dict(state)
+    out["pools"] = tuple(
+        {
+            name: paged_kv.commit_page_rows(
+                state["pools"][layer][name], ids,
+                state[f"tail_{name}"][layer].reshape(b, -1), spec,
+            )
+            for name, spec in layer_streams
+        }
+        for layer, layer_streams in enumerate(prog.streams)
+    )
+    p_iota = jax.lax.broadcasted_iota(
+        jnp.int32, state["page_table"].shape, 1)
+    slot = (p_iota == state["n_pages"][:, None]) & mask[:, None]
+    out["page_table"] = jnp.where(slot, page_ids[:, None],
+                                  state["page_table"])
+    out["n_pages"] = state["n_pages"] + mask.astype(jnp.int32)
+    out["tail_len"] = jnp.where(mask, 0, state["tail_len"])
+    return out
+
+
+def _held_lanes_with_full_tails(sched, full, page):
+    """Every lane held by a request mid-answer over a state drawn at
+    random (pools, tails, recurrent state, page tables), the tails of the
+    lanes ``full`` at a whole page. Returns a copy of that state."""
+    sv = sched.server.serve
+    rng = np.random.default_rng(34)
+
+    def drawn(a):
+        if a.dtype == jnp.int32:  # a pool's words
+            return jnp.asarray(rng.integers(-2**31, 2**31, a.shape,
+                                            dtype=np.int64).astype(np.int32))
+        return jnp.asarray(rng.standard_normal(a.shape).astype(a.dtype))
+
+    st = sched._state
+    for name in list(st):
+        if name == "pools" or name.startswith(("tail_", "state_")):
+            st[name] = jax.tree.map(drawn, st[name])
+    b = sv.max_batch
+    tail_len = rng.integers(0, page, b)
+    tail_len[full] = page
+    n_pages = rng.integers(0, sv.pages_per_seq, b)
+    table = rng.integers(0, sv.max_pages, (b, sv.pages_per_seq))
+    table[np.arange(sv.pages_per_seq)[None] >= n_pages[:, None]] = -1
+    st.update(
+        tail_len=jnp.asarray(tail_len, jnp.int32),
+        n_pages=jnp.asarray(n_pages, jnp.int32),
+        page_table=jnp.asarray(table, jnp.int32),
+        tokens=jnp.asarray(rng.integers(0, 50, b), jnp.int32),
+        pos=jnp.asarray(rng.integers(0, 50, b), jnp.int32),
+        active=jnp.ones((b,), bool),
+    )
+    for lane in range(b):
+        sched._lanes[lane] = Request(id=f"c{lane}", tokens=[1],
+                                     max_new_tokens=64)
+    sched._left[:] = 32
+    sched._tail_len[:] = tail_len
+    return jax.tree.map(lambda a: jnp.array(a, copy=True), st)
+
+
+def _note_allocs(sched):
+    """{request id: the page ``cache.alloc`` gave it}, filled as it goes."""
+    given, alloc = {}, sched.cache.alloc
+
+    def noted(seq_id):
+        pid = alloc(seq_id)
+        if pid is not None:
+            given[seq_id] = pid
+        return pid
+
+    sched.cache.alloc = noted
+    return given
+
+
+def _masked_operands(lanes, given):
+    """``(mask, page_ids)`` of the all-lanes commit that promotes
+    ``lanes`` to the pages :func:`_note_allocs` saw them given."""
+    mask = np.zeros((_COMMIT_LANES,), bool)
+    mask[lanes] = True
+    pids = np.zeros((_COMMIT_LANES,), np.int32)
+    pids[lanes] = [given[f"c{lane}"] for lane in lanes]
+    return jnp.asarray(mask), jnp.asarray(pids)
+
+
+def _assert_state_is(sched, want):
+    """The scheduler's state against ``want``, bit for bit, but for the
+    pools' scratch row (written by whatever a call did not want)."""
+    got, scratch = sched._state, sched.server.serve.max_pages
+    assert sorted(got) == sorted(want)
+    for name in want:
+        cut = (lambda a: a[:scratch]) if name == "pools" else (lambda a: a)
+        _assert_same(jax.tree.map(cut, got[name]),
+                     jax.tree.map(cut, want[name]))
+
+
+@pytest.mark.parametrize("full", ["one", "k", "all"])
+@pytest.mark.parametrize("kind", ["gpt2", "latent", "hybrid"])
+def test_indexed_commit_writes_what_the_all_lanes_commit_wrote(
+        model_setup, monkeypatch, kind, full):
+    """One full tail, ``K`` of them, and every lane's at once (two calls,
+    the second padded): pools, page table and counts are the masked
+    all-lanes commit's bit for bit, for a K/V adapter, the latent one (``c``
+    and ``kr``) and a hybrid one, whose recurrent state, like every tail,
+    token and position, is left as it was."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    build, page, _vocab = _mix_adapter(kind, model_setup,
+                                       lanes=_COMMIT_LANES, pages=24)
+    sched = ContinuousBatchScheduler(build(None))
+    k = sched.server.serve.commit_lanes
+    assert k == 4
+    lanes = {"one": [4], "k": [0, 2, 3, 5],
+             "all": list(range(_COMMIT_LANES))}[full]
+    before = _held_lanes_with_full_tails(sched, lanes, page)
+    given = _note_allocs(sched)
+    metrics.reset()
+    sched._commit_full_tails()
+    assert sorted(given) == [f"c{lane}" for lane in lanes]
+    want = _masked_commit(sched, before, *_masked_operands(lanes, given))
+    _assert_state_is(sched, want)
+    for name in want:  # and nothing but pools and bookkeeping moved
+        if name not in ("pools", "page_table", "n_pages", "tail_len"):
+            _assert_same(want[name], before[name])
+    assert not sched._tail_len[lanes].any()
+    calls = -(-len(lanes) // k)
+    assert metrics.get("cgx.serve.commit.calls") == calls
+    assert metrics.get("cgx.serve.commit.rows") == calls * k
+    assert metrics.get("cgx.serve.commit.lanes") == len(lanes)
+    assert metrics.get("cgx.serve.pages_committed") == len(lanes) * sum(
+        len(layer) for layer in sched._prog.streams)
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "latent", "hybrid"])
+def test_commit_and_eviction_in_one_tick(model_setup, monkeypatch, kind):
+    """Five tails full with three pages left in the pool: the first three
+    are committed in one call, the other two evicted back to the queue
+    with their lanes released, and the state is the all-lanes commit's
+    over the released lanes."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    build, page, _vocab = _mix_adapter(kind, model_setup,
+                                       lanes=_COMMIT_LANES, pages=24)
+    sched = ContinuousBatchScheduler(build(None))
+    lanes = [0, 1, 3, 4, 5]
+    before = _held_lanes_with_full_tails(sched, lanes, page)
+    for i in range(21):  # another 21 pages are somebody's
+        assert sched.cache.alloc(f"other{i % 3}") is not None
+    given = _note_allocs(sched)
+    reqs = list(sched._lanes)
+    metrics.reset()
+    sched._commit_full_tails()
+    kept, evicted = lanes[:3], lanes[3:]
+    assert sorted(given) == [f"c{lane}" for lane in kept]
+    assert metrics.get("cgx.serve.decode_evictions") == 2
+    assert sched._waiting == [reqs[lane] for lane in evicted]
+    assert [sched._lanes[lane] for lane in evicted] == [None, None]
+    assert not sched._left[evicted].any() and not sched._released
+    released = np.zeros((_COMMIT_LANES,), bool)
+    released[evicted] = True
+    want = dict(before)
+    want.update(sched._prog.release_lanes(
+        {name: before[name] for name in sched_mod._LANE_RESET}, released))
+    _assert_state_is(sched, _masked_commit(
+        sched, want, *_masked_operands(kept, given)))
+    assert not sched._tail_len[lanes].any()
+    assert metrics.get("cgx.serve.commit.calls") == 1
+    assert metrics.get("cgx.serve.commit.lanes") == 3
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "latent", "hybrid"])
+def test_a_step_queued_ahead_finds_the_pages_committed_before_it(
+        model_setup, monkeypatch, kind):
+    """Six requests of one prompt length on six lanes: every tail fills
+    in the same tick, so the commit in front of a step queued ahead runs
+    the program twice, and every request still gets the tokens of a
+    greedy decode of that request alone."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    build, page, vocab = _mix_adapter(kind, model_setup,
+                                      lanes=_COMMIT_LANES, pages=30)
+    rng = np.random.default_rng(34)
+
+    def requests(tag):
+        return [Request(id=f"{tag}{i}", max_new_tokens=2 * page + 3,
+                        tokens=[int(t) for t in row])
+                for i, row in enumerate(
+                    rng.integers(0, vocab, (_COMMIT_LANES, page + 3)))]
+
+    rng_state = rng.bit_generator.state
+    plain = []
+    for req in requests("p"):
+        alone = ContinuousBatchScheduler(build(None))
+        alone.submit(req)
+        assert alone.run(deadline_s=DEADLINE_S)
+        plain.append(req.output)
+    rng.bit_generator.state = rng_state
+    metrics.reset()
+    sched = ContinuousBatchScheduler(build(None))
+    inner, ahead = sched._commit_full_tails, []
+
+    def noted():
+        lanes = metrics.get("cgx.serve.commit.lanes")
+        inner()
+        if sched._steps:  # under a step dispatched and not read yet
+            ahead.append(metrics.get("cgx.serve.commit.lanes") - lanes)
+
+    sched._commit_full_tails = noted
+    reqs = requests("a")
+    for r in reqs:
+        sched.submit(r)
+    assert sched.run(deadline_s=DEADLINE_S)
+    assert [r.output for r in reqs] == plain
+    assert _COMMIT_LANES in ahead  # all six at once, ahead of a step
+    commits = [n for n in ahead if n]
+    assert metrics.get("cgx.serve.commit.calls") > len(commits)
+    assert metrics.get("cgx.serve.commit.lanes") == 2 * _COMMIT_LANES
+    assert metrics.get("cgx.serve.decode.ahead") > 0
+    assert sched.cache.free_pages == 30

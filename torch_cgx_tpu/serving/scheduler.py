@@ -33,7 +33,9 @@ A tick queues everything before it reads anything. The programs are
 ordered on the device by the state they donate to one another, so the
 host dispatches prefill, lane write, the next prefill, its lane write,
 the commit of full tails (decided from tail lengths the host counts
-itself: set at the lane write, plus one a step, zero at a commit) and the
+itself: set at the lane write, plus one a step, zero at a commit; the
+program takes the few lanes that filled by index, ``ServeConfig.commit_lanes``
+a call, and quantizes their rows alone) and the
 decode step, and only then reads: the first tokens in admission order,
 then the step's tokens. A first token is stamped, and can finish its
 request, when the host holds it. When no lane is free and none produces
@@ -131,6 +133,17 @@ class ServeConfig:
     def pages_per_seq(self) -> int:
         return -(-self.max_seq // self.page_tokens)
 
+    @property
+    def commit_lanes(self) -> int:
+        """Tails one call of the ``commit`` program promotes (its ``K``).
+        With every lane decoding, ``max_batch / page_tokens`` tails fill a
+        step; a power of two four times that, and at least 4, leaves a
+        second call to the rare tick in which more fill at once (the start
+        of a run, a burst of equal prompts): 8 of 96 lanes at 64-token
+        pages, 4 of 32 or 64 at 64 or 256."""
+        fills = -(-self.max_batch // self.page_tokens)
+        return min(self.max_batch, max(4, 1 << (4 * fills - 1).bit_length()))
+
     @classmethod
     def from_env(cls, model=None,
                  eos_token: Optional[int] = None) -> "ServeConfig":
@@ -222,7 +235,8 @@ class Request:
 # the pool by its id and nothing gathers or reshapes the pool first (on the
 # chip a ``(n, W)`` and a ``(n * W / 128, 128)`` array tile differently:
 # ``ops/paged_kv.py``, "Layouts"); ``(max_pages + 1, page_tokens, n_head,
-# d_head) f16`` for a raw one. The last row is the masked commit's scratch.
+# d_head) f16`` for a raw one. The last row is scratch: a padded slot of the
+# ``commit`` program and a prefill's last page that is a tail write there.
 #
 # The GPT-2 adapter: explicit-parameter forward passes over the module's
 # own parameter tree (models/gpt2.py) — decode against the paged cache
@@ -579,33 +593,37 @@ def _build_programs(server) -> SimpleNamespace:
             nxt = jnp.concatenate([nxt, counts.astype(jnp.int32)])
         return out, nxt
 
-    def commit(state, commit_mask, page_ids):
-        """Promote full tails into pool pages: quantize every lane's
-        tail rows, scatter only the committing lanes' rows (others land
-        in the scratch row — pools carry ``max_pages + 1`` rows so the
-        masked scatter needs no dynamic shapes)."""
-        b = commit_mask.shape[0]
-        ids = jnp.where(commit_mask, page_ids, sv.max_pages)
+    def commit(state, lanes, page_ids):
+        """Promote the full tails of ``lanes (K,)`` into pool pages
+        ``page_ids (K,)``, ``K = ServeConfig.commit_lanes``: the K lanes'
+        tail rows alone are gathered, reshaped, quantized and scattered, a
+        layer and a stream at a time, and their ``page_table`` slot,
+        ``n_pages`` and ``tail_len`` written by scatter. Tails fill at
+        ``max_batch / page_tokens`` a step, so a program over every lane's
+        tail would throw nearly all of its work away. A slot the
+        caller has no tail for names any valid lane and the scratch row
+        (``max_pages``; pools carry ``max_pages + 1`` rows): its rows land
+        there and its lane's counts are left as they are, so one program
+        of one width serves any number of full tails."""
+        k = lanes.shape[0]
         out = dict(state)
         out["pools"] = tuple(
             {
                 name: paged_kv.commit_page_rows(
-                    state["pools"][layer][name], ids,
-                    state[f"tail_{name}"][layer].reshape(b, -1), spec,
+                    state["pools"][layer][name], page_ids,
+                    state[f"tail_{name}"][layer][lanes].reshape(k, -1), spec,
                 )
                 for name, spec in streams[layer]
             }
             for layer in range(n_layer)
         )
-        p_iota = jax.lax.broadcasted_iota(
-            jnp.int32, state["page_table"].shape, 1
-        )
-        slot = (p_iota == state["n_pages"][:, None]) & commit_mask[:, None]
-        out["page_table"] = jnp.where(
-            slot, page_ids[:, None], state["page_table"]
-        )
-        out["n_pages"] = state["n_pages"] + commit_mask.astype(jnp.int32)
-        out["tail_len"] = jnp.where(commit_mask, 0, state["tail_len"])
+        # Out of bounds for a padded slot: the scatters below drop it.
+        at = jnp.where(page_ids < sv.max_pages, lanes, sv.max_batch)
+        out["page_table"] = state["page_table"].at[
+            at, state["n_pages"][lanes]
+        ].set(page_ids, mode="drop")
+        out["n_pages"] = state["n_pages"].at[at].add(1, mode="drop")
+        out["tail_len"] = state["tail_len"].at[at].set(0, mode="drop")
         return out
 
     def ingest(pools, layer_rows, ids):
@@ -867,7 +885,8 @@ class ContinuousBatchScheduler:
         b = sv.max_batch
         pools = tuple(
             {
-                # +1 row: the masked-commit scratch row (see commit()).
+                # +1 row: scratch, where a padded slot of commit() and a
+                # prefill's last page that is a tail write; never read.
                 name: paged_kv.empty_pool(sv.max_pages + 1, spec)
                 for name, spec in layer
             }
@@ -1567,15 +1586,18 @@ class ContinuousBatchScheduler:
         """Promote full tails into pool pages, so that every lane has
         tail room for the step dispatched next: which tails are full is
         the host's own count, nothing is read from the device. A lane the
-        pool has no page for is evicted back to the queue."""
+        pool has no page for is evicted back to the queue. The ``commit``
+        program takes ``ServeConfig.commit_lanes`` lanes by index, so the
+        device quantizes the tails that filled and not every lane's; when
+        more are full at once (the start of a run, a burst of equal
+        prompts) it is dispatched again over the donated state for the
+        next few, and every full tail is committed before this returns."""
         sv = self.server.serve
         full = [i for i, r in enumerate(self._lanes)
                 if r is not None and self._tail_len[i] >= sv.page_tokens]
         if not full:
             return
-        mask = np.zeros((sv.max_batch,), bool)
-        pids = np.zeros((sv.max_batch,), np.int32)
-        committed = []
+        committed, pids = [], []
         for lane in full:
             req = self._lanes[lane]
             pid = self.cache.alloc(req.id)
@@ -1590,9 +1612,8 @@ class ContinuousBatchScheduler:
                 self._waiting.append(req)
                 self._vacate(lane)
                 continue
-            mask[lane] = True
-            pids[lane] = pid
             committed.append(lane)
+            pids.append(pid)
         self._release_lanes()
         if not committed:
             return
@@ -1612,9 +1633,19 @@ class ContinuousBatchScheduler:
                         self.server.layer_name(layer), spec,
                         rows, already_host=True,
                     )
-        self._state = self._prog.commit(
-            self._state, jnp.asarray(mask), jnp.asarray(pids)
-        )
+        k = sv.commit_lanes
+        for at in range(0, len(committed), k):
+            lanes, ids = committed[at:at + k], pids[at:at + k]
+            pad = k - len(lanes)  # slots left over go to the scratch row
+            self._state = self._prog.commit(
+                self._state,
+                np.asarray(lanes + lanes[:1] * pad, np.int32),
+                np.asarray(ids + [sv.max_pages] * pad, np.int32),
+            )
+        calls = -(-len(committed) // k)
+        metrics.add("cgx.serve.commit.calls", float(calls))
+        metrics.add("cgx.serve.commit.rows", float(calls * k))
+        metrics.add("cgx.serve.commit.lanes", float(len(committed)))
         self._tail_len[committed] = 0
         self._note_pages(len(committed))
         metrics.add(
