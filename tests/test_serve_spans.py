@@ -8,7 +8,10 @@ that waits for it. Since ISSUE 32 the two lie in different phases of the tick
 (the dispatch in ``_admit``, the read after the decode step is queued), so
 ``serve.prefill.local``, which spans both, is a histogram and a timeline
 record closed at the read, with no annotation of its own; and the counters of
-what the tick read, queued ahead and dropped are checked here too.
+what the tick read, queued ahead and dropped are checked here too. ISSUE 35
+cut the tick where the host stops (a span at every dispatch and every
+blocking read) and reads three accounts from the cuts: the tick's (with the
+stall record), the first token's, and the unfed device's.
 
 CPU, the tiny model: counts, containment and nesting are what a CPU run can
 say; the times themselves are read on the chip (PERF.md).
@@ -19,6 +22,10 @@ from __future__ import annotations
 import gc
 import glob
 import json
+import logging
+import re
+import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -46,9 +53,12 @@ GEN = 5
 PREFILL_PHASES = ("prefill_forward_s", "prefill_first_token_s")
 PER_ADMISSION = (
     "prefill_s", *PREFILL_PHASES, "admit_lane_s", "queue_wait_s",
-    "ready_wait_s",
+    "ready_wait_s", "ttft_behind_s",
 )
-PER_DECODE_STEP = ("decode_prepare_s", "decode_step_s", "decode_emit_s")
+# ``dispatch_step_s`` counts steps dispatched and ``wait_step_s`` steps read:
+# the same number once a run has read every step it queued.
+PER_DECODE_STEP = ("decode_prepare_s", "decode_step_s", "decode_emit_s",
+                   "dispatch_step_s", "wait_step_s")
 # Span -> the span that holds it (names as the timeline has them; the
 # profiler's trace has them under ``cgx.``).
 PARENT = {
@@ -59,6 +69,11 @@ PARENT = {
     "serve.decode.prepare": "serve.step",
     "serve.decode_step": "serve.step",
     "serve.decode.emit": "serve.step",
+    # the cuts of ISSUE 35: the fresh commit lies in serve.decode.prepare,
+    # the run-ahead's, both step dispatches and the wait in serve.decode_step
+    "serve.dispatch.step": "serve.step",
+    "serve.dispatch.commit": "serve.step",
+    "serve.wait.step": "serve.step",
 }
 ANNOTATED_REQUEST_SPANS = [n for n in PARENT
                            if "prefill" in n or "admit" in n]
@@ -139,7 +154,83 @@ def test_waits_and_spans_decompose_ttft(server):
     whole = total("queue_wait_s", "prefill_s")
     assert whole <= ttft <= whole + 1e-3
     assert total("prefill_forward_s", "ready_wait_s", "admit_lane_s",
-                 "prefill_first_token_s") <= total("prefill_s")
+                 "ttft_behind_s", "prefill_first_token_s"
+                 ) <= total("prefill_s")
+
+
+def _slow_step_dispatch(sched, seconds):
+    """Every ``decode_step`` dispatch of ``sched`` takes ``seconds`` more
+    (its programs are the module's cached ones: a copy, not a patch)."""
+    prog = sched._prog
+
+    def decode_step(params, state):
+        time.sleep(seconds)
+        return prog.decode_step(params, state)
+
+    sched._prog = SimpleNamespace(**{**vars(prog),
+                                     "decode_step": decode_step})
+
+
+def _served_with_timeline(server, run_dir, sizes, slow_s):
+    """Serve ``sizes`` with every step dispatch slowed; returns the
+    requests, each one's TTFT parts in seconds from its own spans' fields
+    and durations in the timeline, and the steps queued ahead."""
+    timeline.reset()
+    timeline.set_rank(0)
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    _slow_step_dispatch(sched, slow_s)
+    reqs = _requests(server, sizes, tag=run_dir.name)
+    _tick_until_done(sched, reqs)
+    timeline.flush()
+    spans = [e for e in map(json.loads, open(run_dir / "spans-rank0.jsonl"))
+             if e.get("kind") == "span" and "req" in e]
+    parts = {}
+    for r in reqs:
+        mine = {e["name"]: e for e in spans if e["req"] == r.id}
+        local, lane = mine["serve.prefill.local"], mine["serve.admit_lane"]
+        read = mine["serve.prefill.first_token"]
+        parts[r.id] = {
+            "queue_wait": local["queue_wait_ms"] / 1e3,
+            "prefill_forward": mine["serve.prefill.forward"]["dur_s"],
+            "ready_wait": lane["ready_wait_ms"] / 1e3,
+            "admit_lane": lane["dur_s"],
+            "behind": read["behind_ms"] / 1e3,
+            "first_token": read["dur_s"],
+        }
+    return reqs, parts, metrics.get("cgx.serve.decode.ahead")
+
+
+def test_every_requests_ttft_is_its_six_parts(server, tmp_path, monkeypatch):
+    """TTFT = queue wait + prefill dispatch + ready wait + lane write +
+    what stood behind the lane write + the read, for every request, from
+    the spans' own fields (``behind_ms`` on ``serve.prefill.first_token``).
+    ``behind`` holds the step dispatches between a lane write and the read
+    of its first token: one in a tick that queued no step ahead (a lone
+    request, three lanes free), two in a tick that did (the fourth of four
+    long answers on four lanes)."""
+    _serve(server)  # compile outside the timed stretch
+    slow = 0.05
+    runs = {}
+    for name, sizes in (("alone", [(5, 4)]),
+                        ("full", [(5, 12), (9, 12), (12, 12), (7, 12)])):
+        run_dir = tmp_path / name
+        run_dir.mkdir()
+        monkeypatch.setenv("CGX_METRICS_DIR", str(run_dir))
+        runs[name] = _served_with_timeline(server, run_dir, sizes, slow)
+    for reqs, parts, _ in runs.values():
+        for r in reqs:
+            ttft = r.first_token_at - r.submitted_at
+            whole = sum(parts[r.id].values())
+            # the parts leave out the few statements between them
+            assert whole <= ttft + 1e-5, (r.id, parts[r.id])
+            assert ttft - whole <= max(2e-3, 0.05 * ttft), (r.id, parts[r.id])
+    (lone,), lone_parts, lone_ahead = runs["alone"]
+    _, full_parts, full_ahead = runs["full"]
+    assert lone_ahead == 0.0 and full_ahead > 0.0
+    behind_alone = lone_parts[lone.id]["behind"]
+    behind_ahead = max(p["behind"] for p in full_parts.values())
+    assert slow <= behind_alone < 2 * slow <= behind_ahead
 
 
 def test_request_spans_carry_req_in_the_timeline(server, tmp_path,
@@ -438,6 +529,186 @@ def test_a_first_token_whose_read_raises_fails_its_request_alone(server):
     assert metrics.get("span.serve.prefill.local.errors") == 1.0
     assert sched.cache.free_pages == server.serve.max_pages
     assert sched._lanes == [None] * server.serve.max_batch
+
+
+# ---------------------------------------------------------------------------
+# The tick cut where the host stops, and its three accounts (ISSUE 35).
+# ---------------------------------------------------------------------------
+
+
+def test_commit_dispatch_is_timed_once_a_tick_that_committed(server):
+    """``dispatch_commit_s`` has one sample for every call of
+    ``_commit_full_tails`` that promoted a tail, the fresh step's (inside
+    ``serve.decode.prepare``) and the run-ahead's alike, and none for a
+    call that found no tail full."""
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    commit = sched._commit_full_tails
+    calls = {"all": 0, "committed": 0}
+
+    def counted():
+        before = metrics.get("cgx.serve.commit.lanes")
+        commit()
+        calls["all"] += 1
+        calls["committed"] += metrics.get("cgx.serve.commit.lanes") > before
+
+    sched._commit_full_tails = counted
+    reqs = _requests(server, [(5, 40), (9, 44), (12, 48), (7, 50), (11, 3)])
+    _tick_until_done(sched, reqs)
+    assert metrics.get("cgx.serve.decode.ahead") > 0  # both kinds ran
+    assert 0 < calls["committed"] < calls["all"]
+    assert metrics.get("cgx.serve.dispatch_commit_s") == calls["committed"]
+    steps = metrics.get("cgx.serve.decode_steps")
+    assert metrics.get("cgx.serve.dispatch_step_s") == steps
+    assert metrics.get("cgx.serve.wait_step_s") == steps
+    # every blocking copy is one of the two waits
+    assert metrics.get("cgx.serve.host_reads") == steps + metrics.get(
+        "cgx.serve.prefill_first_token_s")
+
+
+def test_ticks_and_the_time_between_them_add_up_to_the_loop(server):
+    """``step_s.sum + between_steps_s.sum`` is the wall time of the loop
+    that calls ``step()``: the divisor of every share of the tick."""
+    _serve(server)  # compile outside the timed stretch
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    for r in _requests(server, [(5, 40), (9, 44), (12, 48), (7, 50)]):
+        sched.submit(r)
+    start = time.perf_counter()
+    assert sched.run(deadline_s=300.0)
+    wall = time.perf_counter() - start
+    found = metrics.snapshot("cgx.serve.")
+    ticks = found["cgx.serve.step_s.count"]
+    assert found["cgx.serve.between_steps_s.count"] == ticks - 1
+    summed = (found["cgx.serve.step_s.sum"]
+              + found["cgx.serve.between_steps_s.sum"])
+    assert summed <= wall and wall - summed <= 0.02 * wall
+
+
+def test_device_unfed_is_observed_once_a_tick_that_leaves_nothing_queued(
+        server):
+    """While every step is queued ahead a read never leaves the device
+    with nothing to run, and ``device_unfed_s`` observes nothing; once a
+    lane finishes every tick, each tick's last read does, and the next
+    tick's first dispatch closes the gap: one sample a tick."""
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    seen = {"ahead": 0.0}
+    unfed_while_ahead = []
+
+    def each_tick():
+        ahead = metrics.get("cgx.serve.decode.ahead")
+        if ahead > seen["ahead"]:
+            unfed_while_ahead.append(
+                metrics.get("cgx.serve.device_unfed_s"))
+        seen["ahead"] = ahead
+
+    _tick_until_done(
+        sched, _requests(server, [(5, 40), (9, 44), (12, 48), (7, 50)]),
+        each_tick)
+    assert len(unfed_while_ahead) == 38 and not any(unfed_while_ahead)
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    _tick_until_done(sched, _requests(server, [(5 + i, 2) for i in range(9)]))
+    steps = metrics.get("cgx.serve.decode_steps")
+    assert metrics.get("cgx.serve.decode.ahead") == 0.0 and steps > 1
+    # the last tick's gap has no dispatch to end it
+    assert metrics.get("cgx.serve.device_unfed_s") == steps - 1
+
+
+class _Lines(logging.Handler):
+    """The package logger's warning lines (it does not propagate)."""
+
+    def __init__(self):
+        super().__init__(logging.WARNING)
+        self.lines = []
+
+    def emit(self, record):
+        self.lines.append(record.getMessage())
+
+
+@pytest.fixture
+def stall_lines():
+    handler = _Lines()
+    logger = logging.getLogger("torch_cgx_tpu")
+    logger.addHandler(handler)
+    yield handler.lines
+    logger.removeHandler(handler)
+
+
+def _largest_item(line):
+    """The phase a stall line gives most of the tick to."""
+    items = re.findall(r"(\S+)=([\d.]+)", line.split("|")[0])
+    return max(items, key=lambda item: float(item[1]))[0]
+
+
+def test_a_stall_is_counted_once_and_its_line_names_the_phase(
+        server, stall_lines, monkeypatch):
+    """Steady ticks record no stall. A sleep inside the read of a step's
+    tokens gives one, whose line puts the time under ``wait.step``; a
+    sleep between two ``step()`` calls gives one under ``between_steps``:
+    the caller was away, so neither ``stall_s`` (the loop's own slow
+    ticks) nor ``between_steps_s`` (its turn-arounds) observes that gap.
+    A scheduler's first 32 ticks record nothing, whatever they take."""
+    from torch_cgx_tpu.serving import scheduler as sched_mod
+
+    _serve(server)  # compile outside the timed stretch
+    armed = []
+
+    class SleepyNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(x, *args, **kwargs):
+            if armed and isinstance(x, jax.Array):
+                time.sleep(armed.pop())
+            return np.asarray(x, *args, **kwargs)
+
+    monkeypatch.setattr(sched_mod, "np", SleepyNumpy())
+    metrics.reset()
+    sched = ContinuousBatchScheduler(server)
+    (req,) = _requests(server, [(5, 56)])
+    sched.submit(req)
+    stalls = lambda: metrics.get("cgx.serve.stalls")
+    while sched.outstanding():
+        if sched._ticks == 3:
+            armed.append(0.6)  # inside the warm-up: recorded nowhere
+        if sched._ticks == 40:
+            assert stalls() == 0.0 and stall_lines == []
+            armed.append(0.6)
+        if sched._ticks == 48:
+            assert stalls() == 1.0
+            time.sleep(0.6)
+        sched.step()
+        assert sched._ticks < 1000, "serving run wedged"
+    assert len(req.output) == 56
+    assert stalls() == 2.0 and metrics.get("cgx.serve.stall_s") == 1.0
+    assert metrics.histogram_stats("cgx.serve.stall_s")["min"] >= 0.6
+    gaps = metrics.histogram_stats("cgx.serve.between_steps_s")
+    assert gaps["count"] == sched._ticks - 2 and gaps["max"] < 0.5
+    in_read, between = stall_lines
+    assert "stalled tick 41" in in_read and "stalled tick 49" in between
+    assert _largest_item(in_read) == "wait.step"
+    assert _largest_item(between) == "between_steps"
+
+
+def test_compiles_count_a_program_rebuild_and_no_steady_tick(server):
+    """``cgx.serve.compiles``: JAX's own count of the programs it built.
+    Serving the same shapes again builds none; after the program cache is
+    dropped the next scheduler's programs are built anew and counted."""
+    from torch_cgx_tpu.serving import scheduler as sched_mod
+
+    _serve(server)
+    warm = metrics.get("cgx.serve.compiles")
+    _serve(server)
+    assert metrics.get("cgx.serve.compiles") == warm
+    sched_mod.invalidate_decode_cache("test")
+    _serve(server)
+    rebuilt = metrics.get("cgx.serve.compiles") - warm
+    assert rebuilt >= 3  # prefill_pages, admit_lane, decode_step at least
+    assert metrics.get("cgx.serve.compile_s") == metrics.get(
+        "cgx.serve.compiles")
 
 
 # ---------------------------------------------------------------------------

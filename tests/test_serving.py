@@ -220,8 +220,10 @@ def test_allocator_invalidate_bumps_generation():
     cache = kv_mod.PagedKvCache(max_pages=4, page_tokens=4)
     cache.alloc("s")
     gen = cache.generation
+    before = metrics.get("cgx.serve.cache_invalidations")
     kv_mod.invalidate_page_tables("test")
     assert cache.generation == gen + 1
+    assert metrics.get("cgx.serve.cache_invalidations") > before
     assert not cache.has_seq("s")
     assert cache.free_pages == 4
 
@@ -326,6 +328,7 @@ def test_remote_prefill_matches_local(model_setup, monkeypatch):
     ]
     for r in reqs:
         sched.submit(r, remote=True)
+    before = metrics.snapshot("cgx.serve.")
     t = threading.Thread(
         target=lambda: [worker.serve(r.id, r.tokens) for r in reqs]
     )
@@ -335,6 +338,13 @@ def test_remote_prefill_matches_local(model_setup, monkeypatch):
     worker.stop()
     assert ok
     assert metrics.get("cgx.serve.prefill_failovers") == 0
+    after = metrics.snapshot("cgx.serve.")
+    grown = lambda name: after.get(f"cgx.serve.{name}", 0.0) - before.get(
+        f"cgx.serve.{name}", 0.0)
+    # the wire lost nothing: every frame the worker shipped, decode polled
+    assert grown("frames_shipped") > 0 and grown("frames_lost") == 0
+    assert grown("frames_received") == grown("frames_shipped")
+    assert grown("ingest_s.count") == len(reqs)  # a span a stream
     local = _run_local(cfg, params, prompts, gen=8)
     assert [r.output for r in reqs] == local
 
@@ -576,8 +586,12 @@ def test_slo_controller_drops_and_recovers_bits(monkeypatch):
     # violate: TTFT p90 far over target
     for _ in range(20):
         metrics.observe("cgx.serve.ttft_ms", 400.0)
+    counted = [metrics.get("cgx.serve.slo_violations"),
+               metrics.get("cgx.serve.slo_updates")]
     ctl.update()
     assert ctl.budget == 7
+    assert metrics.get("cgx.serve.slo_violations") == counted[0] + 1
+    assert metrics.get("cgx.serve.slo_updates") == counted[1] + 1
     cc = kv_mod.resolve_kv_config("layer_0")
     assert cc is not None and cc.bits == 7
     v0 = cfg_mod.registry_version()
@@ -1197,6 +1211,8 @@ def test_sender_retry_keeps_seq_dense():
             super().set(k, v)
 
     store = FlakyStore()
+    errors = metrics.get("cgx.serve.ship_errors")
+    lost = metrics.get("cgx.serve.frames_lost")
     sender = KvPageSender(store, "s0", depth=4)
     recv = KvPageReceiver(store)
     recv.add_stream("s0")
@@ -1212,6 +1228,9 @@ def test_sender_retry_keeps_seq_dense():
     sender.stop()
     assert len(got) == 2, "retried frame never became fetchable"
     assert recv.complete("s0")
+    assert metrics.get("cgx.serve.ship_errors") == errors + 1
+    assert metrics.get("cgx.serve.frames_lost") == lost
+    assert metrics.get("cgx.serve.send_backlog") == 0  # the queue drained
 
 
 def test_tps_only_slo_recovers(monkeypatch):

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import threading
 from collections import deque
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence, Tuple
 
 # Reservoir depth per histogram: quantiles describe the *recent* window
 # (the operationally interesting one — a 10-minute-old stall should not
@@ -161,6 +161,15 @@ class Metrics:
         with self._lock:
             h = self._histograms.get(name)
             return h.stats() if h is not None else None
+
+    def sums(self, names: Sequence[str]) -> Tuple[float, ...]:
+        """The ``.sum`` of each named histogram (0.0 for one nothing has
+        observed yet), under one lock: what a caller reads at the two ends
+        of a stretch to say where its time went, where a ``snapshot`` would
+        sort every reservoir of the registry."""
+        with self._lock:
+            found = map(self._histograms.get, names)
+            return tuple(0.0 if h is None else h.sum for h in found)
 
     def snapshot(self, prefix: str = "") -> Dict[str, float]:
         """Flat view of every instrument, optionally filtered by name

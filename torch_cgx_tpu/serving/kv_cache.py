@@ -207,7 +207,6 @@ class PagedKvCache:
             self._seqs[dst_seq] = _SeqEntry(
                 pages=list(src.pages), tokens=src.tokens
             )
-            metrics.add("cgx.serve.seq_forks")
             # Fork changes dedup truth without touching the free list —
             # the one mutator the old pool_free-only refresh missed.
             self._publish_gauges_locked()
@@ -246,7 +245,6 @@ class PagedKvCache:
                     freed += 1
                 else:
                     self._refs[pid] = n - 1
-            metrics.add("cgx.serve.pages_freed", float(freed))
             self._publish_gauges_locked()
             memledger.note_release("serve.kv_pool", n=freed)
             return freed

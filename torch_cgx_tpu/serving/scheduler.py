@@ -48,6 +48,19 @@ beyond the one being read). With ``eos_token`` set a lane can finish
 unannounced under a step so queued: the token that step decodes for it is
 dropped (``cgx.serve.decode.discarded_tokens``).
 
+The tick is cut where the host stops: a ``trace_span`` at every dispatch
+(``serve.prefill.forward``, ``serve.admit_lane``, ``serve.dispatch.commit``,
+``serve.dispatch.step``) and at every blocking read
+(``serve.prefill.first_token``, ``serve.wait.step``). Three accounts are read
+from the cuts (docs/OBSERVABILITY.md): the tick's (with the caller's time
+between two ticks it adds up to the loop's wall time, and a tick far over the
+running mean leaves one warning line that says where its time went:
+:meth:`ContinuousBatchScheduler._close_tick`), the first token's (what was
+dispatched between a request's lane write and the read of its token,
+``cgx.serve.ttft_behind_s``) and the unfed device's (from a read that leaves
+nothing queued to the next dispatch that carries work,
+``cgx.serve.device_unfed_s``).
+
 Requests arrive with their KV either computed here (local prefill — the
 colocated mode, also the FAILOVER path) or shipped by a disaggregated
 prefill worker over the :mod:`.transport` counter streams; decode polls
@@ -89,7 +102,12 @@ from ..ops import codec_host
 from ..ops import paged_kv
 from ..observability import memledger, timeline
 from ..utils.logging import get_logger, metrics
-from ..utils.tracing import install_gc_hook, observe_span, trace_span
+from ..utils.tracing import (
+    install_compile_listener,
+    install_gc_hook,
+    observe_span,
+    trace_span,
+)
 from ..wire import dispatch as wire_dispatch
 from . import kv_cache as kv_mod
 from . import transport as tp
@@ -102,6 +120,30 @@ _TPS_EWMA = 0.2  # tokens/s gauge smoothing
 # burst, and each one queued holds its prefill's outputs (a lane's tails and
 # recurrent state) on the device from its dispatch, whenever it runs.
 _ADMISSIONS_IN_FLIGHT = 2
+# A stall: a tick, with the caller's time before it, longer than both; a
+# scheduler's first ticks record none (its programs compile there, and the
+# mean they are held against, which forgets at the same rate, is not yet one).
+_STALL_MIN_S = 0.5
+_STALL_OVER_MEAN = 8.0
+_STALL_WARMUP_TICKS = 32
+# The tick's account: where a stalled tick's time went, by the histogram
+# under ``cgx.serve.`` whose ``.sum`` is read at the tick's two ends. The
+# tick itself, then the spans that lie side by side inside it, then what
+# can lie inside any of those.
+_TICK_SPANS = {
+    "dispatch.step": "dispatch_step_s",
+    "dispatch.commit": "dispatch_commit_s",
+    "prefill.forward": "prefill_forward_s",
+    "admit_lane": "admit_lane_s",
+    "wait.step": "wait_step_s",
+    "first_token": "prefill_first_token_s",
+    "emit": "decode_emit_s",
+}
+_TICK_WITHIN = {"gc": "host_gc_s", "compile": "compile_s"}
+_TICK_ACCOUNT = tuple(
+    f"cgx.serve.{hist}"
+    for hist in ("step_s", *_TICK_SPANS.values(), *_TICK_WITHIN.values())
+)
 # The per-lane bookkeeping of the decode state, and what ``release_lanes``
 # resets each entry of a finished or evicted lane to.
 _LANE_RESET = {"active": False, "n_pages": 0, "tail_len": 0, "page_table": -1}
@@ -546,9 +588,7 @@ def _decode_program(server) -> SimpleNamespace:
     prog = _PROGRAM_CACHE.get(key)
     if prog is not None:
         _PROGRAM_CACHE.move_to_end(key)
-        metrics.add("cgx.serve.program_cache_hits")
         return prog
-    metrics.add("cgx.serve.program_cache_misses")
     prog = _build_programs(server)
     _PROGRAM_CACHE[key] = prog
     while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
@@ -805,6 +845,9 @@ class _Ready:
     # ``CGX_QERR_STATS``: the rows ``prefill_pages`` quantized, by layer,
     # on the device until that read.
     qerr_rows: Dict[int, jax.Array] = dataclasses.field(default_factory=dict)
+    # The return of its ``admit_lane`` dispatch (``time.perf_counter()``):
+    # ``cgx.serve.ttft_behind_s`` counts from here to the read.
+    admitted_at: float = 0.0
 
 
 @dataclasses.dataclass
@@ -847,6 +890,7 @@ class ContinuousBatchScheduler:
         # owner — arm it here too (no-op when CGX_MEMLEDGER is unset).
         memledger.maybe_start()
         self._gc_pauses = install_gc_hook()
+        install_compile_listener()
         self.cache = kv_mod.PagedKvCache(sv.max_pages, sv.page_tokens)
         self._cache_gen = self.cache.generation
         self._prog = _decode_program(server)
@@ -876,6 +920,15 @@ class ContinuousBatchScheduler:
         self._tokens_total = 0
         self._last_step_t: Optional[float] = None
         self._tps = 0.0
+        # The tick's clock (``time.perf_counter()``): the end of the last
+        # ``step()``; the ticks so far and the running mean of a tick with
+        # the gap before it (the stall record's yardstick); and the instant
+        # a blocking read left the device with nothing queued, until the
+        # next program that carries work is dispatched.
+        self._step_end: Optional[float] = None
+        self._ticks = 0
+        self._tick_mean = 0.0
+        self._unfed_since: Optional[float] = None
 
     # -- state plumbing ----------------------------------------------------
 
@@ -1029,7 +1082,6 @@ class ContinuousBatchScheduler:
         ship the KV stream named by ``req.id`` (requires a receiver);
         otherwise the scheduler prefills locally at admission."""
         req.submitted_at = time.monotonic()
-        metrics.add("cgx.serve.requests_submitted")
         # Request attribution anchor (ISSUE 17): the critical-path
         # engine's TTFT decomposition starts every request at this
         # instant and joins the rest of the flow by ``req``.
@@ -1068,15 +1120,80 @@ class ContinuousBatchScheduler:
         decode step for every active lane, then read what they produced
         (first tokens, the step's tokens) and evict completed lanes.
         Returns whether anything progressed (the run loop's idle-sleep
-        signal). Waits for nothing but the device's own programs."""
+        signal). Waits for nothing but the device's own programs.
+
+        Around the tick: ``cgx.serve.between_steps_s`` observes the
+        caller's time since the last ``step()`` returned (with
+        ``cgx.serve.step_s`` it adds up to the loop's wall time), and
+        :meth:`_close_tick` holds the two against their running mean. A
+        gap that is a stall by itself is the caller's absence, not its
+        turn-around: the stall record has it, and the loop's accounts
+        (the time between steps, the unfed device) leave it out."""
+        before = metrics.sums(_TICK_ACCOUNT)
+        between, away = 0.0, False
+        if self._step_end is not None:
+            between = time.perf_counter() - self._step_end
+            away = self._is_stall(between)
+            if away:
+                self._unfed_since = None  # nobody was there to feed it
+            else:
+                metrics.observe("cgx.serve.between_steps_s", between)
         with trace_span("serve.step"):
-            self._gc_pauses.publish()
             self._maybe_rebuild()
             progressed = self._drain_transport()
             progressed |= self._failover_stalled()
             progressed |= self._admit()
             progressed |= self._decode()
+        self._step_end = time.perf_counter()
+        self._close_tick(between, away, before)
         return progressed
+
+    def _is_stall(self, seconds: float) -> bool:
+        """Whether the tick about to end (or the gap before it) is one:
+        longer than both constants allow, past the scheduler's first
+        ticks."""
+        return (self._ticks >= _STALL_WARMUP_TICKS
+                and seconds >= _STALL_MIN_S
+                and seconds >= _STALL_OVER_MEAN * self._tick_mean)
+
+    def _close_tick(self, between: float, away: bool,
+                    before: Tuple[float, ...]) -> None:
+        """The stall record. A tick, with the ``between`` seconds the
+        caller took before it, longer than ``_STALL_MIN_S`` and than
+        ``_STALL_OVER_MEAN`` times the running mean of the ticks before
+        it, counts in ``cgx.serve.stalls`` and leaves one warning line
+        that says where its time went: the growth of each histogram of
+        ``_TICK_ACCOUNT`` since ``before``, which ``step()`` read as the
+        tick began. ``cgx.serve.stall_s`` observes it unless the caller
+        was ``away`` (the gap alone was the stall: no tick of this loop
+        was slow). Those histograms are the process's: two schedulers
+        ticking in one process read each other's time. Full collections
+        are published here, so that a pause lands in the tick it
+        lengthened."""
+        self._gc_pauses.publish()
+        grown = [b - a for a, b in
+                 zip(before, metrics.sums(_TICK_ACCOUNT))]
+        whole = between + grown[0]
+        stalled, mean = self._is_stall(whole), self._tick_mean
+        self._ticks += 1
+        self._tick_mean += ((grown[0] if away else whole) - mean) / min(
+            self._ticks, _STALL_WARMUP_TICKS)
+        if not stalled:
+            return
+        metrics.add("cgx.serve.stalls")
+        if not away:
+            metrics.observe("cgx.serve.stall_s", whole)
+        spans = grown[1:1 + len(_TICK_SPANS)]
+        parts = [("between_steps", between), *zip(_TICK_SPANS, spans),
+                 ("other", grown[0] - sum(spans))]
+        within = zip(_TICK_WITHIN, grown[1 + len(_TICK_SPANS):])
+        log.warning(
+            "serving: stalled tick %d: %.3f s, the running mean %.4f s: %s"
+            " | inside those: %s",
+            self._ticks, whole, mean,
+            " ".join(f"{name}={v:.3f}" for name, v in parts),
+            " ".join(f"{name}={v:.3f}" for name, v in within),
+        )
 
     def run(self, *, deadline_s: float = 120.0,
             idle_sleep_s: float = 0.002) -> bool:
@@ -1307,6 +1424,7 @@ class ContinuousBatchScheduler:
                     )
                 )
                 self._state["pools"] = pools
+                self._fed()
         except BaseException:
             self._close_prefill_span((start, fields), ok=False)
             raise
@@ -1406,6 +1524,7 @@ class ContinuousBatchScheduler:
             self._left[lane] = max(req.max_new_tokens - 1, 0)
             self._unread.append((lane, ready))
             metrics.add("cgx.serve.requests_admitted")
+        ready.admitted_at = time.perf_counter()
 
     def _read_first_tokens(self, keep: int = 0) -> bool:
         """The read phase of the admissions dispatched and not read (all
@@ -1416,18 +1535,23 @@ class ContinuousBatchScheduler:
         and its TTFT here, when the host does. A first token that is all
         the request asked for (or the end of sequence) finishes it here."""
         n = max(len(self._unread) - keep, 0)
-        unread, self._unread = self._unread[:n], self._unread[n:]
-        for lane, ready in unread:
+        for _ in range(n):
+            lane, ready = self._unread.pop(0)
             req = ready.req
             first = ready.first_token
+            # What stood between its lane write and this read: the other
+            # admissions, the commits and the steps dispatched since.
+            behind = time.perf_counter() - ready.admitted_at
+            metrics.observe("cgx.serve.ttft_behind_s", behind)
             try:
                 if not isinstance(first, int):  # still on the device
                     with trace_span(
                         "serve.prefill.first_token",
                         hist="cgx.serve.prefill_first_token_s", req=req.id,
+                        behind_ms=round(behind * 1e3, 3),
                     ):
                         first = int(first)
-                    metrics.add("cgx.serve.host_reads")
+                    self._note_read()
                 for layer, rows in ready.qerr_rows.items():
                     _observe_page_qerr(
                         self.server.layer_name(layer),
@@ -1455,7 +1579,7 @@ class ContinuousBatchScheduler:
             self._emit(lane, req, first)
             self._note_tokens(1)
         self._release_lanes()  # a first token can finish its request
-        return bool(unread)
+        return bool(n)
 
     def _emit(self, lane: int, req: Request, token: int) -> None:
         """Hand a request the token the host has just read for it."""
@@ -1537,8 +1661,9 @@ class ContinuousBatchScheduler:
                 metrics.add("cgx.serve.decode.ahead")
             self._read_first_tokens()
             step = self._steps.popleft()
-            nxt = np.asarray(step.tokens)
-            metrics.add("cgx.serve.host_reads")
+            with trace_span("serve.wait.step", hist="cgx.serve.wait_step_s"):
+                nxt = np.asarray(step.tokens)
+            self._note_read()
         with trace_span("serve.decode.emit", hist="cgx.serve.decode_emit_s"):
             metrics.add("cgx.serve.decode_steps")
             # What the adapter counted this step, read with the tokens.
@@ -1573,9 +1698,13 @@ class ContinuousBatchScheduler:
         which are the lanes held here (whatever vacates a lane releases it
         before the next dispatch); a token is read for the lanes whose
         request still has one to come."""
-        self._state, tokens = self._prog.decode_step(
-            self.server.p, self._state
-        )
+        with trace_span(
+            "serve.dispatch.step", hist="cgx.serve.dispatch_step_s"
+        ):
+            self._state, tokens = self._prog.decode_step(
+                self.server.p, self._state
+            )
+            self._fed()
         held = [i for i, r in enumerate(self._lanes) if r is not None]
         self._tail_len[held] += 1
         lanes = {i: self._lanes[i] for i in held if self._left[i] > 0}
@@ -1634,14 +1763,18 @@ class ContinuousBatchScheduler:
                         rows, already_host=True,
                     )
         k = sv.commit_lanes
-        for at in range(0, len(committed), k):
-            lanes, ids = committed[at:at + k], pids[at:at + k]
-            pad = k - len(lanes)  # slots left over go to the scratch row
-            self._state = self._prog.commit(
-                self._state,
-                np.asarray(lanes + lanes[:1] * pad, np.int32),
-                np.asarray(ids + [sv.max_pages] * pad, np.int32),
-            )
+        with trace_span(
+            "serve.dispatch.commit", hist="cgx.serve.dispatch_commit_s"
+        ):
+            for at in range(0, len(committed), k):
+                lanes, ids = committed[at:at + k], pids[at:at + k]
+                pad = k - len(lanes)  # slots left over: the scratch row
+                self._state = self._prog.commit(
+                    self._state,
+                    np.asarray(lanes + lanes[:1] * pad, np.int32),
+                    np.asarray(ids + [sv.max_pages] * pad, np.int32),
+                )
+                self._fed()
         calls = -(-len(committed) // k)
         metrics.add("cgx.serve.commit.calls", float(calls))
         metrics.add("cgx.serve.commit.rows", float(calls * k))
@@ -1653,6 +1786,25 @@ class ContinuousBatchScheduler:
             float(sum(len(layer) for layer in self._prog.streams)
                   * len(committed)),
         )
+
+    def _note_read(self) -> None:
+        """A blocking copy from the device has just returned: count it,
+        and where it leaves nothing dispatched and unread the device has
+        nothing of ours to run from now until :meth:`_fed`."""
+        metrics.add("cgx.serve.host_reads")
+        if not self._steps and not self._unread:
+            self._unfed_since = time.perf_counter()
+
+    def _fed(self) -> None:
+        """A program that carries work (``prefill_pages``, ``commit``,
+        ``decode_step``; not a lane write or a release) has just been
+        dispatched: ``cgx.serve.device_unfed_s`` observes how long the
+        device had stood with nothing queued, the host's own estimate of
+        its idle gap."""
+        if self._unfed_since is not None:
+            metrics.observe("cgx.serve.device_unfed_s",
+                            time.perf_counter() - self._unfed_since)
+            self._unfed_since = None
 
     def _note_tokens(self, n: int) -> None:
         self._tokens_total += n

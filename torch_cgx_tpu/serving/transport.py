@@ -555,6 +555,5 @@ class KvPageReceiver:
                 metrics.add("cgx.serve.frames_received")
                 if st.expected is not None and st.received >= st.expected:
                     st.done = True
-                    metrics.add("cgx.serve.streams_completed")
                 out.append((stream, frame))
         return out

@@ -114,6 +114,30 @@ def install_gc_hook() -> GcPauses:
     return hook
 
 
+_compile_listener_installed = False
+
+
+def install_compile_listener() -> None:
+    """Count the process's backend compiles, as JAX reports them, into
+    ``cgx.serve.compiles`` and the histogram ``cgx.serve.compile_s``: a
+    ``jax.monitoring`` duration listener, registered on first call (JAX
+    keeps listeners for the life of the process). The event ends every
+    program build, a persistent-cache retrieval included, and fires on the
+    thread that built it, so a tick that grew the count is the tick that
+    compiled."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    _compile_listener_installed = True
+
+    def on_duration(event: str, duration: float, **_) -> None:
+        if event.endswith("backend_compile_duration"):
+            metrics.add("cgx.serve.compiles")
+            metrics.observe("cgx.serve.compile_s", duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
 def named_scope(name: str):
     """Annotation for traced (jitted) code regions — shows up in the XLA HLO
     and device profile."""
