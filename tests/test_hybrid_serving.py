@@ -358,7 +358,7 @@ def test_layers_that_name_different_streams_build(params, monkeypatch):
             assert (st[name][layer] is None) == attention
     spec = prog.specs[1]
     assert (spec.n_head, spec.d_head, spec.bits) == (2, 8, 8)
-    assert st["tail_k"][4].shape == (3, PAGE, 2, 8)
+    assert st["tail_k"][4].shape == (3, PAGE, 2 * 8)
     assert st["state_conv"][0].shape == (3, 3, cfg.d_xbc)
     assert st["state_ssm"][3].shape == (3, cfg.d_state, cfg.d_inner)
     assert st["state_ssm"][3].dtype == jnp.float32

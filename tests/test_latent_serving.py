@@ -411,8 +411,8 @@ def test_latent_streams_in_state_pools_and_key(params, monkeypatch):
     assert (c_spec.n_head, c_spec.d_head) == (1, HF["kv_lora_rank"])
     assert (kr_spec.n_head, kr_spec.d_head) == (1, HF["qk_rope_head_dim"])
     assert c_spec.bits == kr_spec.bits == 8
-    assert st["tail_c"][2].shape == (3, PAGE, 1, HF["kv_lora_rank"])
-    assert st["tail_kr"][2].shape == (3, PAGE, 1, HF["qk_rope_head_dim"])
+    assert st["tail_c"][2].shape == (3, PAGE, HF["kv_lora_rank"])
+    assert st["tail_kr"][2].shape == (3, PAGE, HF["qk_rope_head_dim"])
     key = sched_mod._program_key(server)
     assert key[0] == "mla_moe"
     gpt2 = GPT2Server(GPT2Config.tiny(), {"params": {}}, _serve())

@@ -506,7 +506,7 @@ def test_layers_that_name_different_streams_build(params, monkeypatch):
             assert (st[name][layer] is None) == attention
     spec = prog.specs[2]
     assert (spec.n_head, spec.d_head, spec.bits) == (4, 16, 8)
-    assert st["tail_k"][4].shape == (3, PAGE, 4, 16)
+    assert st["tail_k"][4].shape == (3, PAGE, 4 * 16)
     assert st["state_conv"][0].shape == (3, 3, D_QKV)
     assert st["state_gdn"][3].shape == (3, 8, D_VALUE)
     assert st["state_gdn"][3].dtype == jnp.float32

@@ -1047,8 +1047,9 @@ def test_admission_matches_hand_composition(model_setup, monkeypatch, bits,
                 paged_kv.empty_pool(server.serve.max_pages + 1, spec), ids,
                 rows, spec,
             )
-            t = np.zeros((PAGE, server.n_head, server.d_head), np.float32)
-            t[:tail] = np.asarray(cache[layer][0, n_full * PAGE: s])
+            t = np.zeros((PAGE, server.n_head * server.d_head), np.float32)
+            t[:tail] = np.asarray(
+                cache[layer][0, n_full * PAGE: s]).reshape(tail, t.shape[1])
             want_tails[kind].append(t)
         want_rows.append([
             np.asarray(a)[np.asarray(ids)] for a in jax.tree.leaves(pools)
@@ -1421,9 +1422,10 @@ def test_decode_step_holds_no_table_sized_glue(model_setup, monkeypatch):
     def old_read(state):
         pages = paged_kv.gather_dequant_pages(
             state["pools"][0]["k"], state["page_table"], spec, jnp.float32)
+        assert state["tail_k"][0].shape == (
+            sv.max_batch, sv.page_tokens, cfg.d_model)
         return jnp.concatenate(
-            [pages, state["tail_k"][0].reshape(sv.max_batch, -1,
-                                               cfg.d_model)], axis=1
+            [pages, state["tail_k"][0]], axis=1
         ).astype(cfg.dtype)
 
     old = table_sized_glue(

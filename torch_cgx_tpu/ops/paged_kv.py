@@ -271,6 +271,30 @@ def gather_dequant_pages(
     return rows.reshape(b, p * spec.page_tokens, width)
 
 
+def append_tail_rows(tail: jax.Array, tail_idx: jax.Array, fresh: jax.Array,
+                     dtype) -> Tuple[jax.Array, jax.Array]:
+    """A decode step's write into the lanes' raw tail, and the tail as the
+    attention reads it. ``tail (B, page_tokens, width) f32`` is kept as
+    those rows, a position's heads side by side as a page holds them;
+    ``fresh (B, ...)``, this token's K or V of ``width`` values a lane, goes
+    to row ``tail_idx (B,)`` of its lane as float32. Returns ``(the new
+    tail, its rows in dtype)``.
+
+    One row a lane is scattered into the donated tail, the cast fuses into
+    the attention's dots, and no program relays a tail between what is kept
+    and what is contracted (XLA may still stage a tail through its other
+    memory space: PERF.md section 5). (Kept by head, ``(B, page_tokens,
+    n_head, d_head)``, every layer of every step rewrote the whole tail
+    through a ``where`` and copied it to rows, the two tiling differently;
+    the ``where`` over rows was measured too and lost to this by 1.7-2.0
+    ms a step: PERF.md section 6, PR 36.)"""
+    b, _, width = tail.shape
+    tail = tail.at[jnp.arange(b), tail_idx].set(
+        fresh.reshape(b, width).astype(jnp.float32)
+    )
+    return tail, tail.astype(dtype)
+
+
 def commit_page_rows(pool, page_ids: jax.Array, rows: jax.Array, spec: PageSpec):
     """Functionally write ``rows (n, flat)`` payloads into pool rows
     ``page_ids (n,)`` (quantizing when the spec does) — the jitted

@@ -20,9 +20,9 @@ at the prompt's last token:
 
 ``state_streams`` states them; the scheduler carries them in the donated
 state beside pools and tails. What an attention layer's decode position does
-is the same in both (:func:`lane_masks`, :func:`attend_paged`): the token's
-``k`` and ``v`` into the raw tail, the committed pages read where they lie,
-one ``decode_attention`` over both.
+is the same in both (``scheduler.lane_masks``, :func:`attend_paged`): the
+token's ``k`` and ``v`` into the raw tail, the committed pages read where
+they lie, one ``decode_attention`` over both.
 
 Page geometry is the streams' arithmetic (``serving/latent.py`` says the
 same of its own). granite-4.0-h-micro at 256 tokens a page and bucket 512:
@@ -50,54 +50,26 @@ from ..models import granite_hybrid as gh
 from ..models import olmo_hybrid as oh
 from ..models.attention import decode_attention
 from ..models.mla_moe import _mm, rms_norm
-from ..ops import paged_kv
-from .scheduler import ServeConfig, page_specs
-
-
-def lane_masks(serve: ServeConfig, state):
-    """What every attention layer of a decode step shares: ``(onehot (B,
-    page_tokens, 1, 1)``, where this token's K and V go in the tail; ``mask_c
-    (B, pages x page_tokens)``, the committed positions; ``mask_t (B,
-    page_tokens))``, the tail's live positions, this token's among them."""
-    pt = serve.page_tokens
-    b = state["tokens"].shape[0]
-    tail_idx = jnp.minimum(state["tail_len"], pt - 1)
-    onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-        == tail_idx[:, None]
-    )[:, :, None, None]
-    committed = state["n_pages"] * pt
-    pos_c = jax.lax.broadcasted_iota(
-        jnp.int32, (b, serve.pages_per_seq * pt), 1)
-    pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-    mask_c = pos_c < committed[:, None]
-    mask_t = pos_t <= tail_idx[:, None]
-    return onehot, mask_c, mask_t
+from .scheduler import (
+    ServeConfig,
+    lane_masks,
+    layer_cache_rows,
+    page_specs,
+)
 
 
 def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
                  score_divisor):
     """One decode position of an attention layer over a lane's cache: this
-    token's ``k`` and ``v (B, 1, Hk, dh)`` written into the raw float32
-    tails, the committed pages read as ``dt`` rows where they lie
-    (``paged_kv.gather_dequant_pages``), one ``decode_attention`` of ``q (B,
-    1, H, dh)`` over pages and tail. Returns ``(o (B, H * dh), {stream: its
-    new tail})``."""
-    onehot, mask_c, mask_t = masks
-    b, pt = onehot.shape[:2]
-    width = k.shape[-2] * k.shape[-1]
-    pages, tails, new = {}, {}, {}
-    for (name, spec), fresh in zip(layer_streams, (k, v)):
-        tail = jnp.where(
-            onehot, fresh.astype(jnp.float32),
-            state[f"tail_{name}"][layer],
-        )
-        new[name] = tail
-        tails[name] = tail.reshape(b, pt, width).astype(dt)
-        pages[name] = paged_kv.gather_dequant_pages(
-            state["pools"][layer][name], state["page_table"],
-            spec, dt,
-        )
+    token's ``k`` and ``v (B, 1, Hk, dh)`` into the raw tails and the
+    committed pages read as ``dt`` rows where they lie
+    (``scheduler.layer_cache_rows``), one ``decode_attention`` of ``q (B, 1,
+    H, dh)`` over pages and tail. ``masks`` are ``scheduler.lane_masks``'.
+    Returns ``(o (B, H * dh), {stream: its new tail})``."""
+    tail_idx, mask_c, mask_t = masks
+    pages, tails, new = layer_cache_rows(
+        state, layer, layer_streams, tail_idx, (k, v), dt
+    )
     o = decode_attention(
         q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],
         mask=mask_c, tail_mask=mask_t, score_divisor=score_divisor,

@@ -30,9 +30,13 @@ import jax.numpy as jnp
 
 from ..models import mla_moe
 from ..models.mla_moe import MlaMoeConfig
-from ..ops import paged_kv
 from ..parallel import moe
-from .scheduler import ServeConfig, page_specs
+from .scheduler import (
+    ServeConfig,
+    lane_masks,
+    layer_cache_rows,
+    page_specs,
+)
 
 
 class LatentMoEServer:
@@ -119,21 +123,9 @@ class LatentMoEServer:
         layers (``load_max`` their largest) counted over the active
         lanes)``."""
         cfg, dt = self.cfg, self.cfg.dtype
-        pt = self.serve.page_tokens
-        p_dim = self.serve.pages_per_seq
         x = mla_moe.embed(cfg, self.p, state["tokens"][:, None])
         positions = state["pos"][:, None]
-        b = x.shape[0]
-        tail_idx = jnp.minimum(state["tail_len"], pt - 1)
-        onehot = (
-            jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-            == tail_idx[:, None]
-        )[:, :, None, None]
-        committed = state["n_pages"] * pt
-        pos_c = jax.lax.broadcasted_iota(jnp.int32, (b, p_dim * pt), 1)
-        pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-        mask_c = pos_c < committed[:, None]
-        mask_t = pos_t <= tail_idx[:, None]
+        tail_idx, mask_c, mask_t = lane_masks(self.serve, state)
         new_tails = {"c": [], "kr": []}
         counts = []
         for layer in range(cfg.n_layer):
@@ -142,18 +134,11 @@ class LatentMoEServer:
             q_nope, q_rope, c, k_r = mla_moe.mla_project(
                 cfg, y, pl["attn"], positions
             )
-            pages, tails = {}, {}
-            for (name, spec), new in zip(streams[layer], (c, k_r)):
-                tail = jnp.where(
-                    onehot, new[:, :, None].astype(jnp.float32),
-                    state[f"tail_{name}"][layer],
-                )
+            pages, tails, written = layer_cache_rows(
+                state, layer, streams[layer], tail_idx, (c, k_r), dt
+            )
+            for name, tail in written.items():
                 new_tails[name].append(tail)
-                tails[name] = tail[:, :, 0].astype(dt)  # cast alone
-                pages[name] = paged_kv.gather_dequant_pages(
-                    state["pools"][layer][name], state["page_table"], spec,
-                    dt,
-                )
             o = mla_moe.attend_absorbed(
                 cfg, pl["attn"], q_nope[:, 0], q_rope[:, 0], pages["c"],
                 pages["kr"], mask_c, tail=(tails["c"], tails["kr"], mask_t),

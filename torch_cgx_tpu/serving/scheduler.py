@@ -263,7 +263,9 @@ class Request:
 #                   position ``last_idx``; None in a list for a layer
 #                   without that stream)
 #   decode_forward(state, streams) -> (logits (B, V), {stream: [each
-#                   layer's new tail (B, page_tokens, n_head, d_head)]} and,
+#                   layer's new tail (B, page_tokens, n_head * d_head) f32,
+#                   rows as the attention reads them, this token's written
+#                   by ``paged_kv.append_tail_rows``]} and,
 #                   in the same dictionary, {state stream: [each layer's new
 #                   state (B, *shape)]}, None for a layer without it,
 #                   int32 vector of ``step_counters`` or None); ``state``
@@ -322,6 +324,44 @@ def _ln(x, scale, bias, eps=1e-6):
 def _dense(x, w, b, dtype):
     y = x.astype(dtype) @ w.astype(dtype)
     return y + b.astype(dtype) if b is not None else y
+
+
+def lane_masks(serve: ServeConfig, state):
+    """What every attention layer of a decode step shares: ``(tail_idx
+    (B,)``, the tail row this token's cache payload goes to; ``mask_c (B,
+    pages x page_tokens)``, the committed positions; ``mask_t (B,
+    page_tokens))``, the tail's live positions, this token's among them."""
+    pt = serve.page_tokens
+    b = state["tokens"].shape[0]
+    tail_idx = jnp.minimum(state["tail_len"], pt - 1)
+    committed = state["n_pages"] * pt
+    pos_c = jax.lax.broadcasted_iota(
+        jnp.int32, (b, serve.pages_per_seq * pt), 1)
+    pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
+    mask_c = pos_c < committed[:, None]
+    mask_t = pos_t <= tail_idx[:, None]
+    return tail_idx, mask_c, mask_t
+
+
+def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
+                     dtype):
+    """A layer's cache as its attention contracts it, at a decode position:
+    for each of the layer's streams, in order, this token's payload (the
+    matching entry of ``fresh``, ``(B, ...)`` of the stream's width) written
+    into the raw tail (``paged_kv.append_tail_rows``) and the committed
+    pages read where they lie (``paged_kv.gather_dequant_pages``). Returns
+    ``({stream: pages (B, P * page_tokens, width)}, {stream: tail rows (B,
+    page_tokens, width)}``, both in ``dtype``, ``{stream: the new float32
+    tail})``."""
+    pages, tails, new = {}, {}, {}
+    for (name, spec), value in zip(layer_streams, fresh):
+        new[name], tails[name] = paged_kv.append_tail_rows(
+            state[f"tail_{name}"][layer], tail_idx, value, dtype
+        )
+        pages[name] = paged_kv.gather_dequant_pages(
+            state["pools"][layer][name], state["page_table"], spec, dtype
+        )
+    return pages, tails, new
 
 
 class GPT2Server:
@@ -444,51 +484,24 @@ class GPT2Server:
         tail with this token's K/V appended; one softmax over both.
         Returns (logits (B, vocab), the new tails by stream, None)."""
         cfg = self.cfg
-        pt = self.serve.page_tokens
-        p_dim = self.serve.pages_per_seq
         x = self._embed(state["tokens"][:, None], state["pos"][:, None])
-        b = x.shape[0]
-        tail_idx = jnp.minimum(state["tail_len"], pt - 1)
-        onehot = (
-            jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-            == tail_idx[:, None]
-        )
-        committed = state["n_pages"] * pt
-        pos_c = jax.lax.broadcasted_iota(jnp.int32, (b, p_dim * pt), 1)
-        mask_c = pos_c < committed[:, None]
-        pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-        mask_t = pos_t <= tail_idx[:, None]
-        new_tk: List[jax.Array] = []
-        new_tv: List[jax.Array] = []
-
-        def tail_rows(t):  # (B, pt, H, Dh) f32 -> (B, pt, Dm), cast alone
-            return t.reshape(b, pt, cfg.d_model).astype(cfg.dtype)
-
+        tail_idx, mask_c, mask_t = lane_masks(self.serve, state)
+        new: Dict[str, List[jax.Array]] = {"k": [], "v": []}
         for layer in range(cfg.n_layer):
             pl = self.p[f"h_{layer}"]
             q, k, v = self._qkv(x, pl)  # (B, H, 1, Dh)
-            k_new = k[:, :, 0][:, None]  # (B, H, Dh) -> (B, 1, H, Dh)
-            v_new = v[:, :, 0][:, None]
-            sel = onehot[:, :, None, None]
-            tk = jnp.where(sel, k_new.astype(jnp.float32),
-                           state["tail_k"][layer])
-            tv = jnp.where(sel, v_new.astype(jnp.float32),
-                           state["tail_v"][layer])
-            new_tk.append(tk)
-            new_tv.append(tv)
-            pool, spec = state["pools"][layer], streams[layer][0][1]
-            kc = paged_kv.gather_dequant_pages(
-                pool["k"], state["page_table"], spec, cfg.dtype
+            pages, tails, written = layer_cache_rows(
+                state, layer, streams[layer], tail_idx,
+                (k[:, :, 0], v[:, :, 0]), cfg.dtype,
             )
-            vc = paged_kv.gather_dequant_pages(
-                pool["v"], state["page_table"], spec, cfg.dtype
-            )
+            for name, tail in written.items():
+                new[name].append(tail)
             o = decode_attention(
-                q[:, :, 0], kc, vc, tail_rows(tk), tail_rows(tv),
+                q[:, :, 0], pages["k"], pages["v"], tails["k"], tails["v"],
                 mask=mask_c, tail_mask=mask_t,
             )
             x = self._block_tail(x, pl, o[:, None])
-        return self._logits(x)[:, -1], {"k": new_tk, "v": new_tv}, None
+        return self._logits(x)[:, -1], new, None
 
 
 # ---------------------------------------------------------------------------
@@ -636,8 +649,9 @@ def _build_programs(server) -> SimpleNamespace:
     def commit(state, lanes, page_ids):
         """Promote the full tails of ``lanes (K,)`` into pool pages
         ``page_ids (K,)``, ``K = ServeConfig.commit_lanes``: the K lanes'
-        tail rows alone are gathered, reshaped, quantized and scattered, a
-        layer and a stream at a time, and their ``page_table`` slot,
+        tails alone are gathered (rows as they are kept, flattened to ``(K,
+        page_tokens * width)`` payloads), quantized and scattered, a layer
+        and a stream at a time, and their ``page_table`` slot,
         ``n_pages`` and ``tail_len`` written by scatter. Tails fill at
         ``max_batch / page_tokens`` a step, so a program over every lane's
         tail would throw nearly all of its work away. A slot the
@@ -700,7 +714,7 @@ def _build_programs(server) -> SimpleNamespace:
         every page of every layer's streams through ``commit_page_rows``
         into the donated pools at ``ids (padded pages,)``, and the last
         page's first ``tail_len`` rows as the lane's tails ``{stream: (L,
-        page_tokens, H, Dh) f32}``, zero from ``tail_len`` on. A last page
+        page_tokens, H * Dh) f32}``, zero from ``tail_len`` on. A last page
         that is a tail has the scratch row for its id, so one program
         serves every prompt length under a padded length, whole pages or
         not. The first token is a scalar, ``admit_lane``'s operand as it
@@ -713,7 +727,7 @@ def _build_programs(server) -> SimpleNamespace:
         first, payloads = prefill(params, tokens, positions, last_idx)
         n_pages = ids.shape[0]
         live = jax.lax.broadcasted_iota(
-            jnp.int32, (sv.page_tokens, 1, 1), 0
+            jnp.int32, (sv.page_tokens, 1), 0
         ) < tail_len
         out, tails, qerr_rows = [], {name: [] for name in names}, {}
         for layer in range(n_layer):
@@ -724,9 +738,10 @@ def _build_programs(server) -> SimpleNamespace:
                 written[name] = paged_kv.commit_page_rows(
                     pool[name], ids, rows, spec
                 )
-                tails[name].append(
-                    jnp.where(live, x[-sv.page_tokens:], 0.0)
-                )
+                tails[name].append(jnp.where(
+                    live, x[-sv.page_tokens:].reshape(sv.page_tokens, -1),
+                    0.0,
+                ))
                 if (observe_qerr and spec.quantized
                         and name == streams[layer][0][0]):
                     qerr_rows[layer] = rows
@@ -748,9 +763,9 @@ def _build_programs(server) -> SimpleNamespace:
         state: its page-table row, counts, first token (a scalar still on
         the device from the local prefill, or a host one from a page
         stream) and position, its stacked tails ``{stream: (L, page_tokens,
-        H, Dh)}``, device or host arrays alike, and its recurrent state ``{state stream: (L,
-        *shape)}`` (whatever the lane's last request left there is
-        overwritten whole)."""
+        H * Dh)}``, device or host arrays alike, and its recurrent state
+        ``{state stream: (L, *shape)}`` (whatever the lane's last request
+        left there is overwritten whole)."""
         out = dict(state)
         for name, value in (
             ("page_table", table_row), ("n_pages", n_pages),
@@ -823,9 +838,10 @@ class _Ready:
 
     req: Request
     page_ids: List[int]
-    # {stream: (L, page_tokens, H, Dh) f32}, rows from ``tail_len`` on
-    # zero: device arrays from the local prefill, host arrays from a page
-    # stream — the ``admit_lane`` program takes either.
+    # {stream: (L, page_tokens, H * Dh) f32}, a position a row as the
+    # state keeps them, rows from ``tail_len`` on zero: device arrays from
+    # the local prefill, host arrays from a page stream — the
+    # ``admit_lane`` program takes either.
     tails: Dict[str, Union[jax.Array, np.ndarray]]
     tail_len: int
     # A scalar still on the device from the local prefill (nothing waits
@@ -946,11 +962,13 @@ class ContinuousBatchScheduler:
             for layer in streams
         )
         # A layer without the stream holds None in the stream's tuple, so
-        # that every per-layer entry is found at its layer's index.
+        # that every per-layer entry is found at its layer's index. A tail
+        # is kept as the rows the attention contracts and the commit
+        # quantizes, a position's heads side by side: no program relays it.
         tails = {
             f"tail_{name}": tuple(
                 None if spec is None else jnp.zeros(
-                    (b, spec.page_tokens, spec.n_head, spec.d_head),
+                    (b, spec.page_tokens, spec.n_head * spec.d_head),
                     jnp.float32,
                 )
                 for spec in (dict(layer).get(name) for layer in streams)
@@ -1298,7 +1316,7 @@ class ContinuousBatchScheduler:
         ]
         tails = {
             name: np.zeros(
-                (n_layer, pt, spec.n_head, spec.d_head), np.float32
+                (n_layer, pt, spec.n_head * spec.d_head), np.float32
             )
             for name, spec in streams[0]
         }
@@ -1326,7 +1344,7 @@ class ContinuousBatchScheduler:
             else:  # tail
                 vals = np.frombuffer(f.payload, np.float16).astype(
                     np.float32
-                ).reshape(-1, spec.n_head, spec.d_head)
+                ).reshape(-1, spec.n_head * spec.d_head)
                 tails[name][f.layer, : vals.shape[0]] = vals
         if n_pages:
             layer_rows = [
