@@ -1115,6 +1115,30 @@ def _cell_cases():
         yield f"olmoh-kv-commit-{rows}", "quantize", dict(
             bits=8, rows=rows, numel=_OLMOH_PAGE,
         ), {"quantize": flat}, {"_pipe_tc": tc}
+    # ISSUE 37: ling3-serve-reason128. ``c`` and ``kr`` of the one
+    # latent-attention layer at the JoyAI geometry, read through a (128, 7)
+    # page table over a pool of 897 rows: ``c`` paged, two pages a grid step;
+    # the 64-wide ``kr`` keeps the gather and XLA's reshape.
+    ling = dict(bits=8, rows=896, out_dtype=jnp.bfloat16, lanes=128, pool=897)
+    yield "ling3-decode-pages-c", "dequantize_pages", dict(
+        ling, page=(256, 1, 512),
+    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    yield "ling3-decode-pages-kr", "dequantize_pages", dict(
+        ling, page=(256, 1, 64),
+    ), {"dequantize_pages": "xla_gather", "dequantize": "pallas_flat.bfloat16",
+        "dequantize_rows": "xla_reshape"}, {"_pages_tc": None, "_pipe_tc": 16}
+    # Its commits: the tails that filled in the decode loop (a few of the
+    # 128 lanes a call), a padded prompt's 1 or 2 pages in prefill_pages (256
+    # and 512 tokens).
+    tails = _commit_lanes(128, 256)
+    for name, numel, commits in (
+        ("ling3-c", _JOYAI_C, {tails: 16, 1: 8, 2: 16}),
+        ("ling3-kr", _JOYAI_KR, {tails: 4, 1: 1, 2: 2}),
+    ):
+        for rows, tc in commits.items():
+            yield f"{name}-commit-{rows}", "quantize", dict(
+                bits=8, rows=rows, numel=numel,
+            ), {"quantize": flat}, {"_pipe_tc": tc}
     # Page commits: the tails that filled in the decode loop (ISSUE 34: 4 of
     # the 32 lanes a call, where every lane's 32 rows were quantized), a
     # padded prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and

@@ -121,3 +121,122 @@ def test_gpt2_moe_forward_and_grad():
     assert jnp.isfinite(val)
     g = grads["params"]["h_0"]["moe_mlp"]["experts_in"]
     assert float(jnp.abs(g).max()) > 0
+
+
+# ---------------------------------------------------------------------------
+# The serving plane's dropless layer: group-limited selection and a chip's
+# share of a layer's experts (ISSUE 37).
+# ---------------------------------------------------------------------------
+
+from torch_cgx_tpu.parallel import moe  # noqa: E402
+
+
+def _dropless_operands(seed, t=24, d=16, e=32, f=8):
+    rng = np.random.default_rng(seed)
+    arr = lambda *shape, scale=1.0: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape) * scale, jnp.float32)
+    return dict(y=arr(t, d), router=arr(d, e, scale=0.5),
+                bias=arr(e, scale=0.3), gate=arr(e, d, f, scale=0.3),
+                up=arr(e, d, f, scale=0.3), down=arr(e, f, d, scale=0.3))
+
+
+@pytest.mark.parametrize("n_group,topk_group,top_k", [
+    (8, 4, 8), (4, 2, 4), (4, 1, 3), (2, 2, 5),
+])
+def test_group_limited_selection_equals_a_plain_loop(n_group, topk_group,
+                                                     top_k):
+    """``sigmoid_topk_route`` with groups against a loop over tokens in
+    numpy: a group's score is the sum of its two largest ``score + bias``,
+    the ``topk_group`` best groups stay, the ``top_k`` largest ``score +
+    bias`` among their experts are chosen, and the weights are the chosen
+    scores (without the bias) over their sum, times the scale."""
+    e = 64
+    ops = _dropless_operands(n_group * 10 + top_k, t=40, e=e)
+    with jax.default_matmul_precision("highest"):
+        idx, weights = moe.sigmoid_topk_route(
+            ops["y"], ops["router"], ops["bias"], top_k=top_k, scale=2.5,
+            n_group=n_group, topk_group=topk_group)
+    scores = 1.0 / (1.0 + np.exp(-np.asarray(ops["y"], np.float64)
+                                 @ np.asarray(ops["router"], np.float64)))
+    biased = scores + np.asarray(ops["bias"], np.float64)
+    size = e // n_group
+    for t in range(scores.shape[0]):
+        group_score = [np.sort(biased[t, g * size: (g + 1) * size])[-2:].sum()
+                       for g in range(n_group)]
+        stays = np.argsort(group_score)[-topk_group:]
+        allowed = [i for i in range(e) if i // size in stays]
+        chosen = sorted(allowed, key=lambda i: -biased[t, i])[:top_k]
+        assert sorted(int(i) for i in idx[t]) == sorted(chosen)
+        want = {i: scores[t, i] / scores[t, chosen].sum() * 2.5
+                for i in chosen}
+        for i, w in zip(np.asarray(idx[t]), np.asarray(weights[t])):
+            assert w == pytest.approx(want[int(i)], rel=1e-5)
+
+
+def test_one_group_is_the_ungrouped_selection():
+    ops = _dropless_operands(3)
+    kw = dict(top_k=4, scale=1.5)
+    plain = moe.sigmoid_topk_route(ops["y"], ops["router"], ops["bias"], **kw)
+    grouped = moe.sigmoid_topk_route(ops["y"], ops["router"], ops["bias"],
+                                     n_group=4, topk_group=4, **kw)
+    assert np.array_equal(plain[0], grouped[0])
+    assert np.array_equal(plain[1], grouped[1])
+
+
+@pytest.mark.parametrize("shares", [4, 2, 1])
+def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer(shares):
+    """A layer whose experts are divided over ``shares`` chips: each share is
+    told which contiguous range it holds, routes over all of them and
+    computes its own experts' part; the parts add up to what the layer that
+    holds every expert gives (float32, limit 1e-5 of the largest value: the
+    sums are taken in another order). The counts add up too: every share
+    sees every assignment made, the held assignments and the experts touched
+    sum to the whole layer's, and nothing is dropped."""
+    ops = _dropless_operands(11, e=32)
+    kw = dict(top_k=4, scale=2.5, dtype=jnp.float32, n_group=4, topk_group=2)
+    y, router, bias = ops["y"], ops["router"], ops["bias"]
+    with jax.default_matmul_precision("highest"):
+        whole, stats = moe.dropless_moe(y, router, bias, ops["gate"],
+                                        ops["up"], ops["down"], **kw)
+        total, held_stats = 0.0, []
+        n = 32 // shares
+        for s in range(shares):
+            at = slice(s * n, (s + 1) * n)
+            part, st = moe.dropless_moe(
+                y, router, bias, ops["gate"][at], ops["up"][at],
+                ops["down"][at], held=s * n, **kw)
+            total = total + part
+            held_stats.append(dict(zip(moe.HELD_STATS, np.asarray(st))))
+    assert float(jnp.max(jnp.abs(total - whole))) < 1e-5 * float(
+        jnp.max(jnp.abs(whole)))
+    stats = dict(zip(moe.STATS, np.asarray(stats)))
+    assert stats["assignments"] == 24 * 4 and stats["dropped"] == 0
+    for st in held_stats:
+        assert st["assignments"] == stats["assignments"]
+        assert st["dropped"] == 0
+    assert sum(st["held_assignments"] for st in held_stats) == 24 * 4
+    assert sum(st["experts_touched"] for st in held_stats) == stats[
+        "experts_touched"]
+    assert max(st["load_max"] for st in held_stats) == stats["load_max"]
+
+
+def test_a_share_counts_its_own_experts_over_the_counted_rows():
+    """``count_mask`` leaves idle rows out of a share's counts as it does of
+    the whole layer's; a share that no counted row reaches counts nothing
+    and still returns zeros for what it does not hold."""
+    ops = _dropless_operands(5, e=16)
+    mask = jnp.arange(24) < 10
+    out, st = moe.dropless_moe(
+        ops["y"], ops["router"], ops["bias"], ops["gate"][:4], ops["up"][:4],
+        ops["down"][:4], top_k=2, scale=1.0, dtype=jnp.float32,
+        count_mask=mask, held=12)
+    st = dict(zip(moe.HELD_STATS, np.asarray(st)))
+    idx, _ = moe.sigmoid_topk_route(ops["y"], ops["router"], ops["bias"],
+                                    top_k=2, scale=1.0)
+    here = np.asarray((idx >= 12) & (idx < 16))
+    assert st["assignments"] == 20
+    assert st["held_assignments"] == int(here[:10].sum())
+    assert st["dropped"] == 0
+    untouched = ~here.any(axis=1)
+    assert bool(jnp.all(out[untouched] == 0.0))
+    assert bool(jnp.all(jnp.isfinite(out)))

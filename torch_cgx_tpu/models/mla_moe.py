@@ -8,7 +8,8 @@ Block ``l``::
     h  = x + Attn(RMSNorm(x))
     x' = h + FFN_l(RMSNorm(h))          FFN_0 dense SwiGLU, FFN_l>0 experts
 
-**MLA.** ``c_q = RMSNorm(W_qa x)``; ``q = W_qb c_q`` split per head into
+**MLA.** ``c_q = RMSNorm(W_qa x)``; ``q = W_qb c_q`` (``q = W_q x`` for a
+layer whose tree holds a full-rank ``q``) split per head into
 ``[q_nope | q_rope]``; ``[c_kv | k_r] = W_kva x``; ``c = RMSNorm(c_kv)``;
 ``q_rope`` and ``k_r`` rotated at the token's position (interleaved pairs),
 ``k_r`` one per token for all heads; ``[k_nope_h | v_h] = W_kvb c``; scores
@@ -140,10 +141,12 @@ def mla_project(cfg: MlaMoeConfig, x, pa, positions):
     the rotated key ``k_r (B, S, dr)``, float32."""
     dt = cfg.dtype
     b, s, _ = x.shape
-    c_q = rms_norm(_mm(x, pa["q_a"], dt), pa["q_a_norm"], cfg.eps)
-    q = _mm(c_q, pa["q_b"], dt).reshape(
-        b, s, cfg.n_head, cfg.d_nope + cfg.d_rope
-    )
+    if "q" in pa:  # no low rank (``q_lora_rank`` null)
+        q = _mm(x, pa["q"], dt)
+    else:
+        c_q = rms_norm(_mm(x, pa["q_a"], dt), pa["q_a_norm"], cfg.eps)
+        q = _mm(c_q, pa["q_b"], dt)
+    q = q.reshape(b, s, cfg.n_head, cfg.d_nope + cfg.d_rope)
     q_nope, q_rope = q[..., : cfg.d_nope], q[..., cfg.d_nope:]
     kv = _mm(x, pa["kv_a"], dt)
     c = rms_norm(kv[..., : cfg.kv_lora_rank], pa["kv_a_norm"], cfg.eps)

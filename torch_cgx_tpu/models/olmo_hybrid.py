@@ -272,6 +272,31 @@ def unit_lower_inverse(a):
     return t
 
 
+def carry_chunks(state0, w, u, qg, qk, k_end, whole):
+    """The chunks one after another, the state carried: with ``S`` entering
+    a chunk, ``V' = U - W S``, ``O = Qg S + QK V'``, ``S' = whole S + K_end^T
+    V'``. ``w``, ``qg``, ``k_end (B, nc, H, L, dk)``, ``u (B, nc, H, L,
+    dv)``, ``qk (B, nc, H, L, L)``; ``whole`` the decay over a whole chunk,
+    ``(B, nc, H)`` a head or ``(B, nc, H, dk)`` a key channel; ``state0 (B,
+    H, dk, dv)``. Returns the last state and ``o (nc, B, H, L, dv)``."""
+    a_head = whole.ndim == 3
+
+    def carry(state, c):
+        w_c, u_c, qg_c, qk_c, k_end_c, whole_c = c
+        vp = u_c - jnp.matmul(w_c, state, precision=HI)
+        o_c = (jnp.matmul(qg_c, state, precision=HI)
+               + jnp.matmul(qk_c, vp, precision=HI))
+        rows = whole_c[..., None, None] if a_head else whole_c[..., None]
+        new = rows * state + jnp.einsum(
+            "bhld,bhlv->bhdv", k_end_c, vp, precision=HI)
+        return new, o_c
+
+    return jax.lax.scan(
+        carry, state0,
+        tuple(t.swapaxes(0, 1) for t in (w, u, qg, qk, k_end, whole)),
+    )
+
+
 def gdn_chunk_scan(q, k, v, log_alpha, beta, chunk: int, state0=None):
     """The recurrence ``S_t = alpha_t S_{t-1} + k_t u_t^T`` with ``u_t =
     beta_t (v_t - alpha_t S_{t-1}^T k_t)``, ``o_t = S_t^T q_t`` over ``S``
@@ -320,21 +345,9 @@ def gdn_chunk_scan(q, k, v, log_alpha, beta, chunk: int, state0=None):
         k_end = k * jnp.exp(g[..., -1:] - g)[..., None]
         whole = jnp.exp(g[..., -1])  # (b, nc, h): decay over a chunk
 
-        def carry(state, c):
-            w_c, u_c, qg_c, qk_c, k_end_c, whole_c = c
-            vp = u_c - jnp.matmul(w_c, state, precision=HI)
-            o_c = (jnp.matmul(qg_c, state, precision=HI)
-                   + jnp.matmul(qk_c, vp, precision=HI))
-            new = whole_c[..., None, None] * state + jnp.einsum(
-                "bhld,bhlv->bhdv", k_end_c, vp, precision=HI)
-            return new, o_c
-
         if state0 is None:
             state0 = jnp.zeros((b, h, dk, dv), jnp.float32)
-        final, o = jax.lax.scan(
-            carry, state0,
-            tuple(t.swapaxes(0, 1) for t in (w, u, q * eg, qk, k_end, whole)),
-        )
+        final, o = carry_chunks(state0, w, u, q * eg, qk, k_end, whole)
     # (nc, b, h, L, dv) -> (b, S, h, dv)
     o = jnp.moveaxis(o, 0, 1).swapaxes(2, 3).reshape(b, nc * chunk, h, dv)
     return o[:, :s], final

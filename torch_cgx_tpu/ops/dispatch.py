@@ -350,17 +350,34 @@ def ssm_update(state, decay, dtx, bm, cm):
     return ssm.ssm_update_xla(state, decay, dtx, bm, cm)
 
 
+def _delta_rule_update(site, kernel, state, q, k, v, alpha, beta):
+    """``ops/gdn.py``'s one-step update under the kernel name ``kernel``,
+    counted as ``cgx.codec.lowering.<site>.pallas`` / ``.xla``."""
+    impl = cfg_mod.codec_impl()
+    if impl == "pallas" or (impl == "auto" and _on_tpu()):
+        codec_pallas.note_lowering(site, "pallas")
+        return gdn.gdn_update_pallas(
+            state, q, k, v, alpha, beta, name=kernel,
+            interpret=not _on_tpu(),
+        )
+    codec_pallas.note_lowering(site, "xla")
+    return gdn.gdn_update_xla(state, q, k, v, alpha, beta)
+
+
 def gdn_update(state, q, k, v, alpha, beta):
     """One token's update of a gated delta-rule layer's recurrent state,
     all lanes (``ops/gdn.py``): the ``cgx_gdn_update`` kernel on the TPU
     (and, interpreted, wherever ``CGX_CODEC_IMPL=pallas`` asks for the
     kernels), its ``jax.numpy`` form elsewhere; counted per call site as
     ``cgx.codec.lowering.gdn_update.pallas`` / ``.xla``."""
-    impl = cfg_mod.codec_impl()
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        codec_pallas.note_lowering("gdn_update", "pallas")
-        return gdn.gdn_update_pallas(
-            state, q, k, v, alpha, beta, interpret=not _on_tpu()
-        )
-    codec_pallas.note_lowering("gdn_update", "xla")
-    return gdn.gdn_update_xla(state, q, k, v, alpha, beta)
+    return _delta_rule_update("gdn_update", "cgx_gdn_update", state, q, k, v,
+                              alpha, beta)
+
+
+def kda_update(state, q, k, v, alpha, beta):
+    """:func:`gdn_update` for a layer whose decay is a number a key channel
+    (``alpha (B, H, dk)``: KDA): the same kernel, ``cgx_kda_update`` in the
+    device's trace, the same ``jax.numpy`` form; counted per call site as
+    ``cgx.codec.lowering.kda_update.pallas`` / ``.xla``."""
+    return _delta_rule_update("kda_update", "cgx_kda_update", state, q, k, v,
+                              alpha, beta)

@@ -22,7 +22,11 @@ dispatch, shaped for the MXU and for GSPMD expert parallelism:
   scaling factor): the token-expert assignments sorted by expert and every
   expert's SwiGLU run over its own contiguous rows through
   ``jax.lax.ragged_dot``. No capacity, so no token is ever dropped, at any
-  skew. Holds every expert on one device (no EP exchange yet).
+  skew. Told which experts it holds (``held``: a contiguous range, a
+  chip's share of a layer that several chips divide), it routes over all of
+  them and computes its own experts' part of the result, without the
+  exchange; all of them is the default. Selection may be limited to the best
+  ``topk_group`` of ``n_group`` groups of experts.
 
 Composes with the quantized gradient allreduce: expert weights are regular
 pytree leaves, so per-layer compression configs apply (pattern
@@ -224,27 +228,44 @@ class MoEMlp(nn.Module):
 
 # What :func:`dropless_moe` counts, in the order of its ``stats`` vector.
 STATS = ("assignments", "experts_touched", "load_max", "dropped")
+# The same of a layer that holds a share of its experts (``held``).
+HELD_STATS = STATS + ("held_assignments",)
 
 
-def sigmoid_topk_route(y, router, bias, *, top_k: int, scale: float):
+def sigmoid_topk_route(y, router, bias, *, top_k: int, scale: float,
+                       n_group: int = 1, topk_group: int = 1):
     """``y (T, D)`` -> the chosen experts ``(T, top_k)`` int32 and their
     combine weights ``(T, top_k)`` float32: scores ``sigmoid(y W_g)`` in
     float32 at full matmul precision (a near-tie between the last expert
     chosen and the first left out must not turn on the MXU's bf16 passes),
     selection by ``score + bias``, weights the chosen scores normalised to
-    sum to 1, times ``scale``."""
+    sum to 1, times ``scale``. With ``n_group > 1`` the experts are
+    ``n_group`` groups of neighbours, a group's score is the sum of its two
+    largest ``score + bias``, and the selection is among the experts of the
+    ``topk_group`` best groups alone."""
     scores = jax.nn.sigmoid(jnp.matmul(
         y.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
-    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    biased = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = biased.shape
+        best_two, _ = jax.lax.top_k(biased.reshape(t, n_group, -1), 2)
+        _, groups = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+        kept = jnp.zeros((t, n_group), bool).at[
+            jnp.arange(t)[:, None], groups].set(True)
+        biased = jnp.where(jnp.repeat(kept, e // n_group, axis=1), biased,
+                           -jnp.inf)
+    _, idx = jax.lax.top_k(biased, top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
     return idx.astype(jnp.int32), weights
 
 
 def dropless_moe(y, router, bias, gate, up, down, *, top_k: int,
-                 scale: float, dtype=jnp.bfloat16, count_mask=None):
+                 scale: float, dtype=jnp.bfloat16, count_mask=None,
+                 n_group: int = 1, topk_group: int = 1,
+                 held: Optional[int] = None):
     """``sum_i w_i E_i(y)`` over the ``top_k`` routed experts of every row
     of ``y (T, D)``; experts ``gate, up (E, D, F)``, ``down (E, F, D)``,
     each ``down(silu(gate y) * up y)``. Returns ``(out (T, D) dtype, stats
@@ -252,33 +273,62 @@ def dropless_moe(y, router, bias, gate, up, down, *, top_k: int,
     experts that got at least one, the largest expert's load, and tokens
     dropped (assignments asked for less assignments computed; 0 by
     construction, counted all the same). ``count_mask (T,)`` bool leaves
-    rows (idle decode lanes) out of the counts; they are computed anyway."""
+    rows (idle decode lanes) out of the counts; they are computed anyway.
+
+    ``held`` says that ``gate``, ``up`` and ``down`` are a share of the
+    layer's experts, those from ``held`` on: the router (as wide as the
+    whole layer) chooses among all of them, the assignments that fall on an
+    expert held here are computed and the others add nothing. ``stats`` is
+    then :data:`HELD_STATS`: every assignment made, then the held experts
+    touched, the largest held expert's load, the held assignments asked for
+    less those computed, and the held assignments."""
     t, _ = y.shape
     e = gate.shape[0]
     idx, weights = sigmoid_topk_route(
-        y, router, bias, top_k=top_k, scale=scale
+        y, router, bias, top_k=top_k, scale=scale, n_group=n_group,
+        topk_group=topk_group,
     )
     flat = idx.reshape(-1)
+    if held is not None:
+        here = (flat >= held) & (flat < held + e)
+        # An absent expert sorts after every held one and is no group: its
+        # rows lie past the groups' end and are zeroed below.
+        flat = jnp.where(here, flat - held, e)
     order = jnp.argsort(flat, stable=True)  # assignments, by expert
-    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1, mode="drop")
     xs = y.astype(dtype)[order // top_k]  # (T * top_k, D)
     h = jax.nn.silu(
         jax.lax.ragged_dot(xs, gate.astype(dtype), sizes)
     ) * jax.lax.ragged_dot(xs, up.astype(dtype), sizes)
     rows = jax.lax.ragged_dot(h, down.astype(dtype), sizes)
     rows = rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
+    if held is not None:
+        rows = jnp.where(here[order][:, None], rows, 0.0)
     out = jnp.zeros((t * top_k, y.shape[1]), jnp.float32).at[order].set(rows)
     out = out.reshape(t, top_k, -1).sum(axis=1).astype(dtype)
 
     counted = jnp.ones((t,), bool) if count_mask is None else count_mask
     per_row = jnp.repeat(counted.astype(jnp.int32), top_k)
-    load = jnp.zeros((e,), jnp.int32).at[flat].add(per_row)
-    asked = jnp.sum(per_row)
-    stats = jnp.stack([
-        jnp.sum(load), jnp.sum(load > 0).astype(jnp.int32), jnp.max(load),
+    load = jnp.zeros((e,), jnp.int32).at[flat].add(per_row, mode="drop")
+    made = jnp.sum(per_row)
+    asked = made if held is None else jnp.sum(per_row * here)
+    stats = [
+        made if held is not None else jnp.sum(load),
+        jnp.sum(load > 0).astype(jnp.int32), jnp.max(load),
         asked - jnp.sum(load),
-    ]).astype(jnp.int32)
-    return out, stats
+    ]
+    if held is not None:
+        stats.append(jnp.sum(load))
+    return out, jnp.stack(stats).astype(jnp.int32)
+
+
+def total_stats(counts):
+    """A decode step's counts from its expert layers' ``stats`` vectors
+    (:data:`STATS` or :data:`HELD_STATS`): summed, but ``load_max`` their
+    largest."""
+    counts = jnp.stack(counts)  # (expert layers, len(STATS))
+    peak = STATS.index("load_max")
+    return jnp.sum(counts, axis=0).at[peak].set(jnp.max(counts[:, peak]))
 
 
 def moe_param_spec(path: str, leaf, axis: str = "ep") -> Optional[P]:

@@ -18,10 +18,11 @@ inference:
   the GPT-2 adapter (streams ``k``, ``v``).
 * :mod:`.latent` — the latent-attention (MLA) adapter with dropless
   experts (streams ``c``, ``kr``).
-* :mod:`.hybrid` — the two hybrid adapters: ``k``, ``v`` pages on their
-  few attention layers, a per-lane recurrent state on the others (``conv``,
-  ``ssm`` on Mamba-2 layers; ``conv``, ``gdn``, a matrix a head, on gated
-  delta-rule layers).
+* :mod:`.hybrid` — the hybrid adapters: pages on their few attention
+  layers (``k``, ``v``; ``c``, ``kr`` where the attention is latent), a
+  per-lane recurrent state on the others (``conv``, ``ssm`` on Mamba-2
+  layers; ``conv`` and ``gdn`` or ``kda``, a matrix a head, on delta-rule
+  layers).
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -36,6 +37,10 @@ from .scheduler import (  # noqa: F401
     invalidate_decode_cache,
 )
 from .latent import LatentMoEServer  # noqa: F401
-from .hybrid import HybridGDNServer, HybridSSMServer  # noqa: F401
+from .hybrid import (  # noqa: F401
+    HybridGDNServer,
+    HybridLatentMoEServer,
+    HybridSSMServer,
+)
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401

@@ -3,7 +3,9 @@ few attention layers among them, behind the one scheduler.
 :class:`HybridSSMServer` serves ``models/granite_hybrid.py`` (Mamba-2 layers
 and grouped-query attention), :class:`HybridGDNServer`
 ``models/olmo_hybrid.py`` (gated delta-rule layers and multi-head attention
-with normed queries and keys).
+with normed queries and keys), :class:`HybridLatentMoEServer`
+``models/ling_hybrid.py`` (KDA layers and latent attention, routed experts
+of which the tree may hold a share).
 
 Their layers name different streams. An attention layer leaves pages: ``k``
 and ``v``, rows of ``n_kv_head * d_head`` (no positional embedding, so
@@ -17,6 +19,11 @@ at the prompt's last token:
   last inputs) and ``ssm (d_state, d_inner)``; ``ops.dispatch.ssm_update``.
 * gated delta rule: ``conv (d_conv - 1, heads x (2 d_k + d_v))`` and ``gdn
   (d_k, heads x d_v)``, a matrix a head; ``ops.dispatch.gdn_update``.
+* KDA (a delta rule gated a key channel): ``conv (d_conv - 1, 3 heads x
+  d_head)`` and ``kda (d_head, heads x d_head)``;
+  ``ops.dispatch.kda_update``. Its attention layers leave no ``k`` and ``v``
+  but ``serving/latent.py``'s latent streams ``c`` and ``kr``, read by the
+  absorbed attention: state streams and latent streams in one model.
 
 ``state_streams`` states them; the scheduler carries them in the donated
 state beside pools and tails. What an attention layer's decode position does
@@ -47,9 +54,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import granite_hybrid as gh
+from ..models import ling_hybrid as lh
+from ..models import mla_moe
 from ..models import olmo_hybrid as oh
 from ..models.attention import decode_attention
 from ..models.mla_moe import _mm, rms_norm
+from ..parallel import moe
 from .scheduler import (
     ServeConfig,
     lane_masks,
@@ -78,11 +88,11 @@ def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
 
 
 class _HybridAdapter:
-    """What the two adapters share of the protocol in ``scheduler.py``: one
-    ``(model config, params)`` pair, ``k`` and ``v`` pages on the layers the
-    config lists as ``attention_layers``, and on the others the state
-    streams a subclass names, kept in ``state_dtype`` (float32 unless a
-    control asks for less)."""
+    """What the adapters share of the protocol in ``scheduler.py``: one
+    ``(model config, params)`` pair, pages on the layers the config lists as
+    ``attention_layers`` (``k`` and ``v`` unless a subclass names other
+    streams), and on the others the state streams a subclass names, kept in
+    ``state_dtype`` (float32 unless a control asks for less)."""
 
     step_counters = ()
 
@@ -281,3 +291,124 @@ class HybridGDNServer(_HybridAdapter):
             x = oh.mlp_half(cfg, pl, oh.post_norm_residual(
                 cfg, x, mixed, pl["mixer_norm"]))
         return oh.logits(cfg, self.p, x), new, None
+
+
+class HybridLatentMoEServer(_HybridAdapter):
+    """Model adapter for one ``(LingHybridConfig, params)`` pair: state
+    streams ``conv`` and ``kda`` on the KDA layers, cache streams ``c`` and
+    ``kr`` (``serving/latent.py``'s geometry) on the latent-attention
+    layers, and the expert layers' counts every decode step."""
+
+    kind = "hybrid_kda_mla"
+
+    def __init__(self, model_cfg, params,
+                 serve: Optional[ServeConfig] = None,
+                 state_dtype: Any = jnp.float32):
+        super().__init__(model_cfg, params, serve, state_dtype)
+        # What a decode step counts over its expert layers, as
+        # ``cgx.serve.<name>``: the layer's ``stats`` in order.
+        names = (moe.STATS if model_cfg.experts_held is None
+                 else moe.HELD_STATS)
+        self.step_counters = tuple(f"moe.{name}" for name in names)
+
+    def cache_streams(self, layer: int):
+        cfg = self.cfg
+        if layer not in cfg.attention_layers:
+            return ()
+        c, kr = page_specs(
+            self.layer_name(layer), self.serve.page_tokens,
+            [(1, cfg.kv_lora_rank), (1, cfg.d_rope)],
+        )
+        return (("c", c), ("kr", kr))
+
+    def state_streams(self, layer: int):
+        cfg = self.cfg
+        if cfg.layer_types[layer] != "kda":
+            return ()
+        return (
+            ("conv", (cfg.d_conv - 1, cfg.d_qkv), self.state_dtype),
+            ("kda", (cfg.d_head, cfg.d_inner), self.state_dtype),
+        )
+
+    # -- forwards ----------------------------------------------------------
+
+    def prefill_forward(self, tokens, positions, last_idx):
+        """Full causal forward over a (right-padded) prompt: the logits at
+        ``last_idx``; each latent-attention layer's ``c (B, S, 1, Rkv)`` and
+        rotated ``kr (B, S, 1, dr)`` f32 (right-padding is inert under the
+        causal mask; a padded token does go through the experts, dropless);
+        each KDA layer's ``conv`` and ``kda`` state after position
+        ``last_idx``, which the pad does not reach
+        (``ling_hybrid.kda_prefill``). One list a stream, None for a layer
+        without it."""
+        cfg = self.cfg
+        x = lh.embed(cfg, self.p, tokens)
+        out = {name: [None] * cfg.n_layer for name in ("c", "kr", "conv",
+                                                       "kda")}
+        for layer, kind in enumerate(cfg.layer_types):
+            pl = self.p[f"layer_{layer}"]
+            y = rms_norm(x, pl["mixer_norm"], cfg.eps)
+            if kind == "kda":
+                mixed, conv, state = lh.kda_prefill(
+                    cfg, pl["kda"], y, last_idx
+                )
+                out["conv"][layer] = conv.astype(self.state_dtype)
+                out["kda"][layer] = state.astype(self.state_dtype)
+            else:
+                pa = pl["attn"]
+                q_nope, q_rope, c, k_r = mla_moe.mla_project(
+                    cfg, y, pa, positions
+                )
+                out["c"][layer] = c[:, :, None]
+                out["kr"][layer] = k_r[:, :, None]
+                mixed = lh.mla_out(cfg, pa, mla_moe.attend_expanded(
+                    cfg, pa, q_nope, q_rope, c, k_r), y)
+            x, _ = lh.ffn_half(cfg, pl, x + mixed)
+        x_last = jax.lax.dynamic_index_in_dim(x, last_idx, 1)
+        return (lh.logits(cfg, self.p, x_last)[:, -1], out["c"], out["kr"],
+                out["conv"], out["kda"])
+
+    def decode_forward(self, state, streams):
+        """One decode position: a latent-attention layer writes this token's
+        ``c`` and ``kr`` into its raw tails and reads its committed pages
+        where they lie, the absorbed attention over both; a KDA layer takes
+        one step of its recurrence and hands back its state, rewritten.
+        Returns (logits (B, V), the new tails and states by stream, the
+        expert layers' counts over the active lanes,
+        ``moe.total_stats``)."""
+        cfg, dt = self.cfg, self.cfg.dtype
+        x = lh.embed(cfg, self.p, state["tokens"])  # (B, D)
+        positions = state["pos"][:, None]
+        tail_idx, mask_c, mask_t = lane_masks(self.serve, state)
+        new = {name: [None] * cfg.n_layer for name in ("c", "kr", "conv",
+                                                       "kda")}
+        counts = []
+        for layer, kind in enumerate(cfg.layer_types):
+            pl = self.p[f"layer_{layer}"]
+            y = rms_norm(x, pl["mixer_norm"], cfg.eps)
+            if kind == "kda":
+                mixed, new["conv"][layer], new["kda"][layer] = lh.kda_step(
+                    cfg, pl["kda"], y, state["state_conv"][layer],
+                    state["state_kda"][layer],
+                )
+            else:
+                pa = pl["attn"]
+                q_nope, q_rope, c, k_r = mla_moe.mla_project(
+                    cfg, y[:, None], pa, positions
+                )
+                pages, tails, written = layer_cache_rows(
+                    state, layer, streams[layer], tail_idx, (c, k_r), dt
+                )
+                for name, tail in written.items():
+                    new[name][layer] = tail
+                o = mla_moe.attend_absorbed(
+                    cfg, pa, q_nope[:, 0], q_rope[:, 0], pages["c"],
+                    pages["kr"], mask_c,
+                    tail=(tails["c"], tails["kr"], mask_t),
+                )
+                mixed = lh.mla_out(cfg, pa, o, y)
+            x, stats = lh.ffn_half(cfg, pl, x + mixed,
+                                   count_mask=state["active"])
+            if stats is not None:
+                counts.append(stats)
+        return lh.logits(cfg, self.p, x), new, moe.total_stats(counts)

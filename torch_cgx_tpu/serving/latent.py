@@ -149,9 +149,4 @@ class LatentMoEServer:
             if stats is not None:
                 counts.append(stats)
         logits = mla_moe.logits(cfg, self.p, x)[:, -1]
-        counts = jnp.stack(counts)  # (expert layers, len(moe.STATS))
-        peak = moe.STATS.index("load_max")
-        summed = jnp.sum(counts, axis=0).at[peak].set(
-            jnp.max(counts[:, peak])
-        )
-        return logits, new_tails, summed
+        return logits, new_tails, moe.total_stats(counts)
