@@ -328,13 +328,19 @@ def _loop_moe(y, pm, top_k, scale):
     return out, load
 
 
+@pytest.mark.parametrize("impl", ["auto", "pallas"])
 @pytest.mark.parametrize("skew", [0.0, 100.0])
-def test_dropless_experts_match_a_per_token_loop(params, skew):
+def test_dropless_experts_match_a_per_token_loop(monkeypatch, params, skew,
+                                                 impl):
     """``parallel.moe.dropless_moe`` against a loop over tokens and their
     experts, first as drawn, then with a selection bias so skewed that one
     expert takes EVERY token: nothing is dropped (no capacity exists to
     overflow) and the counts say what happened. float32 against float64:
-    limit 1e-5 of the output's largest value (reading 4e-7)."""
+    limit 1e-5 of the output's largest value (reading 4e-7). Under
+    ``CGX_CODEC_IMPL=pallas`` the products are the ``cgx_grouped_matmul``
+    kernel's (interpreted): the skewed case is one group over two row
+    tiles and fifteen groups of none."""
+    monkeypatch.setenv("CGX_CODEC_IMPL", impl)
     pm = dict(params["layer_2"]["moe"])
     pm["bias"] = pm["bias"].at[5].add(skew)
     rng = np.random.default_rng(2)

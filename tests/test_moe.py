@@ -183,15 +183,21 @@ def test_one_group_is_the_ungrouped_selection():
     assert np.array_equal(plain[1], grouped[1])
 
 
+@pytest.mark.parametrize("impl", ["auto", "pallas"])
 @pytest.mark.parametrize("shares", [4, 2, 1])
-def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer(shares):
+def test_the_shares_of_an_expert_layer_add_up_to_the_whole_layer(
+        monkeypatch, shares, impl):
     """A layer whose experts are divided over ``shares`` chips: each share is
     told which contiguous range it holds, routes over all of them and
     computes its own experts' part; the parts add up to what the layer that
     holds every expert gives (float32, limit 1e-5 of the largest value: the
     sums are taken in another order). The counts add up too: every share
     sees every assignment made, the held assignments and the experts touched
-    sum to the whole layer's, and nothing is dropped."""
+    sum to the whole layer's, and nothing is dropped. Under
+    ``CGX_CODEC_IMPL=pallas`` the products are the ``cgx_grouped_matmul``
+    kernel's (interpreted), a share's rows of experts held elsewhere lying
+    past the groups' end."""
+    monkeypatch.setenv("CGX_CODEC_IMPL", impl)
     ops = _dropless_operands(11, e=32)
     kw = dict(top_k=4, scale=2.5, dtype=jnp.float32, n_group=4, topk_group=2)
     y, router, bias = ops["y"], ops["router"], ops["bias"]

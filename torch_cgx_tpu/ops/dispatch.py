@@ -16,7 +16,7 @@ from jax import lax
 
 from .. import config as cfg_mod
 from ..config import CompressionConfig
-from . import codec, codec_pallas, gdn, ssm
+from . import codec, codec_pallas, gdn, grouped_matmul as gmm, ssm
 
 
 def _on_tpu() -> bool:
@@ -334,14 +334,20 @@ def reduce_rows_requantize(
     )
 
 
+def _kernels() -> bool:
+    """Whether the state and expert kernels run: on the TPU, and
+    interpreted wherever ``CGX_CODEC_IMPL=pallas`` asks for the kernels."""
+    impl = cfg_mod.codec_impl()
+    return impl == "pallas" or (impl == "auto" and _on_tpu())
+
+
 def ssm_update(state, decay, dtx, bm, cm):
     """One token's update of a state-space layer's recurrent state, all
     lanes (``ops/ssm.py``): the ``cgx_ssm_update`` kernel on the TPU (and,
     interpreted, wherever ``CGX_CODEC_IMPL=pallas`` asks for the kernels),
     its ``jax.numpy`` form elsewhere; counted per call site as
     ``cgx.codec.lowering.ssm_update.pallas`` / ``.xla``."""
-    impl = cfg_mod.codec_impl()
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
+    if _kernels():
         codec_pallas.note_lowering("ssm_update", "pallas")
         return ssm.ssm_update_pallas(
             state, decay, dtx, bm, cm, interpret=not _on_tpu()
@@ -353,8 +359,7 @@ def ssm_update(state, decay, dtx, bm, cm):
 def _delta_rule_update(site, kernel, state, q, k, v, alpha, beta):
     """``ops/gdn.py``'s one-step update under the kernel name ``kernel``,
     counted as ``cgx.codec.lowering.<site>.pallas`` / ``.xla``."""
-    impl = cfg_mod.codec_impl()
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
+    if _kernels():
         codec_pallas.note_lowering(site, "pallas")
         return gdn.gdn_update_pallas(
             state, q, k, v, alpha, beta, name=kernel,
@@ -381,3 +386,21 @@ def kda_update(state, q, k, v, alpha, beta):
     ``cgx.codec.lowering.kda_update.pallas`` / ``.xla``."""
     return _delta_rule_update("kda_update", "cgx_kda_update", state, q, k, v,
                               alpha, beta)
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """The experts' grouped product (``ops/grouped_matmul.py``): ``lhs (M,
+    K)`` sorted by group times ``rhs (E, K, N)`` by ``sizes (E,)``. Where
+    :func:`grouped_matmul.takes_kernel` says the shapes are the kernel's,
+    the ``cgx_grouped_matmul`` kernel on the TPU (and, interpreted, wherever
+    ``CGX_CODEC_IMPL=pallas`` asks for the kernels); ``jax.lax.ragged_dot``
+    elsewhere; counted per call site as
+    ``cgx.codec.lowering.grouped_matmul.pallas`` / ``.xla``."""
+    if _kernels() and gmm.takes_kernel(lhs.shape[0], *rhs.shape,
+                                       rhs.dtype.itemsize):
+        codec_pallas.note_lowering("grouped_matmul", "pallas")
+        return gmm.grouped_matmul_pallas(
+            lhs, rhs, sizes, interpret=not _on_tpu()
+        )
+    codec_pallas.note_lowering("grouped_matmul", "xla")
+    return gmm.grouped_matmul_xla(lhs, rhs, sizes)

@@ -21,8 +21,9 @@ dispatch, shaped for the MXU and for GSPMD expert parallelism:
   form: sigmoid scores, a selection bias, normalised top-k weights times a
   scaling factor): the token-expert assignments sorted by expert and every
   expert's SwiGLU run over its own contiguous rows through
-  ``jax.lax.ragged_dot``. No capacity, so no token is ever dropped, at any
-  skew. Told which experts it holds (``held``: a contiguous range, a
+  ``ops.dispatch.grouped_matmul`` (the ``cgx_grouped_matmul`` kernel on the
+  chip, ``jax.lax.ragged_dot`` elsewhere). No capacity, so no token is ever
+  dropped, at any skew. Told which experts it holds (``held``: a contiguous range, a
   chip's share of a layer that several chips divide), it routes over all of
   them and computes its own experts' part of the result, without the
   exchange; all of them is the default. Selection may be limited to the best
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..ops import dispatch
 from ..utils import compat
 from ..wire import dispatch as wire_dispatch
 from ..wire.edges import EDGE_MOE_A2A
@@ -297,10 +299,12 @@ def dropless_moe(y, router, bias, gate, up, down, *, top_k: int,
     order = jnp.argsort(flat, stable=True)  # assignments, by expert
     sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1, mode="drop")
     xs = y.astype(dtype)[order // top_k]  # (T * top_k, D)
-    h = jax.nn.silu(
-        jax.lax.ragged_dot(xs, gate.astype(dtype), sizes)
-    ) * jax.lax.ragged_dot(xs, up.astype(dtype), sizes)
-    rows = jax.lax.ragged_dot(h, down.astype(dtype), sizes)
+
+    def product(rows, weights):
+        return dispatch.grouped_matmul(rows, weights.astype(dtype), sizes)
+
+    h = jax.nn.silu(product(xs, gate)) * product(xs, up)
+    rows = product(h, down)
     rows = rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
     if held is not None:
         rows = jnp.where(here[order][:, None], rows, 0.0)
