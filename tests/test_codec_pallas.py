@@ -1139,6 +1139,27 @@ def _cell_cases():
             yield f"{name}-commit-{rows}", "quantize", dict(
                 bits=8, rows=rows, numel=numel,
             ), {"quantize": flat}, {"_pipe_tc": tc}
+    # ISSUE 41: smallthinker-serve-mix8k. ``k`` and ``v`` of every layer, a
+    # page of 256 tokens x 4 K/V heads x 128 (the latent ``c``'s geometry
+    # again). A global layer reads a (48, 36) page table over a pool of 1,729
+    # rows, a window layer its (48, 17) ring over a pool of 817 under a
+    # lowering counter (and a kernel name) of its own: both paged, two pages
+    # a grid step.
+    small = dict(bits=8, out_dtype=jnp.bfloat16, page=(256, 4, 128), lanes=48)
+    yield "smallthinker-decode-pages-global", "dequantize_pages", dict(
+        small, rows=48 * 36, pool=1729,
+    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    yield "smallthinker-decode-pages-window", "dequantize_pages", dict(
+        small, rows=48 * 17, pool=817, window=True,
+    ), {"dequantize_pages.window": "pallas_paged", "dequantize_rows": flat,
+        "dequantize": "pallas_flat.bfloat16"}, {"_pages_tc": 16}
+    # Its commits: the tails that filled in the decode loop (4 of the 48
+    # lanes a call); a padded prompt's 2 or 32 pages in prefill_pages (512
+    # and 8,192 tokens), of which a window layer writes the last 18.
+    for rows in (_commit_lanes(48, 256), 2, 18, 32):
+        yield f"smallthinker-kv-commit-{rows}", "quantize", dict(
+            bits=8, rows=rows, numel=_JOYAI_C,
+        ), {"quantize": flat}, {"_pipe_tc": 16}
     # Page commits: the tails that filled in the decode loop (ISSUE 34: 4 of
     # the 32 lanes a call, where every lane's 32 rows were quantized), a
     # padded prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and
@@ -1191,7 +1212,8 @@ def _trace_cell_call(kind, *, bits, rows, numel=None, bucket=512, **kw):
             lambda: paged_kv.empty_pool(kw.get("pool", 513), spec))
         return jax.eval_shape(
             lambda pool, table: paged_kv.gather_dequant_pages(
-                pool, table, spec, kw["out_dtype"]),
+                pool, table, spec, kw["out_dtype"],
+                window=kw.get("window", False)),
             pool, jax.ShapeDtypeStruct((lanes, rows // lanes), jnp.int32),
         )
     if kind == "quantize":

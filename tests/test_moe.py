@@ -246,3 +246,103 @@ def test_a_share_counts_its_own_experts_over_the_counted_rows():
     untouched = ~here.any(axis=1)
     assert bool(jnp.all(out[untouched] == 0.0))
     assert bool(jnp.all(jnp.isfinite(out)))
+
+
+# ---------------------------------------------------------------------------
+# The routing given from outside, the softmax-of-top-k route and the ReLU
+# gate (ISSUE 41).
+# ---------------------------------------------------------------------------
+
+
+def _plain_experts(z, idx, weights, gate, up, down, act):
+    """``sum_i w_i E_idx_i(z)``, a token and an expert at a time."""
+    z, idx, weights = np.asarray(z), np.asarray(idx), np.asarray(weights)
+    gate, up, down = np.asarray(gate), np.asarray(up), np.asarray(down)
+    out = np.zeros_like(z)
+    for t in range(z.shape[0]):
+        for e, w in zip(idx[t], weights[t]):
+            g = z[t] @ gate[e]
+            g = np.maximum(g, 0.0) if act == "relu" else g / (1 + np.exp(-g))
+            out[t] += w * ((g * (z[t] @ up[e])) @ down[e])
+    return out
+
+
+def test_softmax_topk_route_takes_the_largest_logits_and_softmaxes_them():
+    ops = _dropless_operands(3, e=16)
+    with jax.default_matmul_precision("highest"):
+        idx, weights = moe.softmax_topk_route(ops["y"], ops["router"],
+                                              top_k=6)
+    logits = np.asarray(ops["y"]) @ np.asarray(ops["router"])
+    want = np.argsort(-logits, axis=-1, kind="stable")[:, :6]
+    assert (np.asarray(idx) == want).all() and idx.dtype == jnp.int32
+    picked = np.take_along_axis(logits, want, axis=-1)
+    soft = np.exp(picked - picked.max(-1, keepdims=True))
+    soft /= soft.sum(-1, keepdims=True)
+    assert np.allclose(np.asarray(weights), soft, atol=1e-6)
+    assert np.allclose(np.asarray(weights).sum(-1), 1.0, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["auto", "pallas"])
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_routing_given_from_outside_equals_a_plain_loop(monkeypatch, act,
+                                                        impl):
+    """The experts computed on ``z`` under a routing made from another
+    tensor (``y``), with either gate, against a token and an expert at a
+    time; the router, its bias and the scale are not read."""
+    monkeypatch.setenv("CGX_CODEC_IMPL", impl)
+    ops = _dropless_operands(7, e=8)
+    z = jnp.asarray(np.random.default_rng(8).standard_normal((24, 16)),
+                    jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        routing = moe.softmax_topk_route(ops["y"], ops["router"], top_k=3)
+        out, stats = moe.dropless_moe(
+            z, None, None, ops["gate"], ops["up"], ops["down"], top_k=3,
+            dtype=jnp.float32, routing=routing, act=act)
+    want = _plain_experts(z, *routing, ops["gate"], ops["up"], ops["down"],
+                          act)
+    assert np.max(np.abs(np.asarray(out) - want)) < 1e-5 * np.max(
+        np.abs(want))
+    stats = dict(zip(moe.STATS, np.asarray(stats)))
+    assert stats["assignments"] == 24 * 3 and stats["dropped"] == 0
+    load = np.bincount(np.asarray(routing[0]).reshape(-1), minlength=8)
+    assert stats["load_max"] == load.max()
+    assert stats["experts_touched"] == (load > 0).sum()
+
+
+def test_the_relu_gate_is_not_the_silu_gate():
+    ops = _dropless_operands(9, e=8)
+    outs = [moe.dropless_moe(ops["y"], ops["router"], ops["bias"],
+                             ops["gate"], ops["up"], ops["down"], top_k=2,
+                             scale=1.0, dtype=jnp.float32, act=act)[0]
+            for act in ("silu", "relu")]
+    assert float(jnp.max(jnp.abs(outs[0] - outs[1]))) > 1e-2
+
+
+# The first 16 hex digits of the SHA-256 of ``dropless_moe``'s jaxpr as text
+# at the parent of PR 41, which added ``routing`` and ``act``, for the two
+# call sites the benchmark serves: JoyAI's (``mla_moe.ffn``: every expert
+# held, counted over a mask) and Ling's (``ling_hybrid.ffn_half``: a share
+# held, groups). With neither argument given the layer traces to the same
+# program, bit for bit.
+PARENT_JAXPR = {"joyai": "7ddd57813e2c10f4", "ling": "6249bac50af9f64b"}
+
+
+@pytest.mark.parametrize("site", sorted(PARENT_JAXPR))
+def test_defaults_trace_to_the_parents_jaxpr(site):
+    import hashlib
+
+    e, f = 8, 12
+    y = jnp.zeros((6, 16), jnp.float32)
+    router, bias = jnp.zeros((16, e)), jnp.zeros((e,))
+    experts = (jnp.zeros((e, 16, f)), jnp.zeros((e, 16, f)),
+               jnp.zeros((e, f, 16)))
+    if site == "joyai":
+        fn = lambda y, r, b, *w: moe.dropless_moe(  # noqa: E731
+            y, r, b, *w, top_k=2, scale=2.5, dtype=jnp.float32,
+            count_mask=jnp.ones((6,), bool))
+    else:
+        fn = lambda y, r, b, *w: moe.dropless_moe(  # noqa: E731
+            y, r, b, *(x[:4] for x in w), top_k=2, scale=2.5,
+            dtype=jnp.float32, n_group=4, topk_group=2, held=2)
+    text = str(jax.make_jaxpr(fn)(y, router, bias, *experts))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_JAXPR[site]

@@ -51,6 +51,13 @@ SHAPES = {
     "joyai_rows256_up": (65536, 256, 2048, 768, 256),
     "joyai_rows512_up": (131072, 256, 2048, 768, 256),
     "joyai_rows512_down": (131072, 256, 768, 2048, 256),
+    # The window/global cell (PR 41): 48 lanes x 6 a decode step, 4.5 rows a
+    # group; a prefill of 8,192 tokens, 768 rows a group, past
+    # ``MAX_GROUP_ROWS`` and so ``ragged_dot``'s today.
+    "smallthinker_decode_up": (288, 64, 2560, 768, 64),
+    "smallthinker_decode_down": (288, 64, 768, 2560, 64),
+    "smallthinker_prefill8k_up": (49152, 64, 2560, 768, 64),
+    "smallthinker_prefill8k_down": (49152, 64, 768, 2560, 64),
 }
 
 

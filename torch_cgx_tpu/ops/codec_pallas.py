@@ -400,7 +400,7 @@ def _quantize_flat_impl(
     jax.jit,
     static_argnames=(
         "bits", "bucket_size", "interpret", "tc", "with_add", "out_dtype",
-        "row_width",
+        "row_width", "name",
     ),
 )
 def _dequantize_flat_impl(
@@ -416,6 +416,7 @@ def _dequantize_flat_impl(
     with_add: bool = False,
     out_dtype=np.dtype(np.float32),
     row_width: Optional[int] = None,
+    name: str = "cgx_dequantize_flat",
 ):
     """Zero-relayout dequantize: words (rows, W) int32 + meta (rows, nb_r, 2)
     -> (rows, nb_r*B) ``out_dtype``. Word blocks are natural (., 128) flat
@@ -456,7 +457,9 @@ def _dequantize_flat_impl(
     block, so nothing gathers or reshapes the pool in front of the kernel.
     ``tc`` is the chunks a grid step decodes, a whole number of pages
     (:func:`_pages_tc`): one page operand pair a page, the body above once
-    a page. The ids must be valid rows (the caller clips its sentinels)."""
+    a page. The ids must be valid rows (the caller clips its sentinels).
+    ``name`` is the paged read's kernel name, for a call site that is timed
+    apart (a window layer's ring: ``cgx_dequantize_window``)."""
     b = bucket_size
     rb = b // 128
     nb_r = meta.shape[1]
@@ -524,7 +527,7 @@ def _dequantize_flat_impl(
 
         out = pl.pallas_call(
             _dequantize_pages_kernel,
-            name="cgx_dequantize_flat",
+            name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(rows // ppb,),
@@ -1009,6 +1012,7 @@ def dequantize_pages(
     out_dtype,
     row_width: int,
     interpret: bool = False,
+    name: str = "cgx_dequantize_flat",
 ) -> jax.Array:
     """The paged read: decode pool rows ``page_ids (n,)`` of a page pool
     kept in the flat kernel's operand layout (``words (pool rows, W/128,
@@ -1022,7 +1026,7 @@ def dequantize_pages(
     return _dequantize_flat_impl(
         words, meta, None, page_ids,
         bits=bits, bucket_size=bucket_size, interpret=interpret, tc=tc,
-        out_dtype=store, row_width=row_width,
+        out_dtype=store, row_width=row_width, name=name,
     ).astype(out_dtype)
 
 

@@ -128,15 +128,16 @@ def dequantize_pages(
     tile: int,
     out_dtype,
     row_width: int,
+    name: str = "cgx_dequantize_flat",
 ) -> jax.Array:
     """The paged cache read on Pallas dispatch
     (``codec_pallas.dequantize_pages``); ``ops/paged_kv.py`` decides
     whether a pool takes it (:func:`takes_pallas` and
-    ``PageSpec.paged_read_tile``)."""
+    ``PageSpec.paged_read_tile``) and what the kernel is called."""
     return codec_pallas.dequantize_pages(
         words, meta, page_ids, bits=cc.bits, bucket_size=cc.bucket_size,
         tc=tile, out_dtype=out_dtype, row_width=row_width,
-        interpret=not _on_tpu(),
+        interpret=not _on_tpu(), name=name,
     )
 
 

@@ -207,8 +207,15 @@ def pool_qtensor(
     )
 
 
+# A window layer's read of its ring (``serving/scheduler.py``, "Window and
+# global pages"): the same kernel under a name of its own, so that a trace
+# times the two page classes apart, and a lowering counter of its own.
+WINDOW_READ = "cgx_dequantize_window"
+
+
 def gather_dequant_pages(
-    pool, page_table: jax.Array, spec: PageSpec, dtype=jnp.float32
+    pool, page_table: jax.Array, spec: PageSpec, dtype=jnp.float32,
+    *, window: bool = False,
 ) -> jax.Array:
     """The decode program's paged KV read: decode the pool rows
     ``page_table (B, P)`` names for the consumer -> ``(B, P * page_tokens,
@@ -221,6 +228,9 @@ def gather_dequant_pages(
     bounds) and their decoded tokens are garbage by construction —
     callers mask attention scores by the lane's committed token count,
     never by inspecting decoded values. Every table entry is decoded.
+    ``window``: the table is a window layer's ring ``(B, ring)``; the kernel
+    is then called :data:`WINDOW_READ` and the call site counted as
+    ``cgx.codec.lowering.dequantize_pages.window.*``.
 
     Two lowerings, counted per call site as
     ``cgx.codec.lowering.dequantize_pages.*``: ``pallas_paged`` on Pallas
@@ -256,12 +266,13 @@ def gather_dequant_pages(
     if ops_dispatch.takes_pallas(spec.flat, spec.cc):
         tile = spec.paged_read_tile(b * p, dtype)
     codec_pallas.note_lowering(
-        "dequantize_pages", "pallas_paged" if tile else "xla_gather"
+        "dequantize_pages.window" if window else "dequantize_pages",
+        "pallas_paged" if tile else "xla_gather",
     )
     if tile:
         rows = ops_dispatch.dequantize_pages(
             words, meta, ids, spec.cc, tile=tile, out_dtype=dtype,
-            row_width=width,
+            row_width=width, **({"name": WINDOW_READ} if window else {}),
         )
     else:
         rows = ops_dispatch.dequantize_batch(

@@ -27,7 +27,9 @@ dispatch, shaped for the MXU and for GSPMD expert parallelism:
   chip's share of a layer that several chips divide), it routes over all of
   them and computes its own experts' part of the result, without the
   exchange; all of them is the default. Selection may be limited to the best
-  ``topk_group`` of ``n_group`` groups of experts.
+  ``topk_group`` of ``n_group`` groups of experts. The routing may also be
+  the caller's (``routing``: a model whose router reads another tensor than
+  the experts compute on, :func:`softmax_topk_route`), and the gate a ReLU.
 
 Composes with the quantized gradient allreduce: expert weights are regular
 pytree leaves, so per-layer compression configs apply (pattern
@@ -264,13 +266,35 @@ def sigmoid_topk_route(y, router, bias, *, top_k: int, scale: float,
     return idx.astype(jnp.int32), weights
 
 
+def softmax_topk_route(y, router, *, top_k: int):
+    """``y (T, D)`` -> the chosen experts ``(T, top_k)`` int32 and their
+    combine weights ``(T, top_k)`` float32: the ``top_k`` largest logits ``y
+    W_r`` (float32 at full matmul precision, as in
+    :func:`sigmoid_topk_route`) and the softmax over those alone, so the
+    weights sum to 1."""
+    logits = jnp.matmul(
+        y.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    chosen, idx = jax.lax.top_k(logits, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
+
+
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def dropless_moe(y, router, bias, gate, up, down, *, top_k: int,
-                 scale: float, dtype=jnp.bfloat16, count_mask=None,
+                 scale: float = 1.0, dtype=jnp.bfloat16, count_mask=None,
                  n_group: int = 1, topk_group: int = 1,
-                 held: Optional[int] = None):
+                 held: Optional[int] = None, routing=None,
+                 act: str = "silu"):
     """``sum_i w_i E_i(y)`` over the ``top_k`` routed experts of every row
     of ``y (T, D)``; experts ``gate, up (E, D, F)``, ``down (E, F, D)``,
-    each ``down(silu(gate y) * up y)``. Returns ``(out (T, D) dtype, stats
+    each ``down(act(gate y) * up y)``, ``act`` ``silu`` or ``relu``.
+    ``routing``, where given, is ``(idx (T, top_k) int32, weights (T, top_k)
+    float32)`` from the caller (``router``, ``bias``, ``scale`` and the
+    groups are then not read); otherwise :func:`sigmoid_topk_route`
+    decides. Returns ``(out (T, D) dtype, stats
     (4,) int32)``, ``stats`` as :data:`STATS` names them: assignments made,
     experts that got at least one, the largest expert's load, and tokens
     dropped (assignments asked for less assignments computed; 0 by
@@ -286,10 +310,12 @@ def dropless_moe(y, router, bias, gate, up, down, *, top_k: int,
     less those computed, and the held assignments."""
     t, _ = y.shape
     e = gate.shape[0]
-    idx, weights = sigmoid_topk_route(
-        y, router, bias, top_k=top_k, scale=scale, n_group=n_group,
-        topk_group=topk_group,
-    )
+    if routing is None:
+        routing = sigmoid_topk_route(
+            y, router, bias, top_k=top_k, scale=scale, n_group=n_group,
+            topk_group=topk_group,
+        )
+    idx, weights = routing
     flat = idx.reshape(-1)
     if held is not None:
         here = (flat >= held) & (flat < held + e)
@@ -303,7 +329,7 @@ def dropless_moe(y, router, bias, gate, up, down, *, top_k: int,
     def product(rows, weights):
         return dispatch.grouped_matmul(rows, weights.astype(dtype), sizes)
 
-    h = jax.nn.silu(product(xs, gate)) * product(xs, up)
+    h = _GATES[act](product(xs, gate)) * product(xs, up)
     rows = product(h, down)
     rows = rows.astype(jnp.float32) * weights.reshape(-1)[order][:, None]
     if held is not None:

@@ -23,6 +23,9 @@ inference:
   per-lane recurrent state on the others (``conv``, ``ssm`` on Mamba-2
   layers; ``conv`` and ``gdn`` or ``kda``, a matrix a head, on delta-rule
   layers).
+* :mod:`.window` — the window/global adapter: ``k``, ``v`` pages on every
+  layer, a sliding-window layer's kept as a ring a lane beside the global
+  page table, dropless experts routed from the block's input.
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -42,5 +45,6 @@ from .hybrid import (  # noqa: F401
     HybridLatentMoEServer,
     HybridSSMServer,
 )
+from .window import WindowMoEServer  # noqa: F401
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401

@@ -16,6 +16,16 @@ the serving SLO controller's write target — wins per layer; otherwise
 baseline). ``CGX_WIRE=off`` forces every page raw, the same one-knob
 bisection story as every other edge kind.
 
+Two page classes (docs/SERVING.md, "Window and global pages"). A *global*
+page is what the above describes: a sequence holds as many as it is long.
+A sliding-window layer's pages are a *ring*: a sequence never needs more
+than ``ring`` of them, so it is given a ring whole (``alloc_ring``; ring
+``g`` is the ``ring`` consecutive rows from ``g * ring`` of the window
+layers' pools) and writes page ``n`` over page ``n - ring`` in slot ``n %
+ring``. A ring is returned with the sequence's pages (``free_seq``,
+``invalidate``), so no window page outlives its sequence, and it is never
+forked.
+
 Recovery cascade (ISSUE 15 satellite): live caches register in a module
 WeakSet; ``supervisor.invalidate_trace_caches`` reaches
 :func:`invalidate_page_tables`, which bumps every live cache's
@@ -87,7 +97,7 @@ class PagedKvCache:
     (``ops/paged_kv.py`` pools) — this class owns which rows mean what.
     """
 
-    def __init__(self, max_pages: int, page_tokens: int):
+    def __init__(self, max_pages: int, page_tokens: int, rings: int = 0):
         if max_pages < 1 or page_tokens < 1:
             raise ValueError(
                 f"max_pages/page_tokens must be >= 1, got "
@@ -95,11 +105,14 @@ class PagedKvCache:
             )
         self.max_pages = int(max_pages)
         self.page_tokens = int(page_tokens)
+        self.rings = int(rings)  # window-class rings to hand out (0: none)
         self.generation = 0
         self._lock = threading.Lock()
         self._free: List[int] = list(range(max_pages - 1, -1, -1))
         self._refs: Dict[int, int] = {}
         self._seqs: Dict[str, _SeqEntry] = {}
+        self._free_rings: List[int] = list(range(self.rings - 1, -1, -1))
+        self._ring_of: Dict[str, int] = {}
         _LIVE.add(self)
 
     # -- introspection -----------------------------------------------------
@@ -191,6 +204,24 @@ class PagedKvCache:
             memledger.note_alloc("serve.kv_pool")
             return pid
 
+    def alloc_ring(self, seq_id: str) -> Optional[int]:
+        """The window-class ring of ``seq_id`` (one a sequence, taken on
+        first use). None when every ring is held: admission backpressure,
+        like :meth:`alloc`."""
+        with self._lock:
+            ring = self._ring_of.get(seq_id)
+            if ring is None:
+                if not self._free_rings:
+                    metrics.add("cgx.serve.pool_exhausted")
+                    return None
+                ring = self._ring_of[seq_id] = self._free_rings.pop()
+            return ring
+
+    @property
+    def free_rings(self) -> int:
+        with self._lock:
+            return len(self._free_rings)
+
     def fork(self, src_seq: str, dst_seq: str) -> List[int]:
         """Share ``src_seq``'s committed pages into a new sequence
         (prefix reuse): every shared page's refcount bumps; the fork
@@ -218,6 +249,9 @@ class PagedKvCache:
         Unknown sequences are a no-op (eviction paths race completion).
         Returns the number of pages actually returned to the pool."""
         with self._lock:
+            ring = self._ring_of.pop(seq_id, None)
+            if ring is not None:
+                self._free_rings.append(ring)
             e = self._seqs.pop(seq_id, None)
             if e is None:
                 return 0
@@ -267,6 +301,8 @@ class PagedKvCache:
             self._seqs.clear()
             self._refs.clear()
             self._free = list(range(self.max_pages - 1, -1, -1))
+            self._ring_of.clear()
+            self._free_rings = list(range(self.rings - 1, -1, -1))
             self.generation += 1
             metrics.add("cgx.serve.cache_invalidations")
             self._publish_gauges_locked()

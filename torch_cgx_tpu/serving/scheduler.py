@@ -15,6 +15,19 @@ rewritten whole by every decode step, written into a lane at admission from
 what the prefill left on the device, and never committed, paged, forked or
 evicted by page.
 
+A layer's pages are of one of two CLASSES, which the adapter states
+(``page_window(layer)``, "Window and global pages" in docs/SERVING.md).
+*Global* pages are the above: the lane's row of ``page_table``, as many as
+the sequence is long. A sliding-window layer's pages are a *ring*: ``ring =
+ceil(W / page_tokens) + 1`` pool rows a sequence (``kv_cache.alloc_ring``),
+named by the lane's row of a second table, ``ring_table (lanes, ring)``;
+page ``n`` is written into slot ``n % ring``, over page ``n - ring``, which
+no query of the lane can see again. Such a layer's pools hold ``max_batch x
+ring + 1`` rows whatever ``max_seq`` is, its decode read goes over the ring
+alone, and its mask is made of positions (:func:`ring_masks`). An adapter
+that states no window builds exactly the programs and the state it built
+before there were classes.
+
 The decode worker runs ONE compiled step program: for every lane of a
 fixed ``CGX_SERVE_MAX_BATCH``-wide batch, gather the lane's committed KV
 pages (``ops/paged_kv.gather_dequant_pages`` — ``cfg.dtype`` rows as the
@@ -147,6 +160,8 @@ _TICK_ACCOUNT = tuple(
 # The per-lane bookkeeping of the decode state, and what ``release_lanes``
 # resets each entry of a finished or evicted lane to.
 _LANE_RESET = {"active": False, "n_pages": 0, "tail_len": 0, "page_table": -1}
+# The same of the second table, which a model with window layers keeps.
+_RING_RESET = {"ring_table": -1}
 
 
 # ---------------------------------------------------------------------------
@@ -253,6 +268,12 @@ class Request:
 #                   layers' union, in order of first appearance
 #   state_streams(l)  the layer's recurrent state a lane, ``((name, shape,
 #                   dtype), ...)``; ``()`` for a layer (or a model) with none
+#   page_window(l)  optional. The class of the layer's pages: 0, global (the
+#                   lane's ``page_table`` row); ``W``, the layer attends the
+#                   last ``W`` positions and keeps its pages as a ring (the
+#                   lane's ``ring_table`` row, :func:`ring_pages` slots). All
+#                   window layers of a model state one ``W``. An adapter
+#                   without the method has global pages alone
 #   with_params(p)  the adapter over another (traced) parameter tree
 #   kv_bytes_per_token()  float32 bytes a token's cache weighs, all layers
 #   state_bytes_per_lane()  float32 bytes of a lane's state streams, all layers
@@ -272,7 +293,8 @@ class Request:
 #                   holds ``pools[l][stream]``, ``tail_<stream>[l]`` and
 #                   ``state_<state stream>[l]``
 #
-# ``pools[l][stream]`` is ``paged_kv.empty_pool(max_pages + 1, spec)``: for
+# ``pools[l][stream]`` is ``paged_kv.empty_pool(max_pages + 1, spec)``
+# (``max_batch * ring + 1`` for a window layer): for
 # a quantized stream ``(words (max_pages + 1, *spec.word_shape) int32, meta
 # (max_pages + 1, num_buckets, 2) f32)``, a page's wire words as rows of
 # 128 — the flat decode kernel's own blocks, so the read fetches a page from
@@ -343,8 +365,32 @@ def lane_masks(serve: ServeConfig, state):
     return tail_idx, mask_c, mask_t
 
 
+def ring_pages(serve: ServeConfig, window: int) -> int:
+    """Pool rows a sequence's ring holds on a layer of window ``window``:
+    the pages that can hold a visible key while the tail fills, and the one
+    that has slid out, which the next commit writes over."""
+    return -(-window // serve.page_tokens) + 1
+
+
+def ring_masks(serve: ServeConfig, state, window: int):
+    """A window layer's ``mask_c (B, ring x page_tokens)`` beside
+    :func:`lane_masks`' (whose tail mask holds as it is: a tail is never
+    longer than a page, and a window never shorter). Slot ``s`` of a lane's
+    ring holds the newest committed page ``n`` with ``n % ring == s``; its
+    row ``r`` is position ``n * page_tokens + r``, live where the lane's
+    token at ``pos`` can see it: ``pos - position < window``."""
+    pt = serve.page_tokens
+    ring = ring_pages(serve, window)
+    b = state["tokens"].shape[0]
+    at = jax.lax.broadcasted_iota(jnp.int32, (b, ring * pt), 1)
+    newest = state["n_pages"][:, None] - 1
+    page = newest - (newest - at // pt) % ring  # under 0: never written
+    position = page * pt + at % pt
+    return (page >= 0) & (state["pos"][:, None] - position < window)
+
+
 def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
-                     dtype):
+                     dtype, window: bool = False):
     """A layer's cache as its attention contracts it, at a decode position:
     for each of the layer's streams, in order, this token's payload (the
     matching entry of ``fresh``, ``(B, ...)`` of the stream's width) written
@@ -352,14 +398,16 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
     pages read where they lie (``paged_kv.gather_dequant_pages``). Returns
     ``({stream: pages (B, P * page_tokens, width)}, {stream: tail rows (B,
     page_tokens, width)}``, both in ``dtype``, ``{stream: the new float32
-    tail})``."""
+    tail})``. ``window``: the layer's pages are the lane's ring, ``P`` its
+    slots, in the ring's order (a softmax does not care)."""
+    table = state["ring_table" if window else "page_table"]
     pages, tails, new = {}, {}, {}
     for (name, spec), value in zip(layer_streams, fresh):
         new[name], tails[name] = paged_kv.append_tail_rows(
             state[f"tail_{name}"][layer], tail_idx, value, dtype
         )
         pages[name] = paged_kv.gather_dequant_pages(
-            state["pools"][layer][name], state["page_table"], spec, dtype
+            state["pools"][layer][name], table, spec, dtype, window=window,
         )
     return pages, tails, new
 
@@ -528,6 +576,37 @@ def _resolved_state_streams(server) -> Tuple:
     )
 
 
+def _resolved_windows(server) -> Tuple[int, ...]:
+    """Every layer's page class as the adapter states it
+    (``page_window``): 0 for global pages, the window for a ring."""
+    page_window = getattr(server, "page_window", None)
+    if page_window is None:
+        return (0,) * server.n_layer
+    return tuple(int(page_window(layer)) for layer in range(server.n_layer))
+
+
+def _ring(server, streams, windows) -> int:
+    """Slots of a lane's ring (:func:`ring_pages` of the window layers' one
+    window), 0 for a model without window layers."""
+    found = sorted({w for w in windows if w})
+    if not found:
+        return 0
+    if len(found) > 1:
+        raise ValueError(
+            f"adapter {server.kind!r} states the windows {found}: the lanes "
+            "keep one ring table, so every window layer has the same window"
+        )
+    if found[0] < server.serve.page_tokens:
+        raise ValueError(
+            f"window {found[0]} is shorter than a page "
+            f"({server.serve.page_tokens} tokens): the tail would outlive it"
+        )
+    bare = [l for l, w in enumerate(windows) if w and not streams[l]]
+    if bare:
+        raise ValueError(f"layers {bare} state a window and no cache stream")
+    return ring_pages(server.serve, found[0])
+
+
 def _stream_names(streams) -> Tuple[str, ...]:
     """The names the layers' streams (cache or state) go by: their union,
     in order of first appearance."""
@@ -575,6 +654,7 @@ def _program_key(server) -> Tuple:
          server.serve.max_pages, server.serve.max_seq),
         _resolved_streams(server),
         _resolved_state_streams(server),
+        _resolved_windows(server),
         cfg_mod.registry_version(),
         cfg_mod.trace_knob_fingerprint(),
     )
@@ -623,6 +703,8 @@ def _build_programs(server) -> SimpleNamespace:
     holders = {**_holders(streams), **_holders(state_streams)}
     n_layer = server.n_layer
     sv = server.serve
+    windows = _resolved_windows(server)
+    ring = _ring(server, streams, windows)
 
     def decode_step(params, state):
         """One token for every lane. Returns the new state and what the
@@ -646,9 +728,11 @@ def _build_programs(server) -> SimpleNamespace:
             nxt = jnp.concatenate([nxt, counts.astype(jnp.int32)])
         return out, nxt
 
-    def commit(state, lanes, page_ids):
+    def commit(state, lanes, page_ids, ring_ids=None):
         """Promote the full tails of ``lanes (K,)`` into pool pages
-        ``page_ids (K,)``, ``K = ServeConfig.commit_lanes``: the K lanes'
+        ``page_ids (K,)`` (a window layer's into ``ring_ids (K,)``, rows of
+        its own pools: the lane's ring slot ``n_pages % ring``, whose last
+        page has slid out of the window), ``K = ServeConfig.commit_lanes``: the K lanes'
         tails alone are gathered (rows as they are kept, flattened to ``(K,
         page_tokens * width)`` payloads), quantized and scattered, a layer
         and a stream at a time, and their ``page_table`` slot,
@@ -664,7 +748,8 @@ def _build_programs(server) -> SimpleNamespace:
         out["pools"] = tuple(
             {
                 name: paged_kv.commit_page_rows(
-                    state["pools"][layer][name], page_ids,
+                    state["pools"][layer][name],
+                    ring_ids if windows[layer] else page_ids,
                     state[f"tail_{name}"][layer][lanes].reshape(k, -1), spec,
                 )
                 for name, spec in streams[layer]
@@ -676,6 +761,10 @@ def _build_programs(server) -> SimpleNamespace:
         out["page_table"] = state["page_table"].at[
             at, state["n_pages"][lanes]
         ].set(page_ids, mode="drop")
+        if ring:
+            out["ring_table"] = state["ring_table"].at[
+                at, state["n_pages"][lanes] % ring
+            ].set(ring_ids, mode="drop")
         out["n_pages"] = state["n_pages"].at[at].add(1, mode="drop")
         out["tail_len"] = state["tail_len"].at[at].set(0, mode="drop")
         return out
@@ -709,7 +798,7 @@ def _build_programs(server) -> SimpleNamespace:
     observe_qerr = cfg_mod.qerr_stats()  # in the program key's fingerprint
 
     def prefill_pages(params, pools, tokens, positions, last_idx, ids,
-                      tail_len):
+                      tail_len, ring_ids=None):
         """The local prefill of one padded prompt, whole: forward, then
         every page of every layer's streams through ``commit_page_rows``
         into the donated pools at ``ids (padded pages,)``, and the last
@@ -723,7 +812,11 @@ def _build_programs(server) -> SimpleNamespace:
         programs were built. Last, the lane's recurrent state after
         ``last_idx`` as the adapter's prefill left it, ``{state stream: (its
         layers, *shape)}``, empty for a model without state streams. Tails
-        and states are stacked over the layers that have the stream."""
+        and states are stacked over the layers that have the stream. A
+        window layer writes the prompt's last ``ring_ids.shape[0]`` padded
+        pages alone (at most ``ring + 1``: the pages its ring keeps are
+        among them), into ``ring_ids``; the host names the scratch row for
+        those that have slid out already."""
         first, payloads = prefill(params, tokens, positions, last_idx)
         n_pages = ids.shape[0]
         live = jax.lax.broadcasted_iota(
@@ -736,7 +829,9 @@ def _build_programs(server) -> SimpleNamespace:
                 x = payloads[name][layer][0]  # (padded tokens, H, Dh)
                 rows = x.reshape(n_pages, -1)
                 written[name] = paged_kv.commit_page_rows(
-                    pool[name], ids, rows, spec
+                    pool[name],
+                    *((ring_ids, rows[-ring_ids.shape[0]:])
+                      if windows[layer] else (ids, rows)), spec,
                 )
                 tails[name].append(jnp.where(
                     live, x[-sv.page_tokens:].reshape(sv.page_tokens, -1),
@@ -758,20 +853,21 @@ def _build_programs(server) -> SimpleNamespace:
         )
 
     def admit_lane(state, lane, table_row, n_pages, tail_len, token, pos,
-                   tails, states):
+                   tails, states, ring_row=None):
         """Write one ready request into lane ``lane`` of the donated
         state: its page-table row, counts, first token (a scalar still on
         the device from the local prefill, or a host one from a page
         stream) and position, its stacked tails ``{stream: (L, page_tokens,
         H * Dh)}``, device or host arrays alike, and its recurrent state
         ``{state stream: (L, *shape)}`` (whatever the lane's last request
-        left there is overwritten whole)."""
+        left there is overwritten whole). ``ring_row (ring,)``: the lane's
+        row of ``ring_table``, where a layer has a window."""
         out = dict(state)
         for name, value in (
             ("page_table", table_row), ("n_pages", n_pages),
             ("tail_len", tail_len), ("tokens", token), ("pos", pos),
             ("active", True),
-        ):
+        ) + ((("ring_table", ring_row),) if ring else ()):
             out[name] = state[name].at[lane].set(value)
         for prefix, which, written in (("tail", names, tails),
                                        ("state", state_names, states)):
@@ -786,10 +882,11 @@ def _build_programs(server) -> SimpleNamespace:
     def release_lanes(lanes, mask):
         """Reset the lane bookkeeping (``_LANE_RESET``) of the lanes in
         ``mask (B,) bool``: finished or evicted, once a tick."""
+        reset = {**_LANE_RESET, **_RING_RESET}
         return {
             name: jnp.where(
                 mask.reshape((-1,) + (1,) * (value.ndim - 1)),
-                _LANE_RESET[name], value,
+                reset[name], value,
             )
             for name, value in lanes.items()
         }
@@ -797,6 +894,15 @@ def _build_programs(server) -> SimpleNamespace:
     return SimpleNamespace(
         streams=streams,
         names=names,
+        windows=windows,
+        window=max(windows),
+        ring=ring,
+        # Cache streams over the layers of each class: (global, window).
+        class_streams=tuple(
+            sum(len(layer) for layer, w in zip(streams, windows)
+                if bool(w) == ringed)
+            for ringed in (False, True)
+        ),
         state_streams=state_streams,
         state_names=state_names,
         specs=_leading_specs(streams),
@@ -852,6 +958,9 @@ class _Ready:
     # the prompt's last token, left on the device by the local prefill;
     # empty for a model without state streams.
     states: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
+    # Its ring of the window layers' pools (``kv_cache.alloc_ring``), for a
+    # model with window layers.
+    ring: Optional[int] = None
     # End of the prefill (or ingest) that built it, on ``submitted_at``'s
     # clock: ``cgx.serve.ready_wait_s`` counts from here to the lane write.
     ready_at: float = dataclasses.field(default_factory=time.monotonic)
@@ -907,11 +1016,16 @@ class ContinuousBatchScheduler:
         memledger.maybe_start()
         self._gc_pauses = install_gc_hook()
         install_compile_listener()
-        self.cache = kv_mod.PagedKvCache(sv.max_pages, sv.page_tokens)
-        self._cache_gen = self.cache.generation
         self._prog = _decode_program(server)
         self._prog_key = _program_key(server)
+        # A ring a lane for the window layers, where the model has any.
+        self.cache = kv_mod.PagedKvCache(
+            sv.max_pages, sv.page_tokens,
+            rings=sv.max_batch if self._prog.ring else 0,
+        )
+        self._cache_gen = self.cache.generation
         self._state_bytes = 0  # the recurrent state held (memledger owner)
+        self._window_bytes = 0  # the window layers' pools (memledger owner)
         self._state = self._fresh_state()
         self._lanes: List[Optional[Request]] = [None] * sv.max_batch
         self._waiting: List[Request] = []  # local-prefill queue
@@ -927,6 +1041,11 @@ class ContinuousBatchScheduler:
         # for a free lane).
         self._tail_len = np.zeros((sv.max_batch,), np.int64)
         self._left = np.zeros((sv.max_batch,), np.int64)
+        # With window layers: the state's ``n_pages`` a lane, counted here
+        # like the tail lengths (the ring slot a commit writes, the live
+        # pages a step reads), and the lane's ring.
+        self._n_pages = np.zeros((sv.max_batch,), np.int64)
+        self._ring_of = np.zeros((sv.max_batch,), np.int64)
         # Dispatched and unread, in device order: lane writes whose first
         # token nobody has read, and decode steps (two at most, the second
         # only by :meth:`_runs_ahead`).
@@ -952,15 +1071,20 @@ class ContinuousBatchScheduler:
         sv = self.server.serve
         streams = self._prog.streams
         b = sv.max_batch
+        ring = self._prog.ring
         pools = tuple(
             {
                 # +1 row: scratch, where a padded slot of commit() and a
-                # prefill's last page that is a tail write; never read.
-                name: paged_kv.empty_pool(sv.max_pages + 1, spec)
+                # prefill's last page that is a tail write; never read. A
+                # window layer holds a ring a lane, whatever ``max_seq``.
+                name: paged_kv.empty_pool(
+                    (b * ring if window else sv.max_pages) + 1, spec)
                 for name, spec in layer
             }
-            for layer in streams
+            for layer, window in zip(streams, self._prog.windows)
         )
+        if ring:
+            self._note_window_pools(pools)
         # A layer without the stream holds None in the stream's tuple, so
         # that every per-layer entry is found at its layer's index. A tail
         # is kept as the rows the attention contracts and the commit
@@ -1010,7 +1134,34 @@ class ContinuousBatchScheduler:
             "tokens": jnp.zeros((b,), jnp.int32),
             "pos": jnp.zeros((b,), jnp.int32),
             "active": jnp.zeros((b,), bool),
+            **({"ring_table": jnp.full((b, ring), -1, jnp.int32)}
+               if ring else {}),
         }
+
+    def _note_window_pools(self, pools) -> None:
+        """The bytes the pools hold by page class, as gauges
+        (``cgx.serve.kv.pool_bytes.window`` / ``.global``, and ``.uniform``:
+        what the window layers would hold with ``max_pages + 1`` rows like
+        the others) and, the window layers', as the memory ledger's owner
+        ``serve.kv.window``."""
+        sv = self.server.serve
+        held = {True: 0, False: 0}
+        uniform = 0
+        for layer, window in zip(pools, self._prog.windows):
+            nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(layer))
+            held[bool(window)] += nbytes
+            if window:
+                rows = sv.max_batch * self._prog.ring + 1
+                uniform += nbytes // rows * (sv.max_pages + 1)
+        for name, value in (("window", held[True]), ("global", held[False]),
+                            ("uniform", uniform)):
+            metrics.set(f"cgx.serve.kv.pool_bytes.{name}", float(value))
+        if self._window_bytes:  # a rebuild drops the old pools
+            memledger.note_release("serve.kv.window", n=sv.max_batch,
+                                   nbytes=self._window_bytes)
+        memledger.note_alloc("serve.kv.window", n=sv.max_batch,
+                             nbytes=held[True])
+        self._window_bytes = held[True]
 
     def _maybe_rebuild(self) -> None:
         """Program-era and cache-generation checks, once per step.
@@ -1087,6 +1238,7 @@ class ContinuousBatchScheduler:
         self._steps.clear()
         self._tail_len[:] = 0
         self._left[:] = 0
+        self._n_pages[:] = 0
         if requeued:
             log.info(
                 "serving scheduler reset (%s): %d request(s) requeued "
@@ -1372,12 +1524,15 @@ class ContinuousBatchScheduler:
 
     def _note_pages(self, n_pages: int) -> None:
         """``n_pages`` pages of every stream of every layer went into the
-        pools: the wire plane's ``kv_page`` accounting."""
+        pools (of a window layer no more than its ring keeps): the wire
+        plane's ``kv_page`` accounting."""
         if not n_pages:
             return
         for layer, layer_streams in enumerate(self._prog.streams):
+            kept = (min(n_pages, self._prog.ring)
+                    if self._prog.windows[layer] else n_pages)
             for _, spec in layer_streams:
-                _account_pages(self.server.layer_name(layer), spec, n_pages)
+                _account_pages(self.server.layer_name(layer), spec, kept)
 
     # -- local prefill (colocated mode + the failover rung) ---------------
 
@@ -1398,8 +1553,14 @@ class ContinuousBatchScheduler:
                 self.cache.free_seq(req.id)
                 return None  # pool pressure: stay queued
             pids.append(pid)
+        ring = None
+        if self._prog.ring:
+            ring = self.cache.alloc_ring(req.id)
+            if ring is None:
+                self.cache.free_seq(req.id)
+                return None  # every ring is held: stay queued
         try:
-            return self._local_prefill_compute(req, prompt, pids)
+            return self._local_prefill_compute(req, prompt, pids, ring)
         except BaseException:
             # A prefill failure (jit error, bad prompt) must release the
             # pages it reserved — the request re-enters the queue or
@@ -1408,10 +1569,12 @@ class ContinuousBatchScheduler:
             raise
 
     def _local_prefill_compute(
-        self, req: Request, prompt: np.ndarray, pids: List[int]
+        self, req: Request, prompt: np.ndarray, pids: List[int],
+        ring: Optional[int] = None,
     ) -> _Ready:
         """The dispatch of one request's prefill, its full pages ``pids``
-        reserved: one call of the ``prefill_pages`` program, which leaves
+        (and, for a model with window layers, its ``ring``) reserved: one
+        call of the ``prefill_pages`` program, which leaves
         the pages in the pools and the tails and the first token on the
         device. Nothing is read here: the ``serve.prefill.local`` span
         opened now is closed by :meth:`_read_first_tokens`."""
@@ -1439,6 +1602,7 @@ class ContinuousBatchScheduler:
                         self.server.p, self._state["pools"], padded[None],
                         np.arange(padded.shape[0], dtype=np.int32)[None],
                         np.int32(s - 1), ids, np.int32(tail_len),
+                        *self._ring_rows(ring, n_full, len(ids)),
                     )
                 )
                 self._state["pools"] = pools
@@ -1452,7 +1616,31 @@ class ContinuousBatchScheduler:
             req=req, page_ids=pids, tails=tails,
             tail_len=tail_len, first_token=first, pos=s,
             states=states, span=(start, fields), qerr_rows=qerr_rows,
+            ring=ring,
         )
+
+    def _slot_rows(self, ring_id, pages):
+        """The window pools' rows of pages ``pages`` of the sequence that
+        holds ring ``ring_id`` (arrays or numbers): page ``n`` lies in slot
+        ``n % ring`` of its ring."""
+        return ring_id * self._prog.ring + pages % self._prog.ring
+
+    def _ring_rows(self, ring_id: Optional[int], n_full: int,
+                   n_padded: int):
+        """``prefill_pages``' last operand for a model with window layers
+        (nothing for one without): the window pools' row of each of the
+        prompt's last ``ring + 1`` padded pages. Page ``n`` of the ``ring``
+        newest full ones goes to slot ``n % ring`` of the request's ring;
+        an older one has slid out and a last page that is a tail is no
+        page, and both go to the scratch row."""
+        ring = self._prog.ring
+        if not ring:
+            return ()
+        pages = np.arange(max(n_padded - ring - 1, 0), n_padded)
+        kept = (pages >= n_full - ring) & (pages < n_full)
+        scratch = self.server.serve.max_batch * ring
+        return (np.where(kept, self._slot_rows(ring_id, pages),
+                         scratch).astype(np.int32),)
 
     @staticmethod
     def _close_prefill_span(span: Optional[Tuple[float, Dict]],
@@ -1529,11 +1717,20 @@ class ContinuousBatchScheduler:
             table_row = np.full((sv.pages_per_seq,), -1, np.int32)
             table_row[: len(ready.page_ids)] = ready.page_ids
             first = ready.first_token
+            n_full, ring = len(ready.page_ids), self._prog.ring
+            ring_row = ()
+            if ring:  # the slots the prefill wrote (``_ring_rows``)
+                pages = np.arange(max(n_full - ring, 0), n_full)
+                ring_row = np.full((ring,), -1, np.int32)
+                ring_row[pages % ring] = self._slot_rows(ready.ring, pages)
+                ring_row = (ring_row,)
+                self._n_pages[lane] = n_full
+                self._ring_of[lane] = ready.ring
             self._state = self._prog.admit_lane(
                 self._state, np.int32(lane), table_row,
-                np.int32(len(ready.page_ids)), np.int32(ready.tail_len),
+                np.int32(n_full), np.int32(ready.tail_len),
                 np.int32(first) if isinstance(first, int) else first,
-                np.int32(ready.pos), ready.tails, ready.states,
+                np.int32(ready.pos), ready.tails, ready.states, *ring_row,
             )
             if ready.states:
                 metrics.add("cgx.serve.state.lane_writes")
@@ -1642,8 +1839,10 @@ class ContinuousBatchScheduler:
         mask[self._released] = True
         self._released.clear()
         self._tail_len[mask] = _LANE_RESET["tail_len"]
+        self._n_pages[mask] = _LANE_RESET["n_pages"]
+        names = (*_LANE_RESET, *(_RING_RESET if self._prog.ring else ()))
         self._state.update(self._prog.release_lanes(
-            {name: self._state[name] for name in _LANE_RESET}, mask
+            {name: self._state[name] for name in names}, mask
         ))
 
     # -- decode ------------------------------------------------------------
@@ -1724,10 +1923,26 @@ class ContinuousBatchScheduler:
             )
             self._fed()
         held = [i for i, r in enumerate(self._lanes) if r is not None]
+        if self._prog.ring:
+            self._note_live_pages(held)
         self._tail_len[held] += 1
         lanes = {i: self._lanes[i] for i in held if self._left[i] > 0}
         self._left[list(lanes)] -= 1
         self._steps.append(_Step(tokens=tokens, lanes=lanes))
+
+    def _note_live_pages(self, held: List[int]) -> None:
+        """The pages the step just dispatched has a visible key in, summed
+        over the held lanes, by class and for one layer of the class, from
+        the host's own counts: every committed page of a global layer; of a
+        window layer those from the page that holds the oldest position the
+        lane's token (at ``n_pages * page_tokens + tail_len``) can see."""
+        pt, window = self.server.serve.page_tokens, self._prog.window
+        n_pages = self._n_pages[held]
+        oldest = np.maximum(
+            n_pages * pt + self._tail_len[held] - window + 1, 0) // pt
+        metrics.add("cgx.serve.kv.live_pages.global", float(n_pages.sum()))
+        metrics.add("cgx.serve.kv.live_pages.window",
+                    float(np.maximum(n_pages - oldest, 0).sum()))
 
     def _commit_full_tails(self) -> None:
         """Promote full tails into pool pages, so that every lane has
@@ -1791,6 +2006,7 @@ class ContinuousBatchScheduler:
                     self._state,
                     np.asarray(lanes + lanes[:1] * pad, np.int32),
                     np.asarray(ids + [sv.max_pages] * pad, np.int32),
+                    *self._ring_slots(lanes, pad),
                 )
                 self._fed()
         calls = -(-len(committed) // k)
@@ -1799,11 +2015,29 @@ class ContinuousBatchScheduler:
         metrics.add("cgx.serve.commit.lanes", float(len(committed)))
         self._tail_len[committed] = 0
         self._note_pages(len(committed))
-        metrics.add(
-            "cgx.serve.pages_committed",
-            float(sum(len(layer) for layer in self._prog.streams)
-                  * len(committed)),
-        )
+        of_global, of_window = self._prog.class_streams
+        metrics.add("cgx.serve.pages_committed",
+                    float(of_global * len(committed)))
+        if self._prog.ring:
+            # A page written over one that slid out: the ring had turned.
+            recycled = int((self._n_pages[committed] >= self._prog.ring).sum())
+            self._n_pages[committed] += 1
+            metrics.add("cgx.serve.window.pages_committed",
+                        float(of_window * len(committed)))
+            metrics.add("cgx.serve.window.pages_recycled",
+                        float(of_window * recycled))
+
+    def _ring_slots(self, lanes: List[int], pad: int):
+        """``commit``'s last operand for a model with window layers
+        (nothing for one without): the window pools' row each lane's full
+        tail goes to, slot ``n_pages % ring`` of the lane's ring; the
+        scratch row for a padded slot."""
+        ring = self._prog.ring
+        if not ring:
+            return ()
+        rows = self._slot_rows(self._ring_of[lanes], self._n_pages[lanes])
+        scratch = self.server.serve.max_batch * ring
+        return (np.asarray(list(rows) + [scratch] * pad, np.int32),)
 
     def _note_read(self) -> None:
         """A blocking copy from the device has just returned: count it,

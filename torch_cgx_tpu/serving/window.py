@@ -1,0 +1,140 @@
+"""The window/global adapter: a grouped-query decoder most of whose layers
+attend a sliding window, with dropless experts routed from the block's input
+(``models/window_moe.py``), behind the one scheduler.
+
+Every layer leaves ``k`` and ``v`` pages, rows of ``n_kv_head * d_head``
+(``k`` as the scores contract it: rotated on a window layer, bare on a
+global one). What differs between layers is the pages' CLASS
+(``page_window``, "Window and global pages" in docs/SERVING.md): a global
+layer's are the lane's page table, as long as the sequence; a window
+layer's are the lane's ring, ``W / page_tokens + 1`` pool rows whatever the
+sequence's length, read under a kernel name of its own
+(``cgx_dequantize_window``) and masked by position
+(``scheduler.ring_masks``). Prefill attends in query blocks, a window
+layer's block over the band of keys it can see
+(``window_moe.attend_blocks``).
+
+Page geometry is the streams' arithmetic (``serving/hybrid.py`` says the
+same of its own): at 256 tokens a page and bucket 512 a ``k`` or ``v`` page
+of four heads of 128 is 256 buckets, eight whole 32-bucket chunks, one
+bucket a token, rows of 512: the flat Mosaic kernels at commit and the paged
+read at decode.
+
+The disaggregated path cannot address a ring and refuses this adapter
+(``transport.require_kv_streams``); it is served with local prefill.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import numpy as np
+
+from ..models import window_moe as wm
+from ..models.mla_moe import rms_norm
+from ..models.window_moe import WindowMoeConfig
+from ..parallel import moe
+from .hybrid import attend_paged
+from .scheduler import ServeConfig, lane_masks, page_specs, ring_masks
+
+
+class WindowMoEServer:
+    """Model adapter (the protocol is in ``scheduler.py``) for one
+    ``(WindowMoeConfig, params)`` pair; cache streams ``k`` and ``v`` on
+    every layer, the window layers' as rings."""
+
+    kind = "window_moe"
+    # What a decode step counts over its expert layers, as
+    # ``cgx.serve.<name>``: ``moe.STATS`` in order.
+    step_counters = tuple(f"moe.{name}" for name in moe.STATS)
+
+    def __init__(self, model_cfg: WindowMoeConfig, params,
+                 serve: Optional[ServeConfig] = None):
+        self.cfg = model_cfg
+        self.p = params
+        self.serve = serve or ServeConfig.from_env(model_cfg)
+        self.n_layer = model_cfg.n_layer
+        self.geometry = tuple(
+            (f.name, str(getattr(model_cfg, f.name)))
+            for f in dataclasses.fields(model_cfg)
+        )
+
+    def layer_name(self, layer: int) -> str:
+        return f"layer_{layer}"
+
+    def cache_streams(self, layer: int):
+        (spec,) = page_specs(self.layer_name(layer), self.serve.page_tokens,
+                             [(self.cfg.n_kv_head, self.cfg.d_head)])
+        return (("k", spec), ("v", spec))
+
+    def state_streams(self, layer: int):
+        return ()
+
+    def page_window(self, layer: int) -> int:
+        return self.cfg.windows[layer]
+
+    def with_params(self, params) -> "WindowMoEServer":
+        return WindowMoEServer(self.cfg, params, self.serve)
+
+    def kv_bytes_per_token(self) -> int:
+        return self.cfg.kv_bytes_per_token()
+
+    def state_bytes_per_lane(self) -> int:
+        return 0
+
+    # -- forwards ----------------------------------------------------------
+
+    def prefill_forward(self, tokens, positions, last_idx):
+        """Full causal forward over a (right-padded) prompt: the logits at
+        ``last_idx``, then every layer's ``k`` and ``v (B, S, Hk, dh)`` f32,
+        ``k`` rotated where the layer rotates. Right-padding is inert for
+        every real position under the causal mask; a padded token does go
+        through the experts (dropless: it takes no real token's place)."""
+        cfg = self.cfg
+        x = wm.embed(cfg, self.p, tokens)
+        ks, vs = [], []
+        for layer in range(cfg.n_layer):
+            pl = self.p[f"layer_{layer}"]
+            y = rms_norm(x, pl["in_norm"], cfg.eps)
+            q, k, v = wm.attn_project(cfg, layer, y, pl["attn"], positions)
+            ks.append(k)
+            vs.append(v)
+            o = wm.attend_blocks(cfg, q, k, v, cfg.windows[layer])
+            x, _ = wm.block_tail(cfg, pl, x, y, o)
+        x_last = jax.lax.dynamic_index_in_dim(x, last_idx, 1)
+        return wm.logits(cfg, self.p, x_last)[:, -1], ks, vs
+
+    def decode_forward(self, state, streams):
+        """One decode position: this token's ``k`` and ``v`` into the raw
+        tails, a global layer's committed pages read through the page table
+        and a window layer's through the ring, one ``decode_attention`` over
+        pages and tail under the class's mask. Returns ``(logits (B, V), the
+        new tails by stream, moe.STATS summed over the layers (``load_max``
+        their largest) counted over the active lanes)``."""
+        cfg, dt = self.cfg, self.cfg.dtype
+        x = wm.embed(cfg, self.p, state["tokens"])  # (B, D)
+        positions = state["pos"][:, None]
+        tail_idx, mask_c, mask_t = lane_masks(self.serve, state)
+        window = max(cfg.windows)
+        mask_r = ring_masks(self.serve, state, window) if window else None
+        new = {"k": [], "v": []}
+        counts = []
+        for layer in range(cfg.n_layer):
+            pl = self.p[f"layer_{layer}"]
+            y = rms_norm(x, pl["in_norm"], cfg.eps)
+            q, k, v = wm.attn_project(cfg, layer, y[:, None], pl["attn"],
+                                      positions)
+            ringed = bool(cfg.windows[layer])
+            o, tails = attend_paged(
+                state, layer, streams[layer],
+                (tail_idx, mask_r if ringed else mask_c, mask_t), q, k, v,
+                dt, np.sqrt(cfg.d_head), window=ringed,
+            )
+            for name, tail in tails.items():
+                new[name].append(tail)
+            x, stats = wm.block_tail(cfg, pl, x, y, o,
+                                     count_mask=state["active"])
+            counts.append(stats)
+        return wm.logits(cfg, self.p, x), new, moe.total_stats(counts)
