@@ -8,6 +8,8 @@ asserted byte-equal, not merely close.
 """
 
 import dataclasses
+import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -1016,6 +1018,99 @@ def test_paged_decode_tpu():
         words, meta, ids, bits=bits, bucket_size=bucket, tc=10,
         out_dtype=jnp.bfloat16, row_width=width)
     np.testing.assert_array_equal(_bits_of(got), _bits_of(want))
+
+
+# The guard (ISSUE 42): one small page geometry (32 tokens of 512, one chunk a
+# page, bucket 512), a table of 8 entries over a pool of 9.
+_GUARD_CASES = [
+    pytest.param(bits, ppb, rows,
+                 id=f"{bits}bit-ppb{ppb}-{'rows' if rows else 'flat'}")
+    for bits in (4, 8) for ppb in (1, 2) for rows in (False, True)
+]
+# The first 16 hex digits of the SHA-256 of the unguarded paged call's jaxpr
+# as text, computed on the parent of the PR that brought the guard (PR 42's
+# parent, its ``git archive``), by (bits, pages a grid step, rows stored).
+_PARENT_PAGED = {
+    (4, 1, False): "8dd92a4b6164f0d0", (4, 1, True): "1f7b4730e73ea8e1",
+    (4, 2, False): "30c729e7f46def80", (4, 2, True): "349ef386d23aaca9",
+    (8, 1, False): "5d94264e144ff1e1", (8, 1, True): "a518a52e05c06633",
+    (8, 2, False): "f35d881d47f9506e", (8, 2, True): "afd3e8468a7a3eb4",
+}
+
+
+def _guard_call(bits, ppb, rows):
+    return functools.partial(
+        codec_pallas._dequantize_flat_impl, bits=bits, bucket_size=512,
+        interpret=True, tc=ppb, out_dtype=np.dtype(jnp.bfloat16),
+        row_width=512 if rows else None)
+
+
+@pytest.mark.parametrize("bits,ppb,rows", _GUARD_CASES)
+def test_guarded_paged_decode_zeroes_dead_pages_and_keeps_live_ones(
+        bits, ppb, rows):
+    """ISSUE 42: with ``live``, a live entry's rows are the unguarded call's
+    bit for bit and a dead entry's are all zeros whatever id it carries (a
+    sentinel clipped to row 0, a stale page, the first entry of an operand's
+    sequence, a run of dead entries that ends the table); ``live`` all ones
+    is the call without a guard."""
+    rng = np.random.default_rng(42 + bits + ppb)
+    n = 8
+    _, words, meta = _paged_pool(rng, n + 1, 32 * 512, bits, 512)
+    call = _guard_call(bits, ppb, rows)
+    ids = jnp.asarray([0, 4, 7, 0, 2, 2, 8, 0], jnp.int32)
+    want = _bits_of(call(words, meta, None, ids))
+    assert want.any(axis=(1, 2)).all()  # no page decodes to zeros
+    for live in ([0, 1, 1, 0, 1, 0, 0, 0], [1, 0, 0, 0, 0, 1, 1, 1],
+                 [0] * n):
+        got = _bits_of(call(words, meta, None, ids,
+                            jnp.asarray(live, jnp.int32)))
+        keep = np.asarray(live, bool)
+        np.testing.assert_array_equal(got[keep], want[keep])
+        assert not got[~keep].any()
+    np.testing.assert_array_equal(
+        _bits_of(call(words, meta, None, ids, jnp.ones((n,), jnp.int32))),
+        want)
+
+
+@pytest.mark.parametrize("bits,ppb,rows", _GUARD_CASES)
+def test_unguarded_paged_decode_is_the_parents_jaxpr(bits, ppb, rows):
+    """``live=None`` traces the program the parent traced: every cell but the
+    ring's (and the training codec, which has no ``page_ids``) is untouched."""
+    n = 8
+    words = jax.ShapeDtypeStruct((n + 1, bits * 4, 128), jnp.int32)
+    meta = jax.ShapeDtypeStruct((n + 1, 32, 2), jnp.float32)
+    ids = jax.ShapeDtypeStruct((n,), jnp.int32)
+    call = _guard_call(bits, ppb, rows)
+    jaxpr = jax.make_jaxpr(lambda w, m, i: call(w, m, None, i))(
+        words, meta, ids)
+    sha = hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
+    assert sha == _PARENT_PAGED[bits, ppb, rows]
+    guarded = jax.make_jaxpr(lambda w, m, i, l: call(w, m, None, i, l))(
+        words, meta, ids, ids)
+    assert str(guarded).count("cond[") >= 2 * ppb  # a branch pair a page
+
+
+@pytest.mark.tpu  # compiled Mosaic lowering of the guard and the held block
+def test_guarded_paged_decode_tpu():
+    rng = np.random.default_rng(42)
+    lanes, ring = 4, 17
+    n = lanes * ring
+    _, words, meta = _paged_pool(rng, n + 1, 256 * 512, 8, 512,
+                                 interpret=False)
+    ids = jnp.asarray(rng.permutation(n + 1)[:n], jnp.int32)
+    live = np.ones((lanes, ring), np.int32)
+    live[0, 3] = 0  # a slot that slid out
+    live[1, 2:] = 0  # a short lane
+    live[3] = 0  # a vacated lane
+    live = jnp.asarray(live.reshape(-1))
+    kw = dict(bits=8, bucket_size=512, tc=16, out_dtype=jnp.bfloat16,
+              row_width=512)
+    want = _bits_of(codec_pallas.dequantize_pages(words, meta, ids, **kw))
+    got = _bits_of(codec_pallas.dequantize_pages(
+        words, meta, ids, live=live, **kw))
+    keep = np.asarray(live, bool)
+    np.testing.assert_array_equal(got[keep], want[keep])
+    assert not got[~keep].any()
 
 
 # ---------------------------------------------------------------------------

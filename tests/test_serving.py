@@ -863,6 +863,48 @@ def test_paged_read_is_the_gathered_read_bit_for_bit(geo, monkeypatch):
         np.asarray(got).view(kind), np.asarray(want).view(kind))
 
 
+@pytest.mark.parametrize("geo", sorted(_READ_GEOS) + ["raw"])
+def test_guarded_read_zeroes_dead_entries_in_every_lowering(geo, monkeypatch):
+    """ISSUE 42: with ``live``, ``gather_dequant_pages`` returns one array
+    whichever lowering writes it (the kernel's guard on Pallas dispatch, a
+    ``where`` over the gathered decode or over a raw pool's rows): a live
+    entry's rows are the unguarded read's bit for bit, a dead entry's are
+    zeros whether its slot names a sentinel or a stale page."""
+    from torch_cgx_tpu.ops import paged_kv
+
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    pt, h, d, dt, tile = _READ_GEOS.get(geo, (64, 20, 64, jnp.float16, None))
+    spec = paged_kv.PageSpec(pt, h, d, *((0, 1) if geo == "raw" else (8, 512)))
+    b, p, max_pages = 2, 4, 8
+    rng = np.random.default_rng(len(geo))
+    pool = paged_kv.commit_page_rows(
+        paged_kv.empty_pool(max_pages + 1, spec), jnp.arange(max_pages),
+        jnp.asarray(rng.standard_normal((max_pages, spec.flat)),
+                    jnp.float32), spec)
+    table = rng.permutation(max_pages).reshape(b, p).astype(np.int32)
+    table[0, 2:] = -1  # a short lane: sentinels, dead
+    live = np.asarray([[1, 1, 0, 0], [1, 0, 1, 1]], bool)  # and a stale page
+    table = jnp.asarray(table)
+
+    bare = paged_kv.gather_dequant_pages(pool, table, spec, dt, window=True)
+    metrics.reset()
+    got = paged_kv.gather_dequant_pages(
+        pool, table, spec, dt, window=True, live=jnp.asarray(live))
+    if geo != "raw":
+        lowering = "pallas_paged" if tile else "xla_gather"
+        assert metrics.snapshot(
+            "cgx.codec.lowering.dequantize_pages.") == {
+            f"cgx.codec.lowering.dequantize_pages.window.{lowering}": 1}
+    assert got.shape == bare.shape and got.dtype == bare.dtype == dt
+    kind = {2: np.uint16, 4: np.uint32}[np.dtype(dt).itemsize]
+
+    def pages(a):
+        return np.asarray(a).view(kind).reshape(b, p, pt, h * d)
+
+    np.testing.assert_array_equal(pages(got)[live], pages(bare)[live])
+    assert pages(bare)[~live].any() and not pages(got)[~live].any()
+
+
 @pytest.mark.parametrize("page,bits,bucket,why", [
     ((64, 20, 64), 0, 1, "a raw pool: nothing to decode"),
     ((16, 20, 64), 8, 512, "40 buckets: a chunk tail, the XLA codec's"),

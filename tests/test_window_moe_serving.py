@@ -207,6 +207,7 @@ def test_live_and_committed_pages_are_counted_by_class(served, run):
     assert counted["decode_steps"] == gen - 1
     assert counted["kv.live_pages.global"] == pages.sum()
     assert counted["kv.live_pages.window"] == (pages - oldest).sum()
+    assert counted["kv.decoded_pages.window"] == (pages - oldest).sum()
     assert (pages - oldest).max() == RING - 1  # the spare slot is never live
     commits = (n + gen - 2) // PAGE - n // PAGE
     assert counted["window.pages_committed"] == 12 * commits
@@ -316,6 +317,117 @@ def test_ring_masks_hide_what_slid_out():
     assert (got == want).all()
     # Lane 2 sees positions 72..103: pages 9, 10, 11 whole, none of 7, 8.
     assert got[2].sum() == 3 * PAGE
+
+
+def test_ring_live_is_ring_masks_by_slot():
+    """A slot is live iff one of its rows is: never-written slots, the slot
+    whose page slid out and a vacated lane's whole ring are dead."""
+    sv = _serve(max_batch=4)
+    state = {
+        "tokens": jnp.zeros((4,), jnp.int32),
+        "n_pages": jnp.asarray([0, 3, 12, 5], jnp.int32),
+        "pos": jnp.asarray([5, 3 * PAGE + 2, 12 * PAGE + 7, 5 * PAGE],
+                           jnp.int32),
+    }
+    rows = np.asarray(sched_mod.ring_masks(sv, state, WINDOW))
+    live = np.asarray(sched_mod.ring_live(sv, state, WINDOW))
+    assert live.shape == (4, RING)
+    assert (live == rows.reshape(4, RING, PAGE).any(-1)).all()
+    # no page; pages 0-2; pages 9-11 of 7-11 held; pages 1-4 of 0-4 held.
+    assert live.sum(-1).tolist() == [0, 3, 3, 4]
+
+
+def _mixed_batch_steps(params, watch):
+    """Serve a short request (it finishes first and leaves its lane vacant),
+    one of 2 pages and one prefilled at 85 tokens, whose ring of 5 has turned
+    twice, in a batch of three; ``watch(sched, p, state)`` is called before
+    every decode step on the state the step is given."""
+    server = WindowMoEServer(_cfg(), params, _serve(max_batch=3))
+    sched = ContinuousBatchScheduler(server)
+    prog = sched._prog
+
+    def decode_step(p, state):
+        watch(sched, p, state)
+        return prog.decode_step(p, state)
+
+    sched._prog = SimpleNamespace(**{**vars(prog), "decode_step": decode_step})
+    for i, (n, gen) in enumerate([(9, 3), (17, 14), (85, 14)]):
+        sched.submit(Request(id=f"r{i}", tokens=_prompt(n, seed=10 + i),
+                             max_new_tokens=gen))
+    assert sched.run(deadline_s=600.0)
+
+
+def test_the_guard_leaves_every_held_lanes_logits_bit_for_bit(
+        params, monkeypatch):
+    """A step's logits with the guard are the logits without it on every
+    held lane, through steps at which the batch holds a lane with 2 pages, a
+    lane whose ring has turned twice and a vacated lane (whose row is never
+    served and is finite either way: its slots are all dead, its scores'
+    mask a finite -1e30)."""
+    from torch_cgx_tpu.serving import window as window_mod
+
+    def forward(server, prog):
+        return jax.jit(lambda p, st: server.with_params(p).decode_forward(
+            st, prog.streams)[0])
+
+    seen = []
+
+    def watch(sched, p, state):
+        lanes = [r is not None for r in sched._lanes]
+        if "guarded" not in probes:
+            probes["guarded"] = forward(sched.server, sched._prog)
+            with monkeypatch.context() as m:
+                m.setattr(window_mod, "ring_live", lambda *a: None)
+                probes["bare"] = forward(sched.server, sched._prog)
+                probes["bare"](p, state)  # traced while the guard is off
+        seen.append((lanes, np.asarray(state["n_pages"]),
+                     np.asarray(probes["guarded"](p, state)),
+                     np.asarray(probes["bare"](p, state))))
+
+    probes = {}
+    _mixed_batch_steps(params, watch)
+    mixed = 0
+    for lanes, n_pages, guarded, bare in seen:
+        assert np.isfinite(guarded).all() and np.isfinite(bare).all()
+        np.testing.assert_array_equal(guarded[lanes], bare[lanes])
+        mixed += lanes == [False, True, True] and n_pages.tolist()[1:] in (
+            [2, 10], [2, 11])
+    assert mixed >= 5  # the batch the docstring names was really seen
+
+
+def test_the_hosts_live_pages_are_the_devices_mask_at_every_step(params):
+    """The device's page mask (``ring_masks`` reduced by page, which is
+    ``ring_live``) sums at every dispatched step to what the host adds to
+    ``cgx.serve.kv.live_pages.window`` and to ``.decoded_pages.window`` from
+    its own counts, through a run that crosses commits and slide-outs."""
+    device, host = [], []
+
+    def watch(sched, p, state):
+        sv, b = sched.server.serve, sched.server.serve.max_batch
+        rows = np.asarray(sched_mod.ring_masks(sv, state, WINDOW))
+        live = np.asarray(sched_mod.ring_live(sv, state, WINDOW))
+        assert (live == rows.reshape(b, RING, PAGE).any(-1)).all()
+        device.append(float(live.sum()))
+        if not host:
+            note = sched._note_live_pages
+
+            def counted(held):
+                names = [f"cgx.serve.kv.{n}.window"
+                         for n in ("live_pages", "decoded_pages")]
+                before = [metrics.get(n) for n in names]
+                note(held)
+                host.append([metrics.get(n) - b
+                             for n, b in zip(names, before)])
+
+            sched._note_live_pages = counted
+
+    _mixed_batch_steps(params, watch)
+    assert len(host) == len(device) > 12
+    assert [h[0] for h in host] == device and [h[1] for h in host] == device
+    # The long lane commits pages 10 and 11 on the way (85 + 13 positions):
+    # each commit slides a page out, so its live slots stay at RING - 1 or
+    # fall to RING - 2 while the short lanes' grow.
+    assert max(device) > min(device)
 
 
 def test_banded_prefill_equals_full_attention_under_the_band():

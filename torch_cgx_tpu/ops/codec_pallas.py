@@ -408,6 +408,7 @@ def _dequantize_flat_impl(
     meta: jax.Array,
     add_to: Optional[jax.Array] = None,
     page_ids: Optional[jax.Array] = None,
+    live: Optional[jax.Array] = None,
     *,
     bits: int,
     bucket_size: int,
@@ -459,7 +460,15 @@ def _dequantize_flat_impl(
     (:func:`_pages_tc`): one page operand pair a page, the body above once
     a page. The ids must be valid rows (the caller clips its sentinels).
     ``name`` is the paged read's kernel name, for a call site that is timed
-    apart (a window layer's ring: ``cgx_dequantize_window``)."""
+    apart (a window layer's ring: ``cgx_dequantize_window``).
+
+    ``live (n,) int32`` beside ``page_ids``: the guard. Output row ``i`` is
+    decoded where ``live[i] != 0`` and all zeros elsewhere, whatever id the
+    dead entry carries; a dead page is not fetched either (its ``index_map``s
+    name the block its operand already holds, so Pallas issues no copy: the
+    ids are forward-filled along each page operand's sequence in front of
+    the call). What is left of a dead page is the write of its zero rows.
+    ``None`` is the call without a guard, jaxpr for jaxpr."""
     b = bucket_size
     rb = b // 128
     nb_r = meta.shape[1]
@@ -508,13 +517,26 @@ def _dequantize_flat_impl(
 
     def _dequantize_pages_kernel(ids_ref, *refs):
         del ids_ref  # read by the index maps alone
+        live_ref = None
+        if live is not None:
+            live_ref, refs = refs[0], refs[1:]
         out_ref = refs[2 * ppb]
         flat_ref = refs[2 * ppb + 1] if k > 1 else None
         for p in range(ppb):
-            _store(
-                out_ref, flat_ref, _decode(refs[p], refs[ppb + p]),
-                slice(p * t_rows, (p + 1) * t_rows),
-            )
+            at = slice(p * t_rows, (p + 1) * t_rows)
+
+            def decode_page(p=p, at=at):
+                _store(out_ref, flat_ref, _decode(refs[p], refs[ppb + p]), at)
+
+            if live_ref is None:
+                decode_page()
+                continue
+            is_live = live_ref[pl.program_id(0) * ppb + p] != 0
+            pl.when(is_live)(decode_page)
+
+            @pl.when(jnp.logical_not(is_live))
+            def _(at=at):
+                out_ref[at, :] = jnp.zeros((t_rows, k * 128), out_dtype)
 
     out_shape = jax.ShapeDtypeStruct(
         (n_chunks * CHUNK_BUCKETS * rb // k, k * 128), out_dtype
@@ -523,13 +545,22 @@ def _dequantize_flat_impl(
     if page_ids is not None:
 
         def page(p):  # the p-th page of grid step i, by its id
-            return lambda i, ids: (ids[i * ppb + p], 0, 0)
+            return lambda i, ids, *_: (ids[i * ppb + p], 0, 0)
 
+        prefetch = (page_ids,)
+        if live is not None:
+            # A dead page names the pool row of the last live page before it
+            # in its operand's sequence (the first step's own before any).
+            ids2, live2 = page_ids.reshape(-1, ppb), live.reshape(-1, ppb)
+            step = jax.lax.broadcasted_iota(jnp.int32, ids2.shape, 0)
+            last = jax.lax.cummax(jnp.where(live2 != 0, step, 0), axis=0)
+            held = jnp.take_along_axis(ids2, last, axis=0).reshape(-1)
+            prefetch = (held, live)
         out = pl.pallas_call(
             _dequantize_pages_kernel,
             name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=len(prefetch),
                 grid=(rows // ppb,),
                 in_specs=[
                     pl.BlockSpec((None,) + words.shape[1:], page(p),
@@ -541,14 +572,14 @@ def _dequantize_flat_impl(
                     for p in range(ppb)
                 ],
                 out_specs=pl.BlockSpec(
-                    (ppb * t_rows, k * 128), lambda i, ids: (i, 0),
+                    (ppb * t_rows, k * 128), lambda i, *_: (i, 0),
                     memory_space=pltpu.VMEM,
                 ),
                 scratch_shapes=scratch_shapes,
             ),
             out_shape=out_shape,
             interpret=interpret,
-        )(page_ids, *([words] * ppb), *([meta] * ppb))
+        )(*prefetch, *([words] * ppb), *([meta] * ppb))
         return out.reshape(rows, -1, k * 128)
 
     w_row = words.shape[1]
@@ -1013,18 +1044,21 @@ def dequantize_pages(
     row_width: int,
     interpret: bool = False,
     name: str = "cgx_dequantize_flat",
+    live: Optional[jax.Array] = None,
 ) -> jax.Array:
     """The paged read: decode pool rows ``page_ids (n,)`` of a page pool
     kept in the flat kernel's operand layout (``words (pool rows, W/128,
     128) int32``, ``meta (pool rows, nb, 2) f32``) -> ``(n, numel /
     row_width, row_width)`` of ``out_dtype``, the values of
     :func:`dequantize_batch` over the gathered rows bit for bit. ``tc``:
-    :func:`_pages_tc`'s tile, which the caller has checked is not None."""
+    :func:`_pages_tc`'s tile, which the caller has checked is not None.
+    ``live (n,) int32``: the guard of :func:`_dequantize_flat_impl`; a dead
+    entry's rows are zeros and its page is not read."""
     store = _flat_store(out_dtype)
     note_lowering("dequantize_rows", "pallas_flat")
     note_lowering("dequantize", _flat_lowering(store))
     return _dequantize_flat_impl(
-        words, meta, None, page_ids,
+        words, meta, None, page_ids, live,
         bits=bits, bucket_size=bucket_size, interpret=interpret, tc=tc,
         out_dtype=store, row_width=row_width, name=name,
     ).astype(out_dtype)

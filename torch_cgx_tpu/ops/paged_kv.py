@@ -215,7 +215,7 @@ WINDOW_READ = "cgx_dequantize_window"
 
 def gather_dequant_pages(
     pool, page_table: jax.Array, spec: PageSpec, dtype=jnp.float32,
-    *, window: bool = False,
+    *, window: bool = False, live: Optional[jax.Array] = None,
 ) -> jax.Array:
     """The decode program's paged KV read: decode the pool rows
     ``page_table (B, P)`` names for the consumer -> ``(B, P * page_tokens,
@@ -227,10 +227,17 @@ def gather_dequant_pages(
     Sentinel entries (< 0) are clipped to row 0 (every fetch stays in
     bounds) and their decoded tokens are garbage by construction —
     callers mask attention scores by the lane's committed token count,
-    never by inspecting decoded values. Every table entry is decoded.
-    ``window``: the table is a window layer's ring ``(B, ring)``; the kernel
-    is then called :data:`WINDOW_READ` and the call site counted as
-    ``cgx.codec.lowering.dequantize_pages.window.*``.
+    never by inspecting decoded values. Without ``live`` every table entry
+    is decoded. ``window``: the table is a window layer's ring ``(B,
+    ring)``; the kernel is then called :data:`WINDOW_READ` and the call site
+    counted as ``cgx.codec.lowering.dequantize_pages.window.*``.
+    ``live (B, P) bool`` (the ring's caller has one:
+    ``scheduler.ring_live``): the entries that hold a key some query can
+    see. A dead entry's page is neither fetched nor decoded and its rows
+    read zero, finite under the attention's ``p @ v`` whatever its slot
+    names (a sentinel, a page that slid out, a vacated lane's ring); a live
+    entry's rows are what they are without the guard, bit for bit, in either
+    lowering.
 
     Two lowerings, counted per call site as
     ``cgx.codec.lowering.dequantize_pages.*``: ``pallas_paged`` on Pallas
@@ -258,8 +265,15 @@ def gather_dequant_pages(
     b, p = page_table.shape
     ids = jnp.maximum(page_table.reshape(-1), 0)
     width = spec.n_head * spec.d_head
+
+    def dead_zeroed(rows):  # what the kernel's guard stores, by a ``where``
+        if live is None:
+            return rows
+        rows = rows.reshape(b * p, spec.page_tokens, width)
+        return jnp.where(live.reshape(-1, 1, 1), rows, 0)
+
     if not spec.quantized:
-        rows = pool[ids].astype(dtype)
+        rows = dead_zeroed(pool[ids].astype(dtype))
         return rows.reshape(b, p * spec.page_tokens, width)
     words, meta = pool
     tile = None
@@ -273,12 +287,13 @@ def gather_dequant_pages(
         rows = ops_dispatch.dequantize_pages(
             words, meta, ids, spec.cc, tile=tile, out_dtype=dtype,
             row_width=width, **({"name": WINDOW_READ} if window else {}),
+            live=None if live is None else live.reshape(-1).astype(jnp.int32),
         )
     else:
-        rows = ops_dispatch.dequantize_batch(
+        rows = dead_zeroed(ops_dispatch.dequantize_batch(
             pool_qtensor(words, meta, ids, spec), out_dtype=dtype,
             row_width=width,
-        )
+        ))
     return rows.reshape(b, p * spec.page_tokens, width)
 
 

@@ -69,18 +69,19 @@ from .scheduler import (
 
 
 def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
-                 score_divisor, window: bool = False):
+                 score_divisor, window: bool = False, live=None):
     """One decode position of an attention layer over a lane's cache: this
     token's ``k`` and ``v (B, 1, Hk, dh)`` into the raw tails and the
     committed pages read as ``dt`` rows where they lie
     (``scheduler.layer_cache_rows``), one ``decode_attention`` of ``q (B, 1,
     H, dh)`` over pages and tail. ``masks`` are ``scheduler.lane_masks``'
     (with ``window``, the layer's pages are the lane's ring and the page
-    mask ``scheduler.ring_masks``'). Returns ``(o (B, H * dh), {stream: its
-    new tail})``."""
+    mask ``scheduler.ring_masks``', and ``live`` its slots that hold a
+    visible key, ``scheduler.ring_live``'s: the read skips the others).
+    Returns ``(o (B, H * dh), {stream: its new tail})``."""
     tail_idx, mask_c, mask_t = masks
     pages, tails, new = layer_cache_rows(
-        state, layer, layer_streams, tail_idx, (k, v), dt, window
+        state, layer, layer_streams, tail_idx, (k, v), dt, window, live
     )
     o = decode_attention(
         q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],

@@ -372,6 +372,13 @@ def ring_pages(serve: ServeConfig, window: int) -> int:
     return -(-window // serve.page_tokens) + 1
 
 
+def _slot_pages(state, slot, ring: int):
+    """The newest committed page a lane's ring holds in ``slot (B, ...)``:
+    the newest ``n`` with ``n % ring == slot``; under 0, never written."""
+    newest = state["n_pages"][:, None] - 1
+    return newest - (newest - slot) % ring
+
+
 def ring_masks(serve: ServeConfig, state, window: int):
     """A window layer's ``mask_c (B, ring x page_tokens)`` beside
     :func:`lane_masks`' (whose tail mask holds as it is: a tail is never
@@ -383,14 +390,30 @@ def ring_masks(serve: ServeConfig, state, window: int):
     ring = ring_pages(serve, window)
     b = state["tokens"].shape[0]
     at = jax.lax.broadcasted_iota(jnp.int32, (b, ring * pt), 1)
-    newest = state["n_pages"][:, None] - 1
-    page = newest - (newest - at // pt) % ring  # under 0: never written
+    page = _slot_pages(state, at // pt, ring)
     position = page * pt + at % pt
     return (page >= 0) & (state["pos"][:, None] - position < window)
 
 
+def ring_live(serve: ServeConfig, state, window: int):
+    """:func:`ring_masks` by slot, ``(B, ring) bool``: the slots that hold a
+    row the lane's token can see, which is whether it sees the slot's newest
+    row. A dead slot is one never written (a short lane's, a vacated lane's
+    whole ring) or one whose page has slid out of the window; the read
+    neither fetches nor decodes it (``paged_kv.gather_dequant_pages``). A
+    step's sum over the held lanes is what the host counts as
+    ``cgx.serve.kv.live_pages.window``."""
+    pt = serve.page_tokens
+    ring = ring_pages(serve, window)
+    b = state["tokens"].shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (b, ring), 1)
+    page = _slot_pages(state, slot, ring)
+    newest_row = page * pt + pt - 1
+    return (page >= 0) & (state["pos"][:, None] - newest_row < window)
+
+
 def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
-                     dtype, window: bool = False):
+                     dtype, window: bool = False, live=None):
     """A layer's cache as its attention contracts it, at a decode position:
     for each of the layer's streams, in order, this token's payload (the
     matching entry of ``fresh``, ``(B, ...)`` of the stream's width) written
@@ -399,7 +422,9 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
     ``({stream: pages (B, P * page_tokens, width)}, {stream: tail rows (B,
     page_tokens, width)}``, both in ``dtype``, ``{stream: the new float32
     tail})``. ``window``: the layer's pages are the lane's ring, ``P`` its
-    slots, in the ring's order (a softmax does not care)."""
+    slots, in the ring's order (a softmax does not care). ``live (B, P)
+    bool``: the table's entries the read decodes (:func:`ring_live`), the
+    others' rows zeros; None reads every entry."""
     table = state["ring_table" if window else "page_table"]
     pages, tails, new = {}, {}, {}
     for (name, spec), value in zip(layer_streams, fresh):
@@ -408,6 +433,7 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
         )
         pages[name] = paged_kv.gather_dequant_pages(
             state["pools"][layer][name], table, spec, dtype, window=window,
+            live=live,
         )
     return pages, tails, new
 
@@ -1935,14 +1961,19 @@ class ContinuousBatchScheduler:
         over the held lanes, by class and for one layer of the class, from
         the host's own counts: every committed page of a global layer; of a
         window layer those from the page that holds the oldest position the
-        lane's token (at ``n_pages * page_tokens + tail_len``) can see."""
+        lane's token (at ``n_pages * page_tokens + tail_len``) can see. Those
+        are the slots the step's read leaves open (:func:`ring_live` on the
+        device), counted again as ``kv.decoded_pages.window``: what the
+        window read decodes, which was every slot of every lane's ring
+        before the read had a guard."""
         pt, window = self.server.serve.page_tokens, self._prog.window
         n_pages = self._n_pages[held]
         oldest = np.maximum(
             n_pages * pt + self._tail_len[held] - window + 1, 0) // pt
+        live = float(np.maximum(n_pages - oldest, 0).sum())
         metrics.add("cgx.serve.kv.live_pages.global", float(n_pages.sum()))
-        metrics.add("cgx.serve.kv.live_pages.window",
-                    float(np.maximum(n_pages - oldest, 0).sum()))
+        metrics.add("cgx.serve.kv.live_pages.window", live)
+        metrics.add("cgx.serve.kv.decoded_pages.window", live)
 
     def _commit_full_tails(self) -> None:
         """Promote full tails into pool pages, so that every lane has

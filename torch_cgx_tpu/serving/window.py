@@ -37,7 +37,13 @@ from ..models.mla_moe import rms_norm
 from ..models.window_moe import WindowMoeConfig
 from ..parallel import moe
 from .hybrid import attend_paged
-from .scheduler import ServeConfig, lane_masks, page_specs, ring_masks
+from .scheduler import (
+    ServeConfig,
+    lane_masks,
+    page_specs,
+    ring_live,
+    ring_masks,
+)
 
 
 class WindowMoEServer:
@@ -110,15 +116,20 @@ class WindowMoEServer:
         """One decode position: this token's ``k`` and ``v`` into the raw
         tails, a global layer's committed pages read through the page table
         and a window layer's through the ring, one ``decode_attention`` over
-        pages and tail under the class's mask. Returns ``(logits (B, V), the
-        new tails by stream, moe.STATS summed over the layers (``load_max``
-        their largest) counted over the active lanes)``."""
+        pages and tail under the class's mask; of a ring the read takes the
+        slots that hold a visible key (``scheduler.ring_live``, one mask a
+        step for every window layer's two streams). Returns ``(logits (B,
+        V), the new tails by stream, moe.STATS summed over the layers
+        (``load_max`` their largest) counted over the active lanes)``."""
         cfg, dt = self.cfg, self.cfg.dtype
         x = wm.embed(cfg, self.p, state["tokens"])  # (B, D)
         positions = state["pos"][:, None]
         tail_idx, mask_c, mask_t = lane_masks(self.serve, state)
         window = max(cfg.windows)
-        mask_r = ring_masks(self.serve, state, window) if window else None
+        mask_r = live_r = None
+        if window:
+            mask_r = ring_masks(self.serve, state, window)
+            live_r = ring_live(self.serve, state, window)
         new = {"k": [], "v": []}
         counts = []
         for layer in range(cfg.n_layer):
@@ -131,6 +142,7 @@ class WindowMoEServer:
                 state, layer, streams[layer],
                 (tail_idx, mask_r if ringed else mask_c, mask_t), q, k, v,
                 dt, np.sqrt(cfg.d_head), window=ringed,
+                live=live_r if ringed else None,
             )
             for name, tail in tails.items():
                 new[name].append(tail)
