@@ -25,6 +25,7 @@ from benchmark import weights_mla_moe as weights  # noqa: E402
 from torch_cgx_tpu.models import mla_moe  # noqa: E402
 from torch_cgx_tpu.models.gpt2 import GPT2Config  # noqa: E402
 from torch_cgx_tpu.models.mla_moe import MlaMoeConfig  # noqa: E402
+from torch_cgx_tpu.ops import prefill_attention as pfa  # noqa: E402
 from torch_cgx_tpu.parallel import moe  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
 from torch_cgx_tpu.serving.latent import LatentMoEServer  # noqa: E402
@@ -466,3 +467,38 @@ def test_from_env_asks_the_adapter_for_its_cache_bytes(params, monkeypatch):
 def test_gpt2_server_says_what_it_is():
     with pytest.raises(ValueError, match="dense-MLP GPT-2 adapter"):
         GPT2Server(GPT2Config.tiny(n_experts=4), {"params": {}}, _serve())
+
+
+def test_a_long_prompt_through_the_kernel_serves_the_loops_tokens(
+        params, monkeypatch):
+    """A prompt of five query blocks (35 positions, four pages and a tail)
+    served with ``ops.dispatch.prefill_attention`` on the kernel
+    (interpreted here, at tiles of 8 queries by 16 keys so that a layer is
+    several tiles and blocks, all four heads a grid step against the shared
+    rotated key): the three layers' call sites count ``.pallas``, the tokens
+    served from the latent pages are the loop's, and the decode steps'
+    logits inside the 8-bit limit and a tenth of it from the loop's."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    prompt, gen = _prompt(4 * PAGE + 3, seed=7), 10
+    tokens, got = _served_logits(params, _cfg(), prompt, gen)
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    monkeypatch.setattr(pfa, "KEY_BLOCK", 16)
+    monkeypatch.setattr(pfa, "TILE_ROWS", 32)
+    site = "cgx.codec.lowering.prefill_attention."
+    before = metrics.snapshot(site)
+    tokens_k, got_k = _served_logits(params, _cfg(), prompt, gen)
+    after = metrics.snapshot(site)
+    assert after[site + "pallas"] - before.get(site + "pallas", 0) == 3
+    assert after.get(site + "xla", 0) == before.get(site + "xla", 0)
+    assert tokens_k == tokens
+    ref = np.asarray(reference.forward(
+        params, jnp.asarray(prompt + tokens[:-1], jnp.int32), HF,
+        q_block=16, expert_block=8))
+    ref_steps = ref[len(prompt): len(prompt) + gen - 1]
+    print(f"8-bit pages against the reference: loop {_gap(got, ref_steps):.4f}"
+          f", kernel {_gap(got_k, ref_steps):.4f}; kernel against loop "
+          f"{_gap(got_k, got):.4f}")
+    assert _gap(got_k, ref_steps) < PAGE_LIMITS["8"]
+    # A latent within a last place of a bucket's edge rounds the other way:
+    # a tenth of what the pages themselves cost, a tenth of the limit.
+    assert _gap(got_k, got) < 0.1 * PAGE_LIMITS["8"]

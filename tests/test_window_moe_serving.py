@@ -29,6 +29,7 @@ from benchmark import weights_window_moe as weights  # noqa: E402
 from torch_cgx_tpu.models import window_moe as wm  # noqa: E402
 from torch_cgx_tpu.models.gpt2 import GPT2, GPT2Config  # noqa: E402
 from torch_cgx_tpu.models.window_moe import WindowMoeConfig  # noqa: E402
+from torch_cgx_tpu.ops import prefill_attention as pfa  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
 from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
@@ -522,3 +523,32 @@ def test_an_adapter_without_windows_builds_the_parents_programs():
             np.int32(3), np.int32(10), out[2], out[4])),
     )
     assert got == PARENT
+
+
+def test_a_long_prompt_through_the_kernel_serves_the_loops_tokens(
+        params, served, monkeypatch):
+    """The run prefilled at more than twice the window (75 positions: five
+    query blocks), with ``ops.dispatch.prefill_attention`` on the kernel
+    (interpreted here, at tiles of 16 queries by 16 keys so that a layer is
+    several tiles and a window layer's tiles skip blocks at both ends): all
+    eight layers' call sites count ``.pallas``, the tokens served through
+    the turned ring are the loop's, and the decode steps' logits are as
+    near the reference's as the loop's were."""
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    monkeypatch.setattr(pfa, "KEY_BLOCK", 16)
+    monkeypatch.setattr(pfa, "TILE_ROWS", 32)  # two query heads a K/V head
+    prompt, tokens, got, _ = served["beyond"]
+    site = "cgx.codec.lowering.prefill_attention."
+    before = metrics.snapshot(site)
+    [(tokens_k, got_k)], _ = _serve_requests(
+        params, [(prompt, RUNS["beyond"][1])])
+    after = metrics.snapshot(site)
+    assert after[site + "pallas"] - before.get(site + "pallas", 0) == 8
+    assert after.get(site + "xla", 0) == before.get(site + "xla", 0)
+    assert tokens_k == tokens
+    _, steps = _reference_steps(params, prompt, tokens)
+    widest, mean = _gaps(got_k, steps)
+    print(f"kernel: widest step {widest:.4f}, mean step {mean:.4f}; from "
+          f"the loop's logits {np.max(np.abs(got_k - got)):.2e}")
+    assert widest < LIMIT_WIDEST and mean < LIMIT_MEAN, (widest, mean)
+    assert np.max(np.abs(got_k - got)) < 1e-3 * np.std(steps)

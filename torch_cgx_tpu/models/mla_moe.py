@@ -16,7 +16,8 @@ layer whose tree holds a full-rank ``q``) split per head into
 ``(q_nope_h . k_nope_h + q_rope_h . k_r) / sqrt(d_nope + d_rope)``. What a
 cache has to hold of a token is ``c`` and the rotated ``k_r``, nothing
 else. :func:`attend_expanded` rebuilds every head's key and value from
-``c`` (prefill, in query blocks so that no ``(H, S, S)`` tensor is held);
+``c`` (prefill, ``ops/prefill_attention.py``: no ``(H, S, S)`` tensor is
+held);
 :func:`attend_absorbed` folds ``W_kvb`` into the query and the output
 (decode: no per-head key or value of a cached token is ever rebuilt). Both
 are the same mathematics.
@@ -47,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import dispatch
 from ..parallel import moe
 from .attention import joined_softmax
 
@@ -170,40 +172,20 @@ def _softmax_scale(cfg: MlaMoeConfig):
 
 def attend_expanded(cfg: MlaMoeConfig, pa, q_nope, q_rope, c, k_r):
     """Causal attention of a whole prompt, every head's key and value
-    rebuilt from ``c``: ``(B, S, H*dv)``. Queries go ``cfg.q_block`` at a
-    time, so the scores held are ``(B, H, q_block, S)``."""
+    rebuilt from ``c``: ``(B, S, H*dv)``. ``ops.dispatch.prefill_attention``
+    over the two-part keys: a prompt of more than ``cfg.q_block`` positions
+    is one pass of the ``cgx_prefill_attention`` kernel where the kernels
+    run; else queries go ``cfg.q_block`` at a time, so the scores held are
+    ``(B, H, q_block, S)``."""
     dt = cfg.dtype
-    b, s, h, _ = q_nope.shape
     w_k, w_v = _kv_b_heads(cfg, pa)
     c_dt, kr_dt = c.astype(dt), k_r.astype(dt)
     k_nope = jnp.einsum("bsl,lhn->bshn", c_dt, w_k.astype(dt))
     v = jnp.einsum("bsl,lhv->bshv", c_dt, w_v.astype(dt))
-    blk = min(cfg.q_block, s)
-    n_blk = -(-s // blk)
-    pad = n_blk * blk - s
-    if pad:
-        q_nope = jnp.pad(q_nope, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        q_rope = jnp.pad(q_rope, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    key_pos = jnp.arange(s)
-
-    def one(i):
-        qn = jax.lax.dynamic_slice_in_dim(q_nope, i * blk, blk, 1)
-        qr = jax.lax.dynamic_slice_in_dim(q_rope, i * blk, blk, 1)
-        scores = (
-            jnp.einsum("bqhn,bkhn->bhqk", qn, k_nope,
-                       preferred_element_type=jnp.float32)
-            + jnp.einsum("bqhr,bkr->bhqk", qr, kr_dt,
-                         preferred_element_type=jnp.float32)
-        ) * _softmax_scale(cfg)
-        q_pos = i * blk + jnp.arange(blk)
-        causal = key_pos[None, :] <= q_pos[:, None]
-        scores = jnp.where(causal, scores, np.float32(-1e30))
-        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-        return jnp.einsum("bhqk,bkhv->bqhv", probs, v)
-
-    o = jax.lax.map(one, jnp.arange(n_blk))  # (n_blk, B, blk, H, dv)
-    o = o.transpose(1, 0, 2, 3, 4).reshape(b, n_blk * blk, h * cfg.d_v)
-    return o[:, :s]
+    return dispatch.prefill_attention(
+        q_nope, k_nope, v, q_rope, kr_dt, scale=_softmax_scale(cfg),
+        q_block=cfg.q_block, dtype=dt,
+    )
 
 
 def attend_absorbed(cfg: MlaMoeConfig, pa, q_nope, q_rope, c_all, kr_all,

@@ -20,9 +20,9 @@ dims, pairs ``(i, i + d_head / 2)``) and lets query ``i`` read keys ``i - W <
 j <= i``; a global layer rotates nothing (no positional embedding at all)
 and reads every ``j <= i``. What a cache holds of a token is ``k`` as the
 scores contract it (rotated on a window layer) and ``v``.
-:func:`attend_blocks` is the prefill: queries go ``cfg.q_block`` at a time,
-and a window layer's block reads the band of keys it can see, so no ``(H, S,
-S)`` tensor is held and a window layer does a window's work.
+:func:`attend_blocks` is the prefill (``ops/prefill_attention.py``): a tile
+of queries against the blocks of keys it can see under a running softmax, so
+no ``(H, S, S)`` tensor is held and a window layer does a window's work.
 
 **Experts.** ``E_e(z) = W_down,e (relu(W_gate,e z) * W_up,e z)`` through
 ``parallel.moe.dropless_moe`` with the routing given from outside
@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import dispatch
 from ..parallel import moe
 from .mla_moe import _mm, rms_norm
 
@@ -128,46 +129,18 @@ def attn_project(cfg: WindowMoeConfig, layer: int, y, pa, positions):
 
 def attend_blocks(cfg: WindowMoeConfig, q, k, v, window: int):
     """Causal attention of a whole prompt from position 0, each K/V head
-    read by its group of query heads: ``(B, S, H*dh)``. Queries go
-    ``cfg.q_block`` at a time. With ``window`` a block reads the ``window +
-    q_block`` keys that end with its own last query (fewer in a shorter
-    prompt) under the band ``q_pos - window < key_pos <= q_pos``; without,
-    every key under the causal mask. The scores held are ``(B, H, q_block,
-    keys read)``."""
-    dt = cfg.dtype
-    b, s, h, d = q.shape
-    hk = cfg.n_kv_head
-    blk = min(cfg.q_block, s)
-    n_blk = -(-s // blk)
-    pad = n_blk * blk - s
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    qg = q.reshape(b, n_blk * blk, hk, h // hk, d)
-    k_dt, v_dt = k.astype(dt), v.astype(dt)
-    band = min(s, window + blk) if window else s
-
-    def one(i):
-        qb = jax.lax.dynamic_slice_in_dim(qg, i * blk, blk, 1)
-        # The band's first key: it ends with the block's last query, and
-        # stays inside the prompt.
-        lo = jnp.clip((i + 1) * blk - band, 0, s - band)
-        kb = jax.lax.dynamic_slice_in_dim(k_dt, lo, band, 1)
-        vb = jax.lax.dynamic_slice_in_dim(v_dt, lo, band, 1)
-        scores = jnp.einsum("bqkgd,btkd->bkgqt", qb, kb,
-                            preferred_element_type=jnp.float32
-                            ) / np.float32(np.sqrt(d))
-        q_pos = (i * blk + jnp.arange(blk))[:, None]
-        key_pos = (lo + jnp.arange(band))[None, :]
-        seen = key_pos <= q_pos
-        if window:
-            seen &= q_pos - key_pos < window
-        scores = jnp.where(seen, scores, np.float32(-1e30))
-        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
-        return jnp.einsum("bkgqt,btkd->bqkgd", probs, vb)
-
-    o = jax.lax.map(one, jnp.arange(n_blk))  # (n_blk, B, blk, Hk, G, dh)
-    o = jnp.moveaxis(o, 0, 1).reshape(b, n_blk * blk, h * d)
-    return o[:, :s]
+    read by its group of query heads: ``(B, S, H*dh)``, under the band
+    ``q_pos - window < key_pos <= q_pos`` (every ``key_pos <= q_pos``
+    without a window). ``ops.dispatch.prefill_attention``: a prompt of more
+    than ``cfg.q_block`` positions is one pass of the ``cgx_prefill_
+    attention`` kernel over the key blocks a tile of queries can see, where
+    the kernels run; else queries go ``cfg.q_block`` at a time, and a block
+    reads the ``window + q_block`` keys that end with its own last query
+    (every key without a window)."""
+    return dispatch.prefill_attention(
+        q, k, v, window=window, scale=1.0 / np.sqrt(q.shape[-1]),
+        q_block=cfg.q_block, dtype=cfg.dtype,
+    )
 
 
 def experts(cfg: WindowMoeConfig, pm, y, z, count_mask=None):

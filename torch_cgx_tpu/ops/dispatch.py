@@ -16,7 +16,8 @@ from jax import lax
 
 from .. import config as cfg_mod
 from ..config import CompressionConfig
-from . import codec, codec_pallas, gdn, grouped_matmul as gmm, ssm
+from . import codec, codec_pallas, gdn, grouped_matmul as gmm
+from . import prefill_attention as pfa, ssm
 
 
 def _on_tpu() -> bool:
@@ -407,3 +408,34 @@ def grouped_matmul(lhs, rhs, sizes):
         )
     codec_pallas.note_lowering("grouped_matmul", "xla")
     return gmm.grouped_matmul_xla(lhs, rhs, sizes)
+
+
+def prefill_attention(q, k, v, q_rope=None, k_rope=None, *, window: int = 0,
+                      scale: float, q_block: int, dtype):
+    """The attention of a whole prompt from position 0
+    (``ops/prefill_attention.py``): ``q (B, S, H, d)``, ``k (B, S, Hk,
+    d)``, ``v (B, S, Hk, dv)`` and, for a key of two parts, ``q_rope (B, S,
+    H, dr)``, ``k_rope (B, S, dr)`` -> ``(B, S, H * dv)`` in ``dtype``,
+    query ``i`` over the keys ``i - window < j <= i`` (every ``j <= i``
+    without a window). Where :func:`prefill_attention.takes_kernel` says the
+    shapes are the kernel's (a prompt of more than ``q_block`` positions),
+    the ``cgx_prefill_attention`` kernel on the TPU (and, interpreted,
+    wherever ``CGX_CODEC_IMPL=pallas`` asks for the kernels); the loop over
+    blocks of ``q_block`` queries elsewhere; counted per call site as
+    ``cgx.codec.lowering.prefill_attention.pallas`` / ``.xla``."""
+    s, h, d = q.shape[1:]
+    compiled = _on_tpu()
+    if _kernels() and pfa.takes_kernel(
+        s, q_block, h, k.shape[2], d, v.shape[3],
+        0 if k_rope is None else k_rope.shape[-1], window, compiled,
+    ):
+        codec_pallas.note_lowering("prefill_attention", "pallas")
+        return pfa.prefill_attention_pallas(
+            q.astype(dtype), k, v, q_rope, k_rope, window=window,
+            scale=float(scale), interpret=not compiled,
+        )
+    codec_pallas.note_lowering("prefill_attention", "xla")
+    return pfa.prefill_attention_xla(
+        q, k, v, q_rope, k_rope, window=window, scale=scale,
+        q_block=q_block, dtype=dtype,
+    )
