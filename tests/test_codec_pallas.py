@@ -1131,7 +1131,7 @@ _TRAIN_CHUNKS = (110592, 314880, 316096)  # ... whose rows end in a chunk tail
 def _commit_lanes(max_batch, page_tokens):
     """Rows of a cell's one ``commit`` program: ``ServeConfig.commit_lanes``
     at the cell's lanes and page size (4, and 8 for the 96-lane cell)."""
-    from torch_cgx_tpu.serving.scheduler import ServeConfig
+    from torch_cgx_tpu.serving.adapter import ServeConfig
 
     return ServeConfig(page_tokens=page_tokens, max_batch=max_batch,
                        max_pages=8, max_seq=page_tokens,

@@ -32,11 +32,11 @@ from torch_cgx_tpu.ops import ssm  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
 from torch_cgx_tpu.serving.hybrid import HybridSSMServer  # noqa: E402
 from torch_cgx_tpu.serving.prefill import PrefillWorker  # noqa: E402
+from torch_cgx_tpu.serving.adapter import ServeConfig  # noqa: E402
+from torch_cgx_tpu.serving.gpt2 import GPT2Server  # noqa: E402
 from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
-    GPT2Server,
     Request,
-    ServeConfig,
 )
 from torch_cgx_tpu.serving.transport import KvPageReceiver  # noqa: E402
 from torch_cgx_tpu.utils.logging import metrics  # noqa: E402

@@ -32,10 +32,10 @@ from torch_cgx_tpu.ops import gdn  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
 from torch_cgx_tpu.serving.hybrid import HybridGDNServer  # noqa: E402
 from torch_cgx_tpu.serving.prefill import PrefillWorker  # noqa: E402
+from torch_cgx_tpu.serving.adapter import ServeConfig  # noqa: E402
 from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
     Request,
-    ServeConfig,
 )
 from torch_cgx_tpu.serving.transport import KvPageReceiver  # noqa: E402
 from torch_cgx_tpu.utils.logging import metrics  # noqa: E402

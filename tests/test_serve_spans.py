@@ -34,11 +34,11 @@ import pytest
 
 from torch_cgx_tpu.models.gpt2 import GPT2, GPT2Config
 from torch_cgx_tpu.observability import timeline
+from torch_cgx_tpu.serving.adapter import ServeConfig
+from torch_cgx_tpu.serving.gpt2 import GPT2Server
 from torch_cgx_tpu.serving.scheduler import (
     ContinuousBatchScheduler,
-    GPT2Server,
     Request,
-    ServeConfig,
 )
 from torch_cgx_tpu.utils.logging import metrics
 from torch_cgx_tpu.utils.tracing import GcPauses, install_gc_hook, trace_span

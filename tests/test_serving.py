@@ -29,14 +29,15 @@ import pytest
 from torch_cgx_tpu import config as cfg_mod
 from torch_cgx_tpu.models.gpt2 import GPT2, GPT2Config
 from torch_cgx_tpu.serving import kv_cache as kv_mod
+from torch_cgx_tpu.serving import programs as programs_mod
 from torch_cgx_tpu.serving import scheduler as sched_mod
 from torch_cgx_tpu.serving import transport as tp
 from torch_cgx_tpu.serving.prefill import PrefillWorker
+from torch_cgx_tpu.serving.adapter import ServeConfig
+from torch_cgx_tpu.serving.gpt2 import GPT2Server
 from torch_cgx_tpu.serving.scheduler import (
     ContinuousBatchScheduler,
-    GPT2Server,
     Request,
-    ServeConfig,
 )
 from torch_cgx_tpu.serving.slo import ServeSloController
 from torch_cgx_tpu.serving.transport import (
@@ -498,7 +499,7 @@ def test_registry_write_rekeys_program(model_setup):
                                                       bucket_size=0)),
     )
     assert sched_mod._program_key(server) != k0
-    specs = sched_mod._resolved_specs(server)
+    specs = programs_mod._resolved_specs(server)
     assert specs[0].bits == 5
     assert specs[1].bits == cfg_mod.kv_bits()
 
@@ -790,7 +791,7 @@ def test_a_written_page_has_the_frames_wire_bytes(model_setup, monkeypatch,
                     SimpleNamespace(payload=f), spec)
                 for f in frames
             ], spec)
-            pool = sched_mod._ingest_pool(
+            pool = programs_mod._ingest_pool(
                 empty, jnp.asarray(ids), stacked, spec)
     words, meta = pool
     assert words.shape == (sv.max_pages + 1,) + spec.word_shape
@@ -973,7 +974,7 @@ def test_rekey_drains_active_lanes_without_token_loss(
     assert sched.run(deadline_s=DEADLINE_S)
     assert len(first.output) == 8 and len(second.output) == 4
     assert metrics.get("cgx.serve.bits_adoptions") == adopts0 + 1
-    assert sched_mod._resolved_specs(server)[0].bits == 5
+    assert programs_mod._resolved_specs(server)[0].bits == 5
     # nothing leaked: every page returned to the pool
     assert sched.cache.free_pages == sched.cache.max_pages
 
@@ -1085,7 +1086,7 @@ def test_admission_matches_hand_composition(model_setup, monkeypatch, bits,
             rows = cache[layer][0, : n_full * PAGE].reshape(n_full, -1)
             if spec.quantized:
                 rows = paged_kv.quantize_page_rows(rows, spec)
-            pools[kind] = sched_mod._ingest_pool(
+            pools[kind] = programs_mod._ingest_pool(
                 paged_kv.empty_pool(server.serve.max_pages + 1, spec), ids,
                 rows, spec,
             )
@@ -1932,7 +1933,7 @@ def test_commit_and_eviction_in_one_tick(model_setup, monkeypatch, kind):
     released[evicted] = True
     want = dict(before)
     want.update(sched._prog.release_lanes(
-        {name: before[name] for name in sched_mod._LANE_RESET}, released))
+        {name: before[name] for name in programs_mod._LANE_RESET}, released))
     _assert_state_is(sched, _masked_commit(
         sched, want, *_masked_operands(kept, given)))
     assert not sched._tail_len[lanes].any()

@@ -1,0 +1,260 @@
+"""The serving plane's four layers (docs/SERVING.md, "The pieces"):
+``adapter.py`` under the adapters (``gpt2.py``, ``latent.py``, ``hybrid.py``,
+``window.py``) and under ``programs.py``, which is under ``scheduler.py``.
+Imports point one way, every server is an ``Adapter`` with the defaults the
+copies it lost had, the names the benchmark reaches into the scheduler for
+are where it looks, the state ``programs.fresh_state`` lays out is the one
+the scheduler built itself before the split, and a cached program keeps no
+model alive.
+
+Tiny sizes, the geometries of the adapters' own test files; traced or
+parsed, nothing but the last test runs a program.
+"""
+
+from __future__ import annotations
+
+import ast
+import dataclasses
+import gc
+import hashlib
+import sys
+import weakref
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+from benchmark import weights as gpt2_weights  # noqa: E402
+from torch_cgx_tpu.models.gpt2 import GPT2Config  # noqa: E402
+from torch_cgx_tpu.serving import adapter as adapter_mod  # noqa: E402
+from torch_cgx_tpu.serving import programs as programs_mod  # noqa: E402
+from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
+from torch_cgx_tpu.serving.gpt2 import GPT2Server  # noqa: E402
+from torch_cgx_tpu.serving.hybrid import (  # noqa: E402
+    HybridGDNServer,
+    HybridLatentMoEServer,
+    HybridSSMServer,
+)
+from torch_cgx_tpu.serving.latent import LatentMoEServer  # noqa: E402
+from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
+    ContinuousBatchScheduler,
+    Request,
+)
+from torch_cgx_tpu.serving.window import WindowMoEServer  # noqa: E402
+from torch_cgx_tpu.wire import edges  # noqa: E402
+
+import test_hybrid_serving as granite  # noqa: E402
+import test_latent_serving as latent  # noqa: E402
+import test_ling_hybrid_serving as ling  # noqa: E402
+import test_olmo_hybrid_serving as olmo  # noqa: E402
+import test_window_moe_serving as window  # noqa: E402
+
+SERVING = Path(adapter_mod.__file__).parent
+ADAPTERS = ("gpt2", "latent", "hybrid", "window")
+GPT2_HF = dict(vocab_size=512, n_layer=2, n_head=4, n_embd=64,
+               n_positions=128, init={})
+GPT2_CFG = GPT2Config(vocab_size=512, n_layer=2, n_head=4, d_model=64,
+                      max_seq=128)
+# kind -> (class, model config, serve config): what each adapter's own test
+# file serves.
+SERVERS = {
+    "gpt2": (GPT2Server, lambda: GPT2_CFG, granite._serve),
+    "mla_moe": (LatentMoEServer, latent._cfg, latent._serve),
+    "hybrid_ssm": (HybridSSMServer, granite._cfg, granite._serve),
+    "hybrid_gdn": (HybridGDNServer, olmo._cfg, olmo._serve),
+    "hybrid_kda_mla": (HybridLatentMoEServer, ling._cfg, ling._serve),
+    "window_moe": (WindowMoEServer, window._cfg, window._serve),
+}
+HYBRIDS = ("hybrid_ssm", "hybrid_gdn", "hybrid_kda_mla")
+
+
+@pytest.fixture(autouse=True)
+def _clean(monkeypatch):
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    edges.clear_edges()
+    yield
+    edges.clear_edges()
+
+
+def _server(kind, params=None):
+    """The adapter of ``kind`` over no model: geometry alone."""
+    cls, cfg, serve = SERVERS[kind]
+    return cls(cfg(), {} if params is None else params, serve())
+
+
+def _sibling_imports(module):
+    """``[(sibling module, the names taken from it, whether the import
+    stands at module top)]`` of ``serving/<module>.py``."""
+    tree = ast.parse((SERVING / f"{module}.py").read_text())
+    top = {id(node) for node in tree.body}
+    found = []
+    for node in ast.walk(tree):
+        if not (isinstance(node, ast.ImportFrom) and node.level == 1):
+            continue
+        names = [alias.name for alias in node.names]
+        if node.module is None:  # from . import a, b
+            found += [(name, [], id(node) in top) for name in names]
+        else:
+            found.append((node.module, names, id(node) in top))
+    return found
+
+
+@pytest.mark.parametrize("module", [
+    "adapter", *ADAPTERS, "programs", "scheduler", "prefill"])
+def test_imports_point_one_way(module):
+    found = _sibling_imports(module)
+    siblings = {name for name, _, _ in found}
+    assert all(at_top for _, _, at_top in found), found
+    if module == "adapter":
+        assert sorted((name, names) for name, names, _ in found) == [
+            ("kv_cache", ["resolve_kv_config"]),
+            ("transport", ["DEFAULT_SHIP_DEPTH"]),
+        ]
+    elif module in (*ADAPTERS, "programs"):
+        assert siblings == {"adapter"}
+    elif module == "scheduler":
+        assert "programs" in siblings
+        assert not siblings & {"prefill", *ADAPTERS}
+    else:  # the prefill worker sits on top of the scheduler
+        assert siblings == {"transport", "programs", "scheduler"}
+
+
+@pytest.mark.parametrize("kind", list(SERVERS))
+def test_adapter_defaults(kind):
+    server = _server(kind)
+    cfg, serve = server.cfg, server.serve
+    layers = range(server.n_layer)
+    assert isinstance(server, adapter_mod.Adapter) and server.kind == kind
+    assert server.n_layer == cfg.n_layer
+    assert [server.layer_name(l) for l in layers] == [
+        f"layer_{l}" for l in layers]
+    assert server.geometry == tuple(
+        (f.name, str(getattr(cfg, f.name))) for f in dataclasses.fields(cfg))
+    hash(server.geometry)
+    assert server.kv_bytes_per_token() == cfg.kv_bytes_per_token()
+    assert [server.page_window(l) for l in layers] == (
+        list(cfg.windows) if kind == "window_moe" else [0] * cfg.n_layer)
+    if kind in HYBRIDS:
+        recurrent = [l for l in layers if l not in cfg.attention_layers]
+        assert recurrent and all(server.state_streams(l) for l in recurrent)
+        assert all(server.state_streams(l) == ()
+                   for l in cfg.attention_layers)
+        assert server.state_bytes_per_lane() == cfg.state_bytes_per_lane()
+    else:
+        assert all(server.state_streams(l) == () for l in layers)
+        assert server.state_bytes_per_lane() == 0
+    counts = {"gpt2": False, "hybrid_ssm": False, "hybrid_gdn": False}
+    assert bool(server.step_counters) == counts.get(kind, True)
+    tree = {"a": "tree"}
+    other = server.with_params(tree)
+    assert type(other) is type(server)
+    assert other.p is tree and other.serve is serve and other.cfg is cfg
+    assert other.geometry == server.geometry
+    if kind in HYBRIDS:
+        half = type(server)(cfg, {}, serve, state_dtype=jnp.bfloat16)
+        assert half.with_params(tree).state_dtype == jnp.bfloat16
+        assert (half.state_bytes_per_lane()
+                == cfg.state_bytes_per_lane() // 2)
+
+
+def test_benchmark_seams_hold(monkeypatch):
+    """What ``benchmark/`` reaches for: ``scheduler._build_programs`` is the
+    name a cache miss calls, the namespace it returns takes another
+    ``decode_step``, and the scheduler's ``_prog`` has the stream tables the
+    drivers read."""
+    built = []
+
+    def altered(state, params):
+        raise AssertionError("never run here")
+
+    def build(server):
+        prog = programs_mod.build(server)
+        prog.decode_step = altered
+        built.append(prog)
+        return prog
+
+    sched_mod.invalidate_decode_cache("test")
+    monkeypatch.setattr(sched_mod, "_build_programs", build)
+    try:
+        sched = ContinuousBatchScheduler(_server("gpt2"))
+        assert built == [sched._prog]
+        assert sched._prog.decode_step is altered
+        for table in ("streams", "names", "state_names", "specs", "windows"):
+            assert len(getattr(sched._prog, table)) == (
+                0 if table == "state_names" else 2)
+        assert sched._state["pools"][1]["v"][0].shape[0] == (
+            sched.server.serve.max_pages + 1)
+        # the same geometry again is a hit: nothing is built
+        assert ContinuousBatchScheduler(_server("gpt2"))._prog is built[0]
+        assert len(built) == 1
+    finally:
+        sched_mod.invalidate_decode_cache("test")
+
+
+# The first 16 hex digits of the SHA-256 of the state's tree structure and
+# of its leaves' ``(shape, dtype)``, and the number of leaves, as
+# ``ContinuousBatchScheduler._fresh_state`` built them at the parent of the
+# PR that moved the construction to ``programs.fresh_state`` (PR 44), at
+# 8-bit pages and at raw ones. A PR that changes the state's layout on
+# purpose reads the new values off this test's failure.
+PARENT_STATE = {
+    "gpt2": {"8": ("5b9dad382d4ed71c", "9950256bbc392522", 18),
+             "0": ("67e35581ee0cc71b", "1510cfbeead82042", 14)},
+    "mla_moe": {"8": ("020ed118abbfcd76", "4f25a0c507940dc8", 24),
+                "0": ("2ec0ae25e92fab99", "915b96f138911ac3", 18)},
+    "hybrid_ssm": {"8": ("2c88912c733a2545", "d4363b586c7c8be3", 24),
+                   "0": ("ba44336ef65a826e", "4b4a5ab248401e47", 20)},
+    "hybrid_gdn": {"8": ("54a0f16dd737b3cd", "3cdee69f1c41e847", 24),
+                   "0": ("af707e80a7f9164f", "7bfd3a6d01cddf41", 20)},
+    "hybrid_kda_mla": {"8": ("759b4ac5f883dd18", "11f2ea3bbb54c710", 24),
+                       "0": ("123b5cc3f6ed322f", "b54095ffdec82137", 22)},
+    "window_moe": {"8": ("9463bd2b906d9e9b", "b9a190bae6634be3", 55),
+                   "0": ("2f58a4980b47dff1", "19b0adb4db25b31e", 39)},
+}
+
+
+def _sha(x) -> str:
+    return hashlib.sha256(str(x).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("kind", list(SERVERS))
+def test_fresh_state_is_the_parents_tree(kind, monkeypatch):
+    got = {}
+    for bits in PARENT_STATE[kind]:
+        monkeypatch.setenv("CGX_KV_BITS", bits)
+        server = _server(kind)
+        state = programs_mod.fresh_state(programs_mod.build(server),
+                                         server.serve)
+        leaves = [(tuple(a.shape), str(a.dtype))
+                  for a in jax.tree_util.tree_leaves(state)]
+        got[bits] = (_sha(jax.tree_util.tree_structure(state)),
+                     _sha(leaves), len(leaves))
+    assert got == PARENT_STATE[kind]
+
+
+def test_a_cached_program_holds_no_model():
+    """The LRU keeps geometry alone: with the scheduler and the server
+    gone, the model's leaves are freed while the entry stays, and another
+    server of the same geometry is handed that entry and serves the same
+    tokens from it."""
+    def serve():
+        server = GPT2Server(GPT2_CFG, gpt2_weights.make_params(GPT2_HF, 1),
+                            granite._serve())
+        sched = ContinuousBatchScheduler(server)
+        req = Request(id="r", tokens=granite._prompt(21), max_new_tokens=6)
+        sched.submit(req)
+        assert sched.run(deadline_s=300.0)
+        leaf = weakref.ref(server.p["wte"]["embedding"])
+        return req.output, sched._prog, leaf
+
+    sched_mod.invalidate_decode_cache("test")
+    tokens, prog, leaf = serve()
+    gc.collect()
+    assert leaf() is None
+    assert list(sched_mod._PROGRAM_CACHE.values()) == [prog]
+    again, prog_again, _ = serve()
+    assert prog_again is prog and len(sched_mod._PROGRAM_CACHE) == 1
+    assert again == tokens and len(tokens) == 6

@@ -19,15 +19,16 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from torch_cgx_tpu.models.gpt2 import GPT2, GPT2Config  # noqa: E402
 from torch_cgx_tpu.ops import paged_kv  # noqa: E402
+from torch_cgx_tpu.serving import programs as programs_mod  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
 from torch_cgx_tpu.serving.hybrid import (  # noqa: E402
     HybridGDNServer,
     HybridSSMServer,
 )
 from torch_cgx_tpu.serving.latent import LatentMoEServer  # noqa: E402
+from torch_cgx_tpu.serving.gpt2 import GPT2Server  # noqa: E402
 from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
-    GPT2Server,
     Request,
 )
 from torch_cgx_tpu.wire import edges  # noqa: E402
@@ -221,7 +222,7 @@ def test_tails_through_prefill_decode_and_commit(served, monkeypatch):
         rows = held[name, layer][lane].reshape(1, -1)
         np.testing.assert_array_equal(
             np.asarray(state[f"tail_{name}"][layer]), held[name, layer])
-        want_pool = sched_mod._ingest_pool(
+        want_pool = programs_mod._ingest_pool(
             paged_kv.empty_pool(sv.max_pages + 1, spec), jnp.asarray([pid]),
             paged_kv.quantize_page_rows(jnp.asarray(rows), spec), spec,
         )

@@ -30,12 +30,12 @@ from torch_cgx_tpu.models import window_moe as wm  # noqa: E402
 from torch_cgx_tpu.models.gpt2 import GPT2, GPT2Config  # noqa: E402
 from torch_cgx_tpu.models.window_moe import WindowMoeConfig  # noqa: E402
 from torch_cgx_tpu.ops import prefill_attention as pfa  # noqa: E402
-from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
+from torch_cgx_tpu.serving import adapter as adapter_mod  # noqa: E402
+from torch_cgx_tpu.serving.adapter import ServeConfig  # noqa: E402
+from torch_cgx_tpu.serving.gpt2 import GPT2Server  # noqa: E402
 from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
-    GPT2Server,
     Request,
-    ServeConfig,
 )
 from torch_cgx_tpu.serving.transport import KvPageReceiver  # noqa: E402
 from torch_cgx_tpu.serving.window import WindowMoEServer  # noqa: E402
@@ -308,7 +308,7 @@ def test_ring_masks_hide_what_slid_out():
         "n_pages": jnp.asarray([0, 3, 12], jnp.int32),
         "pos": jnp.asarray([5, 3 * PAGE + 2, 12 * PAGE + 7], jnp.int32),
     }
-    got = np.asarray(sched_mod.ring_masks(sv, state, WINDOW))
+    got = np.asarray(adapter_mod.ring_masks(sv, state, WINDOW))
     want = np.zeros((3, RING * PAGE), bool)
     for lane, (n_pages, pos) in enumerate([(0, 5), (3, 26), (12, 103)]):
         for page in range(max(n_pages - RING, 0), n_pages):
@@ -330,8 +330,8 @@ def test_ring_live_is_ring_masks_by_slot():
         "pos": jnp.asarray([5, 3 * PAGE + 2, 12 * PAGE + 7, 5 * PAGE],
                            jnp.int32),
     }
-    rows = np.asarray(sched_mod.ring_masks(sv, state, WINDOW))
-    live = np.asarray(sched_mod.ring_live(sv, state, WINDOW))
+    rows = np.asarray(adapter_mod.ring_masks(sv, state, WINDOW))
+    live = np.asarray(adapter_mod.ring_live(sv, state, WINDOW))
     assert live.shape == (4, RING)
     assert (live == rows.reshape(4, RING, PAGE).any(-1)).all()
     # no page; pages 0-2; pages 9-11 of 7-11 held; pages 1-4 of 0-4 held.
@@ -405,8 +405,8 @@ def test_the_hosts_live_pages_are_the_devices_mask_at_every_step(params):
 
     def watch(sched, p, state):
         sv, b = sched.server.serve, sched.server.serve.max_batch
-        rows = np.asarray(sched_mod.ring_masks(sv, state, WINDOW))
-        live = np.asarray(sched_mod.ring_live(sv, state, WINDOW))
+        rows = np.asarray(adapter_mod.ring_masks(sv, state, WINDOW))
+        live = np.asarray(adapter_mod.ring_live(sv, state, WINDOW))
         assert (live == rows.reshape(b, RING, PAGE).any(-1)).all()
         device.append(float(live.sum()))
         if not host:

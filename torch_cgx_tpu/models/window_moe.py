@@ -89,7 +89,7 @@ class WindowMoeConfig:
 
     def kv_bytes_per_token(self) -> int:
         """float32 bytes of one token's K and V over all layers (a window
-        layer stops growing at its window: ``serving/scheduler.py``)."""
+        layer stops growing at its window: ``serving/adapter.py``)."""
         return 2 * self.n_layer * self.n_kv_head * self.d_head * 4
 
     def state_bytes_per_lane(self) -> int:

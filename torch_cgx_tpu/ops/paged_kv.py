@@ -207,7 +207,7 @@ def pool_qtensor(
     )
 
 
-# A window layer's read of its ring (``serving/scheduler.py``, "Window and
+# A window layer's read of its ring (``serving/adapter.py``, "Window and
 # global pages"): the same kernel under a name of its own, so that a trace
 # times the two page classes apart, and a lowering counter of its own.
 WINDOW_READ = "cgx_dequantize_window"
@@ -232,7 +232,7 @@ def gather_dequant_pages(
     ring)``; the kernel is then called :data:`WINDOW_READ` and the call site
     counted as ``cgx.codec.lowering.dequantize_pages.window.*``.
     ``live (B, P) bool`` (the ring's caller has one:
-    ``scheduler.ring_live``): the entries that hold a key some query can
+    ``adapter.ring_live``): the entries that hold a key some query can
     see. A dead entry's page is neither fetched nor decoded and its rows
     read zero, finite under the attention's ``p @ v`` whatever its slot
     names (a sentinel, a page that slid out, a vacated lane's ring); a live

@@ -1243,7 +1243,7 @@ def solve_serve_plan(
 ) -> ServePlan:
     """argmin of :meth:`CostModel.predict_serve` over the candidate
     (page size, shipping depth) grid — the ``CGX_KV_PAGE_TOKENS=0`` /
-    ``CGX_KV_SHIP_DEPTH=0`` decision (``serving/scheduler.py
+    ``CGX_KV_SHIP_DEPTH=0`` decision (``serving/adapter.py
     ServeConfig.from_env``). Ties prefer the smaller page and the
     shallower depth (less pool fragmentation / fewer in-flight frames
     for the same predicted TTFT). Host-side trace-time Python — nothing

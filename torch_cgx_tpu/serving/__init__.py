@@ -12,10 +12,15 @@ inference:
 * :mod:`.transport` — disaggregated prefill→decode shipping of
   quantized pages over the shm/store bridge with publish-after-write
   counter streams (decode never blocks on prefill).
-* :mod:`.scheduler` — continuous-batching decode over a model adapter's
-  cache streams: admit/evict per step, paged gather with the dequantize
-  fused into the KV read, bounded prefill-failover instead of wedging;
-  the GPT-2 adapter (streams ``k``, ``v``).
+* :mod:`.adapter` — what a model adapter is (``Adapter``, ``ServeConfig``)
+  and what it may use of the cache: page specs, lane and ring masks, the
+  paged read.
+* :mod:`.programs` — the seven compiled programs over an adapter's cache
+  streams (paged gather with the dequantize fused into the KV read) and
+  the state they hand one another.
+* :mod:`.scheduler` — continuous-batching decode: admit/evict per step,
+  the program LRU, bounded prefill-failover instead of wedging.
+* :mod:`.gpt2` — the GPT-2 adapter (streams ``k``, ``v``).
 * :mod:`.latent` — the latent-attention (MLA) adapter with dropless
   experts (streams ``c``, ``kr``).
 * :mod:`.hybrid` — the hybrid adapters: pages on their few attention
@@ -32,11 +37,11 @@ inference:
 """
 
 from .kv_cache import PagedKvCache, resolve_kv_config  # noqa: F401
+from .adapter import ServeConfig  # noqa: F401
+from .gpt2 import GPT2Server  # noqa: F401
 from .scheduler import (  # noqa: F401
     ContinuousBatchScheduler,
-    GPT2Server,
     Request,
-    ServeConfig,
     invalidate_decode_cache,
 )
 from .latent import LatentMoEServer  # noqa: F401

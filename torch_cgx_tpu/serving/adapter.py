@@ -1,0 +1,350 @@
+"""What a model adapter is, and what it may use of the cache.
+
+The scheduler (``serving/scheduler.py``) serves a MODEL ADAPTER through the
+compiled programs of ``serving/programs.py``. An adapter states, per layer,
+the cache streams a token leaves behind as ``(name, PageSpec)`` (GPT-2's
+``k`` and ``v``, a latent-attention model's latent and rotated key) and gives
+a prefill and a decode forward over them. Pools, tails and every program go
+over the streams the adapter names; nothing above this module knows what a
+stream means. Layers may name different streams (a hybrid model's few
+attention layers among its recurrent ones): a layer without a stream has no
+pool, tail or payload for it. Beside the pages an adapter may state, per
+layer, STATE streams ``(name, shape, dtype)``: a fixed-size recurrent state
+a lane, rewritten whole by every decode step, written into a lane at
+admission from what the prefill left on the device, and never committed,
+paged, forked or evicted by page.
+
+A layer's pages are of one of two CLASSES, which the adapter states
+(``page_window(layer)``, "Window and global pages" in docs/SERVING.md).
+*Global* pages are the above: the lane's row of ``page_table``, as many as
+the sequence is long. A sliding-window layer's pages are a *ring*: ``ring =
+ceil(W / page_tokens) + 1`` pool rows a sequence (``kv_cache.alloc_ring``),
+named by the lane's row of a second table, ``ring_table (lanes, ring)``;
+page ``n`` is written into slot ``n % ring``, over page ``n - ring``, which
+no query of the lane can see again. Such a layer's pools hold ``max_batch x
+ring + 1`` rows whatever ``max_seq`` is, its decode read goes over the ring
+alone, and its mask is made of positions (:func:`ring_masks`). An adapter
+that states no window builds exactly the programs and the state it built
+before there were classes.
+
+:class:`Adapter` is the protocol, with the defaults every adapter shares; a
+model is a subclass in a module of its own (``gpt2.py``, ``latent.py``,
+``hybrid.py``, ``window.py``) that imports this module, ``models/`` and
+``ops/`` and nothing above. Beside it, what a ``decode_forward`` is made of:
+the lane's masks (:func:`lane_masks`, :func:`ring_masks`,
+:func:`ring_live`), a layer's cache as its attention contracts it
+(:func:`layer_cache_rows`), and the K/V attention over both
+(:func:`attend_paged`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .. import config as cfg_mod
+from ..models.attention import decode_attention
+from ..ops import paged_kv
+from .kv_cache import resolve_kv_config
+from .transport import DEFAULT_SHIP_DEPTH
+
+
+@dataclasses.dataclass(frozen=True)
+class ServeConfig:
+    """Serving geometry (static shapes of the compiled decode step)."""
+
+    page_tokens: int
+    max_batch: int
+    max_pages: int
+    max_seq: int
+    ship_depth: int
+    eos_token: Optional[int] = None
+
+    def __post_init__(self):
+        if self.max_seq < self.page_tokens:
+            raise ValueError(
+                f"max_seq {self.max_seq} < page_tokens {self.page_tokens}"
+            )
+
+    @property
+    def pages_per_seq(self) -> int:
+        return -(-self.max_seq // self.page_tokens)
+
+    @property
+    def commit_lanes(self) -> int:
+        """Tails one call of the ``commit`` program promotes (its ``K``).
+        With every lane decoding, ``max_batch / page_tokens`` tails fill a
+        step; a power of two four times that, and at least 4, leaves a
+        second call to the rare tick in which more fill at once (the start
+        of a run, a burst of equal prompts): 8 of 96 lanes at 64-token
+        pages, 4 of 32 or 64 at 64 or 256."""
+        fills = -(-self.max_batch // self.page_tokens)
+        return min(self.max_batch, max(4, 1 << (4 * fills - 1).bit_length()))
+
+    @classmethod
+    def from_env(cls, model=None,
+                 eos_token: Optional[int] = None) -> "ServeConfig":
+        """Knobs with the planner filling the zeros: ``CGX_KV_PAGE_TOKENS``
+        / ``CGX_KV_SHIP_DEPTH`` unset lets ``planner.solve_serve_plan``
+        pick page size and shipping depth from the serve cost curves.
+        ``model`` (an adapter or its model config: anything with
+        ``n_layer``, ``kv_bytes_per_token()`` and
+        ``state_bytes_per_lane()``) says what a token's cache weighs, which
+        differs sevenfold between a K/V cache and a latent one, and what a
+        lane's recurrent state weighs whatever its length; without it the
+        static defaults apply."""
+        pt = cfg_mod.kv_page_tokens()
+        depth = cfg_mod.kv_ship_depth()
+        if (not pt or not depth) and model is not None:
+            from ..parallel import planner
+
+            plan = planner.solve_serve_plan(
+                prompt_tokens=min(cfg_mod.serve_max_seq(), 128),
+                kv_token_bytes=model.kv_bytes_per_token(),
+                # a hybrid model's pages are its attention layers' alone
+                n_layers=getattr(model, "n_cache_layers", model.n_layer),
+                bits=cfg_mod.kv_bits(),
+                bucket=cfg_mod.default_compression_config().bucket_size,
+                state_lane_bytes=model.state_bytes_per_lane(),
+            )
+            pt = pt or plan.page_tokens
+            depth = depth or plan.ship_depth
+        return cls(
+            page_tokens=pt or cfg_mod.DEFAULT_KV_PAGE_TOKENS,
+            max_batch=cfg_mod.serve_max_batch(),
+            max_pages=cfg_mod.serve_max_pages(),
+            max_seq=cfg_mod.serve_max_seq(),
+            ship_depth=depth or DEFAULT_SHIP_DEPTH,
+            eos_token=eos_token,
+        )
+
+
+
+def page_specs(layer_name: str, page_tokens: int,
+               shapes: Sequence[Tuple[int, int]]) -> List[paged_kv.PageSpec]:
+    """The page geometry of a layer's cache streams, one per ``(n_head,
+    d_head)`` of ``shapes``, under the CURRENT ``kv_page`` resolution of the
+    layer (resolved once: this runs every tick, for the program key): the
+    registered edge configs (the SLO controller's writes) or the
+    ``CGX_KV_BITS`` env default decide bits; the bucket is the resolved
+    config's (env-back-filled) bucket clipped to each stream's page
+    payload."""
+    cc = resolve_kv_config(layer_name)
+    if cc is None or not cc.enabled:
+        return [paged_kv.PageSpec(page_tokens, h, d, bits=0, bucket_size=1)
+                for h, d in shapes]
+    return [
+        paged_kv.PageSpec(
+            page_tokens, h, d, bits=cc.bits,
+            bucket_size=paged_kv.default_bucket(page_tokens * h * d,
+                                                cc.bucket_size),
+        )
+        for h, d in shapes
+    ]
+
+
+
+def lane_masks(serve: ServeConfig, state):
+    """What every attention layer of a decode step shares: ``(tail_idx
+    (B,)``, the tail row this token's cache payload goes to; ``mask_c (B,
+    pages x page_tokens)``, the committed positions; ``mask_t (B,
+    page_tokens))``, the tail's live positions, this token's among them."""
+    pt = serve.page_tokens
+    b = state["tokens"].shape[0]
+    tail_idx = jnp.minimum(state["tail_len"], pt - 1)
+    committed = state["n_pages"] * pt
+    pos_c = jax.lax.broadcasted_iota(
+        jnp.int32, (b, serve.pages_per_seq * pt), 1)
+    pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
+    mask_c = pos_c < committed[:, None]
+    mask_t = pos_t <= tail_idx[:, None]
+    return tail_idx, mask_c, mask_t
+
+
+def ring_pages(serve: ServeConfig, window: int) -> int:
+    """Pool rows a sequence's ring holds on a layer of window ``window``:
+    the pages that can hold a visible key while the tail fills, and the one
+    that has slid out, which the next commit writes over."""
+    return -(-window // serve.page_tokens) + 1
+
+
+def _slot_pages(state, slot, ring: int):
+    """The newest committed page a lane's ring holds in ``slot (B, ...)``:
+    the newest ``n`` with ``n % ring == slot``; under 0, never written."""
+    newest = state["n_pages"][:, None] - 1
+    return newest - (newest - slot) % ring
+
+
+def ring_masks(serve: ServeConfig, state, window: int):
+    """A window layer's ``mask_c (B, ring x page_tokens)`` beside
+    :func:`lane_masks`' (whose tail mask holds as it is: a tail is never
+    longer than a page, and a window never shorter). Slot ``s`` of a lane's
+    ring holds the newest committed page ``n`` with ``n % ring == s``; its
+    row ``r`` is position ``n * page_tokens + r``, live where the lane's
+    token at ``pos`` can see it: ``pos - position < window``."""
+    pt = serve.page_tokens
+    ring = ring_pages(serve, window)
+    b = state["tokens"].shape[0]
+    at = jax.lax.broadcasted_iota(jnp.int32, (b, ring * pt), 1)
+    page = _slot_pages(state, at // pt, ring)
+    position = page * pt + at % pt
+    return (page >= 0) & (state["pos"][:, None] - position < window)
+
+
+def ring_live(serve: ServeConfig, state, window: int):
+    """:func:`ring_masks` by slot, ``(B, ring) bool``: the slots that hold a
+    row the lane's token can see, which is whether it sees the slot's newest
+    row. A dead slot is one never written (a short lane's, a vacated lane's
+    whole ring) or one whose page has slid out of the window; the read
+    neither fetches nor decodes it (``paged_kv.gather_dequant_pages``). A
+    step's sum over the held lanes is what the host counts as
+    ``cgx.serve.kv.live_pages.window``."""
+    pt = serve.page_tokens
+    ring = ring_pages(serve, window)
+    b = state["tokens"].shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (b, ring), 1)
+    page = _slot_pages(state, slot, ring)
+    newest_row = page * pt + pt - 1
+    return (page >= 0) & (state["pos"][:, None] - newest_row < window)
+
+
+def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
+                     dtype, window: bool = False, live=None):
+    """A layer's cache as its attention contracts it, at a decode position:
+    for each of the layer's streams, in order, this token's payload (the
+    matching entry of ``fresh``, ``(B, ...)`` of the stream's width) written
+    into the raw tail (``paged_kv.append_tail_rows``) and the committed
+    pages read where they lie (``paged_kv.gather_dequant_pages``). Returns
+    ``({stream: pages (B, P * page_tokens, width)}, {stream: tail rows (B,
+    page_tokens, width)}``, both in ``dtype``, ``{stream: the new float32
+    tail})``. ``window``: the layer's pages are the lane's ring, ``P`` its
+    slots, in the ring's order (a softmax does not care). ``live (B, P)
+    bool``: the table's entries the read decodes (:func:`ring_live`), the
+    others' rows zeros; None reads every entry."""
+    table = state["ring_table" if window else "page_table"]
+    pages, tails, new = {}, {}, {}
+    for (name, spec), value in zip(layer_streams, fresh):
+        new[name], tails[name] = paged_kv.append_tail_rows(
+            state[f"tail_{name}"][layer], tail_idx, value, dtype
+        )
+        pages[name] = paged_kv.gather_dequant_pages(
+            state["pools"][layer][name], table, spec, dtype, window=window,
+            live=live,
+        )
+    return pages, tails, new
+
+
+
+def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
+                 score_divisor, window: bool = False, live=None):
+    """One decode position of an attention layer over a lane's cache: this
+    token's ``k`` and ``v (B, 1, Hk, dh)`` into the raw tails and the
+    committed pages read as ``dt`` rows where they lie
+    (:func:`layer_cache_rows`), one ``decode_attention`` of ``q (B, 1, H,
+    dh)`` over pages and tail. ``masks`` are :func:`lane_masks`' (with
+    ``window``, the layer's pages are the lane's ring and the page mask
+    :func:`ring_masks`', and ``live`` its slots that hold a visible key,
+    :func:`ring_live`'s: the read skips the others). Returns ``(o (B, H *
+    dh), {stream: its new tail})``."""
+    tail_idx, mask_c, mask_t = masks
+    pages, tails, new = layer_cache_rows(
+        state, layer, layer_streams, tail_idx, (k, v), dt, window, live
+    )
+    o = decode_attention(
+        q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],
+        mask=mask_c, tail_mask=mask_t, score_divisor=score_divisor,
+    )
+    return o, new
+
+
+class Adapter:
+    """The protocol between a model and the serving plane, for one ``(model
+    config, params)`` pair. What the scheduler and its programs ask of an
+    adapter, and what this class answers for every model:
+
+    ``kind``
+        a name for the program key (``"gpt2"``, ``"mla_moe"``); the
+        subclass's.
+    ``geometry``
+        hashable model geometry, for the program key: the config
+        dataclass's fields.
+    ``n_layer``, ``serve``, ``p``
+        ``p`` is the parameter tree, an operand of every program: the
+        programs keep an adapter without one (``programs.build``) and take
+        the tree through :meth:`with_params` as they trace.
+    ``step_counters``
+        names under ``cgx.serve.`` of what ``decode_forward`` counts each
+        step (empty for a model that counts nothing).
+    ``layer_name(l)``
+        the layer's ``kv_page`` edge name.
+    ``cache_streams(l)``
+        the layer's cache streams, ``((name, PageSpec), ...)``, built with
+        :func:`page_specs`; ``()`` for a layer that leaves no pages. The
+        programs' stream names are the layers' union, in order of first
+        appearance. The subclass's.
+    ``state_streams(l)``
+        the layer's recurrent state a lane, ``((name, shape, dtype),
+        ...)``; ``()`` for a layer (or a model) with none.
+    ``page_window(l)``
+        the class of the layer's pages: 0, global (the lane's
+        ``page_table`` row); ``W``, the layer attends the last ``W``
+        positions and keeps its pages as a ring (the lane's ``ring_table``
+        row, :func:`ring_pages` slots). All window layers of a model state
+        one ``W``.
+    ``with_params(p)``
+        the adapter over another (traced) parameter tree.
+    ``kv_bytes_per_token()``
+        float32 bytes a token's cache weighs, all layers.
+    ``state_bytes_per_lane()``
+        float32 bytes of a lane's state streams, all layers.
+    ``prefill_forward(tokens, positions, last_idx)``
+        the subclass's. Returns logits ``(B, V)``, then one list per cache
+        stream name, of each layer's ``(B, S, n_head, d_head)`` f32 cache
+        payload, then one list per state stream name, of each layer's ``(B,
+        *shape)`` state after position ``last_idx``; None in a list for a
+        layer without that stream.
+    ``decode_forward(state, streams)``
+        the subclass's. Returns logits ``(B, V)``; ``{stream: [each layer's
+        new tail (B, page_tokens, n_head * d_head) f32, rows as the
+        attention reads them, this token's written by
+        paged_kv.append_tail_rows]}`` and, in the same dictionary, ``{state
+        stream: [each layer's new state (B, *shape)]}``, None for a layer
+        without it; and an int32 vector of ``step_counters`` or None.
+        ``state`` holds ``pools[l][stream]``, ``tail_<stream>[l]`` and
+        ``state_<state stream>[l]``, laid out by ``programs.fresh_state``.
+    """
+
+    kind: str
+    step_counters: Tuple[str, ...] = ()
+
+    def __init__(self, model_cfg, params,
+                 serve: Optional[ServeConfig] = None):
+        self.cfg = model_cfg
+        self.p = params
+        self.serve = serve or ServeConfig.from_env(model_cfg)
+        self.n_layer = model_cfg.n_layer
+        self.geometry = tuple(
+            (f.name, str(getattr(model_cfg, f.name)))
+            for f in dataclasses.fields(model_cfg)
+        )
+
+    def layer_name(self, layer: int) -> str:
+        return f"layer_{layer}"
+
+    def state_streams(self, layer: int):
+        return ()
+
+    def page_window(self, layer: int) -> int:
+        return 0
+
+    def with_params(self, params):
+        return type(self)(self.cfg, params, self.serve)
+
+    def kv_bytes_per_token(self) -> int:
+        return self.cfg.kv_bytes_per_token()
+
+    def state_bytes_per_lane(self) -> int:
+        return 0

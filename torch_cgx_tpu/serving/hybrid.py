@@ -27,7 +27,7 @@ at the prompt's last token:
 
 ``state_streams`` states them; the scheduler carries them in the donated
 state beside pools and tails. What an attention layer's decode position does
-is the same in both (``scheduler.lane_masks``, :func:`attend_paged`): the
+is the same in both (``adapter.lane_masks``, ``adapter.attend_paged``): the
 token's ``k`` and ``v`` into the raw tail, the committed pages read where
 they lie, one ``decode_attention`` over both.
 
@@ -46,7 +46,6 @@ served with local prefill.
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -57,63 +56,30 @@ from ..models import granite_hybrid as gh
 from ..models import ling_hybrid as lh
 from ..models import mla_moe
 from ..models import olmo_hybrid as oh
-from ..models.attention import decode_attention
 from ..models.mla_moe import _mm, rms_norm
 from ..parallel import moe
-from .scheduler import (
+from .adapter import (
+    Adapter,
     ServeConfig,
+    attend_paged,
     lane_masks,
     layer_cache_rows,
     page_specs,
 )
 
 
-def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
-                 score_divisor, window: bool = False, live=None):
-    """One decode position of an attention layer over a lane's cache: this
-    token's ``k`` and ``v (B, 1, Hk, dh)`` into the raw tails and the
-    committed pages read as ``dt`` rows where they lie
-    (``scheduler.layer_cache_rows``), one ``decode_attention`` of ``q (B, 1,
-    H, dh)`` over pages and tail. ``masks`` are ``scheduler.lane_masks``'
-    (with ``window``, the layer's pages are the lane's ring and the page
-    mask ``scheduler.ring_masks``', and ``live`` its slots that hold a
-    visible key, ``scheduler.ring_live``'s: the read skips the others).
-    Returns ``(o (B, H * dh), {stream: its new tail})``."""
-    tail_idx, mask_c, mask_t = masks
-    pages, tails, new = layer_cache_rows(
-        state, layer, layer_streams, tail_idx, (k, v), dt, window, live
-    )
-    o = decode_attention(
-        q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],
-        mask=mask_c, tail_mask=mask_t, score_divisor=score_divisor,
-    )
-    return o, new
-
-
-class _HybridAdapter:
-    """What the adapters share of the protocol in ``scheduler.py``: one
-    ``(model config, params)`` pair, pages on the layers the config lists as
-    ``attention_layers`` (``k`` and ``v`` unless a subclass names other
-    streams), and on the others the state streams a subclass names, kept in
-    ``state_dtype`` (float32 unless a control asks for less)."""
-
-    step_counters = ()
+class _HybridAdapter(Adapter):
+    """What the hybrid adapters add to :class:`~.adapter.Adapter`: pages on
+    the layers the config lists as ``attention_layers`` (``k`` and ``v``
+    unless a subclass names other streams), and on the others the state
+    streams a subclass names, kept in ``state_dtype`` (float32 unless a
+    control asks for less)."""
 
     def __init__(self, model_cfg, params,
                  serve: Optional[ServeConfig] = None,
                  state_dtype: Any = jnp.float32):
-        self.cfg = model_cfg
-        self.p = params
-        self.serve = serve or ServeConfig.from_env(model_cfg)
+        super().__init__(model_cfg, params, serve)
         self.state_dtype = jnp.dtype(state_dtype)
-        self.n_layer = model_cfg.n_layer
-        self.geometry = tuple(
-            (f.name, str(getattr(model_cfg, f.name)))
-            for f in dataclasses.fields(model_cfg)
-        )
-
-    def layer_name(self, layer: int) -> str:
-        return f"layer_{layer}"
 
     def cache_streams(self, layer: int):
         cfg = self.cfg
@@ -125,9 +91,6 @@ class _HybridAdapter:
 
     def with_params(self, params):
         return type(self)(self.cfg, params, self.serve, self.state_dtype)
-
-    def kv_bytes_per_token(self) -> int:
-        return self.cfg.kv_bytes_per_token()
 
     def state_bytes_per_lane(self) -> int:
         return (self.cfg.state_bytes_per_lane() // 4
@@ -267,7 +230,7 @@ class HybridGDNServer(_HybridAdapter):
     def decode_forward(self, state, streams):
         """One decode position: a full-attention layer reads its committed
         pages and, apart, its raw tail with this token's K and V appended
-        (:func:`attend_paged`); a delta-rule layer takes one step of its
+        (``adapter.attend_paged``); a delta-rule layer takes one step of its
         recurrence and hands back its state, rewritten. Returns (logits (B,
         V), the new tails and states by stream, None)."""
         cfg, dt = self.cfg, self.cfg.dtype

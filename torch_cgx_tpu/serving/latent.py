@@ -22,45 +22,21 @@ The disaggregated path ships K and V frames and refuses this adapter
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Optional
-
 import jax
-import jax.numpy as jnp
 
 from ..models import mla_moe
-from ..models.mla_moe import MlaMoeConfig
 from ..parallel import moe
-from .scheduler import (
-    ServeConfig,
-    lane_masks,
-    layer_cache_rows,
-    page_specs,
-)
+from .adapter import Adapter, lane_masks, layer_cache_rows, page_specs
 
 
-class LatentMoEServer:
-    """Model adapter (the protocol is in ``scheduler.py``) for one
-    ``(MlaMoeConfig, params)`` pair; cache streams ``c`` and ``kr``."""
+class LatentMoEServer(Adapter):
+    """Model adapter for one ``(MlaMoeConfig, params)`` pair; cache streams
+    ``c`` and ``kr``."""
 
     kind = "mla_moe"
     # What a decode step counts over its expert layers, as
     # ``cgx.serve.<name>``: ``moe.STATS`` in order.
     step_counters = tuple(f"moe.{name}" for name in moe.STATS)
-
-    def __init__(self, model_cfg: MlaMoeConfig, params,
-                 serve: Optional[ServeConfig] = None):
-        self.cfg = model_cfg
-        self.p = params
-        self.serve = serve or ServeConfig.from_env(model_cfg)
-        self.n_layer = model_cfg.n_layer
-        self.geometry = tuple(
-            (f.name, str(getattr(model_cfg, f.name)))
-            for f in dataclasses.fields(model_cfg)
-        )
-
-    def layer_name(self, layer: int) -> str:
-        return f"layer_{layer}"
 
     def cache_streams(self, layer: int):
         c, kr = page_specs(
@@ -68,18 +44,6 @@ class LatentMoEServer:
             [(1, self.cfg.kv_lora_rank), (1, self.cfg.d_rope)],
         )
         return (("c", c), ("kr", kr))
-
-    def state_streams(self, layer: int):
-        return ()
-
-    def with_params(self, params) -> "LatentMoEServer":
-        return LatentMoEServer(self.cfg, params, self.serve)
-
-    def kv_bytes_per_token(self) -> int:
-        return self.cfg.kv_bytes_per_token()
-
-    def state_bytes_per_lane(self) -> int:
-        return 0
 
     # -- forwards ----------------------------------------------------------
 

@@ -28,10 +28,12 @@ from ..ops import codec_host
 from ..utils.logging import get_logger, metrics
 from ..utils.tracing import trace_span
 from . import transport as tp
+from .programs import _resolved_specs
 from .scheduler import (
     _account_pages,
+    _decode_program,
     _observe_page_qerr,
-    _resolved_specs,
+    _pad_prompt,
 )
 
 log = get_logger()
@@ -164,11 +166,9 @@ def _prefill_forward(server, prompt: np.ndarray):
     per-layer K/V as host arrays ``(S, H, Dh) f32`` — jitted through the
     server's own program (prompts pad to a page multiple, so prefill and
     local-prefill numerics AND compiled programs are one code path)."""
-    from . import scheduler as sched_mod
-
-    prog = sched_mod._decode_program(server)
+    prog = _decode_program(server)
     s = prompt.shape[0]
-    padded = sched_mod._pad_prompt(prompt, server.serve.page_tokens)
+    padded = _pad_prompt(prompt, server.serve.page_tokens)
     first, payloads = prog.prefill(
         server.p, padded[None],
         np.arange(padded.shape[0], dtype=np.int32)[None],

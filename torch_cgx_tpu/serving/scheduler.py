@@ -1,46 +1,14 @@
 """Continuous-batching decode scheduler over the paged quantized KV pool.
 
-The scheduler serves a MODEL ADAPTER (the protocol is written out above
-:class:`GPT2Server`): the adapter states, per layer, the cache streams a
-token leaves behind as ``(name, PageSpec)`` — GPT-2's ``k`` and ``v``, a
-latent-attention model's latent and rotated key (``serving/latent.py``) —
-and gives a prefill and a decode forward over them. Pools, tails and every
-compiled program below go over the streams the adapter names; nothing here
-knows what a stream means. Layers may name different streams (a hybrid
-model's few attention layers among its state-space ones,
-``serving/hybrid.py``): a layer without a stream has no pool, tail or
-payload for it. Beside the pages an adapter may state, per layer, STATE
-streams ``(name, shape, dtype)``: a fixed-size recurrent state a lane,
-rewritten whole by every decode step, written into a lane at admission from
-what the prefill left on the device, and never committed, paged, forked or
-evicted by page.
-
-A layer's pages are of one of two CLASSES, which the adapter states
-(``page_window(layer)``, "Window and global pages" in docs/SERVING.md).
-*Global* pages are the above: the lane's row of ``page_table``, as many as
-the sequence is long. A sliding-window layer's pages are a *ring*: ``ring =
-ceil(W / page_tokens) + 1`` pool rows a sequence (``kv_cache.alloc_ring``),
-named by the lane's row of a second table, ``ring_table (lanes, ring)``;
-page ``n`` is written into slot ``n % ring``, over page ``n - ring``, which
-no query of the lane can see again. Such a layer's pools hold ``max_batch x
-ring + 1`` rows whatever ``max_seq`` is, its decode read goes over the ring
-alone, and its mask is made of positions (:func:`ring_masks`). An adapter
-that states no window builds exactly the programs and the state it built
-before there were classes.
-
-The decode worker runs ONE compiled step program: for every lane of a
-fixed ``CGX_SERVE_MAX_BATCH``-wide batch, gather the lane's committed KV
-pages (``ops/paged_kv.gather_dequant_pages`` — ``cfg.dtype`` rows as the
-attention reads them, Pallas codec on TPU dispatch), attend the lane's
-current token against the pages and, apart, the raw f32 tail block, and
-emit the greedy next token. Admission and eviction happen per step
-around that program (continuous batching): completed lanes free their
-pages back to the refcounted pool and a waiting request takes the lane
-on the next step — the batch never drains to refill. An admission is two
-more compiled programs over the same donated state: ``prefill_pages``
-(forward, the prompt's pages into the pools, its tails and its first token
-left on the device) and ``admit_lane`` (one lane written in place, the
-first token an operand it takes from the device).
+The scheduler serves a model adapter (``serving/adapter.py``) through the
+compiled programs built for it (``serving/programs.py``): it decides which
+request holds which lane and which pool rows, dispatches the programs over
+the state they donate to one another, and reads what they produced. Nothing
+here knows what a stream means or what a program computes. Admission and
+eviction happen per step around the one decode program (continuous
+batching): completed lanes free their pages back to the refcounted pool and
+a waiting request takes the lane on the next step, so the batch never drains
+to refill.
 
 A tick queues everything before it reads anything. The programs are
 ordered on the device by the state they donate to one another, so the
@@ -109,8 +77,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import config as cfg_mod
-from ..models.attention import decode_attention, dense_attention
-from ..models.gpt2 import GPT2Config
 from ..ops import codec_host
 from ..ops import paged_kv
 from ..observability import memledger, timeline
@@ -123,6 +89,7 @@ from ..utils.tracing import (
 )
 from ..wire import dispatch as wire_dispatch
 from . import kv_cache as kv_mod
+from . import programs
 from . import transport as tp
 
 log = get_logger()
@@ -157,86 +124,11 @@ _TICK_ACCOUNT = tuple(
     f"cgx.serve.{hist}"
     for hist in ("step_s", *_TICK_SPANS.values(), *_TICK_WITHIN.values())
 )
-# The per-lane bookkeeping of the decode state, and what ``release_lanes``
-# resets each entry of a finished or evicted lane to.
-_LANE_RESET = {"active": False, "n_pages": 0, "tail_len": 0, "page_table": -1}
-# The same of the second table, which a model with window layers keeps.
-_RING_RESET = {"ring_table": -1}
 
 
 # ---------------------------------------------------------------------------
-# Config + request surface.
+# Request surface.
 # ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class ServeConfig:
-    """Serving geometry (static shapes of the compiled decode step)."""
-
-    page_tokens: int
-    max_batch: int
-    max_pages: int
-    max_seq: int
-    ship_depth: int
-    eos_token: Optional[int] = None
-
-    def __post_init__(self):
-        if self.max_seq < self.page_tokens:
-            raise ValueError(
-                f"max_seq {self.max_seq} < page_tokens {self.page_tokens}"
-            )
-
-    @property
-    def pages_per_seq(self) -> int:
-        return -(-self.max_seq // self.page_tokens)
-
-    @property
-    def commit_lanes(self) -> int:
-        """Tails one call of the ``commit`` program promotes (its ``K``).
-        With every lane decoding, ``max_batch / page_tokens`` tails fill a
-        step; a power of two four times that, and at least 4, leaves a
-        second call to the rare tick in which more fill at once (the start
-        of a run, a burst of equal prompts): 8 of 96 lanes at 64-token
-        pages, 4 of 32 or 64 at 64 or 256."""
-        fills = -(-self.max_batch // self.page_tokens)
-        return min(self.max_batch, max(4, 1 << (4 * fills - 1).bit_length()))
-
-    @classmethod
-    def from_env(cls, model=None,
-                 eos_token: Optional[int] = None) -> "ServeConfig":
-        """Knobs with the planner filling the zeros: ``CGX_KV_PAGE_TOKENS``
-        / ``CGX_KV_SHIP_DEPTH`` unset lets ``planner.solve_serve_plan``
-        pick page size and shipping depth from the serve cost curves.
-        ``model`` (an adapter or its model config: anything with
-        ``n_layer``, ``kv_bytes_per_token()`` and
-        ``state_bytes_per_lane()``) says what a token's cache weighs, which
-        differs sevenfold between a K/V cache and a latent one, and what a
-        lane's recurrent state weighs whatever its length; without it the
-        static defaults apply."""
-        pt = cfg_mod.kv_page_tokens()
-        depth = cfg_mod.kv_ship_depth()
-        if (not pt or not depth) and model is not None:
-            from ..parallel import planner
-
-            plan = planner.solve_serve_plan(
-                prompt_tokens=min(cfg_mod.serve_max_seq(), 128),
-                kv_token_bytes=model.kv_bytes_per_token(),
-                # a hybrid model's pages are its attention layers' alone
-                n_layers=getattr(model, "n_cache_layers", model.n_layer),
-                bits=cfg_mod.kv_bits(),
-                bucket=cfg_mod.default_compression_config().bucket_size,
-                state_lane_bytes=model.state_bytes_per_lane(),
-            )
-            pt = pt or plan.page_tokens
-            depth = depth or plan.ship_depth
-        return cls(
-            page_tokens=pt or cfg_mod.DEFAULT_KV_PAGE_TOKENS,
-            max_batch=cfg_mod.serve_max_batch(),
-            max_pages=cfg_mod.serve_max_pages(),
-            max_seq=cfg_mod.serve_max_seq(),
-            ship_depth=depth or tp.DEFAULT_SHIP_DEPTH,
-            eos_token=eos_token,
-        )
 
 
 @dataclasses.dataclass
@@ -253,414 +145,10 @@ class Request:
     done: bool = False
 
 
-# ---------------------------------------------------------------------------
-# Model adapters. What the scheduler and its programs ask of one:
-#
-#   kind            a name for the program key ("gpt2", "mla_moe")
-#   geometry        hashable model geometry, for the program key
-#   n_layer, serve, p (the parameter tree, an argument of every program)
-#   step_counters   names under ``cgx.serve.`` of what ``decode_forward``
-#                   counts each step (empty for a model that counts nothing)
-#   layer_name(l)   the layer's ``kv_page`` edge name
-#   cache_streams(l)  the layer's cache streams, ``((name, PageSpec), ...)``,
-#                   built with :func:`page_specs`; ``()`` for a layer that
-#                   leaves no pages. The programs' stream names are the
-#                   layers' union, in order of first appearance
-#   state_streams(l)  the layer's recurrent state a lane, ``((name, shape,
-#                   dtype), ...)``; ``()`` for a layer (or a model) with none
-#   page_window(l)  optional. The class of the layer's pages: 0, global (the
-#                   lane's ``page_table`` row); ``W``, the layer attends the
-#                   last ``W`` positions and keeps its pages as a ring (the
-#                   lane's ``ring_table`` row, :func:`ring_pages` slots). All
-#                   window layers of a model state one ``W``. An adapter
-#                   without the method has global pages alone
-#   with_params(p)  the adapter over another (traced) parameter tree
-#   kv_bytes_per_token()  float32 bytes a token's cache weighs, all layers
-#   state_bytes_per_lane()  float32 bytes of a lane's state streams, all layers
-#   prefill_forward(tokens, positions, last_idx) -> (logits (B, V), then one
-#                   list per cache stream name, of each layer's (B, S,
-#                   n_head, d_head) f32 cache payload, then one list per
-#                   state stream name, of each layer's (B, *shape) state after
-#                   position ``last_idx``; None in a list for a layer
-#                   without that stream)
-#   decode_forward(state, streams) -> (logits (B, V), {stream: [each
-#                   layer's new tail (B, page_tokens, n_head * d_head) f32,
-#                   rows as the attention reads them, this token's written
-#                   by ``paged_kv.append_tail_rows``]} and,
-#                   in the same dictionary, {state stream: [each layer's new
-#                   state (B, *shape)]}, None for a layer without it,
-#                   int32 vector of ``step_counters`` or None); ``state``
-#                   holds ``pools[l][stream]``, ``tail_<stream>[l]`` and
-#                   ``state_<state stream>[l]``
-#
-# ``pools[l][stream]`` is ``paged_kv.empty_pool(max_pages + 1, spec)``
-# (``max_batch * ring + 1`` for a window layer): for
-# a quantized stream ``(words (max_pages + 1, *spec.word_shape) int32, meta
-# (max_pages + 1, num_buckets, 2) f32)``, a page's wire words as rows of
-# 128 — the flat decode kernel's own blocks, so the read fetches a page from
-# the pool by its id and nothing gathers or reshapes the pool first (on the
-# chip a ``(n, W)`` and a ``(n * W / 128, 128)`` array tile differently:
-# ``ops/paged_kv.py``, "Layouts"); ``(max_pages + 1, page_tokens, n_head,
-# d_head) f16`` for a raw one. The last row is scratch: a padded slot of the
-# ``commit`` program and a prefill's last page that is a tail write there.
-#
-# The GPT-2 adapter: explicit-parameter forward passes over the module's
-# own parameter tree (models/gpt2.py) — decode against the paged cache
-# needs per-layer K/V in and out, which the flax module doesn't expose.
-# ---------------------------------------------------------------------------
-
-
-def page_specs(layer_name: str, page_tokens: int,
-               shapes: Sequence[Tuple[int, int]]) -> List[paged_kv.PageSpec]:
-    """The page geometry of a layer's cache streams, one per ``(n_head,
-    d_head)`` of ``shapes``, under the CURRENT ``kv_page`` resolution of the
-    layer (resolved once: this runs every tick, for the program key): the
-    registered edge configs (the SLO controller's writes) or the
-    ``CGX_KV_BITS`` env default decide bits; the bucket is the resolved
-    config's (env-back-filled) bucket clipped to each stream's page
-    payload."""
-    cc = kv_mod.resolve_kv_config(layer_name)
-    if cc is None or not cc.enabled:
-        return [paged_kv.PageSpec(page_tokens, h, d, bits=0, bucket_size=1)
-                for h, d in shapes]
-    return [
-        paged_kv.PageSpec(
-            page_tokens, h, d, bits=cc.bits,
-            bucket_size=paged_kv.default_bucket(page_tokens * h * d,
-                                                cc.bucket_size),
-        )
-        for h, d in shapes
-    ]
-
-
-def _ln(x, scale, bias, eps=1e-6):
-    """flax.linen.LayerNorm numerics (f32 stats, rsqrt, mean2 variance)."""
-    xf = x.astype(jnp.float32)
-    mean = xf.mean(-1, keepdims=True)
-    mean2 = (xf * xf).mean(-1, keepdims=True)
-    var = jnp.maximum(0.0, mean2 - mean * mean)
-    y = (xf - mean) * jax.lax.rsqrt(var + eps)
-    return y * scale.astype(jnp.float32) + bias.astype(jnp.float32)
-
-
-def _dense(x, w, b, dtype):
-    y = x.astype(dtype) @ w.astype(dtype)
-    return y + b.astype(dtype) if b is not None else y
-
-
-def lane_masks(serve: ServeConfig, state):
-    """What every attention layer of a decode step shares: ``(tail_idx
-    (B,)``, the tail row this token's cache payload goes to; ``mask_c (B,
-    pages x page_tokens)``, the committed positions; ``mask_t (B,
-    page_tokens))``, the tail's live positions, this token's among them."""
-    pt = serve.page_tokens
-    b = state["tokens"].shape[0]
-    tail_idx = jnp.minimum(state["tail_len"], pt - 1)
-    committed = state["n_pages"] * pt
-    pos_c = jax.lax.broadcasted_iota(
-        jnp.int32, (b, serve.pages_per_seq * pt), 1)
-    pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
-    mask_c = pos_c < committed[:, None]
-    mask_t = pos_t <= tail_idx[:, None]
-    return tail_idx, mask_c, mask_t
-
-
-def ring_pages(serve: ServeConfig, window: int) -> int:
-    """Pool rows a sequence's ring holds on a layer of window ``window``:
-    the pages that can hold a visible key while the tail fills, and the one
-    that has slid out, which the next commit writes over."""
-    return -(-window // serve.page_tokens) + 1
-
-
-def _slot_pages(state, slot, ring: int):
-    """The newest committed page a lane's ring holds in ``slot (B, ...)``:
-    the newest ``n`` with ``n % ring == slot``; under 0, never written."""
-    newest = state["n_pages"][:, None] - 1
-    return newest - (newest - slot) % ring
-
-
-def ring_masks(serve: ServeConfig, state, window: int):
-    """A window layer's ``mask_c (B, ring x page_tokens)`` beside
-    :func:`lane_masks`' (whose tail mask holds as it is: a tail is never
-    longer than a page, and a window never shorter). Slot ``s`` of a lane's
-    ring holds the newest committed page ``n`` with ``n % ring == s``; its
-    row ``r`` is position ``n * page_tokens + r``, live where the lane's
-    token at ``pos`` can see it: ``pos - position < window``."""
-    pt = serve.page_tokens
-    ring = ring_pages(serve, window)
-    b = state["tokens"].shape[0]
-    at = jax.lax.broadcasted_iota(jnp.int32, (b, ring * pt), 1)
-    page = _slot_pages(state, at // pt, ring)
-    position = page * pt + at % pt
-    return (page >= 0) & (state["pos"][:, None] - position < window)
-
-
-def ring_live(serve: ServeConfig, state, window: int):
-    """:func:`ring_masks` by slot, ``(B, ring) bool``: the slots that hold a
-    row the lane's token can see, which is whether it sees the slot's newest
-    row. A dead slot is one never written (a short lane's, a vacated lane's
-    whole ring) or one whose page has slid out of the window; the read
-    neither fetches nor decodes it (``paged_kv.gather_dequant_pages``). A
-    step's sum over the held lanes is what the host counts as
-    ``cgx.serve.kv.live_pages.window``."""
-    pt = serve.page_tokens
-    ring = ring_pages(serve, window)
-    b = state["tokens"].shape[0]
-    slot = jax.lax.broadcasted_iota(jnp.int32, (b, ring), 1)
-    page = _slot_pages(state, slot, ring)
-    newest_row = page * pt + pt - 1
-    return (page >= 0) & (state["pos"][:, None] - newest_row < window)
-
-
-def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
-                     dtype, window: bool = False, live=None):
-    """A layer's cache as its attention contracts it, at a decode position:
-    for each of the layer's streams, in order, this token's payload (the
-    matching entry of ``fresh``, ``(B, ...)`` of the stream's width) written
-    into the raw tail (``paged_kv.append_tail_rows``) and the committed
-    pages read where they lie (``paged_kv.gather_dequant_pages``). Returns
-    ``({stream: pages (B, P * page_tokens, width)}, {stream: tail rows (B,
-    page_tokens, width)}``, both in ``dtype``, ``{stream: the new float32
-    tail})``. ``window``: the layer's pages are the lane's ring, ``P`` its
-    slots, in the ring's order (a softmax does not care). ``live (B, P)
-    bool``: the table's entries the read decodes (:func:`ring_live`), the
-    others' rows zeros; None reads every entry."""
-    table = state["ring_table" if window else "page_table"]
-    pages, tails, new = {}, {}, {}
-    for (name, spec), value in zip(layer_streams, fresh):
-        new[name], tails[name] = paged_kv.append_tail_rows(
-            state[f"tail_{name}"][layer], tail_idx, value, dtype
-        )
-        pages[name] = paged_kv.gather_dequant_pages(
-            state["pools"][layer][name], table, spec, dtype, window=window,
-            live=live,
-        )
-    return pages, tails, new
-
-
-class GPT2Server:
-    """The GPT-2 adapter: prefill/decode forwards + serving geometry for
-    one (GPT2Config, params) pair, cache streams ``k`` and ``v``. It is
-    the dense-MLP GPT-2 block and nothing else; a model with experts is
-    served by an adapter that has them (``serving/latent.py``)."""
-
-    kind = "gpt2"
-    step_counters = ()
-
-    def __init__(self, model_cfg: GPT2Config, params,
-                 serve: Optional[ServeConfig] = None):
-        if model_cfg.n_experts:
-            raise ValueError(
-                "GPT2Server is the dense-MLP GPT-2 adapter: it has no "
-                f"expert layer for n_experts={model_cfg.n_experts} (the "
-                "serving plane serves experts through "
-                "serving.latent.LatentMoEServer)"
-            )
-        self.cfg = model_cfg
-        self.p = params.get("params", params)
-        self.serve = serve or ServeConfig.from_env(model_cfg)
-        self.n_layer = model_cfg.n_layer
-        self.n_head = model_cfg.n_head
-        self.d_head = model_cfg.d_model // model_cfg.n_head
-        self.geometry = (
-            model_cfg.n_layer, model_cfg.n_head, model_cfg.d_model,
-            model_cfg.vocab_size, model_cfg.max_seq, str(model_cfg.dtype),
-        )
-
-    def layer_name(self, layer: int) -> str:
-        return f"layer_{layer}"
-
-    def cache_streams(self, layer: int):
-        (spec,) = page_specs(self.layer_name(layer), self.serve.page_tokens,
-                             [(self.n_head, self.d_head)])
-        return (("k", spec), ("v", spec))
-
-    def state_streams(self, layer: int):
-        return ()
-
-    def with_params(self, params) -> "GPT2Server":
-        return GPT2Server(self.cfg, params, self.serve)
-
-    def kv_bytes_per_token(self) -> int:
-        return self.cfg.kv_bytes_per_token()
-
-    def state_bytes_per_lane(self) -> int:
-        return 0
-
-    # -- forwards ----------------------------------------------------------
-
-    def _embed(self, tokens, positions):
-        wte = self.p["wte"]["embedding"]
-        wpe = self.p["wpe"]["embedding"]
-        x = wte[tokens] + wpe[positions]
-        return x.astype(self.cfg.dtype)
-
-    def _logits(self, x):
-        x = _ln(x, self.p["ln_f"]["scale"], self.p["ln_f"]["bias"])
-        wte = self.p["wte"]["embedding"].astype(jnp.float32)
-        return x.astype(jnp.float32) @ wte.T
-
-    def _block_tail(self, x, pl, attn_out):
-        """Shared post-attention half of a block: proj residual + MLP."""
-        dtype = self.cfg.dtype
-        ap = pl["attn"]["attn_proj"]
-        x = x + _dense(attn_out, ap["kernel"], ap.get("bias"), dtype)
-        y = _ln(x, pl["ln_2"]["scale"], pl["ln_2"]["bias"]).astype(dtype)
-        mi, mo = pl["mlp"]["mlp_in"], pl["mlp"]["mlp_out"]
-        h = jax.nn.gelu(_dense(y, mi["kernel"], mi.get("bias"), dtype))
-        return x + _dense(h, mo["kernel"], mo.get("bias"), dtype)
-
-    def _qkv(self, x, pl):
-        dtype = self.cfg.dtype
-        aq = pl["attn"]["attn_qkv"]
-        y = _ln(x, pl["ln_1"]["scale"], pl["ln_1"]["bias"]).astype(dtype)
-        qkv = _dense(y, aq["kernel"], aq.get("bias"), dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads(t):  # (B, S, Dm) -> (B, H, S, Dh)
-            b, s, _ = t.shape
-            return t.reshape(b, s, self.n_head, self.d_head).transpose(
-                0, 2, 1, 3
-            )
-
-        return heads(q), heads(k), heads(v)
-
-    def prefill_forward(self, tokens, positions, last_idx):
-        """Full causal forward over a (right-padded) prompt, returning
-        the logits at ``last_idx`` and every layer's K/V.
-
-        tokens/positions: (B, S) int32 — S is the PADDED length
-        (prompts pad to a page multiple so distinct prompt lengths share
-        one compiled program; under causal attention right-padding
-        cannot perturb any earlier position's K/V or the ``last_idx``
-        logits). Returns (logits (B, vocab), ks, vs): each a list per
-        layer of (B, S, H, Dh) f32 — the cache payload the pages
-        quantize (callers slice off the pad)."""
-        x = self._embed(tokens, positions)
-        ks: List[jax.Array] = []
-        vs: List[jax.Array] = []
-        for layer in range(self.cfg.n_layer):
-            pl = self.p[f"h_{layer}"]
-            q, k, v = self._qkv(x, pl)
-            ks.append(k.transpose(0, 2, 1, 3).astype(jnp.float32))
-            vs.append(v.transpose(0, 2, 1, 3).astype(jnp.float32))
-            o = dense_attention(q, k, v, causal=True)
-            b, _, s, _ = o.shape
-            o = o.transpose(0, 2, 1, 3).reshape(b, s, self.cfg.d_model)
-            x = self._block_tail(x, pl, o)
-        x_last = jax.lax.dynamic_index_in_dim(x, last_idx, 1)
-        return self._logits(x_last)[:, -1], ks, vs
-
-    def decode_forward(self, state, streams):
-        """One decode position against the paged cache: current tokens at
-        their positions, KV read = gathered committed pages (decoded to
-        ``cfg.dtype`` rows, contracted where they lie) and, apart, the raw
-        tail with this token's K/V appended; one softmax over both.
-        Returns (logits (B, vocab), the new tails by stream, None)."""
-        cfg = self.cfg
-        x = self._embed(state["tokens"][:, None], state["pos"][:, None])
-        tail_idx, mask_c, mask_t = lane_masks(self.serve, state)
-        new: Dict[str, List[jax.Array]] = {"k": [], "v": []}
-        for layer in range(cfg.n_layer):
-            pl = self.p[f"h_{layer}"]
-            q, k, v = self._qkv(x, pl)  # (B, H, 1, Dh)
-            pages, tails, written = layer_cache_rows(
-                state, layer, streams[layer], tail_idx,
-                (k[:, :, 0], v[:, :, 0]), cfg.dtype,
-            )
-            for name, tail in written.items():
-                new[name].append(tail)
-            o = decode_attention(
-                q[:, :, 0], pages["k"], pages["v"], tails["k"], tails["v"],
-                mask=mask_c, tail_mask=mask_t,
-            )
-            x = self._block_tail(x, pl, o[:, None])
-        return self._logits(x)[:, -1], new, None
-
 
 # ---------------------------------------------------------------------------
-# Resolved wire specs + the compiled-program LRU.
+# The compiled-program LRU.
 # ---------------------------------------------------------------------------
-
-
-def _resolved_streams(server) -> Tuple:
-    """Every layer's cache streams ``((name, PageSpec), ...)`` under the
-    CURRENT kv_page resolution (:func:`page_specs`), as the adapter states
-    them; ``()`` for a layer that leaves no pages."""
-    return tuple(
-        tuple(server.cache_streams(layer)) for layer in range(server.n_layer)
-    )
-
-
-def _resolved_state_streams(server) -> Tuple:
-    """Every layer's state streams as ``((name, (shape, dtype name)),
-    ...)``: the cache streams' form, a name and what one lane's row is."""
-    return tuple(
-        tuple((name, (tuple(shape), jnp.dtype(dtype).name))
-              for name, shape, dtype in server.state_streams(layer))
-        for layer in range(server.n_layer)
-    )
-
-
-def _resolved_windows(server) -> Tuple[int, ...]:
-    """Every layer's page class as the adapter states it
-    (``page_window``): 0 for global pages, the window for a ring."""
-    page_window = getattr(server, "page_window", None)
-    if page_window is None:
-        return (0,) * server.n_layer
-    return tuple(int(page_window(layer)) for layer in range(server.n_layer))
-
-
-def _ring(server, streams, windows) -> int:
-    """Slots of a lane's ring (:func:`ring_pages` of the window layers' one
-    window), 0 for a model without window layers."""
-    found = sorted({w for w in windows if w})
-    if not found:
-        return 0
-    if len(found) > 1:
-        raise ValueError(
-            f"adapter {server.kind!r} states the windows {found}: the lanes "
-            "keep one ring table, so every window layer has the same window"
-        )
-    if found[0] < server.serve.page_tokens:
-        raise ValueError(
-            f"window {found[0]} is shorter than a page "
-            f"({server.serve.page_tokens} tokens): the tail would outlive it"
-        )
-    bare = [l for l, w in enumerate(windows) if w and not streams[l]]
-    if bare:
-        raise ValueError(f"layers {bare} state a window and no cache stream")
-    return ring_pages(server.serve, found[0])
-
-
-def _stream_names(streams) -> Tuple[str, ...]:
-    """The names the layers' streams (cache or state) go by: their union,
-    in order of first appearance."""
-    return tuple(dict.fromkeys(
-        name for layer in streams for name, _ in layer
-    ))
-
-
-def _holders(streams) -> Dict[str, Dict[int, int]]:
-    """``{stream: {layer: its rank among the layers that have the
-    stream}}``: where a layer's entry lies in an array stacked over those
-    layers (a prefill's tails and states)."""
-    out: Dict[str, Dict[int, int]] = {n: {} for n in _stream_names(streams)}
-    for layer, layer_streams in enumerate(streams):
-        for name, _ in layer_streams:
-            out[name][layer] = len(out[name])
-    return out
-
-
-def _leading_specs(streams) -> Tuple[Optional[paged_kv.PageSpec], ...]:
-    """Each layer's leading stream's spec: the layer's wire resolution
-    (bits are per layer; GPT-2's ``k`` and ``v`` share the whole spec);
-    None for a layer without pages."""
-    return tuple(layer[0][1] if layer else None for layer in streams)
-
-
-def _resolved_specs(server) -> Tuple[paged_kv.PageSpec, ...]:
-    return _leading_specs(_resolved_streams(server))
 
 
 def _program_key(server) -> Tuple:
@@ -678,9 +166,9 @@ def _program_key(server) -> Tuple:
         server.geometry,
         (server.serve.page_tokens, server.serve.max_batch,
          server.serve.max_pages, server.serve.max_seq),
-        _resolved_streams(server),
-        _resolved_state_streams(server),
-        _resolved_windows(server),
+        programs._resolved_streams(server),
+        programs._resolved_state_streams(server),
+        programs._resolved_windows(server),
         cfg_mod.registry_version(),
         cfg_mod.trace_knob_fingerprint(),
     )
@@ -715,248 +203,9 @@ def _decode_program(server) -> SimpleNamespace:
     return prog
 
 
-def _build_programs(server) -> SimpleNamespace:
-    streams = _resolved_streams(server)
-    names = _stream_names(streams)
-    state_streams = _resolved_state_streams(server)
-    state_names = _stream_names(state_streams)
-    both = sorted(set(names) & set(state_names))
-    if both:
-        raise ValueError(
-            f"adapter {server.kind!r} names {both} both a cache stream and "
-            "a state stream"
-        )
-    holders = {**_holders(streams), **_holders(state_streams)}
-    n_layer = server.n_layer
-    sv = server.serve
-    windows = _resolved_windows(server)
-    ring = _ring(server, streams, windows)
-
-    def decode_step(params, state):
-        """One token for every lane. Returns the new state and what the
-        host reads each tick, in one array: the lanes' next tokens, then
-        the adapter's ``step_counters`` (none for GPT-2)."""
-        srv = server.with_params(params)
-        logits, new_tails, counts = srv.decode_forward(state, streams)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out = dict(state)
-        for name in names:
-            out[f"tail_{name}"] = tuple(new_tails[name])
-        for name in state_names:  # rewritten whole, every lane, every step
-            out[f"state_{name}"] = tuple(new_tails[name])
-        out["tail_len"] = jnp.where(
-            state["active"], state["tail_len"] + 1, state["tail_len"]
-        )
-        out["pos"] = jnp.where(state["active"], state["pos"] + 1,
-                               state["pos"])
-        out["tokens"] = jnp.where(state["active"], nxt, state["tokens"])
-        if counts is not None:
-            nxt = jnp.concatenate([nxt, counts.astype(jnp.int32)])
-        return out, nxt
-
-    def commit(state, lanes, page_ids, ring_ids=None):
-        """Promote the full tails of ``lanes (K,)`` into pool pages
-        ``page_ids (K,)`` (a window layer's into ``ring_ids (K,)``, rows of
-        its own pools: the lane's ring slot ``n_pages % ring``, whose last
-        page has slid out of the window), ``K = ServeConfig.commit_lanes``: the K lanes'
-        tails alone are gathered (rows as they are kept, flattened to ``(K,
-        page_tokens * width)`` payloads), quantized and scattered, a layer
-        and a stream at a time, and their ``page_table`` slot,
-        ``n_pages`` and ``tail_len`` written by scatter. Tails fill at
-        ``max_batch / page_tokens`` a step, so a program over every lane's
-        tail would throw nearly all of its work away. A slot the
-        caller has no tail for names any valid lane and the scratch row
-        (``max_pages``; pools carry ``max_pages + 1`` rows): its rows land
-        there and its lane's counts are left as they are, so one program
-        of one width serves any number of full tails."""
-        k = lanes.shape[0]
-        out = dict(state)
-        out["pools"] = tuple(
-            {
-                name: paged_kv.commit_page_rows(
-                    state["pools"][layer][name],
-                    ring_ids if windows[layer] else page_ids,
-                    state[f"tail_{name}"][layer][lanes].reshape(k, -1), spec,
-                )
-                for name, spec in streams[layer]
-            }
-            for layer in range(n_layer)
-        )
-        # Out of bounds for a padded slot: the scatters below drop it.
-        at = jnp.where(page_ids < sv.max_pages, lanes, sv.max_batch)
-        out["page_table"] = state["page_table"].at[
-            at, state["n_pages"][lanes]
-        ].set(page_ids, mode="drop")
-        if ring:
-            out["ring_table"] = state["ring_table"].at[
-                at, state["n_pages"][lanes] % ring
-            ].set(ring_ids, mode="drop")
-        out["n_pages"] = state["n_pages"].at[at].add(1, mode="drop")
-        out["tail_len"] = state["tail_len"].at[at].set(0, mode="drop")
-        return out
-
-    def ingest(pools, layer_rows, ids):
-        """Batch-write received/locally-prefetched page payload rows
-        (n, flat) into pool rows ``ids (n,)`` for every layer and stream
-        (``layer_rows[layer][stream]``) — the stream-completion path
-        (payloads already in pool layout when quantized)."""
-        return tuple(
-            {
-                name: _ingest_pool(
-                    pools[layer][name], ids, layer_rows[layer][name], spec
-                )
-                for name, spec in streams[layer]
-            }
-            for layer in range(n_layer)
-        )
-
-    def prefill(params, tokens, positions, last_idx):
-        """Forward alone, every layer's cache payload (and state) out by
-        stream: the prefill worker's program (``serving/prefill.py`` ships
-        the pages itself)."""
-        srv = server.with_params(params)
-        logits, *payloads = srv.prefill_forward(tokens, positions, last_idx)
-        return (
-            jnp.argmax(logits, axis=-1).astype(jnp.int32),
-            dict(zip(names + state_names, payloads)),
-        )
-
-    observe_qerr = cfg_mod.qerr_stats()  # in the program key's fingerprint
-
-    def prefill_pages(params, pools, tokens, positions, last_idx, ids,
-                      tail_len, ring_ids=None):
-        """The local prefill of one padded prompt, whole: forward, then
-        every page of every layer's streams through ``commit_page_rows``
-        into the donated pools at ``ids (padded pages,)``, and the last
-        page's first ``tail_len`` rows as the lane's tails ``{stream: (L,
-        page_tokens, H * Dh) f32}``, zero from ``tail_len`` on. A last page
-        that is a tail has the scratch row for its id, so one program
-        serves every prompt length under a padded length, whole pages or
-        not. The first token is a scalar, ``admit_lane``'s operand as it
-        is. Also ``{layer: its leading stream's rows as quantized}`` of the
-        quantized layers, empty unless ``CGX_QERR_STATS`` was on when the
-        programs were built. Last, the lane's recurrent state after
-        ``last_idx`` as the adapter's prefill left it, ``{state stream: (its
-        layers, *shape)}``, empty for a model without state streams. Tails
-        and states are stacked over the layers that have the stream. A
-        window layer writes the prompt's last ``ring_ids.shape[0]`` padded
-        pages alone (at most ``ring + 1``: the pages its ring keeps are
-        among them), into ``ring_ids``; the host names the scratch row for
-        those that have slid out already."""
-        first, payloads = prefill(params, tokens, positions, last_idx)
-        n_pages = ids.shape[0]
-        live = jax.lax.broadcasted_iota(
-            jnp.int32, (sv.page_tokens, 1), 0
-        ) < tail_len
-        out, tails, qerr_rows = [], {name: [] for name in names}, {}
-        for layer in range(n_layer):
-            pool, written = pools[layer], {}
-            for name, spec in streams[layer]:
-                x = payloads[name][layer][0]  # (padded tokens, H, Dh)
-                rows = x.reshape(n_pages, -1)
-                written[name] = paged_kv.commit_page_rows(
-                    pool[name],
-                    *((ring_ids, rows[-ring_ids.shape[0]:])
-                      if windows[layer] else (ids, rows)), spec,
-                )
-                tails[name].append(jnp.where(
-                    live, x[-sv.page_tokens:].reshape(sv.page_tokens, -1),
-                    0.0,
-                ))
-                if (observe_qerr and spec.quantized
-                        and name == streams[layer][0][0]):
-                    qerr_rows[layer] = rows
-            out.append(written)
-        states = {
-            name: jnp.stack([payloads[name][layer][0]
-                             for layer in holders[name]])
-            for name in state_names
-        }
-        return (
-            first[0], tuple(out),
-            {name: jnp.stack(t) for name, t in tails.items()}, qerr_rows,
-            states,
-        )
-
-    def admit_lane(state, lane, table_row, n_pages, tail_len, token, pos,
-                   tails, states, ring_row=None):
-        """Write one ready request into lane ``lane`` of the donated
-        state: its page-table row, counts, first token (a scalar still on
-        the device from the local prefill, or a host one from a page
-        stream) and position, its stacked tails ``{stream: (L, page_tokens,
-        H * Dh)}``, device or host arrays alike, and its recurrent state
-        ``{state stream: (L, *shape)}`` (whatever the lane's last request
-        left there is overwritten whole). ``ring_row (ring,)``: the lane's
-        row of ``ring_table``, where a layer has a window."""
-        out = dict(state)
-        for name, value in (
-            ("page_table", table_row), ("n_pages", n_pages),
-            ("tail_len", tail_len), ("tokens", token), ("pos", pos),
-            ("active", True),
-        ) + ((("ring_table", ring_row),) if ring else ()):
-            out[name] = state[name].at[lane].set(value)
-        for prefix, which, written in (("tail", names, tails),
-                                       ("state", state_names, states)):
-            for name in which:
-                out[f"{prefix}_{name}"] = tuple(
-                    None if t is None
-                    else t.at[lane].set(written[name][holders[name][layer]])
-                    for layer, t in enumerate(state[f"{prefix}_{name}"])
-                )
-        return out
-
-    def release_lanes(lanes, mask):
-        """Reset the lane bookkeeping (``_LANE_RESET``) of the lanes in
-        ``mask (B,) bool``: finished or evicted, once a tick."""
-        reset = {**_LANE_RESET, **_RING_RESET}
-        return {
-            name: jnp.where(
-                mask.reshape((-1,) + (1,) * (value.ndim - 1)),
-                reset[name], value,
-            )
-            for name, value in lanes.items()
-        }
-
-    return SimpleNamespace(
-        streams=streams,
-        names=names,
-        windows=windows,
-        window=max(windows),
-        ring=ring,
-        # Cache streams over the layers of each class: (global, window).
-        class_streams=tuple(
-            sum(len(layer) for layer, w in zip(streams, windows)
-                if bool(w) == ringed)
-            for ringed in (False, True)
-        ),
-        state_streams=state_streams,
-        state_names=state_names,
-        specs=_leading_specs(streams),
-        decode_step=jax.jit(decode_step, donate_argnums=(1,)),
-        commit=jax.jit(commit, donate_argnums=(0,)),
-        ingest=jax.jit(ingest, donate_argnums=(0,)),
-        prefill=jax.jit(prefill),
-        prefill_pages=jax.jit(prefill_pages, donate_argnums=(1,)),
-        admit_lane=jax.jit(admit_lane, donate_argnums=(0,)),
-        release_lanes=jax.jit(release_lanes, donate_argnums=(0,)),
-    )
-
-
-def _ingest_pool(pool, ids, rows, spec: paged_kv.PageSpec):
-    """Scatter pre-encoded pool rows: quantized rows arrive as (words,
-    meta) pairs in pool-row form (the transport's wire bytes ARE the
-    pool's, ``paged_kv.pool_words``), raw rows as f32 payloads."""
-    if not spec.quantized:
-        pages = rows.reshape(
-            -1, spec.page_tokens, spec.n_head, spec.d_head
-        ).astype(jnp.float16)
-        return pool.at[ids].set(pages)
-    words, meta = pool
-    rows_words, rows_meta = rows
-    return (
-        words.at[ids].set(rows_words),
-        meta.at[ids].set(rows_meta),
-    )
+# What a cache miss calls, looked up through this module's global (a test
+# that alters a program patches this name).
+_build_programs = programs.build
 
 
 # ---------------------------------------------------------------------------
@@ -1014,7 +263,7 @@ class _Step:
 
 class ContinuousBatchScheduler:
     """Admit/evict-per-step decode over one model adapter
-    (:class:`GPT2Server`, ``latent.LatentMoEServer``).
+    (``adapter.Adapter``: ``gpt2.GPT2Server``, ``latent.LatentMoEServer``).
 
     ``receiver`` (optional :class:`~.transport.KvPageReceiver`) is the
     disaggregated mode: ``submit(req, remote=True)`` registers the
@@ -1094,51 +343,15 @@ class ContinuousBatchScheduler:
     # -- state plumbing ----------------------------------------------------
 
     def _fresh_state(self) -> Dict:
-        sv = self.server.serve
-        streams = self._prog.streams
-        b = sv.max_batch
-        ring = self._prog.ring
-        pools = tuple(
-            {
-                # +1 row: scratch, where a padded slot of commit() and a
-                # prefill's last page that is a tail write; never read. A
-                # window layer holds a ring a lane, whatever ``max_seq``.
-                name: paged_kv.empty_pool(
-                    (b * ring if window else sv.max_pages) + 1, spec)
-                for name, spec in layer
-            }
-            for layer, window in zip(streams, self._prog.windows)
-        )
-        if ring:
-            self._note_window_pools(pools)
-        # A layer without the stream holds None in the stream's tuple, so
-        # that every per-layer entry is found at its layer's index. A tail
-        # is kept as the rows the attention contracts and the commit
-        # quantizes, a position's heads side by side: no program relays it.
-        tails = {
-            f"tail_{name}": tuple(
-                None if spec is None else jnp.zeros(
-                    (b, spec.page_tokens, spec.n_head * spec.d_head),
-                    jnp.float32,
-                )
-                for spec in (dict(layer).get(name) for layer in streams)
-            )
-            for name in self._prog.names
-        }
-        # The recurrent state, one row a lane: zeros until an admission
-        # writes the lane (a free lane's rows go through every decode step
-        # like any other's and reach no other lane).
-        states = {
-            f"state_{name}": tuple(
-                None if row is None else jnp.zeros((b,) + row[0], row[1])
-                for row in (dict(layer).get(name)
-                            for layer in self._prog.state_streams)
-            )
-            for name in self._prog.state_names
-        }
+        """An empty state (``programs.fresh_state``), and the account of
+        the bytes it holds: the scheduler owns them."""
+        b = self.server.serve.max_batch
+        state = programs.fresh_state(self._prog, self.server.serve)
+        if self._prog.ring:
+            self._note_window_pools(state["pools"])
         state_bytes = sum(
-            t.nbytes for per_layer in states.values() for t in per_layer
-            if t is not None
+            t.nbytes for name in self._prog.state_names
+            for t in state[f"state_{name}"] if t is not None
         )
         metrics.set("cgx.serve.state.bytes", float(state_bytes))
         if state_bytes:
@@ -1148,21 +361,7 @@ class ContinuousBatchScheduler:
                 )
             memledger.note_alloc("serve.state", n=b, nbytes=state_bytes)
         self._state_bytes = state_bytes
-        return {
-            "pools": pools,
-            **tails,
-            **states,
-            "page_table": jnp.full(
-                (b, sv.pages_per_seq), -1, jnp.int32
-            ),
-            "n_pages": jnp.zeros((b,), jnp.int32),
-            "tail_len": jnp.zeros((b,), jnp.int32),
-            "tokens": jnp.zeros((b,), jnp.int32),
-            "pos": jnp.zeros((b,), jnp.int32),
-            "active": jnp.zeros((b,), bool),
-            **({"ring_table": jnp.full((b, ring), -1, jnp.int32)}
-               if ring else {}),
-        }
+        return state
 
     def _note_window_pools(self, pools) -> None:
         """The bytes the pools hold by page class, as gauges
@@ -1864,9 +1063,11 @@ class ContinuousBatchScheduler:
         mask = np.zeros((self.server.serve.max_batch,), bool)
         mask[self._released] = True
         self._released.clear()
-        self._tail_len[mask] = _LANE_RESET["tail_len"]
-        self._n_pages[mask] = _LANE_RESET["n_pages"]
-        names = (*_LANE_RESET, *(_RING_RESET if self._prog.ring else ()))
+        reset = programs._LANE_RESET
+        self._tail_len[mask] = reset["tail_len"]
+        self._n_pages[mask] = reset["n_pages"]
+        names = (*reset,
+                 *(programs._RING_RESET if self._prog.ring else ()))
         self._state.update(self._prog.release_lanes(
             {name: self._state[name] for name in names}, mask
         ))
@@ -1962,7 +1163,7 @@ class ContinuousBatchScheduler:
         the host's own counts: every committed page of a global layer; of a
         window layer those from the page that holds the oldest position the
         lane's token (at ``n_pages * page_tokens + tail_len``) can see. Those
-        are the slots the step's read leaves open (:func:`ring_live` on the
+        are the slots the step's read leaves open (``adapter.ring_live`` on the
         device), counted again as ``kv.decoded_pages.window``: what the
         window read decodes, which was every slot of every lane's ring
         before the read had a guard."""

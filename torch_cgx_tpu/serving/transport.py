@@ -72,14 +72,14 @@ def require_kv_streams(server) -> None:
         "the disaggregated prefill path ships K and V page frames "
         f"(serving/transport.py kinds); adapter {server.kind!r} "
     )
-    page_window = getattr(server, "page_window", lambda layer: 0)
     for layer in range(server.n_layer):
         names = [name for name, _ in server.cache_streams(layer)]
         state = [name for name, _, _ in server.state_streams(layer)]
-        if page_window(layer):
+        window = server.page_window(layer)
+        if window:
             raise ValueError(
                 f"{refusal}keeps layer {layer}'s pages as a ring of its "
-                f"window ({page_window(layer)} tokens), which a page stream "
+                f"window ({window} tokens), which a page stream "
                 "cannot address, and is served with local prefill only"
             )
         if state:

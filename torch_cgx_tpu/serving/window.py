@@ -10,7 +10,7 @@ layer's are the lane's page table, as long as the sequence; a window
 layer's are the lane's ring, ``W / page_tokens + 1`` pool rows whatever the
 sequence's length, read under a kernel name of its own
 (``cgx_dequantize_window``) and masked by position
-(``scheduler.ring_masks``). Prefill attends in query blocks, a window
+(``adapter.ring_masks``). Prefill attends in query blocks, a window
 layer's block over the band of keys it can see
 (``window_moe.attend_blocks``).
 
@@ -26,19 +26,15 @@ The disaggregated path cannot address a ring and refuses this adapter
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Optional
-
 import jax
 import numpy as np
 
 from ..models import window_moe as wm
 from ..models.mla_moe import rms_norm
-from ..models.window_moe import WindowMoeConfig
 from ..parallel import moe
-from .hybrid import attend_paged
-from .scheduler import (
-    ServeConfig,
+from .adapter import (
+    Adapter,
+    attend_paged,
     lane_masks,
     page_specs,
     ring_live,
@@ -46,49 +42,22 @@ from .scheduler import (
 )
 
 
-class WindowMoEServer:
-    """Model adapter (the protocol is in ``scheduler.py``) for one
-    ``(WindowMoeConfig, params)`` pair; cache streams ``k`` and ``v`` on
-    every layer, the window layers' as rings."""
+class WindowMoEServer(Adapter):
+    """Model adapter for one ``(WindowMoeConfig, params)`` pair; cache
+    streams ``k`` and ``v`` on every layer, the window layers' as rings."""
 
     kind = "window_moe"
     # What a decode step counts over its expert layers, as
     # ``cgx.serve.<name>``: ``moe.STATS`` in order.
     step_counters = tuple(f"moe.{name}" for name in moe.STATS)
 
-    def __init__(self, model_cfg: WindowMoeConfig, params,
-                 serve: Optional[ServeConfig] = None):
-        self.cfg = model_cfg
-        self.p = params
-        self.serve = serve or ServeConfig.from_env(model_cfg)
-        self.n_layer = model_cfg.n_layer
-        self.geometry = tuple(
-            (f.name, str(getattr(model_cfg, f.name)))
-            for f in dataclasses.fields(model_cfg)
-        )
-
-    def layer_name(self, layer: int) -> str:
-        return f"layer_{layer}"
-
     def cache_streams(self, layer: int):
         (spec,) = page_specs(self.layer_name(layer), self.serve.page_tokens,
                              [(self.cfg.n_kv_head, self.cfg.d_head)])
         return (("k", spec), ("v", spec))
 
-    def state_streams(self, layer: int):
-        return ()
-
     def page_window(self, layer: int) -> int:
         return self.cfg.windows[layer]
-
-    def with_params(self, params) -> "WindowMoEServer":
-        return WindowMoEServer(self.cfg, params, self.serve)
-
-    def kv_bytes_per_token(self) -> int:
-        return self.cfg.kv_bytes_per_token()
-
-    def state_bytes_per_lane(self) -> int:
-        return 0
 
     # -- forwards ----------------------------------------------------------
 
@@ -117,7 +86,7 @@ class WindowMoEServer:
         tails, a global layer's committed pages read through the page table
         and a window layer's through the ring, one ``decode_attention`` over
         pages and tail under the class's mask; of a ring the read takes the
-        slots that hold a visible key (``scheduler.ring_live``, one mask a
+        slots that hold a visible key (``adapter.ring_live``, one mask a
         step for every window layer's two streams). Returns ``(logits (B,
         V), the new tails by stream, moe.STATS summed over the layers
         (``load_max`` their largest) counted over the active lanes)``."""
