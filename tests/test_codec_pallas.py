@@ -1255,6 +1255,28 @@ def _cell_cases():
         yield f"smallthinker-kv-commit-{rows}", "quantize", dict(
             bits=8, rows=rows, numel=_JOYAI_C,
         ), {"quantize": flat}, {"_pipe_tc": 16}
+    # ISSUE 45: trinity-serve-agent64. ``k`` and ``v`` of every layer, a
+    # page of 256 tokens x 8 K/V heads x 128: 262,144 values, 512 buckets,
+    # sixteen whole chunks, two buckets a token, rows of 1,024. The global
+    # layer reads a (64, 19) page table over a pool of 1,217 rows, a window
+    # layer its (64, 17) ring over a pool of 1,089: both paged, a page a grid
+    # step.
+    trinity = dict(bits=8, out_dtype=jnp.bfloat16, page=(256, 8, 128),
+                   lanes=64)
+    yield "trinity-decode-pages-global", "dequantize_pages", dict(
+        trinity, rows=64 * 19, pool=1217,
+    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    yield "trinity-decode-pages-window", "dequantize_pages", dict(
+        trinity, rows=64 * 17, pool=1089, window=True,
+    ), {"dequantize_pages.window": "pallas_paged", "dequantize_rows": flat,
+        "dequantize": "pallas_flat.bfloat16"}, {"_pages_tc": 16}
+    # Its commits: the tails that filled in the decode loop (4 of the 64
+    # lanes a call); a padded prompt's 4 or 16 pages in prefill_pages (1,024
+    # and 4,096 tokens), all of them within a ring.
+    for rows in (_commit_lanes(64, 256), 16):
+        yield f"trinity-kv-commit-{rows}", "quantize", dict(
+            bits=8, rows=rows, numel=256 * 8 * 128,
+        ), {"quantize": flat}, {"_pipe_tc": 16}
     # Page commits: the tails that filled in the decode loop (ISSUE 34: 4 of
     # the 32 lanes a call, where every lane's 32 rows were quantized), a
     # padded prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and

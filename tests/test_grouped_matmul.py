@@ -83,6 +83,43 @@ def test_kernel_in_bfloat16_rounds_once_as_ragged_dot_does(case):
     assert float(gap.max()) <= 2.0 ** -7 * float(jnp.abs(want).max())
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", list(CASES))
+def test_column_blocks_equal_ragged_dot(case, dtype, monkeypatch):
+    """A matrix over ``MAX_BLOCK_BYTES`` (here 64 x 384 against a limit of
+    one 64 x 128 float32 block: three column blocks of 128, the work list walked
+    once a block) through every case of groups: empty ones, groups that share
+    a tile, that end mid-tile, rows past the end. ``K`` is whole in every
+    block, so there is one sum an element: float32 within 1e-5 of the largest
+    value of ``ragged_dot``'s and the loop's (the order of a sum is the
+    backend's), bfloat16 the same bits as ``ragged_dot`` rounds to."""
+    monkeypatch.setattr(gm, "MAX_BLOCK_BYTES", 64 * 128 * 4)
+    m, sizes, tm = CASES[case]
+    k, n = 64, 384
+    assert gm.column_block(k, n, jnp.dtype(dtype).itemsize) == 128
+    lhs, rhs, sizes = _operands(len(case), m, sizes, k, n, dtype)
+    end = int(sizes.sum())
+    lhs = lhs.at[end:].set(jnp.nan)
+    # The function under the jit: a trace cached at another block limit is
+    # not this one.
+    got = np.asarray(gm.grouped_matmul_pallas.__wrapped__(
+        lhs, rhs, sizes, tm=tm, interpret=True), np.float32)
+    xla = np.asarray(gm.grouped_matmul_xla(lhs.at[end:].set(0.0), rhs, sizes),
+                     np.float32)
+    assert np.isfinite(got).all() and (got[end:] == 0.0).all()
+    if dtype == jnp.bfloat16:
+        one_block = np.asarray(gm.grouped_matmul_pallas.__wrapped__(
+            lhs, rhs[:, :, :128], sizes, tm=tm, interpret=True), np.float32)
+        np.testing.assert_array_equal(got[:, :128], one_block)
+        step = 2.0 ** -7 * max(1.0, np.abs(xla).max())
+        assert np.abs(got[:end] - xla[:end]).max(initial=0.0) <= step
+    else:
+        want = _loop(lhs, rhs, sizes)
+        limit = 1e-5 * max(1.0, np.abs(want).max())
+        assert np.abs(got - want).max() < limit
+        assert np.abs(got[:end] - xla[:end]).max(initial=0.0) < limit
+
+
 def test_work_list_names_every_touched_tile_once_and_no_other():
     sizes = jnp.asarray([3, 0, 0, 20, 1, 0, 40, 6], jnp.int32)
     group, tile, bounds, items = gm.work_list(sizes, 70, 16)
@@ -106,9 +143,18 @@ def test_the_rule_is_the_block_and_the_mean_group():
         assert gm.takes_kernel(m, e, k, n)
     assert gm.takes_kernel(512 * 256, 256, 2048, 768)
     assert not gm.takes_kernel(512 * 256 + 1, 256, 2048, 768)  # not timed
-    assert not gm.takes_kernel(1024, 128, 4096, 2048)  # a 16 MiB matrix
+    # A 16 MiB matrix goes in column blocks of 8 MiB, Trinity's 18 MiB one
+    # in three of 6 MiB; a matrix of which 128 columns are no block is refused.
+    assert gm.takes_kernel(1024, 128, 4096, 2048)
+    assert gm.column_block(4096, 2048) == 1024
+    assert gm.takes_kernel(256, 32, 3072, 3072)
+    assert gm.column_block(3072, 3072) == 1024
+    assert not gm.takes_kernel(512 * 32 + 1, 32, 3072, 3072)
+    assert gm.column_block(2560, 768) == 768  # one block, as before
+    assert gm.column_block(1 << 16, 256) is None
+    assert not gm.takes_kernel(1024, 128, 1 << 16, 256)
     assert gm.takes_kernel(1024, 128, 2048, 1024, itemsize=4)
-    assert not gm.takes_kernel(1024, 128, 2560, 1024, itemsize=4)
+    assert gm.column_block(2560, 1024, itemsize=4) == 512  # 10 MiB: two
 
 
 @pytest.mark.parametrize("impl,on_tpu,lowering", [
@@ -202,10 +248,13 @@ def test_held_rows_read_zero_through_the_kernel(monkeypatch):
 @pytest.mark.parametrize("m,e,k,n,routed", [
     (1024, 128, 2560, 768, 512), (1024, 128, 768, 2560, 512),
     (256, 256, 2048, 768, 256),
+    # Trinity's 18 MiB matrix in three column blocks: a decode step's one
+    # row a held group, a 4k prompt's 64.
+    (256, 32, 3072, 3072, 256), (16384, 32, 3072, 3072, 256),
 ])
 def test_grouped_matmul_tpu(m, e, k, n, routed):
-    """A decode step's product of either expert cell, bit for bit
-    ``ragged_dot``'s: the whole of ``K`` is one block, so both take one
+    """A decode step's product of an expert cell, bit for bit
+    ``ragged_dot``'s: the whole of ``K`` is in every block, so both take one
     float32 sum and round it once."""
     rng = np.random.default_rng(40)
     flat = rng.integers(0, routed, size=m)

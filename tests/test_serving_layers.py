@@ -43,9 +43,13 @@ from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
     Request,
 )
-from torch_cgx_tpu.serving.window import WindowMoEServer  # noqa: E402
+from torch_cgx_tpu.serving.window import (  # noqa: E402
+    AfmoeServer,
+    WindowMoEServer,
+)
 from torch_cgx_tpu.wire import edges  # noqa: E402
 
+import test_afmoe_serving as afmoe  # noqa: E402
 import test_hybrid_serving as granite  # noqa: E402
 import test_latent_serving as latent  # noqa: E402
 import test_ling_hybrid_serving as ling  # noqa: E402
@@ -67,6 +71,7 @@ SERVERS = {
     "hybrid_gdn": (HybridGDNServer, olmo._cfg, olmo._serve),
     "hybrid_kda_mla": (HybridLatentMoEServer, ling._cfg, ling._serve),
     "window_moe": (WindowMoEServer, window._cfg, window._serve),
+    "afmoe": (AfmoeServer, afmoe._cfg, afmoe._serve),
 }
 HYBRIDS = ("hybrid_ssm", "hybrid_gdn", "hybrid_kda_mla")
 
@@ -136,7 +141,8 @@ def test_adapter_defaults(kind):
     hash(server.geometry)
     assert server.kv_bytes_per_token() == cfg.kv_bytes_per_token()
     assert [server.page_window(l) for l in layers] == (
-        list(cfg.windows) if kind == "window_moe" else [0] * cfg.n_layer)
+        list(cfg.windows) if kind in ("window_moe", "afmoe")
+        else [0] * cfg.n_layer)
     if kind in HYBRIDS:
         recurrent = [l for l in layers if l not in cfg.attention_layers]
         assert recurrent and all(server.state_streams(l) for l in recurrent)
@@ -213,6 +219,9 @@ PARENT_STATE = {
                        "0": ("123b5cc3f6ed322f", "b54095ffdec82137", 22)},
     "window_moe": {"8": ("9463bd2b906d9e9b", "b9a190bae6634be3", 55),
                    "0": ("2f58a4980b47dff1", "19b0adb4db25b31e", 39)},
+    # No parent: as PR 45 first built it (five layers, four of them rings).
+    "afmoe": {"8": ("8b7c0e9004b7cc2e", "0bd7c88018008783", 37),
+              "0": ("ac233ee66a5ea676", "11b35486f9f7b485", 27)},
 }
 
 

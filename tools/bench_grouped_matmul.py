@@ -1,7 +1,7 @@
 """Time the forms of the experts' grouped product on the chip, A B A B.
 
     chiprun -- python tools/bench_grouped_matmul.py [--shapes a,b]
-        [--tiles 16,32,128] [--own-only] [--calls N]
+        [--tiles 16,32,128] [--own-only] [--calls N] [--block-mib 8,4]
 
 At the shapes the two expert cells hand ``parallel.moe.dropless_moe`` (their
 decode steps and prefills, gate/up and down), times ``jax.lax.ragged_dot``,
@@ -12,7 +12,11 @@ us a call (median over the rounds), us a touched expert's matrix, and the
 rate at which the touched matrices were read; then the largest difference
 from ``ragged_dot``'s result. The table of PERF.md section 6 (PR 40) is
 this script's output (the variant with the weight block cut in two along N,
-which lost by 1-6 %, went with the choice). Fails off the TPU.
+which lost by 1-6 %, went with the choice). The ``trinity_rows*`` shapes
+are a matrix of 18 MiB, which the kernel takes in column blocks
+(``grouped_matmul.column_block``; ``--block-mib`` times it under other block
+limits than ``MAX_BLOCK_BYTES``): PERF.md section 6, PR 45. Fails off the
+TPU.
 """
 
 from __future__ import annotations
@@ -58,14 +62,37 @@ SHAPES = {
     "smallthinker_decode_down": (288, 64, 768, 2560, 64),
     "smallthinker_prefill8k_up": (49152, 64, 2560, 768, 64),
     "smallthinker_prefill8k_down": (49152, 64, 768, 2560, 64),
+    # Trinity's held share (PR 45): 32 of 256 experts, gate/up and down
+    # alike 3072 x 3072, 18 MiB: 1, 2 and 4 rows a held group (64, 128 and
+    # 256 lanes' decode step), 16 and 64 (a 1k and a 4k prompt), 128.
+    **{f"trinity_rows{r}": (256 * r, 32, 3072, 3072, 256)
+       for r in (1, 2, 4, 16, 64, 128)},
 }
 
 
-def forms(m, k, n, tiles, own_only):
+def own(tm, block_mib=None):
+    """The own kernel at row tile ``tm``; ``block_mib`` sets the block limit
+    it is traced under (its column blocks follow)."""
+    if block_mib is None:
+        return functools.partial(gm.grouped_matmul_pallas, tm=tm)
+
+    def traced(lhs, rhs, sizes):
+        was, gm.MAX_BLOCK_BYTES = gm.MAX_BLOCK_BYTES, int(block_mib * 2**20)
+        try:
+            return gm.grouped_matmul_pallas.__wrapped__(lhs, rhs, sizes,
+                                                        tm=tm)
+        finally:
+            gm.MAX_BLOCK_BYTES = was
+
+    return traced
+
+
+def forms(m, k, n, tiles, own_only, block_mibs=()):
     """name -> function of ``(lhs, rhs, sizes)``: ``ragged_dot``, the own
-    kernel at each of ``tiles`` and, unless ``own_only``, megablox at the
-    same row tiles with whole-``K`` whole-``N`` blocks, at 128 x 512 x N and
-    at its default tiling."""
+    kernel at each of ``tiles`` (and under each block limit of
+    ``block_mibs``) and, unless ``own_only``, megablox at the same row tiles
+    with whole-``K`` whole-``N`` blocks, at 128 x 512 x N and at its default
+    tiling."""
     # The package's ``gmm`` is the differentiable wrapper, which hides the
     # module of the same name: the kernel's own function takes a dtype.
     megablox = importlib.import_module(
@@ -77,7 +104,9 @@ def forms(m, k, n, tiles, own_only):
 
     out = {"ragged_dot": gm.grouped_matmul_xla}
     for tm in tiles:
-        out[f"own_tm{tm}"] = functools.partial(gm.grouped_matmul_pallas, tm=tm)
+        out[f"own_tm{tm}"] = own(tm)
+        for mib in block_mibs:
+            out[f"own_tm{tm}_{mib:g}MiB"] = own(tm, mib)
         if not own_only and m % tm == 0:
             out[f"gmm_{tm}xKxN"] = gmm((tm, k, n))
     if not own_only and m % 128 == 0:
@@ -95,6 +124,8 @@ def main():
                     help="row tiles of the own kernel and of megablox")
     ap.add_argument("--own-only", action="store_true",
                     help="ragged_dot and the own kernel, nothing of megablox")
+    ap.add_argument("--block-mib", default="",
+                    help="other block limits to time the own kernel under")
     ap.add_argument("--out", default="chiprun_out/bench_grouped_matmul.jsonl")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
@@ -115,7 +146,8 @@ def main():
         want = np.asarray(gm.grouped_matmul_xla(lhs, rhs, sizes), np.float32)
         fns, times, gaps = {}, {}, {}
         tiles = [int(t) for t in args.tiles.split(",")]
-        for form, fn in forms(m, k, n, tiles, args.own_only).items():
+        mibs = [float(b) for b in args.block_mib.split(",") if b]
+        for form, fn in forms(m, k, n, tiles, args.own_only, mibs).items():
             try:
                 f = jax.jit(fn)
                 got = np.asarray(f(lhs, rhs, sizes), np.float32)

@@ -30,7 +30,9 @@ inference:
   layers).
 * :mod:`.window` — the window/global adapter: ``k``, ``v`` pages on every
   layer, a sliding-window layer's kept as a ring a lane beside the global
-  page table, dropless experts routed from the block's input.
+  page table: SmallThinker's block (dropless experts routed from the
+  block's input) and Trinity's (``afmoe``: sandwich norms, gated QK-normed
+  attention, a held share of experts beside a shared one).
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -50,6 +52,6 @@ from .hybrid import (  # noqa: F401
     HybridLatentMoEServer,
     HybridSSMServer,
 )
-from .window import WindowMoEServer  # noqa: F401
+from .window import AfmoeServer, WindowMoEServer  # noqa: F401
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401
