@@ -921,12 +921,13 @@ def test_flat_kernel_stores_the_consumers_type_and_rows(bits, bucket):
 
 def _paged_pool(rng, n_pool, numel, bits, bucket, interpret=True):
     """A quantized page pool in the flat kernel's operand layout
-    (``ops/paged_kv.py``) and the QTensor it was made from."""
+    (``ops/paged_kv.py``: words as rows of 128, a page's meta as two
+    lane-dense planes) and the wire QTensor it was made from."""
     xs = jnp.asarray(rng.standard_normal((n_pool, numel)) * 3.0, jnp.float32)
     q = codec_pallas.quantize_batch(xs, bits, bucket, interpret=interpret)
     words = jax.lax.bitcast_convert_type(q.packed, jnp.int32).reshape(
         n_pool, -1, 128)
-    return q, words, q.meta
+    return q, words, jnp.swapaxes(q.meta, 1, 2)
 
 
 def _gathered(q, ids):
@@ -985,6 +986,113 @@ def test_paged_decode_is_the_gathered_decode_bit_for_bit(geo):
             row_width=width)))
 
 
+# A K/V or latent page of each of the seven serving adapters (ISSUE 46), at
+# its cell's size: (page tokens, row width); 8 bits, buckets of 512.
+_ADAPTER_PAGES = {
+    "gpt2": (64, 1280),  # 160 buckets: not whole lanes of 128
+    "mla_moe": (256, 512),  # the latent ``c``: 256 buckets
+    "hybrid_ssm": (256, 512),  # granite: 8 K/V heads x 64
+    "hybrid_gdn": (64, 3840),  # Olmo: 480 buckets, fifteen chunks
+    "hybrid_kda_mla": (256, 512),  # Ling's one latent layer
+    "window_moe": (256, 512),  # SmallThinker: 4 K/V heads x 128, the ring's
+    "afmoe": (256, 1024),  # Trinity: 512 buckets, two a token
+}
+
+
+@pytest.mark.parametrize("guarded", [False, True], ids=["bare", "live"])
+@pytest.mark.parametrize("adapter", sorted(_ADAPTER_PAGES))
+def test_paged_read_of_the_planes_is_the_wire_pages_decode(adapter, guarded):
+    """ISSUE 46: the paged read over a pool whose meta lies as planes
+    ``(pool rows, 2, buckets)`` writes, bit for bit, what the UNPAGED kernel
+    (the parent's program: ``test_unguarded_paged_decode_is_the_parents_
+    jaxpr``) decodes from the same pages' wire ``QTensor``, the ``(buckets,
+    2)`` pairs: as rows of the adapter's width, in float32 and bfloat16,
+    under both kernel names, with and without ``live`` (a dead entry's rows
+    are zeros), for a table that repeats a row. ``codec.dequantize`` of
+    those pages, the XLA codec, is the same up to its fused multiply-add, as
+    it is of the unpaged kernel (``test_pallas_wire_matches_xla``)."""
+    pt, width = _ADAPTER_PAGES[adapter]
+    rng = np.random.default_rng(46 + pt + width + guarded)
+    n = 4
+    q, words, meta = _paged_pool(rng, n + 2, pt * width, 8, 512)
+    assert meta.shape == (n + 2, 2, pt * width // 512)
+    ids = jnp.asarray([5, 0, 5, 2], jnp.int32)
+    live = jnp.asarray([1, 0, 1, 1], jnp.int32) if guarded else None
+    wire = _gathered(q, ids)
+    assert wire.meta.shape == (n, pt * width // 512, 2)
+    want = codec_pallas.dequantize_batch(wire, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(jax.vmap(codec.dequantize)(wire)), np.asarray(want),
+        rtol=2e-6, atol=5e-7)
+    want = want.reshape(n, pt, width)
+    if guarded:
+        want = want.at[1].set(0)
+    tile = codec_pallas.pages_tile(n, pt * width, 512, width, jnp.bfloat16)
+    assert tile
+    for name, store in (("cgx_dequantize_flat", jnp.float32),
+                        ("cgx_dequantize_flat", jnp.bfloat16),
+                        ("cgx_dequantize_window", jnp.bfloat16)):
+        got = codec_pallas.dequantize_pages(
+            words, meta, ids, bits=8, bucket_size=512, tc=tile,
+            out_dtype=store, row_width=width, interpret=True, name=name,
+            live=live)
+        assert got.shape == (n, pt, width) and got.dtype == store
+        np.testing.assert_array_equal(
+            _bits_of(got), _bits_of(want.astype(store)))
+
+
+@pytest.mark.parametrize("adapter", sorted(_ADAPTER_PAGES))
+def test_the_read_and_the_commit_take_the_pools_meta_as_it_lies(
+        adapter, monkeypatch):
+    """The gain of ISSUE 46 without a chip: at the cells' page shapes, on
+    TPU dispatch, ``gather_dequant_pages`` hands the pool's meta to the
+    ``pallas_call`` as the very array the pool holds, and ``commit_page_rows``
+    hands it to the ``scatter``: no other equation (a ``transpose``, a
+    ``reshape``, a ``copy``: what XLA answered with a relay of the whole
+    pool) has an operand of the pool's meta's shape. The rows a commit turns
+    are the ``K`` it wrote."""
+    from torch_cgx_tpu.ops import paged_kv
+
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    pt, width = _ADAPTER_PAGES[adapter]
+    spec = paged_kv.PageSpec(pt, width // 128, 128, 8, 512)
+    pool_rows, lanes, pages, k = 65, 8, 4, 4
+    pool = jax.eval_shape(lambda: paged_kv.empty_pool(pool_rows, spec))
+    meta_shape = (pool_rows, 2, spec.num_buckets)
+    assert pool[1].shape == meta_shape
+
+    def takers(jaxpr, shape=meta_shape):
+        found = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if any(tuple(getattr(v.aval, "shape", ())) == shape
+                       for v in eqn.invars):
+                    found.append(eqn.primitive.name)
+                if eqn.primitive.name != "pallas_call":
+                    for sub in jax.core.jaxprs_in_params(eqn.params):
+                        walk(sub)
+
+        walk(jaxpr.jaxpr)
+        return found
+
+    for window in (False, True):
+        read = jax.make_jaxpr(
+            lambda pool, table, live: paged_kv.gather_dequant_pages(
+                pool, table, spec, jnp.bfloat16, window=window,
+                live=live if window else None))(
+            pool, jax.ShapeDtypeStruct((lanes, pages), jnp.int32),
+            jax.ShapeDtypeStruct((lanes, pages), bool))
+        assert sorted(takers(read)) == ["jit", "pallas_call"]
+    commit = jax.make_jaxpr(
+        lambda pool, ids, rows: paged_kv.commit_page_rows(
+            pool, ids, rows, spec))(
+        pool, jax.ShapeDtypeStruct((k,), jnp.int32),
+        jax.ShapeDtypeStruct((k, spec.flat), jnp.float32))
+    assert takers(commit) == ["scatter"]
+    assert "transpose" in takers(commit, (k, spec.num_buckets, 2))
+
+
 def test_pages_tc_is_the_gathered_reads_tile_in_whole_pages():
     """``_pages_tc`` keeps the tile the gathered read of the same table
     takes (``_rows_tc``) where that is whole pages, and refuses (the read
@@ -1027,14 +1135,19 @@ _GUARD_CASES = [
                  id=f"{bits}bit-ppb{ppb}-{'rows' if rows else 'flat'}")
     for bits in (4, 8) for ppb in (1, 2) for rows in (False, True)
 ]
-# The first 16 hex digits of the SHA-256 of the unguarded paged call's jaxpr
-# as text, computed on the parent of the PR that brought the guard (PR 42's
-# parent, its ``git archive``), by (bits, pages a grid step, rows stored).
-_PARENT_PAGED = {
-    (4, 1, False): "8dd92a4b6164f0d0", (4, 1, True): "1f7b4730e73ea8e1",
-    (4, 2, False): "30c729e7f46def80", (4, 2, True): "349ef386d23aaca9",
-    (8, 1, False): "5d94264e144ff1e1", (8, 1, True): "a518a52e05c06633",
-    (8, 2, False): "f35d881d47f9506e", (8, 2, True): "afd3e8468a7a3eb4",
+# The first 16 hex digits of the SHA-256 of the UNPAGED call's jaxpr as text
+# (``page_ids`` None: the training fabric's decode of the wire's ``(rows,
+# buckets, 2)`` pairs), computed on the parent of the PR that turned the
+# pools' meta into planes (PR 46's parent, its ``git archive``), by (bits,
+# chunks a grid step, rows stored), and of the fused add at 4 bits, two
+# chunks a step. (Until PR 46 this table held the unguarded paged call's,
+# from PR 42's parent; that call takes the pool's planes now.)
+_PARENT_UNPAGED = {
+    (4, 1, False): "48a4f28459d4763b", (4, 1, True): "75c0307abbfa682e",
+    (4, 2, False): "74411fbf498a5aa7", (4, 2, True): "dd8d9d3978ba4c09",
+    (8, 1, False): "722f2dd32f922013", (8, 1, True): "41d8069ff36c8ebd",
+    (8, 2, False): "798284d8114e81ba", (8, 2, True): "4292b98f301c83e2",
+    "add": "0196c00d2751e41d",
 }
 
 
@@ -1072,22 +1185,51 @@ def test_guarded_paged_decode_zeroes_dead_pages_and_keeps_live_ones(
         want)
 
 
+def _sha(jaxpr):
+    return hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
+
+
 @pytest.mark.parametrize("bits,ppb,rows", _GUARD_CASES)
 def test_unguarded_paged_decode_is_the_parents_jaxpr(bits, ppb, rows):
-    """``live=None`` traces the program the parent traced: every cell but the
-    ring's (and the training codec, which has no ``page_ids``) is untouched."""
+    """ISSUE 46 changed the PAGED call's meta operand and nothing else: the
+    unpaged call (no ``page_ids``: the training codec, the wire's pairs)
+    traces the program the parent traced; the paged call takes the pool's
+    ``(pool rows, 2, buckets)`` planes a ``(2, buckets)`` block a page, and
+    ``live=None`` is the call without a branch (every cell but the ring's)."""
     n = 8
-    words = jax.ShapeDtypeStruct((n + 1, bits * 4, 128), jnp.int32)
-    meta = jax.ShapeDtypeStruct((n + 1, 32, 2), jnp.float32)
-    ids = jax.ShapeDtypeStruct((n,), jnp.int32)
     call = _guard_call(bits, ppb, rows)
-    jaxpr = jax.make_jaxpr(lambda w, m, i: call(w, m, None, i))(
-        words, meta, ids)
-    sha = hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16]
-    assert sha == _PARENT_PAGED[bits, ppb, rows]
+    pairs = jax.ShapeDtypeStruct((n, 32, 2), jnp.float32)
+    unpaged = jax.make_jaxpr(lambda w, m: call(w, m))(
+        jax.ShapeDtypeStruct((n, bits * 512), jnp.int32), pairs)
+    assert _sha(unpaged) == _PARENT_UNPAGED[bits, ppb, rows]
+    words = jax.ShapeDtypeStruct((n + 1, bits * 4, 128), jnp.int32)
+    meta = jax.ShapeDtypeStruct((n + 1, 2, 32), jnp.float32)
+    ids = jax.ShapeDtypeStruct((n,), jnp.int32)
+    bare = str(jax.make_jaxpr(lambda w, m, i: call(w, m, None, i))(
+        words, meta, ids))
+    assert "cond[" not in bare
+    # The kernel's meta blocks are pages of the pool as it lies ...
+    assert bare.count("f32[2,32]") >= ppb and "f32[32,2]" not in bare
+    # ... and the wire's pairs in place of the planes are refused, not relaid.
+    with pytest.raises(ValueError, match="as planes"):
+        jax.make_jaxpr(lambda w, m, i: call(w, m, None, i))(
+            words, jax.ShapeDtypeStruct((n + 1, 32, 2), jnp.float32), ids)
     guarded = jax.make_jaxpr(lambda w, m, i, l: call(w, m, None, i, l))(
         words, meta, ids, ids)
     assert str(guarded).count("cond[") >= 2 * ppb  # a branch pair a page
+
+
+def test_fused_add_decode_is_the_parents_jaxpr():
+    """The training fabric's decompress-accumulate (``with_add``) traces the
+    program the parent traced, as the plain unpaged call does."""
+    n = 8
+    added = jax.make_jaxpr(functools.partial(
+        codec_pallas._dequantize_flat_impl, bits=4, bucket_size=512,
+        interpret=True, tc=2, with_add=True))(
+        jax.ShapeDtypeStruct((n, 4 * 512), jnp.int32),
+        jax.ShapeDtypeStruct((n, 32, 2), jnp.float32),
+        jax.ShapeDtypeStruct((n, 32 * 512), jnp.float32))
+    assert _sha(added) == _PARENT_UNPAGED["add"]
 
 
 @pytest.mark.tpu  # compiled Mosaic lowering of the guard and the held block
@@ -1163,7 +1305,7 @@ def _cell_cases():
     # 16) page table. Where the kernel stores the rows itself it walks the
     # page table in the gathered read's tile (two pages a grid step); the
     # 64-wide ``kr`` keeps the gather and XLA's reshape.
-    paged = {"dequantize_pages": "pallas_paged", "dequantize_rows": flat}
+    paged = {"dequantize_pages": "pallas_paged.meta_planes", "dequantize_rows": flat}
     yield "gpt2l-decode-pages", "dequantize_pages", dict(
         read, page=(64, 20, 64),
     ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 10}
@@ -1246,7 +1388,7 @@ def _cell_cases():
     ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     yield "smallthinker-decode-pages-window", "dequantize_pages", dict(
         small, rows=48 * 17, pool=817, window=True,
-    ), {"dequantize_pages.window": "pallas_paged", "dequantize_rows": flat,
+    ), {"dequantize_pages.window": "pallas_paged.meta_planes", "dequantize_rows": flat,
         "dequantize": "pallas_flat.bfloat16"}, {"_pages_tc": 16}
     # Its commits: the tails that filled in the decode loop (4 of the 48
     # lanes a call); a padded prompt's 2 or 32 pages in prefill_pages (512
@@ -1268,7 +1410,7 @@ def _cell_cases():
     ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     yield "trinity-decode-pages-window", "dequantize_pages", dict(
         trinity, rows=64 * 17, pool=1089, window=True,
-    ), {"dequantize_pages.window": "pallas_paged", "dequantize_rows": flat,
+    ), {"dequantize_pages.window": "pallas_paged.meta_planes", "dequantize_rows": flat,
         "dequantize": "pallas_flat.bfloat16"}, {"_pages_tc": 16}
     # Its commits: the tails that filled in the decode loop (4 of the 64
     # lanes a call); a padded prompt's 4 or 16 pages in prefill_pages (1,024

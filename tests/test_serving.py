@@ -687,10 +687,14 @@ def test_slo_scoped_controller_leaves_training_edges_alone(monkeypatch):
 
 def _pool_row_wire_bytes(pool, i):
     """Pool row ``i`` of a quantized pool as its frame payload: the
-    host codec's ``meta | words`` (``HostQTensor.to_bytes``)."""
+    host codec's ``meta | words`` (``HostQTensor.to_bytes``), the row's two
+    meta planes back as the wire's (unit, minimum) pairs."""
+    from torch_cgx_tpu.ops import paged_kv
+
     words, meta = (np.asarray(a) for a in pool)
+    pairs = np.ascontiguousarray(paged_kv.wire_meta(meta[i]))
     return np.concatenate([
-        meta[i].reshape(-1).view(np.uint8), words[i].reshape(-1).view(np.uint8)
+        pairs.reshape(-1).view(np.uint8), words[i].reshape(-1).view(np.uint8)
     ])
 
 
@@ -699,7 +703,8 @@ def test_host_wire_bytes_drop_into_pool_rows():
     jit commit produce IDENTICAL pool rows — the zero-re-encoding
     contract the receiver relies on. A pool row holds the wire words as
     rows of 128 int32 (``PageSpec.word_shape``, the flat kernels' operand
-    layout): the frame's words by a reshape, byte for byte."""
+    layout): the frame's words by a reshape, byte for byte; and the frame's
+    (unit, minimum) pairs as two planes, ``(2, num_buckets)``."""
     from torch_cgx_tpu.ops import codec_host, paged_kv
 
     spec = paged_kv.PageSpec(
@@ -722,8 +727,12 @@ def test_host_wire_bytes_drop_into_pool_rows():
     np.testing.assert_array_equal(
         np.asarray(paged_kv.wire_words(words_j, spec)[0]), rehydrated.packed
     )
+    assert meta_j.shape == (1, 2, spec.num_buckets)
     np.testing.assert_array_equal(
-        np.asarray(meta_j[0]), rehydrated.meta
+        np.asarray(meta_j[0]), paged_kv.pool_meta(rehydrated.meta)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(paged_kv.wire_meta(meta_j)[0]), rehydrated.meta
     )
     assert buf.nbytes == spec.wire_bytes()
     np.testing.assert_array_equal(
@@ -741,9 +750,10 @@ def test_a_written_page_has_the_frames_wire_bytes(model_setup, monkeypatch,
     ingest of a received frame, the local prefill — pool row ``id`` is the
     frame the prefill worker would ship for that payload
     (``prefill._encode_page``: the host codec's ``meta | words``), in
-    ``(max_pages + 1, *spec.word_shape) int32`` rows; with pages of a
-    chunk tail through the XLA codec and with whole-chunk pages through
-    the flat Pallas kernel (interpret mode)."""
+    ``(max_pages + 1, *spec.word_shape) int32`` rows and (ISSUE 46) its
+    pairs as the two planes of a ``(max_pages + 1, 2, num_buckets)`` meta;
+    with pages of a chunk tail through the XLA codec and with whole-chunk
+    pages through the flat Pallas kernel (interpret mode)."""
     from types import SimpleNamespace
 
     from torch_cgx_tpu.ops import paged_kv
@@ -796,7 +806,7 @@ def test_a_written_page_has_the_frames_wire_bytes(model_setup, monkeypatch,
     words, meta = pool
     assert words.shape == (sv.max_pages + 1,) + spec.word_shape
     assert words.dtype == jnp.int32 and spec.word_shape[1] == 128
-    assert meta.shape == (sv.max_pages + 1, spec.num_buckets, 2)
+    assert meta.shape == (sv.max_pages + 1, 2, spec.num_buckets)
     for i, frame in zip(ids, frames):
         np.testing.assert_array_equal(
             _pool_row_wire_bytes(pool, int(i)),
@@ -807,6 +817,78 @@ def test_a_written_page_has_the_frames_wire_bytes(model_setup, monkeypatch,
             (2 * cfg.n_layer if writer == "prefill_pages" else 1)
             if geo == "flat-kernel" else 0
         )
+
+
+# A shipped page over the boundary that keeps the wire (ISSUE 46): the
+# GPT-2 large page (160 buckets: the paged kernel) and a page that ends in a
+# chunk tail (40 buckets: the XLA codec's gather), with their transport bytes
+# as they were before the pool's meta became planes.
+_SHIPPED = {
+    "pallas_paged.meta_planes": ((64, 20, 64), 2 * 160 * 4 + 81920),
+    "xla_gather": ((16, 20, 64), 2 * 40 * 4 + 20480),
+}
+
+
+@pytest.mark.parametrize("lowering", sorted(_SHIPPED))
+def test_a_shipped_page_reads_back_as_the_senders_pool_row(lowering,
+                                                           monkeypatch):
+    """A page a prefill worker quantizes (``prefill._encode_page``: the host
+    codec's ``meta | words``, the meta as ``(buckets, 2)`` pairs) and ships
+    as frames (``transport.frame_page`` / ``unframe_page``) is ingested
+    into a pool whose meta lies as planes (``_decode_page_payload`` ->
+    ``_stack_rows`` -> ``_ingest_pool``) and read back, in either lowering,
+    to the rows the sender's own pool gives, bit for bit. The frame is the
+    parent's: ``PageSpec.wire_bytes`` and the pairs at the head of the
+    payload are unchanged, and a pool row goes back to them
+    (``wire_meta``)."""
+    from torch_cgx_tpu.ops import paged_kv
+    from torch_cgx_tpu.serving import prefill as prefill_mod
+
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    page, wire_bytes = _SHIPPED[lowering]
+    spec = paged_kv.PageSpec(*page, 8, 512)
+    assert spec.wire_bytes() == wire_bytes
+    nb, n, max_pages = spec.num_buckets, 3, 8
+    rng = np.random.default_rng(46)
+    rows = rng.standard_normal((n, spec.flat)).astype(np.float32)
+    sent_ids, got_ids = np.asarray([1, 4, 6]), np.asarray([7, 0, 3])
+    sender = paged_kv.commit_page_rows(
+        paged_kv.empty_pool(max_pages + 1, spec), jnp.asarray(sent_ids),
+        jnp.asarray(rows), spec)
+
+    payloads = []
+    for i, row in enumerate(rows):
+        buf = tp.frame_page(0, tp.K_PAGE, i, spec.bits, spec.bucket_size,
+                            spec.flat, prefill_mod._encode_page(row, spec))
+        frame = tp.unframe_page(buf)
+        assert len(frame.payload) == wire_bytes
+        pairs = np.frombuffer(frame.payload[:8 * nb], np.float32)
+        np.testing.assert_array_equal(
+            pairs.reshape(nb, 2),
+            np.asarray(paged_kv.wire_meta(sender[1][sent_ids[i]])))
+        payloads.append(sched_mod._decode_page_payload(frame, spec))
+    assert payloads[0][1].shape == (2, nb)
+    receiver = programs_mod._ingest_pool(
+        paged_kv.empty_pool(max_pages + 1, spec), jnp.asarray(got_ids),
+        sched_mod._stack_rows(payloads, spec), spec)
+    assert receiver[1].shape == (max_pages + 1, 2, nb)
+
+    def read(pool, ids):
+        table = jnp.asarray([[ids[2], ids[0], -1, ids[1]]], jnp.int32)
+        return paged_kv.gather_dequant_pages(pool, table, spec, jnp.bfloat16)
+
+    metrics.reset()
+    got, want = read(receiver, got_ids), read(sender, sent_ids)
+    assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
+        f"cgx.codec.lowering.dequantize_pages.{lowering}": 2}
+    live = np.repeat([True, True, False, True], spec.page_tokens)
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint16)[0, live],
+        np.asarray(want).view(np.uint16)[0, live])
+    # ... and both are the page the worker had, within the codec's step.
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[0, : spec.page_tokens].reshape(-1),
+        rows[2], atol=0.05)
 
 
 _READ_GEOS = {
@@ -822,7 +904,7 @@ _READ_GEOS = {
 def test_paged_read_is_the_gathered_read_bit_for_bit(geo, monkeypatch):
     """ISSUE 30: ``gather_dequant_pages`` on Pallas dispatch (interpret
     mode here) walks the page table inside the decode kernel where the
-    geometry allows (``pallas_paged``: the GPT-2 K/V pages, the latent
+    geometry allows (``pallas_paged.*``: the GPT-2 K/V pages, the latent
     ``c``), and its rows are those of the composition it replaced — gather
     the table's pool rows, ``dequantize_batch`` over them — bit for bit,
     for a permuted table with sentinels, in ``bfloat16`` and ``float32``
@@ -848,7 +930,7 @@ def test_paged_read_is_the_gathered_read_bit_for_bit(geo, monkeypatch):
     metrics.reset()
     got = paged_kv.gather_dequant_pages(pool, table, spec, dt)
     assert got.shape == (b, p * pt, h * d) and got.dtype == dt
-    lowering = "pallas_paged" if tile else "xla_gather"
+    lowering = "pallas_paged.meta_planes" if tile else "xla_gather"
     assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
         f"cgx.codec.lowering.dequantize_pages.{lowering}": 1}
     assert metrics.get("cgx.codec.lowering.dequantize_rows."
@@ -892,7 +974,7 @@ def test_guarded_read_zeroes_dead_entries_in_every_lowering(geo, monkeypatch):
     got = paged_kv.gather_dequant_pages(
         pool, table, spec, dt, window=True, live=jnp.asarray(live))
     if geo != "raw":
-        lowering = "pallas_paged" if tile else "xla_gather"
+        lowering = "pallas_paged.meta_planes" if tile else "xla_gather"
         assert metrics.snapshot(
             "cgx.codec.lowering.dequantize_pages.") == {
             f"cgx.codec.lowering.dequantize_pages.window.{lowering}": 1}
@@ -1519,7 +1601,8 @@ def test_decode_step_hands_the_pool_to_the_kernel_alone(model_setup,
     jaxpr = jax.make_jaxpr(sched._prog.decode_step)(server.p, sched._state)
     reads = 2 * cfg.n_layer
     assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
-        "cgx.codec.lowering.dequantize_pages.pallas_paged": reads}
+        "cgx.codec.lowering.dequantize_pages.pallas_paged.meta_planes":
+            reads}
     # (The jitted impl around the kernel is the one other equation.)
     for shape in (words.shape, meta.shape):
         assert sorted(set(_eqns_touching(jaxpr, shape))) == [

@@ -205,22 +205,26 @@ def test_benchmark_seams_hold(monkeypatch):
 # ``ContinuousBatchScheduler._fresh_state`` built them at the parent of the
 # PR that moved the construction to ``programs.fresh_state`` (PR 44), at
 # 8-bit pages and at raw ones. A PR that changes the state's layout on
-# purpose reads the new values off this test's failure.
+# purpose reads the new values off this test's failure. (PR 46 did, for the
+# 8-bit leaves: a quantized pool's meta is ``(pages, 2, buckets)``, where the
+# parent's was ``(pages, buckets, 2)``; the structure, the count and the raw
+# pools are the parent's, and so are the leaves of the two geometries here
+# whose pages are two buckets.)
 PARENT_STATE = {
     "gpt2": {"8": ("5b9dad382d4ed71c", "9950256bbc392522", 18),
              "0": ("67e35581ee0cc71b", "1510cfbeead82042", 14)},
-    "mla_moe": {"8": ("020ed118abbfcd76", "4f25a0c507940dc8", 24),
+    "mla_moe": {"8": ("020ed118abbfcd76", "f1520cc4a8e25931", 24),
                 "0": ("2ec0ae25e92fab99", "915b96f138911ac3", 18)},
-    "hybrid_ssm": {"8": ("2c88912c733a2545", "d4363b586c7c8be3", 24),
+    "hybrid_ssm": {"8": ("2c88912c733a2545", "6c99d1f0a21e3886", 24),
                    "0": ("ba44336ef65a826e", "4b4a5ab248401e47", 20)},
     "hybrid_gdn": {"8": ("54a0f16dd737b3cd", "3cdee69f1c41e847", 24),
                    "0": ("af707e80a7f9164f", "7bfd3a6d01cddf41", 20)},
-    "hybrid_kda_mla": {"8": ("759b4ac5f883dd18", "11f2ea3bbb54c710", 24),
+    "hybrid_kda_mla": {"8": ("759b4ac5f883dd18", "a357a271a02115f0", 24),
                        "0": ("123b5cc3f6ed322f", "b54095ffdec82137", 22)},
-    "window_moe": {"8": ("9463bd2b906d9e9b", "b9a190bae6634be3", 55),
+    "window_moe": {"8": ("9463bd2b906d9e9b", "64e2b799c23c01b4", 55),
                    "0": ("2f58a4980b47dff1", "19b0adb4db25b31e", 39)},
     # No parent: as PR 45 first built it (five layers, four of them rings).
-    "afmoe": {"8": ("8b7c0e9004b7cc2e", "0bd7c88018008783", 37),
+    "afmoe": {"8": ("8b7c0e9004b7cc2e", "977e0ea45e30c2b2", 37),
               "0": ("ac233ee66a5ea676", "11b35486f9f7b485", 27)},
 }
 

@@ -230,3 +230,74 @@ def test_tails_through_prefill_decode_and_commit(served, monkeypatch):
                              jax.tree.leaves(want_pool)):
             np.testing.assert_array_equal(
                 np.asarray(got)[pid], np.asarray(want)[pid])
+
+
+# ---------------------------------------------------------------------------
+# The pools' meta lies as the read kernel takes it (ISSUE 46): ``(pool rows,
+# 2, buckets)``, and no program relays it. Over all seven adapters.
+# ---------------------------------------------------------------------------
+
+
+def _all_adapters():
+    import test_afmoe_serving as afmoe_tests
+    import test_ling_hybrid_serving as ling_tests
+    import test_window_moe_serving as window_tests
+    from torch_cgx_tpu.serving.hybrid import HybridLatentMoEServer
+    from torch_cgx_tpu.serving.window import AfmoeServer, WindowMoEServer
+
+    return {
+        **ADAPTERS,
+        "hybrid_kda_mla": _from(ling_tests, HybridLatentMoEServer),
+        "window_moe": _from(window_tests, WindowMoEServer),
+        "afmoe": _from(afmoe_tests, AfmoeServer),
+    }
+
+
+# What may take a pool-sized meta operand: the programs' own nesting, the
+# read (the kernel, or the XLA codec's gather of the table's rows) and a
+# writer's scatter of the rows it wrote.
+_POOL_META_TAKERS = {"jit", "pjit", "pallas_call", "gather", "scatter"}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("adapter", [
+    "gpt2", "mla_moe", "hybrid_ssm", "hybrid_gdn", "hybrid_kda_mla",
+    "window_moe", "afmoe",
+])
+def test_no_program_relays_a_pools_meta(adapter, impl, monkeypatch):
+    """Every quantized pool holds its meta as ``(pool rows, 2, buckets)``
+    float32, and in ``decode_step`` and ``commit`` (the programs of every
+    tick) nothing but the read and the writers' scatters has an operand of
+    that shape: no ``transpose``, ``reshape``, ``copy`` or ``convert`` of a
+    pool's meta stands between the state and the ``pallas_call`` / the
+    ``gather`` that reads it or the ``scatter`` that writes it. What a writer
+    turns is the few rows it wrote: a ``transpose`` of ``(K, buckets, 2)``."""
+    if impl == "pallas":
+        monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    server, _ = _all_adapters()[adapter]()
+    sched = ContinuousBatchScheduler(server)
+    prog, state, sv = sched._prog, sched._state, server.serve
+    metas = {}
+    for name, layer, spec in _tail_entries(prog):
+        words, meta = state["pools"][layer][name]
+        assert meta.dtype == jnp.float32
+        assert meta.shape == (words.shape[0], 2, spec.num_buckets)
+        metas[tuple(meta.shape)] = spec.num_buckets
+    assert metas
+
+    k = sv.commit_lanes
+    zeros = np.zeros((k,), np.int32)
+    programs = {
+        "decode_step": jax.make_jaxpr(prog.decode_step)(server.p, state),
+        "commit": jax.make_jaxpr(prog.commit)(
+            state, zeros, zeros, *((zeros,) if prog.ring else ())),
+    }
+    for which, jaxpr in programs.items():
+        takers = {name for shape in metas
+                  for name in gpt2_tests._eqns_touching(jaxpr, shape)}
+        assert takers and takers <= _POOL_META_TAKERS, (which, takers)
+        assert ("scatter" in takers) == (which == "commit")
+        if which == "commit":  # the K rows the commit wrote, never the pool
+            for nb in metas.values():
+                assert "transpose" in gpt2_tests._eqns_touching(
+                    jaxpr, (k, nb, 2))

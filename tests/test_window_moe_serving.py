@@ -480,10 +480,14 @@ def test_windows_must_agree_and_hold_a_page(params):
 # of the SHA-256 of each program's jaxpr as text and of the state's tree. An
 # adapter that states no window has to build exactly those. A PR that changes
 # the programs on purpose reads the new values off this test's failure.
+# (PR 46 did: a pool's meta is ``(pages, 2, buckets)``, so the leaves and
+# every program that takes the pools differ from PR 41's parent in that shape
+# and in one ``transpose`` of the few rows a writer writes or the XLA codec's
+# read gathers; the tree's structure is the parent's.)
 PARENT = {
-    "state": "5b9dad382d4ed71c", "leaves": "3339dfdcee6f069c",
-    "decode_step": "03c922de1b188efc", "commit": "85fd7932018d8dfd",
-    "prefill_pages": "a1479a4f29a5ec9e", "admit_lane": "832692c940bccafd",
+    "state": "5b9dad382d4ed71c", "leaves": "8df471e6b5bf5241",
+    "decode_step": "c212ea5f445bab5b", "commit": "6e161bd35f5b344f",
+    "prefill_pages": "e8dcbcdea5e5f606", "admit_lane": "13d3de0864f2362a",
 }
 
 

@@ -303,6 +303,28 @@ def _dequantize_kernel(words_ref, meta_ref, out_ref, *, bits, tc):
     )
 
 
+def _plane_columns(planes):
+    """A pool page's meta ``(2, nb)`` (plane 0 the units, plane 1 the minima,
+    a bucket a lane: ``ops/paged_kv.py``) as the two ``(nb, 1)`` columns the
+    decode multiplies by, a bucket a sublane. Bucket ``i``'s value is picked
+    from lane ``i`` by an ``iota`` compare and summed along the lanes as its
+    int32 bit pattern with zeros, so the column holds the plane's bits
+    whatever they are (a negative zero, a denormal the float unit would
+    flush)."""
+    nb = planes.shape[1]
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (nb, nb), 1))
+    bits = jax.lax.bitcast_convert_type(planes, jnp.int32)
+    return tuple(
+        jax.lax.bitcast_convert_type(
+            jnp.sum(jnp.where(diag, bits[p : p + 1, :], 0), axis=1,
+                    keepdims=True),
+            jnp.float32,
+        )
+        for p in range(2)
+    )
+
+
 def _pipe_tc(n_chunks: int, bucket_size: int) -> int:
     """Chunks per block for the flat fast path: the largest candidate within
     the VMEM cap that divides the total chunk count (the flat grid tiles all
@@ -420,6 +442,7 @@ def _dequantize_flat_impl(
     name: str = "cgx_dequantize_flat",
 ):
     """Zero-relayout dequantize: words (rows, W) int32 + meta (rows, nb_r, 2)
+    (the wire's pairs; a pool's planes under ``page_ids``, below)
     -> (rows, nb_r*B) ``out_dtype``. Word blocks are natural (., 128) flat
     rows like :func:`_quantize_flat_impl`'s output; the decoded values are
     computed on a full-vreg 2-D ``(tc*32*rb, 128)`` shape (measured ~1.4 ms
@@ -451,11 +474,19 @@ def _dequantize_flat_impl(
     ``acc + (bmin + unit*lvl)``).
 
     ``page_ids (n,) int32``: the paged read. ``words (pool rows, W/128,
-    128)`` and ``meta (pool rows, nb_r, 2)`` are then a page POOL in the
+    128)`` and ``meta (pool rows, 2, nb_r)`` are then a page POOL in the
     kernel's own operand layout (``ops/paged_kv.py``), and output row ``i``
     is the decode of pool row ``page_ids[i]``: the ids are a
     scalar-prefetch operand and the word and meta ``index_map``s pick the
     block, so nothing gathers or reshapes the pool in front of the kernel.
+    A page's meta block is its two planes as they lie, ``(2, nb_r)`` with a
+    bucket a lane (full trailing dimensions: any ``nb_r`` is a legal
+    block), and :func:`_plane_columns` turns them into the per-bucket
+    columns inside the kernel; the arithmetic and its order are the unpaged
+    call's, so a value is that call's over the wire's pairs bit for bit.
+    (As ``(pool rows, nb_r, 2)`` XLA relaid the whole pool in front of
+    every call and the block was a ``(nb_r, 128)`` tile in VMEM: 256 KB a
+    page operand for 4 KB of pairs.)
     ``tc`` is the chunks a grid step decodes, a whole number of pages
     (:func:`_pages_tc`): one page operand pair a page, the body above once
     a page. The ids must be valid rows (the caller clips its sentinels).
@@ -471,7 +502,12 @@ def _dequantize_flat_impl(
     ``None`` is the call without a guard, jaxpr for jaxpr."""
     b = bucket_size
     rb = b // 128
-    nb_r = meta.shape[1]
+    if page_ids is not None and (meta.ndim != 3 or meta.shape[1] != 2):
+        raise ValueError(
+            "the paged read takes a pool's meta as planes, (pool rows, 2, "
+            f"buckets); got {meta.shape}"
+        )
+    nb_r = meta.shape[1] if page_ids is None else meta.shape[2]
     rows = words.shape[0] if page_ids is None else page_ids.shape[0]
     n_chunks = rows * nb_r // CHUNK_BUCKETS
     # Chunks one pass of the body decodes, and passes a grid step.
@@ -490,9 +526,15 @@ def _dequantize_flat_impl(
         lvl = jnp.zeros((tc_body, CHUNK_BUCKETS, rb, 128), jnp.int32)
         for w in range(bits):
             lvl = lvl | (((w4[:, w : w + 1, :, :] >> sub) & 1) << w)
-        m2 = m_ref[:]
-        unit = m2[:, 0:1].reshape(tc_body, CHUNK_BUCKETS, 1, 1)
-        bmin = m2[:, 1:2].reshape(tc_body, CHUNK_BUCKETS, 1, 1)
+        if page_ids is None:  # the wire's pairs, a bucket a sublane
+            m2 = m_ref[:]
+            unit = m2[:, 0:1].reshape(tc_body, CHUNK_BUCKETS, 1, 1)
+            bmin = m2[:, 1:2].reshape(tc_body, CHUNK_BUCKETS, 1, 1)
+        else:  # a pool page's two planes, a bucket a lane
+            unit, bmin = (
+                col.reshape(tc_body, CHUNK_BUCKETS, 1, 1)
+                for col in _plane_columns(m_ref[:])
+            )
         return (bmin + unit * lvl.astype(jnp.float32)).reshape(s_rows, 128)
 
     def _store(out_ref, flat_ref, vals, at=slice(None)):
@@ -567,7 +609,7 @@ def _dequantize_flat_impl(
                                  memory_space=pltpu.VMEM)
                     for p in range(ppb)
                 ] + [
-                    pl.BlockSpec((None, nb_r, 2), page(p),
+                    pl.BlockSpec((None, 2, nb_r), page(p),
                                  memory_space=pltpu.VMEM)
                     for p in range(ppb)
                 ],
@@ -1048,7 +1090,7 @@ def dequantize_pages(
 ) -> jax.Array:
     """The paged read: decode pool rows ``page_ids (n,)`` of a page pool
     kept in the flat kernel's operand layout (``words (pool rows, W/128,
-    128) int32``, ``meta (pool rows, nb, 2) f32``) -> ``(n, numel /
+    128) int32``, ``meta (pool rows, 2, nb) f32``) -> ``(n, numel /
     row_width, row_width)`` of ``out_dtype``, the values of
     :func:`dequantize_batch` over the gathered rows bit for bit. ``tc``:
     :func:`_pages_tc`'s tile, which the caller has checked is not None.

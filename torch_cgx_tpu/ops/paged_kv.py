@@ -11,8 +11,8 @@ program. On TPU dispatch the read is the flat Pallas dequantize kernel
 walking the page table: the table's page ids are its scalar-prefetch
 operand and each grid step fetches its pages' words and meta from the
 pool by id, then writes the table once, in the type and the row order the
-attention reads (:func:`gather_dequant_pages`). Nothing gathers or
-reshapes the pool in front of the kernel, and the pool itself is never
+attention reads (:func:`gather_dequant_pages`). Nothing gathers, reshapes
+or relays the pool in front of the kernel, and the pool itself is never
 decoded.
 
 Layouts (all static per compiled decode program):
@@ -20,8 +20,9 @@ Layouts (all static per compiled decode program):
 * a page's flat payload is ``page_tokens * n_head * d_head`` values
   (one payload per (layer, K|V) pair);
 * quantized pool: ``words (max_pages, *PageSpec.word_shape) int32`` +
-  ``meta (max_pages, num_buckets, 2) f32`` per (layer, kind) — row ``p``
-  is page ``p``'s rows=1 QTensor. The words of a page are the host
+  ``meta (max_pages, 2, num_buckets) f32`` per (layer, kind) — row ``p``
+  is page ``p``'s rows=1 QTensor, its meta as two lane-dense planes
+  (below). The words of a page are the host
   codec's wire words (``ops/codec_host.py``) in their wire order, as rows
   of 128: the flat kernels' own operand layout and type
   (``_quantize_flat_impl`` emits ``(chunks*bits*rb, 128) int32``,
@@ -35,6 +36,21 @@ Layouts (all static per compiled decode program):
   section 6, PR 30). The bytes and their order are the wire's: a frame's
   payload drops into a pool row by a host-side reshape
   (:func:`pool_words`), and ``PageSpec.wire_bytes`` counts both;
+* a page's meta is the wire's ``(num_buckets, 2)`` (unit, minimum) pairs
+  kept as two planes, ``meta[p, 0]`` the units and ``meta[p, 1]`` the
+  minima, a bucket a lane (:func:`pool_meta`; :func:`wire_meta` undoes
+  it). The paged read takes a page's ``(2, num_buckets)`` block as it
+  lies and turns the two rows into the columns it multiplies by inside
+  the kernel (``codec_pallas._plane_columns``). Kept as the wire's pairs,
+  ``(max_pages, num_buckets, 2)``, XLA held the pool with the buckets
+  along the lanes and the Pallas call wanted the pair padded out to 128
+  lanes, so every read call of every step was preceded by a ``copy`` of
+  the whole pool's meta, live pages and dead alike (285 MB written for
+  4.5 MB of pairs in Trinity: PERF.md section 6, PR 46). Every writer
+  turns the few rows it writes (:func:`quantize_page_rows`, the ingest's
+  host payload), never the pool; what leaves the pool for the wire or the
+  XLA codec is turned back (:func:`pool_qtensor`), so the wire's bytes
+  and ``PageSpec.wire_bytes`` are what they were;
 * raw pool (``bits == 0``, the f16 shipping baseline):
   ``(max_pages, page_tokens, n_head, d_head) f16``.
 """
@@ -164,7 +180,7 @@ def empty_pool(max_pages: int, spec: PageSpec):
         )
     return (
         jnp.zeros((max_pages,) + spec.word_shape, jnp.int32),
-        jnp.zeros((max_pages, spec.num_buckets, 2), jnp.float32),
+        jnp.zeros((max_pages, 2, spec.num_buckets), jnp.float32),
     )
 
 
@@ -180,13 +196,24 @@ def wire_words(words, spec: PageSpec):
     return words.reshape(-1, spec.packed_words).view(jnp.uint32)
 
 
+def pool_meta(meta):
+    """Wire meta ``(n, num_buckets, 2)`` (a QTensor's (unit, minimum)
+    pairs, a frame's; device or host array) as pool rows ``(n, 2,
+    num_buckets)``: the units' plane, then the minima's."""
+    return meta.swapaxes(-1, -2)
+
+
+# Pool rows back as the wire's pairs: the same turn undoes itself.
+wire_meta = pool_meta
+
+
 def quantize_page_rows(rows: jax.Array, spec: PageSpec) -> Tuple[jax.Array, jax.Array]:
     """Quantize ``rows (n, flat) f32`` page payloads -> (words, meta)
     pool rows. Deterministic (see :meth:`PageSpec.cc`) so the commit
     path, the host-codec transport path and any replay produce identical
     wire bytes."""
     q = ops_dispatch.quantize_batch(rows.astype(jnp.float32), spec.cc)
-    return pool_words(q.packed, spec), q.meta.astype(jnp.float32)
+    return pool_words(q.packed, spec), pool_meta(q.meta.astype(jnp.float32))
 
 
 def pool_qtensor(
@@ -194,11 +221,12 @@ def pool_qtensor(
 ) -> codec.QTensor:
     """The batched QTensor view of gathered pool rows: ``page_ids (n,)``
     int32 (callers clip sentinel ids to a valid row and mask downstream —
-    gathers stay in-bounds, masking stays explicit)."""
+    gathers stay in-bounds, masking stays explicit). The gathered rows'
+    meta goes back to the wire's pairs here: ``n`` pages, not the pool."""
     n = page_ids.shape[0]
     return codec.QTensor(
         packed=wire_words(words[page_ids], spec),
-        meta=meta[page_ids],
+        meta=wire_meta(meta[page_ids]),
         residual=jnp.zeros((n, 0), jnp.float32),
         numel=spec.flat,
         bits=spec.bits,
@@ -244,8 +272,10 @@ def gather_dequant_pages(
     dispatch where :meth:`PageSpec.paged_read_tile` gives a tile — the
     flat decode kernel fetches each page from the pool by its id
     (``codec_pallas.dequantize_pages``; nothing in front of it but the
-    clip) — and ``xla_gather`` everywhere else: an XLA gather of the
-    table's rows, then ``ops.dispatch.dequantize_batch`` over them (the
+    clip), counted as ``pallas_paged.meta_planes`` after the form the
+    pool's meta reaches it in — and ``xla_gather`` everywhere else: an XLA
+    gather of the table's rows, then ``ops.dispatch.dequantize_batch`` over
+    them, their meta turned back to the wire's pairs (the
     64-wide rotated key of a latent cache, whose rows XLA reshapes; pages
     that are not whole chunks; the XLA codec off the TPU; raw pools, a
     gather and a cast).
@@ -281,7 +311,7 @@ def gather_dequant_pages(
         tile = spec.paged_read_tile(b * p, dtype)
     codec_pallas.note_lowering(
         "dequantize_pages.window" if window else "dequantize_pages",
-        "pallas_paged" if tile else "xla_gather",
+        "pallas_paged.meta_planes" if tile else "xla_gather",
     )
     if tile:
         rows = ops_dispatch.dequantize_pages(

@@ -20,11 +20,13 @@ forward alone, for the prefill worker (``serving/prefill.py``), and
 
 ``pools[l][stream]`` is ``paged_kv.empty_pool(max_pages + 1, spec)``
 (``max_batch * ring + 1`` for a window layer): for a quantized stream
-``(words (max_pages + 1, *spec.word_shape) int32, meta (max_pages + 1,
-num_buckets, 2) f32)``, a page's wire words as rows of 128, the flat decode
-kernel's own blocks, so the read fetches a page from the pool by its id and
-nothing gathers or reshapes the pool first (on the chip a ``(n, W)`` and a
-``(n * W / 128, 128)`` array tile differently: ``ops/paged_kv.py``,
+``(words (max_pages + 1, *spec.word_shape) int32, meta (max_pages + 1, 2,
+num_buckets) f32)``, a page's wire words as rows of 128 and its (unit,
+minimum) pairs as two lane-dense planes, the flat decode kernel's own
+blocks, so the read fetches a page from the pool by its id and nothing
+gathers, reshapes or relays the pool first (on the chip a ``(n, W)`` and a
+``(n * W / 128, 128)`` array tile differently, and a ``(n, buckets, 2)``
+meta was relaid whole in front of every read: ``ops/paged_kv.py``,
 "Layouts"); ``(max_pages + 1, page_tokens, n_head, d_head) f16`` for a raw
 one. The last row is scratch: a padded slot of the ``commit`` program and a
 prefill's last page that is a tail write there.
@@ -427,7 +429,8 @@ def fresh_state(prog: SimpleNamespace, serve: ServeConfig) -> Dict:
 def _ingest_pool(pool, ids, rows, spec: paged_kv.PageSpec):
     """Scatter pre-encoded pool rows: quantized rows arrive as (words,
     meta) pairs in pool-row form (the transport's wire bytes ARE the
-    pool's, ``paged_kv.pool_words``), raw rows as f32 payloads."""
+    pool's, ``paged_kv.pool_words``; its meta pairs as the pool's two
+    planes, ``paged_kv.pool_meta``), raw rows as f32 payloads."""
     if not spec.quantized:
         pages = rows.reshape(
             -1, spec.page_tokens, spec.n_head, spec.d_head

@@ -1328,7 +1328,8 @@ def _pad_prompt(prompt: np.ndarray, page_tokens: int) -> np.ndarray:
 def _decode_page_payload(frame: tp.PageFrame, spec: paged_kv.PageSpec):
     """A page frame's payload in pool-row form: (words, meta) numpy
     pair for quantized specs (the host-codec wire words, reshaped to the
-    pool's rows of 128 — zero re-encoding), or the raw f32 payload row."""
+    pool's rows of 128, and the wire's (unit, minimum) pairs as the pool's
+    two planes — zero re-encoding), or the raw f32 payload row."""
     if not spec.quantized:
         return np.frombuffer(frame.payload, np.float16).astype(
             np.float32
@@ -1339,7 +1340,7 @@ def _decode_page_payload(frame: tp.PageFrame, spec: paged_kv.PageSpec):
     )
     return (
         paged_kv.pool_words(np.asarray(q.packed), spec)[0],
-        np.asarray(q.meta, np.float32),
+        paged_kv.pool_meta(np.asarray(q.meta, np.float32)),
     )
 
 
