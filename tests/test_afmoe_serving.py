@@ -33,9 +33,12 @@ from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
     Request,
 )
+from torch_cgx_tpu.serving import window as window_mod  # noqa: E402
 from torch_cgx_tpu.serving.window import AfmoeServer  # noqa: E402
 from torch_cgx_tpu.utils.logging import metrics  # noqa: E402
 from torch_cgx_tpu.wire import edges  # noqa: E402
+
+import serving_guard  # noqa: E402
 
 PAGE, WINDOW = 8, 32
 RING = WINDOW // PAGE + 1
@@ -264,6 +267,23 @@ def test_lanes_under_across_and_past_the_window_in_one_batch(params):
         assert widest < LIMIT_WIDEST and mean < LIMIT_MEAN, (widest, mean)
     assert sched._prog.ring == RING
     assert sched._prog.windows == (WINDOW, WINDOW, WINDOW, WINDOW, 0)
+
+
+@pytest.mark.parametrize("guard", ["page_live", "ring_live"])
+def test_a_guard_leaves_every_held_lanes_logits_bit_for_bit(params, guard):
+    """A step's logits with both reads guarded (the global layer's by the
+    lane's committed pages, ``adapter.page_live``; the window layers' by
+    the ring's live slots) are the logits with ``guard`` taken off on every
+    held lane, finite on a vacated one, through a batch of a short request
+    (it finishes first and leaves its lane vacant), one of 2 pages of its
+    table's 26 and one prefilled past the window."""
+    sv = _serve(max_batch=3)
+    seen = serving_guard.steps_with_and_without_the_guard(
+        AfmoeServer(_cfg(), params, sv), window_mod,
+        [(_prompt(9, seed=10), 3), (_prompt(17, seed=11), 14),
+         (_prompt(85, seed=12), 14)], guard=guard)
+    share = serving_guard.assert_held_lanes_bit_for_bit(seen, sv.pages_per_seq)
+    assert 0.1 < share < 0.2  # 12-13 of the table's 78 slots
 
 
 def test_the_eight_shares_add_up_to_the_uncut_references_expert_layer(

@@ -30,6 +30,7 @@ from torch_cgx_tpu.models.granite_hybrid import HybridConfig  # noqa: E402
 from torch_cgx_tpu.ops import dispatch as ops_dispatch  # noqa: E402
 from torch_cgx_tpu.ops import ssm  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
+from torch_cgx_tpu.serving import hybrid as hybrid_mod  # noqa: E402
 from torch_cgx_tpu.serving.hybrid import HybridSSMServer  # noqa: E402
 from torch_cgx_tpu.serving.prefill import PrefillWorker  # noqa: E402
 from torch_cgx_tpu.serving.adapter import ServeConfig  # noqa: E402
@@ -43,6 +44,7 @@ from torch_cgx_tpu.utils.logging import metrics  # noqa: E402
 from torch_cgx_tpu.wire import edges  # noqa: E402
 
 from test_faults import FakeStore  # noqa: E402
+import serving_guard  # noqa: E402
 
 PAGE = 16  # = the scan's chunk: a padded prompt is whole chunks
 HF = dict(
@@ -415,6 +417,52 @@ def test_adapters_without_state_keep_their_programs(monkeypatch):
         assert len(jaxpr.jaxpr.invars) == (
             len(jax.tree.leaves(sched._state)) + len(lane_args)
             + len(names))
+
+
+def _mixed_batch():
+    """A short request (it finishes first and leaves its lane vacant), one
+    that commits its second page on the way and one prefilled at four pages
+    of its table's six, as ``(prompt, gen)`` pairs."""
+    return [(_prompt(5, seed=10), 3), (_prompt(PAGE + 9, seed=11), 14),
+            (_prompt(4 * PAGE + 5, seed=12), 14)]
+
+
+def test_the_global_guard_leaves_every_held_lanes_logits_bit_for_bit(
+        params, monkeypatch):
+    """A step's logits with the attention layers' read guarded by the
+    lane's committed pages (``adapter.page_live``) are the logits of the
+    read of the whole table on every held lane, finite on a vacated one,
+    through a batch of a short request (it finishes first and leaves its
+    lane vacant), one that commits its second page on the way and one
+    prefilled at four pages of its six."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    sv = _serve()
+    seen = serving_guard.steps_with_and_without_the_guard(
+        HybridSSMServer(_cfg(), params, sv), hybrid_mod, _mixed_batch())
+    share = serving_guard.assert_held_lanes_bit_for_bit(seen, sv.pages_per_seq)
+    assert 0.2 < share < 0.4  # 5-6 of the table's 18 slots
+
+
+def test_a_hybrid_adapter_counts_what_its_global_read_decodes(
+        params, monkeypatch):
+    """An adapter with no ring writes the global class's three counters: a
+    dispatched step adds the device's ``page_live`` (summed) to
+    ``kv.decoded_pages.global`` and ``kv.live_pages.global`` and the whole
+    table, ``max_batch x pages_per_seq``, to ``kv.table_pages.global``; the
+    window class's counters are not written."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    sv = _serve()
+    before = metrics.snapshot("cgx.serve.kv.")
+    device, host = serving_guard.device_and_host_pages(
+        HybridSSMServer(_cfg(), params, sv), _mixed_batch())
+    after = metrics.snapshot("cgx.serve.kv.")
+    assert len(host) == len(device) > 12
+    assert [h[0] for h in host] == device and [h[1] for h in host] == device
+    assert {h[2] for h in host} == {float(sv.max_batch * sv.pages_per_seq)}
+    assert max(device) > min(device) > 0  # a tail committed on the way
+    for name in ("live_pages", "decoded_pages"):
+        key = f"cgx.serve.kv.{name}.window"
+        assert after.get(key, 0.0) == before.get(key, 0.0)
 
 
 def test_disaggregated_path_refuses_a_recurrent_state(params):

@@ -41,6 +41,7 @@ from torch_cgx_tpu.utils.logging import metrics  # noqa: E402
 from torch_cgx_tpu.wire import edges  # noqa: E402
 
 from test_faults import FakeStore  # noqa: E402
+import serving_guard  # noqa: E402
 
 PAGE = 8
 HF = dict(
@@ -426,6 +427,25 @@ def test_latent_streams_in_state_pools_and_key(params, monkeypatch):
     assert sched_mod._program_key(gpt2)[0] == "gpt2"
     monkeypatch.setenv("CGX_KV_BITS", "4")
     assert sched_mod._program_key(server) != key
+
+
+# sha256 of the decode step's jaxpr at ``_cfg()`` / ``_serve()`` and 8-bit
+# pages, computed on the parent commit's ``git archive`` (PR 46), by lowering.
+PARENT_DECODE_STEP = {"xla": "16e8e292c679375a", "pallas": "fd089edac50f2587"}
+
+
+@pytest.mark.parametrize("impl", sorted(PARENT_DECODE_STEP))
+def test_the_decode_step_reads_its_whole_table_as_the_parent_did(
+        params, monkeypatch, impl):
+    """The latent layers' read takes no guard (``layer_cache_rows`` without
+    ``live``: the adapter says so), so the decode program is the one from
+    before the K/V adapters' global read had one, jaxpr for jaxpr, on the
+    XLA codec and on the kernel."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    monkeypatch.setenv("CGX_CODEC_IMPL", impl)
+    server = LatentMoEServer(_cfg(), params, _serve())
+    assert not server.guards_global_read
+    assert serving_guard.decode_step_sha(server) == PARENT_DECODE_STEP[impl]
 
 
 def test_disaggregated_path_refuses_latent_streams(params):

@@ -43,6 +43,7 @@ from torch_cgx_tpu.utils.logging import metrics  # noqa: E402
 from torch_cgx_tpu.wire import edges  # noqa: E402
 
 from test_faults import FakeStore  # noqa: E402
+import serving_guard  # noqa: E402
 
 PAGE = 16  # = the delta rule's chunk and sub-chunk
 # The published keys at a tiny size: 7 of 12 layers (layer 0, dense, and a
@@ -667,6 +668,25 @@ def test_a_lane_does_not_depend_on_what_other_lanes_hold_or_held(
     assert sched._lanes[lane] is again
     assert sched.run(deadline_s=300.0)
     assert again.output == want
+
+
+# sha256 of the decode step's jaxpr at ``_cfg()`` / ``_serve()`` and 8-bit
+# pages, computed on the parent commit's ``git archive`` (PR 46), by lowering.
+PARENT_DECODE_STEP = {"xla": "dff40274e9ccce3a", "pallas": "c5ce042cb2e16f9d"}
+
+
+@pytest.mark.parametrize("impl", sorted(PARENT_DECODE_STEP))
+def test_the_decode_step_reads_its_whole_table_as_the_parent_did(
+        params, monkeypatch, impl):
+    """The latent layers' read takes no guard (``layer_cache_rows`` without
+    ``live``: the adapter says so), so the decode program is the one from
+    before the K/V adapters' global read had one, jaxpr for jaxpr, on the
+    XLA codec and on the kernel."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    monkeypatch.setenv("CGX_CODEC_IMPL", impl)
+    server = HybridLatentMoEServer(_cfg(), params, _serve())
+    assert not server.guards_global_read
+    assert serving_guard.decode_step_sha(server) == PARENT_DECODE_STEP[impl]
 
 
 def test_disaggregated_path_refuses_a_recurrent_state(params):

@@ -30,6 +30,7 @@ from torch_cgx_tpu.observability import memledger  # noqa: E402
 from torch_cgx_tpu.ops import dispatch as ops_dispatch  # noqa: E402
 from torch_cgx_tpu.ops import gdn  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
+from torch_cgx_tpu.serving import hybrid as hybrid_mod  # noqa: E402
 from torch_cgx_tpu.serving.hybrid import HybridGDNServer  # noqa: E402
 from torch_cgx_tpu.serving.prefill import PrefillWorker  # noqa: E402
 from torch_cgx_tpu.serving.adapter import ServeConfig  # noqa: E402
@@ -42,6 +43,7 @@ from torch_cgx_tpu.utils.logging import metrics  # noqa: E402
 from torch_cgx_tpu.wire import edges  # noqa: E402
 
 from test_faults import FakeStore  # noqa: E402
+import serving_guard  # noqa: E402
 
 PAGE = 16  # = the delta rule's chunk: a padded prompt is whole chunks
 HF = dict(
@@ -536,6 +538,24 @@ def test_admissions_write_the_lanes_state(params, monkeypatch):
                              max_new_tokens=3))
     assert sched.run(deadline_s=300.0)
     assert metrics.get("cgx.serve.state.lane_writes") - before == 4
+
+
+def test_the_global_guard_leaves_every_held_lanes_logits_bit_for_bit(
+        params, monkeypatch):
+    """A step's logits with the attention layers' read guarded by the
+    lane's committed pages (``adapter.page_live``) are the logits of the
+    read of the whole table on every held lane, finite on a vacated one,
+    through a batch of a short request (it finishes first and leaves its
+    lane vacant), one that commits its second page on the way and one
+    prefilled at four pages of its six."""
+    monkeypatch.setenv("CGX_KV_BITS", "8")
+    sv = _serve()
+    seen = serving_guard.steps_with_and_without_the_guard(
+        HybridGDNServer(_cfg(), params, sv), hybrid_mod,
+        [(_prompt(5, seed=10), 3), (_prompt(PAGE + 9, seed=11), 14),
+         (_prompt(4 * PAGE + 5, seed=12), 14)])
+    share = serving_guard.assert_held_lanes_bit_for_bit(seen, sv.pages_per_seq)
+    assert 0.2 < share < 0.4  # 5-6 of the table's 18 slots
 
 
 def test_disaggregated_path_refuses_a_recurrent_state(params):

@@ -43,6 +43,7 @@ from torch_cgx_tpu.utils.logging import metrics  # noqa: E402
 from torch_cgx_tpu.wire import edges  # noqa: E402
 
 from test_faults import FakeStore  # noqa: E402
+import serving_guard  # noqa: E402
 
 PAGE, WINDOW = 8, 32
 RING = WINDOW // PAGE + 1
@@ -207,6 +208,9 @@ def test_live_and_committed_pages_are_counted_by_class(served, run):
     oldest = np.maximum(positions - WINDOW + 1, 0) // PAGE
     assert counted["decode_steps"] == gen - 1
     assert counted["kv.live_pages.global"] == pages.sum()
+    assert counted["kv.decoded_pages.global"] == pages.sum()
+    assert counted["kv.table_pages.global"] == (
+        (gen - 1) * 2 * _serve().pages_per_seq)  # two lanes' rows a step
     assert counted["kv.live_pages.window"] == (pages - oldest).sum()
     assert counted["kv.decoded_pages.window"] == (pages - oldest).sum()
     assert (pages - oldest).max() == RING - 1  # the spare slot is never live
@@ -338,11 +342,36 @@ def test_ring_live_is_ring_masks_by_slot():
     assert live.sum(-1).tolist() == [0, 3, 3, 4]
 
 
+def test_page_live_is_lane_masks_by_page():
+    """A slot of the page table is live iff one of its rows is committed: a
+    lane's first ``n_pages``; a vacated lane's row is dead whole, a full
+    lane's live whole."""
+    sv = _serve(max_batch=4, max_seq=6 * PAGE)
+    state = {
+        "tokens": jnp.zeros((4,), jnp.int32),
+        "n_pages": jnp.asarray([0, 1, 4, 6], jnp.int32),
+        "tail_len": jnp.asarray([0, 3, PAGE - 1, 0], jnp.int32),
+    }
+    _, rows, _ = adapter_mod.lane_masks(sv, state)
+    live = np.asarray(adapter_mod.page_live(sv, state))
+    assert live.shape == (4, 6) and live.dtype == bool
+    assert (live == np.asarray(rows).reshape(4, 6, PAGE).any(-1)).all()
+    assert (live == np.asarray(rows).reshape(4, 6, PAGE).all(-1)).all()
+    assert live.sum(-1).tolist() == [0, 1, 4, 6]
+    assert live[2].tolist() == [True] * 4 + [False] * 2
+
+
+def _mixed_batch():
+    """A short request (it finishes first and leaves its lane vacant), one
+    of 2 pages and one prefilled at 85 tokens, whose ring of 5 has turned
+    twice: a batch of three, as ``(prompt, gen)`` pairs."""
+    return [(_prompt(n, seed=10 + i), gen)
+            for i, (n, gen) in enumerate([(9, 3), (17, 14), (85, 14)])]
+
+
 def _mixed_batch_steps(params, watch):
-    """Serve a short request (it finishes first and leaves its lane vacant),
-    one of 2 pages and one prefilled at 85 tokens, whose ring of 5 has turned
-    twice, in a batch of three; ``watch(sched, p, state)`` is called before
-    every decode step on the state the step is given."""
+    """Serve :func:`_mixed_batch`; ``watch(sched, p, state)`` is called
+    before every decode step on the state the step is given."""
     server = WindowMoEServer(_cfg(), params, _serve(max_batch=3))
     sched = ContinuousBatchScheduler(server)
     prog = sched._prog
@@ -352,48 +381,30 @@ def _mixed_batch_steps(params, watch):
         return prog.decode_step(p, state)
 
     sched._prog = SimpleNamespace(**{**vars(prog), "decode_step": decode_step})
-    for i, (n, gen) in enumerate([(9, 3), (17, 14), (85, 14)]):
-        sched.submit(Request(id=f"r{i}", tokens=_prompt(n, seed=10 + i),
-                             max_new_tokens=gen))
+    for i, (prompt, gen) in enumerate(_mixed_batch()):
+        sched.submit(Request(id=f"r{i}", tokens=prompt, max_new_tokens=gen))
     assert sched.run(deadline_s=600.0)
 
 
-def test_the_guard_leaves_every_held_lanes_logits_bit_for_bit(
-        params, monkeypatch):
-    """A step's logits with the guard are the logits without it on every
-    held lane, through steps at which the batch holds a lane with 2 pages, a
+@pytest.mark.parametrize("guard", ["ring_live", "page_live"])
+def test_the_guard_leaves_every_held_lanes_logits_bit_for_bit(params, guard):
+    """A step's logits with both guards are the logits without ``guard``
+    (the rings', the page table's) on every held lane, through steps at
+    which the batch holds a lane with 2 pages (of 26 slots of its table), a
     lane whose ring has turned twice and a vacated lane (whose row is never
     served and is finite either way: its slots are all dead, its scores'
     mask a finite -1e30)."""
     from torch_cgx_tpu.serving import window as window_mod
 
-    def forward(server, prog):
-        return jax.jit(lambda p, st: server.with_params(p).decode_forward(
-            st, prog.streams)[0])
-
-    seen = []
-
-    def watch(sched, p, state):
-        lanes = [r is not None for r in sched._lanes]
-        if "guarded" not in probes:
-            probes["guarded"] = forward(sched.server, sched._prog)
-            with monkeypatch.context() as m:
-                m.setattr(window_mod, "ring_live", lambda *a: None)
-                probes["bare"] = forward(sched.server, sched._prog)
-                probes["bare"](p, state)  # traced while the guard is off
-        seen.append((lanes, np.asarray(state["n_pages"]),
-                     np.asarray(probes["guarded"](p, state)),
-                     np.asarray(probes["bare"](p, state))))
-
-    probes = {}
-    _mixed_batch_steps(params, watch)
-    mixed = 0
-    for lanes, n_pages, guarded, bare in seen:
-        assert np.isfinite(guarded).all() and np.isfinite(bare).all()
-        np.testing.assert_array_equal(guarded[lanes], bare[lanes])
-        mixed += lanes == [False, True, True] and n_pages.tolist()[1:] in (
-            [2, 10], [2, 11])
-    assert mixed >= 5  # the batch the docstring names was really seen
+    sv = _serve(max_batch=3)
+    seen = serving_guard.steps_with_and_without_the_guard(
+        WindowMoEServer(_cfg(), params, sv), window_mod, _mixed_batch(),
+        guard=guard)
+    serving_guard.assert_held_lanes_bit_for_bit(seen, sv.pages_per_seq)
+    # the batch the docstring names was really seen
+    assert sum(lanes == [False, True, True]
+               and n_pages.tolist()[1:] in ([2, 10], [2, 11])
+               for lanes, n_pages, _, _ in seen) >= 5
 
 
 def test_the_hosts_live_pages_are_the_devices_mask_at_every_step(params):
@@ -429,6 +440,24 @@ def test_the_hosts_live_pages_are_the_devices_mask_at_every_step(params):
     # each commit slides a page out, so its live slots stay at RING - 1 or
     # fall to RING - 2 while the short lanes' grow.
     assert max(device) > min(device)
+
+
+def test_the_hosts_decoded_pages_are_the_devices_page_mask_at_every_step(
+        params):
+    """The device's page mask (``page_live``) sums at every dispatched step
+    to what the host adds to ``cgx.serve.kv.decoded_pages.global`` (and to
+    ``.live_pages.global``) from its own counts, and
+    ``.table_pages.global`` grows by the whole table, ``max_batch x
+    pages_per_seq``, a step: what the global read decoded before it had a
+    guard."""
+    sv = _serve(max_batch=3)
+    device, host = serving_guard.device_and_host_pages(
+        WindowMoEServer(_cfg(), params, sv), _mixed_batch())
+    assert len(host) == len(device) > 12
+    assert [h[0] for h in host] == device and [h[1] for h in host] == device
+    assert {h[2] for h in host} == {float(3 * sv.pages_per_seq)}
+    # The engaged share: the table is 3 x 26 slots, the lanes hold 12-13.
+    assert 0.1 < sum(device) / sum(h[2] for h in host) < 0.2
 
 
 def test_banded_prefill_equals_full_attention_under_the_band():
@@ -527,6 +556,22 @@ def test_an_adapter_without_windows_builds_the_parents_programs():
             np.int32(3), np.int32(10), out[2], out[4])),
     )
     assert got == PARENT
+
+
+def test_gpt2s_decode_step_on_the_kernel_is_the_parents(monkeypatch):
+    """``GPT2Server`` reads its whole table (``layer_cache_rows`` without
+    ``live``; ``guards_global_read`` False): on the paged kernel too its
+    decode step is the jaxpr from before the K/V adapters' global read had
+    a guard (sha256 computed on the parent commit's ``git archive``)."""
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    cfg = GPT2Config(vocab_size=64, n_layer=2, n_head=2, d_model=32,
+                     max_seq=64)
+    tree = GPT2(cfg).init(jax.random.PRNGKey(0),
+                          jnp.zeros((1, 8), jnp.int32))
+    server = GPT2Server(cfg, tree, ServeConfig(
+        page_tokens=8, max_batch=2, max_pages=8, max_seq=32, ship_depth=2))
+    assert not server.guards_global_read
+    assert serving_guard.decode_step_sha(server) == "9bf94dc333d9f7d6"
 
 
 def test_a_long_prompt_through_the_kernel_serves_the_loops_tokens(

@@ -31,9 +31,9 @@ before there were classes.
 model is a subclass in a module of its own (``gpt2.py``, ``latent.py``,
 ``hybrid.py``, ``window.py``) that imports this module, ``models/`` and
 ``ops/`` and nothing above. Beside it, what a ``decode_forward`` is made of:
-the lane's masks (:func:`lane_masks`, :func:`ring_masks`,
-:func:`ring_live`), a layer's cache as its attention contracts it
-(:func:`layer_cache_rows`), and the K/V attention over both
+the lane's masks (:func:`lane_masks`, :func:`page_live`,
+:func:`ring_masks`, :func:`ring_live`), a layer's cache as its attention
+contracts it (:func:`layer_cache_rows`), and the K/V attention over both
 (:func:`attend_paged`).
 """
 
@@ -164,6 +164,19 @@ def lane_masks(serve: ServeConfig, state):
     return tail_idx, mask_c, mask_t
 
 
+def page_live(serve: ServeConfig, state):
+    """:func:`lane_masks`' ``mask_c`` by page, ``(B, pages_per_seq) bool``:
+    the slots of a lane's ``page_table`` row that hold a committed page, its
+    first ``n_pages``. A dead slot is one the lane has not reached (a
+    vacated lane's whole row); the read of a global K/V layer neither
+    fetches nor decodes it (:func:`attend_paged`). A step's sum over the
+    held lanes is what the host counts as
+    ``cgx.serve.kv.decoded_pages.global``."""
+    b = state["tokens"].shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (b, serve.pages_per_seq), 1)
+    return slot < state["n_pages"][:, None]
+
+
 def ring_pages(serve: ServeConfig, window: int) -> int:
     """Pool rows a sequence's ring holds on a layer of window ``window``:
     the pages that can hold a visible key while the tail fills, and the one
@@ -222,8 +235,8 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
     page_tokens, width)}``, both in ``dtype``, ``{stream: the new float32
     tail})``. ``window``: the layer's pages are the lane's ring, ``P`` its
     slots, in the ring's order (a softmax does not care). ``live (B, P)
-    bool``: the table's entries the read decodes (:func:`ring_live`), the
-    others' rows zeros; None reads every entry."""
+    bool``: the table's entries the read decodes (:func:`page_live`,
+    :func:`ring_live`), the others' rows zeros; None reads every entry."""
     table = state["ring_table" if window else "page_table"]
     pages, tails, new = {}, {}, {}
     for (name, spec), value in zip(layer_streams, fresh):
@@ -246,9 +259,13 @@ def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
     (:func:`layer_cache_rows`), one ``decode_attention`` of ``q (B, 1, H,
     dh)`` over pages and tail. ``masks`` are :func:`lane_masks`' (with
     ``window``, the layer's pages are the lane's ring and the page mask
-    :func:`ring_masks`', and ``live`` its slots that hold a visible key,
-    :func:`ring_live`'s: the read skips the others). Returns ``(o (B, H *
-    dh), {stream: its new tail})``."""
+    :func:`ring_masks`'). ``live`` is the page mask by table entry, built
+    once a step and shared by the layers of a class and both streams: a
+    global layer's :func:`page_live`, a window layer's :func:`ring_live`.
+    The read skips the entries it leaves out, whose rows the mask hides
+    anyway (an adapter that hands it down says so:
+    ``Adapter.guards_global_read``). Returns ``(o (B, H * dh), {stream: its
+    new tail})``."""
     tail_idx, mask_c, mask_t = masks
     pages, tails, new = layer_cache_rows(
         state, layer, layer_streams, tail_idx, (k, v), dt, window, live
@@ -278,6 +295,12 @@ class Adapter:
     ``step_counters``
         names under ``cgx.serve.`` of what ``decode_forward`` counts each
         step (empty for a model that counts nothing).
+    ``guards_global_read``
+        whether ``decode_forward`` hands the read of its global layers the
+        lane's committed pages as a guard (:func:`page_live`, through
+        :func:`attend_paged`): the scheduler then counts what the read
+        decodes beside what its table holds
+        (``cgx.serve.kv.decoded_pages.global``, ``.table_pages.global``).
     ``layer_name(l)``
         the layer's ``kv_page`` edge name.
     ``cache_streams(l)``
@@ -319,6 +342,7 @@ class Adapter:
 
     kind: str
     step_counters: Tuple[str, ...] = ()
+    guards_global_read: bool = False
 
     def __init__(self, model_cfg, params,
                  serve: Optional[ServeConfig] = None):

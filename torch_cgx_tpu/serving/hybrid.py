@@ -27,9 +27,13 @@ at the prompt's last token:
 
 ``state_streams`` states them; the scheduler carries them in the donated
 state beside pools and tails. What an attention layer's decode position does
-is the same in both (``adapter.lane_masks``, ``adapter.attend_paged``): the
-token's ``k`` and ``v`` into the raw tail, the committed pages read where
-they lie, one ``decode_attention`` over both.
+is the same in both (``adapter.lane_masks``, ``adapter.page_live``,
+``adapter.attend_paged``): the token's ``k`` and ``v`` into the raw tail, the
+pages the lane has committed read where they lie (a slot of its table it has
+not reached is neither fetched nor decoded), one ``decode_attention`` over
+both. Ling's latent layer reads its whole table (``layer_cache_rows`` without
+a guard: its ``kr`` stream takes the gathered lowering, where a guard is a
+``where`` and saves nothing).
 
 Page geometry is the streams' arithmetic (``serving/latent.py`` says the
 same of its own). granite-4.0-h-micro at 256 tokens a page and bucket 512:
@@ -64,6 +68,7 @@ from .adapter import (
     attend_paged,
     lane_masks,
     layer_cache_rows,
+    page_live,
     page_specs,
 )
 
@@ -102,6 +107,7 @@ class HybridSSMServer(_HybridAdapter):
     ``conv`` and ``ssm`` on the Mamba layers."""
 
     kind = "hybrid_ssm"
+    guards_global_read = True
 
     def state_streams(self, layer: int):
         cfg = self.cfg
@@ -147,15 +153,16 @@ class HybridSSMServer(_HybridAdapter):
                 out["conv"], out["ssm"])
 
     def decode_forward(self, state, streams):
-        """One decode position: an attention layer reads its committed
-        pages (``cfg.dtype`` rows, contracted where they lie) and, apart,
-        its raw tail with this token's K and V appended; a Mamba layer
-        takes one step of its recurrence and hands back its state,
-        rewritten. Returns (logits (B, V), the new tails and states by
-        stream, None)."""
+        """One decode position: an attention layer reads the pages its
+        lane has committed (``adapter.page_live``; ``cfg.dtype`` rows,
+        contracted where they lie) and, apart, its raw tail with this
+        token's K and V appended; a Mamba layer takes one step of its
+        recurrence and hands back its state, rewritten. Returns (logits (B,
+        V), the new tails and states by stream, None)."""
         cfg, dt = self.cfg, self.cfg.dtype
         x = gh.embed(cfg, self.p, state["tokens"][:, None])[:, 0]  # (B, D)
         masks = lane_masks(self.serve, state)
+        live = page_live(self.serve, state)
         new = {name: [None] * cfg.n_layer for name in ("k", "v", "conv",
                                                        "ssm")}
         for layer, kind in enumerate(cfg.layer_types):
@@ -170,7 +177,7 @@ class HybridSSMServer(_HybridAdapter):
                 q, k, v = gh.attn_project(cfg, y[:, None], pl["attn"])
                 o, tails = attend_paged(
                     state, layer, streams[layer], masks, q, k, v, dt,
-                    1.0 / cfg.attention_multiplier,
+                    1.0 / cfg.attention_multiplier, live=live,
                 )
                 for name, tail in tails.items():
                     new[name][layer] = tail
@@ -184,6 +191,7 @@ class HybridGDNServer(_HybridAdapter):
     streams ``conv`` and ``gdn`` on the gated delta-rule layers."""
 
     kind = "hybrid_gdn"
+    guards_global_read = True
 
     def state_streams(self, layer: int):
         cfg = self.cfg
@@ -228,14 +236,16 @@ class HybridGDNServer(_HybridAdapter):
                 out["conv"], out["gdn"])
 
     def decode_forward(self, state, streams):
-        """One decode position: a full-attention layer reads its committed
-        pages and, apart, its raw tail with this token's K and V appended
-        (``adapter.attend_paged``); a delta-rule layer takes one step of its
-        recurrence and hands back its state, rewritten. Returns (logits (B,
-        V), the new tails and states by stream, None)."""
+        """One decode position: a full-attention layer reads the pages its
+        lane has committed (``adapter.page_live``) and, apart, its raw tail
+        with this token's K and V appended (``adapter.attend_paged``); a
+        delta-rule layer takes one step of its recurrence and hands back its
+        state, rewritten. Returns (logits (B, V), the new tails and states
+        by stream, None)."""
         cfg, dt = self.cfg, self.cfg.dtype
         x = oh.embed(cfg, self.p, state["tokens"])  # (B, D)
         masks = lane_masks(self.serve, state)
+        live = page_live(self.serve, state)
         new = {name: [None] * cfg.n_layer for name in ("k", "v", "conv",
                                                        "gdn")}
         for layer, kind in enumerate(cfg.layer_types):
@@ -249,7 +259,7 @@ class HybridGDNServer(_HybridAdapter):
                 q, k, v = oh.attn_project(cfg, x[:, None], pl["attn"])
                 o, tails = attend_paged(
                     state, layer, streams[layer], masks, q, k, v, dt,
-                    np.sqrt(cfg.d_head),
+                    np.sqrt(cfg.d_head), live=live,
                 )
                 for name, tail in tails.items():
                     new[name][layer] = tail

@@ -316,9 +316,9 @@ class ContinuousBatchScheduler:
         # for a free lane).
         self._tail_len = np.zeros((sv.max_batch,), np.int64)
         self._left = np.zeros((sv.max_batch,), np.int64)
-        # With window layers: the state's ``n_pages`` a lane, counted here
-        # like the tail lengths (the ring slot a commit writes, the live
-        # pages a step reads), and the lane's ring.
+        # The state's ``n_pages`` a lane, counted here like the tail lengths
+        # (the live pages a step reads, the ring slot a commit writes), and
+        # with window layers the lane's ring.
         self._n_pages = np.zeros((sv.max_batch,), np.int64)
         self._ring_of = np.zeros((sv.max_batch,), np.int64)
         # Dispatched and unread, in device order: lane writes whose first
@@ -943,13 +943,13 @@ class ContinuousBatchScheduler:
             table_row[: len(ready.page_ids)] = ready.page_ids
             first = ready.first_token
             n_full, ring = len(ready.page_ids), self._prog.ring
+            self._n_pages[lane] = n_full
             ring_row = ()
             if ring:  # the slots the prefill wrote (``_ring_rows``)
                 pages = np.arange(max(n_full - ring, 0), n_full)
                 ring_row = np.full((ring,), -1, np.int32)
                 ring_row[pages % ring] = self._slot_rows(ready.ring, pages)
                 ring_row = (ring_row,)
-                self._n_pages[lane] = n_full
                 self._ring_of[lane] = ready.ring
             self._state = self._prog.admit_lane(
                 self._state, np.int32(lane), table_row,
@@ -1150,7 +1150,7 @@ class ContinuousBatchScheduler:
             )
             self._fed()
         held = [i for i, r in enumerate(self._lanes) if r is not None]
-        if self._prog.ring:
+        if self._prog.ring or self.server.guards_global_read:
             self._note_live_pages(held)
         self._tail_len[held] += 1
         lanes = {i: self._lanes[i] for i in held if self._left[i] > 0}
@@ -1163,16 +1163,25 @@ class ContinuousBatchScheduler:
         the host's own counts: every committed page of a global layer; of a
         window layer those from the page that holds the oldest position the
         lane's token (at ``n_pages * page_tokens + tail_len``) can see. Those
-        are the slots the step's read leaves open (``adapter.ring_live`` on the
-        device), counted again as ``kv.decoded_pages.window``: what the
-        window read decodes, which was every slot of every lane's ring
-        before the read had a guard."""
-        pt, window = self.server.serve.page_tokens, self._prog.window
+        are the slots the step's read leaves open (``adapter.page_live`` and
+        ``adapter.ring_live`` on the device), counted again as
+        ``kv.decoded_pages.<class>``: what the class's read decodes, which
+        was every slot of every lane's table (``kv.table_pages.global``;
+        every lane's ring) before the read had a guard."""
+        sv = self.server.serve
         n_pages = self._n_pages[held]
+        committed = float(n_pages.sum())
+        metrics.add("cgx.serve.kv.live_pages.global", committed)
+        if self.server.guards_global_read:
+            metrics.add("cgx.serve.kv.decoded_pages.global", committed)
+            metrics.add("cgx.serve.kv.table_pages.global",
+                        float(sv.max_batch * sv.pages_per_seq))
+        if not self._prog.ring:
+            return
+        pt, window = sv.page_tokens, self._prog.window
         oldest = np.maximum(
             n_pages * pt + self._tail_len[held] - window + 1, 0) // pt
         live = float(np.maximum(n_pages - oldest, 0).sum())
-        metrics.add("cgx.serve.kv.live_pages.global", float(n_pages.sum()))
         metrics.add("cgx.serve.kv.live_pages.window", live)
         metrics.add("cgx.serve.kv.decoded_pages.window", live)
 
@@ -1253,11 +1262,11 @@ class ContinuousBatchScheduler:
         if self._prog.ring:
             # A page written over one that slid out: the ring had turned.
             recycled = int((self._n_pages[committed] >= self._prog.ring).sum())
-            self._n_pages[committed] += 1
             metrics.add("cgx.serve.window.pages_committed",
                         float(of_window * len(committed)))
             metrics.add("cgx.serve.window.pages_recycled",
                         float(of_window * recycled))
+        self._n_pages[committed] += 1
 
     def _ring_slots(self, lanes: List[int], pad: int):
         """``commit``'s last operand for a model with window layers

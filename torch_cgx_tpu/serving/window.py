@@ -42,6 +42,7 @@ from .adapter import (
     Adapter,
     attend_paged,
     lane_masks,
+    page_live,
     page_specs,
     ring_live,
     ring_masks,
@@ -56,6 +57,7 @@ class WindowMoEServer(Adapter):
     # What a decode step counts over its expert layers, as
     # ``cgx.serve.<name>``: ``moe.STATS`` in order.
     step_counters = tuple(f"moe.{name}" for name in moe.STATS)
+    guards_global_read = True
 
     def cache_streams(self, layer: int):
         (spec,) = page_specs(self.layer_name(layer), self.serve.page_tokens,
@@ -88,10 +90,12 @@ class WindowMoEServer(Adapter):
         return wm.logits(cfg, self.p, x_last)[:, -1], ks, vs
 
     def _masks(self, state):
-        """What a decode step's layers share: :func:`lane_masks`' three and,
-        where the model has window layers, the ring's row mask and its live
-        slots (one of each a step, for every window layer's two streams)."""
-        shared = lane_masks(self.serve, state)
+        """What a decode step's layers share: :func:`lane_masks`' three, the
+        page table's live slots and, where the model has window layers, the
+        ring's row mask and its live slots (one of each a step, for every
+        layer of the class and both streams)."""
+        shared = lane_masks(self.serve, state) + (
+            page_live(self.serve, state),)
         window = max(self.cfg.windows)
         if not window:
             return shared + (None, None)
@@ -101,20 +105,21 @@ class WindowMoEServer(Adapter):
     def _attend(self, state, streams, masks, layer, q, k, v):
         """One decode position of ``layer`` over the lane's cache
         (:func:`attend_paged`): a global layer over the page table, a window
-        layer over its ring, of which the read takes the live slots."""
-        tail_idx, mask_c, mask_t, mask_r, live_r = masks
+        layer over its ring; either read takes its table's live slots."""
+        tail_idx, mask_c, mask_t, live_c, mask_r, live_r = masks
         ringed = bool(self.cfg.windows[layer])
         return attend_paged(
             state, layer, streams[layer],
             (tail_idx, mask_r if ringed else mask_c, mask_t), q, k, v,
             self.cfg.dtype, np.sqrt(self.cfg.d_head), window=ringed,
-            live=live_r if ringed else None,
+            live=live_r if ringed else live_c,
         )
 
     def decode_forward(self, state, streams):
         """One decode position: this token's ``k`` and ``v`` into the raw
         tails, a global layer's committed pages read through the page table
-        and a window layer's through the ring, one ``decode_attention`` over
+        and a window layer's through the ring (either table's live slots
+        alone: ``page_live``, ``ring_live``), one ``decode_attention`` over
         pages and tail under the class's mask (:meth:`_attend`). Returns
         ``(logits (B, V), the new tails by stream, moe.STATS summed over the
         layers (``load_max`` their largest) counted over the active
