@@ -1419,6 +1419,31 @@ def _cell_cases():
         yield f"trinity-kv-commit-{rows}", "quantize", dict(
             bits=8, rows=rows, numel=256 * 8 * 128,
         ), {"quantize": flat}, {"_pipe_tc": 16}
+    # ISSUE 48: xing4-serve-doc16k. ``c`` and ``kr`` of all six layers at the
+    # JoyAI geometry, read through a (32, 69) page table over a pool of 2,209
+    # rows: ``c`` paged, two pages a grid step; the 64-wide ``kr`` keeps the
+    # gather and XLA's reshape.
+    xing = dict(bits=8, rows=32 * 69, out_dtype=jnp.bfloat16, lanes=32,
+                pool=2209)
+    yield "xing4-decode-pages-c", "dequantize_pages", dict(
+        xing, page=(256, 1, 512),
+    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    yield "xing4-decode-pages-kr", "dequantize_pages", dict(
+        xing, page=(256, 1, 64),
+    ), {"dequantize_pages": "xla_gather", "dequantize": "pallas_flat.bfloat16",
+        "dequantize_rows": "xla_reshape"}, {"_pages_tc": None, "_pipe_tc": 16}
+    # Its commits: the tails that filled in the decode loop (4 of the 32
+    # lanes a call), a padded prompt's 32 or 64 pages in prefill_pages (8,192
+    # and 16,384 tokens).
+    tails = _commit_lanes(32, 256)
+    for name, numel, commits in (
+        ("xing4-c", _JOYAI_C, {tails: 16, 32: 16, 64: 16}),
+        ("xing4-kr", _JOYAI_KR, {tails: 4, 32: 16, 64: 16}),
+    ):
+        for rows, tc in commits.items():
+            yield f"{name}-commit-{rows}", "quantize", dict(
+                bits=8, rows=rows, numel=numel,
+            ), {"quantize": flat}, {"_pipe_tc": tc}
     # Page commits: the tails that filled in the decode loop (ISSUE 34: 4 of
     # the 32 lanes a call, where every lane's 32 rows were quantized), a
     # padded prompt's pages in prefill_pages (704 and 896 tokens; 2,048 and

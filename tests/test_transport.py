@@ -493,7 +493,10 @@ def test_two_hosts_liveness_regression(tmp_path):
     # Host A's real heartbeat publishes through the store.
     hb.attach_store(str(host_a), store)
     pid_a = os.getpid()
-    judge = hb.RemoteLiveness(store, stale_s=0.3)
+    # Host A's ticker is a thread of this process: under six loaded test
+    # workers it has gone 0.3 s without a tick (ROADMAP C9), so the judge
+    # allows it a second; what is judged is the same.
+    judge = hb.RemoteLiveness(store, stale_s=1.0)
     judge.observe([pid_a, pid_b])
     for _ in range(5):
         time.sleep(0.1)

@@ -1,12 +1,25 @@
 """Latent-attention (MLA) decoder with a dropless sigmoid-routed expert
-layer: the DeepSeek-V3 family's block, as JoyAI-LLM-Flash publishes it.
+layer: the DeepSeek-V3 family's block, as JoyAI-LLM-Flash publishes it and,
+with YaRN positions and hyper-connected residual streams, Xing4.0-29B-A4B.
 
 Plain functions over a plain parameter tree (no flax module: the serving
 plane needs each layer's cache streams in and out, which a module hides).
-Block ``l``::
+Block ``l``, one residual stream::
 
     h  = x + Attn(RMSNorm(x))
     x' = h + FFN_l(RMSNorm(h))          FFN_0 dense SwiGLU, FFN_l>0 experts
+
+With ``cfg.hc_mult = n`` streams a token (``models/mhc.py`` has the
+equations) the embedding is repeated into ``X (n, D)`` and each of the two
+sublayers reads one mix of the streams and writes into a mix of them::
+
+    u, H_post, H_res = pre(X; hc)       hc = hc_attn, then hc_ffn
+    y     = F(RMSNorm(u))               F = Attn, then FFN_l
+    X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y
+
+and the final norm takes ``read_out(X; hc_head)``. The block is written
+once, in ``serving/latent.py`` (``_enter`` / ``_leave``); the functions
+here take what a sublayer reads and return what it writes, whichever it is.
 
 **MLA.** ``c_q = RMSNorm(W_qa x)``; ``q = W_qb c_q`` (``q = W_q x`` for a
 layer whose tree holds a full-rank ``q``) split per head into
@@ -22,12 +35,22 @@ held);
 (decode: no per-head key or value of a cached token is ever rebuilt). Both
 are the same mathematics.
 
+**Positions.** Pair ``i`` of the ``d_rope / 2`` turns by ``position *
+theta**(-2i / d_rope)``. Under ``cfg.yarn`` (:class:`Yarn`, a published
+``rope_scaling`` of type ``yarn``, as the family computes it) the pairs that
+turn fewer than ``beta_slow`` times over the original positions turn
+``factor`` times slower, those that turn more than ``beta_fast`` times are
+kept, a linear ramp between; cosines and sines times ``mscale(factor,
+mscale) / mscale(factor, mscale_all_dim)`` and the scores' scale times
+``mscale(factor, mscale_all_dim)**2``, ``mscale(f, m) = 0.1 m ln f + 1``.
+
 **Experts.** ``s = sigmoid(W_g y)`` in float32; the ``top_k`` experts of
 largest ``s + b`` (``b`` the selection bias); weights ``s_i / sum_chosen s``
 times ``routed_scale``; ``sum w_i E_i(y) + E_shared(y)`` through
 ``parallel.moe.dropless_moe``. No token is dropped.
 
-Parameter tree (weights in ``cfg.dtype``, norms, router and bias float32)::
+Parameter tree (weights in ``cfg.dtype``; norms, router, bias and the
+hyper-connections' leaves float32)::
 
     embed (V, D)   head (D, V)   norm_f (D,)
     layer_<i>/attn_norm, ffn_norm (D,)
@@ -37,12 +60,16 @@ Parameter tree (weights in ``cfg.dtype``, norms, router and bias float32)::
     layer_0/mlp/{gate (D, F), up (D, F), down (F, D)}
     layer_<i>/moe/{router (D, E), bias (E,), gate (E, D, Fe), up (E, D, Fe),
                    down (E, Fe, D), shared/{gate, up, down}}
+    with hc_mult = n:
+    layer_<i>/hc_attn, hc_ffn/{phi (nD, 2n+n*n), alpha (3,), base (2n+n*n,)}
+    hc_head/{phi (nD, n), alpha (1,), base (n,)}
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+import math
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +78,51 @@ import numpy as np
 from ..ops import dispatch
 from ..parallel import moe
 from .attention import joined_softmax
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's numbers, as a published ``rope_scaling`` of type ``yarn``
+    gives them."""
+
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    def frequency_scale(self, d: int, theta: float):
+        """What each of the ``d / 2`` pairs' frequency ``theta**(-2i/d)`` is
+        multiplied by, float64: 1 for the pairs that turn more than
+        ``beta_fast`` times over the original positions (below the first
+        correction dim), ``1 / factor`` for those that turn less than
+        ``beta_slow`` times (above the second), a linear ramp between."""
+        def correction_dim(turns):
+            return (d * math.log(self.original_positions
+                                 / (turns * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), d - 1)
+        ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                       / max(high - low, 0.001), 0.0, 1.0)
+        return 1.0 - ramp * (1.0 - 1.0 / self.factor)
+
+    @property
+    def rotation_scale(self) -> float:
+        """What the cosines and sines are multiplied by."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the scores' ``1 / sqrt(d)`` is multiplied by."""
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,10 +147,41 @@ class MlaMoeConfig:
     eps: float = 1e-6
     q_block: int = 512  # queries a block of the prefill's attention
     dtype: Any = jnp.bfloat16
+    yarn: Optional[Yarn] = None  # position scaling; None: plain rotation
+    # Hyper-connections (``models/mhc.py``); 0 streams: one residual.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     @classmethod
     def from_hf(cls, c: dict, **kw) -> "MlaMoeConfig":
-        """From the keys of a published ``config.json``."""
+        """From the keys of a published ``config.json``. A ``rope_scaling``
+        of a type that is not computed here is refused, not ignored."""
+        found = {}
+        scaling = c.get("rope_scaling")
+        if scaling is not None:
+            if scaling.get("type") != "yarn":
+                raise ValueError(
+                    f"rope_scaling type {scaling.get('type')!r} is not "
+                    "computed here (yarn is)"
+                )
+            found["yarn"] = Yarn(
+                factor=float(scaling["factor"]),
+                original_positions=int(
+                    scaling["original_max_position_embeddings"]),
+                **{k: float(scaling[k])
+                   for k in ("beta_fast", "beta_slow", "mscale",
+                             "mscale_all_dim") if k in scaling},
+            )
+        if c.get("hc_mult"):
+            found.update(
+                hc_mult=c["hc_mult"],
+                hc_sinkhorn_iters=c["hc_sinkhorn_iters"], hc_eps=c["hc_eps"],
+                hc_clamp=(float(c["mhc_h_res_clamp_min"]),
+                          float(c["mhc_h_res_clamp_max"])),
+            )
+        kw = {**found, **kw}
         return cls(
             vocab_size=c["vocab_size"], n_layer=c["num_hidden_layers"],
             d_model=c["hidden_size"], n_head=c["num_attention_heads"],
@@ -110,16 +213,22 @@ def rms_norm(x, w, eps):
     ) * w.astype(jnp.float32)
 
 
-def rope(x, positions, theta):
+def rope(x, positions, theta, yarn: Optional[Yarn] = None):
     """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the last axis by
-    ``positions * theta**(-2i/d)``. ``x (..., S, [H,] d)``, ``positions``
-    broadcastable to ``x``'s leading axes up to ``S``; float32 out."""
+    ``positions * theta**(-2i/d)``, the frequencies and the rotation scaled
+    as ``yarn`` says (:class:`Yarn`; a factor of 1 is the plain rotation to
+    the bit). ``x (..., S, [H,] d)``, ``positions`` broadcastable to ``x``'s
+    leading axes up to ``S``; float32 out."""
     d = x.shape[-1]
     inv = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if yarn is not None:
+        inv = inv * yarn.frequency_scale(d, theta)
     ang = positions[..., None].astype(jnp.float32) * inv.astype(np.float32)
     if x.ndim == ang.ndim + 1:  # a head axis between S and d
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None and yarn.rotation_scale != 1.0:
+        cos, sin = cos * yarn.rotation_scale, sin * yarn.rotation_scale
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., 0::2], xf[..., 1::2]
     return jnp.stack(
@@ -152,8 +261,9 @@ def mla_project(cfg: MlaMoeConfig, x, pa, positions):
     q_nope, q_rope = q[..., : cfg.d_nope], q[..., cfg.d_nope:]
     kv = _mm(x, pa["kv_a"], dt)
     c = rms_norm(kv[..., : cfg.kv_lora_rank], pa["kv_a_norm"], cfg.eps)
-    k_r = rope(kv[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)
-    q_rope = rope(q_rope, positions, cfg.rope_theta).astype(dt)
+    yarn = getattr(cfg, "yarn", None)  # a config without the field: none
+    k_r = rope(kv[..., cfg.kv_lora_rank:], positions, cfg.rope_theta, yarn)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, yarn).astype(dt)
     return q_nope, q_rope, c, k_r
 
 
@@ -167,7 +277,11 @@ def _kv_b_heads(cfg: MlaMoeConfig, pa):
 
 
 def _softmax_scale(cfg: MlaMoeConfig):
-    return np.float32(1.0 / np.sqrt(cfg.d_nope + cfg.d_rope))
+    """``1 / sqrt(d_nope + d_rope)``, times YaRN's ``mscale`` squared where
+    the config scales positions."""
+    yarn = getattr(cfg, "yarn", None)
+    scale = 1.0 / np.sqrt(cfg.d_nope + cfg.d_rope)
+    return np.float32(scale if yarn is None else scale * yarn.softmax_scale)
 
 
 def attend_expanded(cfg: MlaMoeConfig, pa, q_nope, q_rope, c, k_r):

@@ -16,7 +16,7 @@ from jax import lax
 
 from .. import config as cfg_mod
 from ..config import CompressionConfig
-from . import codec, codec_pallas, gdn, grouped_matmul as gmm
+from . import codec, codec_pallas, gdn, grouped_matmul as gmm, mhc
 from . import prefill_attention as pfa, ssm
 
 
@@ -390,6 +390,27 @@ def kda_update(state, q, k, v, alpha, beta):
     ``cgx.codec.lowering.kda_update.pallas`` / ``.xla``."""
     return _delta_rule_update("kda_update", "cgx_kda_update", state, q, k, v,
                               alpha, beta)
+
+
+def mhc_pre(x, phi, alpha, base, *, kernel: str, phi_t=None, **how):
+    """What a hyper-connection computes in front of its sublayer, a token's
+    flattened streams a row of ``x`` (``ops/mhc.py``; ``how`` is its
+    keywords; ``phi_t`` is ``mhc.kernel_phi(phi)`` where the caller keeps it
+    beside ``phi``, made here without). Where :func:`mhc.token_tile` gives the tokens a tile, the
+    kernel named ``kernel`` (``cgx_mhc_pre_decode`` in a decode step,
+    ``cgx_mhc_pre_prefill`` in a prefill: a trace tells a call over a
+    step's lanes from one over a prompt) on the TPU (and, interpreted,
+    wherever ``CGX_CODEC_IMPL=pallas`` asks for the kernels); the
+    ``jax.numpy`` form elsewhere; counted per call site as
+    ``cgx.codec.lowering.mhc_pre.pallas`` / ``.xla``."""
+    if _kernels() and mhc.token_tile(x.shape[0]) is not None:
+        codec_pallas.note_lowering("mhc_pre", "pallas")
+        return mhc.mhc_pre_pallas(
+            x, mhc.kernel_phi(phi) if phi_t is None else phi_t, alpha, base,
+            name=kernel, interpret=not _on_tpu(), **how
+        )
+    codec_pallas.note_lowering("mhc_pre", "xla")
+    return mhc.mhc_pre_xla(x, phi, alpha, base, **how)
 
 
 def grouped_matmul(lhs, rhs, sizes):

@@ -16,6 +16,14 @@ and bucket 512, a ``c`` page of a 512-wide latent is 256 buckets (eight
 chunks, one bucket a token) and a ``kr`` page of a 64-wide key is 32
 buckets (one chunk, eight tokens a bucket).
 
+A config with ``hc_mult`` carries that many residual streams a token,
+``(B, S, n, D)``, mixed around every sublayer and read out in front of the
+final norm (``models/mhc.py``: the tree then holds ``layer_<i>/hc_attn``,
+``hc_ffn`` and ``hc_head``); what a sublayer reads and what it leaves is
+:meth:`LatentMoEServer._enter` and :meth:`~LatentMoEServer._leave`, and a
+config without it is the one stream and the program it always was.
+Positions are rotated as the config's ``yarn`` says (``mla_moe.rope``).
+
 The disaggregated path ships K and V frames and refuses this adapter
 (``transport.require_kv_streams``); it is served with local prefill.
 """
@@ -23,8 +31,9 @@ The disaggregated path ships K and V frames and refuses this adapter
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
-from ..models import mla_moe
+from ..models import mhc, mla_moe
 from ..parallel import moe
 from .adapter import Adapter, lane_masks, layer_cache_rows, page_specs
 
@@ -38,6 +47,17 @@ class LatentMoEServer(Adapter):
     # ``cgx.serve.<name>``: ``moe.STATS`` in order.
     step_counters = tuple(f"moe.{name}" for name in moe.STATS)
 
+    def __init__(self, model_cfg, params, serve=None):
+        super().__init__(model_cfg, params, serve)
+        if model_cfg.hc_mult:
+            # Beside the expert layers' counts, how far the step's
+            # stream-to-stream mixes were from doubly stochastic.
+            self.step_counters += ("mhc.res_err_ppm",)
+            # ``phi`` as the kernel reads it, made once: the tree is the
+            # programs' argument, and what a program computes from a weight
+            # it computes in every call.
+            self.p = mhc.with_kernel_phi(params)
+
     def cache_streams(self, layer: int):
         c, kr = page_specs(
             self.layer_name(layer), self.serve.page_tokens,
@@ -47,15 +67,50 @@ class LatentMoEServer(Adapter):
 
     # -- forwards ----------------------------------------------------------
 
-    def _block_tail(self, x, pl, attn_out, count_mask=None):
+    def _enter(self, x, hc, norm_w, kernel, errors=None, count_mask=None):
+        """What a sublayer reads, normed, and what :meth:`_leave` needs to
+        write its output back: of one residual stream ``x (B, S, D)``,
+        itself; of hyper-connected streams ``x (B, S, n, D)``
+        (``models/mhc.py``), their ``pre`` mix and the two mixes of the way
+        back, under the kernel name ``kernel``. ``errors`` collects
+        ``mhc.res_error`` over ``count_mask``'s lanes."""
+        cfg = self.cfg
+        if not cfg.hc_mult:
+            return mla_moe.rms_norm(x, norm_w, cfg.eps), x
+        u, h_post, h_res = mhc.pre(cfg, x, hc, kernel)
+        if errors is not None:
+            errors.append(mhc.res_error(h_res, cfg.hc_mult, count_mask))
+        return mla_moe.rms_norm(u, norm_w, cfg.eps), (x, h_post, h_res)
+
+    @staticmethod
+    def _leave(carry, out):
+        """The residual: ``x + out``, or the streams mixed and ``out``
+        spread over them."""
+        if isinstance(carry, tuple):
+            return mhc.mix(carry[0], out, *carry[1:])
+        return carry + out
+
+    def _embed(self, tokens):
+        x = mla_moe.embed(self.cfg, self.p, tokens)
+        return mhc.spread(x, self.cfg.hc_mult) if self.cfg.hc_mult else x
+
+    def _logits(self, x, kernel):
+        """Final norm and head over ``x (B, S, D)`` or, read out first, the
+        streams ``(B, S, n, D)``."""
+        if self.cfg.hc_mult:
+            x = mhc.read_out(self.cfg, x, self.p["hc_head"], kernel)
+        return mla_moe.logits(self.cfg, self.p, x)
+
+    def _block_tail(self, carry, pl, attn_out, kernel, errors=None,
+                    count_mask=None):
         """Output projection, residual, then the layer's feed-forward."""
         cfg = self.cfg
-        x = x + mla_moe._mm(attn_out, pl["attn"]["o"], cfg.dtype)
-        out, stats = mla_moe.ffn(
-            cfg, pl, mla_moe.rms_norm(x, pl["ffn_norm"], cfg.eps),
-            count_mask=count_mask,
-        )
-        return x + out, stats
+        x = self._leave(
+            carry, mla_moe._mm(attn_out, pl["attn"]["o"], cfg.dtype))
+        y, carry = self._enter(x, pl.get("hc_ffn"), pl["ffn_norm"], kernel,
+                               errors, count_mask)
+        out, stats = mla_moe.ffn(cfg, pl, y, count_mask=count_mask)
+        return self._leave(carry, out), stats
 
     def prefill_forward(self, tokens, positions, last_idx):
         """Full causal forward over a (right-padded) prompt: the logits at
@@ -63,12 +118,13 @@ class LatentMoEServer(Adapter):
         S, 1, dr)`` f32. Right-padding is inert for every real position
         under the causal mask; a padded token does go through the experts
         (dropless: it takes no real token's place)."""
-        cfg = self.cfg
-        x = mla_moe.embed(cfg, self.p, tokens)
+        cfg, kernel = self.cfg, mhc.PREFILL_KERNEL
+        x = self._embed(tokens)
         cs, krs = [], []
         for layer in range(cfg.n_layer):
             pl = self.p[f"layer_{layer}"]
-            y = mla_moe.rms_norm(x, pl["attn_norm"], cfg.eps)
+            y, carry = self._enter(x, pl.get("hc_attn"), pl["attn_norm"],
+                                   kernel)
             q_nope, q_rope, c, k_r = mla_moe.mla_project(
                 cfg, y, pl["attn"], positions
             )
@@ -77,24 +133,27 @@ class LatentMoEServer(Adapter):
             o = mla_moe.attend_expanded(
                 cfg, pl["attn"], q_nope, q_rope, c, k_r
             )
-            x, _ = self._block_tail(x, pl, o)
+            x, _ = self._block_tail(carry, pl, o, kernel)
         x_last = jax.lax.dynamic_index_in_dim(x, last_idx, 1)
-        return mla_moe.logits(cfg, self.p, x_last)[:, -1], cs, krs
+        return self._logits(x_last, kernel)[:, -1], cs, krs
 
     def decode_forward(self, state, streams):
         """One decode position against the paged latent cache: ``(logits
         (B, V), the new tails by stream, moe.STATS summed over the expert
         layers (``load_max`` their largest) counted over the active
-        lanes)``."""
-        cfg, dt = self.cfg, self.cfg.dtype
-        x = mla_moe.embed(cfg, self.p, state["tokens"][:, None])
+        lanes)``; a hyper-connected model's counts end with the step's
+        largest ``mhc.res_error`` over the active lanes, in millionths."""
+        cfg, dt, kernel = self.cfg, self.cfg.dtype, mhc.DECODE_KERNEL
+        active = state["active"]
+        x = self._embed(state["tokens"][:, None])
         positions = state["pos"][:, None]
         tail_idx, mask_c, mask_t = lane_masks(self.serve, state)
         new_tails = {"c": [], "kr": []}
-        counts = []
+        counts, errors = [], []
         for layer in range(cfg.n_layer):
             pl = self.p[f"layer_{layer}"]
-            y = mla_moe.rms_norm(x, pl["attn_norm"], cfg.eps)
+            y, carry = self._enter(x, pl.get("hc_attn"), pl["attn_norm"],
+                                   kernel, errors, active)
             q_nope, q_rope, c, k_r = mla_moe.mla_project(
                 cfg, y, pl["attn"], positions
             )
@@ -108,9 +167,13 @@ class LatentMoEServer(Adapter):
                 pages["kr"], mask_c, tail=(tails["c"], tails["kr"], mask_t),
             )
             x, stats = self._block_tail(
-                x, pl, o[:, None], count_mask=state["active"]
+                carry, pl, o[:, None], kernel, errors, active
             )
             if stats is not None:
                 counts.append(stats)
-        logits = mla_moe.logits(cfg, self.p, x)[:, -1]
-        return logits, new_tails, moe.total_stats(counts)
+        logits = self._logits(x, kernel)[:, -1]
+        counts = moe.total_stats(counts)
+        if errors:
+            ppm = jnp.round(1e6 * jnp.max(jnp.stack(errors)))
+            counts = jnp.concatenate([counts, ppm.astype(counts.dtype)[None]])
+        return logits, new_tails, counts
