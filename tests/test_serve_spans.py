@@ -11,7 +11,10 @@ record closed at the read, with no annotation of its own; and the counters of
 what the tick read, queued ahead and dropped are checked here too. ISSUE 35
 cut the tick where the host stops (a span at every dispatch and every
 blocking read) and reads three accounts from the cuts: the tick's (with the
-stall record), the first token's, and the unfed device's.
+stall record), the first token's, and the unfed device's. ISSUE 50 adds the
+device's own: what each program cost the chip, from the stamps of the reads
+that waited for it, held here to a fake device whose programs take a set time
+on a clock the tests move.
 
 CPU, the tiny model: counts, containment and nesting are what a CPU run can
 say; the times themselves are read on the chip (PERF.md).
@@ -710,6 +713,373 @@ def test_compiles_count_a_program_rebuild_and_no_steady_tick(server):
     assert metrics.get("cgx.serve.compile_s") == metrics.get(
         "cgx.serve.compiles")
 
+# ---------------------------------------------------------------------------
+# The device's account, from the scheduler's own stamps (ISSUE 50).
+# ---------------------------------------------------------------------------
+
+STEP_S = 0.03  # the fake device's decode step
+FOUR_LONG = [(5, 40), (9, 44), (12, 48), (7, 50)]
+
+
+class _Clock:
+    """The scheduler's and the spans' ``time``, made of nothing: a reading
+    costs the host a microsecond, a sleep or a blocking read moves it on.
+    What the account says of a fake device is then exact, on any machine."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self):
+        self.now += 1e-6
+        return self.now
+
+    monotonic = perf_counter
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
+class _Pending:
+    """A program's output still on the fake device: the copy to the host
+    returns when its program has ended, at once if it has."""
+
+    def __init__(self, value, clock, done_at):
+        self.value, self.clock, self.done_at = value, clock, done_at
+
+    def _wait(self):
+        self.clock.now = max(self.clock.now, self.done_at)
+
+    def __array__(self, dtype=None, copy=None):
+        self._wait()
+        return np.asarray(self.value)
+
+    def __int__(self):
+        self._wait()
+        return int(self.value)
+
+
+def _on_fake_device(sched, clock, step_s=STEP_S, prefill_s=0.01,
+                    commit_s=0.0):
+    """``sched``'s programs (a copy: they are the module's cached ones)
+    with their results from the real ones and their time from a fake
+    device, which runs one program at a time in dispatch order: a program
+    dispatched now ends its seconds after the later of now and the end of
+    the program before it, and what the scheduler reads of it, a first
+    token or a step's tokens, blocks until then. ``step_s`` and
+    ``prefill_s`` may be functions, of nothing and of the padded length."""
+    prog, free_at = sched._prog, [0.0]
+    of = lambda seconds, *a: seconds(*a) if callable(seconds) else seconds
+
+    def run(seconds):
+        free_at[0] = max(free_at[0], clock.now) + seconds
+        return free_at[0]
+
+    def prefill_pages(params, pools, tokens, *rest):
+        first, *out = prog.prefill_pages(params, pools, tokens, *rest)
+        done = run(of(prefill_s, tokens.shape[1]))
+        return (_Pending(first, clock, done), *out)
+
+    def admit_lane(state, lane, row, n_full, tail_len, first, *rest):
+        if isinstance(first, _Pending):  # an operand still on the device
+            first = first.value
+        return prog.admit_lane(state, lane, row, n_full, tail_len, first,
+                               *rest)
+
+    def commit(*args):
+        run(commit_s)
+        return prog.commit(*args)
+
+    def decode_step(params, state):
+        state, tokens = prog.decode_step(params, state)
+        return state, _Pending(tokens, clock, run(of(step_s)))
+
+    sched._prog = SimpleNamespace(**{
+        **vars(prog), "prefill_pages": prefill_pages,
+        "admit_lane": admit_lane, "commit": commit,
+        "decode_step": decode_step})
+
+
+@pytest.fixture
+def clock(server, monkeypatch):
+    from torch_cgx_tpu.serving import scheduler as sched_mod
+    from torch_cgx_tpu.utils import tracing
+
+    _serve(server)  # a tick that compiles is no sample of what a program costs
+    clock = _Clock()
+    monkeypatch.setattr(sched_mod, "time", clock)
+    monkeypatch.setattr(tracing, "time", clock)
+    metrics.reset()
+    return clock
+
+
+def _device(name):
+    return metrics.get(f"cgx.serve.device.{name}")
+
+
+def _late_read(sched, clock, ticks, seconds):
+    """The host comes ``seconds`` late to the step read of each tick of
+    ``ticks`` (numbered from the scheduler's first)."""
+    inner = sched._read_first_tokens
+
+    def read_first_tokens(*args, **kwargs):  # the call before the step read
+        if not kwargs and not args and sched._ticks in ticks:
+            clock.sleep(seconds)
+        return inner(*args, **kwargs)
+
+    sched._read_first_tokens = read_first_tokens
+
+
+def test_a_pure_step_interval_is_the_steps_device_time(server, clock):
+    """Four long answers on four lanes, every step queued ahead: an
+    interval between two blocked reads that holds one ``decode_step`` is a
+    sample of ``device.step_s`` and reads the fake step's time; one that
+    holds a tick's commit too is a sample of ``commit_step_s`` and reads
+    the two; both whatever the caller does between two ticks."""
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock, commit_s=0.006)
+    _tick_until_done(sched, _requests(server, FOUR_LONG),
+                     each_tick=lambda: clock.sleep(0.004))
+    assert metrics.get("cgx.serve.decode.ahead") == 38.0
+    step = metrics.histogram_stats("cgx.serve.device.step_s")
+    both = metrics.histogram_stats("cgx.serve.device.commit_step_s")
+    assert step["count"] >= 8 and both["count"] >= 8
+    assert step["count"] + both["count"] <= 38
+    assert step["min"] == pytest.approx(STEP_S, abs=1e-4)
+    assert step["max"] == pytest.approx(STEP_S, abs=1e-4)
+    calls = _device("commit_calls") / both["count"]
+    assert calls == 1.0  # four lanes fill one at a time: one call a tick
+    assert (both["mean"] - step["mean"]) / calls == pytest.approx(
+        0.006, abs=1e-4)
+    assert _device("unsound") == 0.0
+    # the running means the stall record holds a tick against
+    assert sched._usual["decode_step"][1] == pytest.approx(STEP_S, abs=1e-4)
+    assert sched._usual["commit"][1] == pytest.approx(0.006, abs=1e-4)
+
+
+def test_steps_that_start_at_a_dispatch_are_accounted_in_no_clean_class(
+        server, clock):
+    """No step queued ahead: every read leaves the device nothing, so every
+    interval starts at the dispatch that next feeds it. Its seconds are the
+    step's device time all the same (``accounted_s``, and the running mean
+    of the class), and ``device.step_s``, which is for intervals the device
+    was fed through, has no sample."""
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock)
+    sched._runs_ahead = lambda: False
+    (req,) = _requests(server, [(5, 50)])
+    sched.submit(req)
+    sched.step()  # the admission's tick
+    accounted, steps = _device("accounted_s"), metrics.get(
+        "cgx.serve.decode_steps")
+    while sched.outstanding():
+        sched.step()
+    steps = metrics.get("cgx.serve.decode_steps") - steps
+    assert steps == 48.0 and _device("step_s") == 0.0
+    assert (_device("accounted_s") - accounted) / steps == pytest.approx(
+        STEP_S, abs=1e-4)
+    assert sched._usual["decode_step"][1] == pytest.approx(STEP_S, abs=1e-4)
+    assert _device("unsound") == 0.0
+
+
+def test_a_late_host_closes_nothing_and_the_next_read_closes_both(
+        server, clock):
+    """The host comes to a step's read a step and a half late: the read
+    does not block and closes nothing; the next read, which blocks, closes
+    one interval that holds both steps, a sample of no clean class, and no
+    wall time is lost."""
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock)
+    _late_read(sched, clock, {20}, 1.5 * STEP_S)
+    seen = {}
+
+    def each_tick():
+        seen[sched._ticks] = (
+            _device("accounted_s"),
+            _device("step_s") + _device("commit_step_s"),
+            [r[1] for r in sched._held])
+
+    _tick_until_done(sched, _requests(server, FOUR_LONG), each_tick)
+    (before, clean, _), (_, _, held), (after, clean_after, _) = (
+        seen[20], seen[21], seen[22])
+    assert [k for k in held if k != "commit"] == ["decode_step"]
+    assert clean_after == clean
+    assert after - before == pytest.approx(2 * STEP_S, abs=1e-4)
+    assert _device("unsound") == 0.0
+
+
+def test_a_late_read_that_leaves_nothing_queued_is_unsound(server, clock):
+    """Nothing queued ahead and the host late to a read: the device stood
+    idle from an instant nobody saw, so the interval is dropped and
+    counted, and the next one starts at the next dispatch."""
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock)
+    sched._runs_ahead = lambda: False
+    _late_read(sched, clock, {10}, 1.5 * STEP_S)
+    seen = {}
+
+    def each_tick():
+        seen[sched._ticks] = (_device("accounted_s"), _device("unsound"))
+
+    _tick_until_done(sched, _requests(server, [(5, 30)]), each_tick)
+    assert seen[10][1] == 0.0 and seen[11][1] == 1.0
+    assert seen[11][0] == seen[10][0]  # the late tick accounted nothing
+    assert seen[12][0] - seen[11][0] == pytest.approx(STEP_S, abs=1e-4)
+    assert _device("unsound") == 1.0
+
+
+def test_a_first_token_read_closes_its_prefill_and_leaves_the_lane_write(
+        server, clock, tmp_path, monkeypatch):
+    """The read of a first token waits for its own ``prefill_pages`` and
+    nothing behind it: the interval it closes is a sample of
+    ``device.prefill_s`` (the timeline's record has the padded length and
+    the request), and the ``admit_lane`` stays owed, to ride in the next
+    interval."""
+    monkeypatch.setenv("CGX_METRICS_DIR", str(tmp_path))
+    timeline.reset()
+    timeline.set_rank(0)
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock, prefill_s=0.04)
+    (req,) = _requests(server, [(19, GEN)], tag="p")
+    sched.submit(req)
+    sched._admit()
+    assert [r[1] for r in sched._owed] == ["prefill_pages", "admit_lane"]
+    sched._read_first_tokens()
+    assert [(r[1], r[3]) for r in sched._owed] == [("admit_lane", req.id)]
+    assert _device("prefill_s") == 0.0  # a sample once its tick is judged
+    sched._settle(stalled=False, unknown=True)
+    stats = metrics.histogram_stats("cgx.serve.device.prefill_s")
+    assert stats["count"] == 1
+    assert stats["mean"] == pytest.approx(0.04, abs=1e-4)
+    assert _device("prefill_tokens") == 24.0  # 19 tokens, pages of 8
+    assert _device("accounted_s") == stats["sum"]
+    while sched.outstanding():
+        sched.step()
+    # the last release is owed until a read that never comes
+    assert [r[1] for r in sched._owed] == ["release_lanes"]
+    assert _device("prefill_s") == 1.0
+    timeline.flush()
+    (record,) = [e for e in map(json.loads,
+                                open(tmp_path / "spans-rank0.jsonl"))
+                 if e.get("name") == "serve.device.prefill"]
+    assert record["tokens"] == 24 and record["req"] == req.id
+    assert record["dur_s"] == pytest.approx(0.04, abs=1e-4)
+
+
+def test_accounted_dropped_and_unfed_time_add_up_to_the_loops_wall(
+        server, clock):
+    """Nothing queued ahead, so the device's intervals and the unfed gaps
+    between them tile the loop; with the one interval a late read dropped
+    (its tick, from the step's dispatch on) they add up to its wall time
+    within 2 %. ``step_s`` and ``between_steps_s`` give the same wall."""
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock)
+    sched._runs_ahead = lambda: False
+    _late_read(sched, clock, {10}, 1.5 * STEP_S)
+    for r in _requests(server, [(5, 40), (9, 40)]):
+        sched.submit(r)
+    start = clock.now
+    while sched.outstanding():
+        sched.step()
+        clock.sleep(0.002)  # the caller's turn-around: the device is unfed
+    wall = clock.now - start
+    assert _device("unsound") == 1.0
+    unfed = metrics.histogram_stats("cgx.serve.device_unfed_s")["sum"]
+    summed = _device("accounted_s") + unfed + 1.5 * STEP_S
+    assert unfed > 35 * 0.002
+    assert abs(wall - summed) <= 0.02 * wall, (wall, summed)
+    ticks = sum(metrics.histogram_stats(f"cgx.serve.{name}")["sum"]
+                for name in ("step_s", "between_steps_s"))
+    assert abs(wall - ticks) <= 0.02 * wall
+
+
+def test_a_callers_absence_opens_no_interval(server, clock):
+    """The caller stays away for 0.6 s with a step queued ahead: the
+    device finished it at an instant nobody saw, so the open interval is
+    dropped with the unfed mark, uncounted, and the account starts again
+    at the next read that blocks: the absence is in no interval."""
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock)
+    for r in _requests(server, [(5, 50), (9, 50), (12, 50), (7, 50)]):
+        sched.submit(r)
+    start = clock.now
+    while sched.outstanding():
+        if sched._ticks == 40:
+            assert sched._since is not None
+            clock.sleep(0.6)
+        sched.step()
+        if sched._ticks == 41:
+            assert sched._since is None and not sched._since_unfed
+    wall = clock.now - start
+    assert metrics.get("cgx.serve.stalls") == 1.0  # the absence itself
+    assert _device("unsound") == 0.0
+    accounted = _device("accounted_s")
+    assert wall - 0.6 - 3 * STEP_S < accounted < wall - 0.6
+
+
+def test_a_slow_step_is_a_stall_and_its_line_names_what_the_device_owed(
+        server, clock, stall_lines):
+    """A step that takes 0.7 s where its class takes 0.03: one stall,
+    whose line says what the tick's reads waited for, what that usually
+    costs the device, and what the process did meanwhile."""
+    slow = []
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock,
+                    step_s=lambda: slow.pop() if slow else STEP_S)
+    for r in _requests(server, [(5, 50), (9, 50), (12, 50), (7, 50)]):
+        sched.submit(r)
+    while sched.outstanding():
+        if sched._ticks == 44:  # its first 32 ticks teach a scheduler nothing
+            assert stall_lines == []
+            slow.append(0.7)
+        sched.step()
+    assert metrics.get("cgx.serve.stalls") == 1.0
+    (line,) = stall_lines
+    assert _largest_item(line) == "wait.step"
+    owed = re.search(r"\| device owed: (.*) \(usual ([\d.]+)\)", line)
+    kinds = owed.group(1).split()
+    assert kinds[-1] == "decode_step"
+    assert set(kinds[:-1]) <= {"commit", "x1"}
+    assert float(owed.group(2)) == pytest.approx(STEP_S, abs=1e-3)
+    process = re.search(r"\| process: cpu=([\d.]+) nivcsw=(\d+)$", line)
+    assert float(process.group(1)) < 0.5  # it waited: it did not compute
+    # the stalled tick's interval is the stall record's: no sample, no lesson
+    assert metrics.histogram_stats("cgx.serve.device.step_s")[
+        "max"] == pytest.approx(STEP_S, abs=1e-4)
+    assert sched._usual["decode_step"][1] == pytest.approx(STEP_S, abs=1e-4)
+    assert _device("accounted_s") > 0.7
+
+
+def test_a_prefill_at_its_usual_time_is_traffic_after_three_samples(
+        server, clock, stall_lines):
+    """A prompt whose prefill takes the device 0.6 s is a stall by the
+    rule of the running mean until its class (the power of two its padded
+    length reaches up to) has closed three clean intervals, and traffic
+    from then on; a prompt three times as long is another class, held
+    against the known one's token until it has three of its own."""
+    sched = ContinuousBatchScheduler(server)
+    _on_fake_device(sched, clock, step_s=0.005,
+                    prefill_s=lambda tokens: 0.075 * tokens)
+    stalls = lambda: metrics.get("cgx.serve.stalls")
+    (warm,) = _requests(server, [(19, 40)], tag="warm")
+    _tick_until_done(sched, [warm])  # past the first 32 ticks
+    assert sched._ticks >= 32 and stalls() == 0.0
+    counted = []
+    for i in range(5):
+        _tick_until_done(sched, _requests(server, [(5, 16)], tag=f"long{i}"))
+        counted.append(stalls())
+    assert counted == [1.0, 2.0, 3.0, 3.0, 3.0]
+    samples, a_token = sched._usual[("prefill_pages", 3)]  # 5-8 tokens
+    assert samples == 5 and a_token == pytest.approx(0.6 / 8, abs=1e-5)
+    # 17-32 tokens: the warm-up's, in a tick that teaches nothing
+    assert ("prefill_pages", 5) not in sched._usual
+    _tick_until_done(sched, _requests(server, [(19, 16)], tag="longer"))
+    assert stalls() == 3.0  # 1.8 s, and 24 of the known class's tokens
+    assert sched._usual[("prefill_pages", 5)][0] == 1
+    # the warm-up request's, the two that were traffic and the longer one
+    assert _device("prefill_s") == 4.0
+    assert len(stall_lines) == 3
+    assert all("release_lanes prefill_pages[8] admit_lane decode_step "
+               "(usual unknown)" in line for line in stall_lines)
 
 # ---------------------------------------------------------------------------
 # trace_span itself.

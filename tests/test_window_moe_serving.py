@@ -212,7 +212,6 @@ def test_live_and_committed_pages_are_counted_by_class(served, run):
     assert counted["kv.table_pages.global"] == (
         (gen - 1) * 2 * _serve().pages_per_seq)  # two lanes' rows a step
     assert counted["kv.live_pages.window"] == (pages - oldest).sum()
-    assert counted["kv.decoded_pages.window"] == (pages - oldest).sum()
     assert (pages - oldest).max() == RING - 1  # the spare slot is never live
     commits = (n + gen - 2) // PAGE - n // PAGE
     assert counted["window.pages_committed"] == 12 * commits
@@ -410,8 +409,8 @@ def test_the_guard_leaves_every_held_lanes_logits_bit_for_bit(params, guard):
 def test_the_hosts_live_pages_are_the_devices_mask_at_every_step(params):
     """The device's page mask (``ring_masks`` reduced by page, which is
     ``ring_live``) sums at every dispatched step to what the host adds to
-    ``cgx.serve.kv.live_pages.window`` and to ``.decoded_pages.window`` from
-    its own counts, through a run that crosses commits and slide-outs."""
+    ``cgx.serve.kv.live_pages.window`` from its own counts, through a run
+    that crosses commits and slide-outs."""
     device, host = [], []
 
     def watch(sched, p, state):
@@ -424,18 +423,16 @@ def test_the_hosts_live_pages_are_the_devices_mask_at_every_step(params):
             note = sched._note_live_pages
 
             def counted(held):
-                names = [f"cgx.serve.kv.{n}.window"
-                         for n in ("live_pages", "decoded_pages")]
-                before = [metrics.get(n) for n in names]
+                name = "cgx.serve.kv.live_pages.window"
+                before = metrics.get(name)
                 note(held)
-                host.append([metrics.get(n) - b
-                             for n, b in zip(names, before)])
+                host.append(metrics.get(name) - before)
 
             sched._note_live_pages = counted
 
     _mixed_batch_steps(params, watch)
     assert len(host) == len(device) > 12
-    assert [h[0] for h in host] == device and [h[1] for h in host] == device
+    assert host == device
     # The long lane commits pages 10 and 11 on the way (85 + 13 positions):
     # each commit slides a page out, so its live slots stay at RING - 1 or
     # fall to RING - 2 while the short lanes' grow.

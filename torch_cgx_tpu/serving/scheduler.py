@@ -32,15 +32,19 @@ dropped (``cgx.serve.decode.discarded_tokens``).
 The tick is cut where the host stops: a ``trace_span`` at every dispatch
 (``serve.prefill.forward``, ``serve.admit_lane``, ``serve.dispatch.commit``,
 ``serve.dispatch.step``) and at every blocking read
-(``serve.prefill.first_token``, ``serve.wait.step``). Three accounts are read
+(``serve.prefill.first_token``, ``serve.wait.step``). Four accounts are read
 from the cuts (docs/OBSERVABILITY.md): the tick's (with the caller's time
-between two ticks it adds up to the loop's wall time, and a tick far over the
-running mean leaves one warning line that says where its time went:
-:meth:`ContinuousBatchScheduler._close_tick`), the first token's (what was
-dispatched between a request's lane write and the read of its token,
-``cgx.serve.ttft_behind_s``) and the unfed device's (from a read that leaves
-nothing queued to the next dispatch that carries work,
-``cgx.serve.device_unfed_s``).
+between two ticks it adds up to the loop's wall time, and a tick far over
+what its own programs usually cost leaves one warning line that says where
+its time went: :meth:`ContinuousBatchScheduler._close_tick`), the first
+token's (what was dispatched between a request's lane write and the read of
+its token, ``cgx.serve.ttft_behind_s``), the unfed device's (from a read that
+leaves nothing queued to the next dispatch that carries work,
+``cgx.serve.device_unfed_s``) and the device's (every dispatch leaves a
+record of what the device owes, every read that blocked closes the interval
+since the last one, and an interval that held one program is that program's
+time on the device, with no profiler: ``cgx.serve.device.*``,
+:meth:`ContinuousBatchScheduler._note_read`).
 
 Requests arrive with their KV either computed here (local prefill — the
 colocated mode, also the FAILOVER path) or shipped by a disaggregated
@@ -67,6 +71,7 @@ served).
 from __future__ import annotations
 
 import dataclasses
+import resource
 import time
 from collections import OrderedDict, deque
 from types import SimpleNamespace
@@ -106,6 +111,20 @@ _ADMISSIONS_IN_FLIGHT = 2
 _STALL_MIN_S = 0.5
 _STALL_OVER_MEAN = 8.0
 _STALL_WARMUP_TICKS = 32
+# The device's account. A read shorter than this did not block: the host came
+# after the device, and what it took was the copy of a buffer that was ready
+# (0.29-0.55 ms on the chip's host, one in a hundred over 1 ms; a read that
+# waited for a program took 3.8 ms and more: PERF.md section 5, PR 50).
+_READ_BLOCKED_S = 1e-3
+# The programs that carry work, as ``cgx.serve.device_unfed_s`` has it, and
+# those that may ride in an interval of a clean class (a lane write, a
+# release: 0.002-0.54 ms of the device each).
+_WORK = frozenset(("prefill_pages", "commit", "decode_step"))
+_RIDERS = frozenset(("admit_lane", "release_lanes"))
+# The account's classes, by the program an interval of the class is a sample
+# of: the histogram under ``cgx.serve.device.`` that takes its clean samples.
+_CLASS_HIST = {"decode_step": "step", "commit": "commit_step",
+               "prefill_pages": "prefill"}
 # The tick's account: where a stalled tick's time went, by the histogram
 # under ``cgx.serve.`` whose ``.sum`` is read at the tick's two ends. The
 # tick itself, then the spans that lie side by side inside it, then what
@@ -248,6 +267,11 @@ class _Ready:
     # The return of its ``admit_lane`` dispatch (``time.perf_counter()``):
     # ``cgx.serve.ttft_behind_s`` counts from here to the read.
     admitted_at: float = 0.0
+    # The number of its ``prefill_pages`` in what the device owes
+    # (:meth:`ContinuousBatchScheduler._owe`): the read of its first token
+    # waits for that program and nothing behind it. 0 from a page stream,
+    # whose first token is on the host.
+    owed: int = 0
 
 
 @dataclasses.dataclass
@@ -255,10 +279,11 @@ class _Step:
     """A decode step dispatched and not read yet: its tokens (then the
     adapter's counters) on the device, and the request each lane's token
     belongs to. A lane is struck out when its request leaves it under the
-    step."""
+    step. ``owed`` is the step's number in what the device owes."""
 
     tokens: jax.Array
     lanes: Dict[int, Request]
+    owed: int
 
 
 class ContinuousBatchScheduler:
@@ -339,6 +364,28 @@ class ContinuousBatchScheduler:
         self._ticks = 0
         self._tick_mean = 0.0
         self._unfed_since: Optional[float] = None
+        # The device's account. What was dispatched and no read has waited
+        # for yet, in dispatch order: (number, kind, count, request, the
+        # dispatch's return). The records of the open interval and its
+        # start: the return of the read that closed the last one, or None,
+        # and then the dispatch of its first program that carries work if
+        # an unfed mark stood before it (``_since_unfed``), else there is
+        # no interval (a caller's absence dropped it). What this tick's
+        # reads waited for and the intervals of one class they closed,
+        # which become samples, and move the running mean of the class
+        # (decode step, commit call, a prefill's padded token by the length's
+        # power of two: [samples, mean]), once the tick has been held
+        # against it; and the process's
+        # resource usage at the last tick's end (the stall line).
+        self._owed: "deque[Tuple]" = deque()
+        self._owed_n = 0
+        self._held: List[Tuple] = []
+        self._since: Optional[float] = None
+        self._since_unfed = True  # nothing dispatched yet: the first feeds it
+        self._tick_owed: List[Tuple] = []
+        self._tick_closed: List[Tuple] = []
+        self._usual: Dict = {}
+        self._rusage = resource.getrusage(resource.RUSAGE_SELF)
 
     # -- state plumbing ----------------------------------------------------
 
@@ -523,14 +570,18 @@ class ContinuousBatchScheduler:
         :meth:`_close_tick` holds the two against their running mean. A
         gap that is a stall by itself is the caller's absence, not its
         turn-around: the stall record has it, and the loop's accounts
-        (the time between steps, the unfed device) leave it out."""
+        (the time between steps, the unfed device, the device's open
+        interval) leave it out."""
         before = metrics.sums(_TICK_ACCOUNT)
         between, away = 0.0, False
+        self._tick_owed.clear()
         if self._step_end is not None:
             between = time.perf_counter() - self._step_end
             away = self._is_stall(between)
             if away:
                 self._unfed_since = None  # nobody was there to feed it
+                self._since, self._since_unfed = None, False
+                self._held.clear()
             else:
                 metrics.observe("cgx.serve.between_steps_s", between)
         with trace_span("serve.step"):
@@ -543,36 +594,75 @@ class ContinuousBatchScheduler:
         self._close_tick(between, away, before)
         return progressed
 
-    def _is_stall(self, seconds: float) -> bool:
-        """Whether the tick about to end (or the gap before it) is one:
-        longer than both constants allow, past the scheduler's first
-        ticks."""
-        return (self._ticks >= _STALL_WARMUP_TICKS
-                and seconds >= _STALL_MIN_S
-                and seconds >= _STALL_OVER_MEAN * self._tick_mean)
+    def _is_stall(self, seconds: float,
+                  usual: Optional[float] = None) -> bool:
+        """Whether the tick about to end (or the gap before it) is one,
+        past the scheduler's first ticks: over ``_STALL_MIN_S``, and by
+        that much over the ``usual`` device time of the programs its reads
+        waited for; where nothing says what those usually cost, over
+        ``_STALL_OVER_MEAN`` times the running mean of the ticks."""
+        if self._ticks < _STALL_WARMUP_TICKS or seconds < _STALL_MIN_S:
+            return False
+        if usual is None:
+            return seconds >= _STALL_OVER_MEAN * self._tick_mean
+        return seconds - usual >= _STALL_MIN_S
+
+    def _usual_s(self) -> Optional[float]:
+        """What the programs this tick's reads waited for usually cost the
+        device: the running means of their classes (a step's, a commit
+        call's, a prefill's padded token's) times what each holds, summed
+        (a rider nothing). None where the tick's reads waited for no
+        program, or for one whose class has closed fewer than three clean
+        intervals; a prefill of such a class is held against the token of
+        the nearest class that has (a first 16k-token prompt against the
+        8k-token ones)."""
+        total = None
+        for _, kind, n, _, _ in self._tick_owed:
+            if kind in _RIDERS:
+                continue
+            key = _class_key(kind, n)
+            cell = self._usual.get(key)
+            if kind == "prefill_pages" and (cell is None or cell[0] < 3):
+                near = [(abs(k[1] - key[1]), c) for k, c in self._usual.items()
+                        if isinstance(k, tuple) and c[0] >= 3]
+                cell = min(near)[1] if near else None
+            if cell is None or cell[0] < 3:
+                return None
+            total = (total or 0.0) + cell[1] * (n or 1)
+        return total
 
     def _close_tick(self, between: float, away: bool,
                     before: Tuple[float, ...]) -> None:
         """The stall record. A tick, with the ``between`` seconds the
-        caller took before it, longer than ``_STALL_MIN_S`` and than
-        ``_STALL_OVER_MEAN`` times the running mean of the ticks before
-        it, counts in ``cgx.serve.stalls`` and leaves one warning line
-        that says where its time went: the growth of each histogram of
-        ``_TICK_ACCOUNT`` since ``before``, which ``step()`` read as the
-        tick began. ``cgx.serve.stall_s`` observes it unless the caller
-        was ``away`` (the gap alone was the stall: no tick of this loop
-        was slow). Those histograms are the process's: two schedulers
-        ticking in one process read each other's time. Full collections
-        are published here, so that a pause lands in the tick it
-        lengthened."""
+        caller took before it, that :meth:`_is_stall` finds too long for
+        what the device owed under its reads counts in
+        ``cgx.serve.stalls`` and leaves one warning line that says where
+        its time went: the growth of each histogram of ``_TICK_ACCOUNT``
+        since ``before``, which ``step()`` read as the tick began; then
+        the programs its reads waited for, in dispatch order, and their
+        usual device time; then what the process did meanwhile, the CPU
+        seconds of all its threads and the times it was taken off a core
+        (a wait with neither is the device or the runtime late, one with
+        switches a host that was not scheduled). ``cgx.serve.stall_s``
+        observes it unless the caller was ``away`` (the gap alone was the
+        stall: no tick of this loop was slow). Those histograms are the
+        process's: two schedulers ticking in one process read each other's
+        time. Full collections are published here, so that a pause lands
+        in the tick it lengthened."""
         self._gc_pauses.publish()
         grown = [b - a for a, b in
                  zip(before, metrics.sums(_TICK_ACCOUNT))]
         whole = between + grown[0]
-        stalled, mean = self._is_stall(whole), self._tick_mean
+        usual = self._usual_s() if whole >= _STALL_MIN_S else None
+        stalled, mean = self._is_stall(whole, usual), self._tick_mean
+        compiled = grown[-1] > 0  # its time is no program's usual cost
+        self._settle(stalled or compiled,
+                     unknown=usual is None and not compiled)
         self._ticks += 1
         self._tick_mean += ((grown[0] if away else whole) - mean) / min(
             self._ticks, _STALL_WARMUP_TICKS)
+        was, now = self._rusage, resource.getrusage(resource.RUSAGE_SELF)
+        self._rusage = now
         if not stalled:
             return
         metrics.add("cgx.serve.stalls")
@@ -582,12 +672,20 @@ class ContinuousBatchScheduler:
         parts = [("between_steps", between), *zip(_TICK_SPANS, spans),
                  ("other", grown[0] - sum(spans))]
         within = zip(_TICK_WITHIN, grown[1 + len(_TICK_SPANS):])
+        owed = [kind + (f"[{n}]" if kind == "prefill_pages" else
+                        f" x{n}" if kind == "commit" else "")
+                for _, kind, n, _, _ in self._tick_owed]
         log.warning(
             "serving: stalled tick %d: %.3f s, the running mean %.4f s: %s"
-            " | inside those: %s",
+            " | inside those: %s | device owed: %s (usual %s)"
+            " | process: cpu=%.3f nivcsw=%d",
             self._ticks, whole, mean,
             " ".join(f"{name}={v:.3f}" for name, v in parts),
             " ".join(f"{name}={v:.3f}" for name, v in within),
+            " ".join(owed) or "nothing",
+            "unknown" if usual is None else f"{usual:.4f}",
+            now.ru_utime + now.ru_stime - was.ru_utime - was.ru_stime,
+            now.ru_nivcsw - was.ru_nivcsw,
         )
 
     def run(self, *, deadline_s: float = 120.0,
@@ -736,6 +834,7 @@ class ContinuousBatchScheduler:
                     self._state["pools"], layer_rows, ids
                 ),
             )
+            self._owe("ingest", req=req.id)
         self._note_pages(n_pages)
         metrics.add("cgx.serve.pages_ingested", float(n_pages))
         self._ready.append(_Ready(
@@ -831,7 +930,8 @@ class ContinuousBatchScheduler:
                     )
                 )
                 self._state["pools"] = pools
-                self._fed()
+                owed = self._owe("prefill_pages", int(padded.shape[0]),
+                                 req.id)
         except BaseException:
             self._close_prefill_span((start, fields), ok=False)
             raise
@@ -841,7 +941,7 @@ class ContinuousBatchScheduler:
             req=req, page_ids=pids, tails=tails,
             tail_len=tail_len, first_token=first, pos=s,
             states=states, span=(start, fields), qerr_rows=qerr_rows,
-            ring=ring,
+            ring=ring, owed=owed,
         )
 
     def _slot_rows(self, ring_id, pages):
@@ -957,6 +1057,7 @@ class ContinuousBatchScheduler:
                 np.int32(first) if isinstance(first, int) else first,
                 np.int32(ready.pos), ready.tails, ready.states, *ring_row,
             )
+            self._owe("admit_lane", req=req.id)
             if ready.states:
                 metrics.add("cgx.serve.state.lane_writes")
             self._lanes[lane] = req
@@ -990,8 +1091,10 @@ class ContinuousBatchScheduler:
                         hist="cgx.serve.prefill_first_token_s", req=req.id,
                         behind_ms=round(behind * 1e3, 3),
                     ):
+                        began = time.perf_counter()
                         first = int(first)
-                    self._note_read()
+                        ended = time.perf_counter()
+                    self._note_read(ready.owed, began, ended, prefill=True)
                 for layer, rows in ready.qerr_rows.items():
                     _observe_page_qerr(
                         self.server.layer_name(layer),
@@ -1071,6 +1174,7 @@ class ContinuousBatchScheduler:
         self._state.update(self._prog.release_lanes(
             {name: self._state[name] for name in names}, mask
         ))
+        self._owe("release_lanes")
 
     # -- decode ------------------------------------------------------------
 
@@ -1106,8 +1210,10 @@ class ContinuousBatchScheduler:
             self._read_first_tokens()
             step = self._steps.popleft()
             with trace_span("serve.wait.step", hist="cgx.serve.wait_step_s"):
+                began = time.perf_counter()
                 nxt = np.asarray(step.tokens)
-            self._note_read()
+                ended = time.perf_counter()
+            self._note_read(step.owed, began, ended)
         with trace_span("serve.decode.emit", hist="cgx.serve.decode_emit_s"):
             metrics.add("cgx.serve.decode_steps")
             # What the adapter counted this step, read with the tokens.
@@ -1148,14 +1254,14 @@ class ContinuousBatchScheduler:
             self._state, tokens = self._prog.decode_step(
                 self.server.p, self._state
             )
-            self._fed()
+            owed = self._owe("decode_step")
         held = [i for i, r in enumerate(self._lanes) if r is not None]
         if self._prog.ring or self.server.guards_global_read:
             self._note_live_pages(held)
         self._tail_len[held] += 1
         lanes = {i: self._lanes[i] for i in held if self._left[i] > 0}
         self._left[list(lanes)] -= 1
-        self._steps.append(_Step(tokens=tokens, lanes=lanes))
+        self._steps.append(_Step(tokens=tokens, lanes=lanes, owed=owed))
 
     def _note_live_pages(self, held: List[int]) -> None:
         """The pages the step just dispatched has a visible key in, summed
@@ -1164,10 +1270,10 @@ class ContinuousBatchScheduler:
         window layer those from the page that holds the oldest position the
         lane's token (at ``n_pages * page_tokens + tail_len``) can see. Those
         are the slots the step's read leaves open (``adapter.page_live`` and
-        ``adapter.ring_live`` on the device), counted again as
-        ``kv.decoded_pages.<class>``: what the class's read decodes, which
-        was every slot of every lane's table (``kv.table_pages.global``;
-        every lane's ring) before the read had a guard."""
+        ``adapter.ring_live`` on the device); a guarded global read's are
+        counted again as ``kv.decoded_pages.global``: what the read
+        decodes, which was every slot of every lane's table
+        (``kv.table_pages.global``) before the read had a guard."""
         sv = self.server.serve
         n_pages = self._n_pages[held]
         committed = float(n_pages.sum())
@@ -1183,7 +1289,6 @@ class ContinuousBatchScheduler:
             n_pages * pt + self._tail_len[held] - window + 1, 0) // pt
         live = float(np.maximum(n_pages - oldest, 0).sum())
         metrics.add("cgx.serve.kv.live_pages.window", live)
-        metrics.add("cgx.serve.kv.decoded_pages.window", live)
 
     def _commit_full_tails(self) -> None:
         """Promote full tails into pool pages, so that every lane has
@@ -1237,6 +1342,7 @@ class ContinuousBatchScheduler:
                         rows, already_host=True,
                     )
         k = sv.commit_lanes
+        calls = -(-len(committed) // k)
         with trace_span(
             "serve.dispatch.commit", hist="cgx.serve.dispatch_commit_s"
         ):
@@ -1249,8 +1355,8 @@ class ContinuousBatchScheduler:
                     np.asarray(ids + [sv.max_pages] * pad, np.int32),
                     *self._ring_slots(lanes, pad),
                 )
-                self._fed()
-        calls = -(-len(committed) // k)
+                if not at:  # the device has work from the first call on
+                    self._owe("commit", calls)
         metrics.add("cgx.serve.commit.calls", float(calls))
         metrics.add("cgx.serve.commit.rows", float(calls * k))
         metrics.add("cgx.serve.commit.lanes", float(len(committed)))
@@ -1280,24 +1386,139 @@ class ContinuousBatchScheduler:
         scratch = self.server.serve.max_batch * ring
         return (np.asarray(list(rows) + [scratch] * pad, np.int32),)
 
-    def _note_read(self) -> None:
-        """A blocking copy from the device has just returned: count it,
-        and where it leaves nothing dispatched and unread the device has
-        nothing of ours to run from now until :meth:`_fed`."""
-        metrics.add("cgx.serve.host_reads")
-        if not self._steps and not self._unread:
-            self._unfed_since = time.perf_counter()
-
-    def _fed(self) -> None:
-        """A program that carries work (``prefill_pages``, ``commit``,
-        ``decode_step``; not a lane write or a release) has just been
-        dispatched: ``cgx.serve.device_unfed_s`` observes how long the
+    def _owe(self, kind: str, n: int = 0, req: Optional[str] = None) -> int:
+        """A device program has just been dispatched: one record at the end
+        of what the device owes, ``n`` a prefill's padded length or a
+        commit's calls. Returns the record's number, by which the read of
+        the program's output names it. Where the program carries work
+        (``prefill_pages``, ``commit``, ``decode_step``; not a lane write
+        or a release), ``cgx.serve.device_unfed_s`` observes how long the
         device had stood with nothing queued, the host's own estimate of
         its idle gap."""
-        if self._unfed_since is not None:
+        now = time.perf_counter()
+        self._owed_n += 1
+        self._owed.append((self._owed_n, kind, n, req, now))
+        if kind in _WORK and self._unfed_since is not None:
             metrics.observe("cgx.serve.device_unfed_s",
-                            time.perf_counter() - self._unfed_since)
+                            now - self._unfed_since)
             self._unfed_since = None
+        return self._owed_n
+
+    def _note_read(self, target: int, began: float, ended: float,
+                   prefill: bool = False) -> None:
+        """A blocking copy from the device (of a first token if
+        ``prefill``, else of a step's tokens) ran from ``began`` to
+        ``ended``: count it, and keep the device's account. The device has
+        finished the program the copy waited for, record ``target``, and
+        every program dispatched before it: those leave what it owes and
+        join the open interval. Where the copy blocked, the device finished
+        at ``ended``, the interval closes there (:meth:`_close_interval`)
+        and the next one opens: at ``ended`` where work is still queued
+        (the device was fed through), else at the dispatch that next feeds
+        it (the unfed mark, which any read that leaves nothing dispatched
+        and unread sets). Where it did not block the host came late and the
+        interval stays open, to be closed by the next read that blocks,
+        unless nothing is left queued: then the device stood idle from an
+        instant nobody saw, and the interval is dropped and counted
+        (``cgx.serve.device.unsound``)."""
+        metrics.add("cgx.serve.host_reads")
+        owed, held = self._owed, self._held
+        before = len(held)
+        while owed and owed[0][0] <= target:
+            held.append(owed.popleft())
+        self._tick_owed += held[before:]
+        unfed = not self._steps and not self._unread
+        if ended - began >= _READ_BLOCKED_S and len(held) > before:
+            self._close_interval(ended, prefill)
+            self._since, self._since_unfed = (
+                (None, True) if unfed else (ended, False))
+        elif unfed:
+            if self._since is not None or self._since_unfed:
+                metrics.add("cgx.serve.device.unsound")
+            held.clear()
+            self._since, self._since_unfed = None, True
+        if unfed:
+            self._unfed_since = ended
+
+    def _close_interval(self, ended: float, prefill: bool) -> None:
+        """The open interval ends at ``ended``, the return of a read that
+        blocked: the device was busy from its start with the programs it
+        holds and nothing else, so its seconds are their device time
+        (``cgx.serve.device.accounted_s``). Where it holds one class it is
+        kept for the tick's end (:meth:`_settle`), with whether it is a
+        clean sample of the class's histogram: ``step`` (fed through, one
+        ``decode_step`` and nothing else), ``commit_step`` (fed through, a
+        tick's ``commit`` calls and one ``decode_step``) or ``prefill``
+        (one ``prefill_pages``, closed by the read of its first token),
+        lane writes and releases riding in the last two. An interval that
+        began at a dispatch is a sample of no histogram and still teaches
+        the stall record what its class costs."""
+        held, start = self._held, self._since
+        fed = start is not None
+        if not fed and self._since_unfed:
+            start = next(r[4] for r in held if r[1] in _WORK)
+        if start is not None:
+            metrics.add("cgx.serve.device.accounted_s", ended - start)
+            work = [r for r in held if r[1] not in _RIDERS]
+            kinds = tuple(r[1] for r in work)
+            if kinds == ("decode_step",):
+                self._tick_closed.append(
+                    ("decode_step", fed and len(held) == 1, start, ended,
+                     0, None))
+            elif kinds == ("prefill_pages",) and prefill:
+                _, _, tokens, req, _ = work[0]
+                self._tick_closed.append(
+                    ("prefill_pages", True, start, ended, tokens, req))
+            elif kinds == ("commit", "decode_step"):
+                self._tick_closed.append(
+                    ("commit", fed, start, ended, work[0][2], None))
+        held.clear()
+
+    def _settle(self, stalled: bool, unknown: bool) -> None:
+        """The tick is over, and so is the judgement of it: the intervals
+        its reads closed become samples. Unless the tick ``stalled`` or
+        built a program (its time is the stall record's or the compiler's,
+        not what a program costs the chip), a clean one is observed by its histogram, ``cgx.serve.device.step_s``,
+        ``commit_step_s`` (``commit_calls`` adds its calls) or ``prefill_s``
+        (``prefill_tokens`` adds its padded length; the timeline's record
+        has it and the request), and each moves the running mean of its
+        class by the rule of ``_tick_mean``: a step's, a prefill's padded
+        token's (a class a power of two of padded length:
+        :func:`_class_key`), and a commit call's, which is what its
+        interval took over the step's mean. A prefill teaches from a stalled tick too
+        where the tick was held against the ticks' mean, a class being
+        ``unknown``: a long prompt's prefill is the one program that can
+        take the stall's floor by itself, and its class would never be
+        known else. The scheduler's first ticks teach nothing (its
+        programs compile there)."""
+        warm = self._ticks >= _STALL_WARMUP_TICKS
+        for kind, clean, start, ended, n, req in self._tick_closed:
+            seconds = ended - start
+            if clean and not stalled:
+                fields = {}
+                if kind == "prefill_pages":
+                    fields = dict(req=req, tokens=n)
+                    metrics.add("cgx.serve.device.prefill_tokens", float(n))
+                elif kind == "commit":
+                    fields = dict(calls=n)
+                    metrics.add("cgx.serve.device.commit_calls", float(n))
+                name = _CLASS_HIST[kind]
+                observe_span(f"serve.device.{name}", start, end=ended,
+                             hist=f"cgx.serve.device.{name}_s", **fields)
+            if not warm or (stalled and not (
+                    unknown and kind == "prefill_pages")):
+                continue
+            if kind == "commit":
+                step = self._usual.get("decode_step")
+                if step is None or step[0] < 3:
+                    continue
+                seconds -= step[1]
+            cell = self._usual.setdefault(_class_key(kind, n), [0, 0.0])
+            seconds /= n or 1  # a commit's call, a prefill's padded token
+            cell[0] += 1
+            cell[1] += (seconds - cell[1]) / min(cell[0],
+                                                 _STALL_WARMUP_TICKS)
+        self._tick_closed.clear()
 
     def _note_tokens(self, n: int) -> None:
         self._tokens_total += n
@@ -1318,6 +1539,16 @@ class ContinuousBatchScheduler:
 # ---------------------------------------------------------------------------
 # Shared page helpers (ingest + accounting).
 # ---------------------------------------------------------------------------
+
+
+def _class_key(kind: str, n: int):
+    """The class whose running mean the stall record holds a program
+    against: its kind, and for a prefill the power of two its padded length
+    reaches up to (a mix of 16k-token prompts pads to four lengths between
+    15,616 and 16,384, each seen a few times an hour: by exact length the
+    classes starve, and a token's cost moves by a third from one power of
+    two to the next)."""
+    return (kind, (n - 1).bit_length()) if kind == "prefill_pages" else kind
 
 
 def _pad_prompt(prompt: np.ndarray, page_tokens: int) -> np.ndarray:

@@ -55,15 +55,18 @@ def trace_span(name: str, *, hist: str | None = None, **fields):
 
 
 def observe_span(name: str, start: float, *, hist: str | None = None,
-                 ok: bool = True, **fields) -> None:
+                 ok: bool = True, end: float | None = None,
+                 **fields) -> None:
     """Close a span that began at ``start`` (a ``time.perf_counter()``
-    reading): :func:`trace_span`'s histogram and timeline record, for a
+    reading) and ends now, or at the reading ``end`` the caller took:
+    :func:`trace_span`'s histogram and timeline record, for a
     span whose two ends lie in different calls and so in no one ``with``
     block (``serve.prefill.local``: the dispatch in one phase of a tick,
-    the read of its first token in another). It has no annotation in the
-    profiler's trace: a thread's annotations nest, and such a span
-    overlaps its neighbours."""
-    dur = time.perf_counter() - start
+    the read of its first token in another; the device's intervals,
+    ``serve.device.*``: from one read's return to the next's). It has no
+    annotation in the profiler's trace: a thread's annotations nest, and
+    such a span overlaps its neighbours."""
+    dur = (time.perf_counter() if end is None else end) - start
     if not ok:
         metrics.add(f"span.{name}.errors", 1.0)
     metrics.observe(hist or f"cgx.{name}_s", dur)
