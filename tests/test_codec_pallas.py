@@ -1151,6 +1151,20 @@ _PARENT_UNPAGED = {
 }
 
 
+# The same of the PAGED call at the plane loop (``unpack`` left alone: every
+# table's read), bare and guarded, computed on PR 51's parent.
+_PARENT_PAGED = {
+    (4, 1, False): ("7a2d4d0598ee31cb", "633e59c91f0f4590"),
+    (4, 1, True): ("f11b9241c9434462", "8586d47881d09222"),
+    (4, 2, False): ("3f11ed53044aaa74", "c712b24a6a3e4631"),
+    (4, 2, True): ("8aaf9222e8ac2c3e", "344c73ddb99301d9"),
+    (8, 1, False): ("227a1074abd517df", "d3edccc860015adc"),
+    (8, 1, True): ("4b39872041af01f4", "9589ea512395d3d1"),
+    (8, 2, False): ("181c47f82b154eee", "9af8aff8e6ef363e"),
+    (8, 2, True): ("0de9cda837b03112", "24425aaa153ba195"),
+}
+
+
 def _guard_call(bits, ppb, rows):
     return functools.partial(
         codec_pallas._dequantize_flat_impl, bits=bits, bucket_size=512,
@@ -1217,6 +1231,8 @@ def test_unguarded_paged_decode_is_the_parents_jaxpr(bits, ppb, rows):
     guarded = jax.make_jaxpr(lambda w, m, i, l: call(w, m, None, i, l))(
         words, meta, ids, ids)
     assert str(guarded).count("cond[") >= 2 * ppb  # a branch pair a page
+    # ISSUE 51 gave the ring's call a byte unpack and the tables' none.
+    assert (_sha(bare), _sha(guarded)) == _PARENT_PAGED[bits, ppb, rows]
 
 
 def test_fused_add_decode_is_the_parents_jaxpr():
@@ -1232,19 +1248,23 @@ def test_fused_add_decode_is_the_parents_jaxpr():
     assert _sha(added) == _PARENT_UNPAGED["add"]
 
 
-@pytest.mark.tpu  # compiled Mosaic lowering of the guard and the held block
-def test_guarded_paged_decode_tpu():
-    rng = np.random.default_rng(42)
-    lanes, ring = 4, 17
-    n = lanes * ring
-    _, words, meta = _paged_pool(rng, n + 1, 256 * 512, 8, 512,
-                                 interpret=False)
-    ids = jnp.asarray(rng.permutation(n + 1)[:n], jnp.int32)
+def _ring_guard(lanes=4, ring=17):
+    """A ring table's ``live`` as the window cells give it, flat."""
     live = np.ones((lanes, ring), np.int32)
     live[0, 3] = 0  # a slot that slid out
     live[1, 2:] = 0  # a short lane
     live[3] = 0  # a vacated lane
-    live = jnp.asarray(live.reshape(-1))
+    return jnp.asarray(live.reshape(-1))
+
+
+@pytest.mark.tpu  # compiled Mosaic lowering of the guard and the held block
+def test_guarded_paged_decode_tpu():
+    rng = np.random.default_rng(42)
+    live = _ring_guard()
+    n = live.size
+    _, words, meta = _paged_pool(rng, n + 1, 256 * 512, 8, 512,
+                                 interpret=False)
+    ids = jnp.asarray(rng.permutation(n + 1)[:n], jnp.int32)
     kw = dict(bits=8, bucket_size=512, tc=16, out_dtype=jnp.bfloat16,
               row_width=512)
     want = _bits_of(codec_pallas.dequantize_pages(words, meta, ids, **kw))
@@ -1253,6 +1273,111 @@ def test_guarded_paged_decode_tpu():
     keep = np.asarray(live, bool)
     np.testing.assert_array_equal(got[keep], want[keep])
     assert not got[~keep].any()
+
+
+def _random_pool(rng, n_pool, chunks, bucket):
+    """A pool of 8-bit pages of ``chunks`` chunks whose words are drawn
+    whole (every level in every plane position, bit 31 set in half of them)
+    and whose page 0 decodes to its levels (unit 1, minimum 0)."""
+    words = rng.integers(-2**31, 2**31, (n_pool, chunks * 8 * bucket // 128,
+                                         128), dtype=np.int32)
+    meta = rng.standard_normal((n_pool, 2, chunks * 32)).astype(np.float32)
+    meta[0, 0], meta[0, 1] = 1.0, 0.0
+    return jnp.asarray(words), jnp.asarray(meta)
+
+
+@pytest.mark.parametrize("k", [1, 8])
+@pytest.mark.parametrize("guarded", [False, True], ids=["bare", "live"])
+@pytest.mark.parametrize("ppb", [1, 2])
+@pytest.mark.parametrize("rb", [1, 4])
+def test_byte_unpack_is_the_plane_loop_bit_for_bit(rb, ppb, guarded, k):
+    """ISSUE 51: the paged read asked for ``unpack="bytes"`` (the ring's
+    call) stores what the plane loop stores, bit for bit: buckets of 128 and
+    512 (``rb`` 1: every swap inside one sublane tile; 4: the cells'), one
+    and two pages a grid step, with and without the guard (a dead page is
+    zeros), flat rows and rows of 1,024, over pages that hold all 256 levels
+    and words whose sign bit is set."""
+    bucket, chunks, n = rb * 128, 2, 6
+    words, meta = _random_pool(np.random.default_rng(51 + rb), n + 1, chunks,
+                               bucket)
+    assert (np.asarray(words) < 0).any()
+    ids = jnp.asarray([0, 3, 6, 3, 1, 5], jnp.int32)
+    live = jnp.asarray([1, 0, 1, 1, 0, 1], jnp.int32) if guarded else None
+    call = functools.partial(
+        codec_pallas._dequantize_flat_impl, bits=8, bucket_size=bucket,
+        interpret=True, tc=ppb * chunks,
+        out_dtype=np.dtype(jnp.bfloat16 if k > 1 else np.float32),
+        row_width=128 * k if k > 1 else None)
+    want = call(words, meta, None, ids, live)
+    got = call(words, meta, None, ids, live, unpack="bytes")
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_array_equal(_bits_of(got), _bits_of(want))
+    levels = np.unique(np.asarray(got[0], np.float32))
+    np.testing.assert_array_equal(levels, np.arange(256))
+    if guarded:
+        assert not _bits_of(got)[[1, 4]].any()
+
+
+def _shifted_values(jaxpr):
+    """Values that go through ``shift_right_arithmetic`` in ``jaxpr``, its
+    kernels and their branches included."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "shift_right_arithmetic":
+            total += int(np.prod(eqn.outvars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += _shifted_values(sub)
+    return total
+
+
+@pytest.mark.parametrize("guarded", [False, True], ids=["bare", "live"])
+@pytest.mark.parametrize("bits", [4, 8])
+def test_byte_unpack_is_honoured_at_eight_bits_alone(bits, guarded):
+    """The static argument asks; the kernel answers by the width it is
+    given. At 8 bits the ring's kernel shifts at most a quarter of the
+    values the plane loop shifts (its 8 rounds a value are three rounds of
+    swaps over half the words and three byte shifts); at any other width
+    the call traces the plane loop's jaxpr, the cells' 4-bit controls'."""
+    n = 8
+    call = _guard_call(bits, 2, True)
+    args = [jax.ShapeDtypeStruct((n + 1, bits * 4, 128), jnp.int32),
+            jax.ShapeDtypeStruct((n + 1, 2, 32), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.int32)]
+    args += [args[2]] if guarded else []
+
+    def traced(**kw):
+        return jax.make_jaxpr(
+            lambda w, m, i, *l: call(w, m, None, i, *l, **kw))(*args)
+
+    planes, asked = traced(), traced(unpack="bytes")
+    assert codec_pallas.unpack_taken("bytes", bits) == (
+        "bytes" if bits == 8 else "planes")
+    assert codec_pallas.unpack_taken("planes", bits) == "planes"
+    if bits == 8:
+        assert 0 < 4 * _shifted_values(asked.jaxpr) <= _shifted_values(
+            planes.jaxpr)
+    else:
+        assert str(asked) == str(planes)
+
+
+@pytest.mark.tpu  # compiled Mosaic lowering of the byte unpack
+@pytest.mark.parametrize("width", [1024, 512], ids=["trinity", "smallthinker"])
+def test_byte_unpack_tpu(width):
+    """The ring's kernel at both window cells' page shapes (256 tokens of
+    1,024 and of 512 values, a page and two pages a grid step), guarded as
+    the ring is: the compiled byte unpack against the compiled plane loop."""
+    rng = np.random.default_rng(51)
+    live = _ring_guard()
+    n = live.size
+    words, meta = _random_pool(rng, n + 1, 256 * width // (32 * 512), 512)
+    ids = jnp.asarray(rng.permutation(n + 1)[:n], jnp.int32)
+    kw = dict(bits=8, bucket_size=512, tc=16, out_dtype=jnp.bfloat16,
+              row_width=width, name="cgx_dequantize_window", live=live)
+    want = _bits_of(codec_pallas.dequantize_pages(words, meta, ids, **kw))
+    got = _bits_of(codec_pallas.dequantize_pages(
+        words, meta, ids, unpack="bytes", **kw))
+    np.testing.assert_array_equal(got, want)
+    assert want[np.asarray(live, bool)].any()
 
 
 # ---------------------------------------------------------------------------
@@ -1388,7 +1513,8 @@ def _cell_cases():
     ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     yield "smallthinker-decode-pages-window", "dequantize_pages", dict(
         small, rows=48 * 17, pool=817, window=True,
-    ), {"dequantize_pages.window": "pallas_paged.meta_planes", "dequantize_rows": flat,
+    ), {"dequantize_pages.window": "pallas_paged.meta_planes",
+        "dequantize_pages.window.unpack": "bytes", "dequantize_rows": flat,
         "dequantize": "pallas_flat.bfloat16"}, {"_pages_tc": 16}
     # Its commits: the tails that filled in the decode loop (4 of the 48
     # lanes a call); a padded prompt's 2 or 32 pages in prefill_pages (512
@@ -1410,7 +1536,8 @@ def _cell_cases():
     ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     yield "trinity-decode-pages-window", "dequantize_pages", dict(
         trinity, rows=64 * 17, pool=1089, window=True,
-    ), {"dequantize_pages.window": "pallas_paged.meta_planes", "dequantize_rows": flat,
+    ), {"dequantize_pages.window": "pallas_paged.meta_planes",
+        "dequantize_pages.window.unpack": "bytes", "dequantize_rows": flat,
         "dequantize": "pallas_flat.bfloat16"}, {"_pages_tc": 16}
     # Its commits: the tails that filled in the decode loop (4 of the 64
     # lanes a call); a padded prompt's 4 or 16 pages in prefill_pages (1,024

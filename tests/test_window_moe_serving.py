@@ -575,6 +575,46 @@ def test_gpt2s_decode_step_on_the_kernel_is_the_parents(monkeypatch):
     assert serving_guard.decode_step_sha(server) == "9bf94dc333d9f7d6"
 
 
+@pytest.mark.parametrize("bits,unpack", [("8", "bytes"), ("4", "planes")])
+@pytest.mark.parametrize("adapter", ["window_moe", "afmoe"])
+def test_the_rings_read_asks_for_the_byte_unpack_and_the_tables_does_not(
+        adapter, bits, unpack, monkeypatch):
+    """ISSUE 51: on the kernel (pages of 32 tokens x 2 K/V heads x 64 in
+    buckets of 128: one whole chunk, fetched by id as on the chip) every
+    window layer's ``k`` and ``v`` read in the decode step of both window
+    adapters notes ``dequantize_pages.window.unpack.bytes`` beside its
+    lowering, ``.unpack.planes`` at 4 bits, which the kernel does not
+    honour; the global layers' reads note their lowering alone."""
+    from benchmark import weights_afmoe
+    from torch_cgx_tpu.models.afmoe import AfmoeConfig
+    from torch_cgx_tpu.serving.window import AfmoeServer
+    import test_afmoe_serving
+
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    monkeypatch.setenv("CGX_COMPRESSION_BUCKET_SIZE", "128")
+    monkeypatch.setenv("CGX_KV_BITS", bits)
+    hf, make, config, server_of, window_layers, global_layers = {
+        "window_moe": (HF, weights.make_params, WindowMoeConfig,
+                       WindowMoEServer, 6, 2),
+        "afmoe": (test_afmoe_serving.HF, weights_afmoe.make_params,
+                  AfmoeConfig, AfmoeServer, 4, 1),
+    }[adapter]
+    hf = dict(hf, head_dim=64)
+    server = server_of(
+        config.from_hf(hf, dtype=jnp.float32, q_block=16), make(hf, 51),
+        ServeConfig(page_tokens=32, max_batch=2, max_pages=12, max_seq=128,
+                    ship_depth=2))
+    sched = ContinuousBatchScheduler(server)
+    metrics.reset()
+    jax.make_jaxpr(sched._prog.decode_step)(server.p, sched._state)
+    site = "cgx.codec.lowering.dequantize_pages."
+    assert metrics.snapshot(site) == {
+        site + "pallas_paged.meta_planes": 2 * global_layers,
+        site + "window.pallas_paged.meta_planes": 2 * window_layers,
+        site + f"window.unpack.{unpack}": 2 * window_layers,
+    }
+
+
 def test_a_long_prompt_through_the_kernel_serves_the_loops_tokens(
         params, served, monkeypatch):
     """The run prefilled at more than twice the window (75 positions: five

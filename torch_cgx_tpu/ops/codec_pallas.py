@@ -325,6 +325,43 @@ def _plane_columns(planes):
     )
 
 
+def unpack_taken(asked: str, bits: int) -> str:
+    """How a decode of ``bits``-wide planes asked for the ``asked`` unpack
+    turns words into levels: ``bytes`` (:func:`_unpack_bytes`) is 8 bits'
+    alone, every other width keeps the plane loop (``planes``)."""
+    if asked not in ("planes", "bytes"):
+        raise ValueError(f"unpack={asked!r}: expected 'planes' or 'bytes'")
+    return asked if bits == 8 else "planes"
+
+
+def _unpack_bytes(words, tc: int, rb: int):
+    """The 8-bit unpack as a bit-matrix transpose: a pass's plane words
+    ``(tc * 8 * rb, 128)`` int32 (row ``(c * 8 + w) * rb + r``: plane ``w``
+    of chunk ``c``) -> the levels ``(tc, 32, rb, 128)`` int32 the plane loop
+    gives, in its sublane order.
+
+    A lane's 8 plane words are an 8 x 32 bit matrix: bit ``s`` of word ``w``
+    is bit ``w`` of sublane ``s``'s level. Three rounds of masked swaps
+    between the words 4, 2 and 1 apart transpose its four 8 x 8 blocks in
+    place, after which byte ``j`` of word ``w`` IS the level of sublane ``8j +
+    w``: 72 operations for the 8 words and a shift and a mask a level, where
+    the plane loop spends 32 a level. The masks' top bits are zero, so the
+    arithmetic ``>>`` of a word whose bit 31 is set is exact. At ``rb`` 4
+    the swaps 4 and 2 apart are between whole (8, 128) tiles; the last
+    round's words share a tile, and Mosaic splits them into the half-filled
+    tiles it holds ``(tc, 32, rb, 128)`` in anyway (docs/PERF_NOTES.md)."""
+    x = words.reshape(tc, 8 * rb, 128)
+    for d, m in ((4, 0x0F0F0F0F), (2, 0x33333333), (1, 0x55555555)):
+        pairs = x.reshape(tc, 4 // d, 2, d * rb, 128)
+        lo, hi = pairs[:, :, 0], pairs[:, :, 1]
+        t = ((lo >> d) ^ hi) & np.int32(m)
+        x = jnp.stack([lo ^ (t << d), hi ^ t], axis=2).reshape(x.shape)
+    lvl = jnp.stack(
+        [(x >> (8 * j) if j else x) & 0xFF for j in range(4)], axis=1
+    )
+    return lvl.reshape(tc, CHUNK_BUCKETS, rb, 128)
+
+
 def _pipe_tc(n_chunks: int, bucket_size: int) -> int:
     """Chunks per block for the flat fast path: the largest candidate within
     the VMEM cap that divides the total chunk count (the flat grid tiles all
@@ -422,7 +459,7 @@ def _quantize_flat_impl(
     jax.jit,
     static_argnames=(
         "bits", "bucket_size", "interpret", "tc", "with_add", "out_dtype",
-        "row_width", "name",
+        "row_width", "name", "unpack",
     ),
 )
 def _dequantize_flat_impl(
@@ -440,6 +477,7 @@ def _dequantize_flat_impl(
     out_dtype=np.dtype(np.float32),
     row_width: Optional[int] = None,
     name: str = "cgx_dequantize_flat",
+    unpack: str = "planes",
 ):
     """Zero-relayout dequantize: words (rows, W) int32 + meta (rows, nb_r, 2)
     (the wire's pairs; a pool's planes under ``page_ids``, below)
@@ -499,7 +537,15 @@ def _dequantize_flat_impl(
     name the block its operand already holds, so Pallas issues no copy: the
     ids are forward-filled along each page operand's sequence in front of
     the call). What is left of a dead page is the write of its zero rows.
-    ``None`` is the call without a guard, jaxpr for jaxpr."""
+    ``None`` is the call without a guard, jaxpr for jaxpr.
+
+    ``unpack``: how a pass turns its plane words into levels. ``"planes"``,
+    the default and every width's but 8 (:func:`unpack_taken`), is the loop
+    over the planes, the program every caller that does not ask keeps;
+    ``"bytes"`` is :func:`_unpack_bytes`, asked for by the ring's read
+    alone (``ops/paged_kv.gather_dequant_pages``) until the tables' roofline
+    readers count their bytes true (ROADMAP.md A1(a), A4c). The levels, and
+    so every stored value, are the same bit for bit."""
     b = bucket_size
     rb = b // 128
     if page_ids is not None and (meta.ndim != 3 or meta.shape[1] != 2):
@@ -519,13 +565,16 @@ def _dequantize_flat_impl(
     t_rows = s_rows // k  # output rows a pass
 
     def _decode(w_ref, m_ref):
-        w4 = w_ref[:].reshape(tc_body, bits, rb, 128)
-        sub = jax.lax.broadcasted_iota(
-            jnp.int32, (tc_body, CHUNK_BUCKETS, rb, 128), 1
-        )
-        lvl = jnp.zeros((tc_body, CHUNK_BUCKETS, rb, 128), jnp.int32)
-        for w in range(bits):
-            lvl = lvl | (((w4[:, w : w + 1, :, :] >> sub) & 1) << w)
+        if unpack_taken(unpack, bits) == "bytes":
+            lvl = _unpack_bytes(w_ref[:], tc_body, rb)
+        else:
+            w4 = w_ref[:].reshape(tc_body, bits, rb, 128)
+            sub = jax.lax.broadcasted_iota(
+                jnp.int32, (tc_body, CHUNK_BUCKETS, rb, 128), 1
+            )
+            lvl = jnp.zeros((tc_body, CHUNK_BUCKETS, rb, 128), jnp.int32)
+            for w in range(bits):
+                lvl = lvl | (((w4[:, w : w + 1, :, :] >> sub) & 1) << w)
         if page_ids is None:  # the wire's pairs, a bucket a sublane
             m2 = m_ref[:]
             unit = m2[:, 0:1].reshape(tc_body, CHUNK_BUCKETS, 1, 1)
@@ -1087,6 +1136,7 @@ def dequantize_pages(
     interpret: bool = False,
     name: str = "cgx_dequantize_flat",
     live: Optional[jax.Array] = None,
+    unpack: str = "planes",
 ) -> jax.Array:
     """The paged read: decode pool rows ``page_ids (n,)`` of a page pool
     kept in the flat kernel's operand layout (``words (pool rows, W/128,
@@ -1102,7 +1152,7 @@ def dequantize_pages(
     return _dequantize_flat_impl(
         words, meta, None, page_ids, live,
         bits=bits, bucket_size=bucket_size, interpret=interpret, tc=tc,
-        out_dtype=store, row_width=row_width, name=name,
+        out_dtype=store, row_width=row_width, name=name, unpack=unpack,
     ).astype(out_dtype)
 
 

@@ -131,16 +131,18 @@ def dequantize_pages(
     row_width: int,
     name: str = "cgx_dequantize_flat",
     live: Optional[jax.Array] = None,
+    unpack: str = "planes",
 ) -> jax.Array:
     """The paged cache read on Pallas dispatch
     (``codec_pallas.dequantize_pages``); ``ops/paged_kv.py`` decides
     whether a pool takes it (:func:`takes_pallas` and
-    ``PageSpec.paged_read_tile``), what the kernel is called and which
-    entries it guards (``live``)."""
+    ``PageSpec.paged_read_tile``), what the kernel is called, which
+    entries it guards (``live``) and how it unpacks 8-bit planes
+    (``unpack``)."""
     return codec_pallas.dequantize_pages(
         words, meta, page_ids, bits=cc.bits, bucket_size=cc.bucket_size,
         tc=tile, out_dtype=out_dtype, row_width=row_width,
-        interpret=not _on_tpu(), name=name, live=live,
+        interpret=not _on_tpu(), name=name, live=live, unpack=unpack,
     )
 
 

@@ -257,8 +257,11 @@ def gather_dequant_pages(
     callers mask attention scores by the lane's committed token count,
     never by inspecting decoded values. Without ``live`` every table entry
     is decoded. ``window``: the table is a window layer's ring ``(B,
-    ring)``; the kernel is then called :data:`WINDOW_READ` and the call site
-    counted as ``cgx.codec.lowering.dequantize_pages.window.*``.
+    ring)``; the kernel is then called :data:`WINDOW_READ`, asked for the
+    byte unpack of 8-bit planes (``codec_pallas._unpack_bytes``; the tables'
+    read keeps the plane loop) and the call site counted as
+    ``cgx.codec.lowering.dequantize_pages.window.*`` (``.unpack.bytes``, or
+    ``.unpack.planes`` at another width, beside the lowering's name).
     ``live (B, P) bool`` (the ring's caller has one:
     ``adapter.ring_live``): the entries that hold a key some query can
     see. A dead entry's page is neither fetched nor decoded and its rows
@@ -314,9 +317,16 @@ def gather_dequant_pages(
         "pallas_paged.meta_planes" if tile else "xla_gather",
     )
     if tile:
+        ring = {}
+        if window:
+            ring = {"name": WINDOW_READ, "unpack": "bytes"}
+            codec_pallas.note_lowering(
+                "dequantize_pages.window.unpack",
+                codec_pallas.unpack_taken("bytes", spec.bits),
+            )
         rows = ops_dispatch.dequantize_pages(
             words, meta, ids, spec.cc, tile=tile, out_dtype=dtype,
-            row_width=width, **({"name": WINDOW_READ} if window else {}),
+            row_width=width, **ring,
             live=None if live is None else live.reshape(-1).astype(jnp.int32),
         )
     else:
