@@ -1,6 +1,6 @@
 """The serving plane's four layers (docs/SERVING.md, "The pieces"):
 ``adapter.py`` under the adapters (``gpt2.py``, ``latent.py``, ``hybrid.py``,
-``window.py``) and under ``programs.py``, which is under ``scheduler.py``.
+``window.py``, ``loop.py``) and under ``programs.py``, which is under ``scheduler.py``.
 Imports point one way, every server is an ``Adapter`` with the defaults the
 copies it lost had, the names the benchmark reaches into the scheduler for
 are where it looks, the state ``programs.fresh_state`` lays out is the one
@@ -39,6 +39,7 @@ from torch_cgx_tpu.serving.hybrid import (  # noqa: E402
     HybridSSMServer,
 )
 from torch_cgx_tpu.serving.latent import LatentMoEServer  # noqa: E402
+from torch_cgx_tpu.serving.loop import LoopServer  # noqa: E402
 from torch_cgx_tpu.serving.scheduler import (  # noqa: E402
     ContinuousBatchScheduler,
     Request,
@@ -53,11 +54,12 @@ import test_afmoe_serving as afmoe  # noqa: E402
 import test_hybrid_serving as granite  # noqa: E402
 import test_latent_serving as latent  # noqa: E402
 import test_ling_hybrid_serving as ling  # noqa: E402
+import test_loop_serving as loop  # noqa: E402
 import test_olmo_hybrid_serving as olmo  # noqa: E402
 import test_window_moe_serving as window  # noqa: E402
 
 SERVING = Path(adapter_mod.__file__).parent
-ADAPTERS = ("gpt2", "latent", "hybrid", "window")
+ADAPTERS = ("gpt2", "latent", "hybrid", "window", "loop")
 GPT2_HF = dict(vocab_size=512, n_layer=2, n_head=4, n_embd=64,
                n_positions=128, init={})
 GPT2_CFG = GPT2Config(vocab_size=512, n_layer=2, n_head=4, d_model=64,
@@ -72,6 +74,7 @@ SERVERS = {
     "hybrid_kda_mla": (HybridLatentMoEServer, ling._cfg, ling._serve),
     "window_moe": (WindowMoEServer, window._cfg, window._serve),
     "afmoe": (AfmoeServer, afmoe._cfg, afmoe._serve),
+    "loop": (LoopServer, loop._cfg, loop._serve),
 }
 HYBRIDS = ("hybrid_ssm", "hybrid_gdn", "hybrid_kda_mla")
 
@@ -226,6 +229,10 @@ PARENT_STATE = {
     # No parent: as PR 45 first built it (five layers, four of them rings).
     "afmoe": {"8": ("8b7c0e9004b7cc2e", "977e0ea45e30c2b2", 37),
               "0": ("ac233ee66a5ea676", "11b35486f9f7b485", 27)},
+    # No parent: as PR 52 first built it (two layers, three passes: the
+    # tree is GPT-2's, the pools' and the tails' leaves carry the passes).
+    "loop": {"8": ("5b9dad382d4ed71c", "3869820bd16b3b0f", 18),
+             "0": ("67e35581ee0cc71b", "d46c8a7b5634a377", 14)},
 }
 
 

@@ -338,7 +338,7 @@ def gather_dequant_pages(
 
 
 def append_tail_rows(tail: jax.Array, tail_idx: jax.Array, fresh: jax.Array,
-                     dtype) -> Tuple[jax.Array, jax.Array]:
+                     dtype, at_pass=None) -> Tuple[jax.Array, jax.Array]:
     """A decode step's write into the lanes' raw tail, and the tail as the
     attention reads it. ``tail (B, page_tokens, width) f32`` is kept as
     those rows, a position's heads side by side as a page holds them;
@@ -353,12 +353,24 @@ def append_tail_rows(tail: jax.Array, tail_idx: jax.Array, fresh: jax.Array,
     n_head, d_head)``, every layer of every step rewrote the whole tail
     through a ``where`` and copied it to rows, the two tiling differently;
     the ``where`` over rows was measured too and lost to this by 1.7-2.0
-    ms a step: PERF.md section 6, PR 36.)"""
-    b, _, width = tail.shape
-    tail = tail.at[jnp.arange(b), tail_idx].set(
+    ms a step: PERF.md section 6, PR 36.)
+
+    ``at_pass`` (a traced index): ``tail`` is a looped adapter's ``(T, B,
+    page_tokens, width)``, every pass's rows of the lanes, carried whole
+    through the loop over the passes; the row goes to pass ``at_pass``'s
+    tail, in place, and that pass's rows alone are read. Returns ``(the new
+    (T, ...) tail, the pass's rows in dtype)``."""
+    b, _, width = tail.shape[-3:]
+    if at_pass is None:
+        tail = tail.at[jnp.arange(b), tail_idx].set(
+            fresh.reshape(b, width).astype(jnp.float32)
+        )
+        return tail, tail.astype(dtype)
+    tail = tail.at[at_pass, jnp.arange(b), tail_idx].set(
         fresh.reshape(b, width).astype(jnp.float32)
     )
-    return tail, tail.astype(dtype)
+    rows = jax.lax.dynamic_index_in_dim(tail, at_pass, 0, keepdims=False)
+    return tail, rows.astype(dtype)
 
 
 def commit_page_rows(pool, page_ids: jax.Array, rows: jax.Array, spec: PageSpec):

@@ -33,6 +33,9 @@ inference:
   page table: SmallThinker's block (dropless experts routed from the
   block's input) and Trinity's (``afmoe``: sandwich norms, gated QK-normed
   attention, a held share of experts beside a shared one).
+* :mod:`.loop` — the looped adapter: ``k``, ``v`` pages on every layer, the
+  layers run ``cache_passes`` times a token and every pass keeps pages and
+  tails of its own (Ouro's block).
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -53,5 +56,6 @@ from .hybrid import (  # noqa: F401
     HybridSSMServer,
 )
 from .window import AfmoeServer, WindowMoEServer  # noqa: F401
+from .loop import LoopServer  # noqa: F401
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401

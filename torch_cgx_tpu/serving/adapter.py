@@ -27,6 +27,19 @@ alone, and its mask is made of positions (:func:`ring_masks`). An adapter
 that states no window builds exactly the programs and the state it built
 before there were classes.
 
+A cache layer is not a weight layer. A looped model runs its ``n_layer``
+layers ``T`` times a token and every pass leaves K and V of its own, which
+that pass alone reads: the adapter states ``cache_passes = T`` and the
+programs give every layer's pools and tails a PASS dimension ("Passes" in
+docs/SERVING.md). A stream's pool then holds ``T x (max_pages + 1)`` rows,
+page ``p`` of pass ``t`` at row ``t x (max_pages + 1) + p`` (a scratch row
+a pass), so one page id names a page's ``2 x n_layer x T`` slabs and the
+page allocator, the page table, ``n_pages`` and ``tail_len`` stay one a
+lane; a tail is ``(T, lanes, page_tokens, width)``. A pass reads through
+the lane's table plus its offset (:func:`pass_view`). With ``cache_passes``
+1, which every adapter but ``serving/loop.py``'s states, nothing has the
+dimension and every program is what it was.
+
 :class:`Adapter` is the protocol, with the defaults every adapter shares; a
 model is a subclass in a module of its own (``gpt2.py``, ``latent.py``,
 ``hybrid.py``, ``window.py``) that imports this module, ``models/`` and
@@ -225,9 +238,25 @@ def ring_live(serve: ServeConfig, state, window: int):
     return (page >= 0) & (state["pos"][:, None] - newest_row < window)
 
 
+def pass_view(state, tails, at_pass, serve: ServeConfig):
+    """``state`` as pass ``at_pass`` (a traced index into the pass
+    dimension) of a looped adapter's decode step reads it: the lane's
+    ``page_table`` moved by the pass's offset into the pools, ``at_pass x
+    (max_pages + 1)`` rows (a sentinel entry then names the scratch row of
+    the pass before, in bounds and dead), and ``tails {stream: each
+    layer's (T, B, page_tokens, width)}`` as the step's passes so far left
+    them, in the place of the state's. The pools are the state's own: a
+    loop over the passes carries the tails and closes over the pools."""
+    return {
+        **state,
+        "page_table": state["page_table"] + at_pass * (serve.max_pages + 1),
+        **{f"tail_{name}": layers for name, layers in tails.items()},
+    }
+
+
 def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
                      dtype, window: bool = False, live=None,
-                     paged_only: bool = False):
+                     paged_only: bool = False, at_pass=None):
     """A layer's cache as its attention contracts it, at a decode position:
     for each of the layer's streams, in order, this token's payload (the
     matching entry of ``fresh``, ``(B, ...)`` of the stream's width) written
@@ -244,12 +273,18 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
     dead entry costs the store of its zero rows; a stream that gathers (a
     latent cache's 64-wide ``kr``; a raw pool) is read whole, since there a
     guard is one more pass over the decoded table and its dead rows, finite
-    whatever they hold, are masked anyway."""
+    whatever they hold, are masked anyway. ``at_pass``: ``state`` is a
+    looped adapter's :func:`pass_view`, whose tails keep every pass's rows
+    ``(T, B, page_tokens, width)``: the payload goes to pass ``at_pass``'s
+    row and that pass's rows are the ones read (the new tail returned is
+    the whole ``(T, ...)`` array)."""
     table = state["ring_table" if window else "page_table"]
     pages, tails, new = {}, {}, {}
     for (name, spec), value in zip(layer_streams, fresh):
         new[name], tails[name] = paged_kv.append_tail_rows(
-            state[f"tail_{name}"][layer], tail_idx, value, dtype
+            state[f"tail_{name}"][layer], tail_idx, value, dtype,
+            # four operands without a pass: what tests put in its place take
+            *(() if at_pass is None else (at_pass,))
         )
         guard = live
         if paged_only and live is not None and not spec.paged_read_tile(
@@ -264,7 +299,8 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
 
 
 def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
-                 score_divisor, window: bool = False, live=None):
+                 score_divisor, window: bool = False, live=None,
+                 at_pass=None):
     """One decode position of an attention layer over a lane's cache: this
     token's ``k`` and ``v (B, 1, Hk, dh)`` into the raw tails and the
     committed pages read as ``dt`` rows where they lie
@@ -276,11 +312,13 @@ def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
     global layer's :func:`page_live`, a window layer's :func:`ring_live`.
     The read skips the entries it leaves out, whose rows the mask hides
     anyway (an adapter that hands it down says so:
-    ``Adapter.guards_global_read``). Returns ``(o (B, H * dh), {stream: its
-    new tail})``."""
+    ``Adapter.guards_global_read``). ``at_pass``: the pass of a looped
+    adapter whose :func:`pass_view` ``state`` is (:func:`layer_cache_rows`).
+    Returns ``(o (B, H * dh), {stream: its new tail})``."""
     tail_idx, mask_c, mask_t = masks
     pages, tails, new = layer_cache_rows(
-        state, layer, layer_streams, tail_idx, (k, v), dt, window, live
+        state, layer, layer_streams, tail_idx, (k, v), dt, window, live,
+        at_pass=at_pass,
     )
     o = decode_attention(
         q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],
@@ -316,6 +354,15 @@ class Adapter:
         its table holds (``cgx.serve.kv.decoded_pages.global``,
         ``.table_pages.global``). ``GPT2Server`` alone does not
         (``serving/gpt2.py`` says why).
+    ``cache_passes``
+        how many times a token goes through the ``n_layer`` layers, each
+        pass leaving cache entries of its own that it alone reads: 1 for
+        every model but a looped one. Above 1 every layer's pools and
+        tails carry a pass dimension (the module's text above), ``state``
+        holds ``tail_<stream>[l]`` as ``(cache_passes, B, page_tokens,
+        width)``, ``prefill_forward`` returns a layer's payload as
+        ``(cache_passes, B, S, n_head, d_head)`` and ``decode_forward`` a
+        layer's new tail with the same leading dimension.
     ``layer_name(l)``
         the layer's ``kv_page`` edge name.
     ``cache_streams(l)``
@@ -358,6 +405,7 @@ class Adapter:
     kind: str
     step_counters: Tuple[str, ...] = ()
     guards_global_read: bool = False
+    cache_passes: int = 1
 
     def __init__(self, model_cfg, params,
                  serve: Optional[ServeConfig] = None):

@@ -31,6 +31,15 @@ meta was relaid whole in front of every read: ``ops/paged_kv.py``,
 one. The last row is scratch: a padded slot of the ``commit`` program and a
 prefill's last page that is a tail write there.
 
+An adapter whose layers run ``T = cache_passes`` times a token
+(``serving/adapter.py``, "A cache layer is not a weight layer") has ``T``
+such blocks of rows in every pool, one after another, pass ``t``'s page
+``p`` at row ``t x (max_pages + 1) + p``, and tails ``(T, max_batch,
+page_tokens, width)``. A page id, a lane's table row, ``n_pages`` and
+``tail_len`` are what they are for any adapter; ``commit``,
+``prefill_pages`` and ``admit_lane`` write all ``T`` passes' rows of the ids
+they are given, a layer and a stream in one call.
+
 Which of the programs built here a scheduler runs now is the scheduler's
 decision (its LRU and the key it is held under); nothing here knows of it.
 """
@@ -157,6 +166,22 @@ def build(server) -> SimpleNamespace:
     sv = server.serve
     windows = _resolved_windows(server)
     ring = _ring(server, streams, windows)
+    passes = int(server.cache_passes)
+    if passes > 1 and (ring or state_names):
+        raise ValueError(
+            f"adapter {server.kind!r} states {passes} cache passes beside "
+            f"{'window layers' if ring else f'the state streams {state_names}'}"
+            ": a pass dimension is the global page pools' and the tails'"
+        )
+
+    def pass_rows(ids):
+        """The pool rows the page ids ``ids (n,)`` name in every pass,
+        ``(passes x n,)``, pass-major: ``ids`` themselves for an adapter of
+        one pass."""
+        if passes == 1:
+            return ids
+        offsets = (sv.max_pages + 1) * jnp.arange(passes, dtype=ids.dtype)
+        return (offsets[:, None] + ids[None, :]).reshape(-1)
 
     def decode_step(params, state):
         """One token for every lane. Returns the new state and what the
@@ -187,8 +212,9 @@ def build(server) -> SimpleNamespace:
         page has slid out of the window), ``K = ServeConfig.commit_lanes``: the K lanes'
         tails alone are gathered (rows as they are kept, flattened to ``(K,
         page_tokens * width)`` payloads), quantized and scattered, a layer
-        and a stream at a time, and their ``page_table`` slot,
-        ``n_pages`` and ``tail_len`` written by scatter. Tails fill at
+        and a stream at a time (every pass's tail of a lane in the one
+        call, into the pass's rows of the pool), and their ``page_table``
+        slot, ``n_pages`` and ``tail_len`` written by scatter. Tails fill at
         ``max_batch / page_tokens`` a step, so a program over every lane's
         tail would throw nearly all of its work away. A slot the
         caller has no tail for names any valid lane and the scratch row
@@ -201,8 +227,12 @@ def build(server) -> SimpleNamespace:
             {
                 name: paged_kv.commit_page_rows(
                     state["pools"][layer][name],
-                    ring_ids if windows[layer] else page_ids,
-                    state[f"tail_{name}"][layer][lanes].reshape(k, -1), spec,
+                    ring_ids if windows[layer] else pass_rows(page_ids),
+                    state[f"tail_{name}"][layer][lanes].reshape(k, -1)
+                    if passes == 1 else
+                    state[f"tail_{name}"][layer][:, lanes].reshape(
+                        passes * k, -1),
+                    spec,
                 )
                 for name, spec in streams[layer]
             }
@@ -268,7 +298,10 @@ def build(server) -> SimpleNamespace:
         window layer writes the prompt's last ``ring_ids.shape[0]`` padded
         pages alone (at most ``ring + 1``: the pages its ring keeps are
         among them), into ``ring_ids``; the host names the scratch row for
-        those that have slid out already."""
+        those that have slid out already. An adapter of several passes
+        hands a layer's payload as ``(passes, 1, padded tokens, H, Dh)``:
+        every pass's pages go into that pass's rows of ``ids`` in the one
+        call, and the tails come out ``(L, passes, page_tokens, H * Dh)``."""
         first, payloads = prefill(params, tokens, positions, last_idx)
         n_pages = ids.shape[0]
         live = jax.lax.broadcasted_iota(
@@ -278,15 +311,21 @@ def build(server) -> SimpleNamespace:
         for layer in range(n_layer):
             pool, written = pools[layer], {}
             for name, spec in streams[layer]:
-                x = payloads[name][layer][0]  # (padded tokens, H, Dh)
-                rows = x.reshape(n_pages, -1)
+                # (padded tokens, H, Dh); (passes, padded tokens, H, Dh)
+                x = (payloads[name][layer][0] if passes == 1
+                     else payloads[name][layer][:, 0])
+                rows = x.reshape(passes * n_pages, -1)
                 written[name] = paged_kv.commit_page_rows(
                     pool[name],
                     *((ring_ids, rows[-ring_ids.shape[0]:])
-                      if windows[layer] else (ids, rows)), spec,
+                      if windows[layer] else (pass_rows(ids), rows)), spec,
                 )
                 tails[name].append(jnp.where(
-                    live, x[-sv.page_tokens:].reshape(sv.page_tokens, -1),
+                    live,
+                    x[-sv.page_tokens:].reshape(sv.page_tokens, -1)
+                    if passes == 1 else
+                    x[:, -sv.page_tokens:].reshape(
+                        passes, sv.page_tokens, -1),
                     0.0,
                 ))
                 if (observe_qerr and spec.quantized
@@ -326,7 +365,8 @@ def build(server) -> SimpleNamespace:
             for name in which:
                 out[f"{prefix}_{name}"] = tuple(
                     None if t is None
-                    else t.at[lane].set(written[name][holders[name][layer]])
+                    else (t.at[lane] if passes == 1 else t.at[:, lane]).set(
+                        written[name][holders[name][layer]])
                     for layer, t in enumerate(state[f"{prefix}_{name}"])
                 )
         return out
@@ -349,10 +389,12 @@ def build(server) -> SimpleNamespace:
         windows=windows,
         window=max(windows),
         ring=ring,
-        # Cache streams over the layers of each class: (global, window).
+        passes=passes,
+        # Cache streams over the layers (and passes) of each class: (global,
+        # window).
         class_streams=tuple(
-            sum(len(layer) for layer, w in zip(streams, windows)
-                if bool(w) == ringed)
+            passes * sum(len(layer) for layer, w in zip(streams, windows)
+                         if bool(w) == ringed)
             for ringed in (False, True)
         ),
         state_streams=state_streams,
@@ -375,13 +417,16 @@ def fresh_state(prog: SimpleNamespace, serve: ServeConfig) -> Dict:
     streams = prog.streams
     b = serve.max_batch
     ring = prog.ring
+    passes = prog.passes
     pools = tuple(
         {
             # +1 row: scratch, where a padded slot of commit() and a
             # prefill's last page that is a tail write; never read. A
-            # window layer holds a ring a lane, whatever ``max_seq``.
+            # window layer holds a ring a lane, whatever ``max_seq``. An
+            # adapter of several passes holds as many such blocks of rows.
             name: paged_kv.empty_pool(
-                (b * ring if window else serve.max_pages) + 1, spec)
+                passes * ((b * ring if window else serve.max_pages) + 1),
+                spec)
             for name, spec in layer
         }
         for layer, window in zip(streams, prog.windows)
@@ -393,7 +438,8 @@ def fresh_state(prog: SimpleNamespace, serve: ServeConfig) -> Dict:
     tails = {
         f"tail_{name}": tuple(
             None if spec is None else jnp.zeros(
-                (b, spec.page_tokens, spec.n_head * spec.d_head),
+                ((passes,) if passes > 1 else ())
+                + (b, spec.page_tokens, spec.n_head * spec.d_head),
                 jnp.float32,
             )
             for spec in (dict(layer).get(name) for layer in streams)

@@ -326,6 +326,7 @@ class ContinuousBatchScheduler:
         self._cache_gen = self.cache.generation
         self._state_bytes = 0  # the recurrent state held (memledger owner)
         self._window_bytes = 0  # the window layers' pools (memledger owner)
+        self._pass_bytes = 0  # a looped adapter's pools and tails (the same)
         self._state = self._fresh_state()
         self._lanes: List[Optional[Request]] = [None] * sv.max_batch
         self._waiting: List[Request] = []  # local-prefill queue
@@ -396,6 +397,8 @@ class ContinuousBatchScheduler:
         state = programs.fresh_state(self._prog, self.server.serve)
         if self._prog.ring:
             self._note_window_pools(state["pools"])
+        if self._prog.passes > 1:
+            self._note_pass_cache(state)
         state_bytes = sum(
             t.nbytes for name in self._prog.state_names
             for t in state[f"state_{name}"] if t is not None
@@ -434,6 +437,28 @@ class ContinuousBatchScheduler:
         memledger.note_alloc("serve.kv.window", n=sv.max_batch,
                              nbytes=held[True])
         self._window_bytes = held[True]
+
+    def _note_pass_cache(self, state) -> None:
+        """What a looped adapter's pass dimension holds: the pools (every
+        pass's rows, ``cgx.serve.kv.pool_bytes.global``) and the raw tails
+        (every pass's, ``cgx.serve.kv.tail_bytes``) as gauges, and both
+        together as the memory ledger's owner ``serve.kv.passes``, ``n``
+        the passes."""
+        held = {
+            "pool_bytes.global": sum(
+                a.nbytes for a in jax.tree_util.tree_leaves(state["pools"])),
+            "tail_bytes": sum(
+                t.nbytes for name in self._prog.names
+                for t in state[f"tail_{name}"] if t is not None),
+        }
+        for name, value in held.items():
+            metrics.set(f"cgx.serve.kv.{name}", float(value))
+        passes, nbytes = self._prog.passes, sum(held.values())
+        if self._pass_bytes:  # a rebuild drops the old pools and tails
+            memledger.note_release("serve.kv.passes", n=passes,
+                                   nbytes=self._pass_bytes)
+        memledger.note_alloc("serve.kv.passes", n=passes, nbytes=nbytes)
+        self._pass_bytes = nbytes
 
     def _maybe_rebuild(self) -> None:
         """Program-era and cache-generation checks, once per step.
@@ -848,13 +873,15 @@ class ContinuousBatchScheduler:
 
     def _note_pages(self, n_pages: int) -> None:
         """``n_pages`` pages of every stream of every layer went into the
-        pools (of a window layer no more than its ring keeps): the wire
-        plane's ``kv_page`` accounting."""
+        pools (of a window layer no more than its ring keeps; of a looped
+        adapter's layer one a pass): the wire plane's ``kv_page``
+        accounting."""
         if not n_pages:
             return
         for layer, layer_streams in enumerate(self._prog.streams):
             kept = (min(n_pages, self._prog.ring)
-                    if self._prog.windows[layer] else n_pages)
+                    if self._prog.windows[layer]
+                    else n_pages * self._prog.passes)
             for _, spec in layer_streams:
                 _account_pages(self.server.layer_name(layer), spec, kept)
 
@@ -1096,10 +1123,12 @@ class ContinuousBatchScheduler:
                         ended = time.perf_counter()
                     self._note_read(ready.owed, began, ended, prefill=True)
                 for layer, rows in ready.qerr_rows.items():
+                    rows = np.asarray(rows)  # every pass's padded pages
                     _observe_page_qerr(
                         self.server.layer_name(layer),
                         self._prog.specs[layer],
-                        np.asarray(rows)[: len(ready.page_ids)],
+                        rows.reshape(self._prog.passes, -1, rows.shape[-1])[
+                            :, : len(ready.page_ids)],
                         already_host=True,
                     )
             except Exception as e:
@@ -1270,7 +1299,9 @@ class ContinuousBatchScheduler:
         window layer those from the page that holds the oldest position the
         lane's token (at ``n_pages * page_tokens + tail_len``) can see. Those
         are the slots the step's read leaves open (``adapter.page_live`` and
-        ``adapter.ring_live`` on the device); a guarded global read's are
+        ``adapter.ring_live`` on the device; the tail's live rows are
+        counted beside them, ``kv.live_tail_rows``); a guarded global
+        read's are
         counted again as ``kv.decoded_pages.global``: what the read
         decodes, which was every slot of every lane's table
         (``kv.table_pages.global``) before the read had a guard."""
@@ -1278,6 +1309,10 @@ class ContinuousBatchScheduler:
         n_pages = self._n_pages[held]
         committed = float(n_pages.sum())
         metrics.add("cgx.serve.kv.live_pages.global", committed)
+        # The tail positions the step reads beside them, its own token's
+        # among them (the tail's mask: ``adapter.lane_masks``).
+        metrics.add("cgx.serve.kv.live_tail_rows",
+                    float((self._tail_len[held] + 1).sum()))
         if self.server.guards_global_read:
             metrics.add("cgx.serve.kv.decoded_pages.global", committed)
             metrics.add("cgx.serve.kv.table_pages.global",
@@ -1333,9 +1368,9 @@ class ContinuousBatchScheduler:
                     # the layer's leading stream is the one the qerr
                     # telemetry watches
                     lead = self._prog.streams[layer][0][0]
-                    rows = np.asarray(
-                        st[f"tail_{lead}"][layer]
-                    )[committed].reshape(len(committed), -1)
+                    tail = np.asarray(st[f"tail_{lead}"][layer])
+                    rows = (tail[committed] if self._prog.passes == 1
+                            else tail[:, committed])  # every pass's page
                     metrics.add("cgx.serve.host_reads")
                     _observe_page_qerr(
                         self.server.layer_name(layer), spec,

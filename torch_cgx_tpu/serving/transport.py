@@ -66,12 +66,19 @@ def require_kv_streams(server) -> None:
     """The disaggregated path ships K and V frames of every layer: refuse,
     up front and in plain words, an adapter whose cache streams are others
     (a latent cache), whose layers do not all leave K and V pages, or that
-    keeps a recurrent state a lane (no frame kind ships one), instead of
-    half-working."""
+    keeps a recurrent state a lane (no frame kind ships one), or whose
+    layers run several passes a token (a frame names a layer and a page and
+    no pass), instead of half-working."""
     refusal = (
         "the disaggregated prefill path ships K and V page frames "
         f"(serving/transport.py kinds); adapter {server.kind!r} "
     )
+    if server.cache_passes > 1:
+        raise ValueError(
+            f"{refusal}runs its layers {server.cache_passes} times a token "
+            "and keeps a cache a pass, which a frame's layer and page "
+            "cannot address, and is served with local prefill only"
+        )
     for layer in range(server.n_layer):
         names = [name for name, _ in server.cache_streams(layer)]
         state = [name for name, _, _ in server.state_streams(layer)]
