@@ -1360,24 +1360,59 @@ def test_byte_unpack_is_honoured_at_eight_bits_alone(bits, guarded):
         assert str(asked) == str(planes)
 
 
+def _table_guard(lanes, pages):
+    """A page table's ``live`` as ``adapter.page_live`` gives it, flat: a
+    lane's first ``n_pages`` slots; a full lane, a vacated one, the others
+    part way."""
+    n_pages = np.arange(lanes) * 3 % (pages + 1)
+    n_pages[:2] = pages, 0
+    return jnp.asarray((np.arange(pages)[None] < n_pages[:, None])
+                       .astype(np.int32).reshape(-1))
+
+
+# The 8-bit reads ``adapter.attend_paged`` asks the byte unpack of, at the
+# cells' page shapes: (tokens a page, row width, chunks a grid step, the
+# kernel's name, the table's guard).
+_BYTE_READS = {
+    # The rings of ISSUE 51: a page and two pages a grid step.
+    "trinity-ring": (256, 1024, 16, "cgx_dequantize_window", _ring_guard),
+    "smallthinker-ring": (256, 512, 16, "cgx_dequantize_window", _ring_guard),
+    # The page tables of ISSUE 53. Ouro: four chunks a page, four pages a
+    # grid step over the 16 x 14 slots a pass reads; Olmo: fifteen chunks, a
+    # page a step; granite: SmallThinker's and the latent ``c``'s geometry,
+    # two pages a step, over a (64, 6) table. (The window cells' global
+    # tables have their rings' page shapes.)
+    "ouro-table": (32, 2048, 16, "cgx_dequantize_flat",
+                   lambda: _table_guard(16, 14)),
+    "olmoh-table": (64, 3840, 15, "cgx_dequantize_flat",
+                    lambda: _table_guard(8, 5)),
+    "granite-table": (256, 512, 16, "cgx_dequantize_flat",
+                      lambda: _table_guard(64, 6)),
+}
+
+
 @pytest.mark.tpu  # compiled Mosaic lowering of the byte unpack
-@pytest.mark.parametrize("width", [1024, 512], ids=["trinity", "smallthinker"])
-def test_byte_unpack_tpu(width):
-    """The ring's kernel at both window cells' page shapes (256 tokens of
-    1,024 and of 512 values, a page and two pages a grid step), guarded as
-    the ring is: the compiled byte unpack against the compiled plane loop."""
+@pytest.mark.parametrize("guarded", [True, False], ids=["live", "bare"])
+@pytest.mark.parametrize("read", sorted(_BYTE_READS))
+def test_byte_unpack_tpu(read, guarded):
+    """The kernel at the page shapes of the five cells whose reads ask for
+    the byte unpack, guarded as the cells guard them and bare: the compiled
+    byte unpack against the compiled plane loop, bit for bit."""
+    pt, width, tc, name, guard = _BYTE_READS[read]
     rng = np.random.default_rng(51)
-    live = _ring_guard()
+    live = guard()
     n = live.size
-    words, meta = _random_pool(rng, n + 1, 256 * width // (32 * 512), 512)
+    words, meta = _random_pool(rng, n + 1, pt * width // (32 * 512), 512)
     ids = jnp.asarray(rng.permutation(n + 1)[:n], jnp.int32)
-    kw = dict(bits=8, bucket_size=512, tc=16, out_dtype=jnp.bfloat16,
-              row_width=width, name="cgx_dequantize_window", live=live)
+    kw = dict(bits=8, bucket_size=512, tc=tc, out_dtype=jnp.bfloat16,
+              row_width=width, name=name, live=live if guarded else None)
     want = _bits_of(codec_pallas.dequantize_pages(words, meta, ids, **kw))
     got = _bits_of(codec_pallas.dequantize_pages(
         words, meta, ids, unpack="bytes", **kw))
     np.testing.assert_array_equal(got, want)
-    assert want[np.asarray(live, bool)].any()
+    keep = np.asarray(live, bool)
+    assert want[keep].any()
+    assert want[~keep].any() != guarded
 
 
 # ---------------------------------------------------------------------------
@@ -1430,7 +1465,12 @@ def _cell_cases():
     # 16) page table. Where the kernel stores the rows itself it walks the
     # page table in the gathered read's tile (two pages a grid step); the
     # 64-wide ``kr`` keeps the gather and XLA's reshape.
-    paged = {"dequantize_pages": "pallas_paged.meta_planes", "dequantize_rows": flat}
+    # The kernel notes the unpack it took beside its lowering: the plane loop
+    # where the adapter builds its own read (GPT-2, the latent ``c``) ...
+    paged = {"dequantize_pages": "pallas_paged.meta_planes",
+             "dequantize_pages.unpack": "planes", "dequantize_rows": flat}
+    # ... and bytes where the read is ``adapter.attend_paged``'s (ISSUE 53).
+    attend = dict(paged, **{"dequantize_pages.unpack": "bytes"})
     yield "gpt2l-decode-pages", "dequantize_pages", dict(
         read, page=(64, 20, 64),
     ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 10}
@@ -1451,8 +1491,8 @@ def _cell_cases():
     for name in ("k", "v"):
         yield f"granite-decode-pages-{name}", "dequantize_pages", dict(
             bits=8, rows=384, out_dtype=jnp.bfloat16, page=(256, 8, 64),
-            lanes=64, pool=385,
-        ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+            lanes=64, pool=385, unpack="bytes",
+        ), dict(attend, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     # Its commits: the tails that filled in the decode loop (ISSUE 34: 4 of
     # the 64 lanes a call), a padded prompt's 2 or 4 pages in prefill_pages
     # (512 and 1,024 tokens).
@@ -1468,8 +1508,8 @@ def _cell_cases():
     for name in ("k", "v"):
         yield f"olmoh-decode-pages-{name}", "dequantize_pages", dict(
             bits=8, rows=480, out_dtype=jnp.bfloat16, page=(64, 30, 128),
-            lanes=96, pool=481,
-        ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 15}
+            lanes=96, pool=481, unpack="bytes",
+        ), dict(attend, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 15}
     # Its commits: the tails that filled in the decode loop (ISSUE 34: 8 of
     # the 96 lanes a call), a padded prompt's 2 pages in prefill_pages (128
     # tokens).
@@ -1507,10 +1547,11 @@ def _cell_cases():
     # rows, a window layer its (48, 17) ring over a pool of 817 under a
     # lowering counter (and a kernel name) of its own: both paged, two pages
     # a grid step.
-    small = dict(bits=8, out_dtype=jnp.bfloat16, page=(256, 4, 128), lanes=48)
+    small = dict(bits=8, out_dtype=jnp.bfloat16, page=(256, 4, 128), lanes=48,
+                 unpack="bytes")
     yield "smallthinker-decode-pages-global", "dequantize_pages", dict(
         small, rows=48 * 36, pool=1729,
-    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    ), dict(attend, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     yield "smallthinker-decode-pages-window", "dequantize_pages", dict(
         small, rows=48 * 17, pool=817, window=True,
     ), {"dequantize_pages.window": "pallas_paged.meta_planes",
@@ -1530,10 +1571,10 @@ def _cell_cases():
     # layer its (64, 17) ring over a pool of 1,089: both paged, a page a grid
     # step.
     trinity = dict(bits=8, out_dtype=jnp.bfloat16, page=(256, 8, 128),
-                   lanes=64)
+                   lanes=64, unpack="bytes")
     yield "trinity-decode-pages-global", "dequantize_pages", dict(
         trinity, rows=64 * 19, pool=1217,
-    ), dict(paged, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
+    ), dict(attend, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     yield "trinity-decode-pages-window", "dequantize_pages", dict(
         trinity, rows=64 * 17, pool=1089, window=True,
     ), {"dequantize_pages.window": "pallas_paged.meta_planes",
@@ -1559,6 +1600,15 @@ def _cell_cases():
         xing, page=(256, 1, 64),
     ), {"dequantize_pages": "xla_gather", "dequantize": "pallas_flat.bfloat16",
         "dequantize_rows": "xla_reshape"}, {"_pages_tc": None, "_pipe_tc": 16}
+    # ISSUE 52, ISSUE 53: ouro-serve-answer16. ``k`` and ``v`` of all 48
+    # layers, a page of 32 tokens x 16 heads x 128: 65,536 values, four whole
+    # chunks, rows of 2,048, read a pass through a (16, 14) page table over a
+    # pool of 4 x 225 rows: paged, four pages a grid step, the byte unpack.
+    for name in ("k", "v"):
+        yield f"ouro-decode-pages-{name}", "dequantize_pages", dict(
+            bits=8, rows=16 * 14, out_dtype=jnp.bfloat16, page=(32, 16, 128),
+            lanes=16, pool=900, unpack="bytes",
+        ), dict(attend, dequantize="pallas_flat.bfloat16"), {"_pages_tc": 16}
     # Its commits: the tails that filled in the decode loop (4 of the 32
     # lanes a call), a padded prompt's 32 or 64 pages in prefill_pages (8,192
     # and 16,384 tokens).
@@ -1624,7 +1674,8 @@ def _trace_cell_call(kind, *, bits, rows, numel=None, bucket=512, **kw):
         return jax.eval_shape(
             lambda pool, table: paged_kv.gather_dequant_pages(
                 pool, table, spec, kw["out_dtype"],
-                window=kw.get("window", False)),
+                window=kw.get("window", False),
+                unpack=kw.get("unpack", "planes")),
             pool, jax.ShapeDtypeStruct((lanes, rows // lanes), jnp.int32),
         )
     if kind == "quantize":

@@ -488,8 +488,11 @@ def test_the_guard_goes_to_c_and_kr_is_read_whole(params, guard_params,
     site = "cgx.codec.lowering.dequantize_pages."
     if impl == "pallas":
         assert (selects, kernels) == ([], [2] * n)
+        # ... on the plane loop: the adapter's own read asks for no unpack
+        # (ISSUE 53; ``tests/test_serving_layers.py`` pins its program).
         assert metrics.snapshot(site) == {
-            site + "pallas_paged.meta_planes": n, site + "xla_gather": n}
+            site + "pallas_paged.meta_planes": n, site + "unpack.planes": n,
+            site + "xla_gather": n}
     else:
         assert (selects, kernels) == ([server.cfg.kv_lora_rank] * n, [])
     narrow = LatentMoEServer(_cfg(), params, _serve())

@@ -18,6 +18,7 @@ Covers the acceptance set:
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 
@@ -879,8 +880,10 @@ def test_a_shipped_page_reads_back_as_the_senders_pool_row(lowering,
 
     metrics.reset()
     got, want = read(receiver, got_ids), read(sender, sent_ids)
-    assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
-        f"cgx.codec.lowering.dequantize_pages.{lowering}": 2}
+    site = "cgx.codec.lowering.dequantize_pages."
+    assert metrics.snapshot(site) == {
+        site + lowering: 2,
+        **({} if lowering == "xla_gather" else {site + "unpack.planes": 2})}
     live = np.repeat([True, True, False, True], spec.page_tokens)
     np.testing.assert_array_equal(
         np.asarray(got).view(np.uint16)[0, live],
@@ -930,9 +933,12 @@ def test_paged_read_is_the_gathered_read_bit_for_bit(geo, monkeypatch):
     metrics.reset()
     got = paged_kv.gather_dequant_pages(pool, table, spec, dt)
     assert got.shape == (b, p * pt, h * d) and got.dtype == dt
-    lowering = "pallas_paged.meta_planes" if tile else "xla_gather"
-    assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
-        f"cgx.codec.lowering.dequantize_pages.{lowering}": 1}
+    site = "cgx.codec.lowering.dequantize_pages."
+    # The kernel notes the unpack it took (the default: the plane loop); a
+    # gather has no kernel to ask.
+    assert metrics.snapshot(site) == (
+        {site + "pallas_paged.meta_planes": 1, site + "unpack.planes": 1}
+        if tile else {site + "xla_gather": 1})
     assert metrics.get("cgx.codec.lowering.dequantize_rows."
                        + ("pallas_flat" if tile else "xla_reshape")) == 1
 
@@ -946,13 +952,19 @@ def test_paged_read_is_the_gathered_read_bit_for_bit(geo, monkeypatch):
         np.asarray(got).view(kind), np.asarray(want).view(kind))
 
 
+@pytest.mark.parametrize("window", [False, True], ids=["table", "ring"])
 @pytest.mark.parametrize("geo", sorted(_READ_GEOS) + ["raw"])
-def test_guarded_read_zeroes_dead_entries_in_every_lowering(geo, monkeypatch):
+def test_guarded_read_zeroes_dead_entries_in_every_lowering(geo, window,
+                                                            monkeypatch):
     """ISSUE 42: with ``live``, ``gather_dequant_pages`` returns one array
     whichever lowering writes it (the kernel's guard on Pallas dispatch, a
     ``where`` over the gathered decode or over a raw pool's rows): a live
     entry's rows are the unguarded read's bit for bit, a dead entry's are
-    zeros whether its slot names a sentinel or a stale page."""
+    zeros whether its slot names a sentinel or a stale page. ISSUE 53: so
+    for a ring and for a page table, and with either unpack asked for: the
+    byte unpack's rows are the plane loop's bit for bit, guarded and bare,
+    and the call site notes which the kernel took (a gather has no kernel
+    to ask, and notes none)."""
     from torch_cgx_tpu.ops import paged_kv
 
     monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
@@ -968,28 +980,31 @@ def test_guarded_read_zeroes_dead_entries_in_every_lowering(geo, monkeypatch):
     table[0, 2:] = -1  # a short lane: sentinels, dead
     live = np.asarray([[1, 1, 0, 0], [1, 0, 1, 1]], bool)  # and a stale page
     table = jnp.asarray(table)
-
-    bare = paged_kv.gather_dequant_pages(pool, table, spec, dt, window=True)
-    metrics.reset()
-    got = paged_kv.gather_dequant_pages(
-        pool, table, spec, dt, window=True, live=jnp.asarray(live))
-    if geo != "raw":
-        site = "cgx.codec.lowering.dequantize_pages.window."
-        lowering = "pallas_paged.meta_planes" if tile else "xla_gather"
-        # The kernel is asked for the byte unpack (ISSUE 51); a gather has
-        # no kernel to ask.
-        unpack = {site + "unpack.bytes": 1} if tile else {}
-        assert metrics.snapshot(
-            "cgx.codec.lowering.dequantize_pages.") == {
-            site + lowering: 1, **unpack}
-    assert got.shape == bare.shape and got.dtype == bare.dtype == dt
     kind = {2: np.uint16, 4: np.uint32}[np.dtype(dt).itemsize]
 
     def pages(a):
         return np.asarray(a).view(kind).reshape(b, p, pt, h * d)
 
-    np.testing.assert_array_equal(pages(got)[live], pages(bare)[live])
-    assert pages(bare)[~live].any() and not pages(got)[~live].any()
+    bare = paged_kv.gather_dequant_pages(pool, table, spec, dt)
+    assert pages(bare)[~live].any()
+    for unpack in ("planes", "bytes"):
+        read = functools.partial(
+            paged_kv.gather_dequant_pages, pool, table, spec, dt,
+            window=window, unpack=unpack)
+        np.testing.assert_array_equal(pages(read()), pages(bare))
+        metrics.reset()
+        got = read(live=jnp.asarray(live))
+        if geo != "raw":
+            site = ("cgx.codec.lowering.dequantize_pages."
+                    + ("window." if window else ""))
+            lowering = "pallas_paged.meta_planes" if tile else "xla_gather"
+            assert metrics.snapshot(
+                "cgx.codec.lowering.dequantize_pages.") == {
+                site + lowering: 1,
+                **({site + f"unpack.{unpack}": 1} if tile else {})}
+        assert got.shape == bare.shape and got.dtype == bare.dtype == dt
+        np.testing.assert_array_equal(pages(got)[live], pages(bare)[live])
+        assert not pages(got)[~live].any()
 
 
 @pytest.mark.parametrize("page,bits,bucket,why", [
@@ -1604,9 +1619,11 @@ def test_decode_step_hands_the_pool_to_the_kernel_alone(model_setup,
     metrics.reset()
     jaxpr = jax.make_jaxpr(sched._prog.decode_step)(server.p, sched._state)
     reads = 2 * cfg.n_layer
-    assert metrics.snapshot("cgx.codec.lowering.dequantize_pages.") == {
-        "cgx.codec.lowering.dequantize_pages.pallas_paged.meta_planes":
-            reads}
+    # ``GPT2Server`` builds its own read and asks for no unpack (ISSUE 53).
+    site = "cgx.codec.lowering.dequantize_pages."
+    assert metrics.snapshot(site) == {
+        site + "pallas_paged.meta_planes": reads,
+        site + "unpack.planes": reads}
     # (The jitted impl around the kernel is the one other equation.)
     for shape in (words.shape, meta.shape):
         assert sorted(set(_eqns_touching(jaxpr, shape))) == [

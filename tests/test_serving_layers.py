@@ -29,6 +29,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark import weights as gpt2_weights  # noqa: E402
 from torch_cgx_tpu.models.gpt2 import GPT2Config  # noqa: E402
+from torch_cgx_tpu.ops import paged_kv  # noqa: E402
 from torch_cgx_tpu.serving import adapter as adapter_mod  # noqa: E402
 from torch_cgx_tpu.serving import programs as programs_mod  # noqa: E402
 from torch_cgx_tpu.serving import scheduler as sched_mod  # noqa: E402
@@ -48,6 +49,7 @@ from torch_cgx_tpu.serving.window import (  # noqa: E402
     AfmoeServer,
     WindowMoEServer,
 )
+from torch_cgx_tpu.utils.logging import metrics  # noqa: E402
 from torch_cgx_tpu.wire import edges  # noqa: E402
 
 import test_afmoe_serving as afmoe  # noqa: E402
@@ -278,3 +280,139 @@ def test_a_cached_program_holds_no_model():
     again, prog_again, _ = serve()
     assert prog_again is prog and len(sched_mod._PROGRAM_CACHE) == 1
     assert again == tokens and len(tokens) == 6
+
+
+# ---------------------------------------------------------------------------
+# Which unpack each adapter's read of its page tables asks the kernel for
+# (ISSUE 53): the five adapters whose attention is ``adapter.attend_paged``
+# ask for the byte unpack, ring and tables alike; the three that call
+# ``adapter.layer_cache_rows`` themselves keep the plane loop, and their
+# decode step is the parent's.
+# ---------------------------------------------------------------------------
+
+ATTEND_PAGED = ("hybrid_ssm", "hybrid_gdn", "window_moe", "afmoe", "loop")
+
+
+def _kernel_server(kind):
+    """The adapter of ``kind`` over abstract weights at the smallest
+    geometry whose pages the kernel fetches by id, as on the chip: rows of
+    128 values (256 latents) in buckets of 128, a page one whole chunk.
+    Returns ``(server, table reads a step, ring reads a step)``."""
+    serve = adapter_mod.ServeConfig(page_tokens=32, max_batch=2, max_pages=12,
+                                    max_seq=128, ship_depth=2)
+
+    def abstract(weights, hf):
+        return jax.eval_shape(lambda: weights.make_params(hf, 1))
+
+    if kind == "gpt2":
+        hf = dict(GPT2_HF, n_head=2, n_embd=128)
+        cfg = dataclasses.replace(GPT2_CFG, n_head=2, d_model=128)
+        return GPT2Server(cfg, abstract(gpt2_weights, hf), serve), 4, 0
+    if kind == "mla_moe":
+        server = latent._guard_server(
+            abstract(latent.weights, latent.GUARD_HF))
+        return server, server.cfg.n_layer, 0  # ``c``; ``kr`` gathers
+    if kind == "hybrid_kda_mla":
+        return ling._guard_server(
+            abstract(ling.weights, ling.GUARD_HF)), 1, 0
+    if kind == "hybrid_ssm":
+        hf = dict(granite.HF, hidden_size=256, num_attention_heads=4)
+        return HybridSSMServer(
+            granite.HybridConfig.from_hf(hf, dtype=jnp.float32),
+            abstract(granite.weights, hf), serve), 4, 0
+    if kind == "hybrid_gdn":
+        hf = dict(olmo.HF, hidden_size=128)
+        return HybridGDNServer(
+            olmo.OlmoHybridConfig.from_hf(hf, dtype=jnp.float32,
+                                          chunk=olmo.PAGE),
+            abstract(olmo.weights, hf), serve), 4, 0
+    if kind == "loop":  # the passes are a scan: a layer's read traces once
+        hf = dict(loop.HF, head_dim=32)
+        return LoopServer(loop._cfg(hf), abstract(loop.weights, hf),
+                          serve), 4, 0
+    mod, cls, config, rings, tables = {
+        "window_moe": (window, WindowMoEServer, window.WindowMoeConfig, 6, 2),
+        "afmoe": (afmoe, AfmoeServer, afmoe.AfmoeConfig, 4, 1),
+    }[kind]
+    hf = dict(mod.HF, head_dim=64)
+    return cls(config.from_hf(hf, dtype=jnp.float32, q_block=16),
+               abstract(mod.weights, hf), serve), 2 * tables, 2 * rings
+
+
+# The first 16 hex digits of the SHA-256 of ``decode_step``'s jaxpr as text
+# at :func:`_kernel_server`'s geometry on the kernel (interpreted), at 8-
+# and at 4-bit pages, computed on PR 53's parent (ebbaf2a, its ``git
+# archive``): every table's read on the plane loop, the rings' asked for
+# bytes since PR 51.
+PARENT_KERNEL_STEP = {
+    "gpt2": {"8": "579193f0068eb78b", "4": "2f21e17dfa565a4f"},
+    "mla_moe": {"8": "a8b7eaa21f65575d", "4": "cbe0c91f0eebf6f4"},
+    "hybrid_ssm": {"8": "646de725b4584808", "4": "14e8daa87c6cfa49"},
+    "hybrid_gdn": {"8": "f80db0efdf8fa8ed", "4": "86809c5a33e73eea"},
+    "hybrid_kda_mla": {"8": "9ac1be021363005b", "4": "7725f9fb8e843115"},
+    "window_moe": {"8": "665d439d91f618b1", "4": "093cbf73ab4f7310"},
+    "afmoe": {"8": "adb419342ce0b89b", "4": "37805c9e828029b2"},
+    "loop": {"8": "fb036e33cbfc8bfc", "4": "e1fa866ac55d0b76"},
+}
+# ... and of the five adapters' whose tables' read ISSUE 53 moved to the byte
+# unpack, at 8 bits, as PR 53 left them (no parent: the next PR to change one
+# on purpose reads the new value off this test's failure).
+PR53_KERNEL_STEP = {
+    "hybrid_ssm": "53aa69a9e6e0fc2e", "hybrid_gdn": "6702f0db8699dd68",
+    "window_moe": "6ffc4d17d7628139", "afmoe": "cc99593cea862e20",
+    "loop": "b08a792abdfb5822",
+}
+
+
+def _kernel_step(server):
+    """``(sha of the traced decode_step, what its reads noted)``; a program
+    of its own a trace (``jit`` caches by function)."""
+    prog = programs_mod.build(server)
+    state = jax.eval_shape(
+        lambda: programs_mod.fresh_state(prog, server.serve))
+    metrics.reset()
+    text = str(jax.make_jaxpr(prog.decode_step)(server.p, state))
+    site = "cgx.codec.lowering.dequantize_pages."
+    return _sha(text), {name[len(site):]: int(count) for name, count
+                        in metrics.snapshot(site).items()}
+
+
+@pytest.mark.parametrize("bits", ["8", "4"])
+@pytest.mark.parametrize("kind", list(SERVERS))
+def test_attend_paged_asks_for_the_byte_unpack_and_an_adapters_own_read_does_not(
+        kind, bits, monkeypatch):
+    """Traced on the kernel, every read of a page table in the decode step
+    of the five ``attend_paged`` adapters (``LoopServer``'s with
+    ``at_pass``) notes ``dequantize_pages.unpack.bytes`` beside its
+    lowering, two a global layer, and the step with the tables' ask alone
+    put back is the parent's program; ``GPT2Server``, ``LatentMoEServer``
+    and ``HybridLatentMoEServer`` note ``.unpack.planes`` and trace the
+    parent's program as they stand. At 4 bits the kernel does not honour
+    the ask: every adapter notes ``.planes`` and keeps the parent's
+    program."""
+    monkeypatch.setenv("CGX_CODEC_IMPL", "pallas")
+    monkeypatch.setenv("CGX_COMPRESSION_BUCKET_SIZE", "128")
+    monkeypatch.setenv("CGX_KV_BITS", bits)
+    server, tables, rings = _kernel_server(kind)
+    asks = kind in ATTEND_PAGED
+    taken = "bytes" if asks and bits == "8" else "planes"
+    sha, noted = _kernel_step(server)
+    gathers = noted.pop("xla_gather", 0)  # a latent cache's rotated key
+    assert gathers == (tables if kind in ("mla_moe", "hybrid_kda_mla") else 0)
+    ring = {"window.pallas_paged.meta_planes": rings,
+            f"window.unpack.{taken}": rings} if rings else {}
+    assert noted == {"pallas_paged.meta_planes": tables,
+                     f"unpack.{taken}": tables, **ring}
+    parent = PARENT_KERNEL_STEP[kind][bits]
+    if taken == "planes":
+        assert sha == parent
+        return
+    assert sha == PR53_KERNEL_STEP[kind] != parent
+    read = paged_kv.gather_dequant_pages
+
+    def the_parents_ask(*args, window=False, unpack=None, **kw):
+        return read(*args, window=window, **kw,
+                    unpack="bytes" if window else "planes")
+
+    monkeypatch.setattr(paged_kv, "gather_dequant_pages", the_parents_ask)
+    assert _kernel_step(server)[0] == parent

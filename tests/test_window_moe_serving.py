@@ -577,14 +577,15 @@ def test_gpt2s_decode_step_on_the_kernel_is_the_parents(monkeypatch):
 
 @pytest.mark.parametrize("bits,unpack", [("8", "bytes"), ("4", "planes")])
 @pytest.mark.parametrize("adapter", ["window_moe", "afmoe"])
-def test_the_rings_read_asks_for_the_byte_unpack_and_the_tables_does_not(
+def test_the_rings_read_and_the_tables_both_ask_for_the_byte_unpack(
         adapter, bits, unpack, monkeypatch):
-    """ISSUE 51: on the kernel (pages of 32 tokens x 2 K/V heads x 64 in
-    buckets of 128: one whole chunk, fetched by id as on the chip) every
-    window layer's ``k`` and ``v`` read in the decode step of both window
-    adapters notes ``dequantize_pages.window.unpack.bytes`` beside its
-    lowering, ``.unpack.planes`` at 4 bits, which the kernel does not
-    honour; the global layers' reads note their lowering alone."""
+    """ISSUE 51, ISSUE 53: on the kernel (pages of 32 tokens x 2 K/V heads x
+    64 in buckets of 128: one whole chunk, fetched by id as on the chip)
+    every window layer's ``k`` and ``v`` read in the decode step of both
+    window adapters notes ``dequantize_pages.window.unpack.bytes`` beside
+    its lowering and every global layer's ``dequantize_pages.unpack.bytes``
+    (``attend_paged`` asks for both); ``.unpack.planes`` at 4 bits, which
+    the kernel does not honour."""
     from benchmark import weights_afmoe
     from torch_cgx_tpu.models.afmoe import AfmoeConfig
     from torch_cgx_tpu.serving.window import AfmoeServer
@@ -610,6 +611,7 @@ def test_the_rings_read_asks_for_the_byte_unpack_and_the_tables_does_not(
     site = "cgx.codec.lowering.dequantize_pages."
     assert metrics.snapshot(site) == {
         site + "pallas_paged.meta_planes": 2 * global_layers,
+        site + f"unpack.{unpack}": 2 * global_layers,
         site + "window.pallas_paged.meta_planes": 2 * window_layers,
         site + f"window.unpack.{unpack}": 2 * window_layers,
     }

@@ -542,10 +542,13 @@ def _dequantize_flat_impl(
     ``unpack``: how a pass turns its plane words into levels. ``"planes"``,
     the default and every width's but 8 (:func:`unpack_taken`), is the loop
     over the planes, the program every caller that does not ask keeps;
-    ``"bytes"`` is :func:`_unpack_bytes`, asked for by the ring's read
-    alone (``ops/paged_kv.gather_dequant_pages``) until the tables' roofline
-    readers count their bytes true (ROADMAP.md A1(a), A4c). The levels, and
-    so every stored value, are the same bit for bit."""
+    ``"bytes"`` is :func:`_unpack_bytes`, asked for by the K/V attention's
+    reads, ring and page tables (``serving/adapter.attend_paged``, through
+    ``ops/paged_kv.gather_dequant_pages``); the adapters that build their
+    own read (GPT-2, the latent ``c`` stream) do not ask until their
+    tables' roofline readers count their bytes true (ROADMAP.md A1(a),
+    A4c(i)). The levels, and so every stored value, are the same bit for
+    bit."""
     b = bucket_size
     rb = b // 128
     if page_ids is not None and (meta.ndim != 3 or meta.shape[1] != 2):

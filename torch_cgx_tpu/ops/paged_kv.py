@@ -244,6 +244,7 @@ WINDOW_READ = "cgx_dequantize_window"
 def gather_dequant_pages(
     pool, page_table: jax.Array, spec: PageSpec, dtype=jnp.float32,
     *, window: bool = False, live: Optional[jax.Array] = None,
+    unpack: str = "planes",
 ) -> jax.Array:
     """The decode program's paged KV read: decode the pool rows
     ``page_table (B, P)`` names for the consumer -> ``(B, P * page_tokens,
@@ -257,11 +258,17 @@ def gather_dequant_pages(
     callers mask attention scores by the lane's committed token count,
     never by inspecting decoded values. Without ``live`` every table entry
     is decoded. ``window``: the table is a window layer's ring ``(B,
-    ring)``; the kernel is then called :data:`WINDOW_READ`, asked for the
-    byte unpack of 8-bit planes (``codec_pallas._unpack_bytes``; the tables'
-    read keeps the plane loop) and the call site counted as
-    ``cgx.codec.lowering.dequantize_pages.window.*`` (``.unpack.bytes``, or
-    ``.unpack.planes`` at another width, beside the lowering's name).
+    ring)``; the kernel is then called :data:`WINDOW_READ` and the call
+    site counted as ``cgx.codec.lowering.dequantize_pages.window.*``.
+    ``unpack``: how the kernel, where the read takes it, turns 8-bit planes
+    into levels: ``"planes"`` is the loop over the planes, ``"bytes"``
+    ``codec_pallas._unpack_bytes``, the same levels in fewer vector
+    operations. The caller asks (``adapter.attend_paged`` asks for bytes,
+    ring and tables alike; the adapters that build their own read leave the
+    default: ``adapter.layer_cache_rows``), and the call site counts what
+    the kernel then does beside the lowering's name, ``.unpack.bytes`` or,
+    at another width than 8, ``.unpack.planes``
+    (``codec_pallas.unpack_taken``); a gather has no kernel to ask.
     ``live (B, P) bool`` (the ring's caller has one:
     ``adapter.ring_live``): the entries that hold a key some query can
     see. A dead entry's page is neither fetched nor decoded and its rows
@@ -312,21 +319,16 @@ def gather_dequant_pages(
     tile = None
     if ops_dispatch.takes_pallas(spec.flat, spec.cc):
         tile = spec.paged_read_tile(b * p, dtype)
+    site = "dequantize_pages.window" if window else "dequantize_pages"
     codec_pallas.note_lowering(
-        "dequantize_pages.window" if window else "dequantize_pages",
-        "pallas_paged.meta_planes" if tile else "xla_gather",
-    )
+        site, "pallas_paged.meta_planes" if tile else "xla_gather")
     if tile:
-        ring = {}
-        if window:
-            ring = {"name": WINDOW_READ, "unpack": "bytes"}
-            codec_pallas.note_lowering(
-                "dequantize_pages.window.unpack",
-                codec_pallas.unpack_taken("bytes", spec.bits),
-            )
+        codec_pallas.note_lowering(
+            site + ".unpack", codec_pallas.unpack_taken(unpack, spec.bits))
         rows = ops_dispatch.dequantize_pages(
             words, meta, ids, spec.cc, tile=tile, out_dtype=dtype,
-            row_width=width, **ring,
+            row_width=width, unpack=unpack,
+            **({"name": WINDOW_READ} if window else {}),
             live=None if live is None else live.reshape(-1).astype(jnp.int32),
         )
     else:

@@ -256,7 +256,8 @@ def pass_view(state, tails, at_pass, serve: ServeConfig):
 
 def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
                      dtype, window: bool = False, live=None,
-                     paged_only: bool = False, at_pass=None):
+                     paged_only: bool = False, at_pass=None,
+                     unpack: str = "planes"):
     """A layer's cache as its attention contracts it, at a decode position:
     for each of the layer's streams, in order, this token's payload (the
     matching entry of ``fresh``, ``(B, ...)`` of the stream's width) written
@@ -277,7 +278,10 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
     looped adapter's :func:`pass_view`, whose tails keep every pass's rows
     ``(T, B, page_tokens, width)``: the payload goes to pass ``at_pass``'s
     row and that pass's rows are the ones read (the new tail returned is
-    the whole ``(T, ...)`` array)."""
+    the whole ``(T, ...)`` array). ``unpack``: the unpack the read's kernel
+    is asked for (``paged_kv.gather_dequant_pages``); the adapters that
+    call this themselves leave it the plane loop (:func:`attend_paged` says
+    why there are two)."""
     table = state["ring_table" if window else "page_table"]
     pages, tails, new = {}, {}, {}
     for (name, spec), value in zip(layer_streams, fresh):
@@ -292,10 +296,9 @@ def layer_cache_rows(state, layer: int, layer_streams, tail_idx, fresh,
             guard = None
         pages[name] = paged_kv.gather_dequant_pages(
             state["pools"][layer][name], table, spec, dtype, window=window,
-            live=guard,
+            live=guard, unpack=unpack,
         )
     return pages, tails, new
-
 
 
 def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
@@ -316,9 +319,14 @@ def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
     adapter whose :func:`pass_view` ``state`` is (:func:`layer_cache_rows`).
     Returns ``(o (B, H * dh), {stream: its new tail})``."""
     tail_idx, mask_c, mask_t = masks
+    # The byte unpack for the ring and the tables alike. The adapters that
+    # call layer_cache_rows themselves (GPT-2, the latent ones) stay on the
+    # plane loop only because their cells' rooflines count a fixed table
+    # (ROADMAP A1(a)); after it, A4c(ii) deletes the keyword,
+    # ``codec_pallas.unpack_taken`` and the counter: add no third form.
     pages, tails, new = layer_cache_rows(
         state, layer, layer_streams, tail_idx, (k, v), dt, window, live,
-        at_pass=at_pass,
+        at_pass=at_pass, unpack="bytes",
     )
     o = decode_attention(
         q[:, 0], pages["k"], pages["v"], tails["k"], tails["v"],
