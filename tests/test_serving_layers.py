@@ -1,6 +1,6 @@
 """The serving plane's four layers (docs/SERVING.md, "The pieces"):
 ``adapter.py`` under the adapters (``gpt2.py``, ``latent.py``, ``hybrid.py``,
-``window.py``, ``loop.py``) and under ``programs.py``, which is under ``scheduler.py``.
+``window.py``, ``loop.py``, ``block.py``) and under ``programs.py``, which is under ``scheduler.py``.
 Imports point one way, every server is an ``Adapter`` with the defaults the
 copies it lost had, the names the benchmark reaches into the scheduler for
 are where it looks, the state ``programs.fresh_state`` lays out is the one
@@ -61,7 +61,7 @@ import test_olmo_hybrid_serving as olmo  # noqa: E402
 import test_window_moe_serving as window  # noqa: E402
 
 SERVING = Path(adapter_mod.__file__).parent
-ADAPTERS = ("gpt2", "latent", "hybrid", "window", "loop")
+ADAPTERS = ("gpt2", "latent", "hybrid", "window", "loop", "block")
 GPT2_HF = dict(vocab_size=512, n_layer=2, n_head=4, n_embd=64,
                n_positions=128, init={})
 GPT2_CFG = GPT2Config(vocab_size=512, n_layer=2, n_head=4, d_model=64,

@@ -434,13 +434,17 @@ def grouped_matmul(lhs, rhs, sizes):
 
 
 def prefill_attention(q, k, v, q_rope=None, k_rope=None, *, window: int = 0,
-                      scale: float, q_block: int, dtype):
+                      scale: float, q_block: int, dtype, block: int = 1):
     """The attention of a whole prompt from position 0
     (``ops/prefill_attention.py``): ``q (B, S, H, d)``, ``k (B, S, Hk,
     d)``, ``v (B, S, Hk, dv)`` and, for a key of two parts, ``q_rope (B, S,
     H, dr)``, ``k_rope (B, S, dr)`` -> ``(B, S, H * dv)`` in ``dtype``,
     query ``i`` over the keys ``i - window < j <= i`` (every ``j <= i``
-    without a window). Where :func:`prefill_attention.takes_kernel` says the
+    without a window; with ``block = L > 1`` every ``j`` up to the end of
+    ``i``'s block of ``L`` positions, ``j < (i // L + 1) * L``: a model
+    that generates by diffusion over blocks, given by the caller alone that
+    has one, so every other call is what it was). Where
+    :func:`prefill_attention.takes_kernel` says the
     shapes are the kernel's (a prompt of more than ``q_block`` positions),
     the ``cgx_prefill_attention`` kernel on the TPU (and, interpreted,
     wherever ``CGX_CODEC_IMPL=pallas`` asks for the kernels); the loop over
@@ -456,9 +460,10 @@ def prefill_attention(q, k, v, q_rope=None, k_rope=None, *, window: int = 0,
         return pfa.prefill_attention_pallas(
             q.astype(dtype), k, v, q_rope, k_rope, window=window,
             scale=float(scale), interpret=not compiled,
+            **({"block": block} if block > 1 else {}),
         )
     codec_pallas.note_lowering("prefill_attention", "xla")
     return pfa.prefill_attention_xla(
         q, k, v, q_rope, k_rope, window=window, scale=scale,
-        q_block=q_block, dtype=dtype,
+        q_block=q_block, dtype=dtype, **({"block": block} if block > 1 else {}),
     )

@@ -375,6 +375,27 @@ def append_tail_rows(tail: jax.Array, tail_idx: jax.Array, fresh: jax.Array,
     return tail, rows.astype(dtype)
 
 
+def append_tail_block(tail: jax.Array, tail_len: jax.Array, fresh: jax.Array,
+                      store: jax.Array) -> jax.Array:
+    """:func:`append_tail_rows` for a step that runs a BLOCK of ``L``
+    positions a lane and keeps the block's rows on some lanes alone (a
+    model that generates by diffusion over blocks: ``serving/adapter.py``,
+    "A step is not a token"). ``fresh (B, L, ...)``, the block's K or V of
+    ``width`` values a position, goes to rows ``tail_len (B,)`` onward of
+    the lanes in ``store (B,) bool`` as float32; a lane that does not store
+    keeps its tail as it is (its rows are scattered out of bounds and
+    dropped). ``tail_len`` and ``page_tokens`` are multiples of ``L``, so a
+    block never straddles the tail's end. Returns the new tail; the caller
+    reads the OLD tail and the block's own rows apart, since the block is
+    visible to itself whether it is stored or not."""
+    b, rows, width = tail.shape
+    n = fresh.shape[1]
+    at = tail_len[:, None] + jnp.arange(n, dtype=tail_len.dtype)[None, :]
+    at = jnp.where(store[:, None], at, rows)  # out of bounds: dropped
+    return tail.at[jnp.arange(b)[:, None], at].set(
+        fresh.reshape(b, n, width).astype(jnp.float32), mode="drop")
+
+
 def commit_page_rows(pool, page_ids: jax.Array, rows: jax.Array, spec: PageSpec):
     """Functionally write ``rows (n, flat)`` payloads into pool rows
     ``page_ids (n,)`` (quantizing when the spec does) — the jitted

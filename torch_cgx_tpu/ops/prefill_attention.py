@@ -5,6 +5,12 @@ A prefill attends every query of a prompt to the keys it can see::
     out[i, h] = softmax_j(q[i, h] . k[j, kv(h)] * scale) v[j, kv(h)]
                 over  i - window < j <= i      (window = 0: every j <= i)
 
+With ``block = L > 1`` (a model that generates by diffusion over blocks,
+``models/sdar_moe.py``) the causal edge is the end of the query's block of
+``L`` positions: ``j < (i // L + 1) * L``, causal over blocks and both ways
+inside one. Tiles and key blocks are multiples of ``L``, so the items of the
+work list are the same and only the mask of an edge block changes.
+
 :func:`prefill_attention_xla` is the query-block loop the models ran before
 PR 43 (``window_moe.attend_blocks``, ``mla_moe.attend_expanded``), moved here
 unchanged: ``jax.lax.map`` over blocks of ``q_block`` queries, each block's
@@ -50,7 +56,15 @@ TILE_ROWS = 2048
 FIRST, LAST, EDGE = 1, 2, 4
 
 
-def _grouped_loop(q, k, v, *, window, scale, q_block, dtype):
+def _seen(key_pos, q_pos, block: int):
+    """The causal edge: ``key_pos <= q_pos``; with ``block > 1`` the end of
+    the query's block of ``block`` positions."""
+    if block == 1:
+        return key_pos <= q_pos
+    return key_pos < (q_pos // block + 1) * block
+
+
+def _grouped_loop(q, k, v, *, window, scale, q_block, dtype, block=1):
     """``window_moe.attend_blocks`` as PR 41 wrote it (it divided by
     ``sqrt(d)``, which is ``1 / scale``)."""
     dt = dtype
@@ -77,7 +91,7 @@ def _grouped_loop(q, k, v, *, window, scale, q_block, dtype):
                             ) / np.float32(1.0 / scale)
         q_pos = (i * blk + jnp.arange(blk))[:, None]
         key_pos = (lo + jnp.arange(band))[None, :]
-        seen = key_pos <= q_pos
+        seen = _seen(key_pos, q_pos, block)
         if window:
             seen &= q_pos - key_pos < window
         scores = jnp.where(seen, scores, np.float32(-1e30))
@@ -121,17 +135,25 @@ def _two_part_loop(q_nope, q_rope, k_nope, k_rope, v, *, scale, q_block):
 
 
 def prefill_attention_xla(q, k, v, q_rope=None, k_rope=None, *, window: int,
-                          scale, q_block: int, dtype):
+                          scale, q_block: int, dtype, block: int = 1):
     """``q (B, S, H, d)``, ``k (B, S, Hk, d)``, ``v (B, S, Hk, dv)`` and,
     for a key of two parts, ``q_rope (B, S, H, dr)``, ``k_rope (B, S, dr)``
     -> ``(B, S, H * dv)`` in ``dtype``: the loop over blocks of ``q_block``
     queries. One-part keys are read by groups of ``H / Hk`` query heads,
     ``k`` and ``v`` cast to ``dtype`` here, the scores divided by ``1 /
     scale``; two-part keys come in ``dtype``, a K/V head a query head, the
-    scores multiplied by ``scale`` (each as its model wrote it)."""
+    scores multiplied by ``scale`` (each as its model wrote it). ``block``:
+    the module's text; one-part keys without a window, a prompt of whole
+    blocks."""
+    if block > 1 and (k_rope is not None or window or q.shape[1] % block
+                      or min(q_block, q.shape[1]) % block):
+        raise ValueError(
+            f"a block mask of {block} takes one-part keys, no window and a "
+            f"prompt ({q.shape[1]}) and query blocks ({q_block}) of whole "
+            "blocks")
     if k_rope is None:
         return _grouped_loop(q, k, v, window=window, scale=scale,
-                             q_block=q_block, dtype=dtype)
+                             q_block=q_block, dtype=dtype, block=block)
     if window:
         raise ValueError("two-part keys take no window")
     return _two_part_loop(q, q_rope, k, k_rope, v, scale=scale,
@@ -202,8 +224,8 @@ def work_list(s: int, tq: int, tk: int, window: int):
     return tuple(np.asarray(x, np.int32) for x in (tile, block, flags))
 
 
-def _kernel(kb, window, scale, two_part, tile_ref, block_ref, flags_ref,
-            *refs):
+def _kernel(kb, window, scale, two_part, block, tile_ref, block_ref,
+            flags_ref, *refs):
     if two_part:
         (q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref,
          qs_ref, qrs_ref, m_ref, l_ref, acc_ref) = refs
@@ -248,7 +270,7 @@ def _kernel(kb, window, scale, two_part, tile_ref, block_ref, flags_ref,
                 axis=0)
             key_pos = j * tk + jax.lax.broadcasted_iota(
                 jnp.int32, (1, tk), 1)
-            seen = key_pos <= q_pos
+            seen = _seen(key_pos, q_pos, block)
             if window:
                 seen &= q_pos - key_pos < window
             s = jnp.where(seen, s, MASKED)
@@ -288,13 +310,18 @@ def _kernel(kb, window, scale, two_part, tile_ref, block_ref, flags_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "scale", "tq", "tk", "interpret"))
+    jax.jit,
+    static_argnames=("window", "scale", "tq", "tk", "interpret", "block"))
 def prefill_attention_pallas(q, k, v, q_rope=None, k_rope=None, *,
                              window: int, scale: float, tq: int = 0,
-                             tk: int = 0, interpret: bool = False):
+                             tk: int = 0, interpret: bool = False,
+                             block: int = 1):
     """:func:`prefill_attention_xla` as one Pallas kernel; the products take
     every operand in ``q``'s type, which is the output's. ``tq`` queries a
-    tile and ``tk`` keys a block (:func:`tiles` where 0)."""
+    tile and ``tk`` keys a block (:func:`tiles` where 0). ``block``: the
+    module's text; tiles and key blocks are then whole blocks of it, so a
+    tile's last key block is still the block of its last query and a key
+    block before the tile's first query is still seen whole."""
     b, s, h, d = q.shape
     hk, dv = k.shape[2], v.shape[3]
     two_part = k_rope is not None
@@ -304,6 +331,8 @@ def prefill_attention_pallas(q, k, v, q_rope=None, k_rope=None, *,
     hb = heads_a_step(h, hk, dr)
     kb = 1 if hk < h else hb
     tq, tk = tiles(s, hb, tq, tk, 8 if interpret else 128)
+    assert block == 1 or not (window or two_part or s % block or tq % block
+                              or tk % block), (block, window, s, tq, tk)
     # Whole tiles and whole blocks: a key past the prompt is past every
     # query's position, and a query past it is cut off below.
     sq, sk = -(-s // tq) * tq, -(-s // tk) * tk
@@ -312,7 +341,7 @@ def prefill_attention_pallas(q, k, v, q_rope=None, k_rope=None, *,
         x = x.astype(q.dtype).reshape(b, s, -1)
         return jnp.pad(x, ((0, 0), (0, to - s), (0, 0))) if to > s else x
 
-    tile, block, flags = work_list(s, tq, tk, window)
+    tile, key_block, flags = work_list(s, tq, tk, window)
 
     def at_tile(bi, hi, i, ti, bl, fl):
         return (bi, ti[i], hi)
@@ -342,7 +371,8 @@ def prefill_attention_pallas(q, k, v, q_rope=None, k_rope=None, *,
                 pltpu.VMEM((hb * tq, dv), jnp.float32)]
     pairs = len(tile) * tq * tk
     out = pl.pallas_call(
-        functools.partial(_kernel, kb, window, np.float32(scale), two_part),
+        functools.partial(_kernel, kb, window, np.float32(scale), two_part,
+                          block),
         name="cgx_prefill_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -364,5 +394,6 @@ def prefill_attention_pallas(q, k, v, q_rope=None, k_rope=None, *,
                 + (h // hb) * len(tile) * tk * (kb * (d + dv) + dr)),
         ),
         interpret=interpret,
-    )(jnp.asarray(tile), jnp.asarray(block), jnp.asarray(flags), *operands)
+    )(jnp.asarray(tile), jnp.asarray(key_block), jnp.asarray(flags),
+      *operands)
     return out[:, :s]

@@ -36,6 +36,10 @@ inference:
 * :mod:`.loop` — the looped adapter: ``k``, ``v`` pages on every layer, the
   layers run ``cache_passes`` times a token and every pass keeps pages and
   tails of its own (Ouro's block).
+* :mod:`.block` — the block-diffusion adapter: ``k``, ``v`` pages on every
+  layer, a decode step runs a block of ``block_tokens`` positions a lane and
+  either denoises it (nothing stored) or stores it and emits its tokens
+  (SDAR's block: QK-normed grouped-query attention, dropless experts).
 * :mod:`.slo` — the WireController's serving objective: re-solve KV
   bit-width per layer against TTFT / tokens-per-second SLOs from the
   live metric stream.
@@ -57,5 +61,6 @@ from .hybrid import (  # noqa: F401
 )
 from .window import AfmoeServer, WindowMoEServer  # noqa: F401
 from .loop import LoopServer  # noqa: F401
+from .block import BlockDiffusionServer  # noqa: F401
 from .slo import ServeSloController  # noqa: F401
 from .transport import KvPageReceiver, KvPageSender  # noqa: F401

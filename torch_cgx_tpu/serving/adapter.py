@@ -40,14 +40,39 @@ the lane's table plus its offset (:func:`pass_view`). With ``cache_passes``
 1, which every adapter but ``serving/loop.py``'s states, nothing has the
 dimension and every program is what it was.
 
+A step is not a token. A model that generates by diffusion over blocks runs
+a BLOCK of ``L`` positions a lane a step: the adapter states ``block_tokens =
+L`` ("Blocks" in docs/SERVING.md) and ``state`` then holds ``tokens (B, L)``,
+the lane's open block (``mask_token`` where a position is not known yet),
+``known (B, L) bool``, ``unmask_step (B, L)``, the denoising step at which a
+position became known (-1 for a token of the prompt), and ``block_step
+(B,)``, the denoising steps the open block has had. A lane whose block is
+all known STORES at the step it is next run (:func:`block_stores`): that
+forward's K and V go to the tail (``paged_kv.append_tail_block``), ``pos``
+and ``tail_len`` advance by ``L`` and the ``L`` tokens are the step's
+output; every other active lane DENOISES: its forward's K and V are
+dropped, and some masked positions take their greedy token
+(:func:`unmask_block`, the one unmask rule). So a lane-step yields 0 tokens
+or ``L``, the device decides which, and the host reads it with the tokens.
+A block's queries see the lane's committed pages, its tail's live rows and
+the block's own ``L`` keys, all of them, in one softmax
+(:func:`attend_paged_block`); a prefill runs under the same mask
+(``ops.dispatch.prefill_attention(block=L)``). Prefill produces no token: it
+covers the prompt's whole blocks, and the ``n % L`` tokens left open the
+lane's first block as known tokens (``admit_lane``). ``page_tokens`` is a
+multiple of ``L``, so a block never straddles a page. With ``block_tokens``
+1, which every adapter but ``serving/block.py``'s states, ``state`` has none
+of those entries and every program is what it was.
+
 :class:`Adapter` is the protocol, with the defaults every adapter shares; a
 model is a subclass in a module of its own (``gpt2.py``, ``latent.py``,
-``hybrid.py``, ``window.py``) that imports this module, ``models/`` and
-``ops/`` and nothing above. Beside it, what a ``decode_forward`` is made of:
-the lane's masks (:func:`lane_masks`, :func:`page_live`,
-:func:`ring_masks`, :func:`ring_live`), a layer's cache as its attention
-contracts it (:func:`layer_cache_rows`), and the K/V attention over both
-(:func:`attend_paged`).
+``hybrid.py``, ``window.py``, ``loop.py``, ``block.py``) that imports this
+module, ``models/`` and ``ops/`` and nothing above. Beside it, what a
+``decode_forward`` is made of: the lane's masks (:func:`lane_masks`,
+:func:`page_live`, :func:`ring_masks`, :func:`ring_live`,
+:func:`block_masks`), a layer's cache as its attention contracts it
+(:func:`layer_cache_rows`), and the K/V attention over both
+(:func:`attend_paged`, :func:`attend_paged_block`).
 """
 
 from __future__ import annotations
@@ -335,6 +360,115 @@ def attend_paged(state, layer: int, layer_streams, masks, q, k, v, dt,
     return o, new
 
 
+# What a block step counts on the device, as ``cgx.serve.<name>``; the last
+# entries of a block adapter's ``step_counters``, which ``programs.build``'s
+# ``decode_step`` appends to what ``decode_forward`` counted: active lanes
+# run, lanes that stored (blocks finished), positions unmasked, and denoise
+# lane-steps that unmasked more than the schedule's share.
+BLOCK_COUNTERS = ("block.lane_steps", "block.stores", "block.unmasked",
+                  "block.early")
+
+
+def block_stores(state):
+    """``(B,) bool``: the lanes that store at the step ``state`` is given
+    to: active, and every position of the open block known."""
+    return state["active"] & jnp.all(state["known"], axis=-1)
+
+
+def block_masks(serve: ServeConfig, state):
+    """:func:`lane_masks` for a step that runs a block a lane: ``(mask_c (B,
+    pages x page_tokens)``, the committed positions; ``mask_t (B,
+    page_tokens))``, the tail's live positions, which are the rows before
+    ``tail_len``: the block's own keys are read apart
+    (:func:`attend_paged_block`), stored or not."""
+    pt = serve.page_tokens
+    b = state["tokens"].shape[0]
+    committed = state["n_pages"] * pt
+    pos_c = jax.lax.broadcasted_iota(
+        jnp.int32, (b, serve.pages_per_seq * pt), 1)
+    pos_t = jax.lax.broadcasted_iota(jnp.int32, (b, pt), 1)
+    return (pos_c < committed[:, None],
+            pos_t < state["tail_len"][:, None])
+
+
+def attend_paged_block(state, layer: int, layer_streams, masks, q, k, v, dt,
+                       score_divisor, store, live=None):
+    """:func:`attend_paged` for a block of ``L`` positions a lane: ``q (B,
+    L, H, dh)`` over the lane's committed pages, read ONCE for the ``L``
+    queries (:func:`paged_kv.gather_dequant_pages`, the byte unpack, the
+    table's ``live`` slots), the tail's live rows and the block's own ``k``
+    and ``v (B, L, Hk, dh)``, every one of which every query of the block
+    sees: one joined softmax. The block's rows go to the raw tails of the
+    lanes in ``store (B,) bool`` alone (``paged_kv.append_tail_block``).
+    ``masks`` are :func:`block_masks`'. The queries are folded head-major
+    into ``decode_attention``'s head axis, ``H x L`` heads of which ``L x H
+    / Hk`` neighbours read one K/V head, and the block's own rows ride
+    behind the tail's (``page_tokens + L`` rows a lane): the contraction is
+    the single-position one and no key is transposed or copied but those
+    few. Returns ``(o (B, L, H * dh), {stream: its new tail})``."""
+    mask_c, mask_t = masks
+    b, n, h, dh = q.shape
+    # The rows behind the pages: the tail's, the block's own, and dead rows
+    # up to a whole number of lanes' worth (128), so that the joined
+    # scores' last dimension tiles (at 1,280 + 64 + 4 columns XLA's cost
+    # model gave up on the softmax's fusions: PERF.md section 6, PR 54).
+    pad = -(mask_t.shape[1] + n) % 128
+    behind = jnp.concatenate(
+        [mask_t, jnp.ones((b, n), bool), jnp.zeros((b, pad), bool)], axis=1)
+    rows, new = {}, {}
+    for (name, spec), fresh in zip(layer_streams, (k, v)):
+        tail = state[f"tail_{name}"][layer]
+        new[name] = paged_kv.append_tail_block(
+            tail, state["tail_len"], fresh, store)
+        rows[name] = (
+            paged_kv.gather_dequant_pages(
+                state["pools"][layer][name], state["page_table"], spec, dt,
+                live=live, unpack="bytes"),
+            jnp.concatenate(
+                [tail.astype(dt), fresh.reshape(b, n, -1).astype(dt),
+                 jnp.zeros((b, pad, tail.shape[-1]), dt)], axis=1),
+        )
+    o = decode_attention(
+        q.transpose(0, 2, 1, 3).reshape(b, h * n, dh),
+        rows["k"][0], rows["v"][0], rows["k"][1], rows["v"][1],
+        mask=mask_c, tail_mask=behind, score_divisor=score_divisor,
+    )
+    o = o.reshape(b, h, n, dh).transpose(0, 2, 1, 3)
+    return o.reshape(b, n, h * dh), new
+
+
+def unmask_block(conf, known, step, steps: int, threshold: float):
+    """The unmask rule of block diffusion, one function for both published
+    schedules. ``conf (B, L)`` float32, the confidence of the greedy token
+    at every position; ``known (B, L) bool``; ``step (B,)``, the denoising
+    steps the block has had; ``steps = T`` and ``threshold = tau`` the
+    adapter's. The schedule's share of step ``s`` is ``n_s = L // T + (s < L
+    % T)``, no more than are masked. ``low_confidence_dynamic``: where at
+    least ``n_s`` masked positions have ``conf > tau`` all of those are
+    unmasked, else the ``n_s`` most confident masked ones;
+    ``low_confidence_static`` is the same rule at ``tau >= 1``, which no
+    confidence passes. Among equal confidences the earlier position goes
+    first (the published ``topk`` leaves that open). Returns ``(unmask (B,
+    L) bool, early (B,) bool``: more than ``n_s`` unmasked). Works on
+    ``jax`` and ``numpy`` arrays alike."""
+    conf = jnp.asarray(conf, jnp.float32)
+    masked = ~jnp.asarray(known)
+    n = conf.shape[-1]
+    step = jnp.asarray(step)
+    share = jnp.minimum(n // steps + (step < n % steps),
+                        jnp.sum(masked, axis=-1))
+    # A masked position's rank among the masked, most confident first.
+    ahead = masked[:, None, :] & (
+        (conf[:, None, :] > conf[:, :, None])
+        | ((conf[:, None, :] == conf[:, :, None])
+           & (jnp.arange(n)[None, :] < jnp.arange(n)[:, None])))
+    most = masked & (jnp.sum(ahead, axis=-1) < share[:, None])
+    confident = masked & (conf > threshold)
+    enough = jnp.sum(confident, axis=-1) >= share
+    unmask = jnp.where(enough[:, None], confident, most)
+    return unmask, jnp.sum(unmask, axis=-1) > share
+
+
 class Adapter:
     """The protocol between a model and the serving plane, for one ``(model
     config, params)`` pair. What the scheduler and its programs ask of an
@@ -371,6 +505,23 @@ class Adapter:
         width)``, ``prefill_forward`` returns a layer's payload as
         ``(cache_passes, B, S, n_head, d_head)`` and ``decode_forward`` a
         layer's new tail with the same leading dimension.
+    ``block_tokens``
+        positions a lane a decode step runs: 1 for every model but one that
+        generates by diffusion over blocks (the module's text above, "A
+        step is not a token"). Above 1, ``L``: ``state["tokens"]`` is ``(B,
+        L)`` beside ``known``, ``unmask_step`` and ``block_step``; the
+        adapter also states ``mask_token``, ``denoise_steps`` and
+        ``unmask_threshold`` (:func:`unmask_block`'s ``T`` and ``tau``); its
+        ``step_counters`` end with :data:`BLOCK_COUNTERS`, which the decode
+        program counts; ``decode_forward`` returns logits ``(B, L, V)``,
+        position ``i``'s row predicting position ``i``'s token, and writes
+        the block's rows into the tails of :func:`block_stores`' lanes
+        alone; ``prefill_forward`` is given the prompt's whole blocks alone
+        and returns None in the logits' place (there is no first token: the
+        ``n % L`` tokens the prefill leaves out open the lane's first block
+        as known tokens, ``admit_lane``'s ``token`` operand, ``(L,)`` with
+        -1 where a position is masked). ``serve.page_tokens`` is a multiple
+        of ``L``.
     ``layer_name(l)``
         the layer's ``kv_page`` edge name.
     ``cache_streams(l)``
@@ -414,6 +565,7 @@ class Adapter:
     step_counters: Tuple[str, ...] = ()
     guards_global_read: bool = False
     cache_passes: int = 1
+    block_tokens: int = 1
 
     def __init__(self, model_cfg, params,
                  serve: Optional[ServeConfig] = None):

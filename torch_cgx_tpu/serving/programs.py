@@ -40,6 +40,14 @@ page_tokens, width)``. A page id, a lane's table row, ``n_pages`` and
 ``prefill_pages`` and ``admit_lane`` write all ``T`` passes' rows of the ids
 they are given, a layer and a stream in one call.
 
+An adapter that runs a block of ``L = block_tokens`` positions a lane a step
+(``serving/adapter.py``, "A step is not a token") has ``tokens (max_batch,
+L)`` beside ``known``, ``unmask_step`` and ``block_step``; its
+``decode_step`` is :func:`build`'s ``decode_block_step``, whose output says
+what each lane emitted; its prefill leaves no first token and its
+``admit_lane`` takes the lane's first block. With ``block_tokens`` 1 the
+state has none of those entries and every program is what it was.
+
 Which of the programs built here a scheduler runs now is the scheduler's
 decision (its LRU and the key it is held under); nothing here knows of it.
 """
@@ -55,7 +63,13 @@ import jax.numpy as jnp
 
 from .. import config as cfg_mod
 from ..ops import paged_kv
-from .adapter import ServeConfig, ring_pages
+from .adapter import (
+    BLOCK_COUNTERS,
+    ServeConfig,
+    block_stores,
+    ring_pages,
+    unmask_block,
+)
 
 # The per-lane bookkeeping of the decode state, and what ``release_lanes``
 # resets each entry of a finished or evicted lane to.
@@ -173,6 +187,25 @@ def build(server) -> SimpleNamespace:
             f"{'window layers' if ring else f'the state streams {state_names}'}"
             ": a pass dimension is the global page pools' and the tails'"
         )
+    block = int(server.block_tokens)
+    if block > 1:
+        if passes > 1 or ring or state_names:
+            raise ValueError(
+                f"adapter {server.kind!r} states a block of {block} positions"
+                " a step beside cache passes, window layers or state "
+                "streams: a block step is the global pages' and the tails'"
+            )
+        if sv.page_tokens % block:
+            raise ValueError(
+                f"a page of {sv.page_tokens} tokens is not whole blocks of "
+                f"{block}: a block would straddle the tail's end"
+            )
+        if tuple(server.step_counters[-len(BLOCK_COUNTERS):]) != (
+                BLOCK_COUNTERS):
+            raise ValueError(
+                f"adapter {server.kind!r} states a block and step counters "
+                f"that do not end with {BLOCK_COUNTERS}"
+            )
 
     def pass_rows(ids):
         """The pool rows the page ids ``ids (n,)`` name in every pass,
@@ -204,6 +237,68 @@ def build(server) -> SimpleNamespace:
         if counts is not None:
             nxt = jnp.concatenate([nxt, counts.astype(jnp.int32)])
         return out, nxt
+
+    def decode_block_step(params, state):
+        """One forward of the open block of every lane (``block_tokens`` >
+        1). A lane whose block is all known (``adapter.block_stores``)
+        STORES: the forward's K and V went to its tails, ``pos`` and
+        ``tail_len`` advance by ``L``, the block's tokens are emitted and
+        the next block opens all masked. Every other active lane DENOISES:
+        greedy token and confidence (the softmax's largest, float32) at
+        every position, some masked positions unmasked
+        (``adapter.unmask_block``), nothing stored. Returns the new state
+        and what the host reads each tick, in one int32 array
+        (:func:`split_block_step` takes it apart): the blocks
+        as this step was given them ``(B x L)`` (a storing lane's are the
+        block it stored), the tokens each lane emitted ``(B,)`` (0, or the
+        stored block's positions that were generated: ``L`` but in a first
+        block, which opens with the prompt's remainder), each position's
+        unmask step ``(B x L)`` (-1 for a token of the prompt), whether the lane
+        stores at the NEXT step ``(B,)`` (its block is all known now: the
+        host's counts of tails and of tokens left follow a step early),
+        then the adapter's ``step_counters``, ``adapter.BLOCK_COUNTERS``
+        last."""
+        srv = server.with_params(params)
+        store = block_stores(state)
+        logits, new_tails, counts = srv.decode_forward(state, streams)
+        active, known = state["active"], state["known"]
+        denoise = active & ~store
+        best = jnp.max(logits, axis=-1)
+        conf = jnp.exp(best - jax.nn.logsumexp(logits, axis=-1))
+        unmask, early = unmask_block(
+            conf, known, state["block_step"], srv.denoise_steps,
+            srv.unmask_threshold)
+        unmask &= denoise[:, None]
+        tokens = jnp.where(
+            unmask, jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            state["tokens"])
+        known = known | unmask
+        out = dict(state)
+        for name in names:
+            out[f"tail_{name}"] = tuple(new_tails[name])
+        stored = store[:, None]
+        out["tokens"] = jnp.where(stored, jnp.int32(srv.mask_token), tokens)
+        out["known"] = known & ~stored
+        out["unmask_step"] = jnp.where(
+            stored, 0,
+            jnp.where(unmask, state["block_step"][:, None],
+                      state["unmask_step"]))
+        out["block_step"] = jnp.where(
+            store, 0, state["block_step"] + denoise.astype(jnp.int32))
+        grown = block * store.astype(jnp.int32)
+        out["tail_len"] = state["tail_len"] + grown
+        out["pos"] = state["pos"] + grown
+        block_counts = jnp.stack([
+            jnp.sum(active), jnp.sum(store), jnp.sum(unmask),
+            jnp.sum(early & denoise)]).astype(jnp.int32)
+        emitted = jnp.sum(state["unmask_step"] >= 0, axis=-1) * store
+        return out, jnp.concatenate([
+            state["tokens"].reshape(-1), emitted.astype(jnp.int32),
+            state["unmask_step"].reshape(-1),
+            (denoise & jnp.all(known, axis=-1)).astype(jnp.int32),
+            *(() if counts is None else (counts.astype(jnp.int32),)),
+            block_counts,
+        ])
 
     def commit(state, lanes, page_ids, ring_ids=None):
         """Promote the full tails of ``lanes (K,)`` into pool pages
@@ -272,8 +367,18 @@ def build(server) -> SimpleNamespace:
         the pages itself)."""
         srv = server.with_params(params)
         logits, *payloads = srv.prefill_forward(tokens, positions, last_idx)
+        if block > 1:
+            # No first token (the prompt's remainder opens the lane's first
+            # block): in its place the count of the non-finite values the
+            # last layer cached at ``last_idx``, 0 of a sound prefill, which
+            # the host reads as the prefill's end.
+            last = jax.lax.dynamic_index_in_dim(
+                [x for x in payloads[0] if x is not None][-1], last_idx, 1)
+            first = jnp.sum(~jnp.isfinite(last), axis=(1, 2, 3))
+        else:
+            first = jnp.argmax(logits, axis=-1)
         return (
-            jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            first.astype(jnp.int32),
             dict(zip(names + state_names, payloads)),
         )
 
@@ -352,11 +457,20 @@ def build(server) -> SimpleNamespace:
         H * Dh)}``, device or host arrays alike, and its recurrent state
         ``{state stream: (L, *shape)}`` (whatever the lane's last request
         left there is overwritten whole). ``ring_row (ring,)``: the lane's
-        row of ``ring_table``, where a layer has a window."""
+        row of ``ring_table``, where a layer has a window. An adapter that
+        runs a block a step has no first token: ``token (L,)`` is the
+        lane's first block, the prompt's tokens past its last whole block
+        and -1 at the positions still masked."""
         out = dict(state)
+        opens = (("tokens", token),) if block == 1 else (
+            ("tokens", jnp.where(token >= 0, token, server.mask_token)),
+            ("known", token >= 0),
+            ("unmask_step", jnp.where(token >= 0, -1, 0)),
+            ("block_step", 0),
+        )
         for name, value in (
             ("page_table", table_row), ("n_pages", n_pages),
-            ("tail_len", tail_len), ("tokens", token), ("pos", pos),
+            ("tail_len", tail_len), *opens, ("pos", pos),
             ("active", True),
         ) + ((("ring_table", ring_row),) if ring else ()):
             out[name] = state[name].at[lane].set(value)
@@ -390,6 +504,7 @@ def build(server) -> SimpleNamespace:
         window=max(windows),
         ring=ring,
         passes=passes,
+        block=block,
         # Cache streams over the layers (and passes) of each class: (global,
         # window).
         class_streams=tuple(
@@ -400,7 +515,8 @@ def build(server) -> SimpleNamespace:
         state_streams=state_streams,
         state_names=state_names,
         specs=_leading_specs(streams),
-        decode_step=jax.jit(decode_step, donate_argnums=(1,)),
+        decode_step=jax.jit(decode_step if block == 1 else decode_block_step,
+                            donate_argnums=(1,)),
         commit=jax.jit(commit, donate_argnums=(0,)),
         ingest=jax.jit(ingest, donate_argnums=(0,)),
         prefill=jax.jit(prefill),
@@ -408,6 +524,17 @@ def build(server) -> SimpleNamespace:
         admit_lane=jax.jit(admit_lane, donate_argnums=(0,)),
         release_lanes=jax.jit(release_lanes, donate_argnums=(0,)),
     )
+
+
+def split_block_step(out, lanes: int, block: int):
+    """What the host read of a block step (``build``'s
+    ``decode_block_step``), apart: ``(tokens (B, L), emitted (B,),
+    unmask_step (B, L), stores next (B,), the step counters)``."""
+    tokens, out = out[: lanes * block], out[lanes * block:]
+    emitted, out = out[:lanes], out[lanes:]
+    unmask, out = out[: lanes * block], out[lanes * block:]
+    return (tokens.reshape(lanes, block), emitted,
+            unmask.reshape(lanes, block), out[:lanes], out[lanes:])
 
 
 def fresh_state(prog: SimpleNamespace, serve: ServeConfig) -> Dict:
@@ -464,11 +591,17 @@ def fresh_state(prog: SimpleNamespace, serve: ServeConfig) -> Dict:
         "page_table": jnp.full((b, serve.pages_per_seq), -1, jnp.int32),
         "n_pages": jnp.zeros((b,), jnp.int32),
         "tail_len": jnp.zeros((b,), jnp.int32),
-        "tokens": jnp.zeros((b,), jnp.int32),
+        "tokens": jnp.zeros((b,) if prog.block == 1 else (b, prog.block),
+                            jnp.int32),
         "pos": jnp.zeros((b,), jnp.int32),
         "active": jnp.zeros((b,), bool),
         **({"ring_table": jnp.full((b, ring), -1, jnp.int32)}
            if ring else {}),
+        # The open block of a lane whose step runs a block of positions.
+        **({"known": jnp.zeros((b, prog.block), bool),
+            "unmask_step": jnp.zeros((b, prog.block), jnp.int32),
+            "block_step": jnp.zeros((b,), jnp.int32)}
+           if prog.block > 1 else {}),
     }
 
 

@@ -29,6 +29,16 @@ beyond the one being read). With ``eos_token`` set a lane can finish
 unannounced under a step so queued: the token that step decodes for it is
 dropped (``cgx.serve.decode.discarded_tokens``).
 
+An adapter whose step runs a block of ``L`` positions a lane
+(``adapter.block_tokens``, docs/SERVING.md "Blocks") yields 0 tokens a lane
+a step or ``L``, and the device decides which. The host's counts follow what
+each read says: beside the tokens a lane emitted the step's output names the
+lanes whose block is all known now, which are the lanes that store at the
+NEXT step, so the tail lengths and the tokens left are counted a step before
+the store is read (:meth:`ContinuousBatchScheduler._account_block_step`), and
+the commit of full tails and the step queued ahead stay what they are. With
+``block_tokens`` 1 none of that runs.
+
 The tick is cut where the host stops: a ``trace_span`` at every dispatch
 (``serve.prefill.forward``, ``serve.admit_lane``, ``serve.dispatch.commit``,
 ``serve.dispatch.step``) and at every blocking read
@@ -162,6 +172,9 @@ class Request:
     submitted_at: float = 0.0
     first_token_at: Optional[float] = None
     done: bool = False
+    # An adapter that generates by diffusion over blocks: the denoising step
+    # of its block at which each token of ``output`` was unmasked.
+    unmask_step: List[int] = dataclasses.field(default_factory=list)
 
 
 
@@ -272,6 +285,9 @@ class _Ready:
     # waits for that program and nothing behind it. 0 from a page stream,
     # whose first token is on the host.
     owed: int = 0
+    # An adapter whose step runs a block: the lane's first block ``(L,)``,
+    # the prompt's tokens past its last whole block, -1 where masked.
+    block: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -284,6 +300,9 @@ class _Step:
     tokens: jax.Array
     lanes: Dict[int, Request]
     owed: int
+    # A block step: whether the host has counted its stores
+    # (:meth:`ContinuousBatchScheduler._account_block_step`).
+    accounted: bool = True
 
 
 class ContinuousBatchScheduler:
@@ -347,6 +366,12 @@ class ContinuousBatchScheduler:
         # with window layers the lane's ring.
         self._n_pages = np.zeros((sv.max_batch,), np.int64)
         self._ring_of = np.zeros((sv.max_batch,), np.int64)
+        # An adapter whose step runs a block: the lanes that store at the
+        # step after the last one read (the device says so, a read early),
+        # and the prompt tokens in a lane's first block, which its first
+        # store does not emit.
+        self._store_next = np.zeros((sv.max_batch,), bool)
+        self._block_skip = np.zeros((sv.max_batch,), np.int64)
         # Dispatched and unread, in device order: lane writes whose first
         # token nobody has read, and decode steps (two at most, the second
         # only by :meth:`_runs_ahead`).
@@ -502,6 +527,7 @@ class ContinuousBatchScheduler:
         generation bump already dropped the tables)."""
         self.cache.free_seq(req.id)
         req.output.clear()
+        req.unmask_step.clear()
         req.first_token_at = None
         self._waiting.insert(0, req)
 
@@ -536,6 +562,7 @@ class ContinuousBatchScheduler:
         self._tail_len[:] = 0
         self._left[:] = 0
         self._n_pages[:] = 0
+        self._store_next[:] = False
         if requeued:
             log.info(
                 "serving scheduler reset (%s): %d request(s) requeued "
@@ -891,14 +918,16 @@ class ContinuousBatchScheduler:
         sv = self.server.serve
         prompt = np.asarray(req.tokens, np.int32)
         s = prompt.shape[0]
-        if s < 1 or s + req.max_new_tokens > sv.max_seq:
+        block = self._prog.block
+        # A lane of a block adapter holds whole blocks: its last one too.
+        if s < 1 or -(-(s + req.max_new_tokens) // block) * block > sv.max_seq:
             raise ValueError(
                 f"request {req.id!r}: prompt {s} + max_new "
                 f"{req.max_new_tokens} exceeds CGX_SERVE_MAX_SEQ "
                 f"{sv.max_seq}"
             )
         pids: List[int] = []
-        for _ in range(s // sv.page_tokens):
+        for _ in range(s // block * block // sv.page_tokens):
             pid = self.cache.alloc(req.id)
             if pid is None:
                 self.cache.free_seq(req.id)
@@ -931,6 +960,18 @@ class ContinuousBatchScheduler:
         opened now is closed by :meth:`_read_first_tokens`."""
         sv = self.server.serve
         pt = sv.page_tokens
+        opens, padded_len = None, 0
+        if self._prog.block > 1:
+            # The prefill covers the prompt's whole blocks, padded as the
+            # whole prompt would be (a prompt length has one padded length
+            # whatever its remainder; zeros past the whole blocks are later
+            # blocks, which nothing real sees); the tokens past them open
+            # the lane's first block as known tokens.
+            whole = prompt.shape[0] // self._prog.block * self._prog.block
+            opens = np.full((self._prog.block,), -1, np.int32)
+            opens[: prompt.shape[0] - whole] = prompt[whole:]
+            padded_len = _pad_prompt(prompt, pt).shape[0]
+            prompt = prompt[:whole]
         s, n_full = prompt.shape[0], len(pids)
         tail_len = s - n_full * pt
         start = time.perf_counter()
@@ -943,7 +984,8 @@ class ContinuousBatchScheduler:
                 "serve.prefill.forward",
                 hist="cgx.serve.prefill_forward_s", req=req.id,
             ):
-                padded = _pad_prompt(prompt, pt)
+                padded = (np.pad(prompt, (0, padded_len - s)) if padded_len
+                          else _pad_prompt(prompt, pt))
                 # A last page that is a tail goes to the scratch row.
                 ids = np.full((padded.shape[0] // pt,), sv.max_pages,
                               np.int32)
@@ -952,7 +994,7 @@ class ContinuousBatchScheduler:
                     self._prog.prefill_pages(
                         self.server.p, self._state["pools"], padded[None],
                         np.arange(padded.shape[0], dtype=np.int32)[None],
-                        np.int32(s - 1), ids, np.int32(tail_len),
+                        np.int32(max(s - 1, 0)), ids, np.int32(tail_len),
                         *self._ring_rows(ring, n_full, len(ids)),
                     )
                 )
@@ -968,7 +1010,7 @@ class ContinuousBatchScheduler:
             req=req, page_ids=pids, tails=tails,
             tail_len=tail_len, first_token=first, pos=s,
             states=states, span=(start, fields), qerr_rows=qerr_rows,
-            ring=ring, owed=owed,
+            ring=ring, owed=owed, block=opens,
         )
 
     def _slot_rows(self, ring_id, pages):
@@ -1069,6 +1111,10 @@ class ContinuousBatchScheduler:
             table_row = np.full((sv.pages_per_seq,), -1, np.int32)
             table_row[: len(ready.page_ids)] = ready.page_ids
             first = ready.first_token
+            if ready.block is not None:
+                token = ready.block  # no first token: the lane's first block
+            else:
+                token = np.int32(first) if isinstance(first, int) else first
             n_full, ring = len(ready.page_ids), self._prog.ring
             self._n_pages[lane] = n_full
             ring_row = ()
@@ -1081,15 +1127,20 @@ class ContinuousBatchScheduler:
             self._state = self._prog.admit_lane(
                 self._state, np.int32(lane), table_row,
                 np.int32(n_full), np.int32(ready.tail_len),
-                np.int32(first) if isinstance(first, int) else first,
-                np.int32(ready.pos), ready.tails, ready.states, *ring_row,
+                token, np.int32(ready.pos), ready.tails, ready.states,
+                *ring_row,
             )
             self._owe("admit_lane", req=req.id)
             if ready.states:
                 metrics.add("cgx.serve.state.lane_writes")
             self._lanes[lane] = req
             self._tail_len[lane] = ready.tail_len
-            self._left[lane] = max(req.max_new_tokens - 1, 0)
+            if ready.block is None:
+                self._left[lane] = max(req.max_new_tokens - 1, 0)
+            else:  # no first token: every token is a step's
+                self._left[lane] = req.max_new_tokens
+                self._store_next[lane] = False
+                self._block_skip[lane] = int((ready.block >= 0).sum())
             self._unread.append((lane, ready))
             metrics.add("cgx.serve.requests_admitted")
         ready.admitted_at = time.perf_counter()
@@ -1140,18 +1191,25 @@ class ContinuousBatchScheduler:
                 self._fail(req, e)
                 continue
             self._close_prefill_span(ready.span, ok=True)
-            now = time.monotonic()
-            req.first_token_at = now
-            ttft_ms = (now - req.submitted_at) * 1e3
-            metrics.observe("cgx.serve.ttft_ms", ttft_ms)
-            timeline.instant(
-                "serve.admit", cat=timeline.CAT_TRACE, req=req.id,
-                lane=int(lane), ttft_ms=round(ttft_ms, 3),
-            )
+            if ready.block is not None:
+                continue  # what was read is the prefill's end, no token
+            self._stamp_first_token(lane, req)
             self._emit(lane, req, first)
             self._note_tokens(1)
         self._release_lanes()  # a first token can finish its request
         return bool(n)
+
+    def _stamp_first_token(self, lane: int, req: Request) -> None:
+        """The host holds the request's first token (a block adapter's: its
+        first block's store): its stamp and its TTFT."""
+        now = time.monotonic()
+        req.first_token_at = now
+        ttft_ms = (now - req.submitted_at) * 1e3
+        metrics.observe("cgx.serve.ttft_ms", ttft_ms)
+        timeline.instant(
+            "serve.admit", cat=timeline.CAT_TRACE, req=req.id,
+            lane=int(lane), ttft_ms=round(ttft_ms, 3),
+        )
 
     def _emit(self, lane: int, req: Request, token: int) -> None:
         """Hand a request the token the host has just read for it."""
@@ -1172,7 +1230,7 @@ class ContinuousBatchScheduler:
         req.done = True
         self._done.append(req)
         late = self._vacate(lane)
-        if late:
+        if late and self._prog.block == 1:  # a block's are counted at emit
             metrics.add("cgx.serve.decode.discarded_tokens", float(late))
         metrics.add("cgx.serve.requests_completed")
 
@@ -1182,6 +1240,7 @@ class ContinuousBatchScheduler:
         struck out, nobody reads them."""
         self._lanes[lane] = None
         self._left[lane] = 0
+        self._store_next[lane] = False
         self._released.append(lane)
         return sum(
             step.lanes.pop(lane, None) is not None for step in self._steps
@@ -1246,17 +1305,85 @@ class ContinuousBatchScheduler:
         with trace_span("serve.decode.emit", hist="cgx.serve.decode_emit_s"):
             metrics.add("cgx.serve.decode_steps")
             # What the adapter counted this step, read with the tokens.
-            for name, count in zip(self.server.step_counters,
-                                   nxt[sv.max_batch:]):
+            if self._prog.block == 1:
+                counts = nxt[sv.max_batch:]
+            else:
+                *block_out, counts = programs.split_block_step(
+                    nxt, sv.max_batch, self._prog.block)
+            for name, count in zip(self.server.step_counters, counts):
                 metrics.add(f"cgx.serve.{name}", float(count))
             metrics.set(
                 "cgx.serve.batch_occupancy", len(step.lanes) / sv.max_batch
             )
-            for lane, req in step.lanes.items():
-                self._emit(lane, req, int(nxt[lane]))
+            if self._prog.block == 1:
+                for lane, req in step.lanes.items():
+                    self._emit(lane, req, int(nxt[lane]))
+                emitted = len(step.lanes)
+            else:
+                emitted = self._emit_blocks(step, *block_out)
             self._release_lanes()
-            self._note_tokens(len(step.lanes))
+            self._note_tokens(emitted)
         return True
+
+    def _emit_blocks(self, step: _Step, tokens, emitted, unmask,
+                     stores) -> int:
+        """The read of a block step (``programs.split_block_step``'s
+        parts): a lane that stored
+        hands its request the block's generated tokens (a first block's
+        prompt tokens, whose unmask step is -1, are the prompt's), each with
+        its unmask step, up to what the request asked for or its end of
+        sequence; what the block holds past that is discarded
+        (``cgx.serve.decode.discarded_tokens``). The lanes that store at
+        the next step are noted, and the step queued ahead, whose stores
+        are known only now, is counted. Returns the tokens emitted."""
+        eos = self.server.serve.eos_token
+        held = list(step.lanes)
+        self._store_next[held] = stores[held] != 0
+        total = 0
+        for lane in held:
+            if not emitted[lane]:
+                continue
+            req = step.lanes[lane]
+            if req.first_token_at is None:
+                self._stamp_first_token(lane, req)
+            fresh = unmask[lane] >= 0
+            assert emitted[lane] == fresh.sum(), (lane, emitted, unmask)
+            new = [int(t) for t in tokens[lane][fresh]]
+            take = new[: req.max_new_tokens - len(req.output)]
+            if eos is not None and eos in take:
+                take = take[: take.index(eos) + 1]
+            req.output.extend(take)
+            req.unmask_step.extend(
+                int(u) for u in unmask[lane][fresh][: len(take)])
+            total += len(take)
+            if len(take) < len(new):
+                metrics.add("cgx.serve.decode.discarded_tokens",
+                            float(len(new) - len(take)))
+            if len(req.output) >= req.max_new_tokens or take[-1] == eos:
+                self._finish_lane(lane)
+        for ahead in self._steps:
+            if not ahead.accounted:
+                self._account_block_step(ahead)
+        return total
+
+    def _account_block_step(self, step: _Step) -> None:
+        """The host's counts for a dispatched block step, once the read of
+        the step before it has named the lanes that store at it
+        (``_store_next``): their tails grow by a block and their requests
+        have a block's tokens fewer to come (a first block's prompt tokens
+        are none of them). A step dispatched behind a read is counted at
+        its dispatch, the step queued ahead at that read; either way before
+        the next commit or dispatch, which therefore find every tail's
+        length exact up to the step before: a tail that filled there is
+        promoted before the lane's next store, queued ahead or not."""
+        n = self._prog.block
+        stores = [i for i in step.lanes if self._store_next[i]]
+        self._tail_len[stores] += n
+        self._left[stores] -= np.minimum(
+            self._left[stores], n - self._block_skip[stores])
+        self._block_skip[stores] = 0
+        self._store_next[stores] = False
+        step.accounted = True
 
     def _runs_ahead(self) -> bool:
         """Whether the next step may be dispatched before the last one is
@@ -1287,6 +1414,15 @@ class ContinuousBatchScheduler:
         held = [i for i, r in enumerate(self._lanes) if r is not None]
         if self._prog.ring or self.server.guards_global_read:
             self._note_live_pages(held)
+        if self._prog.block > 1:
+            # Which lanes store is known once the step before is read: now,
+            # unless this step is queued ahead of that read.
+            step = _Step(tokens=tokens, owed=owed, accounted=False,
+                         lanes={i: self._lanes[i] for i in held})
+            if not self._steps:
+                self._account_block_step(step)
+            self._steps.append(step)
+            return
         self._tail_len[held] += 1
         lanes = {i: self._lanes[i] for i in held if self._left[i] > 0}
         self._left[list(lanes)] -= 1
@@ -1312,7 +1448,7 @@ class ContinuousBatchScheduler:
         # The tail positions the step reads beside them, its own token's
         # among them (the tail's mask: ``adapter.lane_masks``).
         metrics.add("cgx.serve.kv.live_tail_rows",
-                    float((self._tail_len[held] + 1).sum()))
+                    float((self._tail_len[held] + self._prog.block).sum()))
         if self.server.guards_global_read:
             metrics.add("cgx.serve.kv.decoded_pages.global", committed)
             metrics.add("cgx.serve.kv.table_pages.global",
@@ -1351,6 +1487,7 @@ class ContinuousBatchScheduler:
                 metrics.add("cgx.serve.decode_evictions")
                 self.cache.free_seq(req.id)
                 req.output.clear()
+                req.unmask_step.clear()
                 req.first_token_at = None
                 self._waiting.append(req)
                 self._vacate(lane)

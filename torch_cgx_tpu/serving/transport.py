@@ -68,11 +68,19 @@ def require_kv_streams(server) -> None:
     (a latent cache), whose layers do not all leave K and V pages, or that
     keeps a recurrent state a lane (no frame kind ships one), or whose
     layers run several passes a token (a frame names a layer and a page and
-    no pass), instead of half-working."""
+    no pass), or whose step runs a block of positions (a stream brings a
+    first token, a block adapter has none), instead of half-working."""
     refusal = (
         "the disaggregated prefill path ships K and V page frames "
         f"(serving/transport.py kinds); adapter {server.kind!r} "
     )
+    if server.block_tokens > 1:
+        raise ValueError(
+            f"{refusal}runs a block of {server.block_tokens} positions a "
+            "step and opens a lane with the prompt's remainder, where a "
+            "stream's META frame carries one first token, and is served "
+            "with local prefill only"
+        )
     if server.cache_passes > 1:
         raise ValueError(
             f"{refusal}runs its layers {server.cache_passes} times a token "
